@@ -1,16 +1,15 @@
-"""Shared bench provenance: every bench record header must say what
-platform it was captured on, prominently, so bench_gate.py and human
-readers can never mistake a CPU capture for a TPU regression (the
-BENCH_r04/r05 confusion class — ROADMAP environment note).
+"""Shared bench provenance: every bench record header says what device it
+was captured on, so that no CPU capture can be filed under a per-chip
+metric (the BENCH_r05 class, ROADMAP environment note).
 
 Usage in every bench*.py:
 
     from bench_common import provenance
     rec = {"metric": ..., "value": ..., **provenance()}
 
-``provenance()`` probes the live jax backend once (cached) and returns
-``{"on_tpu": bool, "platform": str}``; processes without jax report
-``platform="none"``.
+``provenance()`` initialises JAX in the calling process, which takes the
+chip: a parent calls it once the children that need the chip are done.
+It raises where JAX finds no TPU; these scripts measure a chip or fail.
 """
 
 from __future__ import annotations
@@ -20,16 +19,12 @@ import functools
 
 @functools.lru_cache(maxsize=1)
 def provenance() -> dict:
-    try:
-        import jax
+    import jax
 
-        try:
-            jax.devices()
-        except RuntimeError:
-            # A pinned-but-dead accelerator plugin: fall back to whatever
-            # backend initializes (mirrors bench.py's probe fallback).
-            jax.config.update("jax_platforms", "")
-        backend = jax.default_backend()
-    except Exception:  # noqa: BLE001 — bench boxes without jax still stamp
-        backend = "none"
-    return {"on_tpu": backend == "tpu", "platform": backend}
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise RuntimeError(
+            f"this benchmark measures a TPU; JAX found {dev.platform!r} "
+            f"({dev.device_kind})"
+        )
+    return {"on_tpu": True, "platform": "tpu", "device_kind": dev.device_kind}

@@ -294,12 +294,6 @@ def best_of(fn, n: int) -> dict:
 def main(repeat: int = 2) -> dict:
     import os
 
-    import jax
-
-    try:
-        jax.devices()
-    except RuntimeError:
-        jax.config.update("jax_platforms", "")
     from bench_common import provenance
 
     import ray_tpu

@@ -58,15 +58,10 @@ def _pct(sorted_vals, p):
 
 
 def record(out, metric, value, unit, **extra):
-    from bench_common import provenance
-
     rec = {
         "metric": metric,
         "value": round(value, 2) if isinstance(value, float) else value,
         "unit": unit,
-        # platform provenance first-class: bench_gate refuses
-        # cross-platform comparisons keyed on on_tpu
-        **provenance(),
         "loadavg_1m_at_capture": round(os.getloadavg()[0], 2),
         "note": NOTE,
     }
@@ -479,6 +474,13 @@ def main():
         except Exception:  # noqa: BLE001
             pass
         ray_tpu.shutdown()
+    # Platform provenance is stamped here, once the replicas that needed
+    # the chip are gone: asking JAX earlier would take the chip from
+    # them.  bench_gate refuses cross-platform comparisons keyed on it.
+    from bench_common import provenance
+
+    for rec in out.values():
+        rec.update(provenance())
     with open(args.out, "w") as f:
         json.dump(out, f, indent=1)
     print(f"wrote {args.out} ({len(out)} records)")

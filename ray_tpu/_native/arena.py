@@ -3,91 +3,81 @@
 store, playing plasma's role from the reference:
 src/ray/object_manager/plasma/).
 
-The library is compiled on first use (g++, cached next to this file);
-environments without a toolchain fall back to the pure-Python
-file-per-object store automatically.
+The library is compiled from shm_arena.cpp on first use (g++) and kept
+next to this file under a name that carries the source's hash, so a
+library built from another revision of the source is never loaded.  A
+build or load that fails raises: the toolchain is part of the
+installation, and a store that quietly ran on another backend would be
+a different system from the one under test.
 """
 
 from __future__ import annotations
 
 import ctypes
-import logging
+import hashlib
 import os
 import subprocess
 import threading
 from typing import Optional, Tuple
 
-logger = logging.getLogger(__name__)
-
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "shm_arena.cpp")
-_LIB = os.path.join(_HERE, "libshm_arena.so")
 
 ID_SIZE = 32
 
 _build_lock = threading.Lock()
 _lib = None
-_lib_failed = False
 
 
-def _build(force: bool = False) -> Optional[str]:
-    if not force and os.path.exists(_LIB) and os.path.getmtime(_LIB) >= os.path.getmtime(_SRC):
-        return _LIB
-    # Per-process temp output: every worker on a host may rebuild
-    # concurrently (e.g. a shipped .so that doesn't load here), and a
-    # shared .tmp would race one compiler's truncation against another's
-    # os.replace, promoting a partially written library.
-    tmp = f"{_LIB}.tmp.{os.getpid()}"
+class NativeArenaBuildError(RuntimeError):
+    """shm_arena.cpp did not compile or the result did not load."""
+
+
+def _lib_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha1(f.read()).hexdigest()[:12]
+    return os.path.join(_HERE, f"libshm_arena.{digest}.so")
+
+
+def _build() -> str:
+    lib = _lib_path()
+    if os.path.exists(lib):
+        return lib
+    # Per-process temp output: every worker on a host may build
+    # concurrently on a fresh checkout, and a shared .tmp would race one
+    # compiler's truncation against another's os.replace, promoting a
+    # partially written library.
+    tmp = f"{lib}.tmp.{os.getpid()}"
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", tmp, "-lpthread"]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _LIB)
-        return _LIB
+        os.replace(tmp, lib)
     except (subprocess.SubprocessError, OSError) as e:
-        stderr = getattr(e, "stderr", b"")
-        logger.warning("native arena build failed (%s); falling back to file store: %s",
-                       e, stderr.decode(errors="replace")[:500] if stderr else "")
+        stderr = getattr(e, "stderr", b"") or b""
         try:
             os.unlink(tmp)
         except OSError:
             pass
-        return None
-
-
-def _dlopen(path: str):
-    """CDLL that treats an unloadable prebuilt .so (e.g. built against a
-    newer GLIBC than this host's) as "rebuild from source", not a crash:
-    a wheel can legitimately ship a library the target machine can't
-    load, and the pure-Python file store is always there to fall back to."""
-    try:
-        return ctypes.CDLL(path)
-    except OSError as e:
-        logger.warning("prebuilt %s does not load on this host (%s); rebuilding", path, e)
-        if _build(force=True) is None:
-            return None
-        try:
-            return ctypes.CDLL(path)
-        except OSError as e2:
-            logger.warning("rebuilt arena library still does not load: %s", e2)
-            return None
+        raise NativeArenaBuildError(
+            f"native arena build failed ({e}): "
+            f"{stderr.decode(errors='replace')[:2000]}"
+        ) from e
+    return lib
 
 
 def load_library():
-    """Build+load the shared library once per process; None if unavailable."""
-    global _lib, _lib_failed
-    if _lib is not None or _lib_failed:
+    """Build+load the shared library once per process."""
+    global _lib
+    if _lib is not None:
         return _lib
     with _build_lock:
-        if _lib is not None or _lib_failed:
+        if _lib is not None:
             return _lib
         path = _build()
-        if path is None:
-            _lib_failed = True
-            return None
-        lib = _dlopen(path)
-        if lib is None:
-            _lib_failed = True
-            return None
+        try:
+            lib = ctypes.CDLL(path)
+        except OSError as e:
+            raise NativeArenaBuildError(f"{path} does not load: {e}") from e
         lib.arena_create.restype = ctypes.c_void_p
         lib.arena_create.argtypes = [ctypes.c_char_p, ctypes.c_uint64, ctypes.c_uint32, ctypes.c_uint32]
         lib.arena_attach.restype = ctypes.c_void_p
@@ -150,8 +140,6 @@ class NativeArena:
     @classmethod
     def create(cls, path: str, capacity: int, table_cap: int = 65536, free_cap: int = 65536) -> Optional["NativeArena"]:
         lib = load_library()
-        if lib is None:
-            return None
         h = lib.arena_create(path.encode(), capacity, table_cap, free_cap)
         if not h:
             return None
@@ -160,8 +148,6 @@ class NativeArena:
     @classmethod
     def attach(cls, path: str) -> Optional["NativeArena"]:
         lib = load_library()
-        if lib is None:
-            return None
         h = lib.arena_attach(path.encode())
         if not h:
             return None

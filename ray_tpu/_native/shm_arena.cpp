@@ -14,7 +14,7 @@
 // immutable once sealed, and eviction cannot reclaim an entry whose
 // refcount > 0.
 //
-// Build: g++ -O3 -shared -fPIC shm_arena.cpp -o libshm_arena.so
+// Build: arena.py compiles this on first use (g++ -O3 -shared -fPIC)
 // Python binding: ctypes (ray_tpu/_native/arena.py).
 
 #include <cerrno>

@@ -12,22 +12,26 @@ slice advertises
                                placement group can pin the coordinator
     tpu-slice:<name>         — slice-affinity label resource
 
-Detection order: explicit env (TPU_CHIPS_PER_HOST), GCE metadata server,
-/dev/accel* device files, then a registered JAX TPU backend.
+Detection never touches JAX: the process that detects is the driver or
+a raylet, and a process that initialises the TPU backend takes the chip
+from the workers that need it.  Chips on this host are counted from the
+device files the TPU driver exposes (``/dev/accel*``, or one numbered
+VFIO group per chip under ``/dev/vfio``); ``TPU_CHIPS_PER_HOST``
+overrides the count.  The accelerator type and slice identity come from
+``TPU_ACCELERATOR_TYPE`` / ``TPU_WORKER_ID`` or the GCE metadata server;
+where neither says, they stay unknown and no typed resource is
+advertised.
 """
 
 from __future__ import annotations
 
 import glob
-import json
 import os
 import urllib.request
 from typing import Dict, Optional
 
 GCE_TPU_METADATA_URL = "http://metadata.google.internal/computeMetadata/v1/instance/attributes/"
 _METADATA_HEADERS = {"Metadata-Flavor": "Google"}
-
-TPU_VISIBLE_CHIPS_ENV = "TPU_VISIBLE_CHIPS"
 
 
 def _query_gce_metadata(key: str, timeout: float = 0.5) -> Optional[str]:
@@ -53,49 +57,24 @@ class TPUAcceleratorManager:
         env_chips = os.environ.get("TPU_CHIPS_PER_HOST")
         if env_chips:
             info["chips"] = int(env_chips)
-            info["accelerator_type"] = os.environ.get("TPU_ACCELERATOR_TYPE", "v5litepod-8")
-        if info["chips"] == 0 and not os.environ.get("RAY_TPU_SKIP_METADATA"):
-            accel = _query_gce_metadata("accelerator-type") if not os.environ.get("TPU_SKIP_MDS_QUERY") else None
-            if accel:
-                info["accelerator_type"] = accel
-                info["chips"] = cls._chips_per_host_for(accel)
-                info["pod_name"] = _query_gce_metadata("instance-id")
-                info["worker_id"] = int(_query_gce_metadata("agent-worker-number") or 0)
-                info["topology"] = _query_gce_metadata("tpu-env")
-        if info["chips"] == 0:
-            # Device files on a TPU VM.
-            accel_devs = glob.glob("/dev/accel*")
-            if accel_devs:
-                info["chips"] = len(accel_devs)
-                info["accelerator_type"] = os.environ.get("TPU_ACCELERATOR_TYPE", "v5litepod-8")
-        if info["chips"] == 0 and os.environ.get("RAY_TPU_DETECT_TPU_VIA_JAX"):
-            # A live JAX TPU backend (covers tunneled/virtual setups).
-            # Opt-in: initializing jax here would lock the chip to this
-            # process (raylet), starving the actual compute workers.
-            try:
-                import jax
-
-                devs = [d for d in jax.devices() if d.platform == "tpu"]
-                if devs:
-                    info["chips"] = len([d for d in devs if getattr(d, "process_index", 0) == jax.process_index()]) or len(devs)
-                    kind = devs[0].device_kind.lower().replace(" ", "")
-                    info["accelerator_type"] = kind
-            except Exception:
-                pass
+        else:
+            info["chips"] = len(glob.glob("/dev/accel*")) or len(
+                [p for p in glob.glob("/dev/vfio/*") if os.path.basename(p).isdigit()]
+            )
+        if info["chips"]:
+            info["accelerator_type"] = os.environ.get("TPU_ACCELERATOR_TYPE")
+            info["worker_id"] = int(os.environ.get("TPU_WORKER_ID") or 0)
+            if info["accelerator_type"] is None and not (
+                os.environ.get("RAY_TPU_SKIP_METADATA") or os.environ.get("TPU_SKIP_MDS_QUERY")
+            ):
+                accel = _query_gce_metadata("accelerator-type")
+                if accel:
+                    info["accelerator_type"] = accel
+                    info["pod_name"] = _query_gce_metadata("instance-id")
+                    info["worker_id"] = int(_query_gce_metadata("agent-worker-number") or 0)
+                    info["topology"] = _query_gce_metadata("tpu-env")
         cls._cached = info
         return info
-
-    @staticmethod
-    def _chips_per_host_for(accelerator_type: str) -> int:
-        # v5litepod-N / v4-N etc.: chips per host is min(4, N) for v4
-        # (4 chips/host) and min(8, N) for v5e/v5p/v2/v3 style hosts.
-        try:
-            family, count = accelerator_type.split("-", 1)
-            count = int(count.split("-")[-1])
-        except ValueError:
-            return 0
-        per_host = 4 if family in ("v4", "v5p") else 8
-        return min(per_host, count)
 
     # -- reference-parity interface ---------------------------------------
     @staticmethod
@@ -115,24 +94,15 @@ class TPUAcceleratorManager:
         """Pod-type + head resources for slice-topology-aware placement."""
         info = cls._detect()
         out: Dict[str, float] = {}
-        if not info["chips"]:
+        accel = info["accelerator_type"]
+        if not info["chips"] or not accel:
             return out
-        accel = info["accelerator_type"] or "tpu"
         out[f"TPU-{accel}"] = float(info["chips"])
         if info["worker_id"] == 0:
             out[f"TPU-{accel}-head"] = 1.0
         if info["pod_name"]:
             out[f"tpu-slice:{info['pod_name']}"] = 1.0
         return out
-
-    @staticmethod
-    def set_current_process_visible_accelerators(ids) -> None:
-        os.environ[TPU_VISIBLE_CHIPS_ENV] = ",".join(str(i) for i in ids)
-
-    @staticmethod
-    def get_current_process_visible_accelerator_ids():
-        v = os.environ.get(TPU_VISIBLE_CHIPS_ENV)
-        return v.split(",") if v else None
 
     @classmethod
     def get_current_pod_name(cls) -> Optional[str]:

@@ -71,15 +71,12 @@ def detect_resources(num_cpus=None, num_tpus=None, resources=None, memory=None) 
     if num_tpus is not None:
         out["TPU"] = float(num_tpus)
     else:
-        try:
-            from ray_tpu._private.accelerators import tpu as tpu_accel
+        from ray_tpu._private.accelerators.tpu import TPUAcceleratorManager
 
-            n = tpu_accel.TPUAcceleratorManager.get_current_node_num_accelerators()
-            if n:
-                out["TPU"] = float(n)
-                out.update(tpu_accel.TPUAcceleratorManager.get_current_node_additional_resources())
-        except Exception:
-            pass
+        n = TPUAcceleratorManager.get_current_node_num_accelerators()
+        if n:
+            out["TPU"] = float(n)
+            out.update(TPUAcceleratorManager.get_current_node_additional_resources())
     if memory is not None:
         out["memory"] = float(memory)
     else:

@@ -64,16 +64,15 @@ class ObjectEntry:
 ARENA_FILENAME = "arena"
 
 
-def _try_native_arena(store_dir: str, capacity: int, create: bool):
-    try:
-        from ray_tpu._native.arena import NativeArena
+def _native_arena(store_dir: str, capacity: int, create: bool):
+    """The node's arena: created by the raylet, attached by its clients.
+    A library that does not build raises (arena.NativeArenaBuildError)."""
+    from ray_tpu._native.arena import NativeArena
 
-        path = os.path.join(store_dir, ARENA_FILENAME)
-        if create:
-            return NativeArena.create(path, capacity)
-        return NativeArena.attach(path) if os.path.exists(path) else None
-    except Exception:
-        return None
+    path = os.path.join(store_dir, ARENA_FILENAME)
+    if create:
+        return NativeArena.create(path, capacity)
+    return NativeArena.attach(path) if os.path.exists(path) else None
 
 
 class ObjectStoreCore:
@@ -91,8 +90,9 @@ class ObjectStoreCore:
         self.num_puts = 0
         self.num_gets = 0
         self.num_evictions = 0
-        # Native arena backend (plasma-equivalent); None → file fallback.
-        self.arena = _try_native_arena(store_dir, capacity_bytes, create=True)
+        # Native arena backend (plasma-equivalent); None when the mapping
+        # could not be created → file-per-object store (stats()["backend"]).
+        self.arena = _native_arena(store_dir, capacity_bytes, create=True)
         if self.arena is not None and CONFIG.arena_prefault_bytes > 0:
             # Background trickled prefault of the hot low region (the
             # bump allocator + freelist reuse low offsets): puts landing
@@ -639,6 +639,7 @@ class ObjectStoreCore:
             # excluded from LRU eviction, so drain migration can't be
             # silently undone by memory pressure.
             "num_pinned": sum(1 for e in self.objects.values() if e.pin_count > 0),
+            "backend": "native_arena" if self.arena is not None else "file",
         }
 
 
@@ -670,7 +671,7 @@ class StoreClient:
         self._raylet = raylet_client  # rpc.RpcClient to the local raylet
         self.store_dir = store_dir
         # Attach to the node's native arena if the raylet created one.
-        self.arena = _try_native_arena(store_dir, 0, create=False)
+        self.arena = _native_arena(store_dir, 0, create=False)
 
     def put_blob(self, object_id: ObjectID, blob: bytes) -> int:
         """Store an already-flattened serialized blob."""

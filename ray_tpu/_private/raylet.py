@@ -48,6 +48,7 @@ class WorkerHandle:
         "running", "spawn_time", "idle_since", "resources_held", "bundle_key",
         "direct_address", "lease_owner", "lease_blocked", "reserved",
         "env_hash", "log_path", "spawn_token", "tenant", "detached",
+        "chips",
     )
 
     def __init__(self, worker_id: WorkerID, proc, job_id: JobID):
@@ -89,6 +90,17 @@ class WorkerHandle:
         self.tenant: str = tenants_mod.DEFAULT_TENANT
         # Detached-actor worker: survives its creating job's teardown.
         self.detached = False
+        # TPU chips of the lease this process was spawned for.  0 = the
+        # process is held to the CPU backend and never opens the chip;
+        # > 0 = a chip owner: it serves that one lease and exits with it
+        # (Raylet._spawn_worker / _hold_chips_until_exit).
+        self.chips = 0.0
+
+    @property
+    def pool_key(self) -> Tuple[JobID, str, float]:
+        """Idle-pool identity: a task or lease only takes a worker of its
+        job, its runtime env and its TPU share."""
+        return (self.job_id, self.env_hash, self.chips)
 
 
 class Raylet:
@@ -133,7 +145,10 @@ class Raylet:
 
         # Worker pool; idle queues keyed by (job_id, runtime-env hash).
         self.workers: Dict[WorkerID, WorkerHandle] = {}
-        self.idle_workers: Dict[Tuple[JobID, str], deque] = defaultdict(deque)
+        self.idle_workers: Dict[Tuple[JobID, str, float], deque] = defaultdict(deque)
+        # TPU still debited for chip owners that were told to go and
+        # whose process has not exited yet: worker id -> (debit, watcher).
+        self._chip_holds: Dict[WorkerID, Tuple[ResourceSet, asyncio.Task]] = {}
         # env_hash -> (error message, monotonic time): envs whose staging
         # failed recently; tasks requiring them fail fast with
         # RuntimeEnvSetupError instead of spawn-looping.
@@ -566,6 +581,8 @@ class Raylet:
                 waiter.fut.set_result("draining")
         self.bundles.clear()
         self.resources_available = self.resources_total.copy()
+        for hold, _task in self._chip_holds.values():
+            self.resources_available.subtract(hold)
         self._inflight_lease_usage.clear()
         self.draining = False
         self.drain_reason = None
@@ -683,10 +700,50 @@ class Raylet:
             self.actor_workers.pop(w.actor_id, None)
         self._release_resources(w)
         if w.proc is not None and w.proc.poll() is None:
+            self._hold_chips_until_exit(w)
             try:
                 w.proc.terminate()
             except Exception:
                 pass
+
+    def _hold_chips_until_exit(self, w: WorkerHandle):
+        """The one-owner-per-chip rule.  A process that initialised the
+        TPU backend keeps the chip until it is gone, whatever the ledger
+        says.  So whichever way a chip owner ends (lease returned, task
+        done, killed, crashed, its bundle returned), the TPU it was
+        spawned for stays debited from the node until its process has
+        exited: no later TPU grant can start a second process on a chip
+        that is still taken.  The debit is the node's, also for a worker
+        placed in a bundle, so it survives the bundle's return (and may
+        take the node's free TPU below zero until the exit)."""
+        if not w.chips or w.worker_id in self._chip_holds:
+            return
+        if w.proc is None or w.proc.poll() is not None:
+            return
+        hold = ResourceSet.of({"TPU": w.chips})
+        self.resources_available.subtract(hold)
+        self._chip_holds[w.worker_id] = (
+            hold, self.loop.create_task(self._release_chip_hold(w)),
+        )
+
+    async def _release_chip_hold(self, w: WorkerHandle):
+        # SIGTERM was sent (or the worker is exiting by itself); SIGKILL
+        # once if it is still there after five seconds.
+        kill_at = time.monotonic() + 5.0
+        try:
+            while w.proc.poll() is None:
+                if time.monotonic() > kill_at:
+                    kill_at = float("inf")
+                    try:
+                        w.proc.kill()
+                    except OSError:
+                        pass
+                await asyncio.sleep(0.02)
+        finally:
+            hold, _task = self._chip_holds.pop(w.worker_id)
+            self.resources_available.add(hold)
+        self._grant_lease_waiters()
+        self._schedule_dispatch()
 
     # ------------------------------------------------------------------
     # GCS pushes
@@ -779,7 +836,12 @@ class Raylet:
                             "gcs_rtt_ms": round(self._gcs_rtt_ms, 1),
                             "gcs_errors": self._gcs_call_errors,
                         },
-                        "available": dict(self.resources_available),
+                        # A chip hold can take free TPU below zero
+                        # for the seconds an owner takes to exit.
+                        "available": {
+                            k: max(0.0, v)
+                            for k, v in self.resources_available.items()
+                        },
                         "total": dict(self.resources_total),
                         "has_pending": bool(self.queue or self.infeasible),
                         # Per-tenant resources held here (leases + actor
@@ -925,11 +987,20 @@ class Raylet:
         job_id: JobID,
         actor_id: Optional[ActorID] = None,
         runtime_env: Optional[dict] = None,
+        chips: float = 0.0,
     ) -> WorkerHandle:
+        """`chips` is the TPU share of the task, lease or actor this
+        process is spawned for.  A process spawned for none is held to
+        the CPU backend here, before it can import JAX: a chip belongs to
+        one process at a time, and a worker that initialised every
+        backend by default would take it from the one whose lease
+        carries it."""
         worker_id = WorkerID.from_random()
         from ray_tpu._private.node import child_env
 
         env = child_env()
+        if not chips:
+            env["JAX_PLATFORMS"] = "cpu"
         env["RAY_TPU_RAYLET_ADDRESS"] = self.address
         env["RAY_TPU_NODE_ID"] = self.node_id.hex()
         env["RAY_TPU_WORKER_ID"] = worker_id.hex()
@@ -971,6 +1042,7 @@ class Raylet:
         w.env_hash = runtime_env_mod.env_hash(runtime_env)
         w.log_path = log_path
         w.tenant = tenants_mod.normalize_tenant(job_tenant)
+        w.chips = chips
         self.workers[worker_id] = w
         return w
 
@@ -1007,7 +1079,7 @@ class Raylet:
         self._kick_spawn_gate()  # one STARTING slot just freed
         conn.meta["worker_id"] = worker_id
         if w.actor_id is None and not w.reserved:
-            self.idle_workers[(w.job_id, w.env_hash)].append(w)
+            self.idle_workers[w.pool_key].append(w)
         self._schedule_dispatch()
         return {"ok": True, "job_config": self.job_configs.get(w.job_id, {})}
 
@@ -1031,7 +1103,9 @@ class Raylet:
         tasks can run (reference: CoreWorker NotifyDirectCallTaskBlocked)."""
         worker_id = conn.meta.get("worker_id")
         w = self.workers.get(worker_id) if worker_id else None
-        if w is None:
+        if w is None or w.chips:
+            # A chip owner keeps its lease while blocked: the chip stays
+            # with its process, so the TPU must not look free.
             return
         if w.state == "LEASED":
             # A leased worker blocked in ray.get: release the lease's
@@ -1051,7 +1125,7 @@ class Raylet:
     async def push_task_unblocked(self, payload, conn):
         worker_id = conn.meta.get("worker_id")
         w = self.workers.get(worker_id) if worker_id else None
-        if w is None:
+        if w is None or w.chips:
             return
         if w.state == "LEASED":
             if w.lease_blocked:
@@ -1092,6 +1166,8 @@ class Raylet:
             if w in dq:
                 dq.remove(w)
         self._release_resources(w)
+        # The connection can close before the process is gone.
+        self._hold_chips_until_exit(w)
         # Fail or retry the tasks it was running.
         for task_bytes, spec in list(w.running.items()):
             self._handle_failed_execution(spec, "worker process died")
@@ -1475,7 +1551,8 @@ class Raylet:
             if not self._try_acquire(spec):
                 remaining.append(spec)
                 continue
-            w = self._pop_idle_worker(spec.job_id, eh)
+            chips = self._task_resources(spec).get("TPU", 0.0)
+            w = self._pop_idle_worker(spec.job_id, eh, chips)
             if w is None:
                 self._release_task_resources(spec)
                 remaining.append(spec)
@@ -1485,14 +1562,19 @@ class Raylet:
                 # exclude_reserved: a STARTING worker claimed by a lease
                 # request will be LEASED on registration and never serve
                 # this queue — it must not suppress the spawn.
-                if not self._worker_starting_for(spec.job_id, eh, exclude_reserved=True):
-                    self._spawn_worker(spec.job_id, runtime_env=spec.runtime_env)
+                if not self._worker_starting_for(
+                    spec.job_id, eh, chips, exclude_reserved=True
+                ):
+                    self._spawn_worker(
+                        spec.job_id, runtime_env=spec.runtime_env, chips=chips
+                    )
                 continue
             self._push_task_to_worker(w, spec)
         self.queue = remaining
 
     def _worker_starting_for(
-        self, job_id: JobID, env_hash: str, exclude_reserved: bool = False
+        self, job_id: JobID, env_hash: str, chips: float = 0.0,
+        exclude_reserved: bool = False,
     ) -> Optional["WorkerHandle"]:
         """The single STARTING-worker-matching predicate shared by the
         dispatch loop (spawn suppression) and the lease path (reuse).
@@ -1501,8 +1583,7 @@ class Raylet:
             if (
                 w.state == "STARTING"
                 and w.actor_id is None  # dedicated actor workers don't count
-                and w.job_id == job_id
-                and w.env_hash == env_hash
+                and w.pool_key == (job_id, env_hash, chips)
                 and not (exclude_reserved and w.reserved)
             ):
                 return w
@@ -1514,8 +1595,10 @@ class Raylet:
             return bk in self.bundles
         return self._task_resources(spec).fits_in(self.resources_total)
 
-    def _pop_idle_worker(self, job_id: JobID, env_hash: str = "") -> Optional[WorkerHandle]:
-        dq = self.idle_workers.get((job_id, env_hash))
+    def _pop_idle_worker(
+        self, job_id: JobID, env_hash: str = "", chips: float = 0.0
+    ) -> Optional[WorkerHandle]:
+        dq = self.idle_workers.get((job_id, env_hash, chips))
         while dq:
             w = dq.popleft()
             if w.state == "IDLE" and w.conn is not None and not w.conn.closed:
@@ -1568,11 +1651,20 @@ class Raylet:
             self._release_task_resources(spec)
             w.resources_held.subtract(self._task_resources(spec))
         if w.actor_id is None and w.state != "DEAD":
-            w.state = "IDLE"
-            w.idle_since = time.monotonic()
-            self.idle_workers[(w.job_id, w.env_hash)].append(w)
+            self._retire_or_pool(w)
         self._schedule_dispatch()
         return True
+
+    def _retire_or_pool(self, w: WorkerHandle):
+        """A worker's task or lease has ended.  A CPU worker goes back
+        to its idle pool; a chip owner exits, because only its exit
+        gives the chip back (_hold_chips_until_exit)."""
+        if w.chips:
+            self._kill_worker_proc(w)
+            return
+        w.state = "IDLE"
+        w.idle_since = time.monotonic()
+        self.idle_workers[w.pool_key].append(w)
 
     # ------------------------------------------------------------------
     # multi-tenant accounting (tenants.py holds the DRF/quota math)
@@ -1903,17 +1995,18 @@ class Raylet:
         granted = False
         try:
             # Find or spawn a worker with a direct endpoint.
-            w = self._pop_idle_worker_for_lease(job_id, lease_env_hash)
+            chips = res.get("TPU", 0.0)
+            w = self._pop_idle_worker_for_lease(job_id, lease_env_hash, chips)
             if w is None:
                 # Reuse a worker already STARTING for this (job, env) —
                 # during slow runtime_env staging (pip install) each ~30s
                 # lease retry would otherwise spawn another duplicate that
                 # just queues behind the same staging flock.
                 w = self._worker_starting_for(
-                    job_id, lease_env_hash, exclude_reserved=True
+                    job_id, lease_env_hash, chips, exclude_reserved=True
                 )
             if w is None:
-                w = self._spawn_worker(job_id, runtime_env=lease_env)
+                w = self._spawn_worker(job_id, runtime_env=lease_env, chips=chips)
             w.reserved = True  # keep dispatch + concurrent grants off it
             try:
                 ok = await self._wait_worker_ready(w, deadline)
@@ -1924,10 +2017,10 @@ class Raylet:
                 if bad is not None:
                     return {"runtime_env_error": bad[0]}
             if not ok or conn.closed:
-                if ok:  # requester vanished: put the worker back
+                if ok:  # requester vanished: put the (unused) worker back
                     w.state = "IDLE"
                     w.idle_since = time.monotonic()
-                    self.idle_workers[(w.job_id, w.env_hash)].append(w)
+                    self.idle_workers[w.pool_key].append(w)
                 return None
             w.state = "LEASED"
             w.resources_held = res.copy()
@@ -1958,9 +2051,9 @@ class Raylet:
         return best
 
     def _pop_idle_worker_for_lease(
-        self, job_id: JobID, env_hash: str = ""
+        self, job_id: JobID, env_hash: str = "", chips: float = 0.0
     ) -> Optional["WorkerHandle"]:
-        dq = self.idle_workers.get((job_id, env_hash))
+        dq = self.idle_workers.get((job_id, env_hash, chips))
         found = None
         rejected = []
         while dq:
@@ -2079,9 +2172,7 @@ class Raylet:
             return
         w.lease_owner = None
         self._release_resources(w)  # handles the lease_blocked case itself
-        w.state = "IDLE"
-        w.idle_since = time.monotonic()
-        self.idle_workers[(w.job_id, w.env_hash)].append(w)
+        self._retire_or_pool(w)
         self._grant_lease_waiters()
         self._schedule_dispatch()
 
@@ -2156,7 +2247,8 @@ class Raylet:
                     raise RuntimeError("insufficient resources for actor")
                 self.resources_available.subtract(res)
             w = self._spawn_worker(
-                spec.job_id, actor_id=spec.actor_id, runtime_env=spec.runtime_env
+                spec.job_id, actor_id=spec.actor_id, runtime_env=spec.runtime_env,
+                chips=res.get("TPU", 0.0),
             )
         except BaseException:
             from ray_tpu._private.spawn_gate import HostSpawnGate
@@ -2265,6 +2357,20 @@ class Raylet:
         key = (payload["pg_id"], payload["bundle_index"])
         b = self.bundles.pop(key, None)
         if b is not None:
+            # A chip owner cannot keep the chip without the reservation
+            # it was placed in: it ends with the bundle, and its hold
+            # keeps the chip debited until the process is gone.  An
+            # actor goes the way ray.kill takes it, so that the GCS
+            # hears of its death.
+            for w in [
+                w for w in self.workers.values()
+                if w.chips and w.bundle_key == key
+            ]:
+                self._hold_chips_until_exit(w)
+                if w.actor_id is not None:
+                    self._kill_actor_local(w.actor_id, intended=True)
+                else:
+                    self._kill_worker_proc(w)
             self.resources_available.add(b["reserved"])
         self._schedule_dispatch()
         return True

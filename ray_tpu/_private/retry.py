@@ -180,10 +180,6 @@ STREAM_POLL = RetryPolicy(base_s=0.01, cap_s=0.1, name="stream_poll")
 # Raylet object-manager pull probes against a not-yet-sealed object.
 PULL_PROBE = RetryPolicy(base_s=0.05, cap_s=1.0, name="pull_probe")
 
-# bench.py chip probe: attempts are whole subprocesses, so delays are
-# coarse.
-BENCH_PROBE = RetryPolicy(base_s=1.0, cap_s=15.0, name="bench_probe")
-
 # Idempotent GCS reads (kv_get, object locations) whose reply was lost in
 # flight: re-asking has no side effects, so a CallTimeout gets a bounded
 # retry instead of failing the caller (see rpc.call_idempotent).  Callers

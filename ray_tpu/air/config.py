@@ -9,6 +9,19 @@ import os
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
+from ray_tpu.exceptions import TPUPlacementError
+
+
+def _tpu_hosts() -> List[float]:
+    """TPU chip counts of the live nodes that advertise any."""
+    import ray_tpu
+
+    return [
+        n["Resources"]["TPU"]
+        for n in ray_tpu.nodes()
+        if n["Alive"] and n["Resources"].get("TPU")
+    ]
+
 
 @dataclass
 class ScalingConfig:
@@ -50,14 +63,33 @@ class ScalingConfig:
         if self.resources_per_worker is not None:
             return dict(self.resources_per_worker)
         if self.use_tpu:
-            try:
-                from ray_tpu._private.accelerators.tpu import TPUAcceleratorManager
-
-                chips = TPUAcceleratorManager.get_current_node_num_accelerators() or 4
-            except Exception:
-                chips = 4
-            return {"TPU": float(chips)}
+            # One worker per TPU host, holding every chip the host
+            # advertises: detected by its raylet or given to init(),
+            # never assumed here.
+            hosts = _tpu_hosts()
+            if not hosts:
+                raise TPUPlacementError(
+                    "ScalingConfig(use_tpu=True), but no node of this cluster "
+                    "advertises a TPU resource (ray_tpu.cluster_resources())"
+                )
+            return {"TPU": float(min(hosts))}
         return {"CPU": 1.0}
+
+    def check_tpu_placement(self) -> None:
+        """Refuse a TPU worker group that would put two worker processes
+        on one host's chips, before any placement group waits for it."""
+        if not self._worker_resources().get("TPU"):
+            return
+        hosts = _tpu_hosts()
+        if self.num_workers > len(hosts):
+            raise TPUPlacementError(
+                f"{self.num_workers} TPU training workers asked for, but the "
+                f"cluster has {len(hosts)} TPU host(s).  A chip belongs to "
+                "one process and nothing assigns chip ids to processes that "
+                "share a host, so each TPU worker must own all chips of its "
+                "own host: use num_workers=1 per host and shard over its "
+                "chips inside the worker (ShardingConfig)."
+            )
 
     def as_placement_group_factory(self):
         from ray_tpu.util.placement_group import placement_group
@@ -71,10 +103,6 @@ class ScalingConfig:
             return placement_group(bundles, strategy=strategy)
 
         return factory
-
-    @property
-    def num_chips_per_worker(self) -> float:
-        return self._worker_resources().get("TPU", 0.0)
 
 
 @dataclass

@@ -198,6 +198,14 @@ class PlacementGroupSchedulingError(RayError):
     pass
 
 
+class TPUPlacementError(RayError):
+    """A TPU request that the cluster's chips cannot serve as asked: no
+    node advertises a TPU, or several TPU worker processes would share
+    one host.  A chip belongs to one process, and nothing assigns chip
+    ids to processes yet, so each TPU worker owns every chip of its own
+    host (ROADMAP.md Queue 1 item 8)."""
+
+
 class QuotaExceededError(RayError):
     """A tenant is over its registered resource quota AND its parked
     admission queue is full (tenant_max_parked) — the backpressure

@@ -68,18 +68,26 @@ def make_sharded_train_step(step_fn: Callable, mesh):
     data_sharding = NamedSharding(mesh, batch_spec(mesh))
     from ray_tpu._private import profiling
 
-    jitted = profiling.instrument_jit(
-        "train_step", jax.jit(step_fn, donate_argnums=(0, 1))
-    )
+    jit_fn = jax.jit(step_fn, donate_argnums=(0, 1))
+    jitted = profiling.instrument_jit("train_step", jit_fn)
 
+    # Traced under the mesh, so that code which cannot be partitioned
+    # automatically (ops.attention's Pallas kernel) can see it.
     def run(params, opt_state, tokens, targets):
         tokens = jax.device_put(tokens, data_sharding)
         targets = jax.device_put(targets, data_sharding)
-        out = jitted(params, opt_state, tokens, targets)
+        with jax.set_mesh(mesh):
+            out = jitted(params, opt_state, tokens, targets)
         profiling.report_device_memory()
         return out
 
+    def lower(*args):
+        with jax.set_mesh(mesh):
+            return jit_fn.lower(*args)
+
     run.data_sharding = data_sharding
+    # the step's own lowering, for checks on what was compiled
+    run.lower = lower
     return run
 
 

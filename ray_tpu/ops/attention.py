@@ -6,7 +6,8 @@ placement —
 - sequence sharded over an "sp" mesh axis → ring attention
   (ops.ring_attention, shard_map + ppermute over the ICI ring);
 - single-device / GSPMD-sharded → Pallas flash kernel on TPU when shapes
-  allow (ops.pallas_attention), else the XLA einsum reference (which XLA
+  allow (ops.pallas_attention; under a mesh, per shard of batch and
+  heads inside a shard_map), else the XLA einsum reference (which XLA
   fuses well on its own).
 
 All paths: f32 accumulation, bf16 in/out, static shapes.
@@ -44,10 +45,54 @@ def causal_attention(q, k, v, *, mesh=None, sp_axis: Optional[str] = None):
 
         return ring_causal_attention(q, k, v, mesh=mesh, axis=sp_axis)
     if _use_pallas(q):
-        from ray_tpu.ops.pallas_attention import flash_attention
-
-        return flash_attention(q, k, v, causal=True)
+        return _flash_over_mesh(q, k, v)
     return reference_causal_attention(q, k, v)
+
+
+# Mesh axes over which the two rule tables shard attention heads
+# (train/sharding/rules.py "model", parallel/sharding.py "tp").  Every
+# other axis of a mesh carries batch.
+_HEAD_AXES = ("model", "tp")
+
+
+def _flash_over_mesh(q, k, v):
+    """The flash kernel under the mesh this step is traced in.
+
+    A Mosaic kernel cannot be partitioned automatically: a multi-device
+    jit that reaches it bare fails to lower.  Attention is independent
+    across batch and heads, so under an ambient mesh
+    (``jax.set_mesh``, which the sharded train steps enter) the kernel
+    runs inside a shard_map, each device on its own shard of B and H
+    with T and D whole.  The axis names only decide which dim a mesh
+    axis splits; whatever layout the operands arrive in, the partitioner
+    reshards to the specs given here, so a wrong guess costs a transfer,
+    never a result.  Axes that do not divide their dim stay unsplit."""
+    from jax.sharding import PartitionSpec as P
+
+    from ray_tpu.ops.pallas_attention import flash_attention
+
+    mesh = jax.sharding.get_abstract_mesh()
+    free = [a for a in mesh.axis_names if a not in mesh.manual_axes and mesh.shape[a] > 1]
+    if not free:
+        return flash_attention(q, k, v, causal=True)
+    B, T, H, D = q.shape
+
+    def dividing(axes, n):
+        kept, size = [], 1
+        for a in axes:
+            if n % (size * mesh.shape[a]) == 0:
+                kept.append(a)
+                size *= mesh.shape[a]
+        return tuple(kept) or None
+
+    spec = P(
+        dividing([a for a in free if a not in _HEAD_AXES], B), None,
+        dividing([a for a in free if a in _HEAD_AXES], H), None,
+    )
+    return jax.shard_map(
+        lambda q, k, v: flash_attention(q, k, v, causal=True),
+        mesh=mesh, in_specs=(spec,) * 3, out_specs=spec, check_vma=False,
+    )(q, k, v)
 
 
 def _use_pallas(q) -> bool:
@@ -55,10 +100,9 @@ def _use_pallas(q) -> bool:
 
     if os.environ.get("RAY_TPU_DISABLE_PALLAS"):
         return False
-    try:
-        if jax.default_backend() != "tpu":
-            return False
-    except Exception:
+    # The CPU tests take the einsum path; a backend that fails to
+    # initialise raises here rather than quietly dropping the kernel.
+    if jax.default_backend() != "tpu":
         return False
     B, T, H, D = q.shape
     # Tuned for the MXU: D a multiple of 64 (64/128 head dims), T a

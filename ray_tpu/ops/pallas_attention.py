@@ -25,27 +25,16 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    HAVE_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
 
 def _compiler_params():
-    sem = ("parallel", "parallel", "arbitrary")
-    for name in ("CompilerParams", "TPUCompilerParams"):
-        cls = getattr(pltpu, name, None)
-        if cls is not None:
-            try:
-                return cls(dimension_semantics=sem)
-            except TypeError:
-                pass
-    return dict(mosaic=dict(dimension_semantics=sem))
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary")
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -313,10 +302,6 @@ _flash.defvjp(_fwd, _bwd)
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 1024, block_k: int = 1024,
                     interpret: bool = False):
     """[B, T, H, D] flash attention (differentiable, Pallas fwd+bwd)."""
-    if not HAVE_PALLAS:
-        from ray_tpu.ops.attention import reference_causal_attention
-
-        return reference_causal_attention(q, k, v)
     B, T, H, D = q.shape
     # Shrink blocks to the largest power-of-two divisor of T at or under
     # the requested size, so any T that is a multiple of 128 works with

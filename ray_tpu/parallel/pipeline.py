@@ -98,16 +98,14 @@ def pipeline_spmd(
         )
         return outputs
 
-    from jax.experimental.shard_map import shard_map
-
     # stage params: sharded over pp on the leading dim; microbatches
     # replicated across pp (other axes handled by the caller's shardings).
-    return shard_map(
+    return jax.shard_map(
         run,
         mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -223,14 +221,12 @@ def pipeline_interleaved(
         )
         return outputs
 
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         run,
         mesh=mesh,
         in_specs=(P(), P(axis), P(), P(), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
 
 

@@ -5,7 +5,7 @@ Learning" — the sebulba actor/learner split; RLAX demonstrates the same
 streaming-into-a-sharded-learner shape at LLM scale).
 
 The synchronous plane pays one actor RPC round-trip per rollout
-(`sample() → get() → update()` — BENCH_rllib: 80.9% of pong_scale wall
+(`sample() → get() → update()` — PERF_ANALYSIS.md "RLlib PPO": 80.9% of pong_scale wall
 time in learner-update+overhead while runners idle).  Here neither side
 ever waits on the other:
 

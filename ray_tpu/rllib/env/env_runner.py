@@ -53,24 +53,27 @@ class SingleAgentEnvRunner:
         self.compute_advantages = compute_advantages
         self.worker_index = worker_index
         self.module = module_spec.build()
-        self._rng = jax.random.PRNGKey(seed * 100003 + worker_index)
         self.params = None
         # Env runners default to CPU inference: per-step policy calls are
         # latency-bound (one small batch per vector-env step), and the
         # TPU belongs to the learner — shipping every step's obs over the
         # device link would serialize rollouts on RTT (the reference's
         # architecture is the same: env runners are CPU actors).
-        self._device = None
-        if inference_backend:
-            try:
-                self._device = jax.local_devices(backend=inference_backend)[0]
-            except RuntimeError:
-                self._device = None  # backend absent: follow the default
-        if self._device is not None:
-            # The per-step rng split must live on the inference device
-            # too, or every env step pays a dispatch to the default
-            # (possibly remote) accelerator just to split a key.
-            self._rng = jax.device_put(self._rng, self._device)
+        # Pinned before this runner's first JAX computation; a backend
+        # that was asked for and is absent raises.  As a remote actor the
+        # runner holds no TPU lease, so the raylet has already held its
+        # process to the CPU (raylet._spawn_worker); inline in the
+        # driver, the learner's chip stays the default device and this
+        # keeps the runner off it.
+        self._device = (
+            jax.local_devices(backend=inference_backend)[0]
+            if inference_backend else None
+        )
+        # The per-step rng split lives on the inference device too, or
+        # every env step pays a dispatch to the default accelerator just
+        # to split a key.
+        with jax.default_device(self._device):
+            self._rng = jax.random.PRNGKey(seed * 100003 + worker_index)
         # connector pipelines (reference: env_to_module / module_to_env
         # insertion points in single_agent_env_runner.sample)
         self.env_to_module = env_to_module

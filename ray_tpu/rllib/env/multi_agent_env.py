@@ -90,15 +90,14 @@ class MultiAgentEnvRunner:
         self.worker_index = worker_index
         self.modules = {pid: spec.build() for pid, spec in module_specs.items()}
         self.params: Dict[str, Any] = {}
-        self._device = None
-        if inference_backend:
-            try:
-                self._device = jax.local_devices(backend=inference_backend)[0]
-            except RuntimeError:
-                self._device = None
-        self._rng = jax.random.PRNGKey(seed * 100003 + worker_index)
-        if self._device is not None:
-            self._rng = jax.device_put(self._rng, self._device)
+        # Same pinning rule as SingleAgentEnvRunner: resolved before the
+        # first JAX computation, and an absent backend raises.
+        self._device = (
+            jax.local_devices(backend=inference_backend)[0]
+            if inference_backend else None
+        )
+        with jax.default_device(self._device):
+            self._rng = jax.random.PRNGKey(seed * 100003 + worker_index)
         self._explore_fns = {
             pid: jax.jit(m.forward_exploration) for pid, m in self.modules.items()
         }

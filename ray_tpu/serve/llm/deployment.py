@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import asyncio
 import dataclasses
+import os
 import uuid
 import zlib
 from typing import Any, AsyncIterator, Dict, List, Optional
@@ -195,6 +196,7 @@ class LLMServer:
 
     def stats(self) -> Dict[str, Any]:
         out = self.engine.stats()
+        out["pid"] = os.getpid()
         out["multiplex"] = {
             "loaded_model_ids": [v.model_id for v in self._loaded_variants()],
             "evictions": self._mx_evictions,
@@ -357,11 +359,22 @@ def build_app(
     engine's ``max_queue`` and the proxy's ``max_queued_requests``).
     The LLM config's ``tenant_quotas`` flow onto the deployment so the
     route table carries them to the proxy's token-bucket admission."""
+    import ray_tpu
     from ray_tpu import serve
 
     cfg = LLMConfig.coerce(llm_config)
+    # On a cluster with TPUs each replica holds one chip as a lease: the
+    # engine runs on one device, a chip belongs to one process, and the
+    # scheduler then leaves a replica the chips cannot hold pending
+    # instead of starting it on a chip that is taken.  Where no node
+    # advertises a TPU the engine runs on the CPU backend and asks for
+    # none.
+    if not ray_tpu.is_initialized():
+        ray_tpu.init()  # as serve.start() would
+    on_tpu = ray_tpu.cluster_resources().get("TPU")
     dep = serve.deployment(
         name=cfg.name,
+        ray_actor_options={"num_tpus": 1} if on_tpu else None,
         num_replicas=num_replicas,
         max_ongoing_requests=max_ongoing_requests,
         max_queued_requests=max_queued_requests,

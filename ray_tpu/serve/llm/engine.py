@@ -41,6 +41,7 @@ from __future__ import annotations
 
 import asyncio
 import collections
+import functools
 import logging
 import time
 import uuid
@@ -93,6 +94,35 @@ class _Request:
     preemptions: int = 0
     folded: int = 0  # tokens already folded into prompt by past preemptions
     t_enqueue: float = 0.0  # last (re)queue time — the starvation clock
+
+
+def prefill_step(cfg, top_k, params, k_pages, v_pages, tokens, phys, last_idx, temp, rng):
+    """One prompt into the paged cache.  tokens [1, Tpad]; phys [Tpad]
+    (scratch slot 0 at pads); logits taken at the last REAL position,
+    not the pad tail."""
+    from ray_tpu.models import gpt2
+
+    logits, k, v = gpt2.prefill_forward(params, cfg, tokens, last_index=last_idx)
+    k_pages = k_pages.at[:, phys].set(k[:, 0])
+    v_pages = v_pages.at[:, phys].set(v[:, 0])
+    first = gpt2.sample_logits(logits, rng, temp, top_k)
+    return first[0], k_pages, v_pages
+
+
+def decode_step(cfg, top_k, params, k_pages, v_pages, tok, pos, idx, mask, write_phys, temp, rng):
+    """Gather each lane's context pages, advance one token, write the
+    new K/V back at write_phys (inactive lanes hit slot 0)."""
+    from ray_tpu.models import gpt2
+
+    k_ctx = k_pages[:, idx]  # [L, B, C, H, Dh]
+    v_ctx = v_pages[:, idx]
+    logits, k_new, v_new = gpt2.decode_forward(
+        params, cfg, tok, pos, k_ctx, v_ctx, mask
+    )
+    k_pages = k_pages.at[:, write_phys].set(k_new)
+    v_pages = v_pages.at[:, write_phys].set(v_new)
+    nxt = gpt2.sample_logits(logits, rng, temp, top_k)
+    return nxt, k_pages, v_pages
 
 
 class LLMEngine:
@@ -168,31 +198,11 @@ class LLMEngine:
         P = self.bm.num_slots
         self.k_pages = jnp.zeros((L, P, H, d_head), cfg.dtype)
         self.v_pages = jnp.zeros((L, P, H, d_head), cfg.dtype)
+        # where the cache lives, reported by stats(): a replica that was
+        # meant for the chip and runs on the CPU is then visible
+        self._device = next(iter(self.k_pages.devices()))
         self._base_key = jax.random.PRNGKey(self.config.seed + 1)
         top_k = self.config.top_k
-
-        def prefill_step(params, k_pages, v_pages, tokens, phys, last_idx, temp, rng):
-            # tokens [1, Tpad]; phys [Tpad] (scratch slot 0 at pads);
-            # logits taken at the last REAL position, not the pad tail.
-            logits, k, v = gpt2.prefill_forward(params, cfg, tokens, last_index=last_idx)
-            k_pages = k_pages.at[:, phys].set(k[:, 0])
-            v_pages = v_pages.at[:, phys].set(v[:, 0])
-            first = gpt2.sample_logits(logits, rng, temp, top_k)
-            return first[0], k_pages, v_pages
-
-        def decode_step(params, k_pages, v_pages, tok, pos, idx, mask, write_phys, temp, rng):
-            # gather each lane's context pages, advance one token, write
-            # the new K/V back at write_phys (inactive lanes hit slot 0)
-            k_ctx = k_pages[:, idx]  # [L, B, C, H, Dh]
-            v_ctx = v_pages[:, idx]
-            logits, k_new, v_new = gpt2.decode_forward(
-                params, cfg, tok, pos, k_ctx, v_ctx, mask
-            )
-            k_pages = k_pages.at[:, write_phys].set(k_new)
-            v_pages = v_pages.at[:, write_phys].set(v_new)
-            nxt = gpt2.sample_logits(logits, rng, temp, top_k)
-            return nxt, k_pages, v_pages
-
         # XLA introspection on the serving hot path: compile-time/
         # retrace counters (prefill compiles once per prompt bucket —
         # a retrace storm here is a bucketing bug) + first-trace
@@ -200,10 +210,12 @@ class LLMEngine:
         from ray_tpu._private import profiling as _profiling
 
         self._prefill_jit = _profiling.instrument_jit(
-            "serve_prefill", jax.jit(prefill_step, donate_argnums=(1, 2))
+            "serve_prefill",
+            jax.jit(functools.partial(prefill_step, cfg, top_k), donate_argnums=(1, 2)),
         )
         self._decode_jit = _profiling.instrument_jit(
-            "serve_decode", jax.jit(decode_step, donate_argnums=(1, 2))
+            "serve_decode",
+            jax.jit(functools.partial(decode_step, cfg, top_k), donate_argnums=(1, 2)),
         )
 
     def _next_rng(self):
@@ -370,6 +382,8 @@ class LLMEngine:
             "waiting": len(self.waiting),
             "running": running,
             "max_batch_size": self.config.max_batch_size,
+            "platform": self._device.platform,
+            "device_kind": self._device.device_kind,
             "kv_blocks_in_use": self.bm.blocks_in_use,
             "kv_blocks_total": self.bm.num_blocks - 1,
             "kv_leak_report": self.bm.leak_report(),

@@ -112,6 +112,7 @@ class BackendExecutor:
                 )
         except Exception:
             pass
+        self.scaling.check_tpu_placement()
         pg = None
         # Elastic groups lease workers individually: a fixed-size
         # placement group would couple every rank's fate to one atomic

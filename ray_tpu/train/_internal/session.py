@@ -250,6 +250,13 @@ class _TrainSession:
         except queue.Empty:
             return {"kind": "pending"}
         if kind == FINISHED:
+            # The last report's checkpoint may still be with the async
+            # writer (the loop thread is done, so nothing submits any
+            # more): land it, and raise a failed write, before the driver
+            # hears FINISHED, tears the group down and hands that
+            # directory out as the run's result.
+            if self._ckpt_writer is not None:
+                self._ckpt_writer.wait()
             return {"kind": "finished"}
         if kind == ERRORED:
             return {"kind": "error", "traceback": metrics["traceback"]}

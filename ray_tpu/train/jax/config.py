@@ -27,9 +27,6 @@ logger = logging.getLogger(__name__)
 class JaxConfig(BackendConfig):
     # None = auto: distributed init iff more than one worker.
     distributed: Optional[bool] = None
-    # Restrict each worker to its own chips (TPU_VISIBLE_CHIPS); default
-    # leaves all host chips visible to the single worker on that host.
-    chips_per_worker: Optional[int] = None
 
     def backend_cls(self):
         return _JaxBackend

@@ -129,22 +129,30 @@ class GspmdPlan:
         param_sh = jax.tree_util.tree_map(lambda x: x.sharding, params)
         opt_sh = jax.tree_util.tree_map(lambda x: x.sharding, opt_state)
         data_sh = self.data_sharding()
-        jitted = profiling.instrument_jit(
-            "gspmd_train_step",
-            jax.jit(
-                step_fn,
-                in_shardings=(param_sh, opt_sh, data_sh, data_sh),
-                out_shardings=(param_sh, opt_sh, self.replicated()),
-                donate_argnums=(0, 1),
-            ),
+        jit_fn = jax.jit(
+            step_fn,
+            in_shardings=(param_sh, opt_sh, data_sh, data_sh),
+            out_shardings=(param_sh, opt_sh, self.replicated()),
+            donate_argnums=(0, 1),
         )
+        jitted = profiling.instrument_jit("gspmd_train_step", jit_fn)
 
+        # Traced under the mesh, so that code which cannot be
+        # partitioned automatically (ops.attention's Pallas kernel) can
+        # see it.
         def run(params, opt_state, tokens, targets):
             tokens = jax.device_put(tokens, data_sh)
             targets = jax.device_put(targets, data_sh)
-            return jitted(params, opt_state, tokens, targets)
+            with jax.set_mesh(self.mesh):
+                return jitted(params, opt_state, tokens, targets)
+
+        def lower(*args):
+            with jax.set_mesh(self.mesh):
+                return jit_fn.lower(*args)
 
         run.data_sharding = data_sh
+        # the step's own lowering, for checks on what was compiled
+        run.lower = lower
         return run
 
     # -- checkpoint -----------------------------------------------------

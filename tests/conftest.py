@@ -19,65 +19,6 @@ except ImportError:
 
 import pytest  # noqa: E402
 
-# ----------------------------------------------------------------------
-# Environment-limited tier-1 guards (ROADMAP "known environment-limited
-# failures", promoted here as capability-probed xfails).  strict=False:
-# an environment that CAN run them reports XPASS, never a failure — the
-# guards only reclassify, they can't hide a recovery.  Tier-1 output
-# thus separates "env-limited" (x) from real regressions (F).
-# ----------------------------------------------------------------------
-
-
-def _jax_capabilities():
-    caps = {"shard_map": False, "multiprocess_backend": False}
-    try:
-        import jax
-    except ImportError:
-        return caps
-    # jax.shard_map moved to the top level in later jax; models/ops/
-    # pipeline code uses the top-level spelling.
-    caps["shard_map"] = hasattr(jax, "shard_map")
-    # Multi-process computations (jax.distributed across actor processes)
-    # are not implemented by the CPU PJRT backend this suite pins
-    # (JAX_PLATFORMS=cpu): "Multiprocess computations aren't implemented
-    # on the CPU backend".  A non-cpu backend would support them.
-    caps["multiprocess_backend"] = jax.default_backend() != "cpu"
-    return caps
-
-
-# nodeid substring -> capability key whose absence xfails it
-_ENV_LIMITED = {
-    "test_models.py::test_gpt2_sharded_train_step_dp_tp_sp": "shard_map",
-    "test_ops.py::test_ring_attention_matches_reference": "shard_map",
-    "test_ops.py::test_ring_attention_composes_with_dp": "shard_map",
-    "test_pipeline.py::test_gpt2_pp_interleaved_matches_unpipelined": "shard_map",
-    "test_sharded_train.py::test_jax_trainer_carries_sharding_config": "multiprocess_backend",
-    "test_train.py::test_jax_trainer_distributed_mlp": "multiprocess_backend",
-    "test_train.py::test_jax_trainer_resume_from_checkpoint": "multiprocess_backend",
-    "test_train.py::test_trainer_restore_from_experiment_dir": "multiprocess_backend",
-    "test_train.py::test_jax_trainer_sharded_gpt2_streaming_split": "multiprocess_backend",
-    "test_train.py::test_typed_restore_sharded_gpt2_with_closure_loop": "multiprocess_backend",
-}
-
-_CAP_REASON = {
-    "shard_map": "env-limited: this jax has no jax.shard_map",
-    "multiprocess_backend": (
-        "env-limited: multiprocess computations aren't implemented on "
-        "the CPU jax backend this suite pins"
-    ),
-}
-
-
-def pytest_collection_modifyitems(config, items):
-    caps = _jax_capabilities()
-    for item in items:
-        for pattern, cap in _ENV_LIMITED.items():
-            if pattern in item.nodeid and not caps[cap]:
-                item.add_marker(
-                    pytest.mark.xfail(strict=False, reason=_CAP_REASON[cap])
-                )
-                break
-
 
 @pytest.fixture(scope="module")
 def ray_cluster():

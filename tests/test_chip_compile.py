@@ -1,0 +1,135 @@
+"""Compiles for a described v5e chip, without the chip.
+
+The TPU's compiler is installed where the tests run, and compiles for a
+device that is described and not attached: what it refuses here (a slice
+off the tiling, too much fast memory, a program over HBM) it would
+refuse on the chip.  These guard the kernels and steps of the main path
+at real widths.  Nothing runs: no result or time comes from here.
+
+All in this one file, compiled in the test's own process, topology
+described inside a fixture: only one process may load the TPU library,
+and the xdist worker that is given this file is the one that does.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps the library from loading
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    """A compile for a described device is written to the persistent
+    cache but cannot be read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+# GPT-2-medium's attention shape in chip_smoke.py's train phase
+FLASH_SHAPE = (8, 1024, 16, 64)
+
+
+def _qkv(sharding):
+    return [jax.ShapeDtypeStruct(FLASH_SHAPE, jnp.bfloat16, sharding=sharding)] * 3
+
+
+def test_flash_forward_compiles_for_v5e(one_chip):
+    from ray_tpu.ops.pallas_attention import flash_attention
+
+    compiled = jax.jit(flash_attention).lower(*_qkv(one_chip)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+def test_flash_forward_backward_compiles_for_v5e(one_chip):
+    from ray_tpu.ops.pallas_attention import flash_attention
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*_qkv(one_chip)).compile()
+    # forward, dq and dk/dv kernels
+    assert compiled.as_text().count("tpu_custom_call") == 3
+
+
+def test_flash_under_a_mesh_compiles_for_v5e(topo):
+    """Under a batch x model mesh (the GSPMD plane's layout) the kernel
+    runs per shard of B and H inside a shard_map: a Mosaic call that
+    reaches a multi-device jit bare fails to lower."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from ray_tpu.ops.attention import _flash_over_mesh
+
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("batch", "model"))
+    sharding = NamedSharding(mesh, P("batch", None, "model", None))
+
+    def loss(q, k, v):
+        return _flash_over_mesh(q, k, v).astype(jnp.float32).sum()
+
+    with jax.set_mesh(mesh):
+        compiled = jax.jit(
+            jax.grad(loss, argnums=(0, 1, 2)), out_shardings=(sharding,) * 3
+        ).lower(*_qkv(sharding)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    # each device works on its own [4, 1024, 8, 64] shard: q/k/v never gather
+    assert "all-gather" not in text
+
+
+def test_engine_decode_step_compiles_for_v5e(one_chip):
+    """The serving engine's decode step (page gather -> one token ->
+    scatter) at GPT-2-small width, depth cut to two layers so that the
+    compile takes seconds; chip_smoke.py's rehearsal covers medium."""
+    import dataclasses
+
+    from ray_tpu.models import gpt2
+    from ray_tpu.serve.llm.engine import decode_step
+
+    cfg = dataclasses.replace(gpt2.GPT2Config.small(dtype=jnp.bfloat16), n_layer=2)
+    B, C, slots = 8, cfg.max_seq_len, 8 * 1024 + 16
+    heads, d_head = cfg.n_head, cfg.d_model // cfg.n_head
+
+    def shaped(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree
+        )
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = shaped(jax.eval_shape(lambda: gpt2.init_params(cfg)))
+    pages = arr((cfg.n_layer, slots, heads, d_head), cfg.dtype)
+    key = shaped(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    compiled = jax.jit(
+        lambda *a: decode_step(cfg, 0, *a), donate_argnums=(1, 2)
+    ).lower(
+        params, pages, pages, arr((B,), jnp.int32), arr((B,), jnp.int32),
+        arr((B, C), jnp.int32), arr((B, C), jnp.bool_), arr((B,), jnp.int32),
+        arr((B,), jnp.float32), key,
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 * 2**30

@@ -8,9 +8,7 @@ import os
 import numpy as np
 import pytest
 
-from ray_tpu._native.arena import NativeArena, load_library
-
-pytestmark = pytest.mark.skipif(load_library() is None, reason="no C++ toolchain")
+from ray_tpu._native.arena import NativeArena
 
 
 @pytest.fixture

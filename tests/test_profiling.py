@@ -561,7 +561,7 @@ def test_bench_gate_skips_error_records():
     lineage = [
         {"round": 1, "parsed": {"metric": "m"}, "metric": "m", "value": 100.0,
          "on_tpu": False},
-        {"round": 2, "parsed": {"metric": "m", "error": "tunnel wedged"},
+        {"round": 2, "parsed": {"metric": "m", "error": "backend hung"},
          "metric": "m", "value": 0.0, "on_tpu": False},
     ]
     result = gate.check_lineage(lineage)
