@@ -21,7 +21,8 @@ chips held by one worker, against the one-device layout.
 
 This process never imports JAX: a parent that touched JAX would hold the
 chip its workers need.  Device facts come back from the worker that holds
-the lease.  Any phase that fails makes the exit code non-zero; the last
+the lease.  Any phase that fails makes the exit code non-zero, and so does
+a process of the cluster that is still there after shutdown; the last
 line of stdout is the result object and is printed only when every phase
 passed on a TPU.
 
@@ -77,7 +78,23 @@ def require(cond, msg):
         raise SmokeFailure(msg)
 
 
+_PIDS = set()  # every process of the cluster that a phase reported
+
+
+def _pids_in(facts):
+    if isinstance(facts, dict):
+        for key, value in facts.items():
+            if key == "pid":
+                yield value
+            else:
+                yield from _pids_in(value)
+    elif isinstance(facts, (list, tuple)):
+        for value in facts:
+            yield from _pids_in(value)
+
+
 def say(phase, **facts):
+    _PIDS.update(_pids_in(facts))  # for the check after shutdown
     print(f"[{phase}] " + json.dumps(facts, sort_keys=True, default=str), flush=True)
 
 
@@ -411,30 +428,23 @@ def _make_requests(config, seed, vocab_size):
 def _session_processes():
     """Every process of this session with its JAX_PLATFORMS and whether
     libtpu is mapped (i.e. it initialised the TPU backend)."""
+    from ray_tpu._private.node import session_pids
     from ray_tpu._private.worker import get_global_worker
 
-    session = get_global_worker().session_info.get("session_dir") or ""
     out = []
-    for pid in (p for p in os.listdir("/proc") if p.isdigit()):
+    for pid in session_pids(get_global_worker().session_info["session_dir"]):
         try:
             with open(f"/proc/{pid}/cmdline", "rb") as f:
-                cmd = f.read().replace(b"\0", b" ").decode(errors="replace")
+                head = b"head_main" in f.read()
             with open(f"/proc/{pid}/environ", "rb") as f:
-                env = dict(
-                    kv.split("=", 1) for kv in f.read().decode(errors="replace").split("\0")
-                    if "=" in kv
-                )
+                env = dict(kv.split(b"=", 1) for kv in f.read().split(b"\0") if b"=" in kv)
             with open(f"/proc/{pid}/maps") as f:
                 libtpu = "libtpu" in f.read()
         except OSError:
             continue
-        if "ray_tpu._private" not in cmd:
-            continue
-        if session not in cmd and env.get("RAY_TPU_SESSION_DIR") != session:
-            continue
-        role = "head" if "head_main" in cmd else "worker"
-        out.append({"pid": int(pid), "role": role, "libtpu_mapped": libtpu,
-                    "JAX_PLATFORMS": env.get("JAX_PLATFORMS")})
+        out.append({"pid": pid, "role": "head" if head else "worker",
+                    "libtpu_mapped": libtpu,
+                    "JAX_PLATFORMS": env.get(b"JAX_PLATFORMS", b"").decode() or None})
     return out
 
 
@@ -561,18 +571,29 @@ def check_sharded(facts, config):
     say("sharded", parity_err=facts["parity_err"], tolerance=tol)
 
 
-def _keep_logs():
-    """Worker logs are all that says why a phase failed on a machine that
-    is thrown away: copy them where the chip tool brings them back."""
+def _stop_cluster():
+    """Shut the cluster down and say which of its processes are still
+    there afterwards (none may be): whatever still belongs to the session,
+    and every process a phase reported, in whatever state.  A chip owner
+    that was killed is a zombie with threads for seconds while the kernel
+    takes its device memory apart; only its pid still names it then.
+    Worker logs are all that says why a phase failed on a machine that is
+    thrown away: copy them to where the chip tool brings them back."""
+    from ray_tpu._private.node import session_pids
     from ray_tpu._private.worker import get_global_worker
 
+    if not ray_tpu.is_initialized():
+        return []
+    session = get_global_worker().session_info["session_dir"]
+    ray_tpu.shutdown()
     try:
-        logs = os.path.join(get_global_worker().session_info["session_dir"], "logs")
         dest = os.path.join(REPO, "chiprun_out", "chip_smoke_logs")
         shutil.rmtree(dest, ignore_errors=True)
-        shutil.copytree(logs, dest)
+        shutil.copytree(os.path.join(session, "logs"), dest)
     except Exception:  # noqa: BLE001 - never the reason a run fails
         traceback.print_exc()
+    dying = {pid for pid in _PIDS if os.path.exists(f"/proc/{pid}")}
+    return sorted(dying | set(session_pids(session)))
 
 
 def main(argv=None) -> int:
@@ -584,6 +605,7 @@ def main(argv=None) -> int:
     cache = place_compile_cache(REPO)
     say("cache", dir=cache, entries_before=count_cache_entries(cache))
     t0 = time.perf_counter()
+    device = None
     try:
         check_runtime(phase_runtime())
         if args.chips == 4:
@@ -607,14 +629,15 @@ def main(argv=None) -> int:
         device = {"platform": facts["platform"], "kind": facts["kind"], "count": facts["count"]}
     except Exception:  # noqa: BLE001 - the boundary: report, exit non-zero
         traceback.print_exc()
-        print("[chip_smoke] FAILED", flush=True)
-        return 1
     finally:
-        if ray_tpu.is_initialized():
-            _keep_logs()
-            ray_tpu.shutdown()
+        t_stop = time.perf_counter()
+        left = _stop_cluster()
+        say("shutdown", left_running=left, shutdown_s=time.perf_counter() - t_stop)
         say("cache", dir=cache, entries_after=count_cache_entries(cache),
             wall_s=time.perf_counter() - t0)
+    if device is None or left:
+        print("[chip_smoke] FAILED", flush=True)
+        return 1
     print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 

@@ -115,6 +115,12 @@ def main():
             await asyncio.wait_for(gcs.stop(), timeout=2)
         except Exception:
             pass
+        if client_server_proc is not None:
+            try:
+                client_server_proc.wait(timeout=3)
+            except subprocess.TimeoutExpired:
+                client_server_proc.kill()
+                client_server_proc.wait()
 
     loop.run_until_complete(run())
 

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import json
 import os
+import signal
 import subprocess
 import sys
 import tempfile
@@ -88,6 +89,32 @@ def detect_resources(num_cpus=None, num_tpus=None, resources=None, memory=None) 
     return out
 
 
+def session_pids(session_dir: str) -> list:
+    """Live processes (zombies excluded) of the session other than the
+    caller: the head and raylets carry the session directory on their
+    command line, workers and what they start in RAY_TPU_SESSION_DIR.
+    A process that is being torn down has neither any more, so only its
+    parent's wait can tell when it is gone."""
+    needle = session_dir.encode()
+    out = []
+    for pid in (int(p) for p in os.listdir("/proc") if p.isdigit()):
+        if pid == os.getpid():
+            continue
+        try:
+            with open(f"/proc/{pid}/stat", "rb") as f:
+                if f.read().rpartition(b")")[2].split()[0] == b"Z":
+                    continue
+            with open(f"/proc/{pid}/cmdline", "rb") as f:
+                args = f.read().split(b"\0")
+            with open(f"/proc/{pid}/environ", "rb") as f:
+                env = f.read().split(b"\0")
+        except OSError:
+            continue  # gone meanwhile, or not ours to read
+        if needle in args or b"RAY_TPU_SESSION_DIR=" + needle in env:
+            out.append(pid)
+    return out
+
+
 class NodeProcesses:
     """Driver-side handles to the processes this driver started."""
 
@@ -108,13 +135,20 @@ class NodeProcesses:
         self.store_root = store_root
 
     def terminate(self):
+        """Stop every process of this session and return only when they
+        are gone.  The head ends its own workers and waits for them
+        (Raylet.stop), which takes as long as the kernel needs to take a
+        killed chip owner's device memory apart: tens of seconds for four
+        chips, hence the long wait here.  The sweep after it is for what a
+        head that had to be killed left behind, since workers run in
+        sessions of their own."""
         for p in self.procs:
             if p.poll() is None:
                 try:
                     p.terminate()
                 except OSError:
                     pass
-        deadline = time.monotonic() + 5
+        deadline = time.monotonic() + 60
         for p in self.procs:
             try:
                 p.wait(timeout=max(0.1, deadline - time.monotonic()))
@@ -123,6 +157,21 @@ class NodeProcesses:
                     p.kill()
                 except OSError:
                     pass
+                p.wait()
+        from ray_tpu._private import retry
+
+        bo = retry.POLL.start(deadline_s=5)
+        while True:
+            left = session_pids(self.session_dir)
+            delay = bo.next_delay() if left else None
+            if delay is None:
+                break
+            for pid in left:
+                try:
+                    os.kill(pid, signal.SIGKILL)
+                except OSError:
+                    pass
+            time.sleep(delay)
         try:
             if os.path.exists(CLUSTER_ADDRESS_FILE):
                 with open(CLUSTER_ADDRESS_FILE) as f:

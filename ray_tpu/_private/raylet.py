@@ -149,6 +149,10 @@ class Raylet:
         # TPU still debited for chip owners that were told to go and
         # whose process has not exited yet: worker id -> (debit, watcher).
         self._chip_holds: Dict[WorkerID, Tuple[ResourceSet, asyncio.Task]] = {}
+        # Every worker process spawned here that was not yet seen dead:
+        # stop() leaves none of them behind, also those already dropped
+        # from `workers` and still on their way out.
+        self._worker_procs: List[subprocess.Popen] = []
         # env_hash -> (error message, monotonic time): envs whose staging
         # failed recently; tasks requiring them fail fast with
         # RuntimeEnvSetupError instead of spawn-looping.
@@ -664,7 +668,38 @@ class Raylet:
             # Always reclaim the shm arena, even if the graceful teardown
             # above raised or was cancelled by raylet_main's stop timeout —
             # a leaked /dev/shm arena outlives the process.
+            self._reap_worker_procs()
             self.cleanup_store_files()
+
+    def _reap_worker_procs(self):
+        """Leave no worker process behind: workers run in sessions of
+        their own, so nothing else ends them when this process is gone.
+        SIGTERM to every one still alive, SIGKILL after two seconds, and
+        wait for each, so that none outlives the raylet even as a zombie."""
+        procs = [p for p in self._worker_procs if p.poll() is None]
+        self._worker_procs = []
+        for p in procs:
+            try:
+                p.terminate()
+            except OSError:
+                pass
+        t0 = time.monotonic()
+        killed = []
+        for p in procs:
+            try:
+                p.wait(timeout=max(0.0, t0 + 2.0 - time.monotonic()))
+            except subprocess.TimeoutExpired:
+                killed.append(p.pid)
+                try:
+                    p.kill()
+                except OSError:
+                    pass
+                p.wait()
+        if procs:
+            logger.info(
+                "stop: %d worker processes gone after %.2fs (SIGKILL for %s)",
+                len(procs), time.monotonic() - t0, killed or "none",
+            )
 
     def cleanup_store_files(self):
         import shutil
@@ -1037,6 +1072,8 @@ class Raylet:
             start_new_session=True,
         )
         out.close()
+        self._worker_procs = [p for p in self._worker_procs if p.poll() is None]
+        self._worker_procs.append(proc)
         w = WorkerHandle(worker_id, proc, job_id)
         w.actor_id = actor_id
         w.env_hash = runtime_env_mod.env_hash(runtime_env)

@@ -130,6 +130,36 @@ def test_only_a_tpu_lease_leaves_a_worker_off_the_cpu_pin(monkeypatch):
         ray_tpu.shutdown()
 
 
+def test_shutdown_leaves_no_process_of_the_session():
+    """Workers run in sessions of their own, so nothing but the raylet
+    ends them: shutdown() returns only when the head and every worker,
+    idle or holding a chip, are gone and were waited for."""
+    import ray_tpu
+    from ray_tpu._private.node import session_pids
+    from ray_tpu._private.worker import get_global_worker
+
+    ray_tpu.init(num_cpus=2, num_tpus=1)
+    try:
+        session = get_global_worker().session_info["session_dir"]
+
+        @ray_tpu.remote
+        def pid():
+            return os.getpid()
+
+        @ray_tpu.remote(num_tpus=1)
+        class Owner:
+            def pid(self):
+                return os.getpid()
+
+        owner = Owner.remote()
+        pids = [ray_tpu.get(pid.remote()), ray_tpu.get(owner.pid.remote())]
+        assert set(pids) < set(session_pids(session))  # and the head
+    finally:
+        ray_tpu.shutdown()
+    assert session_pids(session) == []
+    assert not any(_pid_alive(p) for p in pids)  # not even as zombies
+
+
 def test_use_tpu_without_a_tpu_is_refused(ray_start_regular):
     from ray_tpu.air.config import ScalingConfig
     from ray_tpu.exceptions import TPUPlacementError
