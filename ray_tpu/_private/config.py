@@ -199,12 +199,9 @@ _CONFIG_DEFS: Dict[str, Any] = {
     # break died-mid-capture recovery.
     "profile_table_size": 512,
     # JAX/XLA introspection on instrumented jitted functions: compile
-    # timing, retrace counting, first-trace cost_analysis.  Off = the
-    # wrapper is a cache-size check per call.
+    # timing, retrace counting.  Off = the wrapper is a cache-size
+    # check per call.
     "jax_introspection": True,
-    # Run lowered.cost_analysis() at a function's FIRST trace (one extra
-    # trace per instrumented function, never on the steady-state path).
-    "jax_cost_analysis": True,
     # --- compiled-DAG dataplane (dag/ + experimental/channel.py) ---
     # Unacked-message window per cross-host socket channel: the socket
     # analog of the ring's free-space bound, sized to hide the network

@@ -17,10 +17,9 @@ and compile profiles, not RPC spans):
   table through the existing metrics/span report channel, so a capture
   survives its driver.
 - **What is the device doing?**  ``instrument_jit`` wraps a jitted
-  callable with compile-time/retrace counters and first-trace
-  ``cost_analysis()`` FLOPs/bytes; ``report_device_memory`` publishes
-  ``live_buffers``/``memory_stats`` gauges where the backend supports
-  them (CPU-safe no-op otherwise).
+  callable with compile-time/retrace counters; ``report_device_memory``
+  publishes ``live_buffers``/``memory_stats`` gauges where the backend
+  supports them (CPU-safe no-op otherwise).
 
 Exports: ``collapse`` (collapsed-stack / flamegraph lines),
 ``speedscope`` (speedscope JSON), ``merge_records`` (fold per-process
@@ -565,7 +564,7 @@ def _cache_size(jfn) -> Optional[int]:
 
 
 def jit_stats(name: Optional[str] = None) -> Dict[str, Any]:
-    """Per-instrumented-function compile/retrace/cost records."""
+    """Per-instrumented-function compile/retrace records."""
     with _jit_lock:
         if name is not None:
             return dict(_jit_records.get(name, {}))
@@ -574,7 +573,7 @@ def jit_stats(name: Optional[str] = None) -> Dict[str, Any]:
 
 def instrument_jit(name: str, jfn):
     """Wrap an already-jitted callable with compile-time and retrace
-    counters plus first-trace cost_analysis.
+    counters.
 
     Steady-state cost per call: one cache-size probe + two
     perf_counter reads (~0.5 us) — far inside the telemetry budget for
@@ -593,19 +592,12 @@ def instrument_jit(name: str, jfn):
     with _jit_lock:
         _jit_records.setdefault(
             name,
-            {"compiles": 0, "retraces": 0, "compile_seconds": 0.0, "flops": None,
-             "bytes_accessed": None},
+            {"compiles": 0, "retraces": 0, "compile_seconds": 0.0},
         )
 
     def wrapped(*args, **kwargs):
         from ray_tpu._private import telemetry
 
-        # cost_analysis runs BEFORE the first call: donate_argnums
-        # functions consume their buffers, so lowering afterwards would
-        # trace over deleted arrays.
-        if not state.get("cost_done"):
-            state["cost_done"] = True
-            _capture_cost(name, jfn, args, kwargs)
         t_wall = time.time()
         t0 = time.perf_counter()
         out = jfn(*args, **kwargs)
@@ -643,32 +635,6 @@ def instrument_jit(name: str, jfn):
     wrapped.__name__ = f"instrumented_{name}"
     wrapped.__wrapped__ = jfn
     return wrapped
-
-
-def _capture_cost(name: str, jfn, args, kwargs) -> None:
-    """First-trace cost_analysis: FLOPs + bytes accessed from the
-    lowered computation (one extra trace, never on the steady path).
-    Backends that don't implement it just skip."""
-    try:
-        if not CONFIG.jax_cost_analysis:
-            return
-        lowered = jfn.lower(*args, **kwargs)
-        ca = lowered.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else None
-        if not isinstance(ca, dict):
-            return
-        flops = float(ca.get("flops", 0.0) or 0.0)
-        nbytes = float(ca.get("bytes accessed", 0.0) or 0.0)
-        with _jit_lock:
-            rec = _jit_records[name]
-            rec["flops"] = flops
-            rec["bytes_accessed"] = nbytes
-        from ray_tpu._private import telemetry
-
-        telemetry.set_jax_cost(name, flops, nbytes)
-    except Exception:  # noqa: BLE001 — introspection must never break the hot path
-        pass
 
 
 _dev_report_lock = threading.Lock()

@@ -298,18 +298,6 @@ class _Metrics:
             "function (a climbing series = unstable shapes/dtypes)",
             tag_keys=("function",),
         )
-        self.jax_cost_flops = m.Gauge(
-            "jax_cost_flops",
-            "XLA cost_analysis FLOPs estimate per call of an instrumented "
-            "jitted function, captured at first trace",
-            tag_keys=("function",),
-        )
-        self.jax_cost_bytes = m.Gauge(
-            "jax_cost_bytes",
-            "XLA cost_analysis bytes-accessed estimate per call of an "
-            "instrumented jitted function, captured at first trace",
-            tag_keys=("function",),
-        )
         self.device_memory = m.Gauge(
             "device_memory_bytes",
             "per-device memory from the backend's memory_stats() "
@@ -891,14 +879,6 @@ def count_jax_retrace(function: str) -> None:
         _jax_retrace_bound, function, "jax_retraces", {"function": function}
     )
     b.inc(1.0)
-
-
-def set_jax_cost(function: str, flops: float, nbytes: float) -> None:
-    if not enabled():
-        return
-    m = _metrics()
-    m.jax_cost_flops.set(flops, tags={"function": function})
-    m.jax_cost_bytes.set(nbytes, tags={"function": function})
 
 
 def set_device_memory(device: str, kind: str, value: float) -> None:

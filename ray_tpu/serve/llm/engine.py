@@ -23,7 +23,10 @@ stream consumption, stats) stays responsive during a step.
 Request spans (``serve.request`` -> ``serve.queue`` / ``serve.prefill``
 / ``serve.decode``) are recorded per request so ``state.traces()``
 critical-path analysis attributes end-to-end latency to queue vs prefill
-vs decode.
+vs decode.  The step loop itself is timed by phase (``ENGINE_SPANS``):
+each phase adds its seconds to a cumulative counter in ``stats()`` and,
+while a ``jax.profiler`` trace is being taken, is a span on the clock of
+the device's operations (docs/serving.md "Latency attribution").
 
 Overload armor (docs/serving.md "Overload resilience"): requests carry
 tenant + SLO-class identity.  The waiting queue is a weighted fair queue
@@ -41,6 +44,7 @@ from __future__ import annotations
 
 import asyncio
 import collections
+import contextlib
 import functools
 import logging
 import time
@@ -64,6 +68,38 @@ logger = logging.getLogger(__name__)
 
 # end-of-stream sentinel pushed onto a request's output queue
 FINISHED = object()
+
+# The spans of the step loop, by where its time goes.  ``_phase(name)``
+# opens a profiler TraceAnnotation of that name around each and adds its
+# seconds to ``stats()["<phase>_s"]`` (the name less "engine.", dots to
+# underscores).  Each encloses synchronous code only.
+ENGINE_SPANS = (
+    "engine.admit",          # _reap, _maybe_preempt, _next_admissible
+    "engine.prefill.build",  # bucket, pad, phys_indices of one prompt
+    "engine.prefill.run",    # executor thread: the prefill jit call (dispatch)
+    "engine.prefill.fetch",  # loop thread: int(first_tok), waits for the device
+    "engine.decode.build",   # block tables, mask and phys_indices of all lanes
+    "engine.decode.run",     # executor thread: the decode jit call (dispatch)
+    "engine.decode.fetch",   # loop thread: np.asarray(nxt), waits for the device
+    "engine.emit",           # tokens onto the streams, _finish of lanes that end
+    "engine.metrics",        # _push_metrics, report_device_memory in it
+)
+# What the loop thread awaits is timed by the same helper into counters
+# only (``span=False``): an annotation held across an await would
+# interleave with the coroutines that share the thread.  The two awaits
+# enclose their ``run`` span: the difference is the executor hop.
+_LOOP_WAITS = (
+    "engine.prefill.await",
+    "engine.decode.await",
+    "engine.yield",          # sleep(0): what the loop's other callbacks took
+    "engine.idle",           # nothing to run: waiting on _wake or the 5 ms retry
+)
+# A slice of the loop (from the end of one prefill or iteration to the
+# end of the next, so one device program and the host work around it)
+# that takes longer than this outside ``engine.idle`` is a stall:
+# counted, and logged with its milliseconds by phase.  Eight decode
+# steps of GPT-2-large on a v5e chip.
+STALL_S = 1.0
 
 
 @dataclass
@@ -156,6 +192,20 @@ class LLMEngine:
         # (wall time, tokens emitted) per step, for the tokens/s gauge
         self._tok_window: Deque[tuple] = collections.deque(maxlen=512)
         self._total_tokens = 0
+        self._phase_s = dict.fromkeys(ENGINE_SPANS + _LOOP_WAITS, 0.0)
+        # cumulative counts of work, taken where the work happens; all
+        # plain numbers in stats()
+        self._counts: Dict[str, Any] = {
+            "joined": 0, "queue_wait_s": 0.0,
+            "prompt_tokens": 0, "prefill_bucket_tokens": 0,
+            # of the positions a decode step gathers, those a lane holds
+            "kv_positions_attended": 0, "kv_positions_gathered": 0,
+            "stalls": 0, "stall_s": 0.0,
+        }
+        # where the current slice of the loop began (_note_stall)
+        self._slice_t0 = time.perf_counter()
+        self._slice_before = dict(self._phase_s)
+        self._slice_compiles = 0
         self._shed_total = 0
         # shed attribution: {(where, tenant_label): n}, flushed at 1 Hz
         self._shed_unreported: Dict[tuple, int] = {}
@@ -203,10 +253,13 @@ class LLMEngine:
         self._device = next(iter(self.k_pages.devices()))
         self._base_key = jax.random.PRNGKey(self.config.seed + 1)
         top_k = self.config.top_k
+        # a disabled TraceMe (well under a microsecond) outside a
+        # jax.profiler session; bound here because only this method
+        # imports jax
+        self._annotation = jax.profiler.TraceAnnotation
         # XLA introspection on the serving hot path: compile-time/
         # retrace counters (prefill compiles once per prompt bucket —
-        # a retrace storm here is a bucketing bug) + first-trace
-        # FLOPs/bytes (docs/profiling.md).
+        # a retrace storm here is a bucketing bug; docs/profiling.md).
         from ray_tpu._private import profiling as _profiling
 
         self._prefill_jit = _profiling.instrument_jit(
@@ -391,6 +444,9 @@ class LLMEngine:
             "total_tokens": self._total_tokens,
             "shed_total": self._shed_total,
             "steps": self.step_count,
+            **self._counts,
+            **{n[len("engine."):].replace(".", "_") + "_s": v
+               for n, v in self._phase_s.items()},
             "preemptions_total": self._preempt_total,
             "degradation_level": self._degrade.level,
             "tenants": tenants,
@@ -402,34 +458,91 @@ class LLMEngine:
         return len(self.waiting) + sum(1 for r in self.slots if r is not None)
 
     # -- step loop -------------------------------------------------------
+    @contextlib.contextmanager
+    def _phase(self, name: str, span: bool = True):
+        """Time one phase of the step loop into its cumulative counter
+        and mark it as a span in the profiler's trace (a name of
+        ENGINE_SPANS), or time it alone (``span=False``: a name of
+        _LOOP_WAITS, which encloses an await)."""
+        t0 = time.perf_counter()
+        try:
+            with self._annotation(name) if span else contextlib.nullcontext():
+                yield
+        finally:
+            self._phase_s[name] += time.perf_counter() - t0
+
     async def _run(self):
         loop = asyncio.get_running_loop()
+        self._note_stall()  # the first slice begins now
         while not self._stopped:
             try:
-                self._reap()
-                await self._join_waiters(loop)
-                if not any(r is not None for r in self.slots):
-                    self._push_metrics()
-                    if not self.waiting:
-                        self._wake.clear()
-                        try:
-                            await asyncio.wait_for(self._wake.wait(), timeout=1.0)
-                        except asyncio.TimeoutError:
-                            pass
-                    else:
-                        # waiting but nothing admissible: KV pool full —
-                        # yield until a completion frees blocks
-                        await asyncio.sleep(0.005)
-                    continue
-                await self._decode_once(loop)
-                self._push_metrics()
-                # step boundary: let pending add_request/cancel callbacks run
-                await asyncio.sleep(0)
+                await self._iterate(loop)
             except asyncio.CancelledError:
                 raise
             except Exception:  # noqa: BLE001 — one bad step must not stop serving
                 logger.exception("llm engine step failed; continuing")
-                await asyncio.sleep(0.05)
+                with self._phase("engine.idle", span=False):
+                    await asyncio.sleep(0.05)
+            self._note_stall()
+
+    async def _iterate(self, loop):
+        with self._phase("engine.admit"):
+            self._reap()
+            self._maybe_preempt()
+        await self._join_waiters(loop)
+        if not any(r is not None for r in self.slots):
+            with self._phase("engine.metrics"):
+                self._push_metrics()
+            with self._phase("engine.idle", span=False):
+                if not self.waiting:
+                    self._wake.clear()
+                    try:
+                        await asyncio.wait_for(self._wake.wait(), timeout=1.0)
+                    except asyncio.TimeoutError:
+                        pass
+                else:
+                    # waiting but nothing admissible: KV pool full —
+                    # yield until a completion frees blocks
+                    await asyncio.sleep(0.005)
+            return
+        await self._decode_once(loop)
+        with self._phase("engine.metrics"):
+            self._push_metrics()
+        # step boundary: let pending add_request/cancel callbacks run
+        with self._phase("engine.yield", span=False):
+            await asyncio.sleep(0)
+
+    def _note_stall(self):
+        """End a slice of the loop (called after every prefill and every
+        iteration).  One that took over STALL_S outside ``engine.idle``
+        is counted and logged with the phases its time went to; one in
+        which a program compiled is logged at INFO and not counted."""
+        from ray_tpu._private import profiling as _profiling
+
+        now = time.perf_counter()
+        compiles = sum(_profiling.jit_stats(f).get("compiles", 0)
+                       for f in ("serve_prefill", "serve_decode"))
+        spent = {n: s - self._slice_before[n] for n, s in self._phase_s.items()}
+        took_s = now - self._slice_t0 - spent["engine.idle"]
+        if took_s > STALL_S:
+            compiled = compiles > self._slice_compiles
+            if not compiled:
+                self._counts["stalls"] += 1
+                self._counts["stall_s"] += took_s
+            by_phase = " ".join(
+                f"{n}={1000 * s:.0f}"
+                for n, s in sorted(spent.items(), key=lambda kv: -kv[1]) if s >= 0.0005
+            )
+            logger.log(
+                logging.INFO if compiled else logging.WARNING,
+                "llm engine %s: %.0f ms at step %d, waiting=%d running=%d; ms by phase: %s",
+                "compiled" if compiled else "slow iteration",
+                1000 * took_s, self.step_count, len(self.waiting),
+                sum(1 for r in self.slots if r is not None), by_phase,
+            )
+        self._slice_t0 = now
+        self._slice_before = dict(self._phase_s)
+        self._slice_compiles = compiles
 
     def _reap(self):
         """Step-boundary cleanup: cancelled lanes leave, blocks freed."""
@@ -442,20 +555,23 @@ class LLMEngine:
         """Admit waiting requests into free lanes — the continuous-batch
         join point: new requests enter at a step boundary instead of
         waiting for the running batch to drain."""
-        self._maybe_preempt()
         joined = 0
         for i in range(len(self.slots)):
             if self.slots[i] is not None:
                 continue
-            req = self._next_admissible()
+            with self._phase("engine.admit"):
+                req = self._next_admissible()
             if req is None:
                 break
             req.slot = i
             req.t_join = time.time()
             req.join_step = self.step_count
             self.slots[i] = req
+            self._counts["joined"] += 1
+            self._counts["queue_wait_s"] += req.t_join - req.t_enqueue
             try:
                 await self._prefill(loop, req)
+                self._note_stall()
             except Exception as e:  # noqa: BLE001 — a bad prompt must not kill the loop
                 logger.exception("prefill failed for %s", req.request_id)
                 self.slots[i] = None
@@ -631,76 +747,95 @@ class LLMEngine:
         self._fair_dirty = True
 
     async def _prefill(self, loop, req: _Request):
-        n = len(req.prompt)
-        bucket = self._prefill_bucket(n, self.max_ctx)
-        toks = np.zeros((1, bucket), dtype=np.int32)
-        toks[0, :n] = req.prompt
-        self.bm.advance(req.request_id, n)
-        phys = self.bm.phys_indices(req.request_id, n, bucket)
-        last_idx = np.array([n - 1], dtype=np.int32)
-        temp = np.array([req.temperature], dtype=np.float32)
-        rng = self._next_rng()
-        first_tok, self.k_pages, self.v_pages = await loop.run_in_executor(
-            None,
-            lambda: self._prefill_jit(
-                self.params, self.k_pages, self.v_pages,
-                toks, phys, last_idx, temp, rng,
-            ),
-        )
-        tok = int(first_tok)
-        self._emit(req, tok)
-        self._tok_window.append((time.time(), 1))
-        if req.cancelled or self._is_finished(req, tok):
-            self.slots[req.slot] = None
-            self._finish(req, req.finish_reason or "length")
+        with self._phase("engine.prefill.build"):
+            n = len(req.prompt)
+            bucket = self._prefill_bucket(n, self.max_ctx)
+            toks = np.zeros((1, bucket), dtype=np.int32)
+            toks[0, :n] = req.prompt
+            self.bm.advance(req.request_id, n)
+            phys = self.bm.phys_indices(req.request_id, n, bucket)
+            last_idx = np.array([n - 1], dtype=np.int32)
+            temp = np.array([req.temperature], dtype=np.float32)
+            rng = self._next_rng()
+
+        def run():
+            with self._phase("engine.prefill.run"):
+                return self._prefill_jit(
+                    self.params, self.k_pages, self.v_pages,
+                    toks, phys, last_idx, temp, rng,
+                )
+
+        with self._phase("engine.prefill.await", span=False):
+            first_tok, self.k_pages, self.v_pages = await loop.run_in_executor(None, run)
+        with self._phase("engine.prefill.fetch"):
+            tok = int(first_tok)
+        with self._phase("engine.emit"):
+            self._counts["prompt_tokens"] += n
+            self._counts["prefill_bucket_tokens"] += bucket
+            self._emit(req, tok)
+            self._tok_window.append((time.time(), 1))
+            if req.cancelled or self._is_finished(req, tok):
+                self.slots[req.slot] = None
+                self._finish(req, req.finish_reason or "length")
 
     async def _decode_once(self, loop):
-        B = self.config.max_batch_size
-        C = self.max_ctx
-        tok = np.zeros(B, dtype=np.int32)
-        pos = np.zeros(B, dtype=np.int32)
-        idx = np.zeros((B, C), dtype=np.int32)
-        mask = np.zeros((B, C), dtype=bool)
-        write_phys = np.zeros(B, dtype=np.int32)
-        temp = np.zeros(B, dtype=np.float32)
-        active_lanes = []
-        for i, req in enumerate(self.slots):
-            if req is None:
-                continue
-            rid = req.request_id
-            cur_len = self.bm.seq_len(rid)  # positions already in cache
-            tok[i] = req.tokens[-1]
-            pos[i] = cur_len  # the fed token's position
-            idx[i] = self.bm.phys_indices(rid, cur_len, C)
-            mask[i, :cur_len] = True
-            self.bm.advance(rid, 1)
-            write_phys[i] = self.bm.phys_index(rid, cur_len)
-            temp[i] = req.temperature
-            active_lanes.append(i)
-        rng = self._next_rng()
-        nxt, self.k_pages, self.v_pages = await loop.run_in_executor(
-            None,
-            lambda: self._decode_jit(
-                self.params, self.k_pages, self.v_pages,
-                tok, pos, idx, mask, write_phys, temp, rng,
-            ),
-        )
-        nxt = np.asarray(nxt)
-        self.step_count += 1
-        now = time.time()
-        emitted = 0
-        for i in active_lanes:
-            req = self.slots[i]
-            if req is None:
-                continue
-            t = int(nxt[i])
-            self._emit(req, t, now=now)
-            emitted += 1
-            if req.cancelled or self._is_finished(req, t):
-                self.slots[i] = None
-                self._finish(req, req.finish_reason or "length")
-        if emitted:
-            self._tok_window.append((now, emitted))
+        with self._phase("engine.decode.build"):
+            B = self.config.max_batch_size
+            C = self.max_ctx
+            tok = np.zeros(B, dtype=np.int32)
+            pos = np.zeros(B, dtype=np.int32)
+            idx = np.zeros((B, C), dtype=np.int32)
+            mask = np.zeros((B, C), dtype=bool)
+            write_phys = np.zeros(B, dtype=np.int32)
+            temp = np.zeros(B, dtype=np.float32)
+            active_lanes = []
+            attended = 0
+            for i, req in enumerate(self.slots):
+                if req is None:
+                    continue
+                rid = req.request_id
+                cur_len = self.bm.seq_len(rid)  # positions already in cache
+                tok[i] = req.tokens[-1]
+                pos[i] = cur_len  # the fed token's position
+                idx[i] = self.bm.phys_indices(rid, cur_len, C)
+                mask[i, :cur_len] = True
+                self.bm.advance(rid, 1)
+                write_phys[i] = self.bm.phys_index(rid, cur_len)
+                temp[i] = req.temperature
+                active_lanes.append(i)
+                attended += cur_len
+            rng = self._next_rng()
+
+        def run():
+            with self._phase("engine.decode.run"):
+                return self._decode_jit(
+                    self.params, self.k_pages, self.v_pages,
+                    tok, pos, idx, mask, write_phys, temp, rng,
+                )
+
+        with self._phase("engine.decode.await", span=False):
+            nxt, self.k_pages, self.v_pages = await loop.run_in_executor(None, run)
+        with self._phase("engine.decode.fetch"):
+            nxt = np.asarray(nxt)
+        with self._phase("engine.emit"):
+            self.step_count += 1
+            self._counts["kv_positions_attended"] += attended
+            # k_pages[:, idx] materialises every lane at full length
+            self._counts["kv_positions_gathered"] += B * C
+            now = time.time()
+            emitted = 0
+            for i in active_lanes:
+                req = self.slots[i]
+                if req is None:
+                    continue
+                t = int(nxt[i])
+                self._emit(req, t, now=now)
+                emitted += 1
+                if req.cancelled or self._is_finished(req, t):
+                    self.slots[i] = None
+                    self._finish(req, req.finish_reason or "length")
+            if emitted:
+                self._tok_window.append((now, emitted))
 
     # -- bookkeeping -----------------------------------------------------
     def _emit(self, req: _Request, token: int, now: Optional[float] = None):

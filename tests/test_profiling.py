@@ -316,8 +316,6 @@ def test_instrument_jit_counts_compiles_and_retraces():
     assert rec["compiles"] == 2
     assert rec["retraces"] == 1
     assert rec["compile_seconds"] > 0
-    # cost_analysis captured at first trace (CPU supports it).
-    assert rec["flops"] is not None
 
 
 def test_instrument_jit_kill_switch_returns_unwrapped():

@@ -1,0 +1,309 @@
+"""The LLM engine's step loop timed by phase: the counters in
+``stats()``, the spans in a profiler trace, the slow-iteration line, and
+the benchmark's per-layer metrics that read them.
+
+Engine-level tests, no cluster: asyncio and the ``tiny`` preset on the
+CPU.  Counts are exact; seconds are only ordered and bounded here, a
+speed comes from the chip alone.
+"""
+
+import asyncio
+import json
+import logging
+import os
+import time
+
+import pytest
+
+from ray_tpu.serve.llm import LLMConfig, LLMEngine
+from ray_tpu.serve.llm import engine as engine_mod
+from ray_tpu.serve.llm.engine import ENGINE_SPANS, FINISHED
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# what the loop thread does, phase after phase: these tile an iteration
+# (the two run spans lie inside their awaits, on an executor thread)
+LOOP_PHASES = [n for n in ENGINE_SPANS + engine_mod._LOOP_WAITS if not n.endswith(".run")]
+
+
+def _key(name: str) -> str:
+    return name[len("engine."):].replace(".", "_")
+
+
+def _tiny(**kw) -> LLMConfig:
+    base = dict(model="tiny", max_batch_size=4, num_blocks=64, block_size=8,
+                default_max_tokens=8, temperature=0.0)
+    base.update(kw)
+    return LLMConfig(**base)
+
+
+async def _drain(req):
+    toks = []
+    while True:
+        ev = await req.out.get()
+        if ev is FINISHED:
+            return toks
+        toks.append(ev["token"])
+
+
+async def _generate(eng, batch):
+    reqs = [await eng.add_request(list(range(1, n + 1)), max_tokens=m) for n, m in batch]
+    return await asyncio.gather(*[_drain(r) for r in reqs])
+
+
+# (prompt tokens, output tokens): more requests than the four lanes, one
+# answered by its prefill alone, buckets of 8, 16 and 32
+BATCH = [(3, 5), (8, 1), (9, 7), (17, 4), (5, 6), (12, 3), (30, 9)]
+
+
+@pytest.fixture(scope="module")
+def batch_run():
+    """One engine, BATCH sent at once and drained: its stats and the
+    wall time from before the loop started to after it stopped."""
+
+    async def main():
+        eng = LLMEngine(_tiny())
+        t0 = time.perf_counter()
+        outs = await _generate(eng, BATCH)
+        stats = eng.stats()
+        await eng.stop()
+        return eng, outs, stats, time.perf_counter() - t0
+
+    return asyncio.run(main())
+
+
+def test_counts_are_conserved_over_a_fixed_batch(batch_run):
+    eng, outs, st, _ = batch_run
+    assert [len(o) for o in outs] == [m for _, m in BATCH]
+    assert st["joined"] == len(BATCH)
+    assert st["prompt_tokens"] == sum(n for n, _ in BATCH)
+    assert st["prefill_bucket_tokens"] == sum(
+        LLMEngine._prefill_bucket(n, eng.max_ctx) for n, _ in BATCH)
+    assert st["steps"] > 0
+    assert st["kv_positions_gathered"] == st["steps"] * st["max_batch_size"] * eng.max_ctx
+    # a request's decode step j (of m - 1) attends to its n prompt
+    # positions and the j tokens written before it
+    assert st["kv_positions_attended"] == sum(
+        (m - 1) * n + (m - 1) * (m - 2) // 2 for n, m in BATCH)
+    assert st["total_tokens"] == sum(m for _, m in BATCH)
+    assert st["queue_wait_s"] > 0  # three of seven waited for a lane at least
+    assert st["kv_blocks_in_use"] == 0
+
+
+def test_phase_seconds_fit_the_wall_time(batch_run):
+    _, _, st, wall_s = batch_run
+    for name in ENGINE_SPANS:
+        assert st[_key(name) + "_s"] > 0
+    assert st["decode_await_s"] >= st["decode_run_s"] > 0
+    assert st["prefill_await_s"] >= st["prefill_run_s"] > 0
+    in_loop = sum(st[_key(n) + "_s"] for n in LOOP_PHASES)
+    assert 0 < in_loop <= wall_s
+    # and they leave little of the loop's time unnamed: the run began
+    # with the loop's start and ended with the last token
+    assert in_loop >= 0.8 * wall_s
+
+
+def test_phases_are_spans_in_a_profiler_trace(tmp_path):
+    """A jax.profiler trace taken around a few steps holds the engine's
+    phases on the host plane, where the benchmark's reduction finds them."""
+    import jax
+
+    from benchmark import trace_reduce
+
+    async def main():
+        eng = LLMEngine(_tiny())
+        await _generate(eng, [(5, 3)])  # compile outside the trace
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        before = eng.stats()
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            await _generate(eng, [(5, 4), (6, 6)])
+        finally:
+            jax.profiler.stop_trace()
+        after = eng.stats()
+        await eng.stop()
+        return before, after
+
+    before, after = asyncio.run(main())
+    planes = trace_reduce.load(trace_reduce.find_xplane(str(tmp_path)))
+    spans = trace_reduce.host_spans(planes, ENGINE_SPANS)
+    count = {}
+    for name, _start, dur in spans:
+        assert dur >= 0
+        count[name] = count.get(name, 0) + 1
+    assert set(count) == set(ENGINE_SPANS)
+    steps = after["steps"] - before["steps"]
+    assert steps >= 5
+    assert (count["engine.decode.run"] == count["engine.decode.build"]
+            == count["engine.decode.fetch"] == steps)
+    assert count["engine.prefill.run"] == count["engine.prefill.fetch"] == 2
+
+
+def test_slow_iteration_is_counted_and_logged_by_phase(caplog):
+    async def main():
+        eng = LLMEngine(_tiny())
+        await _generate(eng, [(5, 3)])
+        before = eng.stats()
+        push, calls = eng._push_metrics, []
+
+        def slow_push(force=False):
+            if not calls:
+                time.sleep(1.1)
+            calls.append(force)
+            return push(force)
+
+        eng._push_metrics = slow_push
+        with caplog.at_level(logging.WARNING, logger="ray_tpu.serve.llm.engine"):
+            caplog.clear()
+            await _generate(eng, [(5, 3)])
+        after = eng.stats()
+        await eng.stop()
+        return before, after
+
+    before, after = asyncio.run(main())
+    lines = [r.getMessage() for r in caplog.records if "slow iteration" in r.getMessage()]
+    assert len(lines) == 1, lines
+    by_phase = lines[0].split("ms by phase: ")[1].split()
+    assert by_phase[0].startswith("engine.metrics=")  # the largest comes first
+    assert float(by_phase[0].split("=")[1]) >= 1100
+    assert "waiting=" in lines[0] and "running=" in lines[0] and "at step" in lines[0]
+    assert after["stalls"] - before["stalls"] == 1
+    assert after["stall_s"] - before["stall_s"] >= 1.1
+
+
+def test_a_slice_that_compiles_is_no_stall(caplog):
+    """A deployment's first calls compile for seconds: an INFO line with
+    the phases, and nothing counted."""
+
+    async def main():
+        eng = LLMEngine(_tiny())
+        await _generate(eng, [(5, 3)])
+        jit = eng._prefill_jit
+
+        def slow_jit(*args):
+            time.sleep(1.1)
+            return jit(*args)
+
+        eng._prefill_jit = slow_jit
+        with caplog.at_level(logging.INFO, logger="ray_tpu.serve.llm.engine"):
+            caplog.clear()
+            await _generate(eng, [(40, 2)])  # a bucket not seen yet: a real compile
+        st = eng.stats()
+        await eng.stop()
+        return st
+
+    st = asyncio.run(main())
+    assert st["stalls"] == 0 and st["stall_s"] == 0
+    assert not [r for r in caplog.records if "slow iteration" in r.getMessage()]
+    lines = [r for r in caplog.records if "llm engine compiled" in r.getMessage()]
+    assert len(lines) == 1 and lines[0].levelno == logging.INFO
+    assert "engine.prefill.run=" in lines[0].getMessage()
+
+
+def test_a_full_refill_of_fast_prefills_is_no_stall(monkeypatch):
+    """The threshold holds each prefill and each decode step by itself:
+    an iteration that admits many prompts is long and no stall."""
+    monkeypatch.setattr(engine_mod, "STALL_S", 0.25)
+
+    async def main():
+        eng = LLMEngine(_tiny())
+        await _generate(eng, [(5, 2)])
+        jit = eng._prefill_jit
+
+        def slow_jit(*args):
+            time.sleep(0.1)
+            return jit(*args)
+
+        eng._prefill_jit = slow_jit
+        await _generate(eng, [(5, 2)] * 4)  # four lanes: 0.4 s of prefill in one iteration
+        st = eng.stats()
+        await eng.stop()
+        return st
+
+    st = asyncio.run(main())
+    assert st["prefill_await_s"] >= 0.4 and st["stalls"] == 0
+
+
+def test_an_idle_engine_is_no_stall():
+    """The loop waits up to a second for work; that is idle time."""
+
+    async def main():
+        eng = LLMEngine(_tiny())
+        eng.ensure_started()
+        await asyncio.sleep(1.3)
+        st = eng.stats()
+        await eng.stop()
+        return st
+
+    st = asyncio.run(main())
+    assert st["stalls"] == 0 and st["idle_s"] >= 1.0 and st["steps"] == 0
+
+
+# ----------------------------------------------------------------------
+# the benchmark's per-layer metrics over these counters
+# ----------------------------------------------------------------------
+# stats() at the ends of a 30 s window of 250 steps, as the benchmark's
+# replica-side probe passes them on (numbers and strings only)
+_BEFORE = {
+    "steps": 1000, "max_batch_size": 16, "platform": "tpu", "total_tokens": 9000,
+    "joined": 100, "queue_wait_s": 5.0, "prompt_tokens": 20_000, "prefill_bucket_tokens": 28_000,
+    "kv_positions_attended": 2_000_000, "kv_positions_gathered": 16_384_000,
+    "admit_s": 0.10, "prefill_build_s": 0.05, "prefill_run_s": 0.20, "prefill_await_s": 0.25,
+    "prefill_fetch_s": 4.0, "decode_build_s": 2.0, "decode_run_s": 2.5, "decode_await_s": 3.0,
+    "decode_fetch_s": 108.0, "emit_s": 0.30, "metrics_s": 0.20, "yield_s": 0.40, "idle_s": 50.0,
+}
+_AFTER = {
+    "steps": 1250, "max_batch_size": 16, "platform": "tpu", "total_tokens": 12000,
+    "joined": 140, "queue_wait_s": 8.0, "prompt_tokens": 28_000, "prefill_bucket_tokens": 39_200,
+    "kv_positions_attended": 2_614_400, "kv_positions_gathered": 20_480_000,
+    "admit_s": 0.15, "prefill_build_s": 0.07, "prefill_run_s": 0.28, "prefill_await_s": 0.35,
+    "prefill_fetch_s": 6.08, "decode_build_s": 2.5, "decode_run_s": 3.125, "decode_await_s": 3.75,
+    "decode_fetch_s": 134.0, "emit_s": 0.45, "metrics_s": 0.25, "yield_s": 0.55, "idle_s": 50.5,
+}
+# host time a step: admit 0.05 + build 0.5 + await 0.75 (dispatch and
+# hop) + emit 0.15 + metrics 0.05 + yield 0.15 = 1.65 s over 250 steps;
+# prefill: build 0.02 + await 0.10 + fetch 2.08 = 2.2 s of 30
+_BY_HAND = {
+    "queue_wait_ms.steady": 1000 * 3.0 / 40,
+    "prefill_share_pct.steady": 100 * 2.2 / 30,
+    "prefill_share_pct.backlog": 100 * 2.2 / 30,
+    "host_ms_per_step.steady": 6.6,
+    "host_ms_per_step.backlog": 6.6,
+    "kv_gather_useful_pct.steady": 15.0,
+    "kv_gather_useful_pct.backlog": 15.0,
+    "prefill_pad_ratio.steady": 1.4,
+    "prefill_pad_ratio.backlog": 1.4,
+}
+
+
+@pytest.mark.parametrize("metric", sorted(_BY_HAND))
+def test_layer_metric_reads_the_engine_counters(metric):
+    from benchmark import readers, spec
+
+    how = spec.load_layer_metric(metric)
+    assert how["reader"] == "stats_delta"
+    ctx = {"values": {}, "stats": {"before": _BEFORE, "after": _AFTER, "window_s": 30.0}}
+    assert readers.stats_delta(how["args"], ctx) == pytest.approx(_BY_HAND[metric])
+    # on a program that lacks the counters the metric is left out, not raised
+    old = {"before": {"steps": 1000}, "after": {"steps": 1250}, "window_s": 30.0}
+    assert readers.stats_delta(how["args"], {"values": {}, "stats": old}) is None
+    # and BENCHMARK.json reports it in its one cell, under the layer's name
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        entry = next(m for m in json.load(f)["per_layer"] if m["name"] == metric)
+    cell = "chat-steady" if metric.endswith(".steady") else "batch-backlog"
+    assert entry["workloads"] == ["gpt2-large.serve." + cell]
+    assert (entry["layer"], entry["source"]) == ("serve plane", "program_counter")
+
+
+def test_layer_metric_counters_are_keys_of_stats(batch_run):
+    """The names the expressions use are names stats() has."""
+    import re
+
+    from benchmark import spec
+
+    _, _, st, _ = batch_run
+    for metric in _BY_HAND:
+        expr = spec.load_layer_metric(metric)["args"]["expr"]
+        for key in re.findall(r"\bd\.(\w+)", expr):
+            assert isinstance(st[key], (int, float)), (metric, key)
