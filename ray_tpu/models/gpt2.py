@@ -265,37 +265,24 @@ def prefill_forward(params, cfg: GPT2Config, tokens, last_index=None):
     return logits_last, jnp.stack(ks), jnp.stack(vs)
 
 
-def decode_forward(params, cfg: GPT2Config, tok, pos, k_ctx, v_ctx, ctx_mask):
-    """One decode step over an externally-gathered KV context.
-
-    tok [B] current token ids; pos [B] their positions;
-    k_ctx/v_ctx [L, B, C, H, Dh] the per-layer cached keys/values for
-    positions < pos (padded; ctx_mask [B, C] marks real entries).
-    Returns (logits [B, vocab], k_new [L, B, H, Dh], v_new [L, B, H, Dh])
-    — the caller scatters k_new/v_new into its cache at position pos.
-    """
+def _decode_layers(params, cfg: GPT2Config, tok, pos, attend):
+    """One decode step: tok [B] current token ids, pos [B] their
+    positions; ``attend(i, q, k, v)`` gives layer i's attention of the
+    fed token (q, k, v [B, H, Dh], its own key and value among what it
+    attends to) over whatever cache the caller holds.  Returns
+    (logits [B, vocab], k_new [L, B, H, Dh], v_new [L, B, H, Dh]): the
+    caller writes k_new/v_new into its cache at position pos."""
     dtype = cfg.dtype
-    d_head = cfg.d_model // cfg.n_head
-    scale = 1.0 / (d_head**0.5)
     x = params["wte"]["embedding"].astype(dtype)[tok]
     x = x + params["wpe"]["embedding"].astype(dtype)[pos]
     k_news, v_news = [], []
-    neg = jnp.float32(-1e30)
     for i in range(cfg.n_layer):
         blk = params[f"h_{i}"]
         h = _ln(x, blk["ln_1"], dtype)
         qkv = _dense(h, blk["attn"]["qkv"], dtype)
         q, k, v = jnp.split(qkv, 3, axis=-1)
         q, k, v = (_split_heads(t, cfg.n_head) for t in (q, k, v))  # [B, H, Dh]
-        # scores over the cached context plus the current token itself
-        s_ctx = jnp.einsum("bhd,bchd->bhc", q, k_ctx[i]).astype(jnp.float32) * scale
-        s_ctx = jnp.where(ctx_mask[:, None, :], s_ctx, neg)
-        s_self = (q * k).sum(-1).astype(jnp.float32)[..., None] * scale  # [B, H, 1]
-        probs = jax.nn.softmax(jnp.concatenate([s_ctx, s_self], axis=-1), axis=-1)
-        probs = probs.astype(dtype)
-        att = jnp.einsum("bhc,bchd->bhd", probs[..., :-1], v_ctx[i])
-        att = att + probs[..., -1:] * v
-        att = att.reshape(tok.shape[0], cfg.d_model)
+        att = attend(i, q, k, v).reshape(tok.shape[0], cfg.d_model)
         x = x + _dense(att, blk["attn"]["attn_out"], dtype)
         h2 = _ln(x, blk["ln_2"], dtype)
         m = nn.gelu(_dense(h2, blk["mlp"]["mlp_up"], dtype))
@@ -305,6 +292,36 @@ def decode_forward(params, cfg: GPT2Config, tok, pos, k_ctx, v_ctx, ctx_mask):
     x = _ln(x, params["ln_f"], dtype)
     logits = _dense(x, params["lm_head"], dtype)
     return logits, jnp.stack(k_news), jnp.stack(v_news)
+
+
+def decode_forward(params, cfg: GPT2Config, tok, pos, k_ctx, v_ctx, ctx_mask):
+    """One decode step over an externally-gathered contiguous KV context:
+    k_ctx/v_ctx [L, B, C, H, Dh] the per-layer cached keys/values for
+    positions < pos (padded; ctx_mask [B, C] marks real entries).
+    Returns as ``_decode_layers``."""
+    from ray_tpu.ops.attention import reference_decode_attention
+
+    def attend(i, q, k, v):
+        return reference_decode_attention(q, k, v, k_ctx[i], v_ctx[i], ctx_mask)
+
+    return _decode_layers(params, cfg, tok, pos, attend)
+
+
+def decode_forward_paged(params, cfg: GPT2Config, tok, k_pages, v_pages,
+                         block_tables, lengths, block_size: int):
+    """One decode step over a paged KV pool read in place: k_pages/v_pages
+    [L, num_blocks * block_size, H * Dh]; block_tables [B, pages] the
+    physical block of each logical page of a lane (scratch block 0 where
+    it holds none); lengths [B] the cached positions of a lane, which is
+    also the position of its fed token.  Returns as ``_decode_layers``."""
+    from ray_tpu.ops.attention import paged_decode_attention
+
+    def attend(i, q, k, v):
+        return paged_decode_attention(
+            q, k, v, k_pages, v_pages, i, block_tables, lengths, block_size=block_size
+        )
+
+    return _decode_layers(params, cfg, tok, lengths, attend)
 
 
 def sample_logits(logits, rng, temperature, top_k: int = 0):

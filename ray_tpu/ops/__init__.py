@@ -2,12 +2,13 @@
 
 The reference has no equivalent (intra-model compute is delegated to
 torch); here kernels are first-class: attention (XLA reference impl +
-Pallas flash kernel), ring attention for sequence/context parallelism
+Pallas flash kernel; decode attention over the serving engine's paged
+KV pool, read in place), ring attention for sequence/context parallelism
 (reference capability gap called out in SURVEY.md §5), and collective
 helpers.
 """
 
-__all__ = ["attention", "ring_attention", "pallas_attention"]
+__all__ = ["attention", "ring_attention", "pallas_attention", "pallas_paged_attention"]
 
 
 def __getattr__(name):

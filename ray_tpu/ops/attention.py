@@ -10,6 +10,11 @@ placement —
   heads inside a shard_map), else the XLA einsum reference (which XLA
   fuses well on its own).
 
+Decode (one fed token a lane) has its own pair: over a contiguous
+context the einsum reference; over a paged KV pool the Pallas kernel
+that reads pages in place on TPU (ops.pallas_paged_attention), else a
+gather of the lane's pages and the same reference.
+
 All paths: f32 accumulation, bf16 in/out, static shapes.
 """
 
@@ -32,6 +37,53 @@ def reference_causal_attention(q, k, v):
     scores = jnp.where(mask, scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def reference_decode_attention(q, k_self, v_self, k_ctx, v_ctx, ctx_mask):
+    """One fed token a lane over a contiguous context and itself.
+
+    q, k_self, v_self [B, H, Dh]; k_ctx, v_ctx [B, C, H, Dh]; ctx_mask
+    [B, C] marks the cached positions.  Returns [B, H, Dh]; f32 softmax."""
+    scale = 1.0 / (q.shape[-1] ** 0.5)
+    s_ctx = jnp.einsum("bhd,bchd->bhc", q, k_ctx).astype(jnp.float32) * scale
+    s_ctx = jnp.where(ctx_mask[:, None, :], s_ctx, jnp.float32(-1e30))
+    s_self = (q * k_self).sum(-1).astype(jnp.float32)[..., None] * scale  # [B, H, 1]
+    probs = jax.nn.softmax(jnp.concatenate([s_ctx, s_self], axis=-1), axis=-1)
+    probs = probs.astype(q.dtype)
+    att = jnp.einsum("bhc,bchd->bhd", probs[..., :-1], v_ctx)
+    return att + probs[..., -1:] * v_self
+
+
+def paged_decode_attention(q, k_self, v_self, k_pages, v_pages, layer,
+                           block_tables, lengths, *, block_size):
+    """One fed token a lane over the pages it holds of a paged KV pool,
+    layer ``layer``, and itself (its key and value are not in the pool
+    yet).
+
+    q, k_self, v_self [B, H, Dh]; k_pages, v_pages [L, num_blocks *
+    block_size, H * Dh]; block_tables [B, pages] int32, the physical
+    block of each logical page, scratch block 0 where a lane holds none;
+    lengths [B] int32, the cached positions of a lane (0: it attends to
+    itself alone).  Returns [B, H, Dh].
+
+    On a TPU, where the shapes fit its tiling, the Pallas kernel reads
+    the pages where they lie (ops.pallas_paged_attention).  Elsewhere
+    the lane's pages are gathered to a contiguous context first."""
+    B, H, Dh = q.shape
+    if jax.default_backend() == "tpu":  # as _use_pallas: the CPU tests gather
+        from ray_tpu.ops import pallas_paged_attention as kernel
+
+        if kernel.kernel_takes(H, Dh, block_size, k_pages.dtype):
+            return kernel.paged_decode_attention_kernel(
+                q, k_self, v_self, k_pages, v_pages, layer, block_tables, lengths,
+                block_size=block_size,
+            )
+    C = block_tables.shape[1] * block_size
+    idx = (block_tables[:, :, None] * block_size + jnp.arange(block_size)).reshape(B, C)
+    k_ctx = k_pages[layer][idx].reshape(B, C, H, Dh)
+    v_ctx = v_pages[layer][idx].reshape(B, C, H, Dh)
+    mask = jnp.arange(C)[None, :] < lengths[:, None]
+    return reference_decode_attention(q, k_self, v_self, k_ctx, v_ctx, mask)
 
 
 def causal_attention(q, k, v, *, mesh=None, sp_axis: Optional[str] = None):

@@ -13,7 +13,9 @@ Each iteration is a **step boundary**:
    the prompt's K/V straight into its pages and samples the first token
    (TTFT is measured here);
 3. one jitted decode step advances EVERY active lane a token:
-   gather pages -> decode_forward -> scatter new K/V -> sample.
+   decode_forward_paged (attention reads each lane's pages in place,
+   through its block table, for exactly the pages it holds) -> write
+   the new K/V at their slots -> sample.
 
 Tokens stream to per-request asyncio queues; the serve replica's
 ``handle_request_stream`` path turns them into stream items.  The jitted
@@ -78,7 +80,7 @@ ENGINE_SPANS = (
     "engine.prefill.build",  # bucket, pad, phys_indices of one prompt
     "engine.prefill.run",    # executor thread: the prefill jit call (dispatch)
     "engine.prefill.fetch",  # loop thread: int(first_tok), waits for the device
-    "engine.decode.build",   # block tables, mask and phys_indices of all lanes
+    "engine.decode.build",   # block tables and lengths of all lanes
     "engine.decode.run",     # executor thread: the decode jit call (dispatch)
     "engine.decode.fetch",   # loop thread: np.asarray(nxt), waits for the device
     "engine.emit",           # tokens onto the streams, _finish of lanes that end
@@ -132,6 +134,19 @@ class _Request:
     t_enqueue: float = 0.0  # last (re)queue time — the starvation clock
 
 
+def _write_rows(pages, rows, phys):
+    """pages [L, P, H*Dh] with rows [L, T, H, Dh] written at slots phys
+    [T] of every layer, in place when pages is donated.  The pool is
+    addressed as [L * P, H*Dh] so that the scatter indexes its
+    major-most dim: in the 3-D form XLA re-lays the whole pool out to
+    put the slots first and back again, four pool-sized copies a step."""
+    import jax.numpy as jnp
+
+    L, P, D = pages.shape
+    idx = (jnp.arange(L, dtype=phys.dtype)[:, None] * P + phys[None, :]).reshape(-1)
+    return pages.reshape(L * P, D).at[idx].set(rows.reshape(-1, D)).reshape(L, P, D)
+
+
 def prefill_step(cfg, top_k, params, k_pages, v_pages, tokens, phys, last_idx, temp, rng):
     """One prompt into the paged cache.  tokens [1, Tpad]; phys [Tpad]
     (scratch slot 0 at pads); logits taken at the last REAL position,
@@ -139,24 +154,26 @@ def prefill_step(cfg, top_k, params, k_pages, v_pages, tokens, phys, last_idx, t
     from ray_tpu.models import gpt2
 
     logits, k, v = gpt2.prefill_forward(params, cfg, tokens, last_index=last_idx)
-    k_pages = k_pages.at[:, phys].set(k[:, 0])
-    v_pages = v_pages.at[:, phys].set(v[:, 0])
+    k_pages = _write_rows(k_pages, k[:, 0], phys)
+    v_pages = _write_rows(v_pages, v[:, 0], phys)
     first = gpt2.sample_logits(logits, rng, temp, top_k)
     return first[0], k_pages, v_pages
 
 
-def decode_step(cfg, top_k, params, k_pages, v_pages, tok, pos, idx, mask, write_phys, temp, rng):
-    """Gather each lane's context pages, advance one token, write the
-    new K/V back at write_phys (inactive lanes hit slot 0)."""
+def decode_step(cfg, top_k, block_size, params, k_pages, v_pages, tok, lengths,
+                block_tables, write_phys, temp, rng):
+    """Advance every lane one token.  lengths [B] the positions a lane
+    has cached, which is also the fed token's position; block_tables
+    [B, pages] its physical blocks (scratch block 0 beyond them).
+    Attention reads the lane's pages where they lie; the new K/V go
+    back at write_phys (inactive lanes have length 0 and hit slot 0)."""
     from ray_tpu.models import gpt2
 
-    k_ctx = k_pages[:, idx]  # [L, B, C, H, Dh]
-    v_ctx = v_pages[:, idx]
-    logits, k_new, v_new = gpt2.decode_forward(
-        params, cfg, tok, pos, k_ctx, v_ctx, mask
+    logits, k_new, v_new = gpt2.decode_forward_paged(
+        params, cfg, tok, k_pages, v_pages, block_tables, lengths, block_size
     )
-    k_pages = k_pages.at[:, write_phys].set(k_new)
-    v_pages = v_pages.at[:, write_phys].set(v_new)
+    k_pages = _write_rows(k_pages, k_new, write_phys)
+    v_pages = _write_rows(v_pages, v_new, write_phys)
     nxt = gpt2.sample_logits(logits, rng, temp, top_k)
     return nxt, k_pages, v_pages
 
@@ -198,7 +215,8 @@ class LLMEngine:
         self._counts: Dict[str, Any] = {
             "joined": 0, "queue_wait_s": 0.0,
             "prompt_tokens": 0, "prefill_bucket_tokens": 0,
-            # of the positions a decode step gathers, those a lane holds
+            # of the positions a decode step reads (the whole pages its
+            # kernel copies for the lanes in use), those a lane holds
             "kv_positions_attended": 0, "kv_positions_gathered": 0,
             "stalls": 0, "stall_s": 0.0,
         }
@@ -243,11 +261,11 @@ class LLMEngine:
 
         cfg = self.model_cfg
         self.params = gpt2.init_params(cfg, rng=jax.random.PRNGKey(self.config.seed))
-        L, H = cfg.n_layer, cfg.n_head
-        d_head = cfg.d_model // H
-        P = self.bm.num_slots
-        self.k_pages = jnp.zeros((L, P, H, d_head), cfg.dtype)
-        self.v_pages = jnp.zeros((L, P, H, d_head), cfg.dtype)
+        # a position is one row of all heads: a page is then one
+        # contiguous slab, which the decode kernel copies whole
+        pool = (cfg.n_layer, self.bm.num_slots, cfg.d_model)
+        self.k_pages = jnp.zeros(pool, cfg.dtype)
+        self.v_pages = jnp.zeros(pool, cfg.dtype)
         # where the cache lives, reported by stats(): a replica that was
         # meant for the chip and runs on the CPU is then visible
         self._device = next(iter(self.k_pages.devices()))
@@ -268,7 +286,10 @@ class LLMEngine:
         )
         self._decode_jit = _profiling.instrument_jit(
             "serve_decode",
-            jax.jit(functools.partial(decode_step, cfg, top_k), donate_argnums=(1, 2)),
+            jax.jit(
+                functools.partial(decode_step, cfg, top_k, self.config.block_size),
+                donate_argnums=(1, 2),
+            ),
         )
 
     def _next_rng(self):
@@ -781,36 +802,35 @@ class LLMEngine:
     async def _decode_once(self, loop):
         with self._phase("engine.decode.build"):
             B = self.config.max_batch_size
-            C = self.max_ctx
+            bs = self.bm.block_size
             tok = np.zeros(B, dtype=np.int32)
-            pos = np.zeros(B, dtype=np.int32)
-            idx = np.zeros((B, C), dtype=np.int32)
-            mask = np.zeros((B, C), dtype=bool)
+            lengths = np.zeros(B, dtype=np.int32)
+            tables = np.zeros((B, self.bm.blocks_needed(self.max_ctx)), dtype=np.int32)
             write_phys = np.zeros(B, dtype=np.int32)
             temp = np.zeros(B, dtype=np.float32)
             active_lanes = []
-            attended = 0
+            attended = read = 0
             for i, req in enumerate(self.slots):
                 if req is None:
                     continue
                 rid = req.request_id
                 cur_len = self.bm.seq_len(rid)  # positions already in cache
                 tok[i] = req.tokens[-1]
-                pos[i] = cur_len  # the fed token's position
-                idx[i] = self.bm.phys_indices(rid, cur_len, C)
-                mask[i, :cur_len] = True
+                lengths[i] = cur_len  # also the fed token's position
+                tables[i] = self.bm.block_table(rid, tables.shape[1])
                 self.bm.advance(rid, 1)
                 write_phys[i] = self.bm.phys_index(rid, cur_len)
                 temp[i] = req.temperature
                 active_lanes.append(i)
                 attended += cur_len
+                read += -(-cur_len // bs) * bs  # whole pages
             rng = self._next_rng()
 
         def run():
             with self._phase("engine.decode.run"):
                 return self._decode_jit(
                     self.params, self.k_pages, self.v_pages,
-                    tok, pos, idx, mask, write_phys, temp, rng,
+                    tok, lengths, tables, write_phys, temp, rng,
                 )
 
         with self._phase("engine.decode.await", span=False):
@@ -820,8 +840,7 @@ class LLMEngine:
         with self._phase("engine.emit"):
             self.step_count += 1
             self._counts["kv_positions_attended"] += attended
-            # k_pages[:, idx] materialises every lane at full length
-            self._counts["kv_positions_gathered"] += B * C
+            self._counts["kv_positions_gathered"] += read
             now = time.time()
             emitted = 0
             for i in active_lanes:

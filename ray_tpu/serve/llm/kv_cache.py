@@ -2,15 +2,18 @@
 
 The physical storage is two preallocated arrays per deployment —
 ``k_pages``/``v_pages`` of shape ``[n_layer, num_blocks * block_size,
-n_head, d_head]`` held by the engine — and this module owns the
-*logical* side: a fixed pool of fixed-size blocks, a per-sequence block
-table, and the position -> physical-slot mapping the jitted step
-gathers/scatters through.
+n_head * d_head]`` held by the engine, a block one contiguous slab of
+every layer — and this module owns the *logical* side: a fixed pool of
+fixed-size blocks, a per-sequence block table (what the decode step's
+attention walks to read a lane's pages where they lie), and the
+position -> physical-slot mapping prefill and the decode step write
+through.
 
 Invariants (enforced, and what tests/test_serve_llm.py audits):
 
-- block 0 is a reserved scratch block: padded gather lanes read it and
-  inactive decode lanes write it, so it is never allocated to a sequence;
+- block 0 is a reserved scratch block: block tables are padded with it,
+  prefill's pad positions and inactive decode lanes write it, so it is
+  never allocated to a sequence;
 - a sequence's whole need (prompt + max new tokens) is reserved at
   admission — a sequence admitted once can never die of pool exhaustion
   mid-decode;
@@ -115,12 +118,21 @@ class BlockManager:
 
     def phys_indices(self, seq_id: str, upto: int, width: int) -> np.ndarray:
         """Physical slots for positions [0, upto), right-padded with the
-        scratch slot 0 to ``width`` (the jitted gather's static shape)."""
+        scratch slot 0 to ``width`` (the jitted prefill's static shape)."""
         out = np.zeros(width, dtype=np.int32)
         table = self._tables[seq_id]
         bs = self.block_size
         for p in range(min(upto, width)):
             out[p] = table[p // bs] * bs + p % bs
+        return out
+
+    def block_table(self, seq_id: str, width: int) -> np.ndarray:
+        """Physical blocks of seq_id's logical pages, in order,
+        right-padded with the scratch block 0 to ``width`` (the jitted
+        decode step's static shape)."""
+        out = np.zeros(width, dtype=np.int32)
+        table = self._tables[seq_id]
+        out[:len(table)] = table
         return out
 
     def leak_report(self) -> Dict[str, int]:

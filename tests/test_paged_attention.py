@@ -1,0 +1,190 @@
+"""Decode attention over the paged KV pool (ops.attention
+.paged_decode_attention) against the gathered reference: the lane's
+positions gathered from the same pool (``k_pages[layer][idx]``) and
+attended by what ``gpt2.decode_forward`` uses.
+
+Both paths on the CPU: the plain ``jax.numpy`` path the engine takes off
+the TPU, and the Pallas kernel in interpret mode.  Nothing here is a
+time; tests/test_chip_compile.py compiles the kernel for the chip.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops import attention
+from ray_tpu.ops import pallas_paged_attention as kernel
+
+BS = 16  # block size: whole sublane tiles of bf16 and float32
+PAGES = 20  # pages a lane may hold: max_ctx 320, three compute blocks
+LAYERS = 2
+
+# name -> (n_head, d_head, dtype, tolerance against the reference)
+HEADS = {
+    "tiny": (4, 32, jnp.float32, 2e-5),
+    "small": (12, 64, jnp.bfloat16, 2e-2),
+}
+LENGTHS = {
+    "ragged": [5, 200, 17, 300],
+    "a_lane_of_length_0": [0, 150, 0, 7],
+    # a page is 16 positions, a compute block of the kernel 128
+    "page_and_block_boundaries": [15, 16, 17, 127, 128, 129],
+    "a_lane_at_max_ctx_less_1": [PAGES * BS - 1, 1, 64, PAGES * BS - 1],
+}
+
+
+def _paged(q, k, v, kp, vp, layer, tables, lengths, path):
+    if path == "interpret":
+        return kernel.paged_decode_attention_kernel(
+            q, k, v, kp, vp, layer, tables, lengths, block_size=BS, interpret=True)
+    assert jax.default_backend() != "tpu"  # the dispatch takes the plain path here
+    return attention.paged_decode_attention(
+        q, k, v, kp, vp, layer, tables, lengths, block_size=BS)
+
+
+def _tables(lengths, order):
+    """Block tables handing out the blocks of ``order`` lane by lane,
+    padded with the scratch block 0."""
+    tables = np.zeros((len(lengths), PAGES), np.int32)
+    taken = 0
+    for b, n in enumerate(lengths):
+        need = -(-n // BS)
+        tables[b, :need] = order[taken:taken + need]
+        taken += need
+    return tables
+
+
+def _case(heads, lengths, seed=0):
+    H, Dh, dtype, tol = HEADS[heads]
+    rng = np.random.default_rng(seed)
+    B = len(lengths)
+    slots = (B * PAGES + 1) * BS
+    kp, vp = (jnp.asarray(rng.standard_normal((LAYERS, slots, H * Dh)), dtype) for _ in range(2))
+    q, k, v = (jnp.asarray(rng.standard_normal((B, H, Dh)), dtype) for _ in range(3))
+    return q, k, v, kp, vp, tol
+
+
+def _slots(tables, lengths, width):
+    """Position by position through the block table, as the engine's
+    ``phys_indices`` gather did: the slot of each cached position
+    (scratch slot 0 beyond a lane's length) and the mask of the cached."""
+    idx = np.zeros((len(lengths), width), np.int32)
+    mask = np.zeros((len(lengths), width), bool)
+    for b, n in enumerate(lengths):
+        for p in range(n):
+            idx[b, p] = tables[b, p // BS] * BS + p % BS
+        mask[b, :n] = True
+    return idx, mask
+
+
+def _gathered_reference(q, k, v, kp, vp, layer, tables, lengths):
+    """The lane's positions gathered to a contiguous context, then the
+    contiguous-context attention."""
+    B, H, Dh = q.shape
+    C = PAGES * BS
+    idx, mask = _slots(tables, lengths, C)
+    k_ctx = kp[:, idx][layer].reshape(B, C, H, Dh)
+    v_ctx = vp[:, idx][layer].reshape(B, C, H, Dh)
+    return attention.reference_decode_attention(q, k, v, k_ctx, v_ctx, jnp.asarray(mask))
+
+
+@pytest.mark.parametrize("path", ["plain", "interpret"])
+@pytest.mark.parametrize("heads", list(HEADS))
+@pytest.mark.parametrize("lengths", list(LENGTHS))
+def test_paged_attention_matches_gathered_reference(lengths, heads, path):
+    lens = LENGTHS[lengths]
+    q, k, v, kp, vp, tol = _case(heads, lens)
+    order = np.random.default_rng(1).permutation(np.arange(1, len(lens) * PAGES + 1))
+    tables = _tables(lens, order)
+    layer = 1
+    out = _paged(q, k, v, kp, vp, layer, jnp.asarray(tables), jnp.asarray(lens, jnp.int32), path)
+    ref = _gathered_reference(q, k, v, kp, vp, layer, tables, lens)
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    assert out.shape == ref.shape and np.isfinite(out).all()
+    np.testing.assert_allclose(out, ref, atol=tol, rtol=tol)
+    for b, n in enumerate(lens):
+        if n == 0:  # nothing cached: the fed token attends to itself alone
+            np.testing.assert_array_equal(out[b], np.asarray(v[b], np.float32))
+
+
+@pytest.mark.parametrize("path", ["plain", "interpret"])
+@pytest.mark.parametrize("heads", list(HEADS))
+def test_same_sequences_on_other_physical_pages_bit_identical(heads, path):
+    """The order of summation depends on positions only: the same
+    logical sequences on permuted pages, interleaved between lanes,
+    give the same bits."""
+    lens = [70, 130, 0, 33, 250]
+    q, k, v, kp, vp, _ = _case(heads, lens, seed=3)
+    nblocks = len(lens) * PAGES
+    in_order = _tables(lens, np.arange(1, nblocks + 1))
+    # lane b takes every 5th block of a shuffled pool: pages of
+    # different lanes alternate in memory
+    shuffled = np.random.default_rng(4).permutation(np.arange(1, nblocks + 1))
+    moved = np.zeros_like(in_order)
+    for b, n in enumerate(lens):
+        need = -(-n // BS)
+        moved[b, :need] = shuffled[b::len(lens)][:need]
+    # carry each page's rows to where the second table puts them
+    kp2, vp2 = np.array(kp), np.array(vp)
+    for b, n in enumerate(lens):
+        for j in range(-(-n // BS)):
+            src, dst = in_order[b, j] * BS, moved[b, j] * BS
+            kp2[:, dst:dst + BS] = np.asarray(kp)[:, src:src + BS]
+            vp2[:, dst:dst + BS] = np.asarray(vp)[:, src:src + BS]
+    lengths = jnp.asarray(lens, jnp.int32)
+    a = _paged(q, k, v, kp, vp, 0, jnp.asarray(in_order), lengths, path)
+    b = _paged(q, k, v, jnp.asarray(kp2), jnp.asarray(vp2), 0, jnp.asarray(moved), lengths, path)
+    np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
+
+
+@pytest.mark.parametrize("path", ["plain", "interpret"])
+def test_decode_forward_paged_matches_decode_forward(path, monkeypatch):
+    """The whole decode step of the tiny model: logits and new K/V over
+    the pool read in place against ``decode_forward`` over the same
+    pool gathered to a contiguous context."""
+    from ray_tpu.models import gpt2
+
+    if path == "interpret":
+        monkeypatch.setattr(
+            attention, "paged_decode_attention",
+            lambda *a, block_size: kernel.paged_decode_attention_kernel(
+                *a, block_size=block_size, interpret=True),
+        )
+    cfg = gpt2.GPT2Config.tiny(dtype=jnp.float32)
+    params = gpt2.init_params(cfg, rng=jax.random.PRNGKey(0))
+    lens = [37, 0, 120, 16]
+    B, pages = len(lens), cfg.max_seq_len // BS
+    rng = np.random.default_rng(5)
+    slots = (B * pages + 1) * BS
+    kp, vp = (jnp.asarray(rng.standard_normal((cfg.n_layer, slots, cfg.d_model)), cfg.dtype)
+              for _ in range(2))
+    tables = np.zeros((B, pages), np.int32)
+    order = rng.permutation(np.arange(1, B * pages + 1))
+    for b in range(B):
+        tables[b] = order[b * pages:(b + 1) * pages]
+    idx, mask = _slots(tables, lens, cfg.max_seq_len)
+    tok = jnp.asarray(rng.integers(0, cfg.vocab_size, B), jnp.int32)
+    pos = jnp.asarray(lens, jnp.int32)
+    d_head = cfg.d_model // cfg.n_head
+    ctx = (cfg.n_layer, B, cfg.max_seq_len, cfg.n_head, d_head)
+    want = gpt2.decode_forward(
+        params, cfg, tok, pos, kp[:, idx].reshape(ctx), vp[:, idx].reshape(ctx), jnp.asarray(mask))
+    got = gpt2.decode_forward_paged(params, cfg, tok, kp, vp, jnp.asarray(tables), pos, BS)
+    for w, g in zip(want, got):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=2e-4, rtol=2e-4)
+    assert np.array_equal(np.argmax(got[0], -1), np.argmax(want[0], -1))
+
+
+@pytest.mark.parametrize("n_head, d_head, block_size, dtype, takes", [
+    (20, 64, 16, jnp.bfloat16, True),    # GPT-2-large in the serve cells
+    (12, 64, 16, jnp.bfloat16, True),
+    (4, 32, 16, jnp.float32, True),
+    (4, 32, 8, jnp.float32, True),       # a page is one float32 sublane tile
+    (4, 32, 8, jnp.bfloat16, False),     # half a bf16 tile: the plain path
+    (4, 32, 4, jnp.float32, False),
+    (4, 32, 48, jnp.bfloat16, False),    # a compute block would split a page
+    (3, 32, 16, jnp.bfloat16, False),    # a row of all heads is not whole lanes
+])
+def test_kernel_takes_only_shapes_its_tiling_can(n_head, d_head, block_size, dtype, takes):
+    assert kernel.kernel_takes(n_head, d_head, block_size, dtype) is takes

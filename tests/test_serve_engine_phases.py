@@ -79,11 +79,14 @@ def test_counts_are_conserved_over_a_fixed_batch(batch_run):
     assert st["prefill_bucket_tokens"] == sum(
         LLMEngine._prefill_bucket(n, eng.max_ctx) for n, _ in BATCH)
     assert st["steps"] > 0
-    assert st["kv_positions_gathered"] == st["steps"] * st["max_batch_size"] * eng.max_ctx
     # a request's decode step j (of m - 1) attends to its n prompt
     # positions and the j tokens written before it
     assert st["kv_positions_attended"] == sum(
         (m - 1) * n + (m - 1) * (m - 2) // 2 for n, m in BATCH)
+    # and reads the whole pages that hold them
+    bs = eng.bm.block_size
+    assert st["kv_positions_gathered"] == sum(
+        -(-(n + j) // bs) * bs for n, m in BATCH for j in range(m - 1))
     assert st["total_tokens"] == sum(m for _, m in BATCH)
     assert st["queue_wait_s"] > 0  # three of seven waited for a lane at least
     assert st["kv_blocks_in_use"] == 0

@@ -80,6 +80,21 @@ def test_block_manager_phys_indices_padding():
     assert idx[1] == idx[0] + 1
 
 
+def test_block_manager_block_table_padding():
+    bm = BlockManager(num_blocks=8, block_size=4)
+    bm.allocate("a", 3)
+    bm.allocate("s", 10)  # 3 blocks, reserved whole at admission
+    bm.advance("s", 6)
+    table = bm.block_table("s", 5)
+    assert table.dtype.name == "int32" and table.shape == (5,)
+    assert list(table[3:]) == [0, 0]  # padded with the scratch block
+    assert 0 not in table[:3] and len(set(table[:3])) == 3
+    # the table and the position -> slot mapping agree on every position
+    for pos in range(6):
+        assert bm.phys_index("s", pos) == table[pos // 4] * 4 + pos % 4
+    assert not set(table[:3]) & set(bm.block_table("a", 5)[:1])
+
+
 # ----------------------------------------------------------------------
 # engine: generation, parity, continuous batching, cancel, shed
 # ----------------------------------------------------------------------
