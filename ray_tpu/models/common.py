@@ -1,9 +1,10 @@
-"""Shared training scaffolding for the model families.
+"""Shared scaffolding for the model families.
 
 One copy of the sharded-init / train-step recipe (Megatron layouts from
 parallel.sharding, donated state, explicit batch placement) that
 gpt2.py and llama.py both build on — the models differ in architecture,
-not in how they train.
+not in how they train — and of the sampler the serving engine applies
+to whatever family's logits (``sample_logits``).
 """
 
 from __future__ import annotations
@@ -93,3 +94,21 @@ def make_sharded_train_step(step_fn: Callable, mesh):
 
 def num_params(params) -> int:
     return int(sum(np.prod(p.shape) for p in jax.tree_util.tree_leaves(params)))
+
+
+def sample_logits(logits, rng, temperature, top_k: int = 0):
+    """Per-sequence sampling: temperature <= 0 means greedy (argmax);
+    otherwise softmax sampling at that temperature, optionally truncated
+    to the top_k highest-probability tokens (static; 0 = off).
+
+    logits [B, V], temperature [B] -> token ids [B] (int32).
+    """
+    logits = logits.astype(jnp.float32)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    temp = jnp.maximum(temperature, 1e-6)[:, None]
+    scaled = logits / temp
+    if top_k and top_k > 0 and top_k < logits.shape[-1]:
+        kth = jnp.sort(scaled, axis=-1)[:, -top_k][:, None]
+        scaled = jnp.where(scaled < kth, -1e30, scaled)
+    sampled = jax.random.categorical(rng, scaled, axis=-1).astype(jnp.int32)
+    return jnp.where(temperature > 0.0, sampled, greedy)

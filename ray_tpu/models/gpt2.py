@@ -324,24 +324,6 @@ def decode_forward_paged(params, cfg: GPT2Config, tok, k_pages, v_pages,
     return _decode_layers(params, cfg, tok, lengths, attend)
 
 
-def sample_logits(logits, rng, temperature, top_k: int = 0):
-    """Per-sequence sampling: temperature <= 0 means greedy (argmax);
-    otherwise softmax sampling at that temperature, optionally truncated
-    to the top_k highest-probability tokens (static; 0 = off).
-
-    logits [B, V], temperature [B] -> token ids [B] (int32).
-    """
-    logits = logits.astype(jnp.float32)
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    temp = jnp.maximum(temperature, 1e-6)[:, None]
-    scaled = logits / temp
-    if top_k and top_k > 0 and top_k < logits.shape[-1]:
-        kth = jnp.sort(scaled, axis=-1)[:, -top_k][:, None]
-        scaled = jnp.where(scaled < kth, -1e30, scaled)
-    sampled = jax.random.categorical(rng, scaled, axis=-1).astype(jnp.int32)
-    return jnp.where(temperature > 0.0, sampled, greedy)
-
-
 def generate_greedy(params, cfg: GPT2Config, tokens, n_new: int):
     """Reference full-forward greedy generation (no KV cache): re-runs
     the Flax model over the growing sequence.  O(T^2) per token — test

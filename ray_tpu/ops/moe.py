@@ -1,0 +1,114 @@
+"""Sparse experts without drops: sort, one grouped matmul, weighted sum.
+
+A token goes to ``k`` of ``E`` experts.  ``moe_experts`` sorts the
+``tokens * k`` (token, expert) pairs by expert, gathers the tokens' rows
+in that order, multiplies each group of rows by its expert's weights in
+ONE grouped matmul over the sorted rows (gate and up side by side, then
+down), scales each row by the token's weight for that expert, and sums
+a token's ``k`` rows back.  Every pair is computed: there is no
+capacity, no token is dropped, and nothing of shape ``[tokens, experts,
+...]`` is built (``models/moe.py``'s one-hot dispatch does both).
+
+On a TPU the grouped matmul is the megablox Pallas kernel
+(``jax.experimental.pallas.ops.tpu.megablox``): it visits only the
+(row tile, expert) pairs that exist and reads an expert's weights once
+for each row tile that touches it, so a decode step of 256 rows over 64
+experts reads each expert that was hit about once, and a long prefill
+once for every 256 rows or so.  It runs under the name ``moe_gmm`` (the device trace
+shows ``moe_gmm tpu_custom_call``).  Elsewhere ``jax.lax.ragged_dot``
+computes the same products; the CPU tests take that path, as
+``ops.attention.paged_decode_attention`` does with its gather.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+# Rows a tile of the kernel covers.  A visit multiplies a WHOLE tile by
+# the expert's weights, so a tile of tm rows over groups of g does
+# tm / g times the needed operations; an expert whose rows straddle two
+# tiles has its weights read twice, so rows / tm + E - 1 visits read up
+# to that many experts' weights.  Few large tiles read least, many small
+# ones compute least.  On a v5e chip at OLMoE's widths (ms a layer, gate
+# and up then down, PR 26): 256 rows 1.11 at 64; 4,096 rows 1.63 / 1.47
+# / 2.57 at 128 / 256 / 512; 16,384 rows 3.55 / 2.79 / 3.67.
+_TILE_ROWS = (64, 256)
+# k and n extents of a weight tile: 4 MB of bf16, double-buffered (1.11
+# against 1.14 ms for 2 MB tiles at 256 rows, 1.47 against 1.59 at 4,096)
+_TILE_K, _TILE_N = 2048, 1024
+
+
+def _tile_rows(m: int) -> int:
+    """Rows a tile: an eighth of the rows, within _TILE_ROWS, a power
+    of two (rows are padded to whole tiles)."""
+    lo, hi = _TILE_ROWS
+    tm = lo
+    while tm < hi and tm * 8 < m:
+        tm *= 2
+    return tm
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "tiling"))
+def moe_gmm(rows, weights, group_sizes, *, interpret=False, tiling=None):
+    """rows [m, k] sorted by group, weights [groups, k, n], group_sizes
+    [groups] int32 summing to m -> [m, n] in rows' dtype: each group's
+    rows times its own weights, float32 accumulation.  The megablox
+    kernel under this function's name; `tiling` (tm, tk, tn) is for
+    tests and tuning."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    m, k = rows.shape
+    n = weights.shape[2]
+    tm, tk, tn = tiling or (_tile_rows(m), min(_TILE_K, k), min(_TILE_N, n))
+    pad = -m % tm  # the kernel takes whole row tiles; the pad belongs to no group
+    if pad:
+        rows = jnp.pad(rows, ((0, pad), (0, 0)))
+    # the undecorated function: its pallas_call takes the name of the
+    # innermost jit around it, which is this one
+    out = gmm.__wrapped__(
+        rows, weights, group_sizes, preferred_element_type=rows.dtype,
+        tiling=(tm, tk, tn), interpret=interpret,
+    )
+    return out[:m] if pad else out
+
+
+def grouped_matmul(rows, weights, group_sizes):
+    """As ``moe_gmm``, by the backend: the Pallas kernel on a TPU,
+    ``jax.lax.ragged_dot`` elsewhere."""
+    if jax.default_backend() == "tpu":
+        return moe_gmm(rows, weights, group_sizes)
+    return jax.lax.ragged_dot(rows, weights, group_sizes).astype(rows.dtype)
+
+
+def moe_experts(h, top_p, top_e, wgu, wd):
+    """The expert layer of a token batch.
+
+    h [T, d] the tokens; top_p [T, k] float32 and top_e [T, k] int32 a
+    token's weights and experts; wgu [E, d, 2 * f] every expert's gate
+    and up projections side by side; wd [E, f, d] its down projection.
+    Returns (y [T, d] in h's dtype: ``sum_k p * (silu(h Wg) * (h Wu)) Wd``
+    over a token's k experts; counters int32 [3]: the pairs computed
+    (rows of the second grouped matmul's output that are not all zero:
+    T * k unless a pair was dropped or the kernel skipped a row), the
+    experts that received at least one row, and the rows of the largest
+    group)."""
+    T, d = h.shape
+    k = top_e.shape[1]
+    E = wgu.shape[0]
+    with jax.named_scope("moe.route"):
+        expert = top_e.reshape(T * k)
+        order = jnp.argsort(expert, stable=True)  # pairs by expert, tokens in order within one
+        group_sizes = jnp.bincount(expert, length=E).astype(jnp.int32)
+        rows = h[order // k]
+    with jax.named_scope("moe.experts"):
+        gate, up = jnp.split(grouped_matmul(rows, wgu, group_sizes), 2, axis=-1)
+        out = grouped_matmul(jax.nn.silu(gate) * up, wd, group_sizes)
+    with jax.named_scope("moe.combine"):
+        computed = (out != 0).any(axis=-1).sum(dtype=jnp.int32)
+        out = out.astype(jnp.float32) * top_p.reshape(T * k)[order][:, None]
+        back = jnp.zeros(T * k, order.dtype).at[order].set(jnp.arange(T * k, dtype=order.dtype))
+        y = out[back].reshape(T, k, d).sum(axis=1).astype(h.dtype)
+    return y, jnp.stack([computed, (group_sizes > 0).sum(dtype=jnp.int32), group_sizes.max()])

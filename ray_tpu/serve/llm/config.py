@@ -4,8 +4,33 @@ LLMConfig, scaled down to the knobs this engine actually has)."""
 from __future__ import annotations
 
 import dataclasses
+import importlib
 from dataclasses import dataclass, field
 from typing import Any, Dict, Optional
+
+# The model families the engine serves, one row each: the module that
+# gives the engine its forwards (docs/serving.md "Model families"), that
+# module's config class, and the class's presets ``LLMConfig.model`` may
+# name.  A family is imported only when one of its presets is chosen.
+MODEL_FAMILIES = (
+    ("ray_tpu.models.gpt2", "GPT2Config", ("tiny", "small", "medium", "large")),
+    ("ray_tpu.models.olmoe", "OlmoeConfig", ("olmoe_tiny", "olmoe_1b_7b", "olmoe_1b_7b_12l")),
+)
+
+
+def model_family(cfg):
+    """The family module of a model config: the row of MODEL_FAMILIES
+    that names its class.  A family gives the engine ``init_params(cfg,
+    rng)``, ``prefill_forward(params, cfg, tokens, last_index)`` and
+    ``decode_forward_paged(params, cfg, tok, k_pages, v_pages,
+    block_tables, lengths, block_size)``, both returning (logits, k, v)
+    and optionally a small int32 vector of counters, named by the
+    module's ``COUNTERS``; its config has ``n_layer``, ``d_model``,
+    ``n_head``, ``max_seq_len``, ``vocab_size`` and ``dtype``."""
+    for module, cls, _ in MODEL_FAMILIES:
+        if type(cfg).__name__ == cls:
+            return importlib.import_module(module)
+    raise TypeError(f"{type(cfg).__name__} is the config of no model family in MODEL_FAMILIES")
 
 
 def tokenize_prompt(prompt: Any, vocab_size: int) -> list:
@@ -34,7 +59,9 @@ class LLMConfig:
     """
 
     # model
-    model: str = "tiny"  # GPT2Config preset: tiny | small | medium | large
+    # a preset of a model family: GPT2Config's tiny | small | medium |
+    # large, OlmoeConfig's olmoe_tiny | olmoe_1b_7b | olmoe_1b_7b_12l
+    model: str = "tiny"
     seed: int = 0  # synthetic-weights init seed (no checkpoint loading yet)
     dtype: str = "float32"  # serving compute dtype ("bfloat16" on TPU)
 
@@ -86,17 +113,18 @@ class LLMConfig:
         raise TypeError(f"llm_config must be LLMConfig or dict, got {type(value)}")
 
     def model_config(self):
-        """Resolve the GPT2Config preset with the serving dtype."""
+        """Resolve the preset with the serving dtype: the config of the
+        family whose row of MODEL_FAMILIES lists the name (only that
+        family is imported)."""
         import jax.numpy as jnp
 
-        from ray_tpu.models.gpt2 import GPT2Config
-
-        preset = getattr(GPT2Config, self.model, None)
-        if preset is None or self.model.startswith("_"):
-            raise ValueError(
-                f"unknown model preset {self.model!r} "
-                "(expected tiny | small | medium | large)"
-            )
+        for module, cls, presets in MODEL_FAMILIES:
+            if self.model in presets:
+                preset = getattr(getattr(importlib.import_module(module), cls), self.model)
+                break
+        else:
+            known = " | ".join(name for *_, presets in MODEL_FAMILIES for name in presets)
+            raise ValueError(f"unknown model preset {self.model!r} (expected {known})")
         dtype = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}.get(self.dtype)
         if dtype is None:
             raise ValueError(f"unsupported serving dtype {self.dtype!r}")

@@ -58,7 +58,7 @@ import numpy as np
 
 from ray_tpu._private import tenants as tenants_mod
 from ray_tpu.serve.exceptions import RequestShedError
-from ray_tpu.serve.llm.config import LLMConfig
+from ray_tpu.serve.llm.config import LLMConfig, model_family
 from ray_tpu.serve.llm.kv_cache import BlockManager
 from ray_tpu.serve.llm.overload import (
     DegradationController,
@@ -79,7 +79,7 @@ ENGINE_SPANS = (
     "engine.admit",          # _reap, _maybe_preempt, _next_admissible
     "engine.prefill.build",  # bucket, pad, phys_indices of one prompt
     "engine.prefill.run",    # executor thread: the prefill jit call (dispatch)
-    "engine.prefill.fetch",  # loop thread: int(first_tok), waits for the device
+    "engine.prefill.fetch",  # loop thread: the first token to the host, waits for the device
     "engine.decode.build",   # block tables and lengths of all lanes
     "engine.decode.run",     # executor thread: the decode jit call (dispatch)
     "engine.decode.fetch",   # loop thread: np.asarray(nxt), waits for the device
@@ -150,14 +150,13 @@ def _write_rows(pages, rows, phys):
 def prefill_step(cfg, top_k, params, k_pages, v_pages, tokens, phys, last_idx, temp, rng):
     """One prompt into the paged cache.  tokens [1, Tpad]; phys [Tpad]
     (scratch slot 0 at pads); logits taken at the last REAL position,
-    not the pad tail."""
-    from ray_tpu.models import gpt2
-
-    logits, k, v = gpt2.prefill_forward(params, cfg, tokens, last_index=last_idx)
+    not the pad tail.  Returns the first token, then the family's
+    counters if it has any, in one array: one fetch brings both."""
+    logits, k, v, *counters = model_family(cfg).prefill_forward(params, cfg, tokens, last_index=last_idx)
     k_pages = _write_rows(k_pages, k[:, 0], phys)
     v_pages = _write_rows(v_pages, v[:, 0], phys)
-    first = gpt2.sample_logits(logits, rng, temp, top_k)
-    return first[0], k_pages, v_pages
+    first = _sample(logits, rng, temp, top_k, counters)
+    return first if counters else first[0], k_pages, v_pages
 
 
 def decode_step(cfg, top_k, block_size, params, k_pages, v_pages, tok, lengths,
@@ -166,16 +165,25 @@ def decode_step(cfg, top_k, block_size, params, k_pages, v_pages, tok, lengths,
     has cached, which is also the fed token's position; block_tables
     [B, pages] its physical blocks (scratch block 0 beyond them).
     Attention reads the lane's pages where they lie; the new K/V go
-    back at write_phys (inactive lanes have length 0 and hit slot 0)."""
-    from ray_tpu.models import gpt2
-
-    logits, k_new, v_new = gpt2.decode_forward_paged(
+    back at write_phys (inactive lanes have length 0 and hit slot 0).
+    Returns the lanes' tokens, then the family's counters if any."""
+    logits, k_new, v_new, *counters = model_family(cfg).decode_forward_paged(
         params, cfg, tok, k_pages, v_pages, block_tables, lengths, block_size
     )
     k_pages = _write_rows(k_pages, k_new, write_phys)
     v_pages = _write_rows(v_pages, v_new, write_phys)
-    nxt = gpt2.sample_logits(logits, rng, temp, top_k)
-    return nxt, k_pages, v_pages
+    return _sample(logits, rng, temp, top_k, counters), k_pages, v_pages
+
+
+def _sample(logits, rng, temp, top_k, counters):
+    """The sampled tokens [B], followed by the forward's counters where
+    it returned any: the one array a step's one fetch brings back."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models.common import sample_logits
+
+    tokens = sample_logits(logits, rng, temp, top_k)
+    return jnp.concatenate([tokens, *counters]) if counters else tokens
 
 
 class LLMEngine:
@@ -219,6 +227,7 @@ class LLMEngine:
             # kernel copies for the lanes in use), those a lane holds
             "kv_positions_attended": 0, "kv_positions_gathered": 0,
             "stalls": 0, "stall_s": 0.0,
+            **dict.fromkeys(self._counter_names, 0),
         }
         # where the current slice of the loop began (_note_stall)
         self._slice_t0 = time.perf_counter()
@@ -257,10 +266,11 @@ class LLMEngine:
 
         import jax.numpy as jnp
 
-        from ray_tpu.models import gpt2
-
         cfg = self.model_cfg
-        self.params = gpt2.init_params(cfg, rng=jax.random.PRNGKey(self.config.seed))
+        family = model_family(cfg)
+        self.params = family.init_params(cfg, rng=jax.random.PRNGKey(self.config.seed))
+        # what the family's programs count and return after their tokens
+        self._counter_names = tuple(getattr(family, "COUNTERS", ()))
         # a position is one row of all heads: a page is then one
         # contiguous slab, which the decode kernel copies whole
         pool = (cfg.n_layer, self.bm.num_slots, cfg.d_model)
@@ -789,8 +799,9 @@ class LLMEngine:
         with self._phase("engine.prefill.await", span=False):
             first_tok, self.k_pages, self.v_pages = await loop.run_in_executor(None, run)
         with self._phase("engine.prefill.fetch"):
-            tok = int(first_tok)
+            tok, *counted = np.asarray(first_tok).reshape(-1).tolist()
         with self._phase("engine.emit"):
+            self._count_program(counted)
             self._counts["prompt_tokens"] += n
             self._counts["prefill_bucket_tokens"] += bucket
             self._emit(req, tok)
@@ -839,6 +850,7 @@ class LLMEngine:
             nxt = np.asarray(nxt)
         with self._phase("engine.emit"):
             self.step_count += 1
+            self._count_program(nxt[B:].tolist())
             self._counts["kv_positions_attended"] += attended
             self._counts["kv_positions_gathered"] += read
             now = time.time()
@@ -857,6 +869,12 @@ class LLMEngine:
                 self._tok_window.append((now, emitted))
 
     # -- bookkeeping -----------------------------------------------------
+    def _count_program(self, counted):
+        """Add what one program counted (the family's COUNTERS, fetched
+        with its tokens) to stats()."""
+        for name, n in zip(self._counter_names, counted):
+            self._counts[name] += n
+
     def _emit(self, req: _Request, token: int, now: Optional[float] = None):
         req.tokens.append(token)
         req.generated += 1

@@ -145,3 +145,56 @@ def test_engine_decode_step_compiles_for_v5e(one_chip, monkeypatch):
     # temporaries this program had before it read pages in place; what
     # is left is 3.3 MB of activations and casts.
     assert mem.temp_size_in_bytes < B * C * cfg.d_model * 2
+
+
+def test_olmoe_decode_step_compiles_for_v5e(one_chip, monkeypatch):
+    """The same decode step on the OLMoE family at its published widths
+    (d 2048, 16 heads of 128, 64 experts of width 1024, 8 a token,
+    vocabulary 50,304), 32 lanes over 4096 positions, depth cut to two
+    layers: the paged kernel takes a 2048-wide row, the experts are the
+    grouped-matmul kernel, and the routing builds nothing of
+    [pairs, experts] size."""
+    from ray_tpu.models import olmoe
+    from ray_tpu.serve.llm.engine import decode_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = olmoe.OlmoeConfig.olmoe_1b_7b(n_layer=2)
+    B, C, block = 32, cfg.max_seq_len, 16
+    slots = 32 * 1024 + block
+
+    def shaped(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree
+        )
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = shaped(jax.eval_shape(lambda: olmoe.init_params(cfg)))
+    pages = arr((cfg.n_layer, slots, cfg.d_model), cfg.dtype)
+    key = shaped(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    compiled = jax.jit(
+        lambda *a: decode_step(cfg, 0, block, *a), donate_argnums=(1, 2)
+    ).lower(
+        params, pages, pages, arr((B,), jnp.int32), arr((B,), jnp.int32),
+        arr((B, C // block), jnp.int32), arr((B,), jnp.int32),
+        arr((B,), jnp.float32), key,
+    ).compile()
+    text = compiled.as_text()
+    calls = [ln.split(" = ")[0].split("%")[-1] for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    # a layer: one paged-attention call, and two grouped matmuls (gate
+    # and up side by side, then down)
+    assert sum(c.startswith("paged_decode_attention") for c in calls) == cfg.n_layer
+    assert sum(c.startswith("moe_gmm") for c in calls) == 2 * cfg.n_layer
+    assert len(calls) == 3 * cfg.n_layer
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 * 2**30
+    # 256 pairs over 64 experts: a [pairs, experts, d] dispatch tensor
+    # would be 256 * 64 * 2048 * 2 B = 67 MB; the whole program's
+    # temporaries (rows in and out of the experts, logits) stay under a
+    # quarter of that
+    pairs = B * cfg.num_experts_per_tok
+    assert mem.temp_size_in_bytes < pairs * cfg.num_experts * cfg.d_model * 2 // 4
+    # the program returns its tokens and five counters in one array
+    assert f"s32[{B + len(olmoe.COUNTERS)}]" in text
