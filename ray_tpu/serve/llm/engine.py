@@ -3,24 +3,24 @@
 runtime; PAPERS.md "Fine-Tuning and Serving Gemma 4 31B on Google Cloud
 TPU" for the TPU-native decode shape).
 
-Execution model: one asyncio loop task per engine ("the step loop").
-Each iteration is a **step boundary**:
+Execution model: one asyncio loop task per engine ("the step loop"),
+which keeps one decode program in flight.  Each iteration:
 
 1. cancelled sequences leave the batch and free their KV blocks;
 2. waiting requests join free decode lanes (admission reserved their
    whole KV need up front, so a joined request can never die of pool
-   exhaustion) — each join runs a bucketed, jitted prefill that writes
-   the prompt's K/V straight into its pages and samples the first token
-   (TTFT is measured here);
-3. one jitted decode step advances EVERY active lane a token:
-   decode_forward_paged (attention reads each lane's pages in place,
-   through its block table, for exactly the pages it holds) -> write
-   the new K/V at their slots -> sample.
+   exhaustion): each join dispatches a bucketed, jitted prefill that
+   writes the prompt's K/V straight into its pages and samples the
+   first token (TTFT is measured where that token is fetched);
+3. one jitted decode step for EVERY lane with tokens left is dispatched
+   on the tokens of the step before it, which never leave the device,
+   and only then are the programs dispatched before it fetched, in
+   order, and their tokens emitted (docs/serving.md "What a step is made of").
 
 Tokens stream to per-request asyncio queues; the serve replica's
-``handle_request_stream`` path turns them into stream items.  The jitted
-compute runs in the default executor so the replica's event loop (joins,
-stream consumption, stats) stays responsive during a step.
+``handle_request_stream`` path turns them into stream items.  The jit
+calls run in the default executor so the replica's event loop (joins,
+stream consumption, stats) stays responsive while one compiles.
 
 Request spans (``serve.request`` -> ``serve.queue`` / ``serve.prefill``
 / ``serve.decode``) are recorded per request so ``state.traces()``
@@ -76,13 +76,13 @@ FINISHED = object()
 # seconds to ``stats()["<phase>_s"]`` (the name less "engine.", dots to
 # underscores).  Each encloses synchronous code only.
 ENGINE_SPANS = (
-    "engine.admit",          # _reap, _maybe_preempt, _next_admissible
+    "engine.admit",          # _reap, _preempt_victim, _next_admissible, the lanes of a step
     "engine.prefill.build",  # bucket, pad, phys_indices of one prompt
     "engine.prefill.run",    # executor thread: the prefill jit call (dispatch)
-    "engine.prefill.fetch",  # loop thread: the first token to the host, waits for the device
+    "engine.prefill.fetch",  # loop thread: a prefill in flight: its first token to the host
     "engine.decode.build",   # block tables and lengths of all lanes
     "engine.decode.run",     # executor thread: the decode jit call (dispatch)
-    "engine.decode.fetch",   # loop thread: np.asarray(nxt), waits for the device
+    "engine.decode.fetch",   # loop thread: waiting for the step in flight, np.asarray(nxt)
     "engine.emit",           # tokens onto the streams, _finish of lanes that end
     "engine.metrics",        # _push_metrics, report_device_memory in it
 )
@@ -96,11 +96,10 @@ _LOOP_WAITS = (
     "engine.yield",          # sleep(0): what the loop's other callbacks took
     "engine.idle",           # nothing to run: waiting on _wake or the 5 ms retry
 )
-# A slice of the loop (from the end of one prefill or iteration to the
-# end of the next, so one device program and the host work around it)
-# that takes longer than this outside ``engine.idle`` is a stall:
-# counted, and logged with its milliseconds by phase.  Eight decode
-# steps of GPT-2-large on a v5e chip.
+# A slice of the loop (from one dispatched prefill, fetched program or
+# iteration's end to the next: one device program, the host work around
+# it) that takes longer than this outside ``engine.idle`` is a stall:
+# counted, and logged with its milliseconds by phase.
 STALL_S = 1.0
 
 
@@ -116,6 +115,7 @@ class _Request:
     trace: tuple = ()
     slot: int = -1
     generated: int = 0
+    dispatched: int = 0  # tokens whose programs were dispatched: generated + what is in flight
     finish_reason: str = ""
     cancelled: bool = False
     t_join: float = 0.0
@@ -186,6 +186,17 @@ def _sample(logits, rng, temp, top_k, counters):
     return jnp.concatenate([tokens, *counters]) if counters else tokens
 
 
+@dataclass
+class _InFlight:
+    """A program dispatched and not yet fetched.  The device runs
+    programs in the order they were dispatched (they chain through the
+    donated pool), so these are fetched in that order too."""
+    out: Any  # device array: the tokens, then the family's counters
+    lanes: List[tuple]  # (index of its token in ``out``, request)
+    counts: Dict[str, int]  # added to stats() when it is fetched
+    decode: bool = True  # a decode step, or one request's prefill
+
+
 class LLMEngine:
     """One engine per replica; owns the model params, the paged KV cache,
     and the continuous-batching step loop."""
@@ -212,6 +223,11 @@ class LLMEngine:
         self._loop_task: Optional[asyncio.Task] = None
         self._wake: Optional[asyncio.Event] = None
         self._stopped = False
+        # programs dispatched and not yet fetched, oldest first: between
+        # two iterations at most one decode step, the newest
+        self._inflight: Deque[_InFlight] = collections.deque()
+        # the executor's future of the jit call under way (stop() waits for it)
+        self._dispatching: Optional[asyncio.Future] = None
         self.step_count = 0
         self._rng_counter = 0
         # (wall time, tokens emitted) per step, for the tokens/s gauge
@@ -227,6 +243,10 @@ class LLMEngine:
             # kernel copies for the lanes in use), those a lane holds
             "kv_positions_attended": 0, "kv_positions_gathered": 0,
             "stalls": 0, "stall_s": 0.0,
+            # decode steps dispatched while the one before was unfetched
+            # (the pipeline engaged), and lane-steps whose request had
+            # ended (eos_token, cancel) by the time their token came
+            "decodes_chained": 0, "lane_steps_discarded": 0,
             **dict.fromkeys(self._counter_names, 0),
         }
         # where the current slice of the loop began (_note_stall)
@@ -279,6 +299,13 @@ class LLMEngine:
         # where the cache lives, reported by stats(): a replica that was
         # meant for the chip and runs on the CPU is then visible
         self._device = next(iter(self.k_pages.devices()))
+        # every lane's newest token, on the device: each program's token
+        # goes in as it is dispatched, and is the next decode step's
+        # ``tok``, so no token crosses to the host and back to be fed
+        lanes = self.config.max_batch_size
+        self._lane_tok = jnp.zeros(lanes, jnp.int32)
+        self._put_lane = jax.jit(lambda lane_tok, first, lane: lane_tok.at[lane].set(first.reshape(-1)[0]))
+        self._lanes_of = jax.jit(lambda nxt: nxt[:lanes]) if self._counter_names else (lambda nxt: nxt)
         self._base_key = jax.random.PRNGKey(self.config.seed + 1)
         top_k = self.config.top_k
         # a disabled TraceMe (well under a microsecond) outside a
@@ -336,6 +363,15 @@ class LLMEngine:
                 await task
             except (asyncio.CancelledError, Exception):  # noqa: BLE001
                 pass
+        if self._dispatching is not None:
+            # a jit call under way in the executor thread has donated the
+            # pool: it rebinds k_pages/v_pages before it returns
+            await asyncio.wait([self._dispatching])
+            self._dispatching = None
+        # what is in flight is never fetched and never emitted; its K/V
+        # writes land in blocks freed below, and the device runs them
+        # before whatever a later owner's prefill is dispatched with
+        self._inflight.clear()
         # drain everything still queued/running so blocks balance to zero
         self.slots = [None] * self.config.max_batch_size
         self.waiting.clear()
@@ -512,6 +548,12 @@ class LLMEngine:
                 raise
             except Exception:  # noqa: BLE001 — one bad step must not stop serving
                 logger.exception("llm engine step failed; continuing")
+                try:
+                    # what is in flight ran before the step that raised:
+                    # its tokens are real, and go out first
+                    self._fetch_in_flight()
+                except Exception:  # noqa: BLE001 — the device's answer is lost too
+                    logger.exception("llm engine could not fetch what was in flight")
                 with self._phase("engine.idle", span=False):
                     await asyncio.sleep(0.05)
             self._note_stall()
@@ -519,9 +561,19 @@ class LLMEngine:
     async def _iterate(self, loop):
         with self._phase("engine.admit"):
             self._reap()
-            self._maybe_preempt()
+            victim, for_req = self._preempt_victim()
+        if victim is not None:
+            # a fold takes the victim's tokens from the host: fetch
+            # whatever is in flight first, then decide again on what is
+            # true now (a lane may have ended in it, the victim's too)
+            self._fetch_in_flight()
+            with self._phase("engine.admit"):
+                victim, for_req = self._preempt_victim()
+                if victim is not None:
+                    self._preempt(victim, for_req)
         await self._join_waiters(loop)
-        if not any(r is not None for r in self.slots):
+        newest = await self._dispatch_decode(loop)
+        if newest is None and not self._inflight and not any(r is not None for r in self.slots):
             with self._phase("engine.metrics"):
                 self._push_metrics()
             with self._phase("engine.idle", span=False):
@@ -536,7 +588,9 @@ class LLMEngine:
                     # yield until a completion frees blocks
                     await asyncio.sleep(0.005)
             return
-        await self._decode_once(loop)
+        # the device now has the next step to run: wait for what was
+        # dispatched before it, and emit
+        self._fetch_in_flight(keep=newest)
         with self._phase("engine.metrics"):
             self._push_metrics()
         # step boundary: let pending add_request/cancel callbacks run
@@ -588,7 +642,7 @@ class LLMEngine:
         waiting for the running batch to drain."""
         joined = 0
         for i in range(len(self.slots)):
-            if self.slots[i] is not None:
+            if not self._lane_is_free(i):
                 continue
             with self._phase("engine.admit"):
                 req = self._next_admissible()
@@ -611,6 +665,19 @@ class LLMEngine:
                 continue
             joined += 1
         return joined
+
+    def _lane_is_free(self, i: int) -> bool:
+        """Nobody holds lane ``i``, or its holder's every token has been
+        dispatched: the holder ends when the step in flight is fetched,
+        its K/V writes run before anything dispatched from now on, and a
+        successor that joins now leaves the lane empty for no step."""
+        req = self.slots[i]
+        return req is None or req.dispatched >= req.max_tokens
+
+    def _vacate(self, req: _Request):
+        """``req`` leaves its lane, unless a successor has it already."""
+        if self.slots[req.slot] is req:
+            self.slots[req.slot] = None
 
     @staticmethod
     def _kv_need(req: _Request) -> int:
@@ -697,14 +764,16 @@ class LLMEngine:
         return None
 
     # -- priority preemption (preempt-by-recompute) ----------------------
-    def _maybe_preempt(self):
+    def _preempt_victim(self) -> tuple:
         """When a higher-priority request has starved past
-        ``preempt_wait_s`` and cannot join (no lane, or KV pool full),
-        evict the cheapest strictly-lower-priority running lane.  At most
-        one victim per step boundary — the loop converges over steps
-        instead of mass-evicting on a transient spike."""
+        ``preempt_wait_s`` and cannot join (no lane, or KV pool full):
+        the cheapest strictly-lower-priority running lane to evict, and
+        the request it makes room for; else (None, None).  At most one
+        victim per step boundary — the loop converges over steps instead
+        of mass-evicting on a transient spike."""
+        nobody = (None, None)
         if not self._fair_dirty or not self.waiting:
-            return
+            return nobody
         cand = None
         for req in self.waiting:
             if req.cancelled:
@@ -712,30 +781,31 @@ class LLMEngine:
             if cand is None or (-req.priority, req.seq) < (-cand.priority, cand.seq):
                 cand = req
         if cand is None:
-            return
+            return nobody
         now = time.time()
         if now - (cand.t_enqueue or cand.t_submit) < self.config.preempt_wait_s:
-            return
-        if (any(r is None for r in self.slots)
+            return nobody
+        if (any(self._lane_is_free(i) for i in range(len(self.slots)))
                 and self.bm.can_allocate(self._kv_need(cand))):
-            return  # joins normally this boundary; nothing to evict
+            return nobody  # joins normally this boundary; nothing to evict
         victims = [
             r for r in self.slots
             if r is not None and not r.cancelled and r.priority < cand.priority
         ]
         if not victims:
-            return
+            return nobody
         # cheapest recompute first: lowest priority, least generated
         # (smallest refill), youngest lane
-        victim = min(victims, key=lambda r: (r.priority, r.generated, -r.t_join))
-        self._preempt(victim, cand)
+        return min(victims, key=lambda r: (r.priority, r.generated, -r.t_join)), cand
 
     def _preempt(self, req: _Request, for_req: Optional[_Request] = None):
         """Evict a running lane by recompute: free its KV pages, fold the
         tokens generated so far into its prompt, and re-queue it.  On
         resume, prefill replays the folded context and samples the next
         token — under greedy decoding that argmax is exactly the token
-        the uninterrupted run would have produced (parity-tested)."""
+        the uninterrupted run would have produced (parity-tested).
+        Nothing of ``req`` may be in flight: what is folded is what was
+        emitted, and the client was sent every token of it."""
         import os
 
         from ray_tpu._private.chaos import CHAOS
@@ -777,7 +847,38 @@ class LLMEngine:
         self.waiting.append(req)
         self._fair_dirty = True
 
+    async def _dispatch(self, loop, name: str, call):
+        """Run one jit call in the executor thread, timed as ``<name>.run``
+        inside ``<name>.await``.  ``call`` donates the pool and rebinds
+        ``k_pages``/``v_pages`` in one synchronous stretch of that
+        thread; the await is shielded, so a ``stop()`` that cancels the
+        loop task here leaves the call to finish and finds the engine
+        bound to live buffers."""
+
+        def run():
+            with self._phase(name + ".run"):
+                return call()
+
+        with self._phase(name + ".await", span=False):
+            try:
+                call_under_way = self._dispatching = loop.run_in_executor(None, run)
+            except RuntimeError:
+                # the default executor is shut down: the process is on
+                # its way out and the loop ends here.  Nobody is sent
+                # FINISHED, so open streams break as a dead replica's do
+                self._stopped = True
+                raise
+            try:
+                return await asyncio.shield(call_under_way)
+            finally:
+                if call_under_way.done():
+                    self._dispatching = None
+
     async def _prefill(self, loop, req: _Request):
+        """Dispatch one prompt's prefill behind whatever is in flight.
+        Its first token goes to the lane's place on the device, for the
+        next decode step, and to the host when its turn to be fetched
+        comes."""
         with self._phase("engine.prefill.build"):
             n = len(req.prompt)
             bucket = self._prefill_bucket(n, self.max_ctx)
@@ -788,85 +889,106 @@ class LLMEngine:
             last_idx = np.array([n - 1], dtype=np.int32)
             temp = np.array([req.temperature], dtype=np.float32)
             rng = self._next_rng()
+            lane = np.int32(req.slot)
 
-        def run():
-            with self._phase("engine.prefill.run"):
-                return self._prefill_jit(
-                    self.params, self.k_pages, self.v_pages,
-                    toks, phys, last_idx, temp, rng,
-                )
+        def call():
+            first_tok, self.k_pages, self.v_pages = self._prefill_jit(
+                self.params, self.k_pages, self.v_pages,
+                toks, phys, last_idx, temp, rng,
+            )
+            self._lane_tok = self._put_lane(self._lane_tok, first_tok, lane)
+            return first_tok
 
-        with self._phase("engine.prefill.await", span=False):
-            first_tok, self.k_pages, self.v_pages = await loop.run_in_executor(None, run)
-        with self._phase("engine.prefill.fetch"):
-            tok, *counted = np.asarray(first_tok).reshape(-1).tolist()
-        with self._phase("engine.emit"):
-            self._count_program(counted)
-            self._counts["prompt_tokens"] += n
-            self._counts["prefill_bucket_tokens"] += bucket
-            self._emit(req, tok)
-            self._tok_window.append((time.time(), 1))
-            if req.cancelled or self._is_finished(req, tok):
-                self.slots[req.slot] = None
-                self._finish(req, req.finish_reason or "length")
+        first_tok = await self._dispatch(loop, "engine.prefill", call)
+        req.dispatched += 1
+        self._inflight.append(_InFlight(
+            first_tok, [(0, req)], {"prompt_tokens": n, "prefill_bucket_tokens": bucket},
+            decode=False))
 
-    async def _decode_once(self, loop):
+    async def _dispatch_decode(self, loop) -> Optional[_InFlight]:
+        """Dispatch one decode step for every lane with tokens left, on
+        the lanes' newest tokens where they lie on the device; None
+        where no lane has any.  Lengths, block tables and write slots
+        follow from how many tokens a lane was DISPATCHED for
+        (``bm.seq_len``), never from what they were: so the step is
+        built while the one before it runs."""
+        with self._phase("engine.admit"):
+            # a lane that ends by length is known a step ahead and left
+            # out; one that ends by eos_token or cancel() is found out
+            # when its token comes, a lane-step late
+            lanes = [(i, req) for i, req in enumerate(self.slots)
+                     if not self._lane_is_free(i) and not req.cancelled]
+        if not lanes:
+            return None
         with self._phase("engine.decode.build"):
             B = self.config.max_batch_size
             bs = self.bm.block_size
-            tok = np.zeros(B, dtype=np.int32)
             lengths = np.zeros(B, dtype=np.int32)
             tables = np.zeros((B, self.bm.blocks_needed(self.max_ctx)), dtype=np.int32)
             write_phys = np.zeros(B, dtype=np.int32)
             temp = np.zeros(B, dtype=np.float32)
-            active_lanes = []
             attended = read = 0
-            for i, req in enumerate(self.slots):
-                if req is None:
-                    continue
+            for i, req in lanes:
                 rid = req.request_id
                 cur_len = self.bm.seq_len(rid)  # positions already in cache
-                tok[i] = req.tokens[-1]
                 lengths[i] = cur_len  # also the fed token's position
                 tables[i] = self.bm.block_table(rid, tables.shape[1])
                 self.bm.advance(rid, 1)
                 write_phys[i] = self.bm.phys_index(rid, cur_len)
                 temp[i] = req.temperature
-                active_lanes.append(i)
                 attended += cur_len
                 read += -(-cur_len // bs) * bs  # whole pages
             rng = self._next_rng()
+            counts = {"kv_positions_attended": attended, "kv_positions_gathered": read,
+                      "decodes_chained": int(any(p.decode for p in self._inflight))}
 
-        def run():
-            with self._phase("engine.decode.run"):
-                return self._decode_jit(
-                    self.params, self.k_pages, self.v_pages,
-                    tok, lengths, tables, write_phys, temp, rng,
-                )
+        def call():
+            nxt, self.k_pages, self.v_pages = self._decode_jit(
+                self.params, self.k_pages, self.v_pages,
+                self._lane_tok, lengths, tables, write_phys, temp, rng,
+            )
+            self._lane_tok = self._lanes_of(nxt)
+            return nxt
 
-        with self._phase("engine.decode.await", span=False):
-            nxt, self.k_pages, self.v_pages = await loop.run_in_executor(None, run)
-        with self._phase("engine.decode.fetch"):
-            nxt = np.asarray(nxt)
+        nxt = await self._dispatch(loop, "engine.decode", call)
+        for _, req in lanes:
+            req.dispatched += 1
+        self._inflight.append(_InFlight(nxt, lanes, counts))
+        return self._inflight[-1]
+
+    def _fetch_in_flight(self, keep: Optional[_InFlight] = None):
+        """Fetch the programs in flight, oldest first, and emit their
+        tokens; all of them, or all dispatched before ``keep``."""
+        while self._inflight and self._inflight[0] is not keep:
+            self._fetch(self._inflight.popleft())
+
+    def _fetch(self, prog: _InFlight):
+        with self._phase("engine.decode.fetch" if prog.decode else "engine.prefill.fetch"):
+            out = np.asarray(prog.out).reshape(-1)
         with self._phase("engine.emit"):
-            self.step_count += 1
-            self._count_program(nxt[B:].tolist())
-            self._counts["kv_positions_attended"] += attended
-            self._counts["kv_positions_gathered"] += read
+            # counted here, with the program's own counters, so that
+            # stats() at any instant counts whole programs
+            self.step_count += int(prog.decode)
+            self._count_program(out[len(out) - len(self._counter_names):].tolist())
+            for name, n in prog.counts.items():
+                self._counts[name] += n
             now = time.time()
             emitted = 0
-            for i in active_lanes:
-                req = self.slots[i]
-                if req is None:
+            for i, req in prog.lanes:
+                if self._by_id.get(req.request_id) is not req:
+                    # ended by eos_token or cancel() after this was
+                    # dispatched: the token is dropped
+                    self._counts["lane_steps_discarded"] += 1
                     continue
-                t = int(nxt[i])
+                t = int(out[i])
                 self._emit(req, t, now=now)
                 emitted += 1
                 if req.cancelled or self._is_finished(req, t):
-                    self.slots[i] = None
+                    self._vacate(req)
                     self._finish(req, req.finish_reason or "length")
             if emitted:
                 self._tok_window.append((now, emitted))
+        self._note_stall()
 
     # -- bookkeeping -----------------------------------------------------
     def _count_program(self, counted):

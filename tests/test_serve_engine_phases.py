@@ -92,6 +92,28 @@ def test_counts_are_conserved_over_a_fixed_batch(batch_run):
     assert st["kv_blocks_in_use"] == 0
 
 
+def test_decode_steps_are_chained_on_a_full_batch_and_never_on_single_steps(batch_run):
+    """``decodes_chained`` counts the decode steps dispatched while the
+    step before them was unfetched: most of a batch's, and none where
+    every request takes one decode step and the engine drains between
+    two of them."""
+    _, _, st, _ = batch_run
+    assert st["steps"] / 2 < st["decodes_chained"] < st["steps"]  # the first step follows none
+    assert st["lane_steps_discarded"] == 0
+
+    async def main():
+        eng = LLMEngine(_tiny())
+        for n in (3, 9, 17):
+            await _generate(eng, [(n, 2)])  # a prefill's token, and one decode step's
+        await _generate(eng, [(5, 1)])  # answered by its prefill alone
+        st = eng.stats()
+        await eng.stop()
+        return st
+
+    st = asyncio.run(main())
+    assert (st["steps"], st["decodes_chained"], st["total_tokens"]) == (3, 0, 7)
+
+
 def test_phase_seconds_fit_the_wall_time(batch_run):
     _, _, st, wall_s = batch_run
     for name in ENGINE_SPANS:
@@ -255,6 +277,7 @@ _BEFORE = {
     "admit_s": 0.10, "prefill_build_s": 0.05, "prefill_run_s": 0.20, "prefill_await_s": 0.25,
     "prefill_fetch_s": 4.0, "decode_build_s": 2.0, "decode_run_s": 2.5, "decode_await_s": 3.0,
     "decode_fetch_s": 108.0, "emit_s": 0.30, "metrics_s": 0.20, "yield_s": 0.40, "idle_s": 50.0,
+    "decodes_chained": 700,
 }
 _AFTER = {
     "steps": 1250, "max_batch_size": 16, "platform": "tpu", "total_tokens": 12000,
@@ -263,10 +286,14 @@ _AFTER = {
     "admit_s": 0.15, "prefill_build_s": 0.07, "prefill_run_s": 0.28, "prefill_await_s": 0.35,
     "prefill_fetch_s": 6.08, "decode_build_s": 2.5, "decode_run_s": 3.125, "decode_await_s": 3.75,
     "decode_fetch_s": 134.0, "emit_s": 0.45, "metrics_s": 0.25, "yield_s": 0.55, "idle_s": 50.5,
+    "decodes_chained": 900,
 }
 # host time a step: admit 0.05 + build 0.5 + await 0.75 (dispatch and
 # hop) + emit 0.15 + metrics 0.05 + yield 0.15 = 1.65 s over 250 steps;
-# prefill: build 0.02 + await 0.10 + fetch 2.08 = 2.2 s of 30
+# prefill: build 0.02 + await 0.10 + fetch 2.08 = 2.2 s of 30; 200 of
+# the 250 decode steps were dispatched while the one before was unfetched
+_CELL = {"steady": "gpt2-large.serve.chat-steady", "backlog": "gpt2-large.serve.batch-backlog",
+         "moe": "olmoe-1b-7b.serve.backlog-wide"}
 _BY_HAND = {
     "queue_wait_ms.steady": 1000 * 3.0 / 40,
     "prefill_share_pct.steady": 100 * 2.2 / 30,
@@ -277,6 +304,9 @@ _BY_HAND = {
     "kv_gather_useful_pct.backlog": 15.0,
     "prefill_pad_ratio.steady": 1.4,
     "prefill_pad_ratio.backlog": 1.4,
+    "decode_overlap_pct.steady": 80.0,
+    "decode_overlap_pct.backlog": 80.0,
+    "decode_overlap_pct.moe": 80.0,
 }
 
 
@@ -294,8 +324,7 @@ def test_layer_metric_reads_the_engine_counters(metric):
     # and BENCHMARK.json reports it in its one cell, under the layer's name
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         entry = next(m for m in json.load(f)["per_layer"] if m["name"] == metric)
-    cell = "chat-steady" if metric.endswith(".steady") else "batch-backlog"
-    assert entry["workloads"] == ["gpt2-large.serve." + cell]
+    assert entry["workloads"] == [_CELL[metric.rsplit(".", 1)[1]]]
     assert (entry["layer"], entry["source"]) == ("serve plane", "program_counter")
 
 

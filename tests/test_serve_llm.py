@@ -248,6 +248,201 @@ def test_engine_kv_pool_admission_blocks_then_completes():
 
 
 # ----------------------------------------------------------------------
+# engine: one decode step in flight (docs/serving.md "What a step is
+# made of"): step n+1 is dispatched on step n's tokens before they are
+# fetched, so an end by eos_token or cancel() is found out a step late
+# ----------------------------------------------------------------------
+async def _one_at_a_time(config, batch):
+    eng = LLMEngine(config)
+    outs = []
+    for prompt, n in batch:
+        outs.append(await _drain(await eng.add_request(prompt, max_tokens=n)))
+    stats = eng.stats()
+    await eng.stop()
+    return outs, stats
+
+
+@pytest.mark.parametrize("model", ["tiny", "olmoe_tiny"])
+def test_engine_mixed_batch_equals_one_request_at_a_time(model):
+    """Greedy tokens do not depend on what shares the batch, on when a
+    request joined, or on the step that is in flight while it does."""
+    config = _tiny(model=model, max_batch_size=3, temperature=0.0)
+    batch = [([3, 1, 4, 1, 5], 9), ([2, 7], 1), ([1] * 9, 14), ([8, 8, 8], 4),
+             ([5, 6, 7, 8, 9, 10], 22), ([4, 2], 6), ([9] * 17, 2)]
+
+    async def mixed():
+        eng = LLMEngine(config)
+        reqs = [await eng.add_request(p, max_tokens=n) for p, n in batch[:4]]  # one more than lanes
+        while reqs[2].generated < 3:
+            await asyncio.sleep(0.005)
+        reqs += [await eng.add_request(p, max_tokens=n) for p, n in batch[4:]]  # join mid-stream
+        outs = await asyncio.gather(*[_drain(r) for r in reqs])
+        stats = eng.stats()
+        await eng.stop()
+        return outs, stats
+
+    outs, st = asyncio.run(mixed())
+    alone, st_alone = asyncio.run(_one_at_a_time(config, batch))
+    assert outs == alone
+    assert [len(o) for o in outs] == [n for _, n in batch]
+    for stats in (st, st_alone):
+        assert stats["lane_steps_discarded"] == 0  # an end by length is known a step ahead
+        assert stats["kv_leak_report"]["blocks_in_use"] == 0
+    assert 0 < st["decodes_chained"] <= st["steps"] < st_alone["steps"]
+
+
+def test_engine_successor_joins_before_the_last_token_is_fetched():
+    """A lane that ends by length is known a step ahead: the next
+    request takes it while the step with the last token is in flight,
+    so the lane stands empty for no step; FINISHED still follows the
+    last token, and a lane never decodes for two requests at once."""
+
+    async def main():
+        eng = LLMEngine(_tiny(max_batch_size=1, temperature=0.0))
+        a = await eng.add_request([1, 2, 3], max_tokens=6)
+        b = await eng.add_request([4, 5], max_tokens=4)
+        outs = await asyncio.gather(_drain(a), _drain(b))
+        stats = eng.stats()
+        await eng.stop()
+        return a, b, outs, stats
+
+    a, b, outs, st = asyncio.run(main())
+    alone, _ = asyncio.run(_one_at_a_time(_tiny(max_batch_size=1, temperature=0.0),
+                                          [([1, 2, 3], 6), ([4, 5], 4)]))
+    assert outs == alone
+    assert b.join_step == a.finish_step - 1  # joined with a's last step dispatched, not fetched
+    assert st["steps"] == 5 + 3 and st["lane_steps_discarded"] == 0
+    assert st["decodes_chained"] == st["steps"] - 1  # b's prefill did not break the chain
+    assert st["kv_leak_report"]["blocks_in_use"] == 0
+
+
+@pytest.mark.parametrize("at", [0, 4])
+def test_engine_eos_ends_the_request_a_lane_step_late(at):
+    """The request ends at eos_token and nothing after it is emitted,
+    though the next step was dispatched with its lane: that token is
+    dropped and counted.  ``at`` 0: the prefill's own token ends it."""
+    prompt, n = [3, 1, 4, 1, 5], 12
+
+    async def main():
+        (free,), _ = await _one_at_a_time(_tiny(temperature=0.0), [(prompt, n)])
+        eos = free[at]
+        eng = LLMEngine(_tiny(temperature=0.0, eos_token=eos))
+        req = await eng.add_request(prompt, max_tokens=n)
+        other = await eng.add_request([2, 7, 1], max_tokens=n)
+        toks, others = await asyncio.gather(_drain(req), _drain(other))
+        stats = eng.stats()
+        await eng.stop()
+        return free, eos, req, toks, others, stats
+
+    free, eos, req, toks, others, st = asyncio.run(main())
+    assert toks == free[:free.index(eos) + 1] and len(toks) < n
+    assert req.finish_reason == "eos" and req.generated == len(toks)
+    assert st["lane_steps_discarded"] >= 1
+    assert st["total_tokens"] == len(toks) + len(others)  # a dropped token is not counted
+    assert st["kv_leak_report"]["blocks_in_use"] == 0
+    assert st["kv_leak_report"]["live_sequences"] == 0
+
+
+def test_engine_cancel_and_stop_with_a_step_in_flight():
+    """cancel() of a running request and stop() while a jit call that
+    donates the pool is under way: blocks balance to zero, the engine
+    stays bound to live buffers, and it serves again after a restart."""
+    prompt, n = [3, 1, 4], 6
+
+    async def main():
+        eng = LLMEngine(_tiny(temperature=0.0))
+        want = await _drain(await eng.add_request(prompt, max_tokens=n))
+        a = await eng.add_request([1, 2], max_tokens=200)
+        b = await eng.add_request([3], max_tokens=200)
+        while a.generated < 3:
+            await asyncio.sleep(0.005)
+        assert eng._inflight, "no step in flight between two iterations"
+        eng.cancel(a.request_id)
+        sent = await _drain(a)
+        assert a.finish_reason == "cancelled" and sent == a.tokens
+        before = b.generated
+        while b.generated < before + 3:  # the loop goes on for the other lane
+            await asyncio.sleep(0.005)
+        jit = eng._decode_jit
+
+        def slow_jit(*args):  # so that stop() finds the loop inside the call
+            time.sleep(0.05)
+            return jit(*args)
+
+        eng._decode_jit = slow_jit
+        await asyncio.sleep(0.12)
+        await eng.stop()
+        eng._decode_jit = jit
+        assert b.finish_reason == "engine_stopped" and not eng._inflight
+        assert eng._dispatching is None
+        report = eng.bm.leak_report()
+        assert report["blocks_in_use"] == 0 and report["live_sequences"] == 0
+        assert not eng.k_pages.is_deleted() and not eng.v_pages.is_deleted()
+        discarded = eng.stats()["lane_steps_discarded"]
+        again = await _drain(await eng.add_request(prompt, max_tokens=n))  # ensure_started()
+        await eng.stop()
+        return want, again, discarded
+
+    want, again, discarded = asyncio.run(main())
+    assert again == want
+    assert discarded >= 1  # a's lane-step that was in flight when it was cancelled
+
+
+@pytest.mark.parametrize("what", ["jit_call", "executor_shut_down"])
+def test_engine_failed_step_ends_no_stream_short(what):
+    """A step that raises retires the step in flight first (its tokens
+    are real and go out in order) and never becomes a clean end: after a
+    jit call that raised the loop goes on and every request still gets
+    its max_tokens; with the default executor shut down (a replica on
+    its way out) the loop stops and no stream is sent FINISHED, so a
+    client sees a broken stream, not a short answer."""
+    prompt, n = [3, 1, 4, 1, 5], 12
+
+    async def main():
+        (free,), _ = await _one_at_a_time(_tiny(temperature=0.0), [(prompt, n)])
+        eng = LLMEngine(_tiny(temperature=0.0))
+        req = await eng.add_request(prompt, max_tokens=n)
+        while req.generated < 3:
+            await asyncio.sleep(0.005)
+        seen = []  # tokens on the host when the step raised: one more is in flight
+
+        def planted(*args):
+            seen.append(len(req.tokens))
+            raise RuntimeError("planted")
+
+        loop = asyncio.get_running_loop()
+        if what == "jit_call":
+            jit = eng._decode_jit
+
+            def once(*args):
+                eng._decode_jit = jit
+                planted()
+
+            eng._decode_jit = once
+            toks = await _drain(req)
+        else:
+            loop.run_in_executor = planted
+            while not eng._loop_task.done():
+                await asyncio.sleep(0.005)
+            del loop.run_in_executor
+            toks = list(req.tokens)
+            sent = [req.out.get_nowait() for _ in range(req.out.qsize())]
+            assert FINISHED not in sent and req.finish_reason == ""
+        assert not eng._inflight
+        await eng.stop()
+        return free, seen, toks, req, eng.bm.leak_report()
+
+    free, seen, toks, req, report = asyncio.run(main())
+    assert len(seen) == 1 and len(toks) > seen[0]  # the step in flight was fetched and emitted
+    assert toks[:seen[0] + 1] == free[:seen[0] + 1]
+    if what == "jit_call":
+        assert len(toks) == n and req.finish_reason == "length"
+    else:
+        assert len(toks) == seen[0] + 1 and req.finish_reason == "engine_stopped"
+    assert report["blocks_in_use"] == 0 and report["live_sequences"] == 0
+
+
+# ----------------------------------------------------------------------
 # per-trace critical path (PR 2 carried follow-up)
 # ----------------------------------------------------------------------
 def test_critical_path_sequential_children():

@@ -344,6 +344,104 @@ def test_engine_preempt_parity_exact_for_known_victim():
     assert rep_p["total_allocs"] == rep_p["total_frees"]
 
 
+def test_engine_preempt_with_a_step_in_flight_folds_what_the_client_was_sent():
+    """The loop keeps one decode step in flight; a preemption fetches it
+    first, so the fold holds every token the victim was dispatched for,
+    each of them emitted to its stream, and nothing of it is in flight."""
+    prompt, n = [6, 2, 8], 30
+
+    async def main():
+        eng = LLMEngine(_tiny(max_batch_size=1, preempt_wait_s=0.005,
+                              temperature=0.0,
+                              tenant_weights={"a": 1.0, "b": 1.0}))
+        seen = []
+        pick, preempt = eng._preempt_victim, eng._preempt
+
+        def picking():
+            victim, for_req = pick()
+            if victim is not None:
+                seen.append({"in_flight_when_picked": len(eng._inflight)})
+            return victim, for_req
+
+        def preempting(req, for_req=None):
+            seen[0].update(in_flight=len(eng._inflight), tokens=list(req.tokens),
+                            dispatched=req.dispatched, generated=req.generated,
+                            queued=req.out.qsize(), prompt=list(req.prompt))
+            return preempt(req, for_req)
+
+        eng._preempt_victim, eng._preempt = picking, preempting
+        hog = await eng.add_request(prompt, max_tokens=n, tenant="a", slo="batch")
+        while hog.generated < 4:
+            await asyncio.sleep(0.01)
+        vic = await eng.add_request([5], max_tokens=3, tenant="b", slo="interactive")
+        while not hog.preemptions:
+            await asyncio.sleep(0.005)
+        folded_prompt = list(hog.prompt)
+        toks, _ = await asyncio.gather(_drain(hog), _drain(vic))
+        report = eng.bm.leak_report()
+        await eng.stop()
+        return seen, folded_prompt, toks, report
+
+    seen, folded_prompt, toks, report = asyncio.run(main())
+    first = seen[0]
+    assert first["in_flight_when_picked"] >= 1, "drill is vacuous: nothing was in flight"
+    assert first["in_flight"] == 0
+    assert first["dispatched"] == first["generated"] == len(first["tokens"]) == first["queued"]
+    assert folded_prompt == first["prompt"] + first["tokens"]
+    assert toks[:len(first["tokens"])] == first["tokens"] and len(toks) == n
+    assert report["blocks_in_use"] == 0
+
+
+def test_engine_no_preemption_when_the_step_in_flight_frees_a_lane():
+    """The victim is picked with a step in flight; if the fetch of that
+    step ends another lane (here by eos_token), the starved request joins
+    there and nobody is evicted: the decision is made again on what is
+    true after the fetch."""
+    a_prompt, b_prompt, n, k = [6, 2, 8], [3, 1, 4, 1, 5], 24, 12
+
+    async def main():
+        cfg = dict(max_batch_size=2, temperature=0.0, tenant_weights={"a": 1.0, "b": 1.0})
+        free = LLMEngine(_tiny(**cfg))
+        a_free = await _drain(await free.add_request(a_prompt, max_tokens=n))
+        b_free = await _drain(await free.add_request(b_prompt, max_tokens=n))
+        await free.stop()
+        eos = b_free[k]
+        assert eos not in a_free and b_free.index(eos) == k, "pick other prompts"
+        # nobody is starved until b's step k, the one that ends it, is dispatched
+        eng = LLMEngine(_tiny(eos_token=eos, preempt_wait_s=1e9, **cfg))
+        a = await eng.add_request(a_prompt, max_tokens=n, tenant="a", slo="batch")
+        b = await eng.add_request(b_prompt, max_tokens=n, tenant="a", slo="batch")
+        jit, picks = eng._decode_jit, []
+        pick = eng._preempt_victim
+
+        def decode(*args):
+            if b.dispatched == k:
+                eng.config.preempt_wait_s = 0.0
+            return jit(*args)
+
+        def picking():
+            victim, for_req = pick()
+            picks.append((victim is not None, len(eng._inflight), b.finish_reason))
+            return victim, for_req
+
+        eng._decode_jit, eng._preempt_victim = decode, picking
+        while not b.generated:  # a and b hold the lanes
+            await asyncio.sleep(0.001)
+        c = await eng.add_request([5], max_tokens=3, tenant="b", slo="interactive")
+        assert b.dispatched < k, "c came too late for the drill"
+        outs = await asyncio.gather(_drain(a), _drain(b), _drain(c))
+        stats = eng.stats()
+        await eng.stop()
+        return a_free, b_free, outs, picks, a, stats
+
+    a_free, b_free, outs, picks, a, stats = asyncio.run(main())
+    first = picks.index((True, 1, ""))  # a victim was picked with b's last step in flight
+    assert picks[first + 1] == (False, 0, "eos")  # and given up after the fetch
+    assert a.preemptions == 0 and stats["preemptions_total"] == 0
+    assert outs[0] == a_free and outs[1] == b_free[:k + 1] and len(outs[2]) == 3
+    assert stats["kv_leak_report"]["blocks_in_use"] == 0
+
+
 def test_engine_cancel_preempt_storm_zero_leak():
     """A storm of mixed-class multi-tenant requests with cancels landing
     on waiting, running, and preempted requests must balance the KV pool
