@@ -141,69 +141,42 @@ def _model_cfg(config):
     return cfg
 
 
-def _reference_loss(params, cfg, tokens, targets, chunk=2):
-    """Next-token loss of `params` through a plain float32 forward with
-    einsum attention: independent of the Flax module, of the compute
-    dtype and of the Pallas kernel."""
-    import jax
-    import jax.numpy as jnp
+def _reference_loss(params, cfg, tokens, targets):
+    """Next-token loss of `params` through the benchmark's plain float32
+    forward with einsum attention: independent of the Flax module, of
+    the compute dtype and of the Pallas kernel."""
+    from benchmark.reference import token_losses
 
-    from ray_tpu.models.gpt2 import _dense, _ln, _split_heads
-    from ray_tpu.ops.attention import reference_causal_attention
-
-    f32 = jnp.float32
-
-    def total_loss(params, tok, tgt):
-        B, T = tok.shape
-        x = params["wte"]["embedding"][tok] + params["wpe"]["embedding"][jnp.arange(T)[None]]
-        for i in range(cfg.n_layer):
-            blk = params[f"h_{i}"]
-            qkv = _dense(_ln(x, blk["ln_1"], f32), blk["attn"]["qkv"], f32)
-            q, k, v = (_split_heads(t, cfg.n_head) for t in jnp.split(qkv, 3, axis=-1))
-            att = reference_causal_attention(q, k, v).reshape(B, T, cfg.d_model)
-            x = x + _dense(att, blk["attn"]["attn_out"], f32)
-            h = jax.nn.gelu(_dense(_ln(x, blk["ln_2"], f32), blk["mlp"]["mlp_up"], f32))
-            x = x + _dense(h, blk["mlp"]["mlp_down"], f32)
-        logits = _dense(_ln(x, params["ln_f"], f32), params["lm_head"], f32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        picked = jnp.take_along_axis(logits, tgt[..., None], axis=-1)[..., 0]
-        return (lse - picked).sum()
-
-    total = 0.0
-    # on the TPU a float32 matmul runs as bf16 passes unless told otherwise
-    with jax.default_matmul_precision("float32"):
-        fn = jax.jit(total_loss)
-        for i in range(0, tokens.shape[0], chunk):
-            total += float(fn(params, tokens[i:i + chunk], targets[i:i + chunk]))
-    return total / tokens.size
+    return float(token_losses(params, tokens, targets, cfg.n_layer, cfg.n_head).mean())
 
 
 def train_loop(config):
-    """train_loop_per_worker of the train phase: the sharded-state recipe
-    of models/gpt2.py on a one-device mesh, one repeated batch."""
+    """train_loop_per_worker of the train phase: the trainer's
+    ShardingConfig (a one-device mesh) through the sharding plan, one
+    repeated batch."""
     import statistics
 
     import jax
     import numpy as np
 
+    import ray_tpu.train.sharding as sharding
     from ray_tpu import train
     from ray_tpu.models import gpt2
-    from ray_tpu.parallel import create_mesh
 
     cfg = _model_cfg(config)
     B, T = config["batch"], config["seq"]
     dev = jax.devices()
-    mesh = create_mesh({"dp": 1}, dev[:1])
+    plan = sharding.plan_from_context()
     opt = gpt2.make_adamw(lr=config["lr"])
-    params, opt_state, _ = gpt2.make_sharded_train_state(
-        cfg, mesh, opt, rng=jax.random.PRNGKey(config["seed"])
+    params, opt_state = plan.shard_init(
+        lambda rng: gpt2.init_params(cfg, rng), opt, rng=jax.random.PRNGKey(config["seed"])
     )
     toks = np.random.default_rng(config["seed"]).integers(
         0, cfg.vocab_size, (B, T + 1), dtype=np.int32
     )
     tokens, targets = toks[:, :-1], toks[:, 1:]
     ref_loss = _reference_loss(params, cfg, tokens, targets)
-    step = gpt2.make_sharded_train_step(cfg, mesh, opt)
+    step = plan.jit_train_step(gpt2.make_train_step(cfg, opt), params, opt_state)
     kernel_in_step = "tpu_custom_call" in step.lower(
         params, opt_state, tokens, targets
     ).as_text()
@@ -245,7 +218,6 @@ def sharded_loop(config):
     import gc
 
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
     import ray_tpu.train.sharding as sharding
@@ -259,9 +231,6 @@ def sharded_loop(config):
         0, cfg.vocab_size, (steps, B, T + 1), dtype=np.int32
     )
 
-    def init(rng):
-        return gpt2.GPT2(cfg).init(rng, jnp.zeros((2, min(T, 128)), jnp.int32))["params"]
-
     def bytes_by_device(tree):
         out = {d.id: 0 for d in dev}
         for leaf in jax.tree_util.tree_leaves(tree):
@@ -272,7 +241,7 @@ def sharded_loop(config):
     def run(plan, inspect):
         opt = gpt2.make_adamw(config["lr"])
         params, opt_state = plan.shard_init(
-            init, opt, rng=jax.random.PRNGKey(config["seed"])
+            lambda rng: gpt2.init_params(cfg, rng), opt, rng=jax.random.PRNGKey(config["seed"])
         )
         facts = {
             "mesh": dict(plan.mesh.shape),
@@ -381,8 +350,14 @@ def check_leases(facts):
 
 def phase_train(config):
     from ray_tpu.air.config import ScalingConfig
+    from ray_tpu.train.sharding import ShardingConfig
 
-    facts = _fit(train_loop, config, ScalingConfig(num_workers=1, use_tpu=True))
+    facts = _fit(
+        train_loop, config, ScalingConfig(num_workers=1, use_tpu=True),
+        sharding_config=ShardingConfig(
+            mesh=("batch",), mesh_shape={"batch": 1}, partition_rules=[(r".*", ())],
+        ),
+    )
     say("train", **facts)
     return facts
 

@@ -77,7 +77,7 @@ struct Header {
   // (background prefault thread or populate-on-alloc).  Writes above it
   // would page-fault per 4K; arena_alloc populates the gap in one
   // MADV_POPULATE_WRITE batch instead (~3-4x faster than touch-faulting
-  // a cold 256 MB put — see PERF_ANALYSIS.md).
+  // a cold 256 MB put).
   uint64_t populated_end;
   uint32_t table_cap;
   uint32_t free_cap;

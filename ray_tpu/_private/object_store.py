@@ -96,8 +96,8 @@ class ObjectStoreCore:
         if self.arena is not None and CONFIG.arena_prefault_bytes > 0:
             # Background trickled prefault of the hot low region (the
             # bump allocator + freelist reuse low offsets): puts landing
-            # there run at warm-page memcpy speed (~4x — see
-            # PERF_ANALYSIS.md).  Capped + paced so a multi-raylet box
+            # there run at warm-page memcpy speed (~4x).  Capped +
+            # paced so a multi-raylet box
             # doesn't make capacity x raylets resident or saturate the
             # memory bus at startup.
             import threading

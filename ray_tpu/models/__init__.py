@@ -1,12 +1,12 @@
 """ray_tpu.models — JAX/Flax model families for Train/RLlib/Serve.
 
-Flagship: GPT-2 (ray_tpu.models.gpt2) — the north-star pretraining target.
-Also: Llama family (RoPE/GQA/SwiGLU), expert-parallel MoE, pipeline-
-parallel GPT-2 (gpt2_pp), MLP (MNIST), ResNet (CIFAR), and RLlib
-policy/value nets.
+Flagship: GPT-2 (ray_tpu.models.gpt2) — the north-star pretraining target,
+trained and served.  Served only: OLMoE (olmoe, sparse experts over
+ops/moe.py).  Also: Llama family (RoPE/GQA/SwiGLU), pipeline-parallel
+GPT-2 (gpt2_pp), ViT, MLP (MNIST), ResNet (CIFAR).
 """
 
-__all__ = ["gpt2", "gpt2_pp", "llama", "mlp", "moe", "resnet"]
+__all__ = ["gpt2", "gpt2_pp", "llama", "mlp", "olmoe", "resnet", "vit"]
 
 
 def __getattr__(name):

@@ -1,15 +1,16 @@
 """Shared scaffolding for the model families.
 
-One copy of the sharded-init / train-step recipe (Megatron layouts from
-parallel.sharding, donated state, explicit batch placement) that
-gpt2.py and llama.py both build on — the models differ in architecture,
-not in how they train — and of the sampler the serving engine applies
-to whatever family's logits (``sample_logits``).
+One copy of the loss and the pure train step that gpt2.py and llama.py
+both build on — the models differ in architecture, not in how they
+train — and of the sampler the serving engine applies to whatever
+family's logits (``sample_logits``).  Laying a state out on a mesh and
+jitting the step over it is ``ray_tpu.train.sharding``'s
+(``GspmdPlan.shard_init`` / ``jit_train_step``).
 """
 
 from __future__ import annotations
 
-from typing import Any, Callable
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -37,59 +38,6 @@ def make_train_step(loss_fn: Callable, cfg, optimizer):
         return params, opt_state, loss
 
     return train_step
-
-
-def make_sharded_train_state(init_fn: Callable, mesh, optimizer, rules=None, rng=None):
-    """Initialize params + opt state directly ON the mesh with the
-    Megatron-style layout from parallel.sharding (no host-side giant
-    arrays; init is jitted with output shardings).
-
-    init_fn(rng) -> params pytree.  Returns (params, opt_state, specs).
-    """
-    from ray_tpu.parallel.sharding import gpt_sharding_rules, infer_param_spec, tree_shardings
-
-    rng = rng if rng is not None else jax.random.PRNGKey(0)
-    rules = rules if rules is not None else gpt_sharding_rules()
-    abstract = jax.eval_shape(init_fn, rng)
-    specs = infer_param_spec(abstract, rules, mesh)
-    shardings = tree_shardings(mesh, specs)
-    params = jax.jit(init_fn, out_shardings=shardings)(rng)
-    opt_state = jax.jit(optimizer.init)(params)  # follows param shardings
-    return params, opt_state, specs
-
-
-def make_sharded_train_step(step_fn: Callable, mesh):
-    """jit the step with donated state + explicit batch placement
-    (dp over batch, sp over sequence); param/opt layouts come from the
-    committed shardings set at init."""
-    from jax.sharding import NamedSharding
-
-    from ray_tpu.parallel.sharding import batch_spec
-
-    data_sharding = NamedSharding(mesh, batch_spec(mesh))
-    from ray_tpu._private import profiling
-
-    jit_fn = jax.jit(step_fn, donate_argnums=(0, 1))
-    jitted = profiling.instrument_jit("train_step", jit_fn)
-
-    # Traced under the mesh, so that code which cannot be partitioned
-    # automatically (ops.attention's Pallas kernel) can see it.
-    def run(params, opt_state, tokens, targets):
-        tokens = jax.device_put(tokens, data_sharding)
-        targets = jax.device_put(targets, data_sharding)
-        with jax.set_mesh(mesh):
-            out = jitted(params, opt_state, tokens, targets)
-        profiling.report_device_memory()
-        return out
-
-    def lower(*args):
-        with jax.set_mesh(mesh):
-            return jit_fn.lower(*args)
-
-    run.data_sharding = data_sharding
-    # the step's own lowering, for checks on what was compiled
-    run.lower = lower
-    return run
 
 
 def num_params(params) -> int:

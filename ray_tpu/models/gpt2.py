@@ -4,14 +4,14 @@ The north-star workload ("Ray Train GPT-2 tokens/sec/chip",
 BASELINE.json).  Design notes:
 
 - bf16 compute / f32 params+optimizer (MXU-native precision).
-- Param names line up with ray_tpu.parallel.sharding.gpt_sharding_rules
-  (qkv / attn_out / mlp_up / mlp_down / wte / wpe / lm_head) so TP/FSDP
-  layouts come from one rule table.
+- Param names (qkv / attn_out / mlp_up / mlp_down / wte / wpe / lm_head)
+  are what ray_tpu.train.sharding.gpt2_partition_rules matches: mesh
+  layouts come from that one rule table.
 - `remat` wraps each block with jax.checkpoint to trade FLOPs for HBM.
 - Attention goes through ray_tpu.ops.attention which picks a fused
   implementation (Pallas splash/ring kernel on TPU, reference einsum
-  elsewhere); sequence parallelism shards the seq dim over the "sp"
-  mesh axis.
+  elsewhere); sequence parallelism shards the seq dim over the mesh
+  axis `sp_axis` names.
 - Static shapes everywhere; the block stack uses a Python loop (unrolled
   by trace) — swap to nn.scan for very deep configs.
 """
@@ -169,27 +169,6 @@ def make_adamw(lr: float = 3e-4, weight_decay: float = 0.1):
     return optax.adamw(lr, b1=0.9, b2=0.95, weight_decay=weight_decay)
 
 
-def make_sharded_train_state(cfg: GPT2Config, mesh, optimizer, rng=None, batch: int = 2):
-    """Initialize params + opt state directly ON the mesh with the
-    Megatron-style layout from parallel.sharding (shared recipe in
-    models/common.py)."""
-    from ray_tpu.models import common
-
-    tokens = jnp.zeros((batch, min(cfg.max_seq_len, 128)), dtype=jnp.int32)
-    return common.make_sharded_train_state(
-        lambda rng: GPT2(cfg).init(rng, tokens)["params"], mesh, optimizer, rng=rng
-    )
-
-
-def make_sharded_train_step(cfg: GPT2Config, mesh, optimizer):
-    """jit-compiled SPMD train step: dp/fsdp over batch, tp over hidden,
-    sp over sequence (ring attention), donated state (shared recipe in
-    models/common.py)."""
-    from ray_tpu.models import common
-
-    return common.make_sharded_train_step(make_train_step(cfg, optimizer), mesh)
-
-
 # ----------------------------------------------------------------------
 # Inference plane: prefill / single-token decode with external KV cache.
 #
@@ -265,16 +244,22 @@ def prefill_forward(params, cfg: GPT2Config, tokens, last_index=None):
     return logits_last, jnp.stack(ks), jnp.stack(vs)
 
 
-def _decode_layers(params, cfg: GPT2Config, tok, pos, attend):
-    """One decode step: tok [B] current token ids, pos [B] their
-    positions; ``attend(i, q, k, v)`` gives layer i's attention of the
-    fed token (q, k, v [B, H, Dh], its own key and value among what it
-    attends to) over whatever cache the caller holds.  Returns
-    (logits [B, vocab], k_new [L, B, H, Dh], v_new [L, B, H, Dh]): the
-    caller writes k_new/v_new into its cache at position pos."""
+def decode_forward_paged(params, cfg: GPT2Config, tok, k_pages, v_pages,
+                         block_tables, lengths, block_size: int):
+    """One decode step over a paged KV pool read in place: tok [B] the
+    current token ids; k_pages/v_pages [L, num_blocks * block_size,
+    H * Dh]; block_tables [B, pages] the physical block of each logical
+    page of a lane (scratch block 0 where it holds none); lengths [B]
+    the cached positions of a lane, which is also the position of its
+    fed token.  Returns (logits [B, vocab], k_new [L, B, H, Dh], v_new
+    [L, B, H, Dh]): the fed token's own key and value are among what it
+    attends to, and the caller writes them into the pool at position
+    ``lengths``."""
+    from ray_tpu.ops.attention import paged_decode_attention
+
     dtype = cfg.dtype
     x = params["wte"]["embedding"].astype(dtype)[tok]
-    x = x + params["wpe"]["embedding"].astype(dtype)[pos]
+    x = x + params["wpe"]["embedding"].astype(dtype)[lengths]
     k_news, v_news = [], []
     for i in range(cfg.n_layer):
         blk = params[f"h_{i}"]
@@ -282,7 +267,9 @@ def _decode_layers(params, cfg: GPT2Config, tok, pos, attend):
         qkv = _dense(h, blk["attn"]["qkv"], dtype)
         q, k, v = jnp.split(qkv, 3, axis=-1)
         q, k, v = (_split_heads(t, cfg.n_head) for t in (q, k, v))  # [B, H, Dh]
-        att = attend(i, q, k, v).reshape(tok.shape[0], cfg.d_model)
+        att = paged_decode_attention(
+            q, k, v, k_pages, v_pages, i, block_tables, lengths, block_size=block_size
+        ).reshape(tok.shape[0], cfg.d_model)
         x = x + _dense(att, blk["attn"]["attn_out"], dtype)
         h2 = _ln(x, blk["ln_2"], dtype)
         m = nn.gelu(_dense(h2, blk["mlp"]["mlp_up"], dtype))
@@ -292,36 +279,6 @@ def _decode_layers(params, cfg: GPT2Config, tok, pos, attend):
     x = _ln(x, params["ln_f"], dtype)
     logits = _dense(x, params["lm_head"], dtype)
     return logits, jnp.stack(k_news), jnp.stack(v_news)
-
-
-def decode_forward(params, cfg: GPT2Config, tok, pos, k_ctx, v_ctx, ctx_mask):
-    """One decode step over an externally-gathered contiguous KV context:
-    k_ctx/v_ctx [L, B, C, H, Dh] the per-layer cached keys/values for
-    positions < pos (padded; ctx_mask [B, C] marks real entries).
-    Returns as ``_decode_layers``."""
-    from ray_tpu.ops.attention import reference_decode_attention
-
-    def attend(i, q, k, v):
-        return reference_decode_attention(q, k, v, k_ctx[i], v_ctx[i], ctx_mask)
-
-    return _decode_layers(params, cfg, tok, pos, attend)
-
-
-def decode_forward_paged(params, cfg: GPT2Config, tok, k_pages, v_pages,
-                         block_tables, lengths, block_size: int):
-    """One decode step over a paged KV pool read in place: k_pages/v_pages
-    [L, num_blocks * block_size, H * Dh]; block_tables [B, pages] the
-    physical block of each logical page of a lane (scratch block 0 where
-    it holds none); lengths [B] the cached positions of a lane, which is
-    also the position of its fed token.  Returns as ``_decode_layers``."""
-    from ray_tpu.ops.attention import paged_decode_attention
-
-    def attend(i, q, k, v):
-        return paged_decode_attention(
-            q, k, v, k_pages, v_pages, i, block_tables, lengths, block_size=block_size
-        )
-
-    return _decode_layers(params, cfg, tok, lengths, attend)
 
 
 def generate_greedy(params, cfg: GPT2Config, tokens, n_new: int):

@@ -1,8 +1,8 @@
 """Llama-family decoder in Flax, TPU-first.
 
 Same design rules as models/gpt2.py (bf16 compute / f32 params, static
-shapes, fused attention via ops.attention, Megatron tp layout from
-parallel.sharding — the rule table already names q/k/v/o_proj and
+shapes, fused attention via ops.attention; a mesh layout comes from
+``train.sharding`` with partition rules that name q/k/v/o_proj and
 gate/up/down_proj):
 
 - RMSNorm (no bias anywhere),
@@ -175,23 +175,6 @@ def make_train_step(cfg: LlamaConfig, optimizer):
     from ray_tpu.models import common
 
     return common.make_train_step(loss_fn, cfg, optimizer)
-
-
-def make_sharded_train_state(cfg: LlamaConfig, mesh, optimizer, rng=None, batch: int = 2):
-    """Shared recipe (models/common.py); the rule table already names
-    q/k/v/o_proj + gate/up/down_proj."""
-    from ray_tpu.models import common
-
-    tokens = jnp.zeros((batch, min(cfg.max_seq_len, 128)), dtype=jnp.int32)
-    return common.make_sharded_train_state(
-        lambda rng: Llama(cfg).init(rng, tokens)["params"], mesh, optimizer, rng=rng
-    )
-
-
-def make_sharded_train_step(cfg: LlamaConfig, mesh, optimizer):
-    from ray_tpu.models import common
-
-    return common.make_sharded_train_step(make_train_step(cfg, optimizer), mesh)
 
 
 def num_params(params) -> int:

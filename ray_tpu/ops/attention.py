@@ -3,7 +3,7 @@
 One entry point for all models: picks the best implementation for the
 placement —
 
-- sequence sharded over an "sp" mesh axis → ring attention
+- sequence sharded over a mesh axis (`sp_axis`) → ring attention
   (ops.ring_attention, shard_map + ppermute over the ICI ring);
 - single-device / GSPMD-sharded → Pallas flash kernel on TPU when shapes
   allow (ops.pallas_attention; under a mesh, per shard of batch and
@@ -101,10 +101,9 @@ def causal_attention(q, k, v, *, mesh=None, sp_axis: Optional[str] = None):
     return reference_causal_attention(q, k, v)
 
 
-# Mesh axes over which the two rule tables shard attention heads
-# (train/sharding/rules.py "model", parallel/sharding.py "tp").  Every
-# other axis of a mesh carries batch.
-_HEAD_AXES = ("model", "tp")
+# The mesh axis over which the partition rules shard attention heads
+# (train/sharding/rules.py).  Every other axis of a mesh carries batch.
+_HEAD_AXIS = "model"
 
 
 def _flash_over_mesh(q, k, v):
@@ -138,8 +137,8 @@ def _flash_over_mesh(q, k, v):
         return tuple(kept) or None
 
     spec = P(
-        dividing([a for a in free if a not in _HEAD_AXES], B), None,
-        dividing([a for a in free if a in _HEAD_AXES], H), None,
+        dividing([a for a in free if a != _HEAD_AXIS], B), None,
+        dividing([a for a in free if a == _HEAD_AXIS], H), None,
     )
     return jax.shard_map(
         lambda q, k, v: flash_attention(q, k, v, causal=True),

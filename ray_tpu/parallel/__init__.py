@@ -1,11 +1,12 @@
-"""ray_tpu.parallel — mesh + sharding utilities for SPMD training.
+"""ray_tpu.parallel — device meshes and in-program pipeline schedules.
 
 This is the TPU-native replacement for the reference's torch DDP/FSDP
 wrappers and NCCL process groups (reference:
 python/ray/train/torch/train_loop_utils.py:162 prepare_model,
 python/ray/train/torch/config.py:153): instead of wrapping a model in a
 communication library, we place arrays on a `jax.sharding.Mesh` and let
-XLA insert ICI collectives.
+XLA insert ICI collectives.  How a parameter tree is laid out on a mesh
+and how a step is jitted over it is ``ray_tpu.train.sharding``'s alone.
 """
 
 from ray_tpu.parallel.mesh import (
@@ -14,22 +15,10 @@ from ray_tpu.parallel.mesh import (
     create_mesh,
     local_mesh,
 )
-from ray_tpu.parallel.sharding import (
-    ShardingRules,
-    batch_spec,
-    infer_param_spec,
-    shard_tree,
-    tree_shardings,
-)
 
 __all__ = [
     "MeshConfig",
     "auto_mesh_shape",
     "create_mesh",
     "local_mesh",
-    "ShardingRules",
-    "batch_spec",
-    "infer_param_spec",
-    "shard_tree",
-    "tree_shardings",
 ]

@@ -1,6 +1,10 @@
 """Device mesh construction.
 
-Axis conventions used across ray_tpu (models, trainers, graft entry):
+The sharded training plane names its own axes (``ShardingConfig.mesh``,
+by default ``("batch", "model")``) and builds them through
+``create_mesh``.  Axis names ``create_mesh`` orders itself, for meshes
+made by hand (ring attention over ``sp``, the in-jit pipeline over
+``pp``):
 
     dp — data parallel (batch dim)
     fsdp — sharded data parallel (params sharded over dp replicas)

@@ -5,8 +5,8 @@ Learning" — the sebulba actor/learner split; RLAX demonstrates the same
 streaming-into-a-sharded-learner shape at LLM scale).
 
 The synchronous plane pays one actor RPC round-trip per rollout
-(`sample() → get() → update()` — PERF_ANALYSIS.md "RLlib PPO": 80.9% of pong_scale wall
-time in learner-update+overhead while runners idle).  Here neither side
+(`sample() → get() → update()`: the learner updates while the runners
+idle).  Here neither side
 ever waits on the other:
 
   runner ──traj ring/socket──▶ intake thread ──queue──▶ learner loop

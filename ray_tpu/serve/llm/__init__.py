@@ -21,14 +21,13 @@ Cloud TPU"; docs/serving.md is the operator guide.
 """
 
 from ray_tpu.serve.llm.config import LLMConfig
-from ray_tpu.serve.llm.deployment import LLMServer, StaticBatchLLMServer, build_app
+from ray_tpu.serve.llm.deployment import LLMServer, build_app
 from ray_tpu.serve.llm.engine import LLMEngine
 from ray_tpu.serve.llm.kv_cache import BlockManager
 
 __all__ = [
     "LLMConfig",
     "LLMServer",
-    "StaticBatchLLMServer",
     "LLMEngine",
     "BlockManager",
     "build_app",

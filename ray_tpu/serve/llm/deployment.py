@@ -3,10 +3,7 @@
 ``LLMServer`` is the continuous-batching deployment: ``generate`` is an
 async generator (one stream item per token, via the replica's
 ``handle_request_stream``), ``__call__`` is the one-shot completion
-path.  ``StaticBatchLLMServer`` is the request-level ``@serve.batch``
-baseline the bench compares against: a whole batch decodes in lockstep
-until its LAST member finishes, so mixed-length traffic pays the
-drain barrier continuous batching removes.
+path.
 """
 
 from __future__ import annotations
@@ -217,131 +214,6 @@ class LLMServer:
         await self.engine.stop()
         for v in self._loaded_variants():
             await v.engine.stop()
-
-
-class StaticBatchLLMServer:
-    """Request-level batching baseline: ``@serve.batch`` coalesces
-    requests, then the whole batch generates to completion with a dense
-    per-batch KV cache — no in-flight joins, no early exit for short
-    members.  Kept as the bench's comparison point and as the simplest
-    correct serving path."""
-
-    def __init__(self, llm_config: Optional[Any] = None,
-                 batch_wait_timeout_s: float = 0.05):
-        import functools
-
-        from ray_tpu.serve.batching import batch
-
-        self.config = LLMConfig.coerce(llm_config)
-        self.model_cfg = self.config.model_config()
-        self._build()
-        # bind the batch queue at the configured size at init time
-        self._batched = batch(
-            max_batch_size=self.config.max_batch_size,
-            batch_wait_timeout_s=batch_wait_timeout_s,
-        )(functools.partial(StaticBatchLLMServer._generate_batch, self))
-
-    def _build(self):
-        import jax
-
-        from ray_tpu.models import gpt2
-
-        cfg = self.model_cfg
-        self.params = gpt2.init_params(cfg, rng=jax.random.PRNGKey(self.config.seed))
-        self._gpt2 = gpt2
-        self._jax = jax
-
-        def step(params, cur, lens, k_full, v_full, mask):
-            import jax.numpy as jnp
-
-            logits, k_new, v_new = gpt2.decode_forward(
-                params, cfg, cur, lens, k_full, v_full, mask
-            )
-            B = cur.shape[0]
-            rows = jnp.arange(B)
-            k_full = k_full.at[:, rows, lens].set(k_new)
-            v_full = v_full.at[:, rows, lens].set(v_new)
-            mask = mask.at[rows, lens].set(True)
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return nxt, k_full, v_full, mask
-
-        # compiles once per (B, Ctot-bucket) shape — Ctot is bucketed in
-        # _run_batch so mixed max_tokens don't fan out compilations
-        from ray_tpu._private import profiling as _profiling
-
-        self._step_jit = _profiling.instrument_jit(
-            "serve_static_step", jax.jit(step, donate_argnums=(3, 4))
-        )
-
-    async def _generate_batch(self, payloads: List[Any]) -> List[Dict[str, Any]]:
-        loop = asyncio.get_running_loop()
-        specs = [_parse(p) for p in payloads]
-        return await loop.run_in_executor(None, self._run_batch, specs)
-
-    def _run_batch(self, specs: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
-        import jax.numpy as jnp
-        import numpy as np
-
-        from ray_tpu.serve.llm.config import tokenize_prompt
-
-        cfg = self.model_cfg
-        gpt2 = self._gpt2
-        prompts = []
-        maxts = []
-        for s in specs:
-            toks = tokenize_prompt(s.get("prompt", ""), cfg.vocab_size)
-            prompts.append(toks[: cfg.max_seq_len - 1])
-            mt = int(s.get("max_tokens") or self.config.default_max_tokens)
-            maxts.append(max(1, min(mt, cfg.max_seq_len - len(toks))))
-        B = len(prompts)
-        T = max(len(p) for p in prompts)
-        toks = np.zeros((B, T), dtype=np.int32)
-        for i, p in enumerate(prompts):
-            toks[i, :len(p)] = p
-        last_idx = np.array([len(p) - 1 for p in prompts], dtype=np.int32)
-        logits, k, v = gpt2.prefill_forward(self.params, cfg, jnp.asarray(toks),
-                                            last_index=jnp.asarray(last_idx))
-        # dense cache [L, B, Ctot, H, Dh]; the batch runs until its LAST
-        # member reaches max_tokens (the drain barrier)
-        steps = max(maxts)
-        outs: List[List[int]] = [[] for _ in range(B)]
-        cur = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        lens = jnp.asarray([len(p) for p in prompts], dtype=jnp.int32)
-        # bucket the cache width so mixed max_tokens reuse one compile
-        ctot = T + steps
-        bucket = 16
-        while bucket < ctot:
-            bucket *= 2
-        # NOT clamped to max_seq_len: every individual sequence fits its
-        # own T_i + maxts_i <= max_seq_len, but finished lanes keep
-        # decoding (lockstep) and their positions may run past it —
-        # garbage confined to their own rows
-        Ctot = bucket
-        L, _, _, H, Dh = k.shape
-        k_full = jnp.zeros((L, B, Ctot, H, Dh), cfg.dtype).at[:, :, :T].set(k)
-        v_full = jnp.zeros((L, B, Ctot, H, Dh), cfg.dtype).at[:, :, :T].set(v)
-        mask = np.zeros((B, Ctot), dtype=bool)
-        for i, p in enumerate(prompts):
-            mask[i, :len(p)] = True
-        mask = jnp.asarray(mask)
-        for i in range(B):
-            outs[i].append(int(cur[i]))
-        for _step in range(steps - 1):
-            cur, k_full, v_full, mask = self._step_jit(
-                self.params, cur, lens, k_full, v_full, mask
-            )
-            lens = lens + 1
-            host = np.asarray(cur)
-            for i in range(B):
-                if len(outs[i]) < maxts[i]:
-                    outs[i].append(int(host[i]))
-        return [
-            {"tokens": outs[i], "num_tokens": len(outs[i]), "finish_reason": "length"}
-            for i in range(B)
-        ]
-
-    async def __call__(self, payload: Any) -> Dict[str, Any]:
-        return await self._batched(payload)
 
 
 def build_app(

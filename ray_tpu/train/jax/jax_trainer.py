@@ -2,10 +2,11 @@
 ... shards JAX/Flax train_loop_per_worker across a v5e pod").
 
 DataParallelTrainer with the JaxConfig backend: each worker is one jax
-process on one TPU host; inside train_loop_per_worker the user builds a
-global mesh (ray_tpu.parallel.create_mesh over jax.devices()) and jits a
-sharded train step — collectives ride ICI inside the program, dp/tp/sp
-layouts come from ray_tpu.parallel.sharding.
+process on one TPU host; inside train_loop_per_worker the user binds the
+trainer's ShardingConfig to the global device view
+(ray_tpu.train.sharding.plan_from_context) and jits a sharded train step
+through the plan — collectives ride ICI inside the program, the mesh and
+the layout of every parameter come from ray_tpu.train.sharding.
 """
 
 from __future__ import annotations

@@ -7,11 +7,11 @@ are always replicated; a leaf no rule matches is a TYPED error — silent
 replication of a 2 GB embedding is exactly the bug class this plane
 exists to remove.
 
-Differences from ``ray_tpu.parallel.sharding.ShardingRules`` (the
-Megatron dp/fsdp/tp/sp layout table used by the in-loop recipes): this
-module is config-first (specs are plain tuples of axis names so a
+This is the one table that maps a leaf to a ``PartitionSpec``.  It is
+config-first (specs are plain tuples of axis names so a
 ``ShardingConfig`` pickles into trainer state and travels to workers),
-uses the trainer-facing ``("batch", "model")`` axis vocabulary, and
+uses the trainer-facing ``("batch", "model")`` axis vocabulary (the
+``model`` axis is also the one ``ops.attention`` splits heads over), and
 *refuses* unmatched leaves instead of defaulting them.
 """
 

@@ -185,18 +185,6 @@ if [ "${RAY_TPU_SKIP_PROFILING_SMOKE:-0}" != "1" ]; then
   fi
 fi
 
-# Bench trajectory gate (warn-only): report like-for-like perf
-# regressions across the checked-in BENCH lineage; cross-platform
-# captures (on_tpu mismatch) are skipped loudly, never scored.  Warn
-# mode: a human promotes warnings to blocks — perf boxes vary.
-# Skippable via RAY_TPU_SKIP_BENCH_GATE=1.
-if [ "${RAY_TPU_SKIP_BENCH_GATE:-0}" != "1" ]; then
-  if ! timeout -k 5 30 python scripts/bench_gate.py --warn-only; then
-    echo "bench gate step failed"
-    [ "$rc" -eq 0 ] && rc=1
-  fi
-fi
-
 # Sharded train smoke (GSPMD + MPMD planes end-to-end on CPU devices):
 # batch x model mesh loss parity vs data parallel, per-shard checkpoint
 # re-shard across a mesh resize, and a 2-stage pipeline over real

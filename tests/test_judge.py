@@ -1,0 +1,42 @@
+"""The judge's own cluster-free checks (``benchmark/tests``), run with
+tier-1 so that a PR which breaks the yardstick's arithmetic, its data
+files or the command's refusal without a chip sees it here: trace
+reduction, FLOPs and grouped-matmul work against hand-worked numbers,
+traffic from the seed, the burst-edged rate, the data files against
+``BENCHMARK.json``.
+
+They are imported, not copied: ``benchmark/`` is the yardstick and only a
+``benchmark`` PR edits it.  The end-to-end cells at tiny size stay out
+(they start clusters; ``python -m pytest benchmark/tests -q`` runs them).
+"""
+
+import os
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+pytest.register_assert_rewrite(
+    "benchmark.tests.test_benchmark", "benchmark.tests.test_olmoe_cell"
+)
+
+from benchmark.tests.test_benchmark import (  # noqa: E402,F401
+    test_closed_loop_rate_is_cut_at_bursts,
+    test_data_files_load_and_agree_with_benchmark_json,
+    test_flops_against_hand_worked_numbers,
+    test_operation_names_are_cut_to_instruction_and_target,
+    test_recorded_chip_trace_reduces,
+    test_run_refuses_without_a_chip,
+    test_stats_delta_arithmetic,
+    test_trace_readers_on_the_reduced_trace,
+    test_trace_reduction_busy_union_time_by_name_and_gaps,
+    test_traffic_from_the_seed,
+)
+from benchmark.tests.test_olmoe_cell import (  # noqa: E402,F401
+    test_every_seed_takes_the_pool_from_the_head_of_the_same_order,
+    test_grouped_matmul_work_and_roofline_share_by_hand,
+    test_runner_fails_at_once_where_the_program_has_no_such_family,
+)

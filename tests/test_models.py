@@ -1,4 +1,5 @@
-"""GPT-2 model + sharded train step on the virtual 8-device CPU mesh."""
+"""GPT-2, Llama and ViT models; sharded train steps through the sharding
+plan on the virtual 8-device CPU mesh."""
 
 import numpy as np
 import pytest
@@ -25,64 +26,39 @@ def test_gpt2_forward_shapes():
 
 
 def test_gpt2_sharded_train_step_dp_tp_sp():
-    """Full dp×tp×sp train step: params tp/fsdp-sharded, batch dp-sharded,
-    sequence sp-sharded through ring attention; loss decreases."""
+    """Full batch x model x sequence train step through the sharding
+    plan: params sharded over `model` by the one rule table, batch over
+    `batch`, the sequence over `seq` through ring attention inside the
+    plan-jitted step; the first loss is the unsharded model's and it
+    decreases."""
     from ray_tpu.models import gpt2
-    from ray_tpu.parallel import create_mesh
+    from ray_tpu.train import sharding
 
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 virtual devices")
-    mesh = create_mesh({"dp": 2, "tp": 2, "sp": 2})
-    cfg = gpt2.GPT2Config.tiny(dtype=jnp.float32, mesh=mesh, sp_axis="sp")
+    plan = sharding.build_plan(sharding.ShardingConfig(
+        mesh=("batch", "model", "seq"), mesh_shape={"batch": 2, "model": 2, "seq": 2},
+    ))
+    assert dict(plan.mesh.shape) == {"batch": 2, "model": 2, "seq": 2}
+    cfg = gpt2.GPT2Config.tiny(dtype=jnp.float32, mesh=plan.mesh, sp_axis="seq")
     opt = gpt2.make_adamw(lr=1e-2)
-    params, opt_state, specs = gpt2.make_sharded_train_state(cfg, mesh, opt)
-    step = gpt2.make_sharded_train_step(cfg, mesh, opt)
+    params, opt_state = plan.shard_init(lambda rng: gpt2.init_params(cfg, rng), opt)
+    assert params["h_0"]["attn"]["qkv"]["kernel"].sharding.spec == (None, "model")
+    step = plan.jit_train_step(gpt2.make_train_step(cfg, opt), params, opt_state)
     tokens, targets = _batch(cfg, B=4, T=64)
+    text = step.lower(params, opt_state, tokens, targets).as_text()
+    assert "collective_permute" in text, "no ring over the sequence axis in the step"
+    # the same function as the unsharded model with einsum attention
+    want = float(gpt2.loss_fn(
+        jax.device_get(params), tokens, targets, gpt2.GPT2Config.tiny(dtype=jnp.float32)
+    ))
     losses = []
     for i in range(5):
         params, opt_state, loss = step(params, opt_state, tokens, targets)
         losses.append(float(loss))
     assert all(np.isfinite(losses))
+    assert losses[0] == pytest.approx(want, abs=1e-4)
     assert losses[-1] < losses[0], f"loss did not decrease: {losses}"
-
-
-def test_gpt2_tp_matches_single_device():
-    """The sharded forward must compute the same function as unsharded."""
-    from ray_tpu.models import gpt2
-    from ray_tpu.parallel import create_mesh
-    from ray_tpu.parallel.sharding import gpt_sharding_rules, infer_param_spec, shard_tree
-
-    if len(jax.devices()) < 4:
-        pytest.skip("needs 4 virtual devices")
-    cfg = gpt2.GPT2Config.tiny(dtype=jnp.float32)
-    params = gpt2.init_params(cfg)
-    tokens, _ = _batch(cfg, B=2, T=32)
-    ref = gpt2.GPT2(cfg).apply({"params": params}, tokens)
-
-    mesh = create_mesh({"dp": 2, "tp": 2})
-    specs = infer_param_spec(params, gpt_sharding_rules(), mesh)
-    sharded = shard_tree(params, mesh, specs)
-    out = jax.jit(lambda p, t: gpt2.GPT2(cfg).apply({"params": p}, t))(sharded, tokens)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-4, atol=1e-4)
-
-
-def test_param_sharding_rules_hit_tp_axes():
-    from ray_tpu.models import gpt2
-    from ray_tpu.parallel import create_mesh
-    from ray_tpu.parallel.sharding import gpt_sharding_rules, infer_param_spec
-
-    if len(jax.devices()) < 8:
-        pytest.skip("needs 8 virtual devices")
-    mesh = create_mesh({"dp": 2, "tp": 4})
-    cfg = gpt2.GPT2Config.tiny(dtype=jnp.float32)
-    abstract = jax.eval_shape(lambda: gpt2.init_params(cfg))
-    specs = infer_param_spec(abstract, gpt_sharding_rules(), mesh)
-    flat = {"/".join(str(getattr(k, "key", k)) for k in path): s
-            for path, s in jax.tree_util.tree_flatten_with_path(specs)[0]}
-    qkv = [s for p, s in flat.items() if "qkv/kernel" in p]
-    assert qkv and all("tp" in str(s) for s in qkv), flat
-    down = [s for p, s in flat.items() if "mlp_down/kernel" in p]
-    assert down and all(str(s).startswith("PartitionSpec('tp'") for s in down)
 
 
 # ---------------------------------------------------------------------------
@@ -116,17 +92,31 @@ def test_llama_gqa_kv_heads_smaller():
 
 
 def test_llama_sharded_train_step():
+    """The one rule table serves a second parameter tree: partition
+    rules given with the ShardingConfig, the same plan recipe."""
     from ray_tpu.models import llama
-    from ray_tpu.parallel import create_mesh
+    from ray_tpu.train import sharding
 
     devs = jax.devices()
     if len(devs) < 4:
         pytest.skip("needs 4 devices")
-    mesh = create_mesh({"dp": 2, "tp": 2}, devs[:4])
-    cfg = llama.LlamaConfig.tiny(mesh=mesh)
+    plan = sharding.build_plan(
+        sharding.ShardingConfig(
+            mesh_shape={"batch": 2, "model": 2},
+            partition_rules=[
+                (r"token_embed/embedding", ("model", None)),
+                (r"(q_proj|k_proj|v_proj|gate_proj|up_proj)/kernel", (None, "model")),
+                (r"(o_proj|down_proj)/kernel", ("model", None)),
+                (r"lm_head/kernel", (None, "model")),
+                (r"(ln_attn|ln_mlp|ln_f)/scale", ()),
+            ],
+        ),
+        devs[:4],
+    )
+    cfg = llama.LlamaConfig.tiny()
     opt = __import__("optax").sgd(1e-2)
-    params, opt_state, specs = llama.make_sharded_train_state(cfg, mesh, opt)
-    step = llama.make_sharded_train_step(cfg, mesh, opt)
+    params, opt_state = plan.shard_init(lambda rng: llama.init_params(cfg, rng), opt)
+    step = plan.jit_train_step(llama.make_train_step(cfg, opt), params, opt_state)
     toks = np.random.default_rng(1).integers(0, cfg.vocab_size, (4, 65), dtype=np.int32)
     t, y = jnp.asarray(toks[:, :-1]), jnp.asarray(toks[:, 1:])
     losses = []
@@ -134,10 +124,13 @@ def test_llama_sharded_train_step():
         params, opt_state, loss = step(params, opt_state, t, y)
         losses.append(float(loss))
     assert losses[-1] < losses[0]  # learns on the repeated batch
-    # tp layout hit the projections
-    flat = {"/".join(str(getattr(k, "key", k)) for k in path): s
-            for path, s in jax.tree_util.tree_flatten_with_path(specs)[0]}
-    assert any("q_proj/kernel" in p and "tp" in str(s) for p, s in flat.items())
+    # the model axis hit the projections, each leaf half a device
+    attn = params["h_0"]["attn"]
+    assert attn["q_proj"]["kernel"].sharding.spec == (None, "model")
+    assert attn["o_proj"]["kernel"].sharding.spec == ("model", None)
+    # a tree the rules do not cover is refused, not replicated
+    with pytest.raises(sharding.UnmatchedParamError, match="ln_f/scale"):
+        sharding.match_partition_rules(plan.config.rules()[:-1], params)
 
 
 def test_llama_rope_rotation_properties():
@@ -150,59 +143,6 @@ def test_llama_rope_rotation_properties():
                        np.linalg.norm(np.asarray(x), axis=-1), atol=1e-4)
     # ...and position 0 is the identity rotation.
     assert np.allclose(np.asarray(r[:, 0]), np.asarray(x[:, 0]), atol=1e-6)
-
-
-# ---------------------------------------------------------------------------
-# MoE / expert parallelism
-
-
-def test_moe_routes_and_learns():
-    from ray_tpu.models.moe import MoEConfig, MoEMLP
-
-    cfg = MoEConfig(d_model=32, d_ff=64, num_experts=4, top_k=2, dtype=jnp.float32)
-    mod = MoEMLP(cfg)
-    x = jnp.asarray(np.random.default_rng(3).standard_normal((2, 16, 32)), dtype=jnp.float32)
-    params = mod.init(jax.random.PRNGKey(0), x)["params"]
-    out, aux = mod.apply({"params": params}, x)
-    assert out.shape == x.shape
-    assert np.isfinite(float(aux)) and float(aux) > 0
-
-    def loss(p):
-        y, aux = mod.apply({"params": p}, x)
-        return ((y - x) ** 2).mean() + aux
-
-    grads = jax.grad(loss)(params)
-    norms = [float(jnp.linalg.norm(g)) for g in jax.tree_util.tree_leaves(grads)]
-    assert all(np.isfinite(n) for n in norms) and sum(norms) > 0
-
-
-def test_moe_expert_parallel_matches_single_device():
-    """ep-sharded execution must compute exactly what one device does."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from ray_tpu.models.moe import MoEConfig, MoEMLP, moe_sharding_rules
-    from ray_tpu.parallel import create_mesh
-    from ray_tpu.parallel.sharding import infer_param_spec, tree_shardings
-
-    devs = jax.devices()
-    if len(devs) < 4:
-        pytest.skip("needs 4 devices")
-    mesh = create_mesh({"ep": 4}, devs[:4])
-    cfg = MoEConfig(d_model=32, d_ff=64, num_experts=8, top_k=2, dtype=jnp.float32)
-    mod = MoEMLP(cfg)
-    x = jnp.asarray(np.random.default_rng(4).standard_normal((2, 16, 32)), dtype=jnp.float32)
-    params = mod.init(jax.random.PRNGKey(1), x)["params"]
-    ref_out, ref_aux = mod.apply({"params": params}, x)
-
-    specs = infer_param_spec(params, moe_sharding_rules(), mesh)
-    flat = {"/".join(str(getattr(k, "key", k)) for k in path): s
-            for path, s in jax.tree_util.tree_flatten_with_path(specs)[0]}
-    assert str(flat["experts_gate"]).startswith("PartitionSpec('ep'"), flat
-    sharded_params = jax.device_put(params, tree_shardings(mesh, specs))
-    x_sharded = jax.device_put(x, NamedSharding(mesh, P()))
-    out, aux = jax.jit(lambda p, v: mod.apply({"params": p}, v))(sharded_params, x_sharded)
-    assert np.allclose(np.asarray(out), np.asarray(ref_out), atol=1e-4)
-    assert abs(float(aux) - float(ref_aux)) < 1e-5
 
 
 def test_vit_overfits_synthetic_batch():

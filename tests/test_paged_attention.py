@@ -1,7 +1,8 @@
 """Decode attention over the paged KV pool (ops.attention
 .paged_decode_attention) against the gathered reference: the lane's
 positions gathered from the same pool (``k_pages[layer][idx]``) and
-attended by what ``gpt2.decode_forward`` uses.
+attended by ``reference_decode_attention``; and the whole decode step of
+the tiny model against the Flax model's full forward.
 
 Both paths on the CPU: the plain ``jax.numpy`` path the engine takes off
 the TPU, and the Pallas kernel in interpret mode.  Nothing here is a
@@ -139,10 +140,12 @@ def test_same_sequences_on_other_physical_pages_bit_identical(heads, path):
 
 
 @pytest.mark.parametrize("path", ["plain", "interpret"])
-def test_decode_forward_paged_matches_decode_forward(path, monkeypatch):
-    """The whole decode step of the tiny model: logits and new K/V over
-    the pool read in place against ``decode_forward`` over the same
-    pool gathered to a contiguous context."""
+def test_decode_forward_paged_matches_full_forward(path, monkeypatch):
+    """The whole decode step of the tiny model over a pool that holds
+    real sequences, pages of different lanes interleaved: the logits at
+    the fed position against the Flax model's full forward over the
+    sequence (no cache, no paging: an independent program), the new K/V
+    against the prompt forward's at that position."""
     from ray_tpu.models import gpt2
 
     if path == "interpret":
@@ -156,24 +159,30 @@ def test_decode_forward_paged_matches_decode_forward(path, monkeypatch):
     lens = [37, 0, 120, 16]
     B, pages = len(lens), cfg.max_seq_len // BS
     rng = np.random.default_rng(5)
-    slots = (B * pages + 1) * BS
-    kp, vp = (jnp.asarray(rng.standard_normal((cfg.n_layer, slots, cfg.d_model)), cfg.dtype)
-              for _ in range(2))
+    # each lane's sequence up to and with its fed token; attention is
+    # causal, so what lies beyond it in the row moves nothing before it
+    seqs = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, max(lens) + 1)), jnp.int32)
+    _, k_all, v_all = gpt2.prefill_forward(params, cfg, seqs)  # [L, B, T, H, Dh]
     tables = np.zeros((B, pages), np.int32)
     order = rng.permutation(np.arange(1, B * pages + 1))
     for b in range(B):
         tables[b] = order[b * pages:(b + 1) * pages]
-    idx, mask = _slots(tables, lens, cfg.max_seq_len)
-    tok = jnp.asarray(rng.integers(0, cfg.vocab_size, B), jnp.int32)
-    pos = jnp.asarray(lens, jnp.int32)
-    d_head = cfg.d_model // cfg.n_head
-    ctx = (cfg.n_layer, B, cfg.max_seq_len, cfg.n_head, d_head)
-    want = gpt2.decode_forward(
-        params, cfg, tok, pos, kp[:, idx].reshape(ctx), vp[:, idx].reshape(ctx), jnp.asarray(mask))
-    got = gpt2.decode_forward_paged(params, cfg, tok, kp, vp, jnp.asarray(tables), pos, BS)
-    for w, g in zip(want, got):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=2e-4, rtol=2e-4)
-    assert np.array_equal(np.argmax(got[0], -1), np.argmax(want[0], -1))
+    idx, _ = _slots(tables, lens, cfg.max_seq_len)
+    slots = (B * pages + 1) * BS
+    kp, vp = (rng.standard_normal((cfg.n_layer, slots, cfg.d_model)).astype(np.float32)
+              for _ in range(2))  # what a lane does not hold is noise, not zeros
+    for b, n in enumerate(lens):
+        kp[:, idx[b, :n]] = np.asarray(k_all[:, b, :n]).reshape(cfg.n_layer, n, cfg.d_model)
+        vp[:, idx[b, :n]] = np.asarray(v_all[:, b, :n]).reshape(cfg.n_layer, n, cfg.d_model)
+    rows, pos = np.arange(B), jnp.asarray(lens, jnp.int32)
+    logits, k_new, v_new = gpt2.decode_forward_paged(
+        params, cfg, seqs[rows, pos], jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(tables), pos, BS)
+    want = gpt2.GPT2(cfg).apply({"params": params}, seqs)[rows, pos]
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(want), atol=2e-4, rtol=2e-4)
+    assert np.array_equal(np.argmax(logits, -1), np.argmax(want, -1))
+    np.testing.assert_allclose(np.asarray(k_new), np.asarray(k_all[:, rows, pos]), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(v_new), np.asarray(v_all[:, rows, pos]), atol=2e-5)
 
 
 @pytest.mark.parametrize("n_head, d_head, block_size, dtype, takes", [

@@ -1,8 +1,8 @@
 """Profiling & bottleneck-attribution plane: the on-demand sampling
 profiler (attach / dump / merge / export), its lifecycle edges
 (conflict, dies mid-capture, raylet kill), the <5% attached-overhead
-guard, JAX/XLA introspection, dataplane counters, and the bench
-trajectory gate (reference: `ray timeline` + py-spy attach workflows).
+guard, JAX/XLA introspection and dataplane counters (reference:
+`ray timeline` + py-spy attach workflows).
 """
 
 import json
@@ -386,138 +386,8 @@ def test_compiled_dag_stats_expose_dataplane(cluster):
 
 
 # ----------------------------------------------------------------------
-# bench trajectory gate
+# attach edges
 # ----------------------------------------------------------------------
-def _gate():
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_gate",
-        os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                     "scripts", "bench_gate.py"),
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_bench_gate_refuses_cross_platform_comparison():
-    gate = _gate()
-    lineage = [
-        {"round": 1, "parsed": {"metric": "m"}, "metric": "m", "value": 100.0,
-         "on_tpu": True},
-        {"round": 2, "parsed": {"metric": "m"}, "metric": "m", "value": 10.0,
-         "on_tpu": False},  # 10x lower but CPU: must be a SKIP, not a regression
-    ]
-    result = gate.check_lineage(lineage)
-    assert result["regressions"] == []
-    assert any("CROSS-PLATFORM" in s["reason"] for s in result["skips"])
-
-
-def test_bench_gate_skips_missing_provenance():
-    gate = _gate()
-    lineage = [
-        {"round": 1, "parsed": {"metric": "m"}, "metric": "m", "value": 100.0,
-         "on_tpu": None},
-    ]
-    result = gate.check_lineage(lineage)
-    assert result["regressions"] == [] and result["ok"] == []
-    assert any("PROVENANCE" in s["reason"] for s in result["skips"])
-
-
-def test_bench_gate_flags_like_for_like_regression():
-    gate = _gate()
-    lineage = [
-        {"round": 1, "parsed": {"metric": "m"}, "metric": "m", "value": 100.0,
-         "on_tpu": True},
-        {"round": 2, "parsed": {"metric": "m"}, "metric": "m", "value": 80.0,
-         "on_tpu": True},  # -20% on the same platform
-        {"round": 3, "parsed": {"metric": "m"}, "metric": "m", "value": 79.0,
-         "on_tpu": True},  # -1.2% vs round 2: fine
-    ]
-    result = gate.check_lineage(lineage)
-    assert len(result["regressions"]) == 1
-    reg = result["regressions"][0]
-    assert reg["from_round"] == 1 and reg["to_round"] == 2
-    assert len(result["ok"]) == 1
-
-
-def test_bench_gate_rate_metrics_are_throughputs():
-    """`*_per_s` / `*_per_sec` metrics end in a seconds-ish suffix but
-    are throughputs: a drop must flag, a rise must not (the BENCH_micro
-    `put_small_per_s` class)."""
-    gate = _gate()
-    assert gate._higher_is_better("put_small_per_s")
-    assert gate._higher_is_better("ppo_env_steps_per_sec")
-    assert not gate._higher_is_better("serve_ttft_seconds")
-    result = gate.compare_metric_dicts(
-        {"put_small_per_s": {"value": 1900.0, "on_tpu": False}},
-        {"put_small_per_s": {"value": 1000.0, "on_tpu": False}},
-    )
-    assert len(result["regressions"]) == 1  # 47% throughput drop flags
-    result_up = gate.compare_metric_dicts(
-        {"put_small_per_s": {"value": 1900.0, "on_tpu": False}},
-        {"put_small_per_s": {"value": 2500.0, "on_tpu": False}},
-    )
-    assert result_up["regressions"] == []  # improvement is not a regression
-
-
-def test_bench_gate_latency_direction():
-    gate = _gate()
-    lineage = [
-        {"round": 1, "parsed": {"metric": "p99_latency_seconds"},
-         "metric": "p99_latency_seconds", "value": 1.0, "on_tpu": False},
-        {"round": 2, "parsed": {"metric": "p99_latency_seconds"},
-         "metric": "p99_latency_seconds", "value": 1.5, "on_tpu": False},
-    ]
-    result = gate.check_lineage(lineage)
-    assert len(result["regressions"]) == 1  # latency UP = regression
-
-
-def test_bench_gate_platform_field_beats_on_tpu():
-    """Two non-TPU captures on DIFFERENT backends (gpu vs cpu) must not
-    be scored like-for-like just because on_tpu is False on both."""
-    gate = _gate()
-    lineage = [
-        {"round": 1, "parsed": {"metric": "m"}, "metric": "m", "value": 100.0,
-         "on_tpu": False, "platform": "gpu"},
-        {"round": 2, "parsed": {"metric": "m"}, "metric": "m", "value": 10.0,
-         "on_tpu": False, "platform": "cpu"},
-    ]
-    result = gate.check_lineage(lineage)
-    assert result["regressions"] == []
-    assert any("CROSS-PLATFORM" in s["reason"] for s in result["skips"])
-
-
-def test_bench_gate_legacy_on_tpu_comparable_with_platform_stamped():
-    """A legacy on_tpu-only capture must still score against a newer
-    platform-stamped capture of the same on_tpu value (the coarse
-    evidence doesn't contradict the fine) — r05 (on_tpu:false) vs a
-    new platform:'cpu' capture is the live case."""
-    gate = _gate()
-    lineage = [
-        {"round": 5, "parsed": {"metric": "m"}, "metric": "m", "value": 100.0,
-         "on_tpu": False},  # legacy: no platform field
-        {"round": 6, "parsed": {"metric": "m"}, "metric": "m", "value": 50.0,
-         "on_tpu": False, "platform": "cpu"},
-    ]
-    result = gate.check_lineage(lineage)
-    assert len(result["regressions"]) == 1  # scored, and the -50% flags
-    # And a TPU capture after a CPU blip still scores against the last
-    # TPU point, not the blip.
-    lineage2 = [
-        {"round": 3, "parsed": {"metric": "m"}, "metric": "m", "value": 100.0,
-         "on_tpu": True, "platform": "tpu"},
-        {"round": 5, "parsed": {"metric": "m"}, "metric": "m", "value": 10.0,
-         "on_tpu": False, "platform": "cpu"},
-        {"round": 6, "parsed": {"metric": "m"}, "metric": "m", "value": 95.0,
-         "on_tpu": True, "platform": "tpu"},
-    ]
-    result2 = gate.check_lineage(lineage2)
-    assert result2["regressions"] == []
-    assert any(c["from_round"] == 3 and c["to_round"] == 6 for c in result2["ok"])
-
-
 def test_profile_foreign_session_is_error_not_shared(cluster):
     """A conflict with a session some OTHER operator started must
     surface as an error (the target's samples are missing from this
@@ -541,38 +411,6 @@ def test_profile_foreign_session_is_error_not_shared(cluster):
         )
 
 
-def test_bench_gate_compare_refuses_missing_provenance():
-    """--compare on provenance-less metric dicts must skip loudly, not
-    score (same contract as the lineage path)."""
-    gate = _gate()
-    result = gate.compare_metric_dicts(
-        {"m": {"value": 100.0}}, {"m": {"value": 10.0}}
-    )
-    assert result["regressions"] == []
-    assert any("PROVENANCE" in s["reason"] for s in result["skips"])
-
-
-def test_bench_gate_skips_error_records():
-    """An infra-failure record (error key, value 0) must never score as
-    a like-for-like regression against a real capture."""
-    gate = _gate()
-    lineage = [
-        {"round": 1, "parsed": {"metric": "m"}, "metric": "m", "value": 100.0,
-         "on_tpu": False},
-        {"round": 2, "parsed": {"metric": "m", "error": "backend hung"},
-         "metric": "m", "value": 0.0, "on_tpu": False},
-    ]
-    result = gate.check_lineage(lineage)
-    assert result["regressions"] == []
-    assert any("BENCH FAILED" in s["reason"] for s in result["skips"])
-    dict_result = gate.compare_metric_dicts(
-        {"m": {"value": 100.0, "on_tpu": False}},
-        {"m": {"value": 0.0, "on_tpu": False, "error": "oom"}},
-    )
-    assert dict_result["regressions"] == []
-    assert any("BENCH FAILED" in s["reason"] for s in dict_result["skips"])
-
-
 def test_resolve_targets_rejects_unknown_types():
     """A wrong-typed target must raise, not silently widen to a
     cluster-wide capture."""
@@ -585,25 +423,6 @@ def test_resolve_targets_rejects_unknown_types():
         up.resolve_targets(123, must_not_call)
     with pytest.raises(ValueError):
         up.resolve_targets(b"\x01\x02", must_not_call)
-
-
-def test_bench_gate_warn_only_exit_code(tmp_path):
-    gate = _gate()
-    # A real regression in a scratch lineage: strict fails, warn passes.
-    for n, value in ((1, 100.0), (2, 50.0)):
-        with open(tmp_path / f"BENCH_r0{n}.json", "w") as f:
-            json.dump({"n": n, "parsed": {
-                "metric": "m", "value": value, "on_tpu": True}}, f)
-    assert gate.main(["--repo", str(tmp_path)]) == 1
-    assert gate.main(["--repo", str(tmp_path), "--warn-only"]) == 0
-
-
-def test_bench_gate_checked_in_lineage_warn_only():
-    """The verify.sh invocation must succeed against the real lineage
-    (r04/r05 off-TPU captures are skips, not regressions)."""
-    gate = _gate()
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    assert gate.main(["--repo", repo, "--warn-only"]) == 0
 
 
 # ----------------------------------------------------------------------
@@ -666,18 +485,3 @@ def test_profile_worker_through_raylet_kill():
     finally:
         ray_tpu.shutdown()
         c.shutdown()
-
-
-def test_bench_gate_compare_metric_dicts_cross_platform():
-    gate = _gate()
-    old = {"m": {"value": 100.0, "on_tpu": True}}
-    new = {"m": {"value": 10.0, "on_tpu": False}}
-    result = gate.compare_metric_dicts(old, new)
-    assert result["regressions"] == []
-    assert any("CROSS-PLATFORM" in s["reason"] for s in result["skips"])
-    # like-for-like regression flags
-    result2 = gate.compare_metric_dicts(
-        {"m": {"value": 100.0, "on_tpu": False}},
-        {"m": {"value": 60.0, "on_tpu": False}},
-    )
-    assert len(result2["regressions"]) == 1

@@ -76,16 +76,21 @@ def test_gspmd_mesh_shards_params_and_state():
     )
 
 
-def test_gspmd_loss_parity_vs_data_parallel():
+@pytest.mark.parametrize(
+    "mesh_shape", [{"batch": -1, "model": 2}, {"batch": 1, "model": 2}],
+    ids=["batch4xmodel2", "model2"],
+)
+def test_gspmd_loss_parity_vs_data_parallel(mesh_shape):
     """The acceptance bar: batch x model sharded GPT-2 trains to the
-    same losses as the pure data-parallel layout (same seed/data)."""
+    same losses as the pure data-parallel layout (same seed/data); with
+    batch 1 the model axis alone carries the step, so the sharded
+    program computes the same function as the unsharded one."""
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 devices")
     cfg = _tiny_cfg()
     data = _data()
-    plan_tp = sharding.build_plan(
-        sharding.ShardingConfig(mesh_shape={"batch": -1, "model": 2})
-    )
+    plan_tp = sharding.build_plan(sharding.ShardingConfig(mesh_shape=mesh_shape))
+    assert plan_tp.mesh.shape["model"] == 2
     plan_dp = sharding.build_plan(
         sharding.ShardingConfig(
             mesh=("batch",), mesh_shape={"batch": 8},

@@ -83,25 +83,34 @@ def test_spec_clipped_to_rank_and_mesh_divisibility():
     assert spec["ghost"]["kernel"] == P(None, "model")
 
 
-def test_gpt2_rule_set_covers_and_shards_gpt2_tiny():
+@pytest.mark.parametrize("on_mesh", [False, True], ids=["no_mesh", "batch2xmodel4"])
+@pytest.mark.parametrize("preset", ["tiny", "medium", "large"])
+def test_gpt2_rule_set_covers_and_shards_gpt2_tiny(preset, on_mesh):
     """The shipped rule set must cover EVERY gpt2 leaf (no
-    UnmatchedParamError) and produce the Megatron pairing."""
+    UnmatchedParamError) and produce the Megatron pairing, for the tiny
+    preset and for the two the train cells run (abstract trees); on a
+    mesh whose model axis is 4 wide no dim of theirs is clipped back to
+    replicated."""
     from ray_tpu.models import gpt2
 
-    cfg = gpt2.GPT2Config.tiny(remat=False)
+    if len(jax.devices()) < 8:
+        pytest.skip("needs 8 devices")
+    cfg = getattr(gpt2.GPT2Config, preset)(remat=False)
     params = jax.eval_shape(lambda: gpt2.init_params(cfg))
-    spec = match_partition_rules(gpt2_partition_rules(), params)
+    mesh = Mesh(np.array(jax.devices()[:8]).reshape(2, 4), ("batch", "model")) if on_mesh else None
+    spec = match_partition_rules(gpt2_partition_rules(), params, mesh)
     assert spec["wte"]["embedding"] == P("model", None)
     assert spec["wpe"]["embedding"] == P(None, None)
-    blk = spec["h_0"]
-    assert blk["attn"]["qkv"]["kernel"] == P(None, "model")
-    assert blk["attn"]["attn_out"]["kernel"] == P("model", None)
-    assert blk["mlp"]["mlp_up"]["kernel"] == P(None, "model")
-    assert blk["mlp"]["mlp_down"]["kernel"] == P("model", None)
+    for i in range(cfg.n_layer):
+        blk = spec[f"h_{i}"]
+        assert blk["attn"]["qkv"]["kernel"] == P(None, "model")
+        assert blk["attn"]["attn_out"]["kernel"] == P("model", None)
+        assert blk["mlp"]["mlp_up"]["kernel"] == P(None, "model")
+        assert blk["mlp"]["mlp_down"]["kernel"] == P("model", None)
+        # norms/biases replicate (specs pad to rank: P(None) == replicated)
+        assert all(a is None for a in blk["ln_1"]["scale"])
+        assert all(a is None for a in blk["attn"]["qkv"]["bias"])
     assert spec["lm_head"]["kernel"] == P(None, "model")
-    # norms/biases replicate (specs pad to rank: P(None) == replicated)
-    assert all(a is None for a in blk["ln_1"]["scale"])
-    assert all(a is None for a in blk["attn"]["qkv"]["bias"])
     assert all(a is None for a in spec["ln_f"]["bias"])
 
 

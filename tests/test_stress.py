@@ -1,7 +1,6 @@
 """Scalability-envelope stress tests (reference:
 release/benchmarks/distributed/test_many_{actors,tasks,pgs}.py, scaled
-to this one-core CI box; the full-size envelope numbers live in
-BENCH_micro.json's stress_* entries, produced by bench_stress.py).
+to this one-core CI box).
 
 What must hold even under saturation:
 - everything COMPLETES (no deadlocks, no lost tasks/actors/PGs)

@@ -206,16 +206,15 @@ def test_failure_without_retries_raises(ray_cluster, tmp_path):
 def _gpt2_data_loop(config):
     """The BASELINE configs[3] shape in miniature: every worker is one
     jax.distributed process of a single global mesh; the sharded GPT-2
-    step (dp × tp Megatron layout) consumes batches straight from this
-    rank's Dataset.streaming_split shard via iter_jax_batches
+    step (the trainer's batch x model plan) consumes batches straight
+    from this rank's Dataset.streaming_split shard via iter_jax_batches
     (reference: train/data_parallel_trainer.py:428 + dataset.py:1482)."""
     import jax
     import numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as P
 
     from ray_tpu import train
     from ray_tpu.models import gpt2
-    from ray_tpu.parallel import create_mesh
+    from ray_tpu.train import sharding
 
     ctx = train.get_context()
     assert ctx.get_world_size() == config["num_workers"]
@@ -229,20 +228,20 @@ def _gpt2_data_loop(config):
 
     multihost_utils.sync_global_devices("gpt2_data_loop_start")
 
-    mesh = create_mesh({"dp": n_global // 2, "tp": 2}, jax.devices())
+    plan = sharding.plan_from_context()
+    assert dict(plan.mesh.shape) == {"batch": n_global // 2, "model": 2}
     cfg = gpt2.GPT2Config(
-        vocab_size=256, n_layer=1, n_head=2, d_model=64, max_seq_len=64, mesh=mesh
+        vocab_size=256, n_layer=1, n_head=2, d_model=64, max_seq_len=64
     )
     opt = gpt2.make_adamw(1e-3)
-    params, opt_state, _specs = gpt2.make_sharded_train_state(cfg, mesh, opt)
-    step = gpt2.make_sharded_train_step(cfg, mesh, opt)
+    params, opt_state = plan.shard_init(lambda rng: gpt2.init_params(cfg, rng), opt)
+    step = plan.jit_train_step(gpt2.make_train_step(cfg, opt), params, opt_state)
 
     shard = train.get_dataset_shard("train")
-    data_sharding = NamedSharding(mesh, P("dp"))
     steps, last_loss = 0, None
     for batch in shard.iter_jax_batches(
         batch_size=config["per_worker_batch"],
-        sharding=data_sharding,
+        sharding=plan.data_sharding(),
         dtypes={"data": np.int32},
     ):
         toks = batch["data"]  # global [B, T+1] assembled across ranks
@@ -251,6 +250,12 @@ def _gpt2_data_loop(config):
         last_loss = float(jax.device_get(loss))
         steps += 1
     train.report({"loss": last_loss, "steps": steps})
+
+
+def _batch_by_model():
+    from ray_tpu.train.sharding import ShardingConfig
+
+    return ShardingConfig(mesh_shape={"batch": -1, "model": 2})
 
 
 def test_jax_trainer_sharded_gpt2_streaming_split(ray_cluster, tmp_path):
@@ -269,6 +274,7 @@ def test_jax_trainer_sharded_gpt2_streaming_split(ray_cluster, tmp_path):
         scaling_config=ScalingConfig(num_workers=2),
         run_config=RunConfig(name="gpt2_stream", storage_path=str(tmp_path)),
         datasets={"train": ds},
+        sharding_config=_batch_by_model(),
     )
     result = trainer.fit()
     assert result.metrics is not None
@@ -297,6 +303,7 @@ def test_typed_restore_sharded_gpt2_with_closure_loop(ray_cluster, tmp_path):
         scaling_config=ScalingConfig(num_workers=2),
         run_config=RunConfig(name="gpt2_typed_restore", storage_path=str(tmp_path)),
         datasets={"train": rdata.from_numpy(tokens)},
+        sharding_config=_batch_by_model(),
     )
     r1 = trainer.fit()
     assert r1.metrics["steps"] == 4
