@@ -176,8 +176,10 @@ def make_adamw(lr: float = 3e-4, weight_decay: float = 0.1):
 # paged block pool); these functions own the math.  They are pure-jnp
 # forwards over the same param tree the Flax module trains (names line
 # up 1:1 — wte/wpe/h_i/{ln_1,attn{qkv,attn_out},ln_2,mlp{...}}/ln_f/
-# lm_head), so served weights are exactly the trained ones.  Callers jit
-# them (the engine jits gather -> decode -> scatter as one step).
+# lm_head), so served weights are exactly the trained ones: the forwards
+# take that tree as it is, or as ``serving_params`` lays it out for a
+# server.  Callers jit them (the engine jits gather -> decode -> scatter
+# as one step).
 # ----------------------------------------------------------------------
 
 _LN_EPS = 1e-6  # flax.linen.LayerNorm default, matches the training path
@@ -196,6 +198,22 @@ def _dense(x, p, dtype):
     if "bias" in p:
         out = out + p["bias"].astype(dtype)
     return out
+
+
+def serving_params(params, cfg: GPT2Config):
+    """The tree a server holds: every leaf in the dtype ``prefill_forward``
+    and ``decode_forward_paged`` compute with.  The kernels and biases of
+    ``_dense`` and the two embeddings go to ``cfg.dtype`` once, here, and
+    not in every program that reads them; LayerNorm's scale and bias stay
+    as they are (``_ln`` multiplies in float32).  The forwards give the
+    same result to the bit on either tree: a trainer's float32 tree is
+    rounded at each use the same way."""
+
+    def held(path, leaf):
+        layer_norm = path[-2].key.startswith("ln_")
+        return leaf if layer_norm else leaf.astype(cfg.dtype)
+
+    return jax.tree_util.tree_map_with_path(held, params)
 
 
 def _split_heads(t, n_head):

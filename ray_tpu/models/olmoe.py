@@ -102,6 +102,12 @@ def init_params(cfg: OlmoeConfig, rng=None):
     return {**ends(keys[0]), "layers": [layer(key) for key in keys[1:]]}
 
 
+def serving_params(params, cfg: OlmoeConfig):
+    """The tree a server holds, which ``init_params`` already makes:
+    every leaf is in ``cfg.dtype``, as the forwards' matmuls read it."""
+    return params
+
+
 def _rmsnorm(x, w, eps):
     xf = x.astype(jnp.float32)
     out = xf * jax.lax.rsqrt((xf * xf).mean(-1, keepdims=True) + eps)

@@ -21,7 +21,9 @@ MODEL_FAMILIES = (
 def model_family(cfg):
     """The family module of a model config: the row of MODEL_FAMILIES
     that names its class.  A family gives the engine ``init_params(cfg,
-    rng)``, ``prefill_forward(params, cfg, tokens, last_index)`` and
+    rng)``, ``serving_params(params, cfg)`` (that tree as a server holds
+    it: every leaf in the dtype the two forwards compute with),
+    ``prefill_forward(params, cfg, tokens, last_index)`` and
     ``decode_forward_paged(params, cfg, tok, k_pages, v_pages,
     block_tables, lengths, block_size)``, both returning (logits, k, v)
     and optionally a small int32 vector of counters, named by the

@@ -288,7 +288,12 @@ class LLMEngine:
 
         cfg = self.model_cfg
         family = model_family(cfg)
-        self.params = family.init_params(cfg, rng=jax.random.PRNGKey(self.config.seed))
+        # only what the two programs read is kept, each leaf in the dtype
+        # they compute with: an argument in another dtype is read whole
+        # and cast again by every program
+        self.params = family.serving_params(
+            family.init_params(cfg, rng=jax.random.PRNGKey(self.config.seed)), cfg)
+        self._param_bytes = sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(self.params))
         # what the family's programs count and return after their tokens
         self._counter_names = tuple(getattr(family, "COUNTERS", ()))
         # a position is one row of all heads: a page is then one
@@ -504,6 +509,7 @@ class LLMEngine:
             "max_batch_size": self.config.max_batch_size,
             "platform": self._device.platform,
             "device_kind": self._device.device_kind,
+            "param_bytes": self._param_bytes,
             "kv_blocks_in_use": self.bm.blocks_in_use,
             "kv_blocks_total": self.bm.num_blocks - 1,
             "kv_leak_report": self.bm.leak_report(),
