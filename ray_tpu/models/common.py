@@ -10,11 +10,55 @@ jitting the step over it is ``ray_tpu.train.sharding``'s
 
 from __future__ import annotations
 
+from dataclasses import dataclass
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+
+@dataclass(frozen=True)
+class CacheSpec:
+    """What a family caches for a sequence it serves: its statement to
+    ``serve/llm/engine.py``, which allocates, donates, writes and counts
+    by it (docs/serving.md "Model families").
+
+    Pages a sequence reserves by its length: ``k_pages`` and ``v_pages``
+    of ``[paged_layers, slots, row_width]``, and for each of
+    ``page_extras`` (name, rows a page, width, dtype) a pool of
+    ``[paged_layers, pages * rows, width]`` addressed through the same
+    block table.  State a lane owns whatever its sequence's length: for
+    each of ``lane_state`` (name, shape, dtype) one array of ``[lanes,
+    *shape]``, which reads as zeros to the prefill program that starts a
+    sequence (position 0), by whatever path the sequence took the lane.
+
+    ``prefill_chunk`` is the most tokens one prefill program takes: a
+    longer prompt goes in as chunk programs in order, each reading what
+    the earlier ones wrote.  0: a prompt is one program, which reads no
+    cache."""
+
+    paged_layers: int
+    row_width: int
+    page_extras: tuple = ()
+    lane_state: tuple = ()
+    prefill_chunk: int = 0
+
+    @property
+    def reads_cache(self) -> bool:
+        """Whether the family's forwards read the cache themselves and
+        return what to write into it (``prefill_chunk``,
+        ``decode_forward_cached``), or the cache is K and V a layer,
+        which the plain forwards are handed (``prefill_forward``,
+        ``decode_forward_paged``)."""
+        return bool(self.page_extras or self.lane_state or self.prefill_chunk)
+
+    @property
+    def names(self) -> tuple:
+        """The cache's arrays in the order the engine's programs take
+        and return them."""
+        return ("k_pages", "v_pages", *(e[0] for e in self.page_extras),
+                *(s[0] for s in self.lane_state))
 
 
 def next_token_loss(logits: jax.Array, targets: jax.Array) -> jax.Array:

@@ -200,6 +200,15 @@ def _dense(x, p, dtype):
     return out
 
 
+def cache_spec(cfg: GPT2Config, block_size: int):
+    """What the family caches for a sequence it serves
+    (``models/common.py:CacheSpec``): K and V of all heads, a position,
+    in every layer; nothing else."""
+    from ray_tpu.models.common import CacheSpec
+
+    return CacheSpec(paged_layers=cfg.n_layer, row_width=cfg.d_model)
+
+
 def serving_params(params, cfg: GPT2Config):
     """The tree a server holds: every leaf in the dtype ``prefill_forward``
     and ``decode_forward_paged`` compute with.  The kernels and biases of

@@ -7,7 +7,7 @@ weights NOT renormalised.  Untied head, no biases.
 
 Pure functions over a parameter tree, like the inference plane of
 ``models/gpt2.py``; the module is a *family* to ``serve/llm/engine.py``:
-``init_params``, ``prefill_forward``, ``decode_forward_paged``, and a
+``init_params``, ``cache_spec``, ``prefill_forward``, ``decode_forward_paged``, and a
 config whose sizes go by the engine's names (``n_layer``, ``d_model``,
 ``n_head``, ``max_seq_len``, ``vocab_size``, ``dtype``).  Its forwards
 return, after K and V, the int32 counters named by ``COUNTERS``.
@@ -100,6 +100,14 @@ def init_params(cfg: OlmoeConfig, rng=None):
 
     keys = jax.random.split(rng, cfg.n_layer + 1)
     return {**ends(keys[0]), "layers": [layer(key) for key in keys[1:]]}
+
+
+def cache_spec(cfg: OlmoeConfig, block_size: int):
+    """What the family caches (``models/common.py:CacheSpec``): K and V
+    of all heads, a position, in every layer; nothing else."""
+    from ray_tpu.models.common import CacheSpec
+
+    return CacheSpec(paged_layers=cfg.n_layer, row_width=cfg.d_model)
 
 
 def serving_params(params, cfg: OlmoeConfig):

@@ -13,7 +13,9 @@ placement —
 Decode (one fed token a lane) has its own pair: over a contiguous
 context the einsum reference; over a paged KV pool the Pallas kernel
 that reads pages in place on TPU (ops.pallas_paged_attention), else a
-gather of the lane's pages and the same reference.
+gather of the lane's pages and the same reference; over CHOSEN blocks of
+the pool with grouped queries, the sibling kernel
+(ops.pallas_sparse_paged_attention) or a gather of the chosen pages.
 
 All paths: f32 accumulation, bf16 in/out, static shapes.
 """
@@ -84,6 +86,53 @@ def paged_decode_attention(q, k_self, v_self, k_pages, v_pages, layer,
     v_ctx = v_pages[layer][idx].reshape(B, C, H, Dh)
     mask = jnp.arange(C)[None, :] < lengths[:, None]
     return reference_decode_attention(q, k_self, v_self, k_ctx, v_ctx, mask)
+
+
+def sparse_paged_decode_attention(q, k_self, v_self, k_pages, v_pages, layer, chosen_pages,
+                                  chosen_blocks, counts, lengths, *, block_size, sparse_block):
+    """One fed token a lane over CHOSEN blocks of a paged KV pool, layer
+    ``layer``, and itself; grouped queries.
+
+    q [B, G, R, Dh]: R query heads to each of the G K/V heads; k_self,
+    v_self [B, G, Dh]; k_pages, v_pages [L, num_blocks * block_size,
+    G * Dh]; chosen_blocks [B, G, S] int32 the numbers of the blocks
+    (``sparse_block`` positions each) a (lane, K/V head) pair reads, of
+    which the first counts [B, G] are real; chosen_pages [B, G, S *
+    sparse_block / block_size] the physical pages of those blocks, in
+    order; lengths [B] the cached positions of a lane.  A chosen block
+    is read up to the lane's length.  Returns [B, G, R, Dh].
+
+    On a TPU, where the shapes fit its tiling, the Pallas kernel copies
+    the chosen pages alone (ops.pallas_sparse_paged_attention).
+    Elsewhere they are gathered first."""
+    B, G, R, Dh = q.shape
+    if jax.default_backend() == "tpu":  # as paged_decode_attention: the CPU tests gather
+        from ray_tpu.ops import pallas_sparse_paged_attention as kernel
+
+        if kernel.kernel_takes(R, Dh, block_size, sparse_block, k_pages.dtype):
+            return kernel.sparse_paged_decode_attention_kernel(
+                q, k_self, v_self, k_pages, v_pages, layer, chosen_pages, chosen_blocks, counts,
+                lengths, block_size=block_size, sparse_block=sparse_block,
+            )
+    S = chosen_blocks.shape[-1]
+    rows = (chosen_pages[..., None] * block_size + jnp.arange(block_size)).reshape(B, G, S * sparse_block)
+    heads = jnp.arange(G)[None, :, None]
+
+    def of_pair(pages):  # [B, G, C, G, Dh] -> a pair's own K/V head [B, G, C, Dh]
+        x = pages[layer][rows].reshape(B, G, S * sparse_block, G, Dh)
+        return jnp.take_along_axis(x, heads[..., None, None], axis=3)[:, :, :, 0]
+
+    k_ctx, v_ctx = of_pair(k_pages), of_pair(v_pages)
+    pos = (chosen_blocks[..., None] * sparse_block + jnp.arange(sparse_block)).reshape(B, G, -1)
+    mask = (jnp.arange(S * sparse_block) < (counts * sparse_block)[..., None]) & (
+        pos < lengths[:, None, None])
+    scale = 1.0 / (Dh ** 0.5)
+    s_ctx = jnp.einsum("bgrd,bgcd->bgrc", q, k_ctx).astype(jnp.float32) * scale
+    s_ctx = jnp.where(mask[:, :, None, :], s_ctx, jnp.float32(-1e30))
+    s_self = (q * k_self[:, :, None]).sum(-1).astype(jnp.float32)[..., None] * scale
+    probs = jax.nn.softmax(jnp.concatenate([s_ctx, s_self], axis=-1), axis=-1).astype(q.dtype)
+    att = jnp.einsum("bgrc,bgcd->bgrd", probs[..., :-1], v_ctx)
+    return att + probs[..., -1:] * v_self[:, :, None]
 
 
 def causal_attention(q, k, v, *, mesh=None, sp_axis: Optional[str] = None):

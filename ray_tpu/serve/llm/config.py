@@ -15,7 +15,11 @@ from typing import Any, Dict, Optional
 MODEL_FAMILIES = (
     ("ray_tpu.models.gpt2", "GPT2Config", ("tiny", "small", "medium", "large")),
     ("ray_tpu.models.olmoe", "OlmoeConfig", ("olmoe_tiny", "olmoe_1b_7b", "olmoe_1b_7b_12l")),
+    ("ray_tpu.models.minicpm_sala", "MiniCPMSalaConfig",
+     ("minicpm_sala_tiny", "minicpm_sala", "minicpm_sala_16l")),
 )
+# every preset ``LLMConfig.model`` may name, family by family
+PRESETS = " | ".join(name for *_, presets in MODEL_FAMILIES for name in presets)
 
 
 def model_family(cfg):
@@ -23,12 +27,21 @@ def model_family(cfg):
     that names its class.  A family gives the engine ``init_params(cfg,
     rng)``, ``serving_params(params, cfg)`` (that tree as a server holds
     it: every leaf in the dtype the two forwards compute with),
-    ``prefill_forward(params, cfg, tokens, last_index)`` and
-    ``decode_forward_paged(params, cfg, tok, k_pages, v_pages,
-    block_tables, lengths, block_size)``, both returning (logits, k, v)
-    and optionally a small int32 vector of counters, named by the
-    module's ``COUNTERS``; its config has ``n_layer``, ``d_model``,
-    ``n_head``, ``max_seq_len``, ``vocab_size`` and ``dtype``."""
+    ``cache_spec(cfg, block_size)`` (what it caches for a sequence:
+    ``models/common.py:CacheSpec``) and two forwards.  Where the cache
+    is K and V of every layer and no more: ``prefill_forward(params,
+    cfg, tokens, last_index)`` and ``decode_forward_paged(params, cfg,
+    tok, k_pages, v_pages, block_tables, lengths, block_size)``, both
+    returning (logits, k, v).  Where it states more (page extras, lane
+    state, prompts by chunks: ``CacheSpec.reads_cache``):
+    ``prefill_chunk(params, cfg, cache, tokens, start, last_index,
+    table, lane, block_size)`` and ``decode_forward_cached(params, cfg,
+    cache, tok, block_tables, lengths, block_size)``, which read the
+    cache's arrays by name and return (logits, k, v, {extra: (rows,
+    where)}, {state: value}); the engine writes all of it.  Either pair
+    may end with a small int32 vector of counters, named by the module's
+    ``COUNTERS``.  The config has ``n_layer``, ``d_model``, ``n_head``,
+    ``max_seq_len``, ``vocab_size`` and ``dtype``."""
     for module, cls, _ in MODEL_FAMILIES:
         if type(cfg).__name__ == cls:
             return importlib.import_module(module)
@@ -57,13 +70,15 @@ class LLMConfig:
     blocks at admission — conservative, so a request admitted once can
     never die of cache exhaustion mid-decode.  ``max_batch_size`` is the
     number of decode lanes: the continuous batcher keeps them full by
-    joining waiting requests at step boundaries.
+    joining waiting requests at step boundaries.  A family that keeps
+    state a lane (``cache_spec``) gets one slot of it for each lane.
+
+    ``model`` names a preset of a model family, one of (from
+    ``MODEL_FAMILIES``): {presets}.
     """
 
     # model
-    # a preset of a model family: GPT2Config's tiny | small | medium |
-    # large, OlmoeConfig's olmoe_tiny | olmoe_1b_7b | olmoe_1b_7b_12l
-    model: str = "tiny"
+    model: str = "tiny"  # a preset of a row of MODEL_FAMILIES (PRESETS; the docstring lists them)
     seed: int = 0  # synthetic-weights init seed (no checkpoint loading yet)
     dtype: str = "float32"  # serving compute dtype ("bfloat16" on TPU)
 
@@ -125,8 +140,7 @@ class LLMConfig:
                 preset = getattr(getattr(importlib.import_module(module), cls), self.model)
                 break
         else:
-            known = " | ".join(name for *_, presets in MODEL_FAMILIES for name in presets)
-            raise ValueError(f"unknown model preset {self.model!r} (expected {known})")
+            raise ValueError(f"unknown model preset {self.model!r} (expected {PRESETS})")
         dtype = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}.get(self.dtype)
         if dtype is None:
             raise ValueError(f"unsupported serving dtype {self.dtype!r}")
@@ -136,3 +150,6 @@ class LLMConfig:
     def max_context(self) -> int:
         cfg = self.model_config()
         return min(self.max_model_len or cfg.max_seq_len, cfg.max_seq_len)
+
+
+LLMConfig.__doc__ = LLMConfig.__doc__.format(presets=PRESETS)

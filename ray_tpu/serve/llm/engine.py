@@ -11,7 +11,9 @@ which keeps one decode program in flight.  Each iteration:
    whole KV need up front, so a joined request can never die of pool
    exhaustion): each join dispatches a bucketed, jitted prefill that
    writes the prompt's K/V straight into its pages and samples the
-   first token (TTFT is measured where that token is fetched);
+   first token (TTFT is measured where that token is fetched); where
+   the model family states a chunk, a longer prompt is as many
+   programs, in order, each reading what the ones before it wrote;
 3. one jitted decode step for EVERY lane with tokens left is dispatched
    on the tokens of the step before it, which never leave the device,
    and only then are the programs dispatched before it fetched, in
@@ -135,9 +137,9 @@ class _Request:
 
 
 def _write_rows(pages, rows, phys):
-    """pages [L, P, H*Dh] with rows [L, T, H, Dh] written at slots phys
-    [T] of every layer, in place when pages is donated.  The pool is
-    addressed as [L * P, H*Dh] so that the scatter indexes its
+    """pages [L, P, D] with rows [L, T, ...] (D values each) written at
+    slots phys [T] of every layer, in place when pages is donated.  The
+    pool is addressed as [L * P, D] so that the scatter indexes its
     major-most dim: in the 3-D form XLA re-lays the whole pool out to
     put the slots first and back again, four pool-sized copies a step."""
     import jax.numpy as jnp
@@ -147,32 +149,68 @@ def _write_rows(pages, rows, phys):
     return pages.reshape(L * P, D).at[idx].set(rows.reshape(-1, D)).reshape(L, P, D)
 
 
-def prefill_step(cfg, top_k, params, k_pages, v_pages, tokens, phys, last_idx, temp, rng):
-    """One prompt into the paged cache.  tokens [1, Tpad]; phys [Tpad]
-    (scratch slot 0 at pads); logits taken at the last REAL position,
-    not the pad tail.  Returns the first token, then the family's
-    counters if it has any, in one array: one fetch brings both."""
-    logits, k, v, *counters = model_family(cfg).prefill_forward(params, cfg, tokens, last_index=last_idx)
-    k_pages = _write_rows(k_pages, k[:, 0], phys)
-    v_pages = _write_rows(v_pages, v[:, 0], phys)
+def _write_back(cache, k, v, slots, rows):
+    """K and V rows into their pools at ``slots``, and each page
+    extra's rows where its family said."""
+    cache["k_pages"] = _write_rows(cache["k_pages"], k, slots)
+    cache["v_pages"] = _write_rows(cache["v_pages"], v, slots)
+    for name, (values, where) in rows.items():
+        cache[name] = _write_rows(cache[name], values, where)
+
+
+def prefill_step(cfg, top_k, block_size, spec, params, *args):
+    """One prefill program into the cache the family states (``spec``):
+    ``args`` are the cache's arrays in the order of ``spec.names``
+    (donated), then tokens [1, Tpad], phys [Tpad] (the slots of its
+    rows; scratch slot 0 at pads), last_idx [1] (logits are taken at the
+    last REAL position, not the pad tail), temp, rng, and, for a family
+    that reads its cache, start (the first position of this chunk of the
+    prompt), table [pages] and lane.  Such a family returns what to
+    write: K and V rows, rows of the page extras with their places, the
+    lane's new state.  Returns the first token, then the family's
+    counters if it has any, in one array (one fetch brings both), then
+    the cache."""
+    n = len(spec.names)
+    cache, (tokens, phys, last_idx, temp, rng, *chunk) = dict(zip(spec.names, args)), args[n:]
+    family = model_family(cfg)
+    rows, state = {}, {}
+    if spec.reads_cache:
+        start, table, lane = chunk
+        logits, k, v, rows, state, *counters = family.prefill_chunk(
+            params, cfg, cache, tokens, start, last_idx, table, lane, block_size)
+    else:
+        logits, k, v, *counters = family.prefill_forward(params, cfg, tokens, last_index=last_idx)
+    _write_back(cache, k[:, 0], v[:, 0], phys, rows)
+    for name, value in state.items():
+        cache[name] = cache[name].at[lane].set(value)
     first = _sample(logits, rng, temp, top_k, counters)
-    return first if counters else first[0], k_pages, v_pages
+    return (first if counters else first[0], *cache.values())
 
 
-def decode_step(cfg, top_k, block_size, params, k_pages, v_pages, tok, lengths,
-                block_tables, write_phys, temp, rng):
-    """Advance every lane one token.  lengths [B] the positions a lane
-    has cached, which is also the fed token's position; block_tables
-    [B, pages] its physical blocks (scratch block 0 beyond them).
-    Attention reads the lane's pages where they lie; the new K/V go
-    back at write_phys (inactive lanes have length 0 and hit slot 0).
-    Returns the lanes' tokens, then the family's counters if any."""
-    logits, k_new, v_new, *counters = model_family(cfg).decode_forward_paged(
-        params, cfg, tok, k_pages, v_pages, block_tables, lengths, block_size
-    )
-    k_pages = _write_rows(k_pages, k_new, write_phys)
-    v_pages = _write_rows(v_pages, v_new, write_phys)
-    return _sample(logits, rng, temp, top_k, counters), k_pages, v_pages
+def decode_step(cfg, top_k, block_size, spec, params, *args):
+    """Advance every lane one token: ``args`` are the cache's arrays in
+    the order of ``spec.names`` (donated), then tok [B], lengths [B]
+    (the positions a lane has cached, which is also the fed token's
+    position), block_tables [B, pages] (a lane's physical blocks,
+    scratch block 0 beyond them), write_phys [B], temp, rng.  Attention
+    reads the lane's pages where they lie; the new K/V go back at
+    write_phys (inactive lanes have length 0 and hit slot 0), and
+    whatever else the family states it caches where the family says.
+    Returns the lanes' tokens, then the family's counters if any, then
+    the cache."""
+    n = len(spec.names)
+    cache, (tok, lengths, block_tables, write_phys, temp, rng) = dict(zip(spec.names, args)), args[n:]
+    family = model_family(cfg)
+    rows, state = {}, {}
+    if spec.reads_cache:
+        logits, k_new, v_new, rows, state, *counters = family.decode_forward_cached(
+            params, cfg, cache, tok, block_tables, lengths, block_size)
+    else:
+        logits, k_new, v_new, *counters = family.decode_forward_paged(
+            params, cfg, tok, cache["k_pages"], cache["v_pages"], block_tables, lengths, block_size)
+    _write_back(cache, k_new, v_new, write_phys, rows)
+    cache.update(state)  # every lane's state, whole
+    return (_sample(logits, rng, temp, top_k, counters), *cache.values())
 
 
 def _sample(logits, rng, temp, top_k, counters):
@@ -198,14 +236,19 @@ class _InFlight:
 
 
 class LLMEngine:
-    """One engine per replica; owns the model params, the paged KV cache,
-    and the continuous-batching step loop."""
+    """One engine per replica; owns the model params, the cache the
+    model family states (``cache``: the paged K/V pools, and what else a
+    page or a lane holds), and the continuous-batching step loop."""
 
     def __init__(self, config: Optional[Any] = None):
         self.config = LLMConfig.coerce(config)
         self.model_cfg = self.config.model_config()
         self.max_ctx = self.config.max_context
-        self.bm = BlockManager(self.config.num_blocks, self.config.block_size)
+        # what the family states it caches: pages a sequence reserves by
+        # its length, and state a lane owns whatever its length
+        self._spec = model_family(self.model_cfg).cache_spec(self.model_cfg, self.config.block_size)
+        self.bm = BlockManager(self.config.num_blocks, self.config.block_size,
+                               state_slots=self.config.max_batch_size if self._spec.lane_state else 0)
         # usable pool excludes the reserved scratch block 0: a max-length
         # sequence must fit in the ALLOCATABLE blocks, or a max-size
         # request would pass admission bounds yet park forever
@@ -238,7 +281,9 @@ class LLMEngine:
         # plain numbers in stats()
         self._counts: Dict[str, Any] = {
             "joined": 0, "queue_wait_s": 0.0,
-            "prompt_tokens": 0, "prefill_bucket_tokens": 0,
+            "prompt_tokens": 0, "prefill_bucket_tokens": 0, "prefill_chunks": 0,
+            # bytes of lane state the programs read and wrote
+            "state_bytes": 0,
             # of the positions a decode step reads (the whole pages its
             # kernel copies for the lanes in use), those a lane holds
             "kv_positions_attended": 0, "kv_positions_gathered": 0,
@@ -296,18 +341,24 @@ class LLMEngine:
         self._param_bytes = sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(self.params))
         # what the family's programs count and return after their tokens
         self._counter_names = tuple(getattr(family, "COUNTERS", ()))
-        # a position is one row of all heads: a page is then one
-        # contiguous slab, which the decode kernel copies whole
-        pool = (cfg.n_layer, self.bm.num_slots, cfg.d_model)
-        self.k_pages = jnp.zeros(pool, cfg.dtype)
-        self.v_pages = jnp.zeros(pool, cfg.dtype)
+        # the cache, by the family's statement.  A position is one row
+        # of all the heads it caches: a page is then one contiguous
+        # slab, which the decode kernel copies whole
+        spec, lanes = self._spec, self.config.max_batch_size
+        pool = (spec.paged_layers, self.bm.num_slots, spec.row_width)
+        self.cache = {"k_pages": jnp.zeros(pool, cfg.dtype), "v_pages": jnp.zeros(pool, cfg.dtype)}
+        for name, rows_a_page, width, dtype in spec.page_extras:
+            self.cache[name] = jnp.zeros((spec.paged_layers, self.bm.num_blocks * rows_a_page, width), dtype)
+        for name, shape, dtype in spec.lane_state:
+            self.cache[name] = jnp.zeros((lanes, *shape), dtype)
+        assert tuple(self.cache) == spec.names
+        self._state_bytes = sum(self.cache[name].nbytes for name, *_ in spec.lane_state)
         # where the cache lives, reported by stats(): a replica that was
         # meant for the chip and runs on the CPU is then visible
-        self._device = next(iter(self.k_pages.devices()))
+        self._device = next(iter(self.cache["k_pages"].devices()))
         # every lane's newest token, on the device: each program's token
         # goes in as it is dispatched, and is the next decode step's
         # ``tok``, so no token crosses to the host and back to be fed
-        lanes = self.config.max_batch_size
         self._lane_tok = jnp.zeros(lanes, jnp.int32)
         self._put_lane = jax.jit(lambda lane_tok, first, lane: lane_tok.at[lane].set(first.reshape(-1)[0]))
         self._lanes_of = jax.jit(lambda nxt: nxt[:lanes]) if self._counter_names else (lambda nxt: nxt)
@@ -322,17 +373,28 @@ class LLMEngine:
         # a retrace storm here is a bucketing bug; docs/profiling.md).
         from ray_tpu._private import profiling as _profiling
 
+        # both take the params, then the cache's arrays (donated), then
+        # their inputs, and return their tokens, then the cache
+        held = tuple(range(1, 1 + len(spec.names)))
+        bound = (cfg, top_k, self.config.block_size, spec)
         self._prefill_jit = _profiling.instrument_jit(
-            "serve_prefill",
-            jax.jit(functools.partial(prefill_step, cfg, top_k), donate_argnums=(1, 2)),
-        )
+            "serve_prefill", jax.jit(functools.partial(prefill_step, *bound), donate_argnums=held))
         self._decode_jit = _profiling.instrument_jit(
-            "serve_decode",
-            jax.jit(
-                functools.partial(decode_step, cfg, top_k, self.config.block_size),
-                donate_argnums=(1, 2),
-            ),
-        )
+            "serve_decode", jax.jit(functools.partial(decode_step, *bound), donate_argnums=held))
+
+    # the pools every family has, by name (the cache's first two arrays)
+    k_pages = property(lambda self: self.cache["k_pages"],
+                       lambda self, pages: self.cache.__setitem__("k_pages", pages))
+    v_pages = property(lambda self: self.cache["v_pages"],
+                       lambda self, pages: self.cache.__setitem__("v_pages", pages))
+
+    def _run_on_cache(self, program, *inputs):
+        """One jit call on the cache: its arrays go in donated and the
+        engine is rebound to what comes back, in one synchronous stretch
+        (``_dispatch``).  -> the program's tokens."""
+        out, *held = program(self.params, *self.cache.values(), *inputs)
+        self.cache = dict(zip(self.cache, held))
+        return out
 
     def _next_rng(self):
         import jax
@@ -370,7 +432,7 @@ class LLMEngine:
                 pass
         if self._dispatching is not None:
             # a jit call under way in the executor thread has donated the
-            # pool: it rebinds k_pages/v_pages before it returns
+            # cache: it rebinds it before it returns
             await asyncio.wait([self._dispatching])
             self._dispatching = None
         # what is in flight is never fetched and never emitted; its K/V
@@ -513,6 +575,8 @@ class LLMEngine:
             "kv_blocks_in_use": self.bm.blocks_in_use,
             "kv_blocks_total": self.bm.num_blocks - 1,
             "kv_leak_report": self.bm.leak_report(),
+            "state_slots_in_use": self.bm.state_slots_in_use,
+            "state_slots_total": self.bm.state_slots,
             "tokens_per_s": round(self._tokens_per_s(), 2),
             "total_tokens": self._total_tokens,
             "shed_total": self._shed_total,
@@ -655,6 +719,7 @@ class LLMEngine:
             if req is None:
                 break
             req.slot = i
+            self.bm.hold_state_slot(req.request_id, i)
             req.t_join = time.time()
             req.join_step = self.step_count
             self.slots[i] = req
@@ -855,8 +920,8 @@ class LLMEngine:
 
     async def _dispatch(self, loop, name: str, call):
         """Run one jit call in the executor thread, timed as ``<name>.run``
-        inside ``<name>.await``.  ``call`` donates the pool and rebinds
-        ``k_pages``/``v_pages`` in one synchronous stretch of that
+        inside ``<name>.await``.  ``call`` donates the cache and rebinds
+        it (``_run_on_cache``) in one synchronous stretch of that
         thread; the await is shielded, so a ``stop()`` that cancels the
         loop task here leaves the call to finish and finds the engine
         bound to live buffers."""
@@ -881,35 +946,44 @@ class LLMEngine:
                     self._dispatching = None
 
     async def _prefill(self, loop, req: _Request):
-        """Dispatch one prompt's prefill behind whatever is in flight.
-        Its first token goes to the lane's place on the device, for the
-        next decode step, and to the host when its turn to be fetched
-        comes."""
-        with self._phase("engine.prefill.build"):
-            n = len(req.prompt)
-            bucket = self._prefill_bucket(n, self.max_ctx)
-            toks = np.zeros((1, bucket), dtype=np.int32)
-            toks[0, :n] = req.prompt
-            self.bm.advance(req.request_id, n)
-            phys = self.bm.phys_indices(req.request_id, n, bucket)
-            last_idx = np.array([n - 1], dtype=np.int32)
-            temp = np.array([req.temperature], dtype=np.float32)
-            rng = self._next_rng()
-            lane = np.int32(req.slot)
+        """Dispatch one prompt's prefill behind whatever is in flight:
+        one program, or, where the family states a ``prefill_chunk`` and
+        the prompt is longer, one program a chunk, in order, each
+        reading what the ones before it wrote (K and V through the block
+        table, the lane's state from its slot; the first starts from a
+        state of zeros).  The last one's token is the request's first:
+        it goes to the lane's place on the device, for the next decode
+        step, and to the host when its turn to be fetched comes."""
+        n = len(req.prompt)
+        most = min(self._spec.prefill_chunk or self.max_ctx, self.max_ctx)
+        lane = np.int32(req.slot)
+        for start in range(0, n, most):
+            with self._phase("engine.prefill.build"):
+                m = min(most, n - start)
+                bucket = self._prefill_bucket(m, most)
+                toks = np.zeros((1, bucket), dtype=np.int32)
+                toks[0, :m] = req.prompt[start:start + m]
+                self.bm.advance(req.request_id, m)
+                inputs = [toks, self.bm.phys_indices(req.request_id, start + m, bucket, start=start),
+                          np.array([m - 1], dtype=np.int32),
+                          np.array([req.temperature], dtype=np.float32), self._next_rng()]
+                if self._spec.reads_cache:
+                    inputs += [np.int32(start),
+                               self.bm.block_table(req.request_id, self.bm.blocks_needed(self.max_ctx)), lane]
+                last = start + m == n
+                counts = {"prompt_tokens": m, "prefill_bucket_tokens": bucket, "prefill_chunks": 1,
+                          "state_bytes": 2 * self._state_bytes // self.config.max_batch_size}
 
-        def call():
-            first_tok, self.k_pages, self.v_pages = self._prefill_jit(
-                self.params, self.k_pages, self.v_pages,
-                toks, phys, last_idx, temp, rng,
-            )
-            self._lane_tok = self._put_lane(self._lane_tok, first_tok, lane)
-            return first_tok
+            def call():
+                first_tok = self._run_on_cache(self._prefill_jit, *inputs)
+                if last:
+                    self._lane_tok = self._put_lane(self._lane_tok, first_tok, lane)
+                return first_tok
 
-        first_tok = await self._dispatch(loop, "engine.prefill", call)
+            first_tok = await self._dispatch(loop, "engine.prefill", call)
+            # a chunk before the last is fetched for its counters alone
+            self._inflight.append(_InFlight(first_tok, [(0, req)] if last else [], counts, decode=False))
         req.dispatched += 1
-        self._inflight.append(_InFlight(
-            first_tok, [(0, req)], {"prompt_tokens": n, "prefill_bucket_tokens": bucket},
-            decode=False))
 
     async def _dispatch_decode(self, loop) -> Optional[_InFlight]:
         """Dispatch one decode step for every lane with tokens left, on
@@ -945,14 +1019,16 @@ class LLMEngine:
                 attended += cur_len
                 read += -(-cur_len // bs) * bs  # whole pages
             rng = self._next_rng()
-            counts = {"kv_positions_attended": attended, "kv_positions_gathered": read,
-                      "decodes_chained": int(any(p.decode for p in self._inflight))}
+            counts = {"decodes_chained": int(any(p.decode for p in self._inflight)),
+                      "state_bytes": 2 * self._state_bytes}
+            if "kv_positions_gathered" not in self._counter_names:
+                # every cached position of every lane is read; a family
+                # that reads a selection counts what it reads itself
+                counts.update(kv_positions_attended=attended, kv_positions_gathered=read)
 
         def call():
-            nxt, self.k_pages, self.v_pages = self._decode_jit(
-                self.params, self.k_pages, self.v_pages,
-                self._lane_tok, lengths, tables, write_phys, temp, rng,
-            )
+            nxt = self._run_on_cache(
+                self._decode_jit, self._lane_tok, lengths, tables, write_phys, temp, rng)
             self._lane_tok = self._lanes_of(nxt)
             return nxt
 

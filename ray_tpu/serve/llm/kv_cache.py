@@ -1,13 +1,16 @@
 """Paged KV cache accounting (reference: vLLM BlockSpaceManager).
 
-The physical storage is two preallocated arrays per deployment —
-``k_pages``/``v_pages`` of shape ``[n_layer, num_blocks * block_size,
-n_head * d_head]`` held by the engine, a block one contiguous slab of
-every layer — and this module owns the *logical* side: a fixed pool of
-fixed-size blocks, a per-sequence block table (what the decode step's
-attention walks to read a lane's pages where they lie), and the
-position -> physical-slot mapping prefill and the decode step write
-through.
+The physical storage is what the model family states it caches
+(``models/common.py:CacheSpec``), preallocated by the engine: pools
+addressed by block — ``k_pages``/``v_pages`` of shape ``[paged layers,
+num_blocks * block_size, row width]``, a block one contiguous slab of
+every layer, and whatever else a page carries — and, for a family that
+keeps it, state a LANE owns whatever its sequence's length.  This module
+owns the *logical* side of both: a fixed pool of fixed-size blocks, a
+per-sequence block table (what the decode step's attention walks to read
+a lane's pages where they lie), the position -> physical-slot mapping
+prefill and the decode step write through, and which lane's state slot
+a sequence holds (``hold_state_slot``).
 
 Invariants (enforced, and what tests/test_serve_llm.py audits):
 
@@ -33,7 +36,7 @@ class NoFreeBlocksError(RuntimeError):
 
 
 class BlockManager:
-    def __init__(self, num_blocks: int, block_size: int):
+    def __init__(self, num_blocks: int, block_size: int, state_slots: int = 0):
         if num_blocks < 2:
             raise ValueError("num_blocks must be >= 2 (block 0 is reserved)")
         if block_size < 1:
@@ -44,6 +47,13 @@ class BlockManager:
         self._free: List[int] = list(range(num_blocks - 1, 0, -1))
         self._tables: Dict[str, List[int]] = {}
         self._lens: Dict[str, int] = {}
+        # the other kind of state: a slot a lane owns whatever its
+        # sequence's length (0: the family keeps none).  A sequence holds
+        # its lane's slot from the join to ``free``; a successor that
+        # takes the lane early holds it beside its predecessor, and
+        # starts from zeros, for a moment of its own making
+        self.state_slots = state_slots
+        self._state_slot: Dict[str, int] = {}
         self.total_allocs = 0
         self.total_frees = 0
 
@@ -95,11 +105,24 @@ class BlockManager:
         """Return seq_id's blocks to the pool; idempotent (0 on repeat)."""
         table = self._tables.pop(seq_id, None)
         self._lens.pop(seq_id, None)
+        self._state_slot.pop(seq_id, None)
         if table is None:
             return 0
         self._free.extend(table)
         self.total_frees += 1
         return len(table)
+
+    def hold_state_slot(self, seq_id: str, slot: int) -> None:
+        """seq_id, which holds blocks, takes state slot ``slot`` (its
+        lane's); nothing where the family keeps no such state."""
+        if self.state_slots:
+            if seq_id not in self._tables or not 0 <= slot < self.state_slots:
+                raise ValueError(f"sequence {seq_id!r} cannot hold state slot {slot}")
+            self._state_slot[seq_id] = slot
+
+    @property
+    def state_slots_in_use(self) -> int:
+        return len(set(self._state_slot.values()))
 
     def blocks_held(self, seq_id: str) -> int:
         """Blocks currently reserved by seq_id (0 when unknown) — the
@@ -116,14 +139,14 @@ class BlockManager:
         table = self._tables[seq_id]
         return table[pos // self.block_size] * self.block_size + pos % self.block_size
 
-    def phys_indices(self, seq_id: str, upto: int, width: int) -> np.ndarray:
-        """Physical slots for positions [0, upto), right-padded with the
-        scratch slot 0 to ``width`` (the jitted prefill's static shape)."""
+    def phys_indices(self, seq_id: str, upto: int, width: int, start: int = 0) -> np.ndarray:
+        """Physical slots for positions [start, upto), right-padded with
+        the scratch slot 0 to ``width`` (the jitted prefill's static
+        shape)."""
         out = np.zeros(width, dtype=np.int32)
-        table = self._tables[seq_id]
-        bs = self.block_size
-        for p in range(min(upto, width)):
-            out[p] = table[p // bs] * bs + p % bs
+        pos = np.arange(start, min(upto, start + width))
+        table = np.asarray(self._tables[seq_id], dtype=np.int32)
+        out[:len(pos)] = table[pos // self.block_size] * self.block_size + pos % self.block_size
         return out
 
     def block_table(self, seq_id: str, width: int) -> np.ndarray:
@@ -140,6 +163,7 @@ class BlockManager:
         return {
             "blocks_in_use": self.blocks_in_use,
             "live_sequences": len(self._tables),
+            "state_slots_in_use": self.state_slots_in_use,
             "total_allocs": self.total_allocs,
             "total_frees": self.total_frees,
         }

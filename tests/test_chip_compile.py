@@ -129,7 +129,7 @@ def test_engine_decode_step_compiles_for_v5e(one_chip, monkeypatch):
     pages = arr((cfg.n_layer, slots, cfg.d_model), cfg.dtype)
     key = shaped(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
     compiled = jax.jit(
-        lambda *a: decode_step(cfg, 0, block, *a), donate_argnums=(1, 2)
+        lambda *a: decode_step(cfg, 0, block, gpt2.cache_spec(cfg, block), *a), donate_argnums=(1, 2)
     ).lower(
         params, pages, pages, arr((B,), jnp.int32), arr((B,), jnp.int32),
         arr((B, C // block), jnp.int32), arr((B,), jnp.int32),
@@ -174,7 +174,7 @@ def test_olmoe_decode_step_compiles_for_v5e(one_chip, monkeypatch):
     pages = arr((cfg.n_layer, slots, cfg.d_model), cfg.dtype)
     key = shaped(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
     compiled = jax.jit(
-        lambda *a: decode_step(cfg, 0, block, *a), donate_argnums=(1, 2)
+        lambda *a: decode_step(cfg, 0, block, olmoe.cache_spec(cfg, block), *a), donate_argnums=(1, 2)
     ).lower(
         params, pages, pages, arr((B,), jnp.int32), arr((B,), jnp.int32),
         arr((B, C // block), jnp.int32), arr((B,), jnp.int32),
@@ -198,3 +198,58 @@ def test_olmoe_decode_step_compiles_for_v5e(one_chip, monkeypatch):
     assert mem.temp_size_in_bytes < pairs * cfg.num_experts * cfg.d_model * 2 // 4
     # the program returns its tokens and five counters in one array
     assert f"s32[{B + len(olmoe.COUNTERS)}]" in text
+
+
+def test_minicpm_sala_programs_compile_for_v5e(one_chip, monkeypatch):
+    """MiniCPM-SALA's two programs at the published widths (d 4096, 32
+    query heads of 128 over 2 K/V heads, lightning 32 x 128, feed-forward
+    16,384), 16 lanes over 33,792 positions in pages of 16, depth cut to
+    one layer of each kind: the decode step reads the chosen pages through
+    the sparse paged kernel (one call a sparse layer) and builds nothing
+    of [lanes, max_ctx] keys; a 1,024-token chunk of a prompt compiles
+    with its loop over key blocks and fits beside the cache."""
+    from ray_tpu.models import minicpm_sala as sala
+    from ray_tpu.serve.llm.engine import decode_step, prefill_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = sala.MiniCPMSalaConfig(mixer_types=(sala.LIGHTNING, sala.SPARSE))
+    B, C, block, T = 16, 33792, 16, 1024
+    blocks_in_pool = 4 * C // block + 1
+    spec = sala.cache_spec(cfg, block)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    def shaped(tree):
+        return jax.tree_util.tree_map(lambda x: arr(x.shape, x.dtype), tree)
+
+    params = shaped(jax.eval_shape(lambda: sala.init_params(cfg)))
+    key = shaped(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    pool = arr((spec.paged_layers, blocks_in_pool * block, spec.row_width), cfg.dtype)
+    cache = [pool, pool]
+    cache += [arr((spec.paged_layers, blocks_in_pool * rows, width), dtype)
+              for _, rows, width, dtype in spec.page_extras]
+    cache += [arr((B, *shape), dtype) for _, shape, dtype in spec.lane_state]
+    assert spec.names == ("k_pages", "v_pages", "ck_pages", "lightning_state_0")
+    held = tuple(range(1, 1 + len(cache)))
+
+    decode = jax.jit(lambda *a: decode_step(cfg, 0, block, spec, *a), donate_argnums=held).lower(
+        params, *cache, arr((B,), jnp.int32), arr((B,), jnp.int32), arr((B, C // block), jnp.int32),
+        arr((B,), jnp.int32), arr((B,), jnp.float32), key).compile()
+    text = decode.as_text()
+    calls = [ln.split(" = ")[0].split("%")[-1] for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1 and calls[0].startswith("sparse_paged_decode_attention")
+    # the keys of 16 lanes at 33,792 positions would be 16 * 33792 * 256 * 2 B = 277 MB a
+    # layer; what is gathered is a compressed key every 16 positions (17 MB) and the logits
+    assert decode.memory_analysis().temp_size_in_bytes < B * C * spec.row_width * 2 // 2
+    assert f"s32[{B + len(sala.COUNTERS)}]" in text
+
+    chunk = jax.jit(lambda *a: prefill_step(cfg, 0, block, spec, *a), donate_argnums=held).lower(
+        params, *cache, arr((1, T), jnp.int32), arr((T,), jnp.int32), arr((1,), jnp.int32),
+        arr((1,), jnp.float32), key, arr((), jnp.int32), arr((C // block,), jnp.int32),
+        arr((), jnp.int32)).compile()
+    mem = chunk.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 * 2**30
+    # the scores of 1,024 queries x 32 heads over 33,792 keys would be 4.4 GB in float32
+    assert mem.temp_size_in_bytes < 1 * 2**30
