@@ -205,6 +205,7 @@ def _use_pallas(q) -> bool:
     if jax.default_backend() != "tpu":
         return False
     B, T, H, D = q.shape
-    # Tuned for the MXU: D a multiple of 64 (64/128 head dims), T a
-    # multiple of the 256-wide q/k blocks.
+    # D a multiple of 64 (64/128 head dims: a lane tile or half of one);
+    # T a multiple of 256, the chunk the kernels walk a block in
+    # (pallas_attention.flash_tiles: any such T gets a block and a chunk).
     return T >= 256 and T % 256 == 0 and D % 64 == 0 and D <= 256

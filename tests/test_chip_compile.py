@@ -53,8 +53,8 @@ def _no_persistent_cache():
 FLASH_SHAPE = (8, 1024, 16, 64)
 
 
-def _qkv(sharding):
-    return [jax.ShapeDtypeStruct(FLASH_SHAPE, jnp.bfloat16, sharding=sharding)] * 3
+def _qkv(sharding, shape=FLASH_SHAPE):
+    return [jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)] * 3
 
 
 def test_flash_forward_compiles_for_v5e(one_chip):
@@ -71,8 +71,31 @@ def test_flash_forward_backward_compiles_for_v5e(one_chip):
         return flash_attention(q, k, v).astype(jnp.float32).sum()
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*_qkv(one_chip)).compile()
-    # forward, dq and dk/dv kernels
-    assert compiled.as_text().count("tpu_custom_call") == 3
+    # a head of 1024 positions is resident: the forward, and one backward
+    # kernel for dq, dk and dv
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 2
+
+
+@pytest.mark.parametrize("shape, kernels", [
+    # gpt2-large.train.mesh2x2's shard on a device: 4 of 8 sequences, 10 of 20 heads
+    ((4, 1024, 10, 64), 2),
+    # llama's head size, a sequence of eight blocks: K/V streamed past Q
+    # (forward, dq), Q/dO past K/V (dk/dv), strips of [256, 1024] scores
+    ((1, 8192, 8, 128), 3),
+    # a sequence that no power of two over 256 divides: one block of three chunks
+    ((2, 768, 4, 64), 2),
+])
+def test_flash_forward_backward_compiles_at_other_shapes(one_chip, shape, kernels):
+    """Mosaic refusing a strip's slice, a transpose or the VMEM a step
+    needs shows here: `flash_tiles` answers for every (T, D) that
+    `_use_pallas` admits, not only for the cells'."""
+    from ray_tpu.ops.pallas_attention import flash_attention
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*_qkv(one_chip, shape)).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == kernels
 
 
 def test_flash_under_a_mesh_compiles_for_v5e(topo):
@@ -95,7 +118,7 @@ def test_flash_under_a_mesh_compiles_for_v5e(topo):
             jax.grad(loss, argnums=(0, 1, 2)), out_shardings=(sharding,) * 3
         ).lower(*_qkv(sharding)).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 3
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
     # each device works on its own [4, 1024, 8, 64] shard: q/k/v never gather
     assert "all-gather" not in text
 
