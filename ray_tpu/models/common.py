@@ -24,7 +24,9 @@ class CacheSpec:
     ``serve/llm/engine.py``, which allocates, donates, writes and counts
     by it (docs/serving.md "Model families").
 
-    Pages a sequence reserves by its length: ``k_pages`` and ``v_pages``
+    Pages a sequence reserves by its length: ``k_pages`` and, unless
+    the family states it has no V pool (``v_pool`` False: a family whose
+    one cached row a position serves as key and value both), ``v_pages``
     of ``[paged_layers, slots, row_width]``, and for each of
     ``page_extras`` (name, rows a page, width, dtype) a pool of
     ``[paged_layers, pages * rows, width]`` addressed through the same
@@ -43,6 +45,7 @@ class CacheSpec:
     page_extras: tuple = ()
     lane_state: tuple = ()
     prefill_chunk: int = 0
+    v_pool: bool = True
 
     @property
     def reads_cache(self) -> bool:
@@ -57,8 +60,8 @@ class CacheSpec:
     def names(self) -> tuple:
         """The cache's arrays in the order the engine's programs take
         and return them."""
-        return ("k_pages", "v_pages", *(e[0] for e in self.page_extras),
-                *(s[0] for s in self.lane_state))
+        return ("k_pages", *(("v_pages",) if self.v_pool else ()),
+                *(e[0] for e in self.page_extras), *(s[0] for s in self.lane_state))
 
 
 def next_token_loss(logits: jax.Array, targets: jax.Array) -> jax.Array:

@@ -15,7 +15,9 @@ context the einsum reference; over a paged KV pool the Pallas kernel
 that reads pages in place on TPU (ops.pallas_paged_attention), else a
 gather of the lane's pages and the same reference; over CHOSEN blocks of
 the pool with grouped queries, the sibling kernel
-(ops.pallas_sparse_paged_attention) or a gather of the chosen pages.
+(ops.pallas_sparse_paged_attention) or a gather of the chosen pages;
+over a pool of latent rows that all heads share, keys and values both,
+a third (ops.pallas_mla_paged_attention) or a gather of the lane's rows.
 
 All paths: f32 accumulation, bf16 in/out, static shapes.
 """
@@ -133,6 +135,41 @@ def sparse_paged_decode_attention(q, k_self, v_self, k_pages, v_pages, layer, ch
     probs = jax.nn.softmax(jnp.concatenate([s_ctx, s_self], axis=-1), axis=-1).astype(q.dtype)
     att = jnp.einsum("bgrc,bgcd->bgrd", probs[..., :-1], v_ctx)
     return att + probs[..., -1:] * v_self[:, :, None]
+
+
+def mla_paged_decode_attention(q, row_self, pages, layer, block_tables, lengths, *, block_size,
+                               v_width):
+    """One fed token a lane over the LATENT rows it holds of a paged
+    pool, layer ``layer``, and its own row (not in the pool yet): every
+    head attends the same rows, keys a row's W columns, values its first
+    ``v_width`` (multi-query over a compressed cache; the caller absorbs
+    the up-projections into q and out of the result).
+
+    q [B, H, W], every scale already applied; row_self [B, W]; pages
+    [L, num_blocks * block_size, W]; block_tables [B, pages] int32,
+    scratch block 0 where a lane holds none; lengths [B] int32 the
+    cached positions of a lane (0: it attends to itself alone).
+    Returns [B, H, v_width].
+
+    On a TPU, where the shapes fit its tiling, the Pallas kernel reads
+    the pages where they lie (ops.pallas_mla_paged_attention).
+    Elsewhere the lane's rows are gathered to a contiguous context."""
+    B, H, W = q.shape
+    if jax.default_backend() == "tpu":  # as paged_decode_attention: the CPU tests gather
+        from ray_tpu.ops import pallas_mla_paged_attention as kernel
+
+        if kernel.kernel_takes(H, W, v_width, block_size, pages.dtype):
+            return kernel.mla_paged_decode_attention_kernel(
+                q, row_self, pages, layer, block_tables, lengths, block_size=block_size, v_width=v_width)
+    C = block_tables.shape[1] * block_size
+    idx = (block_tables[:, :, None] * block_size + jnp.arange(block_size)).reshape(B, C)
+    ctx = pages[layer][idx]  # [B, C, W]
+    s_ctx = jnp.einsum("bhw,bcw->bhc", q, ctx).astype(jnp.float32)
+    s_ctx = jnp.where((jnp.arange(C)[None, :] < lengths[:, None])[:, None, :], s_ctx, jnp.float32(-1e30))
+    s_self = (q * row_self[:, None]).sum(-1).astype(jnp.float32)[..., None]
+    probs = jax.nn.softmax(jnp.concatenate([s_ctx, s_self], axis=-1), axis=-1).astype(q.dtype)
+    att = jnp.einsum("bhc,bcv->bhv", probs[..., :-1], ctx[..., :v_width])
+    return att + probs[..., -1:] * row_self[:, None, :v_width]
 
 
 def causal_attention(q, k, v, *, mesh=None, sp_axis: Optional[str] = None):

@@ -18,6 +18,20 @@ once for every 256 rows or so.  It runs under the name ``moe_gmm`` (the device t
 shows ``moe_gmm tpu_custom_call``).  Elsewhere ``jax.lax.ragged_dot``
 computes the same products; the CPU tests take that path, as
 ``ops.attention.paged_decode_attention`` does with its gather.
+
+A SHARE of the experts (``held``): where the chips of a deployment
+divide a layer's experts among them (expert parallelism), each routes
+over ALL the experts, as the router was trained, and is given the
+weights of its own ``count`` experts from ``first`` on.  The pairs whose
+expert is held are sorted, grouped and multiplied as above, none of them
+dropped; the other pairs sort behind every group, take no row tile of
+the grouped matmul and add nothing.  What comes back is then a PARTIAL
+sum: the part of ``sum_k p * expert(h)`` that the held experts give.
+The chips' parts add up to the whole layer's (the exchange that sums
+them across chips is not here: on one chip the layer runs without it),
+and what every chip computes alike, such as a shared expert, is counted
+once (``tests/test_mistral4.py`` adds four shares up).  With no share
+stated the function is what it was, operation for operation.
 """
 
 from __future__ import annotations
@@ -83,16 +97,20 @@ def grouped_matmul(rows, weights, group_sizes):
     return jax.lax.ragged_dot(rows, weights, group_sizes).astype(rows.dtype)
 
 
-def moe_experts(h, top_p, top_e, wgu, wd):
+def moe_experts(h, top_p, top_e, wgu, wd, held=None):
     """The expert layer of a token batch.
 
     h [T, d] the tokens; top_p [T, k] float32 and top_e [T, k] int32 a
     token's weights and experts; wgu [E, d, 2 * f] every expert's gate
     and up projections side by side; wd [E, f, d] its down projection.
+    `held` (first, count), static: wgu and wd are the weights of experts
+    ``first .. first + count - 1`` alone, of the more that top_e ranges
+    over; None: of all of them.
     Returns (y [T, d] in h's dtype: ``sum_k p * (silu(h Wg) * (h Wu)) Wd``
-    over a token's k experts; counters int32 [3]: the pairs computed
-    (rows of the second grouped matmul's output that are not all zero:
-    T * k unless a pair was dropped or the kernel skipped a row), the
+    over those of a token's k experts that are held; counters int32 [3]:
+    the pairs computed (rows of the second grouped matmul's output that
+    are not all zero: the pairs whose expert is held, T * k where all
+    are, unless a pair was dropped or the kernel skipped a row), the
     experts that received at least one row, and the rows of the largest
     group)."""
     T, d = h.shape
@@ -100,12 +118,22 @@ def moe_experts(h, top_p, top_e, wgu, wd):
     E = wgu.shape[0]
     with jax.named_scope("moe.route"):
         expert = top_e.reshape(T * k)
+        if held is not None:
+            first, count = held
+            assert count == E, f"{E} experts' weights for a share of {count}"
+            # a pair of an absent expert sorts behind every group, into none
+            expert = jnp.where((expert >= first) & (expert < first + count), expert - first, E)
         order = jnp.argsort(expert, stable=True)  # pairs by expert, tokens in order within one
-        group_sizes = jnp.bincount(expert, length=E).astype(jnp.int32)
+        group_sizes = jnp.bincount(expert, length=E if held is None else E + 1).astype(jnp.int32)
+        if held is not None:
+            group_sizes = group_sizes[:E]  # less the absent pairs' own count
         rows = h[order // k]
     with jax.named_scope("moe.experts"):
         gate, up = jnp.split(grouped_matmul(rows, wgu, group_sizes), 2, axis=-1)
         out = grouped_matmul(jax.nn.silu(gate) * up, wd, group_sizes)
+        if held is not None:
+            # rows behind the groups were never written: whatever lies there is not a result
+            out = jnp.where((jnp.arange(T * k) < group_sizes.sum())[:, None], out, 0)
     with jax.named_scope("moe.combine"):
         computed = (out != 0).any(axis=-1).sum(dtype=jnp.int32)
         out = out.astype(jnp.float32) * top_p.reshape(T * k)[order][:, None]

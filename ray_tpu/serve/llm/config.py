@@ -17,6 +17,8 @@ MODEL_FAMILIES = (
     ("ray_tpu.models.olmoe", "OlmoeConfig", ("olmoe_tiny", "olmoe_1b_7b", "olmoe_1b_7b_12l")),
     ("ray_tpu.models.minicpm_sala", "MiniCPMSalaConfig",
      ("minicpm_sala_tiny", "minicpm_sala", "minicpm_sala_16l")),
+    ("ray_tpu.models.mistral4", "Mistral4Config",
+     ("mistral_small_4_tiny", "mistral_small_4", "mistral_small_4_6l_ep4")),
 )
 # every preset ``LLMConfig.model`` may name, family by family
 PRESETS = " | ".join(name for *_, presets in MODEL_FAMILIES for name in presets)
@@ -38,7 +40,8 @@ def model_family(cfg):
     table, lane, block_size)`` and ``decode_forward_cached(params, cfg,
     cache, tok, block_tables, lengths, block_size)``, which read the
     cache's arrays by name and return (logits, k, v, {extra: (rows,
-    where)}, {state: value}); the engine writes all of it.  Either pair
+    where)}, {state: value}), v None where the family states no V pool;
+    the engine writes all of it.  Either pair
     may end with a small int32 vector of counters, named by the module's
     ``COUNTERS``.  The config has ``n_layer``, ``d_model``, ``n_head``,
     ``max_seq_len``, ``vocab_size`` and ``dtype``."""

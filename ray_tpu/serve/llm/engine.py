@@ -150,10 +150,12 @@ def _write_rows(pages, rows, phys):
 
 
 def _write_back(cache, k, v, slots, rows):
-    """K and V rows into their pools at ``slots``, and each page
-    extra's rows where its family said."""
+    """K and V rows into their pools at ``slots`` (no V where the family
+    states no V pool: it returns None for it), and each page extra's
+    rows where its family said."""
     cache["k_pages"] = _write_rows(cache["k_pages"], k, slots)
-    cache["v_pages"] = _write_rows(cache["v_pages"], v, slots)
+    if "v_pages" in cache:
+        cache["v_pages"] = _write_rows(cache["v_pages"], v, slots)
     for name, (values, where) in rows.items():
         cache[name] = _write_rows(cache[name], values, where)
 
@@ -180,7 +182,7 @@ def prefill_step(cfg, top_k, block_size, spec, params, *args):
             params, cfg, cache, tokens, start, last_idx, table, lane, block_size)
     else:
         logits, k, v, *counters = family.prefill_forward(params, cfg, tokens, last_index=last_idx)
-    _write_back(cache, k[:, 0], v[:, 0], phys, rows)
+    _write_back(cache, k[:, 0], v if v is None else v[:, 0], phys, rows)
     for name, value in state.items():
         cache[name] = cache[name].at[lane].set(value)
     first = _sample(logits, rng, temp, top_k, counters)
@@ -346,7 +348,9 @@ class LLMEngine:
         # slab, which the decode kernel copies whole
         spec, lanes = self._spec, self.config.max_batch_size
         pool = (spec.paged_layers, self.bm.num_slots, spec.row_width)
-        self.cache = {"k_pages": jnp.zeros(pool, cfg.dtype), "v_pages": jnp.zeros(pool, cfg.dtype)}
+        self.cache = {"k_pages": jnp.zeros(pool, cfg.dtype)}
+        if spec.v_pool:
+            self.cache["v_pages"] = jnp.zeros(pool, cfg.dtype)
         for name, rows_a_page, width, dtype in spec.page_extras:
             self.cache[name] = jnp.zeros((spec.paged_layers, self.bm.num_blocks * rows_a_page, width), dtype)
         for name, shape, dtype in spec.lane_state:
@@ -382,7 +386,7 @@ class LLMEngine:
         self._decode_jit = _profiling.instrument_jit(
             "serve_decode", jax.jit(functools.partial(decode_step, *bound), donate_argnums=held))
 
-    # the pools every family has, by name (the cache's first two arrays)
+    # the paged pools by name (``v_pages`` where the family states one)
     k_pages = property(lambda self: self.cache["k_pages"],
                        lambda self, pages: self.cache.__setitem__("k_pages", pages))
     v_pages = property(lambda self: self.cache["v_pages"],

@@ -276,3 +276,56 @@ def test_minicpm_sala_programs_compile_for_v5e(one_chip, monkeypatch):
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 * 2**30
     # the scores of 1,024 queries x 32 heads over 33,792 keys would be 4.4 GB in float32
     assert mem.temp_size_in_bytes < 1 * 2**30
+
+
+def test_mistral_small_4_programs_compile_for_v5e(one_chip, monkeypatch):
+    """Mistral-Small-4's two programs at the published widths (d 4096,
+    32 heads over one latent row of 320 stored as 384, q rank 1024, 32
+    held experts of width 2048 of 128 routed, a shared one), 48 lanes
+    over 36,864 positions in pages of 64, depth cut to one layer: the
+    decode step reads the latent pages through the absorbed kernel (one
+    call a layer, two grouped matmuls) and builds nothing of [lanes,
+    context, heads, 192]; a 4,096-token chunk compiles with its loop over
+    key blocks and fits beside the weights and the cache."""
+    from ray_tpu.models import mistral4
+    from ray_tpu.serve.llm.engine import decode_step, prefill_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = mistral4.Mistral4Config.mistral_small_4_6l_ep4(n_layer=1)
+    B, C, block, T = 48, 36864, 64, 4096
+    spec = mistral4.cache_spec(cfg, block)
+    assert spec.names == ("k_pages",) and spec.row_width == 384
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    def shaped(tree):
+        return jax.tree_util.tree_map(lambda x: arr(x.shape, x.dtype), tree)
+
+    params = shaped(jax.eval_shape(lambda: mistral4.init_params(cfg)))
+    key = shaped(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    pool = arr((spec.paged_layers, 786432 + block, spec.row_width), cfg.dtype)
+
+    decode = jax.jit(lambda *a: decode_step(cfg, 0, block, spec, *a), donate_argnums=(1,)).lower(
+        params, pool, arr((B,), jnp.int32), arr((B,), jnp.int32), arr((B, C // block), jnp.int32),
+        arr((B,), jnp.int32), arr((B,), jnp.float32), key).compile()
+    text = decode.as_text()
+    calls = [ln.split(" = ")[0].split("%")[-1] for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert sum(c.startswith("mla_paged_decode_attention") for c in calls) == 1
+    assert sum(c.startswith("moe_gmm") for c in calls) == 2 and len(calls) == 3
+    # keys and values of 48 lanes x 36,864 positions expanded to 32 heads of 192 would be 21.7 GB a
+    # layer, one lane's 453 MB; the step's temporaries are rows of the experts and the logits
+    assert decode.memory_analysis().temp_size_in_bytes < 32 * 2**20
+    assert f"s32[{B + len(mistral4.COUNTERS)}]" in text
+    # the pool keeps its row-major layout through the step: no copy of it
+    assert "bf16[1,786496,384]{1,2,0" not in text
+
+    chunk = jax.jit(lambda *a: prefill_step(cfg, 0, block, spec, *a), donate_argnums=(1,)).lower(
+        params, pool, arr((1, T), jnp.int32), arr((T,), jnp.int32), arr((1,), jnp.int32),
+        arr((1,), jnp.float32), key, arr((), jnp.int32), arr((C // block,), jnp.int32),
+        arr((), jnp.int32)).compile()
+    mem = chunk.memory_analysis()
+    # the scores of 4,096 queries x 32 heads over 36,864 keys would be 19 GB in float32
+    assert mem.temp_size_in_bytes < 1 * 2**30
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 * 2**30

@@ -20,7 +20,8 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 pytest.register_assert_rewrite(
-    "benchmark.tests.test_benchmark", "benchmark.tests.test_olmoe_cell"
+    "benchmark.tests.test_benchmark", "benchmark.tests.test_olmoe_cell",
+    "benchmark.tests.test_mistral_small_4_cell",
 )
 
 from benchmark.tests.test_benchmark import (  # noqa: E402,F401
@@ -39,4 +40,12 @@ from benchmark.tests.test_olmoe_cell import (  # noqa: E402,F401
     test_every_seed_takes_the_pool_from_the_head_of_the_same_order,
     test_grouped_matmul_work_and_roofline_share_by_hand,
     test_runner_fails_at_once_where_the_program_has_no_such_family,
+)
+from benchmark.tests.test_mistral_small_4_cell import (  # noqa: E402,F401
+    test_decode_kernel_and_held_experts_work_and_roofline_shares_by_hand,
+    test_the_cell_s_metrics_are_the_entries_of_benchmark_json,
+    test_the_configuration_is_the_catalog_s_but_for_what_it_says_is_reduced,
+)
+from benchmark.tests.test_mistral_small_4_cell import (  # noqa: E402,F401
+    test_runner_fails_at_once_where_the_program_has_no_such_family as test_mistral_runner_fails_at_once_where_the_program_has_no_such_family,
 )
