@@ -199,8 +199,8 @@ _CONFIG_DEFS: Dict[str, Any] = {
     # break died-mid-capture recovery.
     "profile_table_size": 512,
     # JAX/XLA introspection on instrumented jitted functions: compile
-    # timing, retrace counting.  Off = the wrapper is a cache-size
-    # check per call.
+    # timing, retrace counting.  Off = the jitted function is returned
+    # unwrapped.
     "jax_introspection": True,
     # --- compiled-DAG dataplane (dag/ + experimental/channel.py) ---
     # Unacked-message window per cross-host socket channel: the socket

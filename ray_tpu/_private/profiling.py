@@ -553,14 +553,35 @@ _jit_lock = threading.Lock()
 _jit_records: Dict[str, Dict[str, Any]] = {}
 
 
-def _cache_size(jfn) -> Optional[int]:
-    """Compiled-executable cache size of a jitted callable, or None when
-    the jax version doesn't expose it (then only the first call is
-    counted as a compile)."""
-    try:
-        return int(jfn._cache_size())
-    except Exception:  # noqa: BLE001 — private API, version-dependent
-        return None
+# JAX reports every program it lowers (a first call, a new shape or
+# dtype) as this event, in the thread that made the call.  A call that
+# only misses the jit's fast-path cache (the same shapes from another
+# place: NumPy arrays after device arrays) finds its traced and compiled
+# program in memory and lowers nothing, though that cache grows by it.
+_LOWERED_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_lowered = threading.local()
+_lowered_listening = False
+
+
+def _programs_lowered() -> int:
+    """Programs the calling thread has lowered since the first
+    ``instrument_jit``."""
+    return getattr(_lowered, "n", 0)
+
+
+def _listen_for_lowerings() -> None:
+    global _lowered_listening
+    with _jit_lock:
+        if _lowered_listening:
+            return
+        import jax.monitoring
+
+        def on_event(event, _secs, **_kw):
+            if event == _LOWERED_EVENT:
+                _lowered.n = getattr(_lowered, "n", 0) + 1
+
+        jax.monitoring.register_event_duration_secs_listener(on_event)
+        _lowered_listening = True
 
 
 def jit_stats(name: Optional[str] = None) -> Dict[str, Any]:
@@ -575,12 +596,15 @@ def instrument_jit(name: str, jfn):
     """Wrap an already-jitted callable with compile-time and retrace
     counters.
 
-    Steady-state cost per call: one cache-size probe + two
-    perf_counter reads (~0.5 us) — far inside the telemetry budget for
-    step-scale functions.  When a call triggers a (re)trace, its wall
-    time is recorded as ``jax_compile_seconds`` (trace+compile+first
-    run — the stall the operator actually sees) and a
-    ``jax.compile`` span lands in the timeline.  Disabled via
+    Steady-state cost per call: two reads of a thread-local count and
+    two perf_counter reads (~0.5 us) — far inside the telemetry budget
+    for step-scale functions.  A call compiled where JAX reports that it
+    lowered a program (``_LOWERED_EVENT``), not where the jit's
+    fast-path cache grew: that also grows when the same shapes come from
+    another place, which costs no compile.  When a call triggers a
+    (re)trace, its wall time is recorded as ``jax_compile_seconds``
+    (trace+compile+first run — the stall the operator actually sees) and
+    a ``jax.compile`` span lands in the timeline.  Disabled via
     ``jax_introspection=False`` (returns ``jfn`` unwrapped).
     """
     try:
@@ -588,7 +612,8 @@ def instrument_jit(name: str, jfn):
             return jfn
     except Exception:  # noqa: BLE001 — config unavailable in exotic contexts
         pass
-    state = {"cache_size": _cache_size(jfn) or 0, "compiles": 0}
+    _listen_for_lowerings()
+    state = {"compiles": 0}
     with _jit_lock:
         _jit_records.setdefault(
             name,
@@ -600,14 +625,10 @@ def instrument_jit(name: str, jfn):
 
         t_wall = time.time()
         t0 = time.perf_counter()
+        lowered = _programs_lowered()
         out = jfn(*args, **kwargs)
         dt = time.perf_counter() - t0
-        cs = _cache_size(jfn)
-        compiled = (cs is not None and cs > state["cache_size"]) or (
-            cs is None and state["compiles"] == 0
-        )
-        if compiled:
-            state["cache_size"] = cs if cs is not None else state["cache_size"]
+        if _programs_lowered() > lowered:
             state["compiles"] += 1
             first = state["compiles"] == 1
             with _jit_lock:

@@ -15,8 +15,10 @@ which keeps one decode program in flight.  Each iteration:
    the model family states a chunk, a longer prompt is as many
    programs, in order, each reading what the ones before it wrote;
 3. one jitted decode step for EVERY lane with tokens left is dispatched
-   on the tokens of the step before it, which never leave the device,
-   and only then are the programs dispatched before it fetched, in
+   on the tokens of the step before it and on the lanes' lengths, block
+   tables and temperatures, none of which leave the device (a join
+   writes its lane's row there, a leave the device cannot foresee clears
+   it), and only then are the programs dispatched before it fetched, in
    order, and their tokens emitted (docs/serving.md "What a step is made of").
 
 Tokens stream to per-request asyncio queues; the serve replica's
@@ -82,7 +84,7 @@ ENGINE_SPANS = (
     "engine.prefill.build",  # bucket, pad, phys_indices of one prompt
     "engine.prefill.run",    # executor thread: the prefill jit call (dispatch)
     "engine.prefill.fetch",  # loop thread: a prefill in flight: its first token to the host
-    "engine.decode.build",   # block tables and lengths of all lanes
+    "engine.decode.build",   # advance_lanes (dispatch: the step's arguments, on the device), the step's counts
     "engine.decode.run",     # executor thread: the decode jit call (dispatch)
     "engine.decode.fetch",   # loop thread: waiting for the step in flight, np.asarray(nxt)
     "engine.emit",           # tokens onto the streams, _finish of lanes that end
@@ -118,6 +120,7 @@ class _Request:
     slot: int = -1
     generated: int = 0
     dispatched: int = 0  # tokens whose programs were dispatched: generated + what is in flight
+    row_live: bool = False  # its lane's row on the device is its own (joined, and not cleared since)
     finish_reason: str = ""
     cancelled: bool = False
     t_join: float = 0.0
@@ -226,6 +229,63 @@ def _sample(logits, rng, temp, top_k, counters):
     return jnp.concatenate([tokens, *counters]) if counters else tokens
 
 
+# What the device holds of every lane beside its newest token: the
+# arguments of the next decode step that the host would otherwise build
+# and send again each step.  ``rows`` is int32 [B, 2 + pages], a lane a
+# row: the positions it has cached (0 where it does not run), the decode
+# steps it is still to be dispatched for, its physical blocks; ``temp``
+# [B] its temperature; ``step`` the decode steps dispatched so far (the
+# sampling key's counter).  A lane's table and temperature are fixed
+# from its join to its leave (``kv_cache.py``: a sequence's whole need
+# is reserved at admission).  Few arrays, because on the host each array
+# a program RETURNS costs as much as a third of a whole dispatch.
+LANE_STATE = ("rows", "temp", "step")
+_CACHED, _LEFT, _TABLE = 0, 1, 2  # columns of ``rows``
+
+
+def put_lane(lane_tok, lanes, first, lane, row, temp):
+    """A join: the request's first token (the head of ``first``, a
+    prefill's output) to its lane's place, and its row (the positions
+    its prompt cached, the decode steps it has left, its block table)
+    and temperature to the lanes' state."""
+    return lane_tok.at[lane].set(first.reshape(-1)[0]), dict(
+        lanes, rows=lanes["rows"].at[lane].set(row), temp=lanes["temp"].at[lane].set(temp[0]))
+
+
+def clear_lane(lanes, lane):
+    """A leave the device could not foresee (eos_token, cancel(), a
+    preemption, stop()): the lane runs no further step."""
+    return dict(lanes, rows=lanes["rows"].at[lane].set(0))
+
+
+def advance_lanes(block_size, lanes, base_key):
+    """One decode step's arguments from the lanes' state, and the state
+    after it: -> (rows, step, lengths, tables, write_phys, rng), the
+    last four as the host built them before (zeros for a lane that does
+    not run, which then reads nothing and writes scratch slot 0).  A
+    lane whose last step this is has its row zeroed: nothing stale is
+    left for a step to read.  The key is a function of the seed and of
+    how many steps were dispatched."""
+    import jax
+    import jax.numpy as jnp
+
+    rows, step = lanes["rows"], lanes["step"]
+    runs = rows[:, _LEFT] > 0
+    lengths = jnp.where(runs, rows[:, _CACHED], 0)  # also the fed token's position
+    tables = jnp.where(runs[:, None], rows[:, _TABLE:], 0)
+    block = jnp.take_along_axis(tables, (lengths // block_size)[:, None], axis=1)[:, 0]
+    write_phys = block * block_size + lengths % block_size
+    after = rows.at[:, _CACHED].add(1).at[:, _LEFT].add(-1)
+    after = jnp.where((after[:, _LEFT] > 0)[:, None], after, 0)
+    return after, step + 1, lengths, tables, write_phys, jax.random.fold_in(base_key, step)
+
+
+def _host_bytes(args) -> int:
+    """Bytes of the host-made arrays among a jit call's arguments: what
+    the call has to send to the device before its program can run."""
+    return sum(a.nbytes for a in args if isinstance(a, (np.ndarray, np.generic)))
+
+
 @dataclass
 class _InFlight:
     """A program dispatched and not yet fetched.  The device runs
@@ -294,6 +354,11 @@ class LLMEngine:
             # (the pipeline engaged), and lane-steps whose request had
             # ended (eos_token, cancel) by the time their token came
             "decodes_chained": 0, "lane_steps_discarded": 0,
+            # bytes of host-made arguments the decode dispatches carried
+            # (0: a step sends the device nothing it already has), and
+            # rows of the device's lane state written or cleared (joins
+            # plus the leaves the device could not foresee)
+            "decode_host_bytes": 0, "lane_edits": 0,
             **dict.fromkeys(self._counter_names, 0),
         }
         # where the current slice of the loop began (_note_stall)
@@ -364,9 +429,25 @@ class LLMEngine:
         # goes in as it is dispatched, and is the next decode step's
         # ``tok``, so no token crosses to the host and back to be fed
         self._lane_tok = jnp.zeros(lanes, jnp.int32)
-        self._put_lane = jax.jit(lambda lane_tok, first, lane: lane_tok.at[lane].set(first.reshape(-1)[0]))
+        # and the rest of what a decode step is called with (LANE_STATE):
+        # written by a join, cleared by a leave the device cannot
+        # foresee, advanced on the device by one small program a step
+        pages = self.bm.blocks_needed(self.max_ctx)
+        self._lanes = {"rows": jnp.zeros((lanes, _TABLE + pages), jnp.int32),
+                       "temp": jnp.zeros(lanes, jnp.float32), "step": jnp.zeros((), jnp.int32)}
+        assert tuple(self._lanes) == LANE_STATE
+        self._put_lane = jax.jit(put_lane)
+        self._clear_lane = jax.jit(clear_lane)
+        self._advance_lanes = jax.jit(functools.partial(advance_lanes, self.config.block_size))
         self._lanes_of = jax.jit(lambda nxt: nxt[:lanes]) if self._counter_names else (lambda nxt: nxt)
+        # prefills (and whoever replays a sequence through the two
+        # programs) draw their keys from the first by the host's
+        # counter, decode steps from the second by the device's
         self._base_key = jax.random.PRNGKey(self.config.seed + 1)
+        self._decode_key = jax.random.PRNGKey(self.config.seed + 2)
+        self._fold_in = jax.jit(jax.random.fold_in)
+        # the first leave of its kind must not compile mid-service
+        self._lanes = self._clear_lane(self._lanes, np.int32(0))
         top_k = self.config.top_k
         # a disabled TraceMe (well under a microsecond) outside a
         # jax.profiler session; bound here because only this method
@@ -401,10 +482,12 @@ class LLMEngine:
         return out
 
     def _next_rng(self):
-        import jax
-
+        """The next key of the host's stream (a prefill's, a replay's):
+        ``fold_in(base, counter)``, as one jitted dispatch: with a Python
+        int ``jax.random.fold_in`` is several, a millisecond on a v5e's
+        host."""
         self._rng_counter += 1
-        return jax.random.fold_in(self._base_key, self._rng_counter)
+        return self._fold_in(self._base_key, np.uint32(self._rng_counter))
 
     @staticmethod
     def _prefill_bucket(n: int, cap: int) -> int:
@@ -754,6 +837,19 @@ class LLMEngine:
         if self.slots[req.slot] is req:
             self.slots[req.slot] = None
 
+    def _clear_row(self, req: _Request):
+        """``req`` runs no further decode step: where the device still
+        counts some for it, its lane's row is cleared, by one small
+        program dispatched behind the steps it was in.  A lane that ends
+        by length needs none (its count reached 0 on the device in the
+        step the host stopped counting it), and by then the row may be
+        a successor's.  Called from the loop's thread between its
+        dispatches, never beside one."""
+        if req.row_live and req.dispatched < req.max_tokens:
+            self._lanes = self._clear_lane(self._lanes, np.int32(req.slot))
+            self._counts["lane_edits"] += 1
+        req.row_live = False
+
     @staticmethod
     def _kv_need(req: _Request) -> int:
         """Remaining KV reservation.  Invariant under preemption folds:
@@ -887,6 +983,7 @@ class LLMEngine:
 
         if req.slot >= 0:
             self.slots[req.slot] = None
+        self._clear_row(req)
         req.slot = -1
         self.bm.free(req.request_id)
         # Chaos fault point: "@serve.preempt.evict:kill:at=N" dies after
@@ -956,8 +1053,9 @@ class LLMEngine:
         reading what the ones before it wrote (K and V through the block
         table, the lane's state from its slot; the first starts from a
         state of zeros).  The last one's token is the request's first:
-        it goes to the lane's place on the device, for the next decode
-        step, and to the host when its turn to be fetched comes."""
+        it goes to the lane's place on the device with the lane's row
+        (``put_lane``, in the same dispatch), for the next decode step,
+        and to the host when its turn to be fetched comes."""
         n = len(req.prompt)
         most = min(self._spec.prefill_chunk or self.max_ctx, self.max_ctx)
         lane = np.int32(req.slot)
@@ -968,20 +1066,32 @@ class LLMEngine:
                 toks = np.zeros((1, bucket), dtype=np.int32)
                 toks[0, :m] = req.prompt[start:start + m]
                 self.bm.advance(req.request_id, m)
-                inputs = [toks, self.bm.phys_indices(req.request_id, start + m, bucket, start=start),
-                          np.array([m - 1], dtype=np.int32),
-                          np.array([req.temperature], dtype=np.float32), self._next_rng()]
-                if self._spec.reads_cache:
-                    inputs += [np.int32(start),
-                               self.bm.block_table(req.request_id, self.bm.blocks_needed(self.max_ctx)), lane]
                 last = start + m == n
+                temp = np.array([req.temperature], dtype=np.float32)
+                inputs = [toks, self.bm.phys_indices(req.request_id, start + m, bucket, start=start),
+                          np.array([m - 1], dtype=np.int32), temp, self._next_rng()]
+                if last or self._spec.reads_cache:
+                    table = self.bm.block_table(req.request_id, self.bm.blocks_needed(self.max_ctx))
+                if self._spec.reads_cache:
+                    inputs += [np.int32(start), table, lane]
                 counts = {"prompt_tokens": m, "prefill_bucket_tokens": bucket, "prefill_chunks": 1,
                           "state_bytes": 2 * self._state_bytes // self.config.max_batch_size}
+                if last:
+                    # the lane's row: n positions cached, a decode step
+                    # for every token but this program's own, its blocks
+                    # (all zeros where the prefill's token is its last)
+                    left = req.max_tokens - req.dispatched - 1
+                    row = np.concatenate([np.array([n, left], dtype=np.int32), table]) * np.int32(left > 0)
 
             def call():
                 first_tok = self._run_on_cache(self._prefill_jit, *inputs)
                 if last:
-                    self._lane_tok = self._put_lane(self._lane_tok, first_tok, lane)
+                    self._lane_tok, self._lanes = self._put_lane(
+                        self._lane_tok, self._lanes, first_tok, lane, row, temp)
+                    # said here, beside the edit: a stop() that cancels
+                    # the await below must still find the row to clear
+                    req.row_live = True
+                    self._counts["lane_edits"] += 1
                 return first_tok
 
             first_tok = await self._dispatch(loop, "engine.prefill", call)
@@ -991,54 +1101,57 @@ class LLMEngine:
 
     async def _dispatch_decode(self, loop) -> Optional[_InFlight]:
         """Dispatch one decode step for every lane with tokens left, on
-        the lanes' newest tokens where they lie on the device; None
-        where no lane has any.  Lengths, block tables and write slots
-        follow from how many tokens a lane was DISPATCHED for
-        (``bm.seq_len``), never from what they were: so the step is
-        built while the one before it runs."""
+        the lanes' newest tokens and on their lengths, block tables,
+        write slots and temperatures where all of them lie on the
+        device; None where no lane has any.  Those follow from how many
+        tokens a lane was DISPATCHED for, never from what they were: the
+        device advances them itself (``advance_lanes``), so the step is
+        dispatched while the one before it runs and is sent nothing.
+        The host keeps its mirror (``bm.seq_len``, ``dispatched``) for
+        what only it decides: who holds a lane, and who has ended."""
         with self._phase("engine.admit"):
-            # a lane that ends by length is known a step ahead and left
-            # out; one that ends by eos_token or cancel() is found out
-            # when its token comes, a lane-step late
-            lanes = [(i, req) for i, req in enumerate(self.slots)
-                     if not self._lane_is_free(i) and not req.cancelled]
+            # a lane that ends by length is known a step ahead, here and
+            # on the device, and left out; one that ends by eos_token or
+            # cancel() is found out when its token comes, a lane-step
+            # late, or right here, and its row is cleared where it is
+            lanes = []
+            for i, req in enumerate(self.slots):
+                if self._lane_is_free(i):
+                    continue
+                if req.cancelled:
+                    self._clear_row(req)
+                else:
+                    lanes.append((i, req))
         if not lanes:
             return None
         with self._phase("engine.decode.build"):
-            B = self.config.max_batch_size
             bs = self.bm.block_size
-            lengths = np.zeros(B, dtype=np.int32)
-            tables = np.zeros((B, self.bm.blocks_needed(self.max_ctx)), dtype=np.int32)
-            write_phys = np.zeros(B, dtype=np.int32)
-            temp = np.zeros(B, dtype=np.float32)
-            attended = read = 0
-            for i, req in lanes:
-                rid = req.request_id
-                cur_len = self.bm.seq_len(rid)  # positions already in cache
-                lengths[i] = cur_len  # also the fed token's position
-                tables[i] = self.bm.block_table(rid, tables.shape[1])
-                self.bm.advance(rid, 1)
-                write_phys[i] = self.bm.phys_index(rid, cur_len)
-                temp[i] = req.temperature
-                attended += cur_len
-                read += -(-cur_len // bs) * bs  # whole pages
-            rng = self._next_rng()
             counts = {"decodes_chained": int(any(p.decode for p in self._inflight)),
                       "state_bytes": 2 * self._state_bytes}
             if "kv_positions_gathered" not in self._counter_names:
                 # every cached position of every lane is read; a family
                 # that reads a selection counts what it reads itself
-                counts.update(kv_positions_attended=attended, kv_positions_gathered=read)
+                cached = [self.bm.seq_len(req.request_id) for _, req in lanes]
+                counts.update(kv_positions_attended=sum(cached),
+                              kv_positions_gathered=sum(-(-n // bs) * bs for n in cached))  # whole pages
+            # dispatched from this thread: both dispatches in the one
+            # executor hop cost a v5e's host 0.4 ms a step more than one
+            # here and one there (PERF.md §6, PR 34)
+            rows, step, lengths, tables, write_phys, rng = self._advance_lanes(self._lanes, self._decode_key)
+            inputs = (self._lane_tok, lengths, tables, write_phys, self._lanes["temp"], rng)
+            counts["decode_host_bytes"] = _host_bytes((*self._lanes.values(), self._decode_key, *inputs))
 
         def call():
-            nxt = self._run_on_cache(
-                self._decode_jit, self._lane_tok, lengths, tables, write_phys, temp, rng)
-            self._lane_tok = self._lanes_of(nxt)
+            # the state is rebound only once the step is dispatched: a
+            # call that raises leaves device and mirror where they were
+            nxt = self._run_on_cache(self._decode_jit, *inputs)
+            self._lanes, self._lane_tok = dict(self._lanes, rows=rows, step=step), self._lanes_of(nxt)
             return nxt
 
         nxt = await self._dispatch(loop, "engine.decode", call)
         for _, req in lanes:
             req.dispatched += 1
+            self.bm.advance(req.request_id, 1)
         self._inflight.append(_InFlight(nxt, lanes, counts))
         return self._inflight[-1]
 
@@ -1114,6 +1227,7 @@ class LLMEngine:
         engine: frees blocks, emits the sentinel, records spans/TTFT."""
         if self._by_id.pop(req.request_id, None) is None:
             return
+        self._clear_row(req)
         self.bm.free(req.request_id)
         req.finish_reason = req.finish_reason or reason
         req.t_done = time.time()
