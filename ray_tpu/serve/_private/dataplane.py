@@ -25,15 +25,22 @@ Frames (wire-encoded tuples):
 Attach is best-effort: any failure (old replica, config off, channel
 death) falls the affected replica back to the per-call RPC path — the
 dataplane is an optimization, never a correctness dependency.
+
+What the replica's two threads cost is counted where it is spent
+(``replica_counters``): the rx thread stamps ``rx_at`` into a request's
+meta the instant its frame is read (beside the handle's ``sent_at``),
+and each thread sums its busy seconds.  One writer a counter, no lock.
 """
 
 from __future__ import annotations
 
+import logging
 import os
 import queue
 import threading
+import time
 import uuid
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 from ray_tpu.experimental.channel import (
     Channel,
@@ -45,7 +52,34 @@ from ray_tpu.experimental.channel import (
     reattach,
 )
 
+logger = logging.getLogger(__name__)
+
 _DEAD = object()  # rx-thread sentinel fanned out to every waiter on death
+
+# every endpoint this process opened, the detached ones too: what they
+# counted stays in the sums
+_ENDPOINTS: List["ReplicaDataplane"] = []
+
+
+def replica_counters() -> Dict[str, float]:
+    """What this process's replica-side endpoints have counted, summed
+    over them (flat and numeric, for a deployment's ``stats()``):
+    ``frames_rx`` / ``frames_tx`` are the request channels' reads and the
+    response channels' writes (``Channel.stats``, not counted again);
+    ``rx_busy_s`` from a frame read to its coroutine scheduled;
+    ``tx_busy_s`` from a frame taken off the queue to its commit (encode
+    and publish: the work alone); ``egress_tx_s`` from ``_put_frame`` to
+    that commit (the tx thread's wake-up and its wait for the GIL too)."""
+    out = {"frames_rx": 0, "frames_tx": 0, "rx_busy_s": 0.0, "tx_busy_s": 0.0, "egress_tx_s": 0.0}
+    for dp in list(_ENDPOINTS):
+        with dp._chan_lock:
+            req, resp = dp._req, dp._resp
+        out["frames_rx"] += req.stats["reads"] if req is not None else 0
+        out["frames_tx"] += resp.stats["writes"]
+        out["rx_busy_s"] += dp._rx_busy_s
+        out["tx_busy_s"] += dp._tx_busy_s
+        out["egress_tx_s"] += dp._egress_tx_s
+    return out
 
 
 class ReplicaDataplane:
@@ -68,6 +102,10 @@ class ReplicaDataplane:
         # dispatch checks this set at start so the cancel can't be lost.
         self._pre_cancelled: set = set()
         self._closed = False
+        # seconds, each written by one thread alone (replica_counters)
+        self._rx_busy_s = 0.0
+        self._tx_busy_s = 0.0
+        self._egress_tx_s = 0.0
         # Guards _req: the rx thread binds it after a socket accept while
         # shutdown (tx thread or event loop) snapshots it for close.
         self._chan_lock = threading.Lock()
@@ -88,6 +126,7 @@ class ReplicaDataplane:
         self._tx = threading.Thread(
             target=self._tx_loop, daemon=True, name="serve-dataplane-tx"
         )
+        _ENDPOINTS.append(self)
         self._rx.start()
         self._tx.start()
 
@@ -121,8 +160,13 @@ class ReplicaDataplane:
                     if reattach(self._req):
                         continue
                     raise
+                t_read = time.time()
                 kind, rid, method, args, kwargs, model_id = frame[:6]
                 meta = frame[6] if len(frame) > 6 else None
+                if meta:
+                    # where the request first exists in this process
+                    # (the frame's dict is this thread's own)
+                    meta["rx_at"] = t_read
                 if kind == "cancel":
                     # park-then-recheck (the dispatch does the mirrored
                     # register-then-check): whichever side runs second
@@ -133,6 +177,7 @@ class ReplicaDataplane:
                     if task is not None:
                         self._pre_cancelled.discard(rid)
                         self._loop.call_soon_threadsafe(task.cancel)
+                    self._rx_busy_s += time.time() - t_read
                     continue
                 asyncio.run_coroutine_threadsafe(
                     self._dispatch(
@@ -141,8 +186,9 @@ class ReplicaDataplane:
                     ),
                     self._loop,
                 )
-        except (ChannelClosed, Exception):  # noqa: BLE001 — rx death = detach
-            self.shutdown()
+                self._rx_busy_s += time.time() - t_read
+        except (ChannelClosed, Exception) as e:  # noqa: BLE001 — rx death = detach
+            self._detach(e)
 
     async def _dispatch(self, kind, rid, method, args, kwargs, model_id,
                         tctx=None, request_meta=None) -> None:
@@ -203,7 +249,7 @@ class ReplicaDataplane:
         correctly (the tx thread itself has no ambient context)."""
         from ray_tpu.util import tracing
 
-        self._out_q.put((frame, tracing.current_context()))
+        self._out_q.put((frame, tracing.current_context(), time.time()))
 
     # -- response side --------------------------------------------------
     def _tx_loop(self) -> None:
@@ -213,7 +259,8 @@ class ReplicaDataplane:
             item = self._out_q.get()
             if item is None:
                 return
-            frame, rctx = item
+            t_taken = time.time()
+            frame, rctx, t_put = item
             try:
                 if rctx is not None:
                     tok = tracing.adopt_context(rctx)
@@ -223,9 +270,24 @@ class ReplicaDataplane:
                         tracing.reset_context(tok)
                 else:
                     self._resp.write_value(frame, timeout=None)
-            except (ChannelClosed, Exception):  # noqa: BLE001
-                self.shutdown()
+            except (ChannelClosed, Exception) as e:  # noqa: BLE001
+                self._detach(e)
                 return
+            t_committed = time.time()
+            self._tx_busy_s += t_committed - t_taken
+            self._egress_tx_s += t_committed - t_put
+
+    def _detach(self, why: BaseException) -> None:
+        """A thread of this endpoint met the channel's death: the router
+        falls back to the RPC path.  Said once in the replica's log, or
+        it shows only as ``frames_tx`` going flat; silent where
+        ``shutdown()`` came first (the replica's own teardown)."""
+        if not self._closed:
+            logger.warning(
+                "serve dataplane of replica %s detached (requests take the RPC path): %s: %s",
+                getattr(self._replica, "replica_id", "?"), type(why).__name__, why,
+            )
+        self.shutdown()
 
     def shutdown(self) -> None:
         if self._closed:

@@ -10,6 +10,15 @@ import time
 from typing import Any, Dict, Optional
 
 
+def _stamp_rx(request_meta: Optional[dict]) -> Optional[dict]:
+    """The request's meta with ``rx_at``, the instant it first existed in
+    this process: the dataplane's rx thread has set it where the frame
+    was read; on the RPC path that is here, at the top of the handler."""
+    if request_meta and "rx_at" not in request_meta:
+        return dict(request_meta, rx_at=time.time())
+    return request_meta
+
+
 class Replica:
     def __init__(
         self,
@@ -55,6 +64,7 @@ class Replica:
         from ray_tpu.serve._private.request_context import _set_request_meta
         from ray_tpu.serve.multiplex import _set_request_model_id
 
+        request_meta = _stamp_rx(request_meta)
         async with self._sem:
             self._ongoing += 1
             self._total += 1
@@ -79,6 +89,7 @@ class Replica:
         from ray_tpu.serve._private.request_context import _set_request_meta
         from ray_tpu.serve.multiplex import _set_request_model_id
 
+        request_meta = _stamp_rx(request_meta)
         async with self._sem:
             self._ongoing += 1
             self._total += 1

@@ -1,7 +1,10 @@
 """Per-request identity context (tenant + SLO class).
 
 The serving plane threads a small ``request_meta`` dict — ``{"tenant":
-..., "slo": ...}`` — from the proxy header / handle kwarg through the
+..., "slo": ...}``, and since a handle stamps every call the two
+instants ``sent_at`` (``DeploymentHandle._call``) and ``rx_at`` (where
+the request first existed in the replica's process), both
+``time.time()`` — from the proxy header / handle kwarg through the
 router and the channel-dataplane wire frames into the replica, which
 sets it here (a contextvar, same pattern as multiplex's model-id
 context) before dispatching user code.  ``serve.get_request_tenant()`` /

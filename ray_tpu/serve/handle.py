@@ -3,6 +3,7 @@ calling deployments from Python or other deployments."""
 
 from __future__ import annotations
 
+import time
 from typing import Any, Optional
 
 
@@ -157,16 +158,22 @@ class DeploymentHandle:
         return self._router
 
     def _call(self, method: str, args: tuple, kwargs: dict):
+        # the instant the caller handed the request to the runtime, on
+        # the clock of the engine's own stamps: the replica adds
+        # ``rx_at`` where the request first exists in its process and
+        # the engine sums the differences (docs/serving.md "Latency
+        # attribution").  A copy a call: the handle's dict is identity.
+        meta = dict(self._request_meta or (), sent_at=time.time())
         router = self._ensure_router()
         if self._stream:
             gen, rid = router.route_stream(
                 method, args, kwargs, self._multiplexed_model_id,
-                request_meta=self._request_meta,
+                request_meta=meta,
             )
             return DeploymentResponseGenerator(gen, router, rid)
         ref, rid = router.route(
             method, args, kwargs, self._multiplexed_model_id,
-            request_meta=self._request_meta,
+            request_meta=meta,
         )
         return DeploymentResponse(ref, router, rid)
 

@@ -133,6 +133,7 @@ class LLMServer:
                 ev = await req.out.get()
                 if ev is FINISHED:
                     break
+                engine.token_taken(req)
                 yield ev
             yield {
                 "request_id": req.request_id,
@@ -170,6 +171,7 @@ class LLMServer:
                 ev = await req.out.get()
                 if ev is FINISHED:
                     break
+                engine.token_taken(req)
             return {
                 "request_id": req.request_id,
                 "tokens": list(req.tokens),
@@ -192,7 +194,12 @@ class LLMServer:
         return False
 
     def stats(self) -> Dict[str, Any]:
+        from ray_tpu.serve._private.dataplane import replica_counters
+
         out = self.engine.stats()
+        # what the replica's channel endpoints counted on the way in and
+        # out (zeros where requests take the RPC path): flat and numeric
+        out.update(replica_counters())
         out["pid"] = os.getpid()
         out["multiplex"] = {
             "loaded_model_ids": [v.model_id for v in self._loaded_variants()],

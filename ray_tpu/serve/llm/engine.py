@@ -52,15 +52,18 @@ import asyncio
 import collections
 import contextlib
 import functools
+import gc
 import logging
 import time
 import uuid
+import weakref
 from dataclasses import dataclass, field
 from typing import Any, Deque, Dict, List, Optional
 
 import numpy as np
 
 from ray_tpu._private import tenants as tenants_mod
+from ray_tpu.serve._private.request_context import get_request_meta
 from ray_tpu.serve.exceptions import RequestShedError
 from ray_tpu.serve.llm.config import LLMConfig, model_family
 from ray_tpu.serve.llm.kv_cache import BlockManager
@@ -137,6 +140,15 @@ class _Request:
     preemptions: int = 0
     folded: int = 0  # tokens already folded into prompt by past preemptions
     t_enqueue: float = 0.0  # last (re)queue time — the starvation clock
+    # the way in, from the request's meta (0.0 where it carried none: a
+    # direct add_request): the caller's hand-over to the handle, and the
+    # instant the request first existed in this process; never out of
+    # order with t_submit (add_request)
+    t_sent: float = 0.0
+    t_rx: float = 0.0
+    # the way out: when each token not yet taken off ``out`` was emitted,
+    # in order, beside the events and not in them (``token_taken``)
+    emit_times: Deque[float] = field(default_factory=collections.deque)
 
 
 def _write_rows(pages, rows, phys):
@@ -359,8 +371,23 @@ class LLMEngine:
             # rows of the device's lane state written or cleared (joins
             # plus the leaves the device could not foresee)
             "decode_host_bytes": 0, "lane_edits": 0,
+            # the runtime's share of a token, as sums of differences of
+            # stamps on one clock (docs/serving.md "What a step is made
+            # of").  In: requests that carried the handle's ``sent_at``,
+            # from there to the replica's ``rx_at``, from there to
+            # add_request.  First tokens, and the join they followed.
+            # Out: tokens a stream's coroutine took off its queue, and
+            # how long each had lain there
+            "submitted": 0, "ingress_wire_s": 0.0, "ingress_loop_s": 0.0,
+            "first_tokens": 0, "join_to_first_token_s": 0.0,
+            "tokens_out": 0, "egress_loop_s": 0.0,
+            # the interpreter's collections while the loop ran, whichever
+            # thread they began in: every thread of the process stands still
+            "gc_collections": 0, "gc_full_collections": 0, "gc_pause_s": 0.0,
             **dict.fromkeys(self._counter_names, 0),
         }
+        self._gc_t0: Optional[float] = None
+        self._gc_span = None
         # where the current slice of the loop began (_note_stall)
         self._slice_t0 = time.perf_counter()
         self._slice_before = dict(self._phase_s)
@@ -592,6 +619,12 @@ class LLMEngine:
             raise ValueError(f"duplicate request id {rid!r}")
         now = time.time()
         self._seq_counter += 1
+        # the way in, where the request's meta carries it.  Held to the
+        # order t_sent <= t_rx <= now: a stamp from a host whose clock
+        # runs ahead then counts a difference of 0, never a negative one
+        meta = get_request_meta() or {}
+        t_rx = min(float(meta.get("rx_at") or now), now)
+        t_sent = min(float(meta.get("sent_at") or 0.0), t_rx)
         req = _Request(
             request_id=rid,
             prompt=tokens,
@@ -605,7 +638,13 @@ class LLMEngine:
             priority=SLO_PRIORITY[slo],
             seq=self._seq_counter,
             t_enqueue=now,
+            t_sent=t_sent,
+            t_rx=t_rx if t_sent else 0.0,
         )
+        if t_sent:
+            self._counts["submitted"] += 1
+            self._counts["ingress_wire_s"] += t_rx - t_sent
+            self._counts["ingress_loop_s"] += now - t_rx
         if tenant != tenants_mod.DEFAULT_TENANT or req.priority != 1:
             self._fair_dirty = True
         self._by_id[rid] = req
@@ -698,22 +737,60 @@ class LLMEngine:
     async def _run(self):
         loop = asyncio.get_running_loop()
         self._note_stall()  # the first slice begins now
-        while not self._stopped:
-            try:
-                await self._iterate(loop)
-            except asyncio.CancelledError:
-                raise
-            except Exception:  # noqa: BLE001 — one bad step must not stop serving
-                logger.exception("llm engine step failed; continuing")
+        with self._gc_watched():
+            while not self._stopped:
                 try:
-                    # what is in flight ran before the step that raised:
-                    # its tokens are real, and go out first
-                    self._fetch_in_flight()
-                except Exception:  # noqa: BLE001 — the device's answer is lost too
-                    logger.exception("llm engine could not fetch what was in flight")
-                with self._phase("engine.idle", span=False):
-                    await asyncio.sleep(0.05)
-            self._note_stall()
+                    await self._iterate(loop)
+                except asyncio.CancelledError:
+                    raise
+                except Exception:  # noqa: BLE001 — one bad step must not stop serving
+                    logger.exception("llm engine step failed; continuing")
+                    try:
+                        # what is in flight ran before the step that raised:
+                        # its tokens are real, and go out first
+                        self._fetch_in_flight()
+                    except Exception:  # noqa: BLE001 — the device's answer is lost too
+                        logger.exception("llm engine could not fetch what was in flight")
+                    with self._phase("engine.idle", span=False):
+                        await asyncio.sleep(0.05)
+                self._note_stall()
+
+    @contextlib.contextmanager
+    def _gc_watched(self):
+        """While the step loop runs, the interpreter's collections are
+        counted where they happen: one ``gc.callbacks`` hook, gone with
+        the loop task (``stop()`` awaits its end).  It holds the engine
+        weakly, so an engine nobody stopped can still be collected, and
+        its loop's end then takes the hook away."""
+        engine = weakref.ref(self)
+
+        def hook(phase, info):
+            eng = engine()
+            if eng is not None:
+                eng._on_gc(phase, info)
+
+        gc.callbacks.append(hook)
+        try:
+            yield
+        finally:
+            gc.callbacks.remove(hook)
+            self._gc_t0 = None
+
+    def _on_gc(self, phase: str, info: dict):
+        """A collection starts or stops, in whichever thread allocated
+        last; collections never nest.  ``engine.gc`` is held from start
+        to stop in that thread, so a gap of the device whose middle lies
+        in a collection can be named in a traced run."""
+        if phase == "start":
+            self._gc_span = self._annotation("engine.gc")
+            self._gc_span.__enter__()
+            self._gc_t0 = time.perf_counter()
+        elif self._gc_t0 is not None:  # else it began before the hook was there
+            self._counts["gc_pause_s"] += time.perf_counter() - self._gc_t0
+            self._gc_t0 = None
+            self._gc_span.__exit__(None, None, None)
+            self._counts["gc_collections"] += 1
+            self._counts["gc_full_collections"] += int(info["generation"] == 2)
 
     async def _iterate(self, loop):
         with self._phase("engine.admit"):
@@ -1196,14 +1273,17 @@ class LLMEngine:
         for name, n in zip(self._counter_names, counted):
             self._counts[name] += n
 
-    def _emit(self, req: _Request, token: int, now: Optional[float] = None):
+    def _emit(self, req: _Request, token: int, now: float):
         req.tokens.append(token)
         req.generated += 1
         self._total_tokens += 1
         if self._fair_dirty or req.tenant != tenants_mod.DEFAULT_TENANT:
-            self._tenant_tok_window.append((now or time.time(), req.tenant, 1))
+            self._tenant_tok_window.append((now, req.tenant, 1))
         if req.t_first_token == 0.0:
-            req.t_first_token = now or time.time()
+            req.t_first_token = now
+            self._counts["first_tokens"] += 1
+            self._counts["join_to_first_token_s"] += now - req.t_join
+        req.emit_times.append(now)
         req.out.put_nowait(
             {
                 "request_id": req.request_id,
@@ -1211,6 +1291,16 @@ class LLMEngine:
                 "index": req.generated - 1,
             }
         )
+
+    def token_taken(self, req: _Request):
+        """A stream's coroutine took a token's event off ``req.out``
+        (called by whoever awaits that queue on behalf of a client, as
+        ``LLMServer`` does): the seconds the token lay there, waiting for
+        the loop to reach that coroutine, go to ``egress_loop_s``.  Both
+        stamps are this thread's, so the difference is not clamped."""
+        counts = self._counts
+        counts["tokens_out"] += 1
+        counts["egress_loop_s"] += time.time() - req.emit_times.popleft()
 
     def _is_finished(self, req: _Request, token: int) -> bool:
         eos = self.config.eos_token
@@ -1246,16 +1336,25 @@ class LLMEngine:
         return (trace_id, uuid.uuid4().hex[:16], parent)
 
     def _record_spans(self, req: _Request):
-        """serve.request -> {serve.queue, serve.prefill, serve.decode}:
-        the per-request latency decomposition that critical-path analysis
-        surfaces (docs/serving.md)."""
+        """serve.request -> {serve.ingress, serve.queue, serve.prefill,
+        serve.decode}: the per-request latency decomposition that
+        critical-path analysis surfaces (docs/serving.md).  The first
+        only where the request came through a handle (``t_sent``)."""
         try:
             from ray_tpu.util import tracing
 
             trace_id, root_id, parent = req.trace
             end = req.t_done or time.time()
+            # the chain begins where the caller's clock does
+            start = req.t_sent or req.t_submit
+            if req.t_sent:
+                tracing.record_span(
+                    "serve.ingress", start, req.t_submit,
+                    {"wire_s": req.t_rx - req.t_sent, "loop_s": req.t_submit - req.t_rx},
+                    context=(trace_id, uuid.uuid4().hex[:16], root_id),
+                )
             tracing.record_span(
-                "serve.request", req.t_submit, end,
+                "serve.request", start, end,
                 {
                     "request_id": req.request_id,
                     "deployment": self.config.name,

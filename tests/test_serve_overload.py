@@ -348,7 +348,10 @@ def test_engine_preempt_with_a_step_in_flight_folds_what_the_client_was_sent():
     """The loop keeps one decode step in flight; a preemption fetches it
     first, so the fold holds every token the victim was dispatched for,
     each of them emitted to its stream, and nothing of it is in flight."""
-    prompt, n = [6, 2, 8], 30
+    # an answer long enough that the hog still runs when the victim has
+    # starved: at 30 tokens of 0.7 ms a step it had ended first in one
+    # run of six on this machine, and the wait below never returned
+    prompt, n = [6, 2, 8], 120
 
     async def main():
         eng = LLMEngine(_tiny(max_batch_size=1, preempt_wait_s=0.005,
@@ -375,6 +378,7 @@ def test_engine_preempt_with_a_step_in_flight_folds_what_the_client_was_sent():
             await asyncio.sleep(0.01)
         vic = await eng.add_request([5], max_tokens=3, tenant="b", slo="interactive")
         while not hog.preemptions:
+            assert not hog.finish_reason, "drill is vacuous: the hog ended before it was preempted"
             await asyncio.sleep(0.005)
         folded_prompt = list(hog.prompt)
         toks, _ = await asyncio.gather(_drain(hog), _drain(vic))
