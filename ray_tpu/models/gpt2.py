@@ -7,6 +7,12 @@ BASELINE.json).  Design notes:
 - Param names (qkv / attn_out / mlp_up / mlp_down / wte / wpe / lm_head)
   are what ray_tpu.train.sharding.gpt2_partition_rules matches: mesh
   layouts come from that one rule table.
+- The fused qkv kernel is one [d, 3d] leaf, columns q | k | v (the
+  tree the serving forwards, the checkpoints and the benchmark's
+  reference read).  Under a mesh with a `model` axis it is stored by
+  rows and exchanged as weights, so that q, k and v are computed on the
+  device that holds their heads and no activation moves around the
+  attention kernel (`_qkv_by_head`); with no such axis it is a Dense.
 - `remat` wraps each block with jax.checkpoint to trade FLOPs for HBM.
 - Attention goes through ray_tpu.ops.attention which picks a fused
   implementation (Pallas splash/ring kernel on TPU, reference einsum
@@ -71,15 +77,20 @@ class Attention(nn.Module):
     def __call__(self, x, mask=None):
         cfg = self.cfg
         d_head = cfg.d_model // cfg.n_head
-        qkv = nn.Dense(3 * cfg.d_model, use_bias=cfg.use_bias, dtype=cfg.dtype,
-                       param_dtype=cfg.param_dtype, name="qkv")(x)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
         B, T = x.shape[0], x.shape[1]
+        from ray_tpu.ops.attention import causal_attention, mesh_split
+
+        qkv = nn.Dense(3 * cfg.d_model, use_bias=cfg.use_bias, dtype=cfg.dtype,
+                       param_dtype=cfg.param_dtype, name="qkv")
+        # the sequence axis is ring attention's: it splits T, not B
+        split = mesh_split(B, cfg.n_head, skip=(cfg.sp_axis,))
+        if split is None or split[2] is None or self.is_initializing():
+            q, k, v = jnp.split(qkv(x), 3, axis=-1)
+        else:
+            q, k, v = _qkv_by_head(x, qkv.variables["params"], cfg.dtype, *split)
 
         def heads(t):
             return t.reshape(B, T, cfg.n_head, d_head)
-
-        from ray_tpu.ops.attention import causal_attention
 
         out = causal_attention(
             heads(q), heads(k), heads(v), mesh=cfg.mesh, sp_axis=cfg.sp_axis
@@ -87,6 +98,59 @@ class Attention(nn.Module):
         out = out.reshape(B, T, cfg.d_model)
         return nn.Dense(cfg.d_model, use_bias=cfg.use_bias, dtype=cfg.dtype,
                         param_dtype=cfg.param_dtype, name="attn_out")(out)
+
+
+@partial(jax.jit, static_argnums=(2, 3, 4, 5))  # the layers of a model trace and lower it once
+def _qkv_by_head(x, p, dtype, mesh, batch_axes, head_axes):
+    """The fused projection under a mesh whose ``model`` axis (n wide)
+    divides the heads: q, k, v [B, T, d] of ``x @ kernel + bias``, each
+    leaving with its columns over ``model``, a head's on one device: the
+    layout ``ops.attention``'s shard_map of the flash kernel asks for.
+
+    The kernel [d, 3d] is stored q | k | v, so no block of its columns
+    holds a head's q, k and v, and a projection sharded by columns emits
+    activations that must cross the mesh before the kernel.  Stored by
+    ROWS over ``model`` (``gpt2_partition_rules``), a device holds d/n
+    rows of every column; one all-to-all of the weights in the compute
+    dtype swaps those for all d rows of its own heads' columns (the
+    gradient goes back the same way), and the matmul is local.  The
+    weights are a fifth of the bytes of q, k, v and their gradients at
+    the mesh cell's batch, and do not grow with it."""
+    from jax.experimental.layout import Layout, with_layout_constraint
+    from jax.sharding import PartitionSpec as P
+
+    (axis,) = head_axes
+    n = mesh.shape[axis]
+    d = x.shape[-1]
+    bias = [p["bias"].astype(dtype).reshape(3, n, d // n)] if "bias" in p else []
+
+    def row_major(a):
+        # Left to itself the TPU compiler lays the exchanged blocks out
+        # rows-minor and transposes the float32 kernel, both AdamW
+        # moments and the gradient to match, 59 MB of copies a layer;
+        # pinned, what stands around an exchange is one pass over the
+        # 4.9 MB it moves.  The last two dims (rows and columns of a
+        # block) are the tiled ones.
+        return with_layout_constraint(a, Layout(major_to_minor=tuple(range(a.ndim))))
+
+    def local(x, w, *b):  # x [B/b, T, d], w [d/n, 3d], b [3, 1, d/n]
+        with jax.named_scope("gpt2.qkv_exchange"):
+            # [to device, q|k|v, my rows, its columns] -> [from device, q|k|v, its rows, my columns]
+            w = row_major(row_major(w).reshape(d // n, 3, n, d // n).transpose(2, 1, 0, 3))
+            w = row_major(jax.lax.all_to_all(w, axis, 0, 0, tiled=True))
+        y = jnp.einsum("btd,cdk->cbtk", x, w.transpose(1, 0, 2, 3).reshape(3, d, d // n))
+        return y + b[0][:, :, None] if b else y
+
+    # only the axes named here are manual: any other (ring attention's
+    # sequence axis over T) stays the partitioner's.  The kernel's spec
+    # is how gpt2_partition_rules stores it (held to each other in
+    # tests/test_sharding_rules.py); stored otherwise, it is resharded
+    # to this first
+    return jax.shard_map(
+        local, mesh=mesh, axis_names={axis, *(batch_axes or ())},
+        in_specs=(P(batch_axes, None, None), P(axis, None)) + (P(None, axis, None),) * len(bias),
+        out_specs=P(None, batch_axes, None, axis),
+    )(x, p["kernel"].astype(dtype), *bias)
 
 
 class MLP(nn.Module):

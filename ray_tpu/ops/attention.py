@@ -24,6 +24,7 @@ All paths: f32 accumulation, bf16 in/out, static shapes.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -192,27 +193,21 @@ def causal_attention(q, k, v, *, mesh=None, sp_axis: Optional[str] = None):
 _HEAD_AXIS = "model"
 
 
-def _flash_over_mesh(q, k, v):
-    """The flash kernel under the mesh this step is traced in.
-
-    A Mosaic kernel cannot be partitioned automatically: a multi-device
-    jit that reaches it bare fails to lower.  Attention is independent
-    across batch and heads, so under an ambient mesh
-    (``jax.set_mesh``, which the sharded train steps enter) the kernel
-    runs inside a shard_map, each device on its own shard of B and H
-    with T and D whole.  The axis names only decide which dim a mesh
-    axis splits; whatever layout the operands arrive in, the partitioner
-    reshards to the specs given here, so a wrong guess costs a transfer,
-    never a result.  Axes that do not divide their dim stay unsplit."""
-    from jax.sharding import PartitionSpec as P
-
-    from ray_tpu.ops.pallas_attention import flash_attention
-
+def mesh_split(B, H, skip=()):
+    """How the ambient mesh (``jax.set_mesh``, which the sharded train
+    steps enter) splits a batch of B and H heads: (mesh, batch axes,
+    head axes), each a tuple of axis names or None, or None outright
+    where the mesh has no free axis wider than one.  ``_HEAD_AXIS``
+    takes the heads and every other free axis the batch, but for those
+    in ``skip``; an axis that does not divide its dim stays unsplit, one
+    that a surrounding shard_map has made manual is not free.  One
+    answer for every shard_map of the attention block, so that what one
+    emits is what the next asks for."""
     mesh = jax.sharding.get_abstract_mesh()
-    free = [a for a in mesh.axis_names if a not in mesh.manual_axes and mesh.shape[a] > 1]
+    free = [a for a in mesh.axis_names
+            if a not in mesh.manual_axes and a not in skip and mesh.shape[a] > 1]
     if not free:
-        return flash_attention(q, k, v, causal=True)
-    B, T, H, D = q.shape
+        return None
 
     def dividing(axes, n):
         kept, size = [], 1
@@ -222,10 +217,40 @@ def _flash_over_mesh(q, k, v):
                 size *= mesh.shape[a]
         return tuple(kept) or None
 
-    spec = P(
-        dividing([a for a in free if a != _HEAD_AXIS], B), None,
-        dividing([a for a in free if a == _HEAD_AXIS], H), None,
-    )
+    return (mesh, dividing([a for a in free if a != _HEAD_AXIS], B),
+            dividing([a for a in free if a == _HEAD_AXIS], H))
+
+
+def _flash_over_mesh(q, k, v):
+    """The flash kernel under the mesh this step is traced in.
+
+    A Mosaic kernel cannot be partitioned automatically: a multi-device
+    jit that reaches it bare fails to lower.  Attention is independent
+    across batch and heads, so under an ambient mesh the kernel runs
+    inside a shard_map, each device on its own shard of B and H
+    (``mesh_split``) with T and D whole.  The axis names only decide
+    which dim a mesh axis splits; whatever layout the operands arrive
+    in, the partitioner reshards to the specs given here, so a wrong
+    guess costs a transfer, never a result."""
+    from jax.sharding import PartitionSpec as P
+
+    from ray_tpu.ops.pallas_attention import flash_attention
+
+    B, T, H, D = q.shape
+    split = mesh_split(B, H)
+    if split is None:
+        return flash_attention(q, k, v, causal=True)
+    mesh, batch_axes, head_axes = split
+    return _flash_shard_map(q, k, v, mesh=mesh, spec=P(batch_axes, None, head_axes, None))
+
+
+@functools.partial(jax.jit, static_argnames=("mesh", "spec"))
+def _flash_shard_map(q, k, v, *, mesh, spec):
+    # a jit of its own, so that the layers of a model trace and lower
+    # the kernels once and not once each (the step's set-up, not its
+    # program: the calls are inlined when it is compiled)
+    from ray_tpu.ops.pallas_attention import flash_attention
+
     return jax.shard_map(
         lambda q, k, v: flash_attention(q, k, v, causal=True),
         mesh=mesh, in_specs=(spec,) * 3, out_specs=spec, check_vma=False,

@@ -108,14 +108,24 @@ class ShardingConfig:
 
 def gpt2_partition_rules() -> List[Rule]:
     """Tested rule set for ``models/gpt2.py`` over a (batch, model) mesh:
-    Megatron pairing — qkv/mlp-up shard their OUTPUT dim over ``model``,
+    Megatron pairing — mlp-up shards its OUTPUT dim over ``model``,
     attn-out/mlp-down their INPUT dim, so activations cross the mesh
     only at block boundaries; embeddings shard the vocab dim; norms and
-    biases replicate."""
+    biases replicate.
+
+    The fused ``qkv`` kernel [d, 3d] is stored by ROWS.  Its columns run
+    q | k | v, so no block of them holds a head's q, k and v, and a
+    column sharding makes the projection's outputs and their gradients
+    cross ``model`` before the attention kernel.  ``models/gpt2.py``
+    instead exchanges the row shard for the device's own heads' columns
+    (one all-to-all of the weights in the compute dtype, the gradient
+    back the same way) and computes q, k and v where their heads are:
+    the weights are a fifth of those activations' bytes at 8 x 1024
+    tokens, and do not grow with the batch."""
     return [
         (r"wte/embedding", ("model", None)),
         (r"wpe/embedding", (None, None)),
-        (r"(qkv|c_attn)/kernel", (None, "model")),
+        (r"(qkv|c_attn)/kernel", ("model", None)),
         (r"(attn_out|c_proj)/kernel", ("model", None)),
         (r"(mlp_up|c_fc)/kernel", (None, "model")),
         (r"(mlp_down|fc_out)/kernel", ("model", None)),

@@ -11,7 +11,9 @@ described inside a fixture: only one process may load the TPU library,
 and the xdist worker that is given this file is the one that does.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -121,6 +123,75 @@ def test_flash_under_a_mesh_compiles_for_v5e(topo):
     assert text.count('custom_call_target="tpu_custom_call"') == 2
     # each device works on its own [4, 1024, 8, 64] shard: q/k/v never gather
     assert "all-gather" not in text
+
+
+def test_mesh_train_step_moves_weights_not_activations(topo):
+    """`gpt2-large.train.mesh2x2`'s step (the sharding plan's jit of
+    `make_train_step` under the default rules, 8 x 1024 tokens, mesh
+    {batch: 2, model: 2}) at the published widths, depth cut to two
+    layers: what crosses `model` beside Megatron's sums is the fused
+    projection's weights, once forward and once backward a layer.  q, k
+    and v leave the projection on their device's heads, so nothing of a
+    sequence's length is permuted, exchanged or gathered around the
+    flash kernel's shard_map."""
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from ray_tpu.models import gpt2
+    from ray_tpu.parallel.collectives import collectives, format_collectives
+    from ray_tpu.train.sharding import ShardingConfig
+    from ray_tpu.train.sharding.gspmd import GspmdPlan
+    from ray_tpu.train.sharding.rules import match_partition_rules
+
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("batch", "model"))
+    plan = GspmdPlan(ShardingConfig(mesh_shape={"batch": 2, "model": 2}), mesh)
+    cfg = dataclasses.replace(gpt2.GPT2Config.large(remat=False), n_layer=2)
+    opt = gpt2.make_adamw()
+    B, T, d = 8, 1024, cfg.d_model
+
+    def on_mesh(tree, specs):
+        return jax.tree_util.tree_map(
+            lambda s, x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=NamedSharding(mesh, s)),
+            specs, tree, is_leaf=lambda s: isinstance(s, P))
+
+    params = jax.eval_shape(lambda: gpt2.init_params(cfg))
+    params = on_mesh(params, plan.param_specs(params))
+    opt_state = jax.eval_shape(opt.init, params)
+    opt_state = on_mesh(opt_state, match_partition_rules(plan.config.rules(), opt_state, mesh, strict=False))
+    tokens = jax.ShapeDtypeStruct((B, T), jnp.int32, sharding=plan.data_sharding())
+    step = plan.jit_train_step(gpt2.make_train_step(cfg, opt), params, opt_state)
+    text = step.lower(params, opt_state, tokens, tokens).compile().as_text()
+    rows = collectives(text)
+    listing = format_collectives(rows)
+
+    def count(op, where=lambda r: True):
+        return sum(r.count for r in rows if r.op == op and where(r))
+
+    assert count("collective-permute") == 0, listing
+    # nothing T long is exchanged or gathered: the activations stay where they are
+    assert count("all-to-all", lambda r: T in r.dims()) == 0, listing
+    assert count("all-gather", lambda r: T in r.dims()) == 0, listing
+    # a device's half of the weights [d, 3d] in bf16: forward, and the gradient back
+    assert count("all-to-all") == 2 * cfg.n_layer, listing
+    assert all(r.shape.startswith("bf16[") and math.prod(r.dims()) == d * 3 * d // 2
+               for r in rows if r.op == "all-to-all"), listing
+    # Megatron's four sums a layer and the head's two, one of them in a tuple with the loss's
+    local = f"bf16[{B // 2},{T},{d}]"
+    assert count("all-reduce", lambda r: r.shape == local) == 4 * cfg.n_layer + 1, listing
+    assert count("all-reduce", lambda r: r.shape.startswith(local + "+")) == 1, listing
+    # `_qkv_by_head`'s layout pins hold: left to itself the compiler
+    # lays the exchanged blocks out rows-minor and transposes the
+    # float32 kernel, its moments and its gradient to match (six copies
+    # of a device's 9.8 MB a layer, 144+ MB of float32 copied a layer
+    # for the pinned form's 50 and the column-sharded step's 65)
+    def f32_copied(op):  # elements of each float32 result of `op`
+        return [math.prod(int(n) for n in dims.split(","))
+                for dims in re.findall(rf" = \(?f32\[([\d,]+)\][^=]*? {op}\(", text)]
+
+    assert d * 3 * d // 2 not in f32_copied("copy"), f32_copied("copy")
+    assert 4 * sum(f32_copied("copy(?:-start)?")) < 65e6 * cfg.n_layer
 
 
 def test_engine_decode_step_compiles_for_v5e(one_chip, monkeypatch):

@@ -43,7 +43,7 @@ def test_gpt2_sharded_train_step_dp_tp_sp():
     cfg = gpt2.GPT2Config.tiny(dtype=jnp.float32, mesh=plan.mesh, sp_axis="seq")
     opt = gpt2.make_adamw(lr=1e-2)
     params, opt_state = plan.shard_init(lambda rng: gpt2.init_params(cfg, rng), opt)
-    assert params["h_0"]["attn"]["qkv"]["kernel"].sharding.spec == (None, "model")
+    assert params["h_0"]["attn"]["qkv"]["kernel"].sharding.spec == ("model", None)
     step = plan.jit_train_step(gpt2.make_train_step(cfg, opt), params, opt_state)
     tokens, targets = _batch(cfg, B=4, T=64)
     text = step.lower(params, opt_state, tokens, targets).as_text()

@@ -63,10 +63,10 @@ def test_gspmd_mesh_shards_params_and_state():
     opt = gpt2.make_adamw(1e-3)
     params, opt_state = plan.shard_init(_init_fn(cfg), opt)
     qkv = params["h_0"]["attn"]["qkv"]["kernel"]
-    # the model axis really splits the leaf: each shard holds half
-    assert qkv.sharding.spec == jax.sharding.PartitionSpec(None, "model")
-    shard_cols = {s.data.shape[1] for s in qkv.addressable_shards}
-    assert shard_cols == {qkv.shape[1] // 2}
+    # the model axis really splits the leaf: each shard holds half the rows
+    assert qkv.sharding.spec == jax.sharding.PartitionSpec("model", None)
+    shard_rows = {s.data.shape[0] for s in qkv.addressable_shards}
+    assert shard_rows == {qkv.shape[0] // 2}
     # optimizer moments follow the SAME layout; scalars replicate
     flat = jax.tree_util.tree_leaves(opt_state)
     assert all(
@@ -74,6 +74,60 @@ def test_gspmd_mesh_shards_params_and_state():
         or l.sharding.is_fully_replicated
         for l in flat
     )
+
+
+@pytest.mark.parametrize("n_head", [20, 8])
+@pytest.mark.parametrize("mesh_shape", [(2, 2), (2, 4), (1, 8)])
+def test_qkv_exchange_matches_plain_projection(mesh_shape, n_head):
+    """Under a mesh whose `model` axis divides the heads, Attention
+    exchanges the fused projection's weights (one all-to-all over
+    `model`) and emits q, k, v by head: they, and the gradients with
+    respect to x, kernel and bias, are the plain Dense + split's.  An
+    axis that does not divide the heads (8 under 20) takes the plain
+    path."""
+    import flax.linen as nn
+    from jax.sharding import Mesh
+
+    from ray_tpu.ops.attention import mesh_split
+
+    if len(jax.devices()) < 8:
+        pytest.skip("needs 8 devices")
+    exchanged = n_head % mesh_shape[1] == 0
+    cfg = gpt2.GPT2Config(
+        vocab_size=256, n_layer=1, n_head=n_head, d_model=8 * n_head, max_seq_len=16,
+        dtype=jnp.float32, remat=False,
+    )
+    attn = gpt2.Attention(cfg)
+    x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model))
+    params = attn.init(jax.random.PRNGKey(0), x)["params"]
+    params["qkv"]["bias"] = jax.random.normal(jax.random.PRNGKey(2), (3 * cfg.d_model,))
+    # a weight for every element of q, k and v, so that each has a gradient of its own
+    probe = jax.random.normal(jax.random.PRNGKey(3), (3, *x.shape))
+
+    def plain(p, x):
+        return jnp.stack(jnp.split(nn.Dense(3 * cfg.d_model).apply({"params": p}, x), 3, axis=-1))
+
+    def by_head(p, x):
+        return jnp.stack(gpt2._qkv_by_head(x, p, cfg.dtype, *mesh_split(x.shape[0], n_head)))
+
+    def with_grads(fn):
+        def scalar(p, x):
+            out = fn(p, x)
+            return (out * probe).sum(), out
+
+        return jax.jit(jax.value_and_grad(scalar, argnums=(0, 1), has_aux=True))
+
+    want = with_grads(plain)(params["qkv"], x)
+    want_out = attn.apply({"params": params}, x)
+    devices = np.array(jax.devices()[:mesh_shape[0] * mesh_shape[1]]).reshape(mesh_shape)
+    with jax.set_mesh(Mesh(devices, ("batch", "model"))):
+        module = jax.jit(lambda p, x: attn.apply({"params": p}, x)).lower(params, x)
+        assert ("all_to_all" in module.as_text()) == exchanged
+        np.testing.assert_allclose(module.compile()(params, x), want_out, atol=1e-5)
+        if exchanged:
+            got = with_grads(by_head)(params["qkv"], x)
+            for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+                np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize(

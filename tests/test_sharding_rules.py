@@ -103,7 +103,8 @@ def test_gpt2_rule_set_covers_and_shards_gpt2_tiny(preset, on_mesh):
     assert spec["wpe"]["embedding"] == P(None, None)
     for i in range(cfg.n_layer):
         blk = spec[f"h_{i}"]
-        assert blk["attn"]["qkv"]["kernel"] == P(None, "model")
+        # by rows: models/gpt2.py exchanges the weights, not q, k and v
+        assert blk["attn"]["qkv"]["kernel"] == P("model", None)
         assert blk["attn"]["attn_out"]["kernel"] == P("model", None)
         assert blk["mlp"]["mlp_up"]["kernel"] == P(None, "model")
         assert blk["mlp"]["mlp_down"]["kernel"] == P("model", None)
@@ -112,6 +113,42 @@ def test_gpt2_rule_set_covers_and_shards_gpt2_tiny(preset, on_mesh):
         assert all(a is None for a in blk["attn"]["qkv"]["bias"])
     assert spec["lm_head"]["kernel"] == P(None, "model")
     assert all(a is None for a in spec["ln_f"]["bias"])
+
+
+def test_qkv_rule_stores_the_kernel_as_the_exchange_takes_it():
+    """The layout of the fused kernel is decided twice: by the rule that
+    stores it and by the `in_specs` of the shard_map that exchanges it
+    (`models/gpt2.py:_qkv_by_head`).  Where the two part, the result is
+    still right and the partitioner reshards the weights every step; so
+    they are held to each other here."""
+    from ray_tpu.models import gpt2
+    from ray_tpu.ops.attention import mesh_split
+
+    if len(jax.devices()) < 8:
+        pytest.skip("needs 8 devices")
+    cfg = gpt2.GPT2Config.tiny(remat=False)
+    qkv = jax.eval_shape(lambda: gpt2.init_params(cfg))["h_0"]["attn"]["qkv"]
+    mesh = Mesh(np.array(jax.devices()[:8]).reshape(4, 2), ("batch", "model"))
+    stored = match_partition_rules(gpt2_partition_rules(), {"qkv": qkv}, mesh)["qkv"]
+    x = jax.ShapeDtypeStruct((4, 16, cfg.d_model), cfg.dtype)
+
+    def shard_maps(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "shard_map":
+                yield eqn
+            for v in eqn.params.values():
+                if hasattr(v, "jaxpr"):
+                    yield from shard_maps(v.jaxpr)
+
+    with jax.set_mesh(mesh):
+        traced = jax.make_jaxpr(
+            lambda p, x: gpt2._qkv_by_head(x, p, cfg.dtype, *mesh_split(x.shape[0], cfg.n_head))
+        )(qkv, x)
+    (eqn,) = shard_maps(traced.jaxpr)
+    _x, kernel, bias = eqn.params["in_specs"]
+    assert kernel == stored["kernel"] == P("model", None)
+    # the bias is stored whole and handed in as [3, n, d/n], a device's own columns
+    assert all(a is None for a in stored["bias"]) and bias == P(None, "model", None)
 
 
 def test_sharding_config_validation_and_defaults():
