@@ -17,7 +17,9 @@ gather of the lane's pages and the same reference; over CHOSEN blocks of
 the pool with grouped queries, the sibling kernel
 (ops.pallas_sparse_paged_attention) or a gather of the chosen pages;
 over a pool of latent rows that all heads share, keys and values both,
-a third (ops.pallas_mla_paged_attention) or a gather of the lane's rows.
+a third (ops.pallas_mla_paged_attention) or a gather of the lane's rows;
+over the whole of a lane's pages with grouped queries, a fourth
+(ops.pallas_gqa_paged_attention) or a gather of them.
 
 All paths: f32 accumulation, bf16 in/out, static shapes.
 """
@@ -171,6 +173,40 @@ def mla_paged_decode_attention(q, row_self, pages, layer, block_tables, lengths,
     probs = jax.nn.softmax(jnp.concatenate([s_ctx, s_self], axis=-1), axis=-1).astype(q.dtype)
     att = jnp.einsum("bhc,bcv->bhv", probs[..., :-1], ctx[..., :v_width])
     return att + probs[..., -1:] * row_self[:, None, :v_width]
+
+
+def gqa_paged_decode_attention(q, k_self, v_self, k_pages, v_pages, layer, block_tables, lengths, *,
+                               block_size):
+    """One fed token a lane over the pages it holds of a paged KV pool,
+    layer ``layer``, and itself; grouped queries, every cached position
+    attended.
+
+    q [B, G, R, Dh]: R query heads to each of the G K/V heads; k_self,
+    v_self [B, G, Dh]; k_pages, v_pages [L, num_blocks * block_size,
+    G * Dh]; block_tables [B, pages] int32, scratch block 0 where a lane
+    holds none; lengths [B] int32 the cached positions of a lane (0: it
+    attends to itself alone).  Returns [B, G, R, Dh].
+
+    On a TPU, where the shapes fit its tiling, the Pallas kernel reads
+    the pages where they lie, a page once for its group's R heads
+    (ops.pallas_gqa_paged_attention).  Elsewhere the lane's pages are
+    gathered to a contiguous context first."""
+    B, G, R, Dh = q.shape
+    if jax.default_backend() == "tpu":  # as paged_decode_attention: the CPU tests gather
+        from ray_tpu.ops import pallas_gqa_paged_attention as kernel
+
+        if kernel.kernel_takes(R, Dh, block_size, k_pages.dtype):
+            return kernel.gqa_paged_decode_attention_kernel(
+                q, k_self, v_self, k_pages, v_pages, layer, block_tables, lengths, block_size=block_size)
+    C = block_tables.shape[1] * block_size
+    idx = (block_tables[:, :, None] * block_size + jnp.arange(block_size)).reshape(B, C)
+    # every K/V head repeated for its R query heads: the multi-head reference
+    k_ctx = jnp.repeat(k_pages[layer][idx].reshape(B, C, G, Dh), R, axis=2)
+    v_ctx = jnp.repeat(v_pages[layer][idx].reshape(B, C, G, Dh), R, axis=2)
+    mask = jnp.arange(C)[None, :] < lengths[:, None]
+    out = reference_decode_attention(q.reshape(B, G * R, Dh), jnp.repeat(k_self, R, axis=1),
+                                     jnp.repeat(v_self, R, axis=1), k_ctx, v_ctx, mask)
+    return out.reshape(B, G, R, Dh)
 
 
 def causal_attention(q, k, v, *, mesh=None, sp_axis: Optional[str] = None):

@@ -4,10 +4,11 @@ A token goes to ``k`` of ``E`` experts.  ``moe_experts`` sorts the
 ``tokens * k`` (token, expert) pairs by expert, gathers the tokens' rows
 in that order, multiplies each group of rows by its expert's weights in
 ONE grouped matmul over the sorted rows (gate and up side by side, then
-down), scales each row by the token's weight for that expert, and sums
-a token's ``k`` rows back.  Every pair is computed: there is no
-capacity, no token is dropped, and nothing of shape ``[tokens, experts,
-...]`` is built (``models/moe.py``'s one-hot dispatch does both).
+down; for experts without a gate, up then down), scales each row by the
+token's weight for that expert, and sums a token's ``k`` rows back.
+Every pair is computed: there is no capacity, no token is dropped, and
+nothing of shape ``[tokens, experts, ...]`` is built (``models/moe.py``'s
+one-hot dispatch does both).
 
 On a TPU the grouped matmul is the megablox Pallas kernel
 (``jax.experimental.pallas.ops.tpu.megablox``): it visits only the
@@ -65,17 +66,18 @@ def _tile_rows(m: int) -> int:
     return tm
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "tiling"))
-def moe_gmm(rows, weights, group_sizes, *, interpret=False, tiling=None):
+@functools.partial(jax.jit, static_argnames=("interpret", "tiling", "transposed"))
+def moe_gmm(rows, weights, group_sizes, *, interpret=False, tiling=None, transposed=False):
     """rows [m, k] sorted by group, weights [groups, k, n], group_sizes
     [groups] int32 summing to m -> [m, n] in rows' dtype: each group's
-    rows times its own weights, float32 accumulation.  The megablox
-    kernel under this function's name; `tiling` (tm, tk, tn) is for
-    tests and tuning."""
+    rows times its own weights, float32 accumulation.  `transposed`: the
+    weights are [groups, n, k], a group's matrix the other way round.
+    The megablox kernel under this function's name; `tiling` (tm, tk,
+    tn) is for tests and tuning."""
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
     m, k = rows.shape
-    n = weights.shape[2]
+    n = weights.shape[1 if transposed else 2]
     tm, tk, tn = tiling or (_tile_rows(m), min(_TILE_K, k), min(_TILE_N, n))
     pad = -m % tm  # the kernel takes whole row tiles; the pad belongs to no group
     if pad:
@@ -84,20 +86,22 @@ def moe_gmm(rows, weights, group_sizes, *, interpret=False, tiling=None):
     # innermost jit around it, which is this one
     out = gmm.__wrapped__(
         rows, weights, group_sizes, preferred_element_type=rows.dtype,
-        tiling=(tm, tk, tn), interpret=interpret,
+        tiling=(tm, tk, tn), interpret=interpret, transpose_rhs=transposed,
     )
     return out[:m] if pad else out
 
 
-def grouped_matmul(rows, weights, group_sizes):
+def grouped_matmul(rows, weights, group_sizes, transposed=False):
     """As ``moe_gmm``, by the backend: the Pallas kernel on a TPU,
     ``jax.lax.ragged_dot`` elsewhere."""
     if jax.default_backend() == "tpu":
-        return moe_gmm(rows, weights, group_sizes)
+        return moe_gmm(rows, weights, group_sizes, transposed=transposed)
+    if transposed:
+        weights = jnp.swapaxes(weights, 1, 2)
     return jax.lax.ragged_dot(rows, weights, group_sizes).astype(rows.dtype)
 
 
-def moe_experts(h, top_p, top_e, wgu, wd, held=None):
+def moe_experts(h, top_p, top_e, wgu, wd, held=None, gated=True):
     """The expert layer of a token batch.
 
     h [T, d] the tokens; top_p [T, k] float32 and top_e [T, k] int32 a
@@ -105,7 +109,12 @@ def moe_experts(h, top_p, top_e, wgu, wd, held=None):
     and up projections side by side; wd [E, f, d] its down projection.
     `held` (first, count), static: wgu and wd are the weights of experts
     ``first .. first + count - 1`` alone, of the more that top_e ranges
-    over; None: of all of them.
+    over; None: of all of them.  `gated` False, static: the experts have
+    no gate and an expert is ``relu(h Wu)^2 Wd``; wgu is then the up
+    projection alone and TRANSPOSED, [E, f, d] as wd is: an expert width
+    that is not whole lane tiles of 128 (1,856) as the minor dim makes
+    the chip lay the tensor out with d minor, and the kernel, which
+    takes row-major operands, is then handed a copy of it every call.
     Returns (y [T, d] in h's dtype: ``sum_k p * (silu(h Wg) * (h Wu)) Wd``
     over those of a token's k experts that are held; counters int32 [3]:
     the pairs computed (rows of the second grouped matmul's output that
@@ -129,8 +138,12 @@ def moe_experts(h, top_p, top_e, wgu, wd, held=None):
             group_sizes = group_sizes[:E]  # less the absent pairs' own count
         rows = h[order // k]
     with jax.named_scope("moe.experts"):
-        gate, up = jnp.split(grouped_matmul(rows, wgu, group_sizes), 2, axis=-1)
-        out = grouped_matmul(jax.nn.silu(gate) * up, wd, group_sizes)
+        if gated:
+            gate, up = jnp.split(grouped_matmul(rows, wgu, group_sizes), 2, axis=-1)
+            mid = jax.nn.silu(gate) * up
+        else:
+            mid = jnp.square(jax.nn.relu(grouped_matmul(rows, wgu, group_sizes, transposed=True)))
+        out = grouped_matmul(mid, wd, group_sizes)
         if held is not None:
             # rows behind the groups were never written: whatever lies there is not a result
             out = jnp.where((jnp.arange(T * k) < group_sizes.sum())[:, None], out, 0)

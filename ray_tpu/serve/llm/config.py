@@ -19,6 +19,8 @@ MODEL_FAMILIES = (
      ("minicpm_sala_tiny", "minicpm_sala", "minicpm_sala_16l")),
     ("ray_tpu.models.mistral4", "Mistral4Config",
      ("mistral_small_4_tiny", "mistral_small_4", "mistral_small_4_6l_ep4")),
+    ("ray_tpu.models.nemotron_h", "NemotronHConfig",
+     ("nemotron_3_nano_tiny", "nemotron_3_nano", "nemotron_3_nano_26l_ep4")),
 )
 # every preset ``LLMConfig.model`` may name, family by family
 PRESETS = " | ".join(name for *_, presets in MODEL_FAMILIES for name in presets)

@@ -400,3 +400,68 @@ def test_mistral_small_4_programs_compile_for_v5e(one_chip, monkeypatch):
     # the scores of 4,096 queries x 32 heads over 36,864 keys would be 19 GB in float32
     assert mem.temp_size_in_bytes < 1 * 2**30
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 * 2**30
+
+
+def test_nemotron_h_programs_compile_for_v5e(one_chip, monkeypatch):
+    """Nemotron-H's two programs at the published widths (d 2688; Mamba-2
+    64 heads of 64, state 128, 8 groups, convolution 4; 32 query heads of
+    128 over 2 K/V heads; 32 held experts of width 1,856 of 128 routed, a
+    shared one of 3,712), 128 lanes over 6,144 positions in pages of 64,
+    depth cut to one layer of each kind (M, E, *): the decode step updates
+    the lanes' states through the Mamba-2 kernel INTO the donated array
+    (no copy of a state array, of a tail or of a pool anywhere in the
+    program), reads the K/V pages through the grouped-query kernel and
+    runs two grouped matmuls; a 2,048-token chunk compiles with its
+    chunked scan and its loop over key blocks and fits beside the cache."""
+    from ray_tpu.models import nemotron_h as nh
+    from ray_tpu.serve.llm.engine import decode_step, prefill_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = nh.NemotronHConfig.nemotron_3_nano_26l_ep4(pattern="ME*")
+    B, C, block, T, slots = 128, 6144, 64, 2048, 393216 + 64
+    spec = nh.cache_spec(cfg, block)
+    assert spec.names == ("k_pages", "v_pages", "conv_tail_0", "ssm_state_0")
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    def shaped(tree):
+        return jax.tree_util.tree_map(lambda x: arr(x.shape, x.dtype), tree)
+
+    params = shaped(jax.eval_shape(lambda: nh.init_params(cfg)))
+    key = shaped(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    pool = arr((spec.paged_layers, slots, spec.row_width), cfg.dtype)
+    cache = [pool, pool] + [arr((B, *shape), dtype) for _, shape, dtype in spec.lane_state]
+    held = tuple(range(1, 1 + len(cache)))
+    state_bytes = B * 64 * 64 * 128 * 4
+    held_arrays = ("f32[128,64,64,128]", "bf16[128,18432]", f"bf16[1,{slots},256]")
+
+    def copies(text):
+        return [ln.strip()[:120] for ln in text.splitlines()
+                if " copy(" in ln and any(a in ln.split(" copy(")[0] for a in held_arrays)]
+
+    decode = jax.jit(lambda *a: decode_step(cfg, 0, block, spec, *a), donate_argnums=held).lower(
+        params, *cache, arr((B,), jnp.int32), arr((B,), jnp.int32), arr((B, C // block), jnp.int32),
+        arr((B,), jnp.int32), arr((B,), jnp.float32), key).compile()
+    text = decode.as_text()
+    calls = [ln.split(" = ")[0].split("%")[-1] for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert sum(c.startswith("mamba2_decode_step") for c in calls) == 1
+    assert sum(c.startswith("gqa_paged_decode_attention") for c in calls) == 1
+    assert sum(c.startswith("moe_gmm") for c in calls) == 2 and len(calls) == 4
+    assert copies(text) == []
+    mem = decode.memory_analysis()
+    # every held array goes out in the buffer it came in: both pools, the tail, the state
+    assert mem.alias_size_in_bytes >= state_bytes + 2 * slots * 256 * 2 + B * 18432 * 2
+    assert mem.temp_size_in_bytes < state_bytes  # no second array of states among the temporaries
+    assert f"s32[{B + len(nh.COUNTERS)}]" in text
+
+    chunk = jax.jit(lambda *a: prefill_step(cfg, 0, block, spec, *a), donate_argnums=held).lower(
+        params, *cache, arr((1, T), jnp.int32), arr((T,), jnp.int32), arr((1,), jnp.int32),
+        arr((1,), jnp.float32), key, arr((), jnp.int32), arr((C // block,), jnp.int32),
+        arr((), jnp.int32)).compile()
+    assert copies(chunk.as_text()) == []
+    mem = chunk.memory_analysis()
+    # the scores of 2,048 queries x 32 heads over 8,192 keys would be 2.1 GB in float32
+    assert mem.temp_size_in_bytes < 1 * 2**30
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 * 2**30

@@ -21,7 +21,7 @@ if REPO not in sys.path:
 
 pytest.register_assert_rewrite(
     "benchmark.tests.test_benchmark", "benchmark.tests.test_olmoe_cell",
-    "benchmark.tests.test_mistral_small_4_cell",
+    "benchmark.tests.test_mistral_small_4_cell", "benchmark.tests.test_nemotron_3_nano_cell",
 )
 
 from benchmark.tests.test_benchmark import (  # noqa: E402,F401
@@ -48,4 +48,13 @@ from benchmark.tests.test_mistral_small_4_cell import (  # noqa: E402,F401
 )
 from benchmark.tests.test_mistral_small_4_cell import (  # noqa: E402,F401
     test_runner_fails_at_once_where_the_program_has_no_such_family as test_mistral_runner_fails_at_once_where_the_program_has_no_such_family,
+)
+from benchmark.tests.test_nemotron_3_nano_cell import (  # noqa: E402,F401
+    test_decode_kernels_and_held_experts_work_and_roofline_shares_by_hand,
+    test_the_stated_cache_is_three_paged_layers_and_two_arrays_a_mamba_layer,
+)
+from benchmark.tests.test_nemotron_3_nano_cell import (  # noqa: E402,F401
+    test_runner_fails_at_once_where_the_program_has_no_such_family as test_nemotron_runner_fails_at_once_where_the_program_has_no_such_family,
+    test_the_cell_s_metrics_are_the_entries_of_benchmark_json as test_the_nemotron_cell_s_metrics_are_the_entries_of_benchmark_json,
+    test_the_configuration_is_the_catalog_s_but_for_what_it_says_is_reduced as test_the_nemotron_configuration_is_the_catalog_s_but_for_what_it_says_is_reduced,
 )
