@@ -52,7 +52,19 @@ import jax.numpy as jnp
 # / 2.57 at 128 / 256 / 512; 16,384 rows 3.55 / 2.79 / 3.67.
 _TILE_ROWS = (64, 256)
 # k and n extents of a weight tile: 4 MB of bf16, double-buffered (1.11
-# against 1.14 ms for 2 MB tiles at 256 rows, 1.47 against 1.59 at 4,096)
+# against 1.14 ms for 2 MB tiles at 256 rows, 1.47 against 1.59 at 4,096;
+# PR 26).  They are CEILINGS, and a tile never hangs over an extent's
+# end (`gmm_tiling`, PR 40).  Where tk does not divide k the kernel masks
+# its last k step: a float32 round trip, an iota and a select over the
+# WHOLE loaded tile of both operands, to zero columns past k.  At
+# Nemotron-H's widths (k 2,688 up, n 2,688 down; 32 experts, ms a call
+# on a v5e chip, `scripts/gmm_tile_check.py`, PR 40) the up matmul under
+# (2048, 1024), k in 2,048 + 640 masked: 0.594 at 768 rows, 0.974 at
+# 6,144, 1.127 at 12,288; under (896, 1856), three whole k steps and n
+# whole: 0.455, 0.598, 0.692.  An n tile that hangs over costs no mask,
+# only steps: the down matmul in 1,024 + 1,024 + 640 against three of
+# 896: 0.432 / 0.430, 0.635 / 0.567, 0.741 / 0.667.  Tiles under 1 MB
+# lose what the mask does (896 x 512: 0.551 at 768 rows).
 _TILE_K, _TILE_N = 2048, 1024
 
 
@@ -64,6 +76,24 @@ def _tile_rows(m: int) -> int:
     while tm < hi and tm * 8 < m:
         tm *= 2
     return tm
+
+
+def gmm_tiling(m: int, k: int, n: int) -> tuple[int, int, int]:
+    """(tm, tk, tn) of the kernel for rows [m, k] and weights [., k, n],
+    from the shapes alone.  tk divides k, so that no k step is masked:
+    k whole up to _TILE_K, else the largest multiple of 128 that divides
+    k from _TILE_K down to a quarter of it (none: _TILE_K, and the mask).
+    tn is _TILE_N where n is whole tiles of it or fewer than one; else n
+    whole where tk x n is no more than _TILE_K x _TILE_N, else n in as many
+    tiles as _TILE_N would make of it, all alike (whole lanes of 128)."""
+    tk = min(_TILE_K, k)
+    if k % tk:
+        tk = next((t for t in range(_TILE_K, _TILE_K // 4 - 1, -128) if k % t == 0), tk)
+    tn = min(_TILE_N, n)
+    if n % tn:
+        tiles = -(-n // _TILE_N)
+        tn = n if tk * n <= _TILE_K * _TILE_N else -(-n // (tiles * 128)) * 128
+    return _tile_rows(m), tk, tn
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "tiling", "transposed"))
@@ -78,7 +108,7 @@ def moe_gmm(rows, weights, group_sizes, *, interpret=False, tiling=None, transpo
 
     m, k = rows.shape
     n = weights.shape[1 if transposed else 2]
-    tm, tk, tn = tiling or (_tile_rows(m), min(_TILE_K, k), min(_TILE_N, n))
+    tm, tk, tn = tiling or gmm_tiling(m, k, n)
     pad = -m % tm  # the kernel takes whole row tiles; the pad belongs to no group
     if pad:
         rows = jnp.pad(rows, ((0, pad), (0, 0)))

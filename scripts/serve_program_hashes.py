@@ -1,5 +1,5 @@
 """Whether a change left the serve programs of the families that exist
-what they were (PERF.md section 6, PR 33 and PR 38).
+what they were (PERF.md section 6, PR 33, PR 38 and PR 40).
 
     JAX_PLATFORMS=cpu PYTHONPATH=<checkout> python scripts/serve_program_hashes.py out.json
 
@@ -32,6 +32,7 @@ CELLS = {  # preset: lanes, block, pool tokens, max context, prefill tokens
     "olmoe_1b_7b_12l": (32, 16, 32768, 4096, 256),
     "minicpm_sala_16l": (16, 16, 540672, 33792, 4096),
     "mistral_small_4_6l_ep4": (48, 64, 786432, 36864, 4096),
+    "nemotron_3_nano_26l_ep4": (128, 64, 393216, 6144, 2048),
 }
 
 def strip_payloads(text):

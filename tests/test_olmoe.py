@@ -260,6 +260,75 @@ def test_grouped_matmul_kernel_in_interpret_mode_matches_the_plain_path():
     assert [moe._tile_rows(m) for m in (64, 256, 1024, 4096, 16384)] == [64, 64, 128, 256, 256]
 
 
+# every (rows, k, n, transposed) the three served presets hand the grouped
+# matmul in their cells: a decode step's rows (lanes x experts a token) and
+# a prompt chunk's (bucket x experts a token), gate-and-up (or up) then down.
+# OLMoE's and Mistral-Small-4's tilings are today's, literally: PR 40 may
+# not move their programs.  None: only the properties below are held.
+_SERVED_GMM = {
+    "olmoe.decode.up": (256, 2048, 2048, False, (64, 2048, 1024)),
+    "olmoe.decode.down": (256, 1024, 2048, False, (64, 1024, 1024)),
+    "olmoe.chunk256.up": (2048, 2048, 2048, False, (256, 2048, 1024)),
+    "olmoe.chunk256.down": (2048, 1024, 2048, False, (256, 1024, 1024)),
+    "olmoe.chunk2048.up": (16384, 2048, 2048, False, (256, 2048, 1024)),
+    "olmoe.chunk2048.down": (16384, 1024, 2048, False, (256, 1024, 1024)),
+    "mistral.decode.up": (192, 4096, 4096, False, (64, 2048, 1024)),
+    "mistral.decode.down": (192, 2048, 4096, False, (64, 2048, 1024)),
+    "mistral.chunk4096.up": (16384, 4096, 4096, False, (256, 2048, 1024)),
+    "mistral.chunk4096.down": (16384, 2048, 4096, False, (256, 2048, 1024)),
+    "nemotron.decode.up": (768, 2688, 1856, True, None),
+    "nemotron.decode.down": (768, 1856, 2688, False, None),
+    "nemotron.chunk1024.up": (6144, 2688, 1856, True, None),
+    "nemotron.chunk1024.down": (6144, 1856, 2688, False, None),
+    "nemotron.chunk2048.up": (12288, 2688, 1856, True, None),
+    "nemotron.chunk2048.down": (12288, 1856, 2688, False, None),
+}
+
+
+@pytest.mark.parametrize("case", list(_SERVED_GMM))
+def test_grouped_matmul_tiles_divide_the_contraction(case):
+    """No k step of the kernel is a remainder (megablox masks one with a
+    float32 round trip over the whole weight tile), a weight tile is at
+    most 4 MB of bf16, and its lane extent is whole lane tiles of 128 or
+    the whole extent, as a Pallas block must be."""
+    m, k, n, _, today = _SERVED_GMM[case]  # either way round, a weight tile is tk x tn
+    tm, tk, tn = moe.gmm_tiling(m, k, n)
+    if today is not None:
+        assert (tm, tk, tn) == today
+    assert k % tk == 0
+    assert tk * tn * 2 <= 4 * 2**20
+    assert tm == moe._tile_rows(m)
+    assert (tk % 128 == 0 or tk == k) and (tn % 128 == 0 or tn == n)
+    assert -(-n // tn) <= -(-n // 1024)  # no more n tiles than 1,024 made
+
+
+@pytest.mark.parametrize("k, n, transposed, chosen", [
+    (2688, 200, False, (64, 896, 200)),
+    (2688, 1100, False, (64, 896, 1100)),  # the up projection: n whole beyond a tile of 1,024
+    (2688, 1100, True, (64, 896, 1100)),  # and held transposed, as Nemotron-H's is
+    (896, 2688, False, (64, 896, 896)),  # the down projection: n in three tiles alike
+])
+def test_grouped_matmul_kernel_through_the_chosen_tiling_at_widths_the_tiles_do_not_divide(
+        k, n, transposed, chosen):
+    """Nemotron-H's arithmetic at a cut size, through the tiling the
+    function chooses and no override: k = 3 x 128 x 7 over a ceiling of
+    2,048 (three k steps of 896, none masked), n not whole lanes of 128
+    or not whole tiles of 1,024, an empty group, and rows behind the
+    groups that belong to none (a share's absent pairs)."""
+    rng = np.random.default_rng(40)
+    sizes = np.array([5, 0, 19, 1, 7], np.int32)
+    m = int(sizes.sum()) + 9  # 9 rows of no group
+    assert moe.gmm_tiling(m, k, n) == chosen
+    rows = jnp.asarray(rng.standard_normal((m, k)), jnp.float32)
+    w = jnp.asarray(0.05 * rng.standard_normal((len(sizes), k, n)), jnp.float32)
+    want = jax.lax.ragged_dot(rows, w, jnp.asarray(sizes))[: sizes.sum()]
+    if transposed:
+        w = jnp.swapaxes(w, 1, 2)
+    got = moe.moe_gmm(rows, w, jnp.asarray(sizes), interpret=True, transposed=transposed)
+    assert got.shape == (m, n)
+    assert _distance(got[: sizes.sum()], want) < 1e-3
+
+
 # ----------------------------------------------------------------------
 # through LLMEngine
 # ----------------------------------------------------------------------
