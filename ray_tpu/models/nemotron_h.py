@@ -327,15 +327,16 @@ def _qkv(y, lp, cfg):
     return q.reshape(-1, G, H // G, hd), k.reshape(-1, G, hd), v.reshape(-1, G, hd)
 
 
-def chunk_attention(q, ctx_k, ctx_v, start, n_valid):
+def chunk_attention(q, ctx_k, ctx_v, start, n_valid, scale=None):
     """The prefill path: queries [T, G, R, hd] of the positions ``start
     ..`` over the cached rows ``ctx_k``, ``ctx_v`` [C, G, hd] (position
     p in row p; whole key blocks), a block of keys at a time inside an
     online softmax.  A block of keys past a query block's last position,
-    or past the last real position, is not visited.  -> [T, G * R * hd]."""
+    or past the last real position, is not visited.  `scale` multiplies
+    the scores (None: ``hd ** -0.5``).  -> [T, G * R * hd]."""
     T, G, R, hd = q.shape
     tq = min(T, _Q_BLOCK)
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
     outs = []
     for first in range(0, T, tq):
         qb = q[first:first + tq]
@@ -367,6 +368,66 @@ def chunk_attention(q, ctx_k, ctx_v, start, n_valid):
     return jnp.concatenate(outs) if len(outs) > 1 else outs[0]
 
 
+def _mamba_chunk(y, lp, cfg, cache, i, lane, start, n_valid):
+    """Mamba layer i on a chunk's normed tokens y [T, d], from lane
+    ``lane``'s tail and state (zeros where ``start`` is 0) -> (out [T,
+    d], {the tail's name, the state's name: as they stand after the last
+    real position})."""
+    z, xbc, dt = _mamba_in(y, lp, cfg)
+    with jax.named_scope("mamba.conv"):
+        tail = jnp.where(start == 0, 0, cache[_tail_name(i)][lane])
+        xbc, tail = mamba2.conv_tail(xbc, tail, lp["conv_w"], lp["conv_b"], n_valid)
+    with jax.named_scope("mamba.scan"):
+        xs, B, Cm = _mamba_split(xbc, cfg)
+        held = jnp.where(start == 0, 0.0, cache[_state_name(i)][lane])
+        o, held = mamba2.ssd_chunk(xs, dt, -jnp.exp(lp["A_log"]), B, Cm, lp["D"], held, n_valid, cfg.chunk_size)
+    return _mamba_out(o, z, lp, cfg), {_tail_name(i): tail, _state_name(i): held}
+
+
+def _mamba_decode(y, lp, cfg, cache, i, runs):
+    """Mamba layer i on one normed token a lane y [B, d]: the running
+    lanes' states updated where they lie, every tail shifted -> (out [B,
+    d], {the tail's name, the state's name: the whole new arrays})."""
+    z, xbc, dt = _mamba_in(y, lp, cfg)
+    with jax.named_scope("mamba.conv"):
+        xbc, tail = mamba2.conv_tail(xbc[:, None], cache[_tail_name(i)], lp["conv_w"], lp["conv_b"])
+    with jax.named_scope("mamba.step"):
+        xs, Bm, Cm = _mamba_split(xbc[:, 0], cfg)
+        o, state = mamba2.ssm_decode_step(
+            xs, dt, -jnp.exp(lp["A_log"]), Bm, Cm, lp["D"], cache[_state_name(i)], runs)
+    return _mamba_out(o, z, lp, cfg), {_tail_name(i): tail, _state_name(i): state}
+
+
+def _attention_chunk(y, lp, cfg, cache, i, where, room, start, n_valid, scale=None):
+    """Attention layer i on a chunk's normed tokens y [T, d] over the
+    sequence's cached rows (``where`` [C]: its positions' slots by page)
+    and the chunk's own, with ``room`` rows of zeros behind them so that
+    the chunk fits wherever it starts -> (out [T, d], k, v [T, G, hd])."""
+    G, hd = cfg.n_kv_head, cfg.head_dim
+    with jax.named_scope("attn.gqa"):
+        q, k, v = _qkv(y, lp, cfg)
+
+        def context(pages, rows):
+            ctx = jnp.concatenate([_rows(pages, i, where).reshape(-1, G, hd),
+                                   jnp.zeros((room, G, hd), pages.dtype)])
+            return jax.lax.dynamic_update_slice_in_dim(ctx, rows, start, axis=0)
+
+        att = chunk_attention(q, context(cache["k_pages"], k), context(cache["v_pages"], v), start, n_valid, scale)
+        return att @ lp["wo"], k, v
+
+
+def _attention_decode(y, lp, cfg, cache, i, block_tables, lengths, block_size, scale=None):
+    """Attention layer i on one normed token a lane y [B, d] over the
+    lanes' pages where they lie -> (out [B, d], k, v [B, G, hd])."""
+    from ray_tpu.ops.attention import gqa_paged_decode_attention
+
+    with jax.named_scope("attn.gqa"):
+        q, k, v = _qkv(y, lp, cfg)
+        o = gqa_paged_decode_attention(q, k, v, cache["k_pages"], cache["v_pages"], i, block_tables, lengths,
+                                       block_size=block_size, scale=scale)
+        return o.reshape(y.shape[0], -1) @ lp["wo"], k, v
+
+
 def _experts(y, lp, cfg):
     """The expert part on normed tokens y [T, d]: what to add to the
     stream (the shared expert and the held routed experts' part), the
@@ -392,9 +453,10 @@ def _experts(y, lp, cfg):
 
 def _counters(cfg, per_layer, attended=0, gathered=0, lane_steps=0, chunk_tokens=0):
     """COUNTERS of one program from its expert layers' [routed, held,
-    computed, hit, peak] and what its other layers read."""
+    computed, hit, peak] (one entry an expert layer) and what its other
+    layers read."""
     routed, held, computed, hit, peak = jnp.stack(per_layer).sum(0).astype(jnp.int32)
-    n_e = cfg.pattern.count(EXPERTS)
+    n_e = len(per_layer)
     return jnp.stack([routed, held, computed, hit, jnp.int32(cfg.experts_held * n_e), peak, jnp.int32(n_e),
                       *(jnp.asarray(v, jnp.int32) for v in (attended, gathered, lane_steps, chunk_tokens))])
 
@@ -426,7 +488,6 @@ def prefill_chosen(params, cfg: NemotronHConfig, cache, tokens, start, last_inde
     experts each expert layer's router chose [Le, T, k])."""
     T = tokens.shape[1]
     n_valid = last_index[0] + 1
-    G, hd = cfg.n_kv_head, cfg.head_dim
     x = params["embed"][tokens[0]]
     # the sequence's positions by page, then room for this chunk wherever it starts
     C = table.shape[0] * block_size
@@ -436,28 +497,10 @@ def prefill_chosen(params, cfg: NemotronHConfig, cache, tokens, start, last_inde
     for lp, (kind, i) in zip(params["layers"], _kinds(cfg)):
         y = _rmsnorm(x, lp["norm"], cfg.layer_norm_epsilon)
         if kind == MAMBA:
-            z, xbc, dt = _mamba_in(y, lp, cfg)
-            with jax.named_scope("mamba.conv"):
-                tail = jnp.where(start == 0, 0, cache[_tail_name(i)][lane])
-                xbc, state[_tail_name(i)] = mamba2.conv_tail(xbc, tail, lp["conv_w"], lp["conv_b"], n_valid)
-            with jax.named_scope("mamba.scan"):
-                xs, B, Cm = _mamba_split(xbc, cfg)
-                held = jnp.where(start == 0, 0.0, cache[_state_name(i)][lane])
-                o, state[_state_name(i)] = mamba2.ssd_chunk(
-                    xs, dt, -jnp.exp(lp["A_log"]), B, Cm, lp["D"], held, n_valid, cfg.chunk_size)
-            out = _mamba_out(o, z, lp, cfg)
+            out, after = _mamba_chunk(y, lp, cfg, cache, i, lane, start, n_valid)
+            state.update(after)
         elif kind == ATTENTION:
-            with jax.named_scope("attn.gqa"):
-                q, k, v = _qkv(y, lp, cfg)
-
-                def context(pages, rows):
-                    ctx = jnp.concatenate([_rows(pages, i, where).reshape(C, G, hd),
-                                           jnp.zeros((room, G, hd), pages.dtype)])
-                    return jax.lax.dynamic_update_slice_in_dim(ctx, rows, start, axis=0)
-
-                att = chunk_attention(q, context(cache["k_pages"], k), context(cache["v_pages"], v),
-                                      start, n_valid)
-                out = att @ lp["wo"]
+            out, k, v = _attention_chunk(y, lp, cfg, cache, i, where, room, start, n_valid)
             ks.append(k)
             vs.append(v)
         else:
@@ -484,30 +527,16 @@ def decode_chosen(params, cfg: NemotronHConfig, cache, tok, block_tables, length
     hd], {}, {"conv_tail_<i>", "ssm_state_<i>": the whole new arrays},
     COUNTERS, and for the checks the experts each expert layer's router
     chose [Le, B, k])."""
-    from ray_tpu.ops.attention import gqa_paged_decode_attention
-
-    B = tok.shape[0]
     runs = lengths > 0
     x = params["embed"][tok]
     ks, vs, state, counts, chose = [], [], {}, [], []
     for lp, (kind, i) in zip(params["layers"], _kinds(cfg)):
         y = _rmsnorm(x, lp["norm"], cfg.layer_norm_epsilon)
         if kind == MAMBA:
-            z, xbc, dt = _mamba_in(y, lp, cfg)
-            with jax.named_scope("mamba.conv"):
-                xbc, state[_tail_name(i)] = mamba2.conv_tail(
-                    xbc[:, None], cache[_tail_name(i)], lp["conv_w"], lp["conv_b"])
-            with jax.named_scope("mamba.step"):
-                xs, Bm, Cm = _mamba_split(xbc[:, 0], cfg)
-                o, state[_state_name(i)] = mamba2.ssm_decode_step(
-                    xs, dt, -jnp.exp(lp["A_log"]), Bm, Cm, lp["D"], cache[_state_name(i)], runs)
-            out = _mamba_out(o, z, lp, cfg)
+            out, after = _mamba_decode(y, lp, cfg, cache, i, runs)
+            state.update(after)
         elif kind == ATTENTION:
-            with jax.named_scope("attn.gqa"):
-                q, k, v = _qkv(y, lp, cfg)
-                o = gqa_paged_decode_attention(q, k, v, cache["k_pages"], cache["v_pages"], i, block_tables,
-                                               lengths, block_size=block_size)
-                out = o.reshape(B, -1) @ lp["wo"]
+            out, k, v = _attention_decode(y, lp, cfg, cache, i, block_tables, lengths, block_size)
             ks.append(k)
             vs.append(v)
         else:

@@ -176,7 +176,7 @@ def mla_paged_decode_attention(q, row_self, pages, layer, block_tables, lengths,
 
 
 def gqa_paged_decode_attention(q, k_self, v_self, k_pages, v_pages, layer, block_tables, lengths, *,
-                               block_size):
+                               block_size, scale=None):
     """One fed token a lane over the pages it holds of a paged KV pool,
     layer ``layer``, and itself; grouped queries, every cached position
     attended.
@@ -185,19 +185,25 @@ def gqa_paged_decode_attention(q, k_self, v_self, k_pages, v_pages, layer, block
     v_self [B, G, Dh]; k_pages, v_pages [L, num_blocks * block_size,
     G * Dh]; block_tables [B, pages] int32, scratch block 0 where a lane
     holds none; lengths [B] int32 the cached positions of a lane (0: it
-    attends to itself alone).  Returns [B, G, R, Dh].
+    attends to itself alone); scale, static, multiplies the scores
+    (None: ``Dh ** -0.5``).  Returns [B, G, R, Dh].
 
-    On a TPU, where the shapes fit its tiling, the Pallas kernel reads
-    the pages where they lie, a page once for its group's R heads
-    (ops.pallas_gqa_paged_attention).  Elsewhere the lane's pages are
-    gathered to a contiguous context first."""
+    On a TPU, where the shapes fit its tiling (whole lane tiles a head,
+    whole sublane tiles a page; any R that is whole sublane tiles or
+    fewer than one), the Pallas kernel reads the pages where they lie, a
+    page once for its group's R heads (ops.pallas_gqa_paged_attention).
+    Elsewhere the lane's pages are gathered to a contiguous context
+    first."""
     B, G, R, Dh = q.shape
     if jax.default_backend() == "tpu":  # as paged_decode_attention: the CPU tests gather
         from ray_tpu.ops import pallas_gqa_paged_attention as kernel
 
         if kernel.kernel_takes(R, Dh, block_size, k_pages.dtype):
             return kernel.gqa_paged_decode_attention_kernel(
-                q, k_self, v_self, k_pages, v_pages, layer, block_tables, lengths, block_size=block_size)
+                q, k_self, v_self, k_pages, v_pages, layer, block_tables, lengths, block_size=block_size,
+                scale=scale)
+    if scale is not None:  # the reference scales by Dh ** -0.5
+        q = q * (scale * Dh ** 0.5)
     C = block_tables.shape[1] * block_size
     idx = (block_tables[:, :, None] * block_size + jnp.arange(block_size)).reshape(B, C)
     # every K/V head repeated for its R query heads: the multi-head reference

@@ -1,7 +1,8 @@
 """Decode attention over a paged KV pool of GROUPED-query layers, read
 in place (Pallas TPU): the sibling of ``pallas_paged_attention.py`` for
 layers whose ``R`` query heads share each of ``G`` K/V heads
-(``models/nemotron_h.py``: 32 query heads over 2), every cached position
+(``models/nemotron_h.py``: 32 query heads over 2;
+``models/granite_hybrid.py``: 32 over 8), every cached position
 attended.
 
 One new token a lane.  The pools stay in HBM, whole: ``[n_layer,
@@ -16,7 +17,11 @@ folds the block into an online softmax of the lane's heads: a group's
 scores are one ``[R, Dh] x [Dh, positions]`` matmul against the group's
 own columns of the slab, its output one ``[R, positions] x [positions,
 Dh]`` matmul (no block-diagonal query: a group's heads read the same
-columns).  Nothing of shape ``[.., B, max_ctx, ..]`` is built and no
+columns).  A group of fewer query heads than a sublane tile of the
+pool's dtype (4 where bf16 packs 16 rows) is padded to one with heads of
+zeros, whose rows are dropped: a group's rows are then whole tiles, and
+the matmul unit takes a tile's rows at a time whatever they hold.
+Nothing of shape ``[.., B, max_ctx, ..]`` is built and no
 K/V head is repeated for its query heads; a lane of length 0 costs
 nothing.  Operands in the pool's dtype, float32 scores and softmax
 state.  The order of summation depends on positions only, never on
@@ -39,16 +44,22 @@ from ray_tpu.ops.pallas_attention import NEG_INF
 _BLOCK_POSITIONS = 512
 
 
+def _sublanes(dtype) -> int:
+    """Rows of a sublane tile of `dtype` (16 of bf16)."""
+    return 8 * 4 // jnp.dtype(dtype).itemsize
+
+
 def kernel_takes(n_rep, d_head, block_size, dtype) -> bool:
-    """The shapes the kernel's tiling can take: a page and a group's
-    query heads are whole sublane tiles of the pool's dtype (16 rows of
-    bf16), a compute block whole pages, a head whole lane tiles."""
-    sublanes = 8 * 4 // jnp.dtype(dtype).itemsize
+    """The shapes the kernel's tiling can take: a page is whole sublane
+    tiles of the pool's dtype (16 rows of bf16), a compute block whole
+    pages, a head whole lane tiles.  A group's query heads are whole
+    sublane tiles or fewer than one (padded to one)."""
+    sublanes = _sublanes(dtype)
     return (
         block_size % sublanes == 0
         and _BLOCK_POSITIONS % block_size == 0
         and d_head % 128 == 0
-        and n_rep % sublanes == 0
+        and (n_rep % sublanes == 0 or n_rep < sublanes)
     )
 
 
@@ -57,7 +68,7 @@ def _kernel(layer_ref, len_ref, tab_ref,               # scalar prefetch (SMEM)
             o_ref,                                     # output
             item_lane, item_blk, kbuf, vbuf, sems,     # scratch
             qb_ref, m_ref, l_ref, acc_ref,
-            *, block_size, groups):
+            *, block_size, groups, scale):
     bs = block_size
     bk = kbuf.shape[1]           # positions a compute block
     n = bk // bs                 # pages a compute block
@@ -65,7 +76,6 @@ def _kernel(layer_ref, len_ref, tab_ref,               # scalar prefetch (SMEM)
     pages_per_seq = tab_ref.shape[0] // n_lanes
     H, Dh = qb_ref.shape
     G, R = groups, H // groups
-    scale = 1.0 / (Dh ** 0.5)
     layer = layer_ref[0]
 
     def lane_pages(lane):
@@ -184,11 +194,18 @@ def _kernel(layer_ref, len_ref, tab_ref,               # scalar prefetch (SMEM)
     jax.lax.fori_loop(0, total, body, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("block_size", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_size", "scale", "interpret"))
 def gqa_paged_decode_attention_kernel(q, k_self, v_self, k_pages, v_pages, layer, block_tables, lengths, *,
-                                      block_size, interpret=False):
+                                      block_size, scale=None, interpret=False):
     """The arguments of ``ops.attention.gqa_paged_decode_attention``.
     ``interpret=True`` runs the same kernel on the CPU for tests."""
+    heads, tile = q.shape[2], _sublanes(k_pages.dtype)
+    if heads < tile:  # a small group: heads of zeros up to a tile, dropped at the end
+        pad = tile - heads
+        out = gqa_paged_decode_attention_kernel(
+            jnp.pad(q, ((0, 0), (0, 0), (0, pad), (0, 0))), k_self, v_self, k_pages, v_pages, layer, block_tables,
+            lengths, block_size=block_size, scale=scale, interpret=interpret)
+        return out[:, :, :heads]
     B, G, R, Dh = q.shape
     H, GD = G * R, G * Dh
     pages_per_seq = block_tables.shape[1]
@@ -205,7 +222,8 @@ def gqa_paged_decode_attention_kernel(q, k_self, v_self, k_pages, v_pages, layer
         return x.reshape(B, 1, GD).astype(jnp.float32)
 
     out = pl.pallas_call(
-        functools.partial(_kernel, block_size=block_size, groups=G),
+        functools.partial(_kernel, block_size=block_size, groups=G,
+                          scale=1.0 / (Dh ** 0.5) if scale is None else scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(1,),

@@ -21,6 +21,8 @@ MODEL_FAMILIES = (
      ("mistral_small_4_tiny", "mistral_small_4", "mistral_small_4_6l_ep4")),
     ("ray_tpu.models.nemotron_h", "NemotronHConfig",
      ("nemotron_3_nano_tiny", "nemotron_3_nano", "nemotron_3_nano_26l_ep4")),
+    ("ray_tpu.models.granite_hybrid", "GraniteHybridConfig",
+     ("granite_4_0_h_small_tiny", "granite_4_0_h_small", "granite_4_0_h_small_10l_ep2")),
 )
 # every preset ``LLMConfig.model`` may name, family by family
 PRESETS = " | ".join(name for *_, presets in MODEL_FAMILIES for name in presets)
@@ -76,7 +78,12 @@ class LLMConfig:
     never die of cache exhaustion mid-decode.  ``max_batch_size`` is the
     number of decode lanes: the continuous batcher keeps them full by
     joining waiting requests at step boundaries.  A family that keeps
-    state a lane (``cache_spec``) gets one slot of it for each lane.
+    state a lane (``cache_spec``) gets one slot of it for each lane: the
+    ``minicpm_sala*`` presets a float32 state a lightning layer, the
+    ``nemotron_3_nano*`` and ``granite_4_0_h_small*`` presets a
+    convolution tail and a float32 scan state a Mamba-2 layer (25.6 MB
+    and 38.2 MB a lane at the depths their ``*_ep*`` presets hold), and
+    only their attention layers page K and V.
 
     ``model`` names a preset of a model family, one of (from
     ``MODEL_FAMILIES``): {presets}.

@@ -1,5 +1,5 @@
 """Whether a change left the serve programs of the families that exist
-what they were (PERF.md section 6, PR 33, PR 38 and PR 40).
+what they were (PERF.md section 6, PR 33, PR 38, PR 40 and PR 41).
 
     JAX_PLATFORMS=cpu PYTHONPATH=<checkout> python scripts/serve_program_hashes.py out.json
 
@@ -18,7 +18,7 @@ sys.path.insert(0, os.getcwd())
 import jax, jax.numpy as jnp
 from jax.experimental import topologies
 from jax.sharding import SingleDeviceSharding
-from ray_tpu.serve.llm.config import LLMConfig, model_family
+from ray_tpu.serve.llm.config import MODEL_FAMILIES, LLMConfig, model_family
 from ray_tpu.serve.llm.engine import decode_step, prefill_step
 
 jax.default_backend = lambda: "tpu"
@@ -33,6 +33,7 @@ CELLS = {  # preset: lanes, block, pool tokens, max context, prefill tokens
     "minicpm_sala_16l": (16, 16, 540672, 33792, 4096),
     "mistral_small_4_6l_ep4": (48, 64, 786432, 36864, 4096),
     "nemotron_3_nano_26l_ep4": (128, 64, 393216, 6144, 2048),
+    "granite_4_0_h_small_10l_ep2": (32, 64, 557056, 17408, 2048),
 }
 
 def strip_payloads(text):
@@ -58,6 +59,8 @@ def strip_payloads(text):
 
 out = {}
 for preset, (B, block, pool_tokens, max_ctx, T) in CELLS.items():
+    if not any(preset in presets for *_, presets in MODEL_FAMILIES):
+        continue  # a checkout from before the family
     cfg = LLMConfig(model=preset, dtype="bfloat16").model_config()
     family = model_family(cfg)
     spec = family.cache_spec(cfg, block)

@@ -465,3 +465,64 @@ def test_nemotron_h_programs_compile_for_v5e(one_chip, monkeypatch):
     # the scores of 2,048 queries x 32 heads over 8,192 keys would be 2.1 GB in float32
     assert mem.temp_size_in_bytes < 1 * 2**30
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 * 2**30
+
+
+def test_granite_hybrid_programs_compile_for_v5e(one_chip, monkeypatch):
+    """Granite 4.0-H's two programs at the published widths (d 4096;
+    Mamba-2 128 heads of 64, state 128, ONE group, scan blocks of 256; 32
+    query heads of 128 over 8 K/V heads, FOUR a group; 36 held SwiGLU
+    experts of width 768 of 72 routed, a shared one of 1,536; the tied
+    head over 50,176 rows), 32 lanes over 17,408 positions in pages of
+    64, depth cut to one layer of each kind: the decode step updates a
+    lane's 4 MB state through the Mamba-2 kernel INTO the donated array,
+    reads the K/V pages through the grouped-query kernel (a group of 4
+    padded to a sublane tile: no gather of a ``[lanes, max_ctx, ...]``
+    context) and runs two grouped matmuls a layer; a 2,048-token chunk
+    compiles with its scan in blocks of 256 and fits beside the cache."""
+    from ray_tpu.models import granite_hybrid as gh
+    from ray_tpu.serve.llm.engine import decode_step, prefill_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = gh.GraniteHybridConfig.granite_4_0_h_small_10l_ep2(layer_types=("mamba", "attention"))
+    B, C, block, T, slots = 32, 17408, 64, 2048, 557056 + 64
+    spec = gh.cache_spec(cfg, block)
+    assert spec.names == ("k_pages", "v_pages", "conv_tail_0", "ssm_state_0")
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    def shaped(tree):
+        return jax.tree_util.tree_map(lambda x: arr(x.shape, x.dtype), tree)
+
+    params = shaped(jax.eval_shape(lambda: gh.init_params(cfg)))
+    key = shaped(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    pool = arr((spec.paged_layers, slots, spec.row_width), cfg.dtype)
+    cache = [pool, pool] + [arr((B, *shape), dtype) for _, shape, dtype in spec.lane_state]
+    held = tuple(range(1, 1 + len(cache)))
+    state_bytes = B * 128 * 64 * 128 * 4
+
+    decode = jax.jit(lambda *a: decode_step(cfg, 0, block, spec, *a), donate_argnums=held).lower(
+        params, *cache, arr((B,), jnp.int32), arr((B,), jnp.int32), arr((B, C // block), jnp.int32),
+        arr((B,), jnp.int32), arr((B,), jnp.float32), key).compile()
+    text = decode.as_text()
+    calls = [ln.split(" = ")[0].split("%")[-1] for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert sum(c.startswith("mamba2_decode_step") for c in calls) == 1
+    assert sum(c.startswith("gqa_paged_decode_attention") for c in calls) == 1
+    assert sum(c.startswith("moe_gmm") for c in calls) == 4 and len(calls) == 6
+    # the gather path would build K and V contexts of 32 x 17,408 positions: nothing of that extent is here
+    assert f"[{B},{C}," not in text
+    mem = decode.memory_analysis()
+    # every held array goes out in the buffer it came in: both pools, the tail, the state
+    assert mem.alias_size_in_bytes >= state_bytes + 2 * slots * 1024 * 2 + B * 25344 * 2
+    assert mem.temp_size_in_bytes < state_bytes  # no second array of states among the temporaries
+    assert f"s32[{B + len(gh.COUNTERS)}]" in text
+
+    chunk = jax.jit(lambda *a: prefill_step(cfg, 0, block, spec, *a), donate_argnums=held).lower(
+        params, *cache, arr((1, T), jnp.int32), arr((T,), jnp.int32), arr((1,), jnp.int32),
+        arr((1,), jnp.float32), key, arr((), jnp.int32), arr((C // block,), jnp.int32),
+        arr((), jnp.int32)).compile()
+    mem = chunk.memory_analysis()
+    # a block's [256, 256, 128] decays in float32 are 33.5 MB, three of them live; the whole stays under 2 GB
+    assert mem.temp_size_in_bytes < 2 * 2**30
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 * 2**30

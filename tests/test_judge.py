@@ -22,6 +22,7 @@ if REPO not in sys.path:
 pytest.register_assert_rewrite(
     "benchmark.tests.test_benchmark", "benchmark.tests.test_olmoe_cell",
     "benchmark.tests.test_mistral_small_4_cell", "benchmark.tests.test_nemotron_3_nano_cell",
+    "benchmark.tests.test_granite_4_0_h_small_cell",
 )
 
 from benchmark.tests.test_benchmark import (  # noqa: E402,F401
@@ -55,6 +56,30 @@ from benchmark.tests.test_nemotron_3_nano_cell import (  # noqa: E402,F401
 )
 from benchmark.tests.test_nemotron_3_nano_cell import (  # noqa: E402,F401
     test_runner_fails_at_once_where_the_program_has_no_such_family as test_nemotron_runner_fails_at_once_where_the_program_has_no_such_family,
-    test_the_cell_s_metrics_are_the_entries_of_benchmark_json as test_the_nemotron_cell_s_metrics_are_the_entries_of_benchmark_json,
+    test_the_cell_s_metrics_are_the_entries_of_benchmark_json as _nemotron_cell_s_metrics_as_pr_38_wrote_it,
     test_the_configuration_is_the_catalog_s_but_for_what_it_says_is_reduced as test_the_nemotron_configuration_is_the_catalog_s_but_for_what_it_says_is_reduced,
 )
+from benchmark.tests.test_granite_4_0_h_small_cell import (  # noqa: E402,F401
+    test_decode_kernels_held_experts_and_chunk_work_and_their_shares_by_hand,
+    test_the_cut_s_arithmetic_reckoned_again,
+    test_the_stated_cache_is_one_paged_layer_and_two_arrays_a_mamba_layer,
+)
+from benchmark.tests.test_granite_4_0_h_small_cell import (  # noqa: E402,F401
+    test_runner_fails_at_once_where_the_program_has_no_such_family as test_granite_runner_fails_at_once_where_the_program_has_no_such_family,
+    test_the_cell_s_metrics_are_the_entries_of_benchmark_json as test_the_granite_cell_s_metrics_are_the_entries_of_benchmark_json,
+    test_the_configuration_is_the_catalog_s_but_for_what_it_says_is_reduced as test_the_granite_configuration_is_the_catalog_s_but_for_what_it_says_is_reduced,
+)
+
+
+def test_the_nemotron_cell_s_metrics_are_the_entries_of_benchmark_json(monkeypatch):
+    """PR 38's test on the benchmark as far as PR 38 brought it: it says
+    there are eight cells and that its own is the last, which held until
+    the next cell was appended behind it (PR 41), and only a
+    ``benchmark`` PR may edit its file.  The cells and configurations up
+    to its own are still what it says, in their places."""
+    from benchmark import spec
+
+    bench = spec.load_benchmark()
+    then = dict(bench, workloads=bench["workloads"][:8], configs=bench["configs"][:6])
+    monkeypatch.setattr(spec, "load_benchmark", lambda: then)
+    _nemotron_cell_s_metrics_as_pr_38_wrote_it()

@@ -24,7 +24,7 @@ H, P, N, G, K = 8, 8, 16, 2, 4  # heads, head size, state size, groups, convolut
 CHUNK = 8  # positions a block of the chunked scan
 
 
-def _sequence(T, seed=0):
+def _sequence(T, seed=0, H=H, G=G):
     """x [T, H, P], dt [T, H] (after its softplus), A, D [H], B, C [T, G, N]."""
     rng = np.random.default_rng(seed)
     f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa: E731
@@ -36,7 +36,8 @@ def _sequence(T, seed=0):
 def _recurrence(x, dt, A, B, C, D, S=None):
     """The equations a position at a time, in numpy float64."""
     x, dt, A, B, C, D = (np.asarray(v, np.float64) for v in (x, dt, A, B, C, D))
-    T = x.shape[0]
+    T, H = x.shape[:2]
+    G = B.shape[1]
     S = np.zeros((H, P, N)) if S is None else np.asarray(S, np.float64)
     out = np.zeros((T, H, P))
     for t in range(T):
@@ -47,18 +48,18 @@ def _recurrence(x, dt, A, B, C, D, S=None):
     return out, S
 
 
-def _in_pieces(seq, cuts, pad_to=None):
+def _in_pieces(seq, cuts, pad_to=None, chunk=CHUNK):
     """``ssd_chunk`` over the sequence cut at ``cuts``, the state carried
     from piece to piece; a piece is padded to ``pad_to`` positions (or to
     whole blocks) with rows that must change nothing."""
     x, dt, A, B, C, D = seq
     T = x.shape[0]
-    S, outs = jnp.zeros((H, P, N), jnp.float32), []
+    S, outs = jnp.zeros((x.shape[1], P, N), jnp.float32), []
     for a, b in zip((0, *cuts), (*cuts, T)):
         n = b - a
-        width = pad_to or (n if n < CHUNK else -(-n // CHUNK) * CHUNK)
+        width = pad_to or (n if n < chunk else -(-n // chunk) * chunk)
         pad = lambda v: jnp.concatenate([v[a:b], 7.0 + jnp.zeros((width - n, *v.shape[1:]), v.dtype)])  # noqa: E731
-        y, S = mamba2.ssd_chunk(pad(x), pad(dt), A, pad(B), pad(C), D, S, n, CHUNK)
+        y, S = mamba2.ssd_chunk(pad(x), pad(dt), A, pad(B), pad(C), D, S, n, chunk)
         outs.append(y[:n])
     return jnp.concatenate(outs), S
 
@@ -87,6 +88,27 @@ def test_ssd_chunk_pads_leave_the_state_alone():
     want, want_S = _recurrence(*seq)
     got, S = _in_pieces(seq, (5,), pad_to=16)
     assert np.abs(np.asarray(got) - want).max() < TOL and np.abs(np.asarray(S) - want_S).max() < TOL
+
+
+def test_ssd_chunk_at_blocks_of_256_with_one_group_carries_its_state_across_chunks():
+    """Granite 4.0-H's form: ONE group for all the heads and scan blocks
+    of 256, where a fast head's decay over a block underflows (``exp`` of
+    a sum of 256 terms down to -16 each) and must do so without a
+    quotient of two such.  Two chunks of 512 positions (two blocks each)
+    and a third of 100 in a padded block, the state carried between.
+    Steps log-uniform in the published [0.001, 0.1]: a block's log-decay
+    then reaches -400, of which a float32 keeps 3e-5, and what is left
+    between two positions is ``exp`` of a difference of two such: 1e-4
+    seen on outputs of size 1-10, so 5e-4 (blocks of 8 never get there)."""
+    x, _, A, B, C, D = _sequence(1124, seed=11, H=4, G=1)
+    dt = jnp.asarray(np.exp(np.random.default_rng(12).uniform(np.log(1e-3), np.log(0.1), (1124, 4))), jnp.float32)
+    # head 0 is the fastest there can be: its decay over a block, exp(-409.6), is 0 in float32
+    seq = (x, dt.at[:, 0].set(0.1), A.at[0].set(-16.0), B, C, D)
+    want, want_S = _recurrence(*seq)
+    got, S = _in_pieces(seq, (512, 1024), chunk=256)
+    assert np.isfinite(np.asarray(got)).all()
+    assert np.abs(np.asarray(got) - want).max() < 10 * TOL
+    assert np.abs(np.asarray(S) - want_S).max() < 10 * TOL
 
 
 def test_ssm_step_token_by_token_is_ssd_chunk():
@@ -134,7 +156,7 @@ def test_conv_tail_across_a_boundary_is_the_whole_convolution(cuts):
     assert np.array_equal(np.asarray(tail).reshape(K - 1, C), np.asarray(x[-(K - 1):]))
 
 
-def _lanes(Bn, seed=0):
+def _lanes(Bn, seed=0, H=H, G=G):
     rng = np.random.default_rng(seed)
     f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa: E731
     dt = jnp.asarray(np.log1p(np.exp(rng.normal(size=(Bn, H)))), jnp.float32)
@@ -143,19 +165,23 @@ def _lanes(Bn, seed=0):
     return f(Bn, H, P), dt, A, f(Bn, G, 128), f(Bn, G, 128), jnp.asarray(rng.normal(size=H), jnp.float32), state
 
 
-@pytest.mark.parametrize("active", [
-    [True] * 5, [True, False, True, True, False], [False, False, True, False, False], [False] * 5], ids=str)
-def test_mamba2_decode_step_kernel_is_ssm_step(active):
+@pytest.mark.parametrize("active, heads, groups, tol", [
+    ([True] * 5, H, G, 1e-5), ([True, False, True, True, False], H, G, 1e-5),
+    ([False, False, True, False, False], H, G, 1e-5), ([False] * 5, H, G, 1e-5),
+    # Granite 4.0-H: 128 heads that share ONE B and C; the largest of sixteen times the outputs lies higher
+    ([True, True, False, True, True], 128, 1, 5e-5),
+], ids=str)
+def test_mamba2_decode_step_kernel_is_ssm_step(active, heads, groups, tol):
     """The Pallas kernel in interpret mode: the running lanes' outputs
     and states are ``ssm_step``'s, an idle lane's state is the array's
     own bits."""
-    args = _lanes(5, seed=1)
+    args = _lanes(5, seed=1, H=heads, G=groups)
     active = jnp.asarray(active)
     want_y, want_S = mamba2.ssm_step(*args, active)
     y, S = mamba2_decode_step(*args, active, interpret=True)
     on = np.asarray(active)
-    assert np.abs(np.asarray(y) - np.asarray(want_y))[on].max(initial=0.0) < 1e-5
-    assert np.abs(np.asarray(S) - np.asarray(want_S))[on].max(initial=0.0) < 1e-5
+    assert np.abs(np.asarray(y) - np.asarray(want_y))[on].max(initial=0.0) < tol
+    assert np.abs(np.asarray(S) - np.asarray(want_S))[on].max(initial=0.0) < tol
     assert np.array_equal(np.asarray(S)[~on], np.asarray(args[-1])[~on])
 
 
@@ -193,24 +219,35 @@ def _paged(B, Gk, R, Dh, bs, pages, dtype, seed=0):
             1, jnp.asarray(tables), jnp.asarray(lengths))
 
 
+@pytest.mark.parametrize("Gk, R, scale", [
+    (2, 4, None),    # a group smaller than a sublane tile (padded to one), the scale a head's own
+    (8, 4, 0.05),    # Granite 4.0-H: 4 queries a group, 8 groups, a scale that is not Dh ** -0.5 (0.25 here)
+    (2, 16, None),   # Nemotron-H: a group that is a whole bf16 tile, as the kernel first took it
+], ids=str)
 @pytest.mark.parametrize("dtype, tol", [(jnp.float32, 2e-6), (jnp.bfloat16, 3e-2)])
-def test_gqa_paged_decode_is_reference_decode_attention(dtype, tol):
+def test_gqa_paged_decode_is_reference_decode_attention(dtype, tol, Gk, R, scale):
     """Kernel (interpret mode; 3 compute blocks a full lane) and gather
     fallback against ``reference_decode_attention`` on a contiguous
     context with each K/V head repeated for its query heads."""
-    B, Gk, R, Dh, bs, pages = 4, 2, 4, 16, 8, 150
+    B, Dh, bs, pages = 4, 16, 8, 150
     q, ks, vs, kp, vp, layer, tables, lengths = args = _paged(B, Gk, R, Dh, bs, pages, dtype)
     C = pages * bs
     idx = (tables[:, :, None] * bs + jnp.arange(bs)).reshape(B, C)
     rep = lambda v, axis: jnp.repeat(v, R, axis=axis)  # noqa: E731
+    # the reference scales by Dh ** -0.5: hand it queries that carry the rest
+    q_ref = q if scale is None else (q.astype(jnp.float32) * (scale * Dh ** 0.5))
+    ctx = lambda pool: rep(pool[layer][idx].reshape(B, C, Gk, Dh), 2).astype(q_ref.dtype)  # noqa: E731
     want = reference_decode_attention(
-        q.reshape(B, Gk * R, Dh), rep(ks, 1), rep(vs, 1), rep(kp[layer][idx].reshape(B, C, Gk, Dh), 2),
-        rep(vp[layer][idx].reshape(B, C, Gk, Dh), 2), jnp.arange(C)[None, :] < lengths[:, None])
+        q_ref.reshape(B, Gk * R, Dh), rep(ks, 1).astype(q_ref.dtype), rep(vs, 1).astype(q_ref.dtype),
+        ctx(kp), ctx(vp), jnp.arange(C)[None, :] < lengths[:, None])
     want = np.asarray(want, np.float32).reshape(B, Gk, R, Dh)
-    got = gqa_paged_decode_attention_kernel(*args, block_size=bs, interpret=True)
-    assert got.dtype == dtype and np.abs(np.asarray(got, np.float32) - want).max() < tol
-    fallback = gqa_paged_decode_attention(*args, block_size=bs)
+    got = gqa_paged_decode_attention_kernel(*args, block_size=bs, scale=scale, interpret=True)
+    assert got.shape == q.shape and got.dtype == dtype and np.abs(np.asarray(got, np.float32) - want).max() < tol
+    fallback = gqa_paged_decode_attention(*args, block_size=bs, scale=scale)
     assert np.abs(np.asarray(fallback, np.float32) - want).max() < tol
+    if scale is not None:  # and the scale is read: without it the kernel says something else
+        plain = gqa_paged_decode_attention_kernel(*args, block_size=bs, interpret=True)
+        assert np.abs(np.asarray(plain, np.float32) - want).max() > 3 * tol
 
 
 def test_gqa_paged_decode_does_not_depend_on_which_pages_a_lane_holds():
