@@ -402,6 +402,38 @@ def test_mistral_small_4_programs_compile_for_v5e(one_chip, monkeypatch):
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 * 2**30
 
 
+@pytest.mark.parametrize("lanes, H, G", [(128, 64, 8), (32, 128, 1)], ids=["nemotron-h", "granite-4.0-h"])
+def test_mamba2_decode_step_compiles_for_v5e(one_chip, lanes, H, G):
+    """The Mamba-2 decode kernel alone at the two served shapes (heads of
+    64 x 128 float32; 128 lanes of 64 heads in 8 groups, 32 lanes of 128
+    heads in ONE group: 4 MB a lane, two in and two out of them 16 MB of
+    the kernel's VMEM): one custom call, which takes the float32
+    ``HIGHEST`` contraction of two heads' new states with C and fits its
+    ``vmem_limit_bytes`` (the compiler would refuse it here), the states
+    going out in the buffer they came in, nothing of a state's size
+    among the temporaries."""
+    from ray_tpu.ops import pallas_mamba2
+
+    P, N = 64, 128
+    assert pallas_mamba2.kernel_takes(H, P, N, G)
+
+    def arr(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(pallas_mamba2.mamba2_decode_step, donate_argnums=(6,)).lower(
+        arr((lanes, H, P), jnp.bfloat16), arr((lanes, H)), arr((H,)), arr((lanes, G, N)), arr((lanes, G, N)),
+        arr((H,)), arr((lanes, H, P, N)), arr((lanes,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    calls = [ln.split(" = ")[0].split("%")[-1] for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1 and calls[0].startswith("mamba2_decode_step")
+    lane_bytes = H * P * N * 4
+    assert 4 * lane_bytes < pallas_mamba2._VMEM_BYTES
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == lanes * lane_bytes
+    assert mem.temp_size_in_bytes < lane_bytes
+
+
 def test_nemotron_h_programs_compile_for_v5e(one_chip, monkeypatch):
     """Nemotron-H's two programs at the published widths (d 2688; Mamba-2
     64 heads of 64, state 128, 8 groups, convolution 4; 32 query heads of

@@ -156,7 +156,7 @@ def test_conv_tail_across_a_boundary_is_the_whole_convolution(cuts):
     assert np.array_equal(np.asarray(tail).reshape(K - 1, C), np.asarray(x[-(K - 1):]))
 
 
-def _lanes(Bn, seed=0, H=H, G=G):
+def _lanes(Bn, seed=0, H=H, G=G, P=P):
     rng = np.random.default_rng(seed)
     f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa: E731
     dt = jnp.asarray(np.log1p(np.exp(rng.normal(size=(Bn, H)))), jnp.float32)
@@ -165,24 +165,72 @@ def _lanes(Bn, seed=0, H=H, G=G):
     return f(Bn, H, P), dt, A, f(Bn, G, 128), f(Bn, G, 128), jnp.asarray(rng.normal(size=H), jnp.float32), state
 
 
-@pytest.mark.parametrize("active, heads, groups, tol", [
-    ([True] * 5, H, G, 1e-5), ([True, False, True, True, False], H, G, 1e-5),
-    ([False, False, True, False, False], H, G, 1e-5), ([False] * 5, H, G, 1e-5),
+def _cancelling(args, seed, big=300.0):
+    """The same lanes, made so that the new state's products with C are
+    large and cancel: the state's columns and B's are equal in pairs (a
+    random pairing of the N columns, so the new state's are too, bit for
+    bit) and C is ``+big r`` on one of a pair and ``-big r`` on the
+    other, plus the small C it had.  A term is hundreds of times the
+    sum, and the sum's order shows."""
+    B, C, state = np.array(args[3]), 0.01 * np.asarray(args[4]), np.array(args[6])
+    rng = np.random.default_rng(seed)
+    pair = rng.permutation(state.shape[-1])
+    one, other = pair[0::2], pair[1::2]
+    state[..., other], B[..., other] = state[..., one], B[..., one]
+    r = big * rng.normal(size=C[..., one].shape).astype(np.float32)
+    C[..., one] += r
+    C[..., other] -= r
+    return (*args[:3], jnp.asarray(B), jnp.asarray(C), args[5], jnp.asarray(state))
+
+
+@pytest.mark.parametrize("active, heads, groups, rows, tol, cancelling", [
+    ([True] * 5, H, G, P, 1e-5, False), ([True, False, True, True, False], H, G, P, 1e-5, False),
+    ([False, False, True, False, False], H, G, P, 1e-5, False), ([False] * 5, H, G, P, 1e-5, False),
     # Granite 4.0-H: 128 heads that share ONE B and C; the largest of sixteen times the outputs lies higher
-    ([True, True, False, True, True], 128, 1, 5e-5),
+    ([True, True, False, True, True], 128, 1, P, 5e-5, False),
+    ([False, False, False, True, False], 128, 1, P, 5e-5, False),   # one lane alone running at that shape
+    # heads of the served 64 rows, two of them a stationary operand: 4 a group, and 2 of 3 groups
+    ([True, False, True], 8, 2, 64, 5e-5, False), ([True, True, True], 6, 3, 64, 5e-5, False),
+    # products with C that are large and cancel: the distance is held against the terms' size, see below
+    ([True] * 5, H, G, P, 1e-5, True), ([True, True, False, True, True], 128, 1, P, 5e-5, True),
 ], ids=str)
-def test_mamba2_decode_step_kernel_is_ssm_step(active, heads, groups, tol):
+def test_mamba2_decode_step_kernel_is_ssm_step(active, heads, groups, rows, tol, cancelling):
     """The Pallas kernel in interpret mode: the running lanes' outputs
     and states are ``ssm_step``'s, an idle lane's state is the array's
-    own bits."""
-    args = _lanes(5, seed=1, H=heads, G=groups)
+    own bits.  The outputs are held to the tolerance HEAD BY HEAD ([lane,
+    head] maxima: a head's rows put where another's belong would pass a
+    maximum over everything only by luck) and, where the products
+    cancel, against float64: both float32 sums then lie within two
+    units in the last place of the TERMS (not of the sum) from it, and
+    the kernel's no further than ``ssm_step``'s own order of the sum
+    does, twice over."""
+    args = _lanes(len(active), seed=1, H=heads, G=groups, P=rows)
+    if cancelling:
+        args = _cancelling(args, seed=2)
     active = jnp.asarray(active)
     want_y, want_S = mamba2.ssm_step(*args, active)
     y, S = mamba2_decode_step(*args, active, interpret=True)
     on = np.asarray(active)
-    assert np.abs(np.asarray(y) - np.asarray(want_y))[on].max(initial=0.0) < tol
+    assert y.shape == want_y.shape and S.shape == want_S.shape
     assert np.abs(np.asarray(S) - np.asarray(want_S))[on].max(initial=0.0) < tol
     assert np.array_equal(np.asarray(S)[~on], np.asarray(args[-1])[~on])
+    by_head = np.abs(np.asarray(y) - np.asarray(want_y)).max(axis=-1)[on]        # [running lane, head]
+    if not cancelling:
+        assert (by_head < tol).all(), np.argwhere(by_head >= tol)[:5]
+        return
+    x, dt, A, B, C, D, state = (np.asarray(a, np.float64) for a in args)
+    R = heads // groups
+    new = (np.exp(dt * A)[..., None, None] * state
+           + (dt[..., None] * x)[..., None] * np.repeat(B, R, axis=1)[:, :, None, :])
+    terms = new * np.repeat(C, R, axis=1)[:, :, None, :]
+    true = terms.sum(-1) + D[:, None] * x
+    size = np.abs(terms).sum(-1).max(axis=-1)[on]                                # [running lane, head]: sum of |terms|
+    assert (size > 100 * np.abs(true).max(axis=-1)[on]).all()                    # they do cancel
+    mine = np.abs(np.asarray(y, np.float64) - true).max(axis=-1)[on]
+    theirs = np.abs(np.asarray(want_y, np.float64) - true).max(axis=-1)[on]
+    eps = np.finfo(np.float32).eps
+    assert (mine < 2 * eps * size).all(), (mine / (eps * size)).max()
+    assert mine.max() < 2 * theirs.max() + tol
 
 
 def test_mamba2_decode_step_writes_the_buffer_it_read():
