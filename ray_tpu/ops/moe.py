@@ -4,8 +4,10 @@ A token goes to ``k`` of ``E`` experts.  ``moe_experts`` sorts the
 ``tokens * k`` (token, expert) pairs by expert, gathers the tokens' rows
 in that order, multiplies each group of rows by its expert's weights in
 ONE grouped matmul over the sorted rows (gate and up side by side, then
-down; for experts without a gate, up then down), scales each row by the
-token's weight for that expert, and sums a token's ``k`` rows back.
+down; for experts without a gate, up then down), gathers the rows back
+ONCE, as the kernel wrote them, a token's j-th pair at row ``j * tokens
++ t``, and adds a token's ``k`` rows, each times the token's weight for
+that expert, in float32 in one fused pass (one rounding at the end).
 Every pair is computed: there is no capacity, no token is dropped, and
 nothing of shape ``[tokens, experts, ...]`` is built (``models/moe.py``'s
 one-hot dispatch does both).
@@ -32,7 +34,7 @@ The chips' parts add up to the whole layer's (the exchange that sums
 them across chips is not here: on one chip the layer runs without it),
 and what every chip computes alike, such as a shared expert, is counted
 once (``tests/test_mistral4.py`` adds four shares up).  With no share
-stated the function is what it was, operation for operation.
+stated nothing of a share is traced: no select, no row behind a group.
 """
 
 from __future__ import annotations
@@ -131,6 +133,40 @@ def grouped_matmul(rows, weights, group_sizes, transposed=False):
     return jax.lax.ragged_dot(rows, weights, group_sizes).astype(rows.dtype)
 
 
+@jax.jit
+def _combine(out, order, top_p, written):
+    """out [T * k, d] the second grouped matmul's rows, sorted by expert as
+    `order` (a permutation of the T * k pairs, token-major) says; top_p
+    [T, k] float32; `written` the rows the kernel wrote (the held pairs),
+    None where every expert is held -> (y [T, d] in out's dtype: a token's
+    k rows, each times its weight, added in float32 and rounded once; the
+    rows that are not all zero, int32).  A jit of its own: a model's
+    layers and a program's chunk buckets trace and lower it once a shape,
+    not once a layer (its k slices are k times the operations; PR 43)."""
+    T, k = top_p.shape
+    with jax.named_scope("moe.combine"):
+        nonzero = (out != 0).any(axis=-1)
+        if written is not None:
+            # rows behind the groups were never written: whatever lies there (NaN
+            # too) is not a result, and is selected out, never multiplied by zero
+            nonzero &= jnp.arange(T * k) < written
+        computed = nonzero.sum(dtype=jnp.int32)
+        back = jnp.zeros(T * k, order.dtype).at[order].set(jnp.arange(T * k, dtype=order.dtype))
+        # ONE gather of the rows as the kernel wrote them (bf16), a token's j-th
+        # pair at row j * T + t: the k blocks of T rows are slices of the major
+        # dim (no copy, no pad whatever k is), each widened, weighed and added
+        # into the running float32 sum in one fused pass over them
+        slot = back.reshape(T, k).T
+        got = out[slot.reshape(k * T)]
+        y = None
+        for j in range(k):
+            part = got[j * T:(j + 1) * T].astype(jnp.float32) * top_p[:, j, None]
+            if written is not None:
+                part = jnp.where((slot[j] < written)[:, None], part, 0.0)
+            y = part if y is None else y + part
+        return y.astype(out.dtype), computed
+
+
 def moe_experts(h, top_p, top_e, wgu, wd, held=None, gated=True):
     """The expert layer of a token batch.
 
@@ -174,12 +210,5 @@ def moe_experts(h, top_p, top_e, wgu, wd, held=None, gated=True):
         else:
             mid = jnp.square(jax.nn.relu(grouped_matmul(rows, wgu, group_sizes, transposed=True)))
         out = grouped_matmul(mid, wd, group_sizes)
-        if held is not None:
-            # rows behind the groups were never written: whatever lies there is not a result
-            out = jnp.where((jnp.arange(T * k) < group_sizes.sum())[:, None], out, 0)
-    with jax.named_scope("moe.combine"):
-        computed = (out != 0).any(axis=-1).sum(dtype=jnp.int32)
-        out = out.astype(jnp.float32) * top_p.reshape(T * k)[order][:, None]
-        back = jnp.zeros(T * k, order.dtype).at[order].set(jnp.arange(T * k, dtype=order.dtype))
-        y = out[back].reshape(T, k, d).sum(axis=1).astype(h.dtype)
+    y, computed = _combine(out, order, top_p, None if held is None else group_sizes.sum())
     return y, jnp.stack([computed, (group_sizes > 0).sum(dtype=jnp.int32), group_sizes.max()])

@@ -11,7 +11,9 @@ preset of CELLS are lowered at their cells' shapes for a described v5e
 the hash of its MLIR printed without locations.  A payload carries the
 file paths and line numbers of every frame, so two checkouts at
 different paths never agree on ``raw`` for a program with a kernel;
-``stripped`` is the same on both sides exactly when the programs are."""
+``stripped`` is the same on both sides exactly when the programs are.
+``scripts/moe_combine_check.py`` imports ``lowered`` to compile and list
+one of these programs by named scope."""
 import base64, hashlib, json, os, re, sys
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 sys.path.insert(0, os.getcwd())
@@ -57,10 +59,12 @@ def strip_payloads(text):
         return "backend_config = <" + json.dumps(cfg, sort_keys=True) + ">"
     return re.sub(r'backend_config = "((?:[^"\\]|\\.)*)"', lambda m: repl(m) if "custom_call_config" in m.group(1) else m.group(0), text)
 
-out = {}
-for preset, (B, block, pool_tokens, max_ctx, T) in CELLS.items():
+def lowered(preset):
+    """{"serve_prefill", "serve_decode"}: the preset's two programs lowered
+    at its cell's shapes; None for a checkout from before the family."""
+    B, block, pool_tokens, max_ctx, T = CELLS[preset]
     if not any(preset in presets for *_, presets in MODEL_FAMILIES):
-        continue  # a checkout from before the family
+        return None
     cfg = LLMConfig(model=preset, dtype="bfloat16").model_config()
     family = model_family(cfg)
     spec = family.cache_spec(cfg, block)
@@ -74,7 +78,7 @@ for preset, (B, block, pool_tokens, max_ctx, T) in CELLS.items():
     held = tuple(range(1, 1 + len(cache)))
     pages = -(-max_ctx // block)
     chunk = [arr((), jnp.int32), arr((pages,), jnp.int32), arr((), jnp.int32)] if spec.reads_cache else []
-    programs = {
+    return {
         "serve_prefill": jax.jit(lambda *a: prefill_step(cfg, 0, block, spec, *a), donate_argnums=held).lower(
             params, *cache, arr((1, T), jnp.int32), arr((T,), jnp.int32), arr((1,), jnp.int32),
             arr((1,), jnp.float32), key, *chunk),
@@ -82,12 +86,19 @@ for preset, (B, block, pool_tokens, max_ctx, T) in CELLS.items():
             params, *cache, arr((B,), jnp.int32), arr((B,), jnp.int32), arr((B, pages), jnp.int32),
             arr((B,), jnp.int32), arr((B,), jnp.float32), key),
     }
-    for name, lowered in programs.items():
-        text = lowered.as_text()
-        kernels = text.count("tpu_custom_call")
-        stripped = strip_payloads(text) if kernels else text
-        out[f"{preset}.{name}"] = {"raw": hashlib.sha256(text.encode()).hexdigest()[:16],
-                                   "stripped": hashlib.sha256(stripped.encode()).hexdigest()[:16],
-                                   "kernels": kernels, "chars": len(text)}
-        print(preset, name, out[f"{preset}.{name}"], flush=True)
-json.dump(out, open(sys.argv[1], "w"), indent=1)
+
+def main(path):
+    out = {}
+    for preset in CELLS:
+        for name, program in (lowered(preset) or {}).items():
+            text = program.as_text()
+            kernels = text.count("tpu_custom_call")
+            stripped = strip_payloads(text) if kernels else text
+            out[f"{preset}.{name}"] = {"raw": hashlib.sha256(text.encode()).hexdigest()[:16],
+                                       "stripped": hashlib.sha256(stripped.encode()).hexdigest()[:16],
+                                       "kernels": kernels, "chars": len(text)}
+            print(preset, name, out[f"{preset}.{name}"], flush=True)
+    json.dump(out, open(path, "w"), indent=1)
+
+if __name__ == "__main__":
+    main(sys.argv[1])

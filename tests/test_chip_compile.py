@@ -558,3 +558,37 @@ def test_granite_hybrid_programs_compile_for_v5e(one_chip, monkeypatch):
     # a block's [256, 256, 128] decays in float32 are 33.5 MB, three of them live; the whole stays under 2 GB
     assert mem.temp_size_in_bytes < 2 * 2**30
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 * 2**30
+
+
+def test_moe_combine_moves_the_pairs_rows_once(one_chip, monkeypatch):
+    """`moe_experts` at Granite 4.0-H's chunk shape (2,048 tokens x 10
+    experts a token = 20,480 pairs of 4,096 columns, 36 held SwiGLU
+    experts of width 768 of 72): the second grouped matmul writes the
+    pairs' rows as 168 MB of bf16, and the combine gathers them ONCE, in
+    bf16, and widens, weighs and sums them in one fused pass.  Before
+    PR 43 it wrote them out three times in float32 (a product, a gather,
+    a `[2048, 10, 4096]` copy whose 10 rows a token are padded to 16),
+    2.8 GB a layer as laid out: no float32 tensor of the pairs' size may be produced
+    more than once, none with k as the second-minor dim may exist, and
+    the temporaries (873 MB then) stay under 560 MB."""
+    from ray_tpu.ops import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    T, k, d, f, E = 2048, 10, 4096, 768, 36
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(lambda *a: moe.moe_experts(*a, held=(0, E))).lower(
+        arr((T, d), jnp.bfloat16), arr((T, k), jnp.float32), arr((T, k), jnp.int32),
+        arr((E, d, 2 * f), jnp.bfloat16), arr((E, f, d), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    # results of the instructions that run, not of what is fused into them
+    wide = [m.group(0) for m in re.finditer(r" = f32\[([0-9,]+)\]", entry)
+            if math.prod(int(x) for x in m.group(1).split(",")) == T * k * d]
+    assert len(wide) <= 1, wide
+    assert f"[{T},{k},{d}]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 560e6

@@ -233,7 +233,8 @@ def test_mla_paged_kernel_reads_the_lanes_pages(dtype):
 # (c) the shares add up
 # ----------------------------------------------------------------------
 def _moe_experts_before(h, top_p, top_e, wgu, wd):
-    """``ops.moe.moe_experts`` as it was before it took a share."""
+    """``ops.moe.moe_experts`` as it was before it took a share (and before
+    PR 43 changed how a token's k rows are put together)."""
     T, d = h.shape
     k = top_e.shape[1]
     E = wgu.shape[0]
@@ -268,12 +269,16 @@ def test_all_experts_held_is_the_function_it_was_bit_for_bit(dtype):
     want_y, want_c = _moe_experts_before(h, top_p, top_e, wgu, wd)
     for held in (None, (0, E)):
         y, c = moe.moe_experts(h, top_p, top_e, wgu, wd, held=held)
-        assert np.array_equal(np.asarray(y, np.float32), np.asarray(want_y, np.float32))
+        got, want = np.asarray(y, np.float32), np.asarray(want_y, np.float32)
+        # bit for bit where the result is rounded to bfloat16; in float32 to a step of the largest addend: the
+        # combine is a jit of its own since PR 43, and a compiled multiply-add rounds once where two operations round twice
+        assert np.array_equal(got, want) if dtype == jnp.bfloat16 else np.abs(got - want).max() <= 2.0**-23 * np.abs(want).max()
         assert np.asarray(c).tolist() == np.asarray(want_c).tolist() == [T * k, E, int(c[2])]
-    # and the program the all-held call traces is the one it traced before
+    # the values are the earlier function's (k = 2: two additions, in either order); the program is not
+    # since PR 43, which gathers the pairs' rows once and never lays them out as [T, k, d]
     before = jax.make_jaxpr(_moe_experts_before)(h, top_p, top_e, wgu, wd)
     after = jax.make_jaxpr(lambda *a: moe.moe_experts(*a))(h, top_p, top_e, wgu, wd)
-    assert str(before) == str(after)
+    assert f"[{T},{k},{d}]" in str(before) and f"[{T},{k},{d}]" not in str(after)
 
 
 @pytest.mark.parametrize("first, count", [(0, 8), (8, 8), (24, 8), (5, 3)])

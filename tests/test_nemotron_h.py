@@ -225,7 +225,7 @@ def _biased(real, y, lp, cfg):
 def _moe_experts_pr36(h, top_p, top_e, wgu, wd, held=None):
     """``ops.moe.moe_experts`` as it stood before this family (PR 36),
     to the letter: what OLMoE (no share) and Mistral-Small-4 (a share)
-    call must still trace."""
+    call must still compute."""
     T, d = h.shape
     k = top_e.shape[1]
     E = wgu.shape[0]
@@ -262,7 +262,7 @@ def _routing(T, E, k, seed):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("held", [None, (8, 8)], ids=["as_olmoe_calls_it", "as_mistral_small_4_calls_it"])
-def test_gated_experts_are_the_function_they_were_bit_for_bit_and_jaxpr_for_jaxpr(held, dtype):
+def test_gated_experts_are_the_function_they_were_bit_for_bit(held, dtype):
     rng = np.random.default_rng(1)
     T, d, f, E, k = 37, 64, 32, 8, 2
     h = jnp.asarray(rng.normal(size=(T, d)), dtype)
@@ -271,11 +271,16 @@ def test_gated_experts_are_the_function_they_were_bit_for_bit_and_jaxpr_for_jaxp
     top_p, top_e = _routing(T, 32 if held else E, k, seed=2)
     want_y, want_c = _moe_experts_pr36(h, top_p, top_e, wgu, wd, held)
     y, c = moe.moe_experts(h, top_p, top_e, wgu, wd, held=held)
-    assert np.array_equal(np.asarray(y, np.float32), np.asarray(want_y, np.float32))
+    got, want = np.asarray(y, np.float32), np.asarray(want_y, np.float32)
+    # bit for bit where the result is rounded to bfloat16; in float32 to a step of the largest addend: the
+    # combine is a jit of its own since PR 43, and a compiled multiply-add rounds once where two operations round twice
+    assert np.array_equal(got, want) if dtype == jnp.bfloat16 else np.abs(got - want).max() <= 2.0**-23 * np.abs(want).max()
     assert np.asarray(c).tolist() == np.asarray(want_c).tolist()
+    # the values are PR 36's (k = 2: two additions, in either order); the program is not since PR 43, which
+    # gathers the pairs' rows once and never lays them out as [T, k, d]
     before = jax.make_jaxpr(lambda *a: _moe_experts_pr36(*a, held))(h, top_p, top_e, wgu, wd)
     after = jax.make_jaxpr(lambda *a: moe.moe_experts(*a, held=held))(h, top_p, top_e, wgu, wd)
-    assert str(before) == str(after)
+    assert f"[{T},{k},{d}]" in str(before) and f"[{T},{k},{d}]" not in str(after)
 
 
 @pytest.mark.parametrize("first, count", [(0, 32), (0, 8), (24, 8), (5, 3)])

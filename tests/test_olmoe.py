@@ -243,6 +243,65 @@ def test_a_pair_the_grouped_matmul_skips_is_not_counted(monkeypatch):
     assert counters.tolist() == [24 * K - 24, 5, 24]
 
 
+def _combine_cases():
+    for k in (4, 6, 8, 10):
+        for held in (None, (3, 5)):
+            for gated in (True, False):
+                # one case whose tokens are not whole sublane tiles of 8
+                T = 21 if (k, held, gated) == (6, (3, 5), False) else 24
+                yield pytest.param(T, k, held, gated, id=f"k{k}-{'share' if held else 'all'}-{'gated' if gated else 'relu2'}-T{T}")
+
+
+@pytest.mark.parametrize("T, k, held, gated", _combine_cases())
+def test_combine_matches_a_loop_over_tokens(monkeypatch, T, k, held, gated):
+    """What `moe_experts` makes of the second grouped matmul's rows, held to
+    a plain loop: a token's row is the float32 sum, pair by pair, of the
+    bf16 row of each HELD pair times its weight, rounded once.  Where a
+    share is held the rows behind the groups are NaN, as a kernel that
+    never wrote them may leave them: none reaches `y` or the count."""
+    of, f = 12, 16
+    E = of if held is None else held[1]
+    rng = np.random.default_rng(k * 7 + T)
+    h = jnp.asarray(rng.standard_normal((T, D)), jnp.bfloat16)
+    top_e = np.stack([rng.permutation(of)[:k] for _ in range(T)]).astype(np.int32)
+    top_p = (0.05 + 0.4 * rng.random((T, k))).astype(np.float32)
+    wgu = jnp.asarray(0.2 * rng.standard_normal((E, D, 2 * f) if gated else (E, f, D)), jnp.bfloat16)
+    wd = jnp.asarray(0.2 * rng.standard_normal((E, f, D)), jnp.bfloat16)
+    returned = []
+
+    def nan_behind_the_groups(rows, weights, group_sizes, transposed=False):
+        if transposed:
+            weights = jnp.swapaxes(weights, 1, 2)
+        out = jax.lax.ragged_dot(rows, weights, group_sizes).astype(rows.dtype)
+        if held is not None:
+            out = jnp.where((jnp.arange(rows.shape[0]) < group_sizes.sum())[:, None], out, jnp.nan)
+        returned.append(out)
+        return out
+
+    monkeypatch.setattr(moe, "grouped_matmul", nan_behind_the_groups)
+    y, counters = moe.moe_experts(h, jnp.asarray(top_p), jnp.asarray(top_e), wgu, wd, held=held, gated=gated)
+
+    first, count = held or (0, of)
+    is_held = (top_e >= first) & (top_e < first + count)
+    key = np.where(is_held, top_e - first, count).reshape(T * k)
+    row_of = np.empty(T * k, np.int64)
+    row_of[np.argsort(key, kind="stable")] = np.arange(T * k)  # where each pair's row lies, sorted by expert
+    rows = np.asarray(returned[-1].astype(jnp.float32))
+    assert held is None or np.isnan(rows[is_held.sum():]).all()
+    want = np.zeros((T, D), np.float32)
+    for t in range(T):
+        for j in range(k):
+            if is_held[t, j]:
+                want[t] += rows[row_of[t * k + j]] * top_p[t, j]
+    want = np.asarray(jnp.asarray(want).astype(jnp.bfloat16).astype(jnp.float32))
+    got = np.asarray(y.astype(jnp.float32))
+    assert y.dtype == jnp.bfloat16 and np.isfinite(got).all()
+    # a fused multiply-add may round a sum's last float32 bit the other way: one bf16 step at most
+    assert (np.abs(got - want) <= np.abs(want) * 2.0**-7).all() and (got == want).mean() > 0.99
+    assert int(counters[0]) == is_held.sum() > 0
+    assert int(counters[1]) == len(np.unique(top_e[is_held]))
+
+
 def test_grouped_matmul_kernel_in_interpret_mode_matches_the_plain_path():
     """The Pallas kernel the chip runs, on the CPU: uneven groups, an
     empty one, a group that straddles two row tiles, rows that do not
