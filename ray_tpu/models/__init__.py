@@ -3,10 +3,10 @@
 Flagship: GPT-2 (ray_tpu.models.gpt2) — the north-star pretraining target,
 trained and served.  Served only: OLMoE (olmoe, sparse experts over
 ops/moe.py).  Also: Llama family (RoPE/GQA/SwiGLU), pipeline-parallel
-GPT-2 (gpt2_pp), ViT, MLP (MNIST), ResNet (CIFAR).
+GPT-2 (gpt2_pp), ViT, MLP (MNIST).
 """
 
-__all__ = ["gpt2", "gpt2_pp", "llama", "mlp", "olmoe", "resnet", "vit"]
+__all__ = ["gpt2", "gpt2_pp", "llama", "mlp", "olmoe", "vit"]
 
 
 def __getattr__(name):

@@ -64,11 +64,10 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.common import CacheSpec
+from ray_tpu.models.common import CacheSpec, rmsnorm
 from ray_tpu.models.nemotron_h import (
-    _K_BLOCK, _attention_chunk, _attention_decode, _counters, _mamba_chunk, _mamba_decode, _state_name, _tail_name,
+    K_BLOCK, attention_chunk, attention_decode, counters, mamba_chunk, mamba_decode, state_name, tail_name,
 )
-from ray_tpu.models.olmoe import _rmsnorm
 
 MAMBA, ATTENTION = "mamba", "attention"
 # the published kind of each of the 40 layers (config.json: layer_types): attention at 5, 15, 25, 35
@@ -189,7 +188,7 @@ def cache_spec(cfg: GraniteHybridConfig, block_size: int) -> CacheSpec:
     state = (cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.ssm_state_size)
     lane_state = []
     for i in range(cfg.layer_types.count(MAMBA)):
-        lane_state += [(_tail_name(i), tail, cfg.dtype), (_state_name(i), state, jnp.float32)]
+        lane_state += [(tail_name(i), tail, cfg.dtype), (state_name(i), state, jnp.float32)]
     return CacheSpec(paged_layers=cfg.layer_types.count(ATTENTION), row_width=cfg.n_kv_head * cfg.head_dim,
                      lane_state=tuple(lane_state), prefill_chunk=cfg.prefill_chunk)
 
@@ -299,7 +298,7 @@ def _experts(y, lp, cfg):
 
 def _logits(x, params, cfg):
     """The tied head: the embedding's held rows, transposed."""
-    y = _rmsnorm(x, params["norm"], cfg.layer_norm_epsilon)
+    y = rmsnorm(x, params["norm"], cfg.layer_norm_epsilon)
     logits = jax.lax.dot_general(y, params["embed"], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
     return logits / cfg.logits_scaling
 
@@ -336,25 +335,25 @@ def prefill_chosen(params, cfg: GraniteHybridConfig, cache, tokens, start, last_
     # the sequence's positions by page, then room for this chunk wherever it starts
     C = table.shape[0] * block_size
     where = (table[:, None] * block_size + jnp.arange(block_size)).reshape(C)
-    room = -(-(C + T) // _K_BLOCK) * _K_BLOCK - C
+    room = -(-(C + T) // K_BLOCK) * K_BLOCK - C
     ks, vs, state, counts, chose = [], [], {}, [], []
     for lp, (kind, i) in zip(params["layers"], _kinds(cfg)):
-        y = _rmsnorm(x, lp["norm1"], cfg.layer_norm_epsilon)
+        y = rmsnorm(x, lp["norm1"], cfg.layer_norm_epsilon)
         if kind == MAMBA:
-            out, after = _mamba_chunk(y, lp, cfg, cache, i, lane, start, n_valid)
+            out, after = mamba_chunk(y, lp, cfg, cache, i, lane, start, n_valid)
             state.update(after)
         else:
-            out, k, v = _attention_chunk(y, lp, cfg, cache, i, where, room, start, n_valid,
+            out, k, v = attention_chunk(y, lp, cfg, cache, i, where, room, start, n_valid,
                                          scale=cfg.attention_multiplier)
             ks.append(k)
             vs.append(v)
         x = x + res * out
-        out, c, top_e = _experts(_rmsnorm(x, lp["norm2"], cfg.layer_norm_epsilon), lp, cfg)
+        out, c, top_e = _experts(rmsnorm(x, lp["norm2"], cfg.layer_norm_epsilon), lp, cfg)
         counts.append(c)
         chose.append(top_e)
         x = x + res * out
     return (_logits(x[last_index], params, cfg), jnp.stack(ks)[:, None], jnp.stack(vs)[:, None], {}, state,
-            _counters(cfg, counts, chunk_tokens=n_valid * cfg.layer_types.count(MAMBA)), jnp.stack(chose))
+            counters(cfg, counts, chunk_tokens=n_valid * cfg.layer_types.count(MAMBA)), jnp.stack(chose))
 
 
 def decode_forward_cached(params, cfg: GraniteHybridConfig, cache, tok, block_tables, lengths,
@@ -377,21 +376,21 @@ def decode_chosen(params, cfg: GraniteHybridConfig, cache, tok, block_tables, le
     x = _embed(tok, params, cfg)
     ks, vs, state, counts, chose = [], [], {}, [], []
     for lp, (kind, i) in zip(params["layers"], _kinds(cfg)):
-        y = _rmsnorm(x, lp["norm1"], cfg.layer_norm_epsilon)
+        y = rmsnorm(x, lp["norm1"], cfg.layer_norm_epsilon)
         if kind == MAMBA:
-            out, after = _mamba_decode(y, lp, cfg, cache, i, runs)
+            out, after = mamba_decode(y, lp, cfg, cache, i, runs)
             state.update(after)
         else:
-            out, k, v = _attention_decode(y, lp, cfg, cache, i, block_tables, lengths, block_size,
+            out, k, v = attention_decode(y, lp, cfg, cache, i, block_tables, lengths, block_size,
                                           scale=cfg.attention_multiplier)
             ks.append(k)
             vs.append(v)
         x = x + res * out
-        out, c, top_e = _experts(_rmsnorm(x, lp["norm2"], cfg.layer_norm_epsilon), lp, cfg)
+        out, c, top_e = _experts(rmsnorm(x, lp["norm2"], cfg.layer_norm_epsilon), lp, cfg)
         counts.append(c)
         chose.append(top_e)
         x = x + res * out
     pages = -(-lengths // block_size) * block_size
     n_a, n_m = cfg.layer_types.count(ATTENTION), cfg.layer_types.count(MAMBA)
     return (_logits(x, params, cfg), jnp.stack(ks), jnp.stack(vs), {}, state,
-            _counters(cfg, counts, lengths.sum() * n_a, pages.sum() * n_a, runs.sum() * n_m), jnp.stack(chose))
+            counters(cfg, counts, lengths.sum() * n_a, pages.sum() * n_a, runs.sum() * n_m), jnp.stack(chose))

@@ -52,8 +52,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.common import CacheSpec
-from ray_tpu.models.olmoe import _rmsnorm, _rope
+from ray_tpu.models.common import CacheSpec, pool_rows, rmsnorm, rope
 from ray_tpu.ops import block_sparse
 from ray_tpu.ops.lightning import lightning_chunk, lightning_slopes, lightning_step
 
@@ -213,15 +212,6 @@ def serving_params(params, cfg: MiniCPMSalaConfig):
     return params
 
 
-def _rows(pool, layer, where):
-    """Rows ``where`` [...] of layer ``layer`` of a pool [L, P, D], taken
-    from the pool addressed as [L * P, D]: ``pool[layer][where]`` makes
-    XLA copy the whole layer out first (277 MB of K a sparse layer a
-    decode step: 3.5 ms of a 23.6 ms step on the chip, PR 30)."""
-    L, P, D = pool.shape
-    return pool.reshape(L * P, D)[layer * P + where]
-
-
 def _residual(cfg):
     return cfg.scale_depth / (cfg.published_layers ** 0.5)
 
@@ -235,8 +225,8 @@ def _sparse_qkvz(y, lp, cfg):
     over each head (no rotation), and the gate z [N, H * hd]."""
     H, G, hd = cfg.n_head, cfg.n_kv_head, cfg.head_dim
     q, k, v, z = jnp.split(y @ lp["wqkvz"], [H * hd, (H + G) * hd, (H + 2 * G) * hd], axis=-1)
-    q = _rmsnorm(q.reshape(-1, G, H // G, hd), lp["w_qn"], cfg.rms_norm_eps)
-    k = _rmsnorm(k.reshape(-1, G, hd), lp["w_kn"], cfg.rms_norm_eps)
+    q = rmsnorm(q.reshape(-1, G, H // G, hd), lp["w_qn"], cfg.rms_norm_eps)
+    k = rmsnorm(k.reshape(-1, G, hd), lp["w_kn"], cfg.rms_norm_eps)
     return q, k, v.reshape(-1, G, hd), z
 
 
@@ -245,24 +235,24 @@ def _lightning_qkvz(y, lp, cfg, pos):
     normed over each head and rotated, and the gate z."""
     H, hd = cfg.lightning_nh, cfg.lightning_head_dim
     q, k, v, z = (t.reshape(-1, H, hd) for t in jnp.split(y @ lp["wqkvz"], 4, axis=-1))
-    q = _rope(_rmsnorm(q, lp["w_qn"], cfg.rms_norm_eps), pos, cfg.rope_theta)
-    k = _rope(_rmsnorm(k, lp["w_kn"], cfg.rms_norm_eps), pos, cfg.rope_theta)
+    q = rope(rmsnorm(q, lp["w_qn"], cfg.rms_norm_eps), pos, cfg.rope_theta)
+    k = rope(rmsnorm(k, lp["w_kn"], cfg.rms_norm_eps), pos, cfg.rope_theta)
     return q, k, v, z.reshape(-1, H * hd)
 
 
 def _lightning_out(o, z, lp, cfg):
-    o = _rmsnorm(o, lp["w_on"], cfg.rms_norm_eps)
+    o = rmsnorm(o, lp["w_on"], cfg.rms_norm_eps)
     return _gated(o.reshape(o.shape[0], -1), z) @ lp["wo"]
 
 
 def _mlp(x, lp, cfg):
     with jax.named_scope("sala.mlp"):
-        gate, up = jnp.split(_rmsnorm(x, lp["w_post"], cfg.rms_norm_eps) @ lp["wgu"], 2, axis=-1)
+        gate, up = jnp.split(rmsnorm(x, lp["w_post"], cfg.rms_norm_eps) @ lp["wgu"], 2, axis=-1)
         return (jax.nn.silu(gate) * up) @ lp["wd"]
 
 
 def _logits(x, params, cfg):
-    x = _rmsnorm(x, params["norm"], cfg.rms_norm_eps)
+    x = rmsnorm(x, params["norm"], cfg.rms_norm_eps)
     return (x @ params["lm_head"]).astype(jnp.float32) / (cfg.d_model / cfg.dim_model_base)
 
 
@@ -296,12 +286,12 @@ def prefill_chunk(params, cfg: MiniCPMSalaConfig, cache, tokens, start, last_ind
     ck_where = jnp.where(whole, table[win // per_page] * per_page + win % per_page, 0)
     ks, vs, cks, states, counts = [], [], [], {}, []
     for lp, (kind, i) in zip(params["layers"], _kinds(cfg)):
-        y = _rmsnorm(x, lp["w_in"], cfg.rms_norm_eps)
+        y = rmsnorm(x, lp["w_in"], cfg.rms_norm_eps)
         if kind == SPARSE:
             q, k, v, z = _sparse_qkvz(y, lp, cfg)
 
             def context(pages, rows):
-                ctx = jnp.concatenate([_rows(pages, i, where).reshape(C, G, hd),
+                ctx = jnp.concatenate([pool_rows(pages, i, where).reshape(C, G, hd),
                                        jnp.zeros((room, G, hd), pages.dtype)])
                 return jax.lax.dynamic_update_slice_in_dim(ctx, rows, start, axis=0)
 
@@ -338,11 +328,11 @@ def _choose(q, k_new, cache, i, tables, t, cfg, block_size):
     per_page = block_size // stride
     n_win = tables.shape[1] * per_page
     rows = (tables[:, :, None] * per_page + jnp.arange(per_page)).reshape(B, n_win)
-    ck = _rows(cache["ck_pages"], i, rows)  # [B, n_win, G * hd]
+    ck = pool_rows(cache["ck_pages"], i, rows)  # [B, n_win, G * hd]
     # the window whose last key is this token's: the mean of its size - 1 cached keys and k_new
     before = jnp.maximum(t[:, None] - (size - 1) + jnp.arange(size - 1), 0)
     before = jnp.take_along_axis(tables, before // block_size, axis=1) * block_size + before % block_size
-    new = (_rows(cache["k_pages"], i, before).astype(jnp.float32).sum(1)
+    new = (pool_rows(cache["k_pages"], i, before).astype(jnp.float32).sum(1)
            + k_new.reshape(B, G * hd).astype(jnp.float32)) / size
     new = new.astype(ck.dtype)
     completes = ((t + 1) % stride == 0) & (t + 1 >= size)
@@ -385,7 +375,7 @@ def decode_chosen(params, cfg: MiniCPMSalaConfig, cache, tok, block_tables, leng
     ks, vs, cks, states, counts, chose = [], [], [], {}, [], []
     ck_where = jnp.zeros(B, jnp.int32)
     for lp, (kind, i) in zip(params["layers"], _kinds(cfg)):
-        y = _rmsnorm(x, lp["w_in"], cfg.rms_norm_eps)
+        y = rmsnorm(x, lp["w_in"], cfg.rms_norm_eps)
         if kind == SPARSE:
             q, k, v, z = _sparse_qkvz(y, lp, cfg)
             with jax.named_scope("sala.select"):

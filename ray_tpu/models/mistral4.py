@@ -71,8 +71,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.common import CacheSpec
-from ray_tpu.models.olmoe import _rmsnorm
+from ray_tpu.models.common import CacheSpec, rmsnorm
 
 # What a forward returns after what it writes, summed over its layers:
 # token-expert pairs the router made (tokens x 4); of those, the pairs
@@ -286,12 +285,12 @@ def _project(h, lp, cfg, pos):
     N, H = h.shape[0], cfg.n_head
     nope, rope, kv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
     with jax.named_scope("mla.project"):
-        c_q = _rmsnorm(h @ lp["wdq"], lp["w_qn"], cfg.rms_norm_eps)
+        c_q = rmsnorm(h @ lp["wdq"], lp["w_qn"], cfg.rms_norm_eps)
         q = (c_q @ lp["wuq"]).reshape(N, H, nope + rope)
         scale = softmax_scale(cfg) * query_scale(pos, cfg)
         q = (q.astype(jnp.float32) * scale[:, None, None]).astype(q.dtype)
         ckr = h @ lp["wdkv"]
-        c = _rmsnorm(ckr[:, :kv], lp["w_kvn"], cfg.rms_norm_eps)
+        c = rmsnorm(ckr[:, :kv], lp["w_kvn"], cfg.rms_norm_eps)
         k_r = _rope(ckr[:, kv:], pos, cfg)
         row = jnp.concatenate([c, k_r, jnp.zeros((N, cfg.latent_row - kv - rope), c.dtype)], axis=-1)
         return q[..., :nope], _rope(q[..., nope:], pos[:, None], cfg), row
@@ -304,7 +303,7 @@ def _experts(x, lp, cfg):
     router chose [T, k]."""
     from ray_tpu.ops.moe import moe_experts
 
-    h = _rmsnorm(x, lp["w_post"], cfg.rms_norm_eps)
+    h = rmsnorm(x, lp["w_post"], cfg.rms_norm_eps)
     with jax.named_scope("moe.route"):
         logits = jnp.dot(h, lp["router"], preferred_element_type=jnp.float32)
         top_p, top_e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.num_experts_per_tok)
@@ -330,7 +329,7 @@ def _counters(cfg, per_layer, attended=0, gathered=0):
 
 
 def _logits(x, params, cfg):
-    return (_rmsnorm(x, params["norm"], cfg.rms_norm_eps) @ params["lm_head"]).astype(jnp.float32)
+    return (rmsnorm(x, params["norm"], cfg.rms_norm_eps) @ params["lm_head"]).astype(jnp.float32)
 
 
 def expanded_attention(q_nope, q_rope, ctx, wukv, start, n_valid, cfg):
@@ -406,9 +405,9 @@ def prefill_chosen(params, cfg: Mistral4Config, cache, tokens, start, last_index
     L, P, W = pool.shape
     rows_out, counts, chose = [], [], []
     for i, lp in enumerate(params["layers"]):
-        h = _rmsnorm(x, lp["w_in"], cfg.rms_norm_eps)
+        h = rmsnorm(x, lp["w_in"], cfg.rms_norm_eps)
         q_nope, q_rope, row = _project(h, lp, cfg, pos)
-        # as minicpm_sala._rows: the pool addressed as [L * P, W], never a layer copied out
+        # as common.pool_rows: the pool addressed as [L * P, W], never a layer copied out
         ctx = jnp.concatenate([pool.reshape(L * P, W)[i * P + where], jnp.zeros((room, W), pool.dtype)])
         ctx = jax.lax.dynamic_update_slice_in_dim(ctx, row, start, axis=0)
         att = expanded_attention(q_nope, q_rope, ctx, lp["wukv"], start, n_valid, cfg)
@@ -453,7 +452,7 @@ def decode_chosen(params, cfg: Mistral4Config, cache, tok, block_tables, lengths
     x = params["embed"][tok]
     rows_out, counts, chose = [], [], []
     for i, lp in enumerate(params["layers"]):
-        h = _rmsnorm(x, lp["w_in"], cfg.rms_norm_eps)
+        h = rmsnorm(x, lp["w_in"], cfg.rms_norm_eps)
         q_nope, q_rope, row = _project(h, lp, cfg, lengths)
         with jax.named_scope("mla.absorb"):
             q = absorbed_queries(q_nope, q_rope, lp["wukv"], cfg)

@@ -46,6 +46,13 @@ float32 forward of the same equations and reads the same tree: ``embed
 ``lm_head [d, V]``.  Weights are seeded random, made on the device a
 layer at a time in the serving dtype.  There is no training path.
 
+The module is also where the two hybrids' shared layers live:
+``models/granite_hybrid.py`` builds its two-part layers from the Mamba-2
+mixer and the chunked grouped-query attention here (``mamba_chunk``,
+``mamba_decode``, ``attention_chunk``, ``attention_decode``, ``K_BLOCK``)
+and names its lanes' state and sums its counters as this family does
+(``tail_name``, ``state_name``, ``counters``).
+
 ASSUMED, because the source's ``config.json`` does not settle it (the
 file ``benchmark/configs/nemotron-3-nano.json`` lists the same): no
 rotation in attention (``rope_theta`` and ``partial_rotary_factor`` are
@@ -66,9 +73,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.common import CacheSpec
-from ray_tpu.models.minicpm_sala import _rows
-from ray_tpu.models.olmoe import _rmsnorm
+from ray_tpu.models.common import CacheSpec, pool_rows, rmsnorm
 from ray_tpu.ops import mamba2
 
 MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
@@ -88,8 +93,8 @@ COUNTERS = ("moe_pairs_routed", "moe_pairs_held", "moe_pairs", "moe_experts_hit"
             "moe_expert_slots", "moe_peak_rows", "moe_layer_programs",
             "kv_positions_attended", "kv_positions_gathered", "ssm_lane_steps", "ssm_chunk_tokens")
 
-_K_BLOCK = 512  # keys a block of the prefill's online softmax
-_Q_BLOCK = 512  # queries a block of it: scores are [32, _Q_BLOCK, _K_BLOCK] float32
+K_BLOCK = 512  # keys a block of the prefill's online softmax
+_Q_BLOCK = 512  # queries a block of it: scores are [32, _Q_BLOCK, K_BLOCK] float32
 _NEG = -1e30
 
 
@@ -179,11 +184,11 @@ def _kinds(cfg):
     return out
 
 
-def _tail_name(i: int) -> str:
+def tail_name(i: int) -> str:
     return f"conv_tail_{i}"
 
 
-def _state_name(i: int) -> str:
+def state_name(i: int) -> str:
     return f"ssm_state_{i}"
 
 
@@ -201,7 +206,7 @@ def cache_spec(cfg: NemotronHConfig, block_size: int) -> CacheSpec:
     state = (cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.ssm_state_size)
     lane_state = []
     for i in range(cfg.pattern.count(MAMBA)):
-        lane_state += [(_tail_name(i), tail, cfg.dtype), (_state_name(i), state, jnp.float32)]
+        lane_state += [(tail_name(i), tail, cfg.dtype), (state_name(i), state, jnp.float32)]
     return CacheSpec(paged_layers=cfg.pattern.count(ATTENTION), row_width=cfg.n_kv_head * cfg.head_dim,
                      lane_state=tuple(lane_state), prefill_chunk=cfg.prefill_chunk)
 
@@ -342,14 +347,14 @@ def chunk_attention(q, ctx_k, ctx_v, start, n_valid, scale=None):
         qb = q[first:first + tq]
         q_pos = start + first + jnp.arange(tq)
         seen = jnp.minimum(start + first + tq, start + n_valid)
-        blocks = jnp.where(first < n_valid, -(-seen // _K_BLOCK), 0)
+        blocks = jnp.where(first < n_valid, -(-seen // K_BLOCK), 0)
 
         def body(j, carry, qb=qb, q_pos=q_pos):
             m, l, acc = carry
-            k = jax.lax.dynamic_slice_in_dim(ctx_k, j * _K_BLOCK, _K_BLOCK)
-            v = jax.lax.dynamic_slice_in_dim(ctx_v, j * _K_BLOCK, _K_BLOCK)
+            k = jax.lax.dynamic_slice_in_dim(ctx_k, j * K_BLOCK, K_BLOCK)
+            v = jax.lax.dynamic_slice_in_dim(ctx_v, j * K_BLOCK, K_BLOCK)
             s = jnp.einsum("tgrd,kgd->grtk", qb, k, preferred_element_type=jnp.float32) * scale
-            k_pos = j * _K_BLOCK + jnp.arange(_K_BLOCK)
+            k_pos = j * K_BLOCK + jnp.arange(K_BLOCK)
             s = jnp.where(k_pos[None, None, None, :] <= q_pos[None, None, :, None], s, _NEG)
             m_new = jnp.maximum(m, s.max(-1))
             alpha = jnp.exp(m - m_new)
@@ -368,37 +373,37 @@ def chunk_attention(q, ctx_k, ctx_v, start, n_valid, scale=None):
     return jnp.concatenate(outs) if len(outs) > 1 else outs[0]
 
 
-def _mamba_chunk(y, lp, cfg, cache, i, lane, start, n_valid):
+def mamba_chunk(y, lp, cfg, cache, i, lane, start, n_valid):
     """Mamba layer i on a chunk's normed tokens y [T, d], from lane
     ``lane``'s tail and state (zeros where ``start`` is 0) -> (out [T,
     d], {the tail's name, the state's name: as they stand after the last
     real position})."""
     z, xbc, dt = _mamba_in(y, lp, cfg)
     with jax.named_scope("mamba.conv"):
-        tail = jnp.where(start == 0, 0, cache[_tail_name(i)][lane])
+        tail = jnp.where(start == 0, 0, cache[tail_name(i)][lane])
         xbc, tail = mamba2.conv_tail(xbc, tail, lp["conv_w"], lp["conv_b"], n_valid)
     with jax.named_scope("mamba.scan"):
         xs, B, Cm = _mamba_split(xbc, cfg)
-        held = jnp.where(start == 0, 0.0, cache[_state_name(i)][lane])
+        held = jnp.where(start == 0, 0.0, cache[state_name(i)][lane])
         o, held = mamba2.ssd_chunk(xs, dt, -jnp.exp(lp["A_log"]), B, Cm, lp["D"], held, n_valid, cfg.chunk_size)
-    return _mamba_out(o, z, lp, cfg), {_tail_name(i): tail, _state_name(i): held}
+    return _mamba_out(o, z, lp, cfg), {tail_name(i): tail, state_name(i): held}
 
 
-def _mamba_decode(y, lp, cfg, cache, i, runs):
+def mamba_decode(y, lp, cfg, cache, i, runs):
     """Mamba layer i on one normed token a lane y [B, d]: the running
     lanes' states updated where they lie, every tail shifted -> (out [B,
     d], {the tail's name, the state's name: the whole new arrays})."""
     z, xbc, dt = _mamba_in(y, lp, cfg)
     with jax.named_scope("mamba.conv"):
-        xbc, tail = mamba2.conv_tail(xbc[:, None], cache[_tail_name(i)], lp["conv_w"], lp["conv_b"])
+        xbc, tail = mamba2.conv_tail(xbc[:, None], cache[tail_name(i)], lp["conv_w"], lp["conv_b"])
     with jax.named_scope("mamba.step"):
         xs, Bm, Cm = _mamba_split(xbc[:, 0], cfg)
         o, state = mamba2.ssm_decode_step(
-            xs, dt, -jnp.exp(lp["A_log"]), Bm, Cm, lp["D"], cache[_state_name(i)], runs)
-    return _mamba_out(o, z, lp, cfg), {_tail_name(i): tail, _state_name(i): state}
+            xs, dt, -jnp.exp(lp["A_log"]), Bm, Cm, lp["D"], cache[state_name(i)], runs)
+    return _mamba_out(o, z, lp, cfg), {tail_name(i): tail, state_name(i): state}
 
 
-def _attention_chunk(y, lp, cfg, cache, i, where, room, start, n_valid, scale=None):
+def attention_chunk(y, lp, cfg, cache, i, where, room, start, n_valid, scale=None):
     """Attention layer i on a chunk's normed tokens y [T, d] over the
     sequence's cached rows (``where`` [C]: its positions' slots by page)
     and the chunk's own, with ``room`` rows of zeros behind them so that
@@ -408,7 +413,7 @@ def _attention_chunk(y, lp, cfg, cache, i, where, room, start, n_valid, scale=No
         q, k, v = _qkv(y, lp, cfg)
 
         def context(pages, rows):
-            ctx = jnp.concatenate([_rows(pages, i, where).reshape(-1, G, hd),
+            ctx = jnp.concatenate([pool_rows(pages, i, where).reshape(-1, G, hd),
                                    jnp.zeros((room, G, hd), pages.dtype)])
             return jax.lax.dynamic_update_slice_in_dim(ctx, rows, start, axis=0)
 
@@ -416,7 +421,7 @@ def _attention_chunk(y, lp, cfg, cache, i, where, room, start, n_valid, scale=No
         return att @ lp["wo"], k, v
 
 
-def _attention_decode(y, lp, cfg, cache, i, block_tables, lengths, block_size, scale=None):
+def attention_decode(y, lp, cfg, cache, i, block_tables, lengths, block_size, scale=None):
     """Attention layer i on one normed token a lane y [B, d] over the
     lanes' pages where they lie -> (out [B, d], k, v [B, G, hd])."""
     from ray_tpu.ops.attention import gqa_paged_decode_attention
@@ -451,7 +456,7 @@ def _experts(y, lp, cfg):
     return shared + out, jnp.concatenate([jnp.stack([routed, here.sum(dtype=jnp.int32)]), c]), top_e
 
 
-def _counters(cfg, per_layer, attended=0, gathered=0, lane_steps=0, chunk_tokens=0):
+def counters(cfg, per_layer, attended=0, gathered=0, lane_steps=0, chunk_tokens=0):
     """COUNTERS of one program from its expert layers' [routed, held,
     computed, hit, peak] (one entry an expert layer) and what its other
     layers read."""
@@ -462,7 +467,7 @@ def _counters(cfg, per_layer, attended=0, gathered=0, lane_steps=0, chunk_tokens
 
 
 def _logits(x, params, cfg):
-    return (_rmsnorm(x, params["norm"], cfg.layer_norm_epsilon) @ params["lm_head"]).astype(jnp.float32)
+    return (rmsnorm(x, params["norm"], cfg.layer_norm_epsilon) @ params["lm_head"]).astype(jnp.float32)
 
 
 # ----------------------------------------------------------------------
@@ -492,15 +497,15 @@ def prefill_chosen(params, cfg: NemotronHConfig, cache, tokens, start, last_inde
     # the sequence's positions by page, then room for this chunk wherever it starts
     C = table.shape[0] * block_size
     where = (table[:, None] * block_size + jnp.arange(block_size)).reshape(C)
-    room = -(-(C + T) // _K_BLOCK) * _K_BLOCK - C
+    room = -(-(C + T) // K_BLOCK) * K_BLOCK - C
     ks, vs, state, counts, chose = [], [], {}, [], []
     for lp, (kind, i) in zip(params["layers"], _kinds(cfg)):
-        y = _rmsnorm(x, lp["norm"], cfg.layer_norm_epsilon)
+        y = rmsnorm(x, lp["norm"], cfg.layer_norm_epsilon)
         if kind == MAMBA:
-            out, after = _mamba_chunk(y, lp, cfg, cache, i, lane, start, n_valid)
+            out, after = mamba_chunk(y, lp, cfg, cache, i, lane, start, n_valid)
             state.update(after)
         elif kind == ATTENTION:
-            out, k, v = _attention_chunk(y, lp, cfg, cache, i, where, room, start, n_valid)
+            out, k, v = attention_chunk(y, lp, cfg, cache, i, where, room, start, n_valid)
             ks.append(k)
             vs.append(v)
         else:
@@ -509,7 +514,7 @@ def prefill_chosen(params, cfg: NemotronHConfig, cache, tokens, start, last_inde
             chose.append(top_e)
         x = x + out
     return (_logits(x[last_index], params, cfg), jnp.stack(ks)[:, None], jnp.stack(vs)[:, None], {}, state,
-            _counters(cfg, counts, chunk_tokens=n_valid * cfg.pattern.count(MAMBA)), jnp.stack(chose))
+            counters(cfg, counts, chunk_tokens=n_valid * cfg.pattern.count(MAMBA)), jnp.stack(chose))
 
 
 def decode_forward_cached(params, cfg: NemotronHConfig, cache, tok, block_tables, lengths,
@@ -531,12 +536,12 @@ def decode_chosen(params, cfg: NemotronHConfig, cache, tok, block_tables, length
     x = params["embed"][tok]
     ks, vs, state, counts, chose = [], [], {}, [], []
     for lp, (kind, i) in zip(params["layers"], _kinds(cfg)):
-        y = _rmsnorm(x, lp["norm"], cfg.layer_norm_epsilon)
+        y = rmsnorm(x, lp["norm"], cfg.layer_norm_epsilon)
         if kind == MAMBA:
-            out, after = _mamba_decode(y, lp, cfg, cache, i, runs)
+            out, after = mamba_decode(y, lp, cfg, cache, i, runs)
             state.update(after)
         elif kind == ATTENTION:
-            out, k, v = _attention_decode(y, lp, cfg, cache, i, block_tables, lengths, block_size)
+            out, k, v = attention_decode(y, lp, cfg, cache, i, block_tables, lengths, block_size)
             ks.append(k)
             vs.append(v)
         else:
@@ -547,4 +552,4 @@ def decode_chosen(params, cfg: NemotronHConfig, cache, tok, block_tables, length
     pages = -(-lengths // block_size) * block_size
     n_a, n_m = cfg.pattern.count(ATTENTION), cfg.pattern.count(MAMBA)
     return (_logits(x, params, cfg), jnp.stack(ks), jnp.stack(vs), {}, state,
-            _counters(cfg, counts, lengths.sum() * n_a, pages.sum() * n_a, runs.sum() * n_m), jnp.stack(chose))
+            counters(cfg, counts, lengths.sum() * n_a, pages.sum() * n_a, runs.sum() * n_m), jnp.stack(chose))

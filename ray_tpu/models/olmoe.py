@@ -30,6 +30,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models.common import CacheSpec, rmsnorm, rope
+
 # What a forward returns after its K and V, summed over its layers
 # (``ops.moe.moe_experts`` counts the first three where they happen):
 # token-expert pairs computed; experts that received at least one row;
@@ -105,8 +107,6 @@ def init_params(cfg: OlmoeConfig, rng=None):
 def cache_spec(cfg: OlmoeConfig, block_size: int):
     """What the family caches (``models/common.py:CacheSpec``): K and V
     of all heads, a position, in every layer; nothing else."""
-    from ray_tpu.models.common import CacheSpec
-
     return CacheSpec(paged_layers=cfg.n_layer, row_width=cfg.d_model)
 
 
@@ -116,32 +116,15 @@ def serving_params(params, cfg: OlmoeConfig):
     return params
 
 
-def _rmsnorm(x, w, eps):
-    xf = x.astype(jnp.float32)
-    out = xf * jax.lax.rsqrt((xf * xf).mean(-1, keepdims=True) + eps)
-    return (out * w.astype(jnp.float32)).astype(x.dtype)
-
-
-def _rope(x, pos, theta):
-    """Rotary embedding, half-split (rotate_half) convention.
-    x [..., H, Dh]; pos [...] int, a token's index in its sequence."""
-    half = x.shape[-1] // 2
-    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = pos.astype(jnp.float32)[..., None, None] * inv  # [..., 1, half]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
-
-
 def _qkv(h, lp, cfg, pos):
     """The layer's queries, keys and values of tokens h [..., d] at
     positions pos [...]: q and k normed over all d columns, split into
     heads, rotated.  -> three [..., H, Dh]."""
     q, k, v = jnp.split(h @ lp["wqkv"], 3, axis=-1)
-    q = _rmsnorm(q, lp["w_qn"], cfg.rms_norm_eps)
-    k = _rmsnorm(k, lp["w_kn"], cfg.rms_norm_eps)
+    q = rmsnorm(q, lp["w_qn"], cfg.rms_norm_eps)
+    k = rmsnorm(k, lp["w_kn"], cfg.rms_norm_eps)
     q, k, v = (t.reshape(*t.shape[:-1], cfg.n_head, -1) for t in (q, k, v))
-    return _rope(q, pos, cfg.rope_theta), _rope(k, pos, cfg.rope_theta), v
+    return rope(q, pos, cfg.rope_theta), rope(k, pos, cfg.rope_theta), v
 
 
 def _experts(x, lp, cfg):
@@ -149,7 +132,7 @@ def _experts(x, lp, cfg):
     to x, and ``moe_experts``' counters."""
     from ray_tpu.ops.moe import moe_experts
 
-    h = _rmsnorm(x, lp["w_post"], cfg.rms_norm_eps)
+    h = rmsnorm(x, lp["w_post"], cfg.rms_norm_eps)
     with jax.named_scope("moe.route"):
         logits = jnp.dot(h, lp["router"], preferred_element_type=jnp.float32)
         top_p, top_e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.num_experts_per_tok)
@@ -175,7 +158,7 @@ def prefill_forward(params, cfg: OlmoeConfig, tokens, last_index=None):
     x = params["embed"][tokens]
     ks, vs, counts = [], [], []
     for lp in params["layers"]:
-        q, k, v = _qkv(_rmsnorm(x, lp["w_in"], cfg.rms_norm_eps), lp, cfg, pos)
+        q, k, v = _qkv(rmsnorm(x, lp["w_in"], cfg.rms_norm_eps), lp, cfg, pos)
         att = reference_causal_attention(q, k, v).reshape(B, T, cfg.d_model)
         x = x + att @ lp["wo"]
         y, c = _experts(x.reshape(B * T, cfg.d_model), lp, cfg)
@@ -184,7 +167,7 @@ def prefill_forward(params, cfg: OlmoeConfig, tokens, last_index=None):
         vs.append(v)
         counts.append(c)
     x_last = x[:, -1] if last_index is None else x[jnp.arange(B), last_index]
-    logits = _rmsnorm(x_last, params["norm"], cfg.rms_norm_eps) @ params["lm_head"]
+    logits = rmsnorm(x_last, params["norm"], cfg.rms_norm_eps) @ params["lm_head"]
     return logits, jnp.stack(ks), jnp.stack(vs), _counters(cfg, counts)
 
 
@@ -199,7 +182,7 @@ def decode_forward_paged(params, cfg: OlmoeConfig, tok, k_pages, v_pages,
     x = params["embed"][tok]
     ks, vs, counts = [], [], []
     for i, lp in enumerate(params["layers"]):
-        q, k, v = _qkv(_rmsnorm(x, lp["w_in"], cfg.rms_norm_eps), lp, cfg, lengths)
+        q, k, v = _qkv(rmsnorm(x, lp["w_in"], cfg.rms_norm_eps), lp, cfg, lengths)
         att = paged_decode_attention(
             q, k, v, k_pages, v_pages, i, block_tables, lengths, block_size=block_size
         )
@@ -209,5 +192,5 @@ def decode_forward_paged(params, cfg: OlmoeConfig, tok, k_pages, v_pages,
         ks.append(k)
         vs.append(v)
         counts.append(c)
-    logits = _rmsnorm(x, params["norm"], cfg.rms_norm_eps) @ params["lm_head"]
+    logits = rmsnorm(x, params["norm"], cfg.rms_norm_eps) @ params["lm_head"]
     return logits, jnp.stack(ks), jnp.stack(vs), _counters(cfg, counts)

@@ -5,8 +5,7 @@ Dosovitskiy et al. 2021).
 TPU-first shape: patch embedding is a single strided Conv (one MXU
 matmul per patch grid), the encoder reuses full-width bf16 matmuls with
 f32 params, and the train step is one jittable function compatible with
-`parallel.create_mesh` dp sharding — the same template as
-models/resnet.py so JaxTrainer drives either interchangeably."""
+`parallel.create_mesh` dp sharding."""
 
 from __future__ import annotations
 

@@ -10,16 +10,16 @@ placement —
   heads inside a shard_map), else the XLA einsum reference (which XLA
   fuses well on its own).
 
-Decode (one fed token a lane) has its own pair: over a contiguous
-context the einsum reference; over a paged KV pool the Pallas kernel
-that reads pages in place on TPU (ops.pallas_paged_attention), else a
-gather of the lane's pages and the same reference; over CHOSEN blocks of
-the pool with grouped queries, the sibling kernel
-(ops.pallas_sparse_paged_attention) or a gather of the chosen pages;
-over a pool of latent rows that all heads share, keys and values both,
-a third (ops.pallas_mla_paged_attention) or a gather of the lane's rows;
-over the whole of a lane's pages with grouped queries, a fourth
-(ops.pallas_gqa_paged_attention) or a gather of them.
+Decode (one fed token a lane): over a contiguous context the einsum
+reference.  Over a paged pool, on a TPU where a kernel takes the shapes,
+one of four Pallas kernels that read the pages where they lie, else a
+gather of the pages and the same reference: every head its own K and V
+(ops.pallas_paged_attention); CHOSEN blocks of the pool with grouped
+queries (ops.pallas_sparse_paged_attention); a pool of latent rows that
+all heads share, keys and values both (ops.pallas_mla_paged_attention);
+the whole of a lane's pages with grouped queries
+(ops.pallas_gqa_paged_attention).  The four share the walk over the
+pages (ops.paged_walk) and differ in the block's arithmetic.
 
 All paths: f32 accumulation, bf16 in/out, static shapes.
 """

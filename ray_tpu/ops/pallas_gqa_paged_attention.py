@@ -1,31 +1,23 @@
 """Decode attention over a paged KV pool of GROUPED-query layers, read
-in place (Pallas TPU): the sibling of ``pallas_paged_attention.py`` for
-layers whose ``R`` query heads share each of ``G`` K/V heads
-(``models/nemotron_h.py``: 32 query heads over 2;
+in place (Pallas TPU): layers whose ``R`` query heads share each of
+``G`` K/V heads (``models/nemotron_h.py``: 32 query heads over 2;
 ``models/granite_hybrid.py``: 32 over 8), every cached position
 attended.
 
-One new token a lane.  The pools stay in HBM, whole: ``[n_layer,
-num_blocks * block_size, G * Dh]``, a position one row of all its K/V
-heads.  The kernel is one program a layer.  It lists the compute blocks
-(``_BLOCK_POSITIONS`` positions) the lanes hold, lanes in order, and
-walks that list once: for each block it copies the pages the lane holds
-there from HBM to VMEM, a page one contiguous ``[block_size, G * Dh]``
-slab of K and one of V, each copied ONCE for the R query heads of every
-group, the next block's copies running behind this block's compute, and
-folds the block into an online softmax of the lane's heads: a group's
-scores are one ``[R, Dh] x [Dh, positions]`` matmul against the group's
-own columns of the slab, its output one ``[R, positions] x [positions,
-Dh]`` matmul (no block-diagonal query: a group's heads read the same
-columns).  A group of fewer query heads than a sublane tile of the
-pool's dtype (4 where bf16 packs 16 rows) is padded to one with heads of
-zeros, whose rows are dropped: a group's rows are then whole tiles, and
-the matmul unit takes a tile's rows at a time whatever they hold.
-Nothing of shape ``[.., B, max_ctx, ..]`` is built and no
-K/V head is repeated for its query heads; a lane of length 0 costs
-nothing.  Operands in the pool's dtype, float32 scores and softmax
-state.  The order of summation depends on positions only, never on
-which physical pages a lane was given.
+One new token a lane.  The pools are ``[n_layer, num_blocks *
+block_size, G * Dh]``, a position one row of all its K/V heads, read by
+the walk of ``ops/paged_walk.py``: the owner a lane, a page one
+contiguous ``[block_size, G * Dh]`` slab of K and one of V, each copied
+ONCE for the R query heads of every group.  Its own is the block's
+arithmetic: a group's scores are one ``[R, Dh] x [Dh, positions]``
+matmul against the group's own columns of the slab, its output one
+``[R, positions] x [positions, Dh]`` matmul (no block-diagonal query: a
+group's heads read the same columns), and no K/V head is repeated for
+its query heads.  A group of fewer query heads than a sublane tile of
+the pool's dtype (4 where bf16 packs 16 rows) is padded to one with
+heads of zeros, whose rows are dropped: a group's rows are then whole
+tiles, and the matmul unit takes a tile's rows at a time whatever they
+hold.  Operands in the pool's dtype, float32 scores and softmax state.
 """
 
 from __future__ import annotations
@@ -38,15 +30,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops import paged_walk
 from ray_tpu.ops.pallas_attention import NEG_INF
 
 # positions a compute block covers: whole pages, two buffers of K and of V in VMEM
 _BLOCK_POSITIONS = 512
-
-
-def _sublanes(dtype) -> int:
-    """Rows of a sublane tile of `dtype` (16 of bf16)."""
-    return 8 * 4 // jnp.dtype(dtype).itemsize
 
 
 def kernel_takes(n_rep, d_head, block_size, dtype) -> bool:
@@ -54,7 +42,7 @@ def kernel_takes(n_rep, d_head, block_size, dtype) -> bool:
     tiles of the pool's dtype (16 rows of bf16), a compute block whole
     pages, a head whole lane tiles.  A group's query heads are whole
     sublane tiles or fewer than one (padded to one)."""
-    sublanes = _sublanes(dtype)
+    sublanes = paged_walk.sublanes(dtype)
     return (
         block_size % sublanes == 0
         and _BLOCK_POSITIONS % block_size == 0
@@ -69,17 +57,11 @@ def _kernel(layer_ref, len_ref, tab_ref,               # scalar prefetch (SMEM)
             item_lane, item_blk, kbuf, vbuf, sems,     # scratch
             qb_ref, m_ref, l_ref, acc_ref,
             *, block_size, groups, scale):
-    bs = block_size
     bk = kbuf.shape[1]           # positions a compute block
-    n = bk // bs                 # pages a compute block
     n_lanes = len_ref.shape[0]
-    pages_per_seq = tab_ref.shape[0] // n_lanes
     H, Dh = qb_ref.shape
     G, R = groups, H // groups
     layer = layer_ref[0]
-
-    def lane_pages(lane):
-        return (len_ref[lane] + (bs - 1)) // bs
 
     def of_groups(f):
         """f(g, the group's rows, the group's columns) for every group,
@@ -87,20 +69,8 @@ def _kernel(layer_ref, len_ref, tab_ref,               # scalar prefetch (SMEM)
         return jnp.concatenate(
             [f(g, slice(g * R, (g + 1) * R), slice(g * Dh, (g + 1) * Dh)) for g in range(G)], axis=0)
 
-    # -- the work list: one item a compute block a lane holds, lanes in
-    # order, so a lane of length 0 costs nothing and the copies of the
-    # next lane's first block run behind the last block of this one
-    def list_lane(b, total):
-        def note(i, _):
-            item_lane[total + i] = b
-            item_blk[total + i] = i
-            return _
-
-        nblk = (lane_pages(b) + (n - 1)) // n
-        jax.lax.fori_loop(0, nblk, note, 0)
-        return total + nblk
-
-    total = jax.lax.fori_loop(0, n_lanes, list_lane, jnp.int32(0))
+    blocks_of, pages_of = paged_walk.lane_blocks(len_ref, tab_ref, item_lane, item_blk, block_size, bk // block_size)
+    total = paged_walk.list_work(n_lanes, blocks_of, item_lane, item_blk)
 
     # a lane with nothing cached attends to its own token alone
     o_ref[...] = jnp.concatenate(
@@ -109,89 +79,53 @@ def _kernel(layer_ref, len_ref, tab_ref,               # scalar prefetch (SMEM)
     # them finite (the pool holds finite values only)
     vbuf[...] = jnp.zeros_like(vbuf)
 
-    def each_page(j, slot, act):
-        """act(K copy, V copy) for every page the lane holds of item j:
-        HBM page -> its rows of buffer ``slot``."""
-        lane = item_lane[j]
-        first = item_blk[j] * n
-
-        def one(p, _):
-            page = tab_ref[lane * pages_per_seq + first + p]
-            src = pl.ds(pl.multiple_of(page * bs, bs), bs)
-            dst = pl.ds(pl.multiple_of(p * bs, bs), bs)
-            act(
-                pltpu.make_async_copy(
-                    k_hbm.at[layer, src, :], kbuf.at[slot, dst, :], sems.at[0, slot]),
-                pltpu.make_async_copy(
-                    v_hbm.at[layer, src, :], vbuf.at[slot, dst, :], sems.at[1, slot]),
-            )
-            return _
-
-        jax.lax.fori_loop(0, jnp.minimum(n, lane_pages(lane) - first), one, 0)
-
-    def start(j, slot):
-        each_page(j, slot, lambda kc, vc: (kc.start(), vc.start()))
-
-    def wait(j, slot):
-        each_page(j, slot, lambda kc, vc: (kc.wait(), vc.wait()))
-
-    @pl.when(total > 0)
-    def _():
-        start(0, 0)
-
-    def body(j, carry):
-        slot = j % 2
+    def item(j):
         lane = item_lane[j]
         blk = item_blk[j]
         length = len_ref[lane]
 
-        @pl.when(j + 1 < total)
-        def _():
-            start(j + 1, 1 - slot)
-
-        @pl.when(blk == 0)
-        def _():
-            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-            l_ref[...] = jnp.zeros_like(l_ref)
-            acc_ref[...] = jnp.zeros_like(acc_ref)
+        def first():
             qb_ref[...] = q_ref[lane].astype(qb_ref.dtype)
 
-        wait(j, slot)
-        k = kbuf[slot]                                       # [bk, G * Dh]
-        v = vbuf[slot]
-        s = of_groups(lambda g, rows, cols: jax.lax.dot_general(
-            qb_ref[rows, :], k[:, cols], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)) * scale     # [H, bk]
-        pos = blk * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < length, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        # a visited block holds at least one position, so m_new is a
-        # real score and a masked one gives exp(-1e30 - m_new) == 0
-        p = jnp.exp(s - m_new)
-        l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
-        pv = of_groups(lambda g, rows, cols: jax.lax.dot_general(
-            p[rows, :].astype(v.dtype), v[:, cols], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32))             # [H, Dh]
-        acc_ref[...] = alpha * acc_ref[...] + pv
-        m_ref[...] = m_new
+        def fold(slot):
+            k = kbuf[slot]                                       # [bk, G * Dh]
+            v = vbuf[slot]
+            s = of_groups(lambda g, rows, cols: jax.lax.dot_general(
+                qb_ref[rows, :], k[:, cols], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)) * scale     # [H, bk]
+            pos = blk * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(pos < length, s, NEG_INF)
+            m_prev = m_ref[...]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            # a visited block holds at least one position, so m_new is a
+            # real score and a masked one gives exp(-1e30 - m_new) == 0
+            p = jnp.exp(s - m_new)
+            l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
+            pv = of_groups(lambda g, rows, cols: jax.lax.dot_general(
+                p[rows, :].astype(v.dtype), v[:, cols], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))             # [H, Dh]
+            acc_ref[...] = alpha * acc_ref[...] + pv
+            m_ref[...] = m_new
 
-        @pl.when((blk + 1) * bk >= length)
-        def _():
-            # fold in the fed token's own key and value, normalise
-            q32 = qb_ref[...].astype(jnp.float32)
-            s_self = of_groups(lambda g, rows, cols: (q32[rows, :] * ks_ref[lane, :, cols]).sum(
-                axis=-1, keepdims=True)) * scale
-            v_self = of_groups(lambda g, rows, cols: jnp.broadcast_to(vs_ref[lane, :, cols], (R, Dh)))
-            m_all = jnp.maximum(m_new, s_self)
-            a = jnp.exp(m_new - m_all)
-            b = jnp.exp(s_self - m_all)
-            o_ref[lane] = (acc_ref[...] * a + b * v_self) / (l_ref[...] * a + b)
+            @pl.when((blk + 1) * bk >= length)
+            def _():
+                # fold in the fed token's own key and value, normalise
+                q32 = qb_ref[...].astype(jnp.float32)
+                s_self = of_groups(lambda g, rows, cols: (q32[rows, :] * ks_ref[lane, :, cols]).sum(
+                    axis=-1, keepdims=True)) * scale
+                v_self = of_groups(lambda g, rows, cols: jnp.broadcast_to(vs_ref[lane, :, cols], (R, Dh)))
+                m_all = jnp.maximum(m_new, s_self)
+                a = jnp.exp(m_new - m_all)
+                b = jnp.exp(s_self - m_all)
+                o_ref[lane] = (acc_ref[...] * a + b * v_self) / (l_ref[...] * a + b)
 
-        return carry
+        return blk, first, fold
 
-    jax.lax.fori_loop(0, total, body, 0)
+    paged_walk.walk(
+        total, item, block_size=block_size, layer=layer, pages_of=pages_of,
+        streams=[(k_hbm, kbuf, lambda slot: sems.at[0, slot]), (v_hbm, vbuf, lambda slot: sems.at[1, slot])],
+        state=(m_ref, l_ref, acc_ref))
 
 
 @functools.partial(jax.jit, static_argnames=("block_size", "scale", "interpret"))
@@ -199,7 +133,7 @@ def gqa_paged_decode_attention_kernel(q, k_self, v_self, k_pages, v_pages, layer
                                       block_size, scale=None, interpret=False):
     """The arguments of ``ops.attention.gqa_paged_decode_attention``.
     ``interpret=True`` runs the same kernel on the CPU for tests."""
-    heads, tile = q.shape[2], _sublanes(k_pages.dtype)
+    heads, tile = q.shape[2], paged_walk.sublanes(k_pages.dtype)
     if heads < tile:  # a small group: heads of zeros up to a tile, dropped at the end
         pad = tile - heads
         out = gqa_paged_decode_attention_kernel(

@@ -1,26 +1,19 @@
 """Decode attention over a paged pool of LATENT rows, read in place
-(Pallas TPU): the sibling of ``pallas_paged_attention.py`` for layers
-that cache one compressed row a position shared by all their heads
-(multi-head latent attention, ``models/mistral4.py``).
+(Pallas TPU): layers that cache one compressed row a position shared by
+all their heads (multi-head latent attention, ``models/mistral4.py``).
 
 One new token a lane; every head of the lane attends the SAME rows:
 keys are a cached row's ``W`` columns, values its first ``v_width``
-columns.  The pool stays in HBM, whole: ``[n_layer, num_blocks *
-block_size, W]``.  The kernel is one program a layer.  It lists the
-compute blocks (``_BLOCK_POSITIONS`` positions) the lanes hold, lanes in
-order, and walks that list once: for each block it copies the pages the
-lane holds there from HBM to VMEM, a page one contiguous ``[block_size,
-W]`` slab, ONE copy a page serving keys and values both, the next
-block's copies running behind this block's compute, and folds the block
-into an online softmax of the lane's heads: scores are one ``[H, W] x
-[W, positions]`` matmul of all heads (no block-diagonal layout: the
-heads share the row), the output one ``[H, positions] x [positions,
-v_width]`` matmul over the same buffer's first columns.  Nothing of
-shape ``[.., B, max_ctx, ..]`` is built and nothing is expanded to a key
-or a value a head; a lane of length 0 costs nothing.  Operands in the
-pool's dtype, float32 scores and softmax state.  The order of summation
-depends on positions only, never on which physical pages a lane was
-given.  The queries arrive with every scale already in them.
+columns.  The pool is ``[n_layer, num_blocks * block_size, W]``, read
+by the walk of ``ops/paged_walk.py``: the owner a lane, a page one
+contiguous ``[block_size, W]`` slab, ONE copy a page serving keys and
+values both (one stream, one buffer).  Its own is the block's
+arithmetic: scores are one ``[H, W] x [W, positions]`` matmul of all
+heads (no block-diagonal layout: the heads share the row), the output
+one ``[H, positions] x [positions, v_width]`` matmul over the same
+buffer's first columns; nothing is expanded to a key or a value a head.
+Operands in the pool's dtype, float32 scores and softmax state.  The
+queries arrive with every scale already in them.
 """
 
 from __future__ import annotations
@@ -33,6 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops import paged_walk
 from ray_tpu.ops.pallas_attention import NEG_INF
 
 # positions a compute block covers: whole pages, two buffers of it in VMEM
@@ -43,9 +37,8 @@ def kernel_takes(n_head, width, v_width, block_size, dtype) -> bool:
     """The shapes the kernel's tiling can take: a page is whole sublane
     tiles of the pool's dtype, a compute block whole pages, a row and
     its value part whole lane tiles, the heads whole sublane tiles."""
-    sublanes = 8 * 4 // jnp.dtype(dtype).itemsize
     return (
-        block_size % sublanes == 0
+        block_size % paged_walk.sublanes(dtype) == 0
         and _BLOCK_POSITIONS % block_size == 0
         and width % 128 == 0
         and v_width % 128 == 0
@@ -60,30 +53,10 @@ def _kernel(layer_ref, len_ref, tab_ref,               # scalar prefetch (SMEM)
             item_lane, item_blk, buf, sems,            # scratch
             qb_ref, m_ref, l_ref, acc_ref,
             *, block_size, v_width):
-    bs = block_size
     bk = buf.shape[1]            # positions a compute block
-    n = bk // bs                 # pages a compute block
-    n_lanes = len_ref.shape[0]
-    pages_per_seq = tab_ref.shape[0] // n_lanes
     layer = layer_ref[0]
-
-    def lane_pages(lane):
-        return (len_ref[lane] + (bs - 1)) // bs
-
-    # -- the work list: one item a compute block a lane holds, lanes in
-    # order, so a lane of length 0 costs nothing and the copies of the
-    # next lane's first block run behind the last block of this one
-    def list_lane(b, total):
-        def note(i, _):
-            item_lane[total + i] = b
-            item_blk[total + i] = i
-            return _
-
-        nblk = (lane_pages(b) + (n - 1)) // n
-        jax.lax.fori_loop(0, nblk, note, 0)
-        return total + nblk
-
-    total = jax.lax.fori_loop(0, n_lanes, list_lane, jnp.int32(0))
+    blocks_of, pages_of = paged_walk.lane_blocks(len_ref, tab_ref, item_lane, item_blk, block_size, bk // block_size)
+    total = paged_walk.list_work(len_ref.shape[0], blocks_of, item_lane, item_blk)
 
     # a lane with nothing cached attends to its own token alone
     o_ref[...] = jnp.broadcast_to(self_ref[:, :, :v_width], o_ref.shape)
@@ -91,82 +64,50 @@ def _kernel(layer_ref, len_ref, tab_ref,               # scalar prefetch (SMEM)
     # them finite (the pool holds finite values only)
     buf[...] = jnp.zeros_like(buf)
 
-    def each_page(j, slot, act):
-        """act(copy) for every page the lane holds of item j: HBM page
-        -> its rows of buffer ``slot``."""
-        lane = item_lane[j]
-        first = item_blk[j] * n
-
-        def one(p, _):
-            page = tab_ref[lane * pages_per_seq + first + p]
-            src = pl.ds(pl.multiple_of(page * bs, bs), bs)
-            dst = pl.ds(pl.multiple_of(p * bs, bs), bs)
-            act(pltpu.make_async_copy(pool_hbm.at[layer, src, :], buf.at[slot, dst, :], sems.at[slot]))
-            return _
-
-        jax.lax.fori_loop(0, jnp.minimum(n, lane_pages(lane) - first), one, 0)
-
-    def start(j, slot):
-        each_page(j, slot, lambda c: c.start())
-
-    def wait(j, slot):
-        each_page(j, slot, lambda c: c.wait())
-
-    @pl.when(total > 0)
-    def _():
-        start(0, 0)
-
-    def body(j, carry):
-        slot = j % 2
+    def item(j):
         lane = item_lane[j]
         blk = item_blk[j]
         length = len_ref[lane]
 
-        @pl.when(j + 1 < total)
-        def _():
-            start(j + 1, 1 - slot)
-
-        @pl.when(blk == 0)
-        def _():
-            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-            l_ref[...] = jnp.zeros_like(l_ref)
-            acc_ref[...] = jnp.zeros_like(acc_ref)
+        def first():
             qb_ref[...] = q_ref[lane].astype(qb_ref.dtype)
 
-        wait(j, slot)
-        rows = buf[slot]                                     # [bk, W]: keys, and in their first columns values
-        s = jax.lax.dot_general(
-            qb_ref[...], rows, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                    # [H, bk]
-        pos = blk * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < length, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        # a visited block holds at least one position, so m_new is a
-        # real score and a masked one gives exp(-1e30 - m_new) == 0
-        p = jnp.exp(s - m_new)
-        l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
-        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
-            p.astype(rows.dtype), rows[:, :v_width], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                    # [H, v_width]
-        m_ref[...] = m_new
+        def fold(slot):
+            rows = buf[slot]                                     # [bk, W]: keys, and in their first columns values
+            s = jax.lax.dot_general(
+                qb_ref[...], rows, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                                                    # [H, bk]
+            pos = blk * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(pos < length, s, NEG_INF)
+            m_prev = m_ref[...]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            # a visited block holds at least one position, so m_new is a
+            # real score and a masked one gives exp(-1e30 - m_new) == 0
+            p = jnp.exp(s - m_new)
+            l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
+            acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+                p.astype(rows.dtype), rows[:, :v_width], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                                                    # [H, v_width]
+            m_ref[...] = m_new
 
-        @pl.when((blk + 1) * bk >= length)
-        def _():
-            # fold in the fed token's own row, normalise
-            own = self_ref[lane]                             # [1, W]
-            s_self = (qb_ref[...].astype(jnp.float32) * own).sum(axis=-1, keepdims=True)
-            m_all = jnp.maximum(m_new, s_self)
-            a = jnp.exp(m_new - m_all)
-            b = jnp.exp(s_self - m_all)
-            o_ref[lane] = (acc_ref[...] * a + b * own[:, :v_width]) / (l_ref[...] * a + b)
+            @pl.when((blk + 1) * bk >= length)
+            def _():
+                # fold in the fed token's own row, normalise
+                own = self_ref[lane]                             # [1, W]
+                s_self = (qb_ref[...].astype(jnp.float32) * own).sum(axis=-1, keepdims=True)
+                m_all = jnp.maximum(m_new, s_self)
+                a = jnp.exp(m_new - m_all)
+                b = jnp.exp(s_self - m_all)
+                o_ref[lane] = (acc_ref[...] * a + b * own[:, :v_width]) / (l_ref[...] * a + b)
 
-        return carry
+        return blk, first, fold
 
-    jax.lax.fori_loop(0, total, body, 0)
+    paged_walk.walk(
+        total, item, block_size=block_size, layer=layer, pages_of=pages_of,
+        streams=[(pool_hbm, buf, lambda slot: sems.at[slot])], state=(m_ref, l_ref, acc_ref))
 
 
 @functools.partial(jax.jit, static_argnames=("block_size", "v_width", "interpret"))

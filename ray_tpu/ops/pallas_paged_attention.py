@@ -8,20 +8,11 @@ names.  ``ops.attention.paged_decode_attention`` picks this kernel on a
 TPU when ``kernel_takes`` the shapes, and computes the same attention
 from the same block tables in plain ``jax.numpy`` elsewhere.
 
-The kernel is one program a layer.  Lengths and block tables arrive as
-scalar prefetch; the pools stay in HBM, whole, the layer an index into
-them (a slice of the pool as an operand would be a copy of the layer).
-It lists the compute blocks (``_BLOCK_POSITIONS`` positions) the lanes
-hold, lanes in order, and walks that list once: for each block it
-copies exactly the pages the lane holds there from HBM to VMEM, a page
-of all heads at a time (one contiguous ``[block_size, n_head * d_head]``
-slab), the next block's copies running behind this block's compute
-(double-buffered, across lanes too), and folds the block into an online
-softmax.  Nothing of shape ``[.., B, max_ctx, ..]`` is built; a lane
-of length 0 costs nothing.  Operands in the pool's dtype, float32
-scores and softmax state, every cached position attended.  The order of
-summation depends on positions only, never on which physical pages a
-lane was given.
+The kernel is one program a layer, and reads the pool by the walk of
+``ops/paged_walk.py``: the owner a lane, a page one contiguous
+``[block_size, n_head * d_head]`` slab of all heads, of K and of V.
+Operands in the pool's dtype, float32 scores and softmax state, every
+cached position attended.  Its own is the block's arithmetic:
 
 All heads of a page sit in VMEM as ``[positions, n_head * d_head]``.
 To keep the per-head products lane-dense the query is laid out
@@ -43,6 +34,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops import paged_walk
 from ray_tpu.ops.pallas_attention import NEG_INF
 
 # positions a compute block covers: one lane-width of scores
@@ -53,9 +45,8 @@ def kernel_takes(n_head, d_head, block_size, dtype) -> bool:
     """The shapes the kernel's tiling can take: a page is whole sublane
     tiles of the pool's dtype (16 rows of bf16, 8 of float32), a compute
     block whole pages, a row of all heads whole lane tiles."""
-    sublanes = 8 * 4 // jnp.dtype(dtype).itemsize
     return (
-        block_size % sublanes == 0
+        block_size % paged_walk.sublanes(dtype) == 0
         and _BLOCK_POSITIONS % block_size == 0
         and (n_head * d_head) % 128 == 0
     )
@@ -70,32 +61,12 @@ def _kernel(layer_ref, len_ref, tab_ref,               # scalar prefetch (SMEM)
             item_lane, item_blk, kbuf, vbuf, sems,     # scratch
             qbd_ref, m_ref, l_ref, acc_ref,
             *, d_head, block_size):
-    bs = block_size
     bk = kbuf.shape[1]           # positions a compute block
-    n = bk // bs                 # pages a compute block
-    n_lanes = len_ref.shape[0]
-    pages_per_seq = tab_ref.shape[0] // n_lanes
     Hp, HD = qbd_ref.shape
     scale = 1.0 / (d_head ** 0.5)
     layer = layer_ref[0]
-
-    def lane_pages(lane):
-        return (len_ref[lane] + (bs - 1)) // bs
-
-    # -- the work list: one item a compute block a lane holds, lanes in
-    # order, so a lane of length 0 costs nothing and the copies of the
-    # next lane's first block run behind the last block of this one
-    def list_lane(b, total):
-        def note(i, _):
-            item_lane[total + i] = b
-            item_blk[total + i] = i
-            return _
-
-        nblk = (lane_pages(b) + (n - 1)) // n
-        jax.lax.fori_loop(0, nblk, note, 0)
-        return total + nblk
-
-    total = jax.lax.fori_loop(0, n_lanes, list_lane, jnp.int32(0))
+    blocks_of, pages_of = paged_walk.lane_blocks(len_ref, tab_ref, item_lane, item_blk, block_size, bk // block_size)
+    total = paged_walk.list_work(len_ref.shape[0], blocks_of, item_lane, item_blk)
 
     # a lane with nothing cached attends to its own token alone
     o_ref[...] = vs_ref[...]
@@ -103,94 +74,58 @@ def _kernel(layer_ref, len_ref, tab_ref,               # scalar prefetch (SMEM)
     # them finite (the pool holds finite values only)
     vbuf[...] = jnp.zeros_like(vbuf)
 
-    def each_page(j, slot, act):
-        """act(K copy, V copy) for every page the lane holds of item j:
-        HBM page -> its rows of buffer ``slot``."""
-        lane = item_lane[j]
-        first = item_blk[j] * n
-
-        def one(p, _):
-            page = tab_ref[lane * pages_per_seq + first + p]
-            src = pl.ds(pl.multiple_of(page * bs, bs), bs)
-            dst = pl.ds(pl.multiple_of(p * bs, bs), bs)
-            act(
-                pltpu.make_async_copy(
-                    k_hbm.at[layer, src, :], kbuf.at[slot, dst, :], sems.at[0, slot]),
-                pltpu.make_async_copy(
-                    v_hbm.at[layer, src, :], vbuf.at[slot, dst, :], sems.at[1, slot]),
-            )
-            return _
-
-        jax.lax.fori_loop(0, jnp.minimum(n, lane_pages(lane) - first), one, 0)
-
-    def start(j, slot):
-        each_page(j, slot, lambda kc, vc: (kc.start(), vc.start()))
-
-    def wait(j, slot):
-        each_page(j, slot, lambda kc, vc: (kc.wait(), vc.wait()))
-
     row_id = jax.lax.broadcasted_iota(jnp.int32, (Hp, HD), 0)
     col_id = jax.lax.broadcasted_iota(jnp.int32, (Hp, HD), 1)
     diag = (col_id >= row_id * d_head) & (col_id < (row_id + 1) * d_head)
 
-    @pl.when(total > 0)
-    def _():
-        start(0, 0)
-
-    def body(j, carry):
-        slot = j % 2
+    def item(j):
         lane = item_lane[j]
         blk = item_blk[j]
         length = len_ref[lane]
 
-        @pl.when(j + 1 < total)
-        def _():
-            start(j + 1, 1 - slot)
-
-        @pl.when(blk == 0)
-        def _():
-            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-            l_ref[...] = jnp.zeros_like(l_ref)
-            acc_ref[...] = jnp.zeros_like(acc_ref)
+        def first():
             qbd_ref[...] = jnp.where(diag, q_ref[lane], 0.0).astype(qbd_ref.dtype)
 
-        wait(j, slot)
-        k = kbuf[slot]
-        v = vbuf[slot]
-        s = jax.lax.dot_general(
-            qbd_ref[...], k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                            # [Hp, bk]
-        pos = blk * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < length, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        # a visited block holds at least one position, so m_new is a
-        # real score and a masked one gives exp(-1e30 - m_new) == 0
-        p = jnp.exp(s - m_new)
-        l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
-        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                    # [Hp, HD]
-        m_ref[...] = m_new
+        def fold(slot):
+            k = kbuf[slot]
+            v = vbuf[slot]
+            s = jax.lax.dot_general(
+                qbd_ref[...], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale                                            # [Hp, bk]
+            pos = blk * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(pos < length, s, NEG_INF)
+            m_prev = m_ref[...]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            # a visited block holds at least one position, so m_new is a
+            # real score and a masked one gives exp(-1e30 - m_new) == 0
+            p = jnp.exp(s - m_new)
+            l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
+            acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                                                    # [Hp, HD]
+            m_ref[...] = m_new
 
-        @pl.when((blk + 1) * bk >= length)
-        def _():
-            # fold in the fed token's own key and value, normalise, keep
-            # each head's own columns
-            q32 = qbd_ref[...].astype(jnp.float32)
-            s_self = (q32 * ks_ref[lane]).sum(axis=-1, keepdims=True) * scale
-            m_all = jnp.maximum(m_new, s_self)
-            a = jnp.exp(m_new - m_all)
-            b = jnp.exp(s_self - m_all)
-            out = (acc_ref[...] * a + b * vs_ref[lane]) / (l_ref[...] * a + b)
-            o_ref[lane] = jnp.where(diag, out, 0.0).sum(axis=0, keepdims=True)
+            @pl.when((blk + 1) * bk >= length)
+            def _():
+                # fold in the fed token's own key and value, normalise, keep
+                # each head's own columns
+                q32 = qbd_ref[...].astype(jnp.float32)
+                s_self = (q32 * ks_ref[lane]).sum(axis=-1, keepdims=True) * scale
+                m_all = jnp.maximum(m_new, s_self)
+                a = jnp.exp(m_new - m_all)
+                b = jnp.exp(s_self - m_all)
+                out = (acc_ref[...] * a + b * vs_ref[lane]) / (l_ref[...] * a + b)
+                o_ref[lane] = jnp.where(diag, out, 0.0).sum(axis=0, keepdims=True)
 
-        return carry
+        return blk, first, fold
 
-    jax.lax.fori_loop(0, total, body, 0)
+    paged_walk.walk(
+        total, item, block_size=block_size, layer=layer, pages_of=pages_of,
+        streams=[(k_hbm, kbuf, lambda slot: sems.at[0, slot]), (v_hbm, vbuf, lambda slot: sems.at[1, slot])],
+        state=(m_ref, l_ref, acc_ref))
 
 
 @functools.partial(jax.jit, static_argnames=("block_size", "interpret"))

@@ -1,24 +1,24 @@
 """Decode attention over CHOSEN blocks of a paged KV pool, grouped
-queries, read in place (Pallas TPU): the sibling of
-``pallas_paged_attention.py`` for layers that read a selection of the
-cache (``ops/block_sparse.py``) and share a K/V head among several
+queries, read in place (Pallas TPU): layers that read a selection of
+the cache (``ops/block_sparse.py``) and share a K/V head among several
 query heads.
 
 One new token a lane; for each (lane, K/V head) pair a list of block
 numbers (``sparse_block`` positions each, whole pages of the engine's
-pool) and how many of them to read.  The pools stay in HBM, whole:
-``[n_layer, num_blocks * block_size, n_kv * d_head]``, a position one
-row of all K/V heads.  The kernel is one program a layer.  It lists the
-compute blocks (``_BLOCK_POSITIONS`` positions: a few chosen blocks) of
-every pair, pairs in order, and walks that list once: for each it copies
-the pages of its chosen blocks, THAT K/V head's columns only, from HBM
-to VMEM (``[block_size, d_head]`` a copy), the next item's copies
-running behind this item's compute, and folds the block into an online
-softmax of the pair's R query heads: scores are one ``[R, d] x [d,
-positions]`` matmul, no block-diagonal layout needed.  Pages that were
+pool) and how many of them to read.  The pools are ``[n_layer,
+num_blocks * block_size, n_kv * d_head]``, a position one row of all
+K/V heads, read by the walk of ``ops/paged_walk.py``: the owner a pair,
+a compute block a few of its chosen blocks, a page's number read from
+the chosen pages and not from a block table, and THAT K/V head's
+columns only copied (``[block_size, d_head]`` a copy).  Pages that were
 not chosen are never touched; a pair that reads nothing costs nothing.
-The fed token's own key and value, not in the pool yet, are folded in
-last.  Operands in the pool's dtype, float32 scores and softmax state.
+Its own is the block's arithmetic: scores are one ``[R, d] x [d,
+positions]`` matmul of the pair's R query heads, no block-diagonal
+layout needed; a column's position comes from its chosen block's
+number, and a chosen block that holds no cached position yet is masked
+out of the probabilities too.  The fed token's own key and value, not
+in the pool yet, are folded in last.  Operands in the pool's dtype,
+float32 scores and softmax state.
 """
 
 from __future__ import annotations
@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops import paged_walk
 from ray_tpu.ops.pallas_attention import NEG_INF
 
 # positions a compute block covers: one lane-width of scores
@@ -42,9 +43,8 @@ def kernel_takes(n_rep, d_head, block_size, sparse_block, dtype) -> bool:
     tiles of the pool's dtype, a chosen block whole pages, a compute
     block whole chosen blocks, a head one lane tile, and the query heads
     of a K/V head whole sublane tiles."""
-    sublanes = 8 * 4 // jnp.dtype(dtype).itemsize
     return (
-        block_size % sublanes == 0
+        block_size % paged_walk.sublanes(dtype) == 0
         and sparse_block % block_size == 0
         and _BLOCK_POSITIONS % sparse_block == 0
         and d_head % 128 == 0
@@ -58,26 +58,16 @@ def _kernel(layer_ref, len_ref, cnt_ref, blk_ref, page_ref,    # scalar prefetch
             item_pair, item_blk, kbuf, vbuf, sems,             # scratch
             m_ref, l_ref, acc_ref,
             *, n_kv, block_size, sparse_block, n_sel):
-    bs, sb = block_size, sparse_block
+    sb = sparse_block
     bk, d = kbuf.shape[1], kbuf.shape[2]
     per = bk // sb               # chosen blocks a compute block
-    ppb = sb // bs               # pages a chosen block
-    n_pairs = cnt_ref.shape[0]
+    ppb = sb // block_size       # pages a chosen block
     scale = 1.0 / (d ** 0.5)
     layer = layer_ref[0]
 
-    # -- the work list: one item a compute block a pair reads
-    def list_pair(pr, total):
-        def note(i, _):
-            item_pair[total + i] = pr
-            item_blk[total + i] = i
-            return _
-
-        n = (cnt_ref[pr] + (per - 1)) // per
-        jax.lax.fori_loop(0, n, note, 0)
-        return total + n
-
-    total = jax.lax.fori_loop(0, n_pairs, list_pair, jnp.int32(0))
+    # the owner a (lane, K/V head) pair: a compute block of the chosen blocks it reads
+    total = paged_walk.list_work(
+        cnt_ref.shape[0], lambda pr: (cnt_ref[pr] + (per - 1)) // per, item_pair, item_blk)
 
     # a pair that reads nothing attends to its own token alone
     o_ref[...] = jnp.broadcast_to(vs_ref[...], o_ref.shape)
@@ -88,91 +78,61 @@ def _kernel(layer_ref, len_ref, cnt_ref, blk_ref, page_ref,    # scalar prefetch
         """Chosen blocks of item j (at most `per`)."""
         return jnp.minimum(per, cnt_ref[item_pair[j]] - item_blk[j] * per)
 
-    def each_page(j, slot, act):
+    def pages_of(j):
+        """The pages of the item's chosen blocks, that K/V head's columns only."""
         pr = item_pair[j]
         base = (pr * n_sel + item_blk[j] * per) * ppb
         cols = pl.ds(pl.multiple_of((pr % n_kv) * d, d), d)
+        return chosen_here(j) * ppb, lambda p: page_ref[base + p], cols
 
-        def one(p, _):
-            page = page_ref[base + p]
-            src = pl.ds(pl.multiple_of(page * bs, bs), bs)
-            dst = pl.ds(pl.multiple_of(p * bs, bs), bs)
-            act(
-                pltpu.make_async_copy(
-                    k_hbm.at[layer, src, cols], kbuf.at[slot, dst, :], sems.at[0, slot]),
-                pltpu.make_async_copy(
-                    v_hbm.at[layer, src, cols], vbuf.at[slot, dst, :], sems.at[1, slot]),
-            )
-            return _
-
-        jax.lax.fori_loop(0, chosen_here(j) * ppb, one, 0)
-
-    def start(j, slot):
-        each_page(j, slot, lambda kc, vc: (kc.start(), vc.start()))
-
-    def wait(j, slot):
-        each_page(j, slot, lambda kc, vc: (kc.wait(), vc.wait()))
-
-    @pl.when(total > 0)
-    def _():
-        start(0, 0)
-
-    def body(j, carry):
-        slot = j % 2
+    def item(j):
         pr = item_pair[j]
         blk = item_blk[j]
         length = len_ref[pr // n_kv]
         here = chosen_here(j)
 
-        @pl.when(j + 1 < total)
-        def _():
-            start(j + 1, 1 - slot)
+        def fold(slot):
+            q = q_ref[pr].astype(kbuf.dtype)                     # [R, d]
+            s = jax.lax.dot_general(
+                q, kbuf[slot], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale                                            # [R, bk]
+            # the position of each column: chosen block `c` of the item sits in columns c*sb ..
+            col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            pos = jnp.full(s.shape, length, jnp.int32)           # a column of no chosen block: masked
+            for c in range(per):
+                number = blk_ref[pr * n_sel + jnp.minimum(blk * per + c, n_sel - 1)]
+                inside = (col >= c * sb) & (col < (c + 1) * sb) & (c < here)
+                pos = jnp.where(inside, number * sb + col - c * sb, pos)
+            mask = pos < length
+            s = jnp.where(mask, s, NEG_INF)
+            m_prev = m_ref[...]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            # a chosen block may hold no cached position yet (the newest): p is masked, not exp(0)
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
+            acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+                p.astype(vbuf.dtype), vbuf[slot], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                                                    # [R, d]
+            m_ref[...] = m_new
 
-        @pl.when(blk == 0)
-        def _():
-            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-            l_ref[...] = jnp.zeros_like(l_ref)
-            acc_ref[...] = jnp.zeros_like(acc_ref)
+            @pl.when((blk + 1) * per >= cnt_ref[pr])
+            def _():
+                # fold in the fed token's own key and value, normalise
+                s_self = (q_ref[pr] * ks_ref[pr]).sum(axis=-1, keepdims=True) * scale
+                m_all = jnp.maximum(m_new, s_self)
+                a = jnp.exp(m_new - m_all)
+                b = jnp.exp(s_self - m_all)
+                o_ref[pr] = (acc_ref[...] * a + b * vs_ref[pr]) / (l_ref[...] * a + b)
 
-        wait(j, slot)
-        q = q_ref[pr].astype(kbuf.dtype)                     # [R, d]
-        s = jax.lax.dot_general(
-            q, kbuf[slot], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                            # [R, bk]
-        # the position of each column: chosen block `c` of the item sits in columns c*sb ..
-        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        pos = jnp.full(s.shape, length, jnp.int32)           # a column of no chosen block: masked
-        for c in range(per):
-            number = blk_ref[pr * n_sel + jnp.minimum(blk * per + c, n_sel - 1)]
-            inside = (col >= c * sb) & (col < (c + 1) * sb) & (c < here)
-            pos = jnp.where(inside, number * sb + col - c * sb, pos)
-        mask = pos < length
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        # a chosen block may hold no cached position yet (the newest): p is masked, not exp(0)
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
-        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
-            p.astype(vbuf.dtype), vbuf[slot], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                    # [R, d]
-        m_ref[...] = m_new
+        return blk, lambda: None, fold
 
-        @pl.when((blk + 1) * per >= cnt_ref[pr])
-        def _():
-            # fold in the fed token's own key and value, normalise
-            s_self = (q_ref[pr] * ks_ref[pr]).sum(axis=-1, keepdims=True) * scale
-            m_all = jnp.maximum(m_new, s_self)
-            a = jnp.exp(m_new - m_all)
-            b = jnp.exp(s_self - m_all)
-            o_ref[pr] = (acc_ref[...] * a + b * vs_ref[pr]) / (l_ref[...] * a + b)
-
-        return carry
-
-    jax.lax.fori_loop(0, total, body, 0)
+    paged_walk.walk(
+        total, item, block_size=block_size, layer=layer, pages_of=pages_of,
+        streams=[(k_hbm, kbuf, lambda slot: sems.at[0, slot]), (v_hbm, vbuf, lambda slot: sems.at[1, slot])],
+        state=(m_ref, l_ref, acc_ref))
 
 
 @functools.partial(jax.jit, static_argnames=("block_size", "sparse_block", "interpret"))

@@ -9,16 +9,6 @@ XLA insert ICI collectives.  How a parameter tree is laid out on a mesh
 and how a step is jitted over it is ``ray_tpu.train.sharding``'s alone.
 """
 
-from ray_tpu.parallel.mesh import (
-    MeshConfig,
-    auto_mesh_shape,
-    create_mesh,
-    local_mesh,
-)
+from ray_tpu.parallel.mesh import MeshConfig, create_mesh
 
-__all__ = [
-    "MeshConfig",
-    "auto_mesh_shape",
-    "create_mesh",
-    "local_mesh",
-]
+__all__ = ["MeshConfig", "create_mesh"]

@@ -20,9 +20,8 @@ torus locality for contiguous slices.
 
 from __future__ import annotations
 
-import math
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, Optional, Sequence
 
 import jax
 import numpy as np
@@ -57,18 +56,6 @@ class MeshConfig:
         return axes
 
 
-def auto_mesh_shape(n_devices: int, tp: Optional[int] = None) -> Dict[str, int]:
-    """Pick a sensible (dp, tp) factorization: tp up to 8 (one ICI ring),
-    rest data parallel."""
-    if tp is None:
-        tp = 1
-        for cand in (8, 4, 2):
-            if n_devices % cand == 0 and cand <= n_devices:
-                tp = cand
-                break
-    return {"dp": n_devices // tp, "tp": tp}
-
-
 def create_mesh(axes: Dict[str, int], devices: Optional[Sequence] = None) -> Mesh:
     devices = list(devices if devices is not None else jax.devices())
     cfg = MeshConfig(dict(axes)).resolve(len(devices))
@@ -76,15 +63,3 @@ def create_mesh(axes: Dict[str, int], devices: Optional[Sequence] = None) -> Mes
     shape = [cfg[n] for n in names]
     arr = np.array(devices[: int(np.prod(shape))]).reshape(shape)
     return Mesh(arr, axis_names=tuple(names))
-
-
-def local_mesh(axes: Optional[Dict[str, int]] = None) -> Mesh:
-    """Mesh over this process' addressable devices (single-host)."""
-    devs = jax.local_devices()
-    if axes is None:
-        axes = auto_mesh_shape(len(devs))
-    return create_mesh(axes, devs)
-
-
-def mesh_axis_size(mesh: Mesh, name: str) -> int:
-    return mesh.shape.get(name, 1)

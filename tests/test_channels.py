@@ -128,7 +128,13 @@ def test_compiled_multi_output_fan():
 
 def test_compiled_channel_throughput_beats_task_path():
     """The channel plane must clearly beat per-call task submission on a
-    tiny-payload pipeline (that's its reason to exist)."""
+    tiny-payload pipeline (that's its reason to exist).
+
+    Both paths are timed in short rounds, turn and turn about, and the
+    best round of each is compared: the other test processes of a run
+    then load both alike, and a round that lost its core to one of them
+    (a polling reader pays a scheduler's quantum for that, a task call
+    little) does not decide.  Alone the ratio reads 14-18."""
 
     @ray_tpu.remote
     class Echo:
@@ -139,22 +145,21 @@ def test_compiled_channel_throughput_beats_task_path():
         dag = Echo.bind().echo.bind(inp)
     compiled = dag.experimental_compile()
     assert compiled._channels_on
-    ray_tpu.get(compiled.execute(0))  # warm
-    n = 200
-    t0 = time.monotonic()
-    for i in range(n):
-        ray_tpu.get(compiled.execute(i))
-    chan_rate = n / (time.monotonic() - t0)
-    compiled.teardown()
-
     actor = Echo.remote()
+    ray_tpu.get(compiled.execute(0))  # warm
     ray_tpu.get(actor.echo.remote(0))
-    t0 = time.monotonic()
-    for i in range(n):
-        ray_tpu.get(actor.echo.remote(i))
-    task_rate = n / (time.monotonic() - t0)
+
+    def round_of(call, n=40):
+        t0 = time.monotonic()
+        for i in range(n):
+            ray_tpu.get(call(i))
+        return time.monotonic() - t0
+
+    rounds = [(round_of(compiled.execute), round_of(actor.echo.remote)) for _ in range(10)]
+    compiled.teardown()
     ray_tpu.kill(actor)
-    assert chan_rate > task_rate * 1.5, (chan_rate, task_rate)
+    chan_s, task_s = (min(times) for times in zip(*rounds))
+    assert chan_s * 1.5 < task_s, rounds
 
 
 def test_compiled_teardown_unblocks_actors():

@@ -155,7 +155,7 @@ def test_a_broken_model_fails_the_tolerance(monkeypatch, broken):
     if broken == "no_gate":
         monkeypatch.setattr(sala, "_gated", lambda o, z: o)
     elif broken == "no_rotation":
-        monkeypatch.setattr(sala, "_rope", lambda x, pos, theta: x)
+        monkeypatch.setattr(sala, "rope", lambda x, pos, theta: x)
     else:  # every chunk starts from zeros: nothing of the earlier chunks reaches the lightning layers
         chunk = sala.lightning_chunk
         monkeypatch.setattr(sala, "lightning_chunk",

@@ -175,3 +175,22 @@ def test_vit_overfits_synthetic_batch():
         first = first if first is not None else float(loss)
     last = float(loss)
     assert last < first * 0.5, (first, last)
+
+
+def test_no_family_imports_a_private_name_of_another():
+    """What two families share has a public name and one home
+    (``models/common.py``; the two hybrids' layers ``models/nemotron_h.py``):
+    no module under ``ray_tpu/models`` imports a name that starts with an
+    underscore from another one there, so an edit to a private helper is
+    an edit to its own family alone."""
+    import ast
+    import pathlib
+
+    import ray_tpu.models
+
+    borrowed = []
+    for path in sorted(pathlib.Path(ray_tpu.models.__file__).parent.glob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.ImportFrom) and (node.module or "").startswith("ray_tpu.models"):
+                borrowed += [f"{path.name}: {node.module}.{a.name}" for a in node.names if a.name.startswith("_")]
+    assert not borrowed, borrowed

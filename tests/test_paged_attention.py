@@ -7,6 +7,10 @@ the tiny model against the Flax model's full forward.
 Both paths on the CPU: the plain ``jax.numpy`` path the engine takes off
 the TPU, and the Pallas kernel in interpret mode.  Nothing here is a
 time; tests/test_chip_compile.py compiles the kernel for the chip.
+
+And the page walk the four paged-decode kernels share
+(``ops/paged_walk.py``): its work list alone, and each kernel at every
+edge of the walk against its own gather in ``ops/attention.py``.
 """
 
 import jax
@@ -14,8 +18,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.ops import attention
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops import attention, paged_walk
+from ray_tpu.ops import pallas_gqa_paged_attention as gqa_kernel
+from ray_tpu.ops import pallas_mla_paged_attention as mla_kernel
 from ray_tpu.ops import pallas_paged_attention as kernel
+from ray_tpu.ops import pallas_sparse_paged_attention as sparse_kernel
 
 BS = 16  # block size: whole sublane tiles of bf16 and float32
 PAGES = 20  # pages a lane may hold: max_ctx 320, three compute blocks
@@ -197,3 +207,119 @@ def test_decode_forward_paged_matches_full_forward(path, monkeypatch):
 ])
 def test_kernel_takes_only_shapes_its_tiling_can(n_head, d_head, block_size, dtype, takes):
     assert kernel.kernel_takes(n_head, d_head, block_size, dtype) is takes
+
+
+# ----------------------------------------------------------------------
+# the walk the four kernels share
+# ----------------------------------------------------------------------
+def test_work_list_is_the_owners_blocks_in_order_and_skips_the_empty():
+    counts = jnp.asarray([0, 3, 0, 1], jnp.int32)
+
+    def body(cnt_ref, owner_ref, blk_ref, total_ref):
+        total_ref[0] = paged_walk.list_work(cnt_ref.shape[0], lambda owner: cnt_ref[owner], owner_ref, blk_ref)
+
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    owner, blk, total = pl.pallas_call(
+        body, in_specs=[smem], out_specs=[smem] * 3, interpret=True,
+        out_shape=[jax.ShapeDtypeStruct((n,), jnp.int32) for n in (4, 4, 1)])(counts)
+    assert int(total[0]) == 4
+    assert owner.tolist() == [1, 1, 1, 3] and blk.tolist() == [0, 1, 2, 0]
+
+
+def _dense_walk(rand, pools, tables, lens):
+    q, k, v = (rand(len(lens), 4, 32) for _ in range(3))
+    return (kernel.paged_decode_attention_kernel, attention.paged_decode_attention,
+            (q, k, v, *pools, 1, tables, lens), dict(block_size=BS))
+
+
+def _gqa_walk(rand, pools, tables, lens):
+    B = len(lens)
+    return (gqa_kernel.gqa_paged_decode_attention_kernel, attention.gqa_paged_decode_attention,
+            (rand(B, 2, 8, 16), rand(B, 2, 16), rand(B, 2, 16), *pools, 1, tables, lens), dict(block_size=BS))
+
+
+def _mla_walk(rand, pools, tables, lens):
+    B = len(lens)
+    return (mla_kernel.mla_paged_decode_attention_kernel, attention.mla_paged_decode_attention,
+            (0.2 * rand(B, 8, 64), rand(B, 64), *pools, 1, tables, lens), dict(block_size=BS, v_width=32))
+
+
+def _sparse_walk(rand, pools, tables, lens):
+    """Every (lane, K/V head) pair chooses all the blocks its lane holds, in
+    order, in a list with room for more: the pair's compute blocks are
+    then the lane's."""
+    B, G, sb = len(lens), 2, 64
+    counts = -(-np.asarray(lens) // sb)
+    S = tables.shape[1] * BS // sb
+    blocks = np.where(np.arange(S) < counts[:, None], np.arange(S), 0)[:, None, :].repeat(G, 1).astype(np.int32)
+    ppb = sb // BS
+    pages = np.take_along_axis(np.asarray(tables)[:, None, :].repeat(G, 1),
+                               (blocks[..., None] * ppb + np.arange(ppb)).reshape(B, G, -1), axis=2)
+    return (sparse_kernel.sparse_paged_decode_attention_kernel, attention.sparse_paged_decode_attention,
+            (rand(B, G, 8, 32), rand(B, G, 32), rand(B, G, 32), *pools, 1, jnp.asarray(pages), jnp.asarray(blocks),
+             jnp.asarray(counts[:, None].repeat(G, 1), jnp.int32), lens), dict(block_size=BS, sparse_block=sb))
+
+
+# kernel -> (its module, the columns of a pool row, pools, its entry and gather with their arguments)
+WALKS = {
+    "dense": (kernel, 128, 2, _dense_walk),
+    "gqa": (gqa_kernel, 32, 2, _gqa_walk),
+    "mla": (mla_kernel, 64, 1, _mla_walk),
+    "sparse": (sparse_kernel, 64, 2, _sparse_walk),
+}
+
+
+def _walk_edges(what, bk):
+    """Cached positions a lane, by what of the walk they hit; bk the
+    positions of the kernel's compute block."""
+    if what == "one_item":   # the first start and no other
+        return [0, 7, 0]
+    if what == "no_item":    # nothing starts, nothing is walked
+        return [0, 0]
+    # a lane that ends on a compute block's edge, a lane of length 0
+    # between two that run, a lane one position past the edge
+    return [bk, 0, bk + 1, 5]
+
+
+def _assigned(lens, width, n_pools, per_lane, seed):
+    """Pools of noise and block tables that hand the lanes' pages out
+    in an order of ``seed``'s; the rows the lanes hold are the same for
+    every seed, everything else in the pools differs."""
+    held = np.random.default_rng(0).standard_normal((n_pools, LAYERS, len(lens) * per_lane * BS, width))
+    rng = np.random.default_rng(seed)
+    tables = np.zeros((len(lens), per_lane), np.int32)
+    pools = rng.standard_normal((n_pools, LAYERS, (len(lens) * per_lane + 1) * BS, width))
+    order = rng.permutation(np.arange(1, len(lens) * per_lane + 1))
+    for b, n in enumerate(lens):
+        for j in range(-(-n // BS)):
+            page = tables[b, j] = order[b * per_lane + j]
+            logical = (b * per_lane + j) * BS
+            pools[:, :, page * BS:(page + 1) * BS] = held[:, :, logical:logical + BS]
+    return [jnp.asarray(p, jnp.float32) for p in pools], jnp.asarray(tables)
+
+
+@pytest.mark.parametrize("what", ["every_edge", "one_item", "no_item", "other_pages"])
+@pytest.mark.parametrize("name", list(WALKS))
+def test_every_kernel_walks_its_pages_as_its_gather_reads_them(name, what):
+    """Each kernel on the shared walk (interpret mode, float32) at the
+    walk's edges against its own gather reference; and the same lanes
+    on other physical pages, in pools that differ everywhere else, to
+    the bit: the order of summation depends on positions only."""
+    module, width, n_pools, flavour = WALKS[name]
+    lens = _walk_edges(what, module._BLOCK_POSITIONS)
+    per_lane = (-(-max(lens) // 64) + 1) * 64 // BS  # whole chosen blocks of the sparse kernel, one to spare
+
+    def run(seed, interpret):
+        pools, tables = _assigned(lens, width, n_pools, per_lane, seed)
+        rng = np.random.default_rng(7)
+        entry, gather, args, sizes = flavour(
+            lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32), pools, tables,
+            jnp.asarray(lens, jnp.int32))
+        return np.asarray(entry(*args, **sizes, interpret=True) if interpret else gather(*args, **sizes))
+
+    got = run(1, True)
+    assert np.isfinite(got).all()
+    if what == "other_pages":
+        np.testing.assert_array_equal(got, run(2, True))
+    else:
+        np.testing.assert_allclose(got, run(1, False), atol=2e-5, rtol=2e-5)

@@ -304,7 +304,10 @@ def test_engine_preempt_parity_exact_for_known_victim():
     """Single-lane variant pins WHICH request is preempted, so the
     parity assertion is exact: same prompt, same seed, one run preempted
     (possibly repeatedly), one not — byte-identical token streams."""
-    prompt, n = [6, 2, 8], 30
+    # an answer long enough that the hog still runs when each wave has
+    # starved (at 30 tokens of 0.7 ms a step it had ended first in one of
+    # the driver's runs); a wave that finds it ended fails here, by name
+    prompt, n = [6, 2, 8], 120
 
     async def run(preempt: bool):
         eng = LLMEngine(_tiny(max_batch_size=1, preempt_wait_s=0.005,
@@ -313,19 +316,27 @@ def test_engine_preempt_parity_exact_for_known_victim():
         hog = await eng.add_request(prompt, max_tokens=n,
                                     tenant="a", slo="batch")
         vics = []
+
+        async def wave(token, preempted):
+            """One interactive request, added while the hog holds the
+            lane; returns once the hog has been preempted for it."""
+            assert not hog.finish_reason, "drill is vacuous: the hog ended before this wave"
+            vics.append(await eng.add_request([token], max_tokens=3,
+                                              tenant="b", slo="interactive"))
+            while hog.preemptions < preempted:
+                assert not hog.finish_reason, f"drill is vacuous: the hog ended before preemption {preempted}"
+                await asyncio.sleep(0.005)
+
         if preempt:
             while hog.generated < 4:
                 await asyncio.sleep(0.01)
-            vics.append(await eng.add_request([5], max_tokens=3,
-                                              tenant="b", slo="interactive"))
-            while not vics[0].finish_reason:
-                await asyncio.sleep(0.01)
+            await wave(5, 1)
             # a second wave AFTER the hog is back in the lane forces a
             # second preemption through the fold-resume path
-            while hog.slot < 0 and not hog.finish_reason:
+            while hog.slot < 0:
+                assert not hog.finish_reason
                 await asyncio.sleep(0.005)
-            vics.append(await eng.add_request([7], max_tokens=3,
-                                              tenant="b", slo="interactive"))
+            await wave(7, 2)
         await asyncio.gather(*[_drain(r) for r in [hog] + vics])
         st = eng.stats()
         report = eng.bm.leak_report()

@@ -149,21 +149,38 @@ def test_async_actor_streaming_method():
     ray_tpu.kill(a)
 
 
-def test_data_streaming_read_first_block_early():
+def test_data_streaming_read_first_block_early(tmp_path):
     """A Data read over a slow multi-block datasource delivers the first
-    batch before the datasource finishes producing."""
+    batch before the datasource finishes producing: before it has
+    produced its third block, which it holds back until the consumer
+    says it has the first batch (or, for a read that does not stream,
+    until it has waited long enough for the test to fail and not hang).
+    What is compared is what the source did, not a number of seconds."""
     import numpy as np
     import pyarrow as pa
 
     from ray_tpu.data.block import BlockMetadata
     from ray_tpu.data.datasource import Datasource, ReadTask
 
+    produced, got_first = str(tmp_path / "produced"), str(tmp_path / "got_first")
+
     class SlowSource(Datasource):
         def get_read_tasks(self, parallelism):
             def read():
+                import os
+
+                from ray_tpu._private import retry
+
                 for i in range(4):
-                    if i:
-                        time.sleep(0.8)  # later "files" are slow
+                    if i == 2:  # later "files" are slow
+                        wait = retry.POLL.start(deadline_s=30)
+                        while not os.path.exists(got_first):
+                            delay = wait.next_delay()
+                            if delay is None:
+                                break
+                            time.sleep(delay)
+                    with open(produced, "a") as f:
+                        f.write(f"{i}\n")
                     yield pa.table({"x": np.full(10, i)})
 
             meta = BlockMetadata(num_rows=40, size_bytes=40 * 8, schema=None, input_files=None)
@@ -172,11 +189,10 @@ def test_data_streaming_read_first_block_early():
     import ray_tpu.data as rd
 
     ds = rd.read_datasource(SlowSource(), parallelism=1)
-    t0 = time.monotonic()
     it = ds.iter_batches(batch_size=10)
     first = next(iter(it))
-    dt = time.monotonic() - t0
+    with open(produced) as f:
+        blocks = len(f.read().split())
+    open(got_first, "w").close()
     assert len(first["x"]) == 10
-    # Producer needs ~2.4 s for the remaining blocks; the first one must
-    # arrive well before that.
-    assert dt < 1.5, f"first batch took {dt:.2f}s — read is not streaming"
+    assert blocks < 3, f"the source had produced {blocks} blocks before the first batch came — read is not streaming"
