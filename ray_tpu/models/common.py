@@ -5,7 +5,7 @@ both build on — the models differ in architecture, not in how they
 train — of the sampler the serving engine applies to whatever
 family's logits (``sample_logits``), of what a served family states of
 its cache (``CacheSpec``), and of the layer helpers more than one served
-family uses (``rmsnorm``, ``rope``, ``pool_rows``): a family imports
+family uses (``rmsnorm``, ``rope``, ``yarn_inv_freq``, ``pool_rows``): a family imports
 from here, never a private name of another family.  Laying a state out
 on a mesh and jitting the step over it is ``ray_tpu.train.sharding``'s
 (``GspmdPlan.shard_init`` / ``jit_train_step``).
@@ -13,6 +13,7 @@ on a mesh and jitting the step over it is ``ray_tpu.train.sharding``'s
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Callable
 
@@ -120,13 +121,44 @@ def rmsnorm(x, w, eps):
     return (out * w.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope(x, pos, theta):
+def yarn_inv_freq(theta, dim, factor, original_context, beta_fast, beta_slow) -> list:
+    """The rotary frequency of each of a head's ``dim / 2`` pairs under
+    YaRN, as Python floats: below the ramp (fast pairs) the plain
+    ``theta^(-2i/dim)``, above it that over ``factor``, between them a
+    linear blend, the ramp's ends where a pair turns ``beta_fast`` and
+    ``beta_slow`` times in ``original_context`` positions (the published
+    ``yarn_find_correction_range`` / ``linear_ramp``)."""
+
+    def correction_dim(rotations):
+        return dim * math.log(original_context / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    out = []
+    for i in range(dim // 2):
+        freq = theta ** (-2.0 * i / dim)
+        ramp = min(max((i - low) / (high - low), 0.0), 1.0)  # 0: kept; 1: interpolated
+        out.append(freq / factor * ramp + freq * (1.0 - ramp))
+    return out
+
+
+def rope(x, pos, theta, inv_freq=None, factor=None):
     """Rotary embedding, half-split (rotate_half) convention.
-    x [..., H, Dh]; pos [...] int, a token's index in its sequence."""
+    x [..., H, Dh]; pos [...] int, a token's index in its sequence.
+    `inv_freq` (Dh / 2 Python floats, ``yarn_inv_freq``'s) replaces the
+    plain ``theta^(-2i/Dh)``; `factor` multiplies cos and sin (YaRN's
+    ``attention_factor``: a score then carries its square)."""
     half = x.shape[-1] // 2
-    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if inv_freq is None:
+        inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    else:
+        inv = jnp.asarray(inv_freq, jnp.float32)
     ang = pos.astype(jnp.float32)[..., None, None] * inv  # [..., 1, half]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if factor is not None:
+        cos, sin = cos * factor, sin * factor
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
 

@@ -71,7 +71,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.common import CacheSpec, rmsnorm
+from ray_tpu.models.common import CacheSpec, rmsnorm, yarn_inv_freq as common_yarn_inv_freq
 
 # What a forward returns after what it writes, summed over its layers:
 # token-expert pairs the router made (tokens x 4); of those, the pairs
@@ -165,26 +165,11 @@ class Mistral4Config:
 # ----------------------------------------------------------------------
 def yarn_inv_freq(cfg: Mistral4Config) -> list:
     """The rotary frequency of each of the ``qk_rope_head_dim / 2``
-    pairs, as Python floats: below the ramp (fast pairs) the plain
-    ``theta^(-2i/d)``, above it that over ``factor``, between them a
-    linear blend, the ramp's ends where a pair turns ``beta_fast`` and
-    ``beta_slow`` times in the original context (DeepSeek-V3's
-    published ``yarn_find_correction_range`` / ``linear_ramp``)."""
-    d, base, orig = cfg.qk_rope_head_dim, cfg.rope_theta, cfg.original_max_position_embeddings
-
-    def correction_dim(rotations):
-        return d * math.log(orig / (rotations * 2 * math.pi)) / (2 * math.log(base))
-
-    low = max(math.floor(correction_dim(cfg.beta_fast)), 0)
-    high = min(math.ceil(correction_dim(cfg.beta_slow)), d - 1)
-    if low == high:
-        high += 0.001
-    out = []
-    for i in range(d // 2):
-        freq = base ** (-2.0 * i / d)
-        ramp = min(max((i - low) / (high - low), 0.0), 1.0)  # 0: kept; 1: interpolated
-        out.append(freq / cfg.rope_factor * ramp + freq * (1.0 - ramp))
-    return out
+    pairs, as Python floats: ``common.yarn_inv_freq`` (DeepSeek-V3's
+    published ``yarn_find_correction_range`` / ``linear_ramp``) at this
+    configuration's numbers."""
+    return common_yarn_inv_freq(cfg.rope_theta, cfg.qk_rope_head_dim, cfg.rope_factor,
+                                cfg.original_max_position_embeddings, cfg.beta_fast, cfg.beta_slow)
 
 
 def _yarn_mscale(factor: float, mscale: float) -> float:

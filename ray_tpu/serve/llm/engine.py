@@ -703,6 +703,9 @@ class LLMEngine:
             "kv_leak_report": self.bm.leak_report(),
             "state_slots_in_use": self.bm.state_slots_in_use,
             "state_slots_total": self.bm.state_slots,
+            # what the lanes own whatever their sequences' lengths
+            # (``CacheSpec.lane_state``: scan states, tails, rings)
+            "state_bytes_held": self._state_bytes,
             "tokens_per_s": round(self._tokens_per_s(), 2),
             "total_tokens": self._total_tokens,
             "shed_total": self._shed_total,

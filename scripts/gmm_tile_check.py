@@ -50,6 +50,8 @@ FAMILIES = {
                 "moe_intermediate_size", "n_routed_experts", 128, True),
     "nemotron": ("nemotron-3-nano", "nemotron-3-nano.serve.reason-backlog",
                  "moe_intermediate_size", "n_routed_experts", 128, False),
+    "mellum": ("mellum2-12b-a2.5b", "mellum2-12b-a2.5b.serve.mixed-backlog",
+               "moe_intermediate_size", "num_experts", 64, True),
 }
 CLAMP_K, CLAMP_N = 2048, 1024
 

@@ -22,7 +22,7 @@ if REPO not in sys.path:
 pytest.register_assert_rewrite(
     "benchmark.tests.test_benchmark", "benchmark.tests.test_olmoe_cell",
     "benchmark.tests.test_mistral_small_4_cell", "benchmark.tests.test_nemotron_3_nano_cell",
-    "benchmark.tests.test_granite_4_0_h_small_cell",
+    "benchmark.tests.test_granite_4_0_h_small_cell", "benchmark.tests.test_mellum2_cell",
 )
 
 from benchmark.tests.test_benchmark import (  # noqa: E402,F401
@@ -68,6 +68,16 @@ from benchmark.tests.test_granite_4_0_h_small_cell import (  # noqa: E402,F401
     test_runner_fails_at_once_where_the_program_has_no_such_family as test_granite_runner_fails_at_once_where_the_program_has_no_such_family,
     test_the_cell_s_metrics_are_the_entries_of_benchmark_json as test_the_granite_cell_s_metrics_are_the_entries_of_benchmark_json,
     test_the_configuration_is_the_catalog_s_but_for_what_it_says_is_reduced as test_the_granite_configuration_is_the_catalog_s_but_for_what_it_says_is_reduced,
+)
+from benchmark.tests.test_mellum2_cell import (  # noqa: E402,F401
+    test_decode_kernel_experts_and_chunk_work_and_their_shares_by_hand,
+    test_the_stated_cache_is_three_paged_layers_and_two_rings_a_lane,
+)
+from benchmark.tests.test_mellum2_cell import (  # noqa: E402,F401
+    test_runner_fails_at_once_where_the_program_has_no_such_family as test_mellum_runner_fails_at_once_where_the_program_has_no_such_family,
+    test_the_cell_s_metrics_are_the_entries_of_benchmark_json as test_the_mellum_cell_s_metrics_are_the_entries_of_benchmark_json,
+    test_the_configuration_is_the_catalog_s_but_for_what_it_says_is_reduced as test_the_mellum_configuration_is_the_catalog_s_but_for_what_it_says_is_reduced,
+    test_the_cut_s_arithmetic_reckoned_again as test_the_mellum_cut_s_arithmetic_reckoned_again,
 )
 
 
