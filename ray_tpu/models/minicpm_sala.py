@@ -65,9 +65,11 @@ PUBLISHED_MIXERS = (_S, _L, _L, _L, _L, _L, _L, _L, _L, _S, _L, _L, _L, _L, _L, 
 # What a forward returns after what it writes, summed over its sparse
 # layers: blocks read and blocks there were, over every (token or lane,
 # K/V head); and of a decode step, the positions its kernel copied (the
-# chosen blocks, whole) and those among them a lane holds.
+# chosen blocks, whole) and those among them a lane holds; and of a chunk,
+# the tiles of queries that scored blocks (none does under ``dense_len``)
+# and the tiles there were.
 COUNTERS = ("sparse_blocks_kept", "sparse_blocks_cached", "kv_positions_attended",
-            "kv_positions_gathered")
+            "kv_positions_gathered", "sparse_tiles_selected", "sparse_tiles")
 
 
 @dataclass(frozen=True)
@@ -297,12 +299,12 @@ def prefill_chunk(params, cfg: MiniCPMSalaConfig, cache, tokens, start, last_ind
 
             ctx_k, ctx_v = context(cache["k_pages"], k), context(cache["v_pages"], v)
             ck = block_sparse.compress_keys(ctx_k, cfg)
-            o, kept, cached = block_sparse.sparse_chunk_attention(q, ctx_k, ctx_v, ck, start, n_valid, cfg)
+            o, counted = block_sparse.sparse_chunk_attention(q, ctx_k, ctx_v, ck, start, n_valid, cfg)
             out = _gated(o.reshape(T, -1), z) @ lp["wo"]
             ks.append(k)
             vs.append(v)
             cks.append(ck[win].reshape(n_win, G * hd).astype(cfg.dtype))
-            counts.append(jnp.stack([kept, cached]))
+            counts.append(counted)
         else:
             with jax.named_scope("sala.lightning"):
                 q, k, v, z = _lightning_qkvz(y, lp, cfg, pos)
@@ -312,7 +314,8 @@ def prefill_chunk(params, cfg: MiniCPMSalaConfig, cache, tokens, start, last_ind
                 out = _lightning_out(o, z, lp, cfg)
         x = x + (_residual(cfg) * out).astype(x.dtype)
         x = x + (_residual(cfg) * _mlp(x, lp, cfg)).astype(x.dtype)
-    counters = jnp.concatenate([jnp.stack(counts).sum(0).astype(jnp.int32), jnp.zeros(2, jnp.int32)])
+    blocks, tiles = jnp.split(jnp.stack(counts).sum(0), 2)
+    counters = jnp.concatenate([blocks, jnp.zeros(2, jnp.int32), tiles])
     return (_logits(x[last_index], params, cfg), jnp.stack(ks)[:, None], jnp.stack(vs)[:, None],
             {"ck_pages": (jnp.stack(cks), ck_where)}, states, counters)
 
@@ -393,7 +396,7 @@ def decode_chosen(params, cfg: MiniCPMSalaConfig, cache, tok, block_tables, leng
             chosen = jnp.arange(blocks.shape[-1]) < n[..., None]
             held = jnp.clip(lengths[:, None, None] - blocks * sb, 0, sb)
             counts.append(jnp.stack([n.sum(), (block_sparse.blocks_cached(lengths, cfg) * (lengths > 0)).sum()
-                                     * cfg.n_kv_head, jnp.where(chosen, held, 0).sum(), n.sum() * sb]))
+                                     * cfg.n_kv_head, jnp.where(chosen, held, 0).sum(), n.sum() * sb, 0, 0]))
         else:
             with jax.named_scope("sala.lightning"):
                 q, k, v, z = _lightning_qkvz(y, lp, cfg, lengths)

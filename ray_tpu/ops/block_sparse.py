@@ -10,14 +10,16 @@ and those that hold the last ``window_size`` positions are always kept;
 the ``topk`` highest blocks are read.  A query at a position under
 ``dense_len`` reads every position before it.
 
-``sp`` below is anything with those seven attributes (the model's
-config).  ``block_keep`` gives a mask (prefill, and the reference of the
+``sp`` below is anything hashable with those seven attributes (the
+model's config; ``sparse_chunk_attention`` takes it as a static argument).  ``block_keep`` gives a mask (prefill, and the reference of the
 tests), ``block_choice`` a list of block numbers (decode: the kernel
 walks it), ``sparse_chunk_attention`` is a prompt chunk's attention over
 the sequence's cached positions under that mask.  Plain ``jax.numpy``.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +29,8 @@ NEG = -1e30
 # before them _K_BLOCK at a time under an online softmax: the scores of
 # 4,096 queries over 32k keys would be 17 GB
 _Q_TILE, _K_BLOCK = 512, 1024
+# and a tile's selection scores the compressed keys _W_BLOCK windows at a time
+_W_BLOCK = 512
 
 
 def compress_keys(k, sp):
@@ -42,14 +46,24 @@ def compress_keys(k, sp):
     return total / sp.kernel_size
 
 
-def _block_scores(p, n_blocks, sp):
-    """p [..., NW] a window's probability (0 where it is not valid) ->
-    [..., n_blocks]: the largest of the windows that overlap a block."""
+def _whole(j, t, sp):
+    """Windows j [NW] against queries at t [...] -> [..., 1, 1, NW]: the
+    window's last position lies at or before the query's."""
+    return (sp.kernel_stride * j + sp.kernel_size - 1 <= t[..., None])[..., None, None, :]
+
+
+def _block_scores(p, n_blocks, sp, before=None):
+    """p [..., NW] a window's probability (0 where it is not valid), the
+    first of them the first window that starts in a block; before
+    [..., lead] the windows ahead of them (none: p starts at window 0)
+    -> [..., n_blocks]: the largest of the windows that overlap a block."""
     r = sp.block_size // sp.kernel_stride  # windows that start in a block
     lead = sp.kernel_size // sp.kernel_stride - 1  # and those that start before it and reach in
+    assert r * sp.kernel_stride == sp.block_size and lead <= r, (sp.block_size, sp.kernel_size, sp.kernel_stride)
+    if before is None:
+        before = jnp.zeros((*p.shape[:-1], lead), p.dtype)
     pad = lead + r * (n_blocks + 1) - p.shape[-1]
-    p = jnp.concatenate([jnp.zeros((*p.shape[:-1], lead), p.dtype), p,
-                         jnp.zeros((*p.shape[:-1], max(pad, 0)), p.dtype)], axis=-1)
+    p = jnp.concatenate([before, p, jnp.zeros((*p.shape[:-1], max(pad, 0)), p.dtype)], axis=-1)
     # block b reads padded windows r*b .. r*b + r + lead - 1
     first = p[..., :r * n_blocks].reshape(*p.shape[:-1], n_blocks, r).max(-1)
     if not lead:
@@ -63,14 +77,17 @@ def block_scores(s, t, n_blocks, sp):
     against the compressed keys (already scaled); t [...] the query's
     position.  -> [..., G, n_blocks] float32: infinity for blocks always
     kept, -1 for blocks that start after t, else the selection score."""
-    NW = s.shape[-1]
-    j = jnp.arange(NW)
-    valid = (sp.kernel_stride * j + sp.kernel_size - 1 <= t[..., None])[..., None, None, :]
+    valid = _whole(jnp.arange(s.shape[-1]), t, sp)
     s = jnp.where(valid, s, NEG)
     e = jnp.where(valid, jnp.exp(s - s.max(-1, keepdims=True)), 0.0)
     p = (e / jnp.maximum(e.sum(-1, keepdims=True), 1e-30)).sum(-2)  # [..., G, NW]
-    score = _block_scores(p, n_blocks, sp)
-    b = jnp.arange(n_blocks)
+    return _forced(_block_scores(p, n_blocks, sp), t, sp)
+
+
+def _forced(score, t, sp):
+    """score [..., G, n_blocks] by the windows' probabilities -> the same
+    with infinity for blocks always kept and -1 for blocks that start after t."""
+    b = jnp.arange(score.shape[-1])
     tt = t[..., None, None]
     recent = jnp.maximum(tt - (sp.window_size - 1), 0) // sp.block_size
     always = (b < sp.init_blocks) | (b >= recent) | (tt < sp.dense_len)
@@ -86,10 +103,18 @@ def blocks_cached(t, sp):
 def block_keep(score, sp):
     """score [..., G, n_blocks] of ``block_scores`` -> the mask of the
     blocks the query reads: the ``topk`` highest (all that there are
-    under ``dense_len``)."""
-    k = min(sp.topk, score.shape[-1])
-    kth = jax.lax.top_k(score, k)[0][..., -1:]
-    return (score >= kth) & (score >= 0)
+    under ``dense_len``), ties with the last of them included.  The mask
+    is ``score >= lax.top_k(score, k)[0][..., -1:]`` exactly: the k-th
+    highest value is one of the scores, whatever finds it.  It comes
+    from a sort along the FIRST axis of a two-dimensional copy, so that
+    the v5e compiler sorts with a query a lane: it sorts an array along
+    whatever axis its producer left minor, and along the minor one a
+    tile's 1,024 rows of 592 take 2.5 ms for 0.2 (PR 46;
+    ``scripts/sparse_chunk_check.py listing`` fails where the compiled
+    sort has another layout)."""
+    n = score.shape[-1]
+    kth = jnp.sort(score.reshape(-1, n).T, axis=0)[n - min(sp.topk, n)].reshape(score.shape[:-1])
+    return (score >= kth[..., None]) & (score >= 0)
 
 
 def block_choice(score, t, n_sel, sp):
@@ -107,6 +132,7 @@ def max_choice(sp):
     return max(sp.topk, -(-sp.dense_len // sp.block_size))
 
 
+@functools.partial(jax.jit, static_argnames="sp")
 def sparse_chunk_attention(q, ctx_k, ctx_v, ck, start, n_valid, sp):
     """A prompt chunk's queries over the sequence's positions so far.
 
@@ -114,25 +140,80 @@ def sparse_chunk_attention(q, ctx_k, ctx_v, ck, start, n_valid, sp):
     ``n_valid`` real); ctx_k, ctx_v [C, G, d] the sequence's keys and
     values by position, this chunk's among them (C a multiple of
     ``_K_BLOCK``, at least ``start + T``); ck [C / stride, G, d] the
-    compressed keys.  -> (o [T, G, R, d] in q's dtype, blocks kept,
-    blocks cached: both summed over real queries and K/V heads)."""
+    compressed keys.  -> (o [T, G, R, d] in q's dtype, int32 [4]: blocks
+    kept and blocks cached, both summed over real queries and K/V heads;
+    tiles that selected and tiles, of those with a real query).
+
+    A tile's selection does what its queries' positions call for: it
+    scores the windows that are whole at its last query, ``_W_BLOCK`` at
+    a time (a pass for the softmax's normaliser, a pass for the blocks'
+    scores), and none at all where that query lies under ``dense_len``:
+    there every block before a query is read whatever it scores.  A jit
+    of its own: a model's sparse layers trace and lower it once a
+    chunk's shape, not once a layer."""
     T, G, R, d = q.shape
     C = ctx_k.shape[0]
     tq = min(T, _Q_TILE)
     kb, per = _K_BLOCK, _K_BLOCK // sp.block_size
     n_blocks = C // sp.block_size
     scale = 1.0 / (d ** 0.5)
-    ck = ck.astype(q.dtype)
+    wb = min(_W_BLOCK, ck.shape[0])
+    per_w, lead = sp.block_size // sp.kernel_stride, sp.kernel_size // sp.kernel_stride - 1
+    assert wb % per_w == 0, (wb, per_w)  # a step's windows are whole blocks'
+    w_steps = -(-ck.shape[0] // wb)
+    ck = jnp.concatenate([ck.astype(q.dtype), jnp.zeros((w_steps * wb - ck.shape[0], G, d), q.dtype)])
 
     def tile(xs):
         qt, off = xs  # [tq, G, R, d]
         t = start + off + jnp.arange(tq)
+        last = start + off + tq - 1
+
+        def windows(i):
+            """A step's windows against the tile's queries: (s [tq, G, R,
+            wb], NEG where a window is not whole at a query; which are)."""
+            s = jnp.einsum("tgrd,jgd->tgrj", qt, jax.lax.dynamic_slice_in_dim(ck, i * wb, wb),
+                           preferred_element_type=jnp.float32) * scale
+            valid = _whole(i * wb + jnp.arange(wb), t, sp)
+            return jnp.where(valid, s, NEG), valid
+
+        def select():
+            # windows 0 .. whole - 1 are whole at the tile's last query; the step that holds
+            # window `whole` is taken too: its first block may reach back into the one before
+            whole = (last + 1 - sp.kernel_size) // sp.kernel_stride + 1
+            steps = jnp.minimum(whole // wb + 1, w_steps)
+
+            def normaliser(i, carry):
+                top, total = carry
+                s, valid = windows(i)
+                new = jnp.maximum(top, s.max(-1, keepdims=True))
+                e = jnp.where(valid, jnp.exp(s - new), 0.0)
+                return new, jnp.exp(top - new) * total + e.sum(-1, keepdims=True)
+
+            top, total = jax.lax.fori_loop(0, steps, normaliser, (
+                jnp.full((tq, G, R, 1), NEG, jnp.float32), jnp.zeros((tq, G, R, 1), jnp.float32)))
+            total = jnp.maximum(total, 1e-30)
+
+            def blocks(i, carry):
+                score, before = carry  # before [tq, G, lead]: the last windows of the step before
+                s, valid = windows(i)
+                p = (jnp.where(valid, jnp.exp(s - top), 0.0) / total).sum(-2)  # [tq, G, wb]
+                step = _block_scores(p, wb // per_w, sp, before)
+                return jax.lax.dynamic_update_slice_in_dim(score, step, i * (wb // per_w), axis=2), p[..., wb - lead:]
+
+            score, _ = jax.lax.fori_loop(0, steps, blocks, (
+                jnp.zeros((tq, G, w_steps * (wb // per_w)), jnp.float32), jnp.zeros((tq, G, lead), jnp.float32)))
+            return block_keep(_forced(score[..., :n_blocks], t, sp), sp)
+
+        def all_before():
+            return jnp.broadcast_to((sp.block_size * jnp.arange(n_blocks) <= t[:, None])[:, None], (tq, G, n_blocks))
+
+        selects = last >= sp.dense_len
         with jax.named_scope("sala.select"):
-            s = jnp.einsum("tgrd,jgd->tgrj", qt, ck, preferred_element_type=jnp.float32) * scale
-            keep = block_keep(block_scores(s, t, n_blocks, sp), sp)  # [tq, G, n_blocks]
+            keep = jax.lax.cond(selects, select, all_before)  # [tq, G, n_blocks]
         real = off + jnp.arange(tq) < n_valid
         kept = jnp.where(real[:, None], keep.sum(-1), 0).sum()
         cached = G * jnp.where(real, blocks_cached(t, sp), 0).sum()
+        counts = jnp.stack([kept, cached, selects & real[0], real[0]]).astype(jnp.int32)
 
         def block(i, carry):
             m, l, acc = carry
@@ -154,8 +235,8 @@ def sparse_chunk_attention(q, ctx_k, ctx_v, ck, start, n_valid, sp):
         # key blocks up to the tile's last query; a query always reads itself, so l > 0
         with jax.named_scope("sala.sparse"):
             _, l, acc = jax.lax.fori_loop(0, (start + off + tq + kb - 1) // kb, block, init)
-        return (acc / l).astype(q.dtype), kept, cached
+        return (acc / l).astype(q.dtype), counts
 
     offs = jnp.arange(T // tq, dtype=jnp.int32) * tq
-    o, kept, cached = jax.lax.map(tile, (q.reshape(T // tq, tq, G, R, d), offs))
-    return o.reshape(T, G, R, d), kept.sum(), cached.sum()
+    o, counts = jax.lax.map(tile, (q.reshape(T // tq, tq, G, R, d), offs))
+    return o.reshape(T, G, R, d), counts.sum(0)

@@ -20,6 +20,7 @@ block (``test_decode_selection_...``, ``test_prefill_selection_...``).
 """
 
 import asyncio
+import functools
 import os
 import sys
 
@@ -208,6 +209,116 @@ def test_prefill_selection_is_the_reference_s_token_by_token(t):
         assert sorted(np.asarray(blocks)[g, :int(counts[g])].tolist()) == np.flatnonzero(np.asarray(got)[g]).tolist()
 
 
+@functools.partial(jax.jit, static_argnames="sp")
+def _parent_chunk_attention(q, ctx_k, ctx_v, ck, start, n_valid, sp):
+    """``sparse_chunk_attention`` as it stood before a tile's selection
+    went by its position: every tile scores every window the context has
+    room for, in one piece.  -> (o, blocks kept, blocks cached)."""
+    T, G, R, d = q.shape
+    tq, kb = min(T, block_sparse._Q_TILE), block_sparse._K_BLOCK
+    per, n_blocks, scale = kb // sp.block_size, ctx_k.shape[0] // sp.block_size, 1.0 / (d ** 0.5)
+    ck = ck.astype(q.dtype)
+
+    def tile(xs):
+        qt, off = xs
+        t = start + off + jnp.arange(tq)
+        s = jnp.einsum("tgrd,jgd->tgrj", qt, ck, preferred_element_type=jnp.float32) * scale
+        keep = block_sparse.block_keep(block_sparse.block_scores(s, t, n_blocks, sp), sp)
+        real = off + jnp.arange(tq) < n_valid
+        kept = jnp.where(real[:, None], keep.sum(-1), 0).sum()
+        cached = G * jnp.where(real, block_sparse.blocks_cached(t, sp), 0).sum()
+
+        def block(i, carry):
+            m, l, acc = carry
+            kblk = jax.lax.dynamic_slice_in_dim(ctx_k, i * kb, kb)
+            vblk = jax.lax.dynamic_slice_in_dim(ctx_v, i * kb, kb)
+            sc = jnp.einsum("tgrd,kgd->tgrk", qt, kblk, preferred_element_type=jnp.float32) * scale
+            mask = jnp.repeat(jax.lax.dynamic_slice_in_dim(keep, i * per, per, axis=2), sp.block_size, axis=2)
+            mask = (mask & (i * kb + jnp.arange(kb) <= t[:, None, None]))[:, :, None, :]
+            sc = jnp.where(mask, sc, block_sparse.NEG)
+            m_new = jnp.maximum(m, sc.max(-1, keepdims=True))
+            p = jnp.where(mask, jnp.exp(sc - m_new), 0.0)
+            alpha = jnp.exp(m - m_new)
+            acc = alpha * acc + jnp.einsum("tgrk,kgd->tgrd", p.astype(vblk.dtype), vblk,
+                                           preferred_element_type=jnp.float32)
+            return m_new, alpha * l + p.sum(-1, keepdims=True), acc
+
+        init = (jnp.full((tq, G, R, 1), block_sparse.NEG, jnp.float32), jnp.zeros((tq, G, R, 1), jnp.float32),
+                jnp.zeros((tq, G, R, d), jnp.float32))
+        _, l, acc = jax.lax.fori_loop(0, (start + off + tq + kb - 1) // kb, block, init)
+        return (acc / l).astype(q.dtype), kept, cached
+
+    offs = jnp.arange(T // tq, dtype=jnp.int32) * tq
+    o, kept, cached = jax.lax.map(tile, (q.reshape(T // tq, tq, G, R, d), offs))
+    return o.reshape(T, G, R, d), kept.sum(), cached.sum()
+
+
+@pytest.mark.parametrize("start, selected", [
+    (0, 0),  # the tile wholly under dense_len: nothing is scored
+    (32, 1),  # queries 32..95 straddle it
+    (4032, 1),  # wholly past it, half the context's room not reached: two of its four steps of windows
+])
+def test_a_chunk_s_tile_selects_by_its_position_and_reads_what_it_always_read(start, selected):
+    """The prefill's attention against its form before this selection,
+    kept above.  The output depends on the selection through the mask
+    alone, so the same output to the bit is the same blocks kept, block
+    for block (the normaliser's sum, taken a step of windows at a time,
+    may differ in its last bits: on these seeds no block changes sides)."""
+    rng = np.random.default_rng(start)
+    T, G, R, d, C = 64, 2, 2, 16, 8192
+    n_valid = 50
+    q = jnp.asarray(rng.normal(size=(T, G, R, d)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(C, G, d)), jnp.float32) for _ in range(2))
+    ck = block_sparse.compress_keys(k, CFG)
+    assert ck.shape[0] == 4 * block_sparse._W_BLOCK
+    o, counts = block_sparse.sparse_chunk_attention(q, k, v, ck, jnp.int32(start), jnp.int32(n_valid), CFG)
+    want_o, kept, cached = _parent_chunk_attention(q, k, v, ck, jnp.int32(start), jnp.int32(n_valid), CFG)
+    assert np.array_equal(np.asarray(o), np.asarray(want_o))
+    assert counts.tolist() == [int(kept), int(cached), selected, 1]
+    assert (kept < cached) == (start > 0)  # under dense_len every block before a query is read
+
+
+@pytest.mark.parametrize("kernel, stride, block", [(32, 16, 64), (16, 16, 64), (48, 16, 32), (64, 16, 64)])
+def test_blocks_score_the_same_a_step_of_windows_at_a_time(kernel, stride, block):
+    """``_block_scores``, the one window-to-block maximum: a chunk's tile
+    hands it a step of windows with the last of the step before, decode
+    every window in one piece; a maximum is exact in any order, so the
+    two agree to the bit whether 0, 1, 2 or 3 windows reach in."""
+    import types
+
+    sp = types.SimpleNamespace(kernel_size=kernel, kernel_stride=stride, block_size=block)
+    r, lead, n_blocks, step = block // stride, kernel // stride - 1, 24, 8
+    p = jnp.asarray(np.random.default_rng(kernel + block).random((3, 2, r * n_blocks)), jnp.float32)
+    whole = block_sparse._block_scores(p, n_blocks, sp)
+    before, parts = jnp.zeros((3, 2, lead), jnp.float32), []
+    for i in range(0, n_blocks, step):
+        part = p[..., r * i:r * (i + step)]
+        parts.append(block_sparse._block_scores(part, step, sp, before))
+        before = part[..., r * step - lead:]
+    assert np.array_equal(np.asarray(whole), np.concatenate(parts, -1))
+    for b in (0, 5, n_blocks - 1):  # and it is the maximum over the windows that overlap the block
+        lo = max(r * b - lead, 0)
+        assert np.array_equal(np.asarray(whole[..., b]), np.asarray(p[..., lo:r * (b + 1)].max(-1)))
+
+
+@pytest.mark.parametrize("n_blocks", [2, 16, 48])
+def test_block_keep_is_top_k_s_mask(n_blocks):
+    """``block_keep`` finds the k-th highest score by a sort of a
+    transposed copy; the mask is ``lax.top_k``'s to the bit, ties, the
+    forced blocks' infinity and the unreached blocks' -1 included."""
+    rng = np.random.default_rng(n_blocks)
+    score = np.round(rng.random((7, 2, n_blocks)), 1).astype(np.float32)  # tenths: ties at the k-th
+    score[:, :, :CFG.init_blocks] = np.inf
+    score[:, :, n_blocks - n_blocks // 4:] = -1.0
+    score[0] = -1.0
+    score[0, :, 0] = np.inf
+    kth = jax.lax.top_k(jnp.asarray(score), min(CFG.topk, n_blocks))[0][..., -1:]
+    want = (score >= np.asarray(kth)) & (score >= 0)
+    assert np.array_equal(np.asarray(block_sparse.block_keep(jnp.asarray(score), CFG)), want)
+    assert want[0].sum() == 2  # where nothing is reached but a forced block, that block a K/V head
+    assert n_blocks < 48 or want.sum(-1).max() > CFG.topk  # a tie at the k-th keeps both
+
+
 # ----------------------------------------------------------------------
 # the ops
 # ----------------------------------------------------------------------
@@ -296,10 +407,12 @@ def test_engine_serves_the_reference_s_tokens_and_counts_what_it_read():
         first, second = await asyncio.gather(*[_drain(await eng.add_request(prompt, max_tokens=8))
                                                for _ in range(2)])
         mid = eng.stats()
+        await _drain(await eng.add_request(prompt[:40], max_tokens=2))  # under dense_len
+        end = eng.stats()
         await eng.stop()
-        return eng, first, second, mid
+        return eng, first, second, mid, end
 
-    eng, first, second, stats = asyncio.run(main())
+    eng, first, second, stats, end = asyncio.run(main())
     assert first == second and len(first) == 8
     seq = np.asarray(prompt + first, np.int32)
     want = np.asarray(reference.full_logits(eng.params, jnp.asarray(seq), eng.model_cfg)[0])
@@ -308,6 +421,9 @@ def test_engine_serves_the_reference_s_tokens_and_counts_what_it_read():
     assert stats["prefill_chunks"] == 8 and stats["prompt_tokens"] == 400
     assert stats["prefill_bucket_tokens"] == 2 * (3 * 64 + 8)
     assert 0 < stats["sparse_blocks_kept"] < stats["sparse_blocks_cached"]
+    # a chunk is one tile of 64 queries in each of the 2 sparse layers; the first lies under dense_len
+    assert (stats["sparse_tiles_selected"], stats["sparse_tiles"]) == (2 * 2 * 3, 2 * 2 * 4)
+    assert (end["sparse_tiles_selected"], end["sparse_tiles"]) == (2 * 2 * 3, 2 * 2 * 4 + 2)
     assert 0 < stats["kv_positions_attended"] <= stats["kv_positions_gathered"]
     # a decode program reads and writes every lane's state, a chunk its lane's
     state = 2 * eng.cache["lightning_state_0"].nbytes  # two lightning layers
