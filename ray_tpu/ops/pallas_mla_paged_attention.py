@@ -8,17 +8,31 @@ columns.  The pool is ``[n_layer, num_blocks * block_size, W]``, read
 by the walk of ``ops/paged_walk.py``: the owner a lane, a page one
 contiguous ``[block_size, W]`` slab, ONE copy a page serving keys and
 values both (one stream, one buffer).  Its own is the block's
-arithmetic: scores are one ``[H, W] x [W, positions]`` matmul of all
-heads (no block-diagonal layout: the heads share the row), the output
-one ``[H, positions] x [positions, v_width]`` matmul over the same
-buffer's first columns; nothing is expanded to a key or a value a head.
+arithmetic: scores are ``[H, W] x [W, positions]`` matmuls of all heads
+(no block-diagonal layout: the heads share the row), the output
+``[H, positions] x [positions, v_width]`` matmuls over the same buffer's
+first columns; nothing is expanded to a key or a value a head.
 Operands in the pool's dtype, float32 scores and softmax state.  The
 queries arrive with every scale already in them.
+
+A compute block is LARGE (``_BLOCK_POSITIONS``) and folded in parts
+(``_PART_POSITIONS``) under ONE running maximum: the parts' score
+matmuls, exponentials and output matmuls are independent of one another
+but for that maximum, so the scheduler runs one part's matmul under
+another's softmax, and what is paid once a block (the state's
+read-modify-write, the chain matmul -> max -> exp -> matmul from its
+first operand to its last result, the walk's branches) is paid once in
+``_BLOCK_POSITIONS`` positions.  A lane's last block folds only the
+parts that hold a position and masks only the last of them, so a long
+block costs a short lane nothing.  With that the call runs at the pace
+of its page copies (``scripts/mla_decode_check.py``; PERF.md section 6,
+PR 47).
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -30,13 +44,43 @@ from ray_tpu.ops import paged_walk
 from ray_tpu.ops.pallas_attention import NEG_INF
 
 # positions a compute block covers: whole pages, two buffers of it in VMEM
-_BLOCK_POSITIONS = 512
+_BLOCK_POSITIONS = 4096
+# positions of a block folded in one piece: a score matmul, an output matmul
+_PART_POSITIONS = 512
+# what a kernel gets of VMEM unasked; its operands and its result live there beside the scratch
+_VMEM_BYTES = 16 * 2**20
+assert _BLOCK_POSITIONS % _PART_POSITIONS == 0
+
+
+def vmem_scratch(n_head, width, v_width, dtype) -> list:
+    """The kernel's VMEM scratch, ``(shape, dtype)`` each: two buffers of
+    a compute block's rows, a lane's queries, the softmax state."""
+    return [
+        ((2, _BLOCK_POSITIONS, width), dtype),        # buf: two compute blocks of rows
+        ((n_head, width), dtype),                     # the lane's queries, in the pool's dtype
+        ((n_head, 1), jnp.float32),                   # m: running max
+        ((n_head, 1), jnp.float32),                   # l: running sum
+        ((n_head, v_width), jnp.float32),             # acc: unnormalised output
+    ]
+
+
+def vmem_scratch_bytes(n_head, width, v_width, dtype) -> int:
+    """Bytes of ``vmem_scratch`` as the chip lays it out: the last two
+    dimensions in whole (sublane, 128-lane) tiles of the dtype."""
+    def tiled(shape, dt):
+        *lead, rows, cols = shape
+        sub = paged_walk.sublanes(dt)
+        return math.prod(lead) * -(-rows // sub) * sub * -(-cols // 128) * 128 * jnp.dtype(dt).itemsize
+
+    return sum(tiled(shape, dt) for shape, dt in vmem_scratch(n_head, width, v_width, dtype))
 
 
 def kernel_takes(n_head, width, v_width, block_size, dtype) -> bool:
     """The shapes the kernel's tiling can take: a page is whole sublane
     tiles of the pool's dtype, a compute block whole pages, a row and
-    its value part whole lane tiles, the heads whole sublane tiles."""
+    its value part whole lane tiles, the heads whole sublane tiles; and
+    the two buffers of a block leave half the VMEM a kernel gets to its
+    operands."""
     return (
         block_size % paged_walk.sublanes(dtype) == 0
         and _BLOCK_POSITIONS % block_size == 0
@@ -44,6 +88,7 @@ def kernel_takes(n_head, width, v_width, block_size, dtype) -> bool:
         and v_width % 128 == 0
         and v_width <= width
         and n_head % 8 == 0
+        and 2 * vmem_scratch_bytes(n_head, width, v_width, dtype) <= _VMEM_BYTES
     )
 
 
@@ -54,13 +99,14 @@ def _kernel(layer_ref, len_ref, tab_ref,               # scalar prefetch (SMEM)
             qb_ref, m_ref, l_ref, acc_ref,
             *, block_size, v_width):
     bk = buf.shape[1]            # positions a compute block
+    part = _PART_POSITIONS       # positions a part of it
     layer = layer_ref[0]
     blocks_of, pages_of = paged_walk.lane_blocks(len_ref, tab_ref, item_lane, item_blk, block_size, bk // block_size)
     total = paged_walk.list_work(len_ref.shape[0], blocks_of, item_lane, item_blk)
 
     # a lane with nothing cached attends to its own token alone
     o_ref[...] = jnp.broadcast_to(self_ref[:, :, :v_width], o_ref.shape)
-    # stale rows of a partly filled block meet a probability of 0; keep
+    # stale rows of a partly filled part meet a probability of 0; keep
     # them finite (the pool holds finite values only)
     buf[...] = jnp.zeros_like(buf)
 
@@ -73,35 +119,62 @@ def _kernel(layer_ref, len_ref, tab_ref,               # scalar prefetch (SMEM)
             qb_ref[...] = q_ref[lane].astype(qb_ref.dtype)
 
         def fold(slot):
-            rows = buf[slot]                                     # [bk, W]: keys, and in their first columns values
-            s = jax.lax.dot_general(
-                qb_ref[...], rows, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )                                                    # [H, bk]
-            pos = blk * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(pos < length, s, NEG_INF)
-            m_prev = m_ref[...]
-            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            # a visited block holds at least one position, so m_new is a
-            # real score and a masked one gives exp(-1e30 - m_new) == 0
-            p = jnp.exp(s - m_new)
-            l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
-            acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
-                p.astype(rows.dtype), rows[:, :v_width], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )                                                    # [H, v_width]
-            m_ref[...] = m_new
+            last = (blk + 1) * bk >= length
+            # the parts of this block that hold a position
+            held = (jnp.minimum(length - blk * bk, bk) + (part - 1)) // part
 
-            @pl.when((blk + 1) * bk >= length)
+            def rows_of(c):                                      # [part, W]: keys, and in their first columns values
+                return buf[slot, c * part:(c + 1) * part, :]
+
+            def parts(n, ends):
+                """Fold the block's first n parts into the state under one
+                running maximum; ``ends``: the n-th holds the lane's last
+                position, and stale rows past it."""
+                ss = []
+                for c in range(n):
+                    s = jax.lax.dot_general(
+                        qb_ref[...], rows_of(c), (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    )                                            # [H, part]
+                    if ends and c == n - 1:
+                        pos = blk * bk + c * part + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                        s = jnp.where(pos < length, s, NEG_INF)
+                    ss.append(s)
+                top = functools.reduce(jnp.maximum, ss)
+                m_prev = m_ref[...]
+                m_new = jnp.maximum(m_prev, top.max(axis=-1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                l = alpha * l_ref[...]
+                acc = alpha * acc_ref[...]
+                # a visited block holds at least one position, so m_new is a
+                # real score and a masked one gives exp(-1e30 - m_new) == 0
+                for c, s in enumerate(ss):
+                    p = jnp.exp(s - m_new)
+                    l = l + p.sum(axis=-1, keepdims=True)
+                    acc = acc + jax.lax.dot_general(
+                        p.astype(buf.dtype), rows_of(c)[:, :v_width], (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    )                                            # [H, v_width]
+                l_ref[...] = l
+                acc_ref[...] = acc
+                m_ref[...] = m_new
+                return m_new
+
+            @pl.when(jnp.logical_not(last))
             def _():
-                # fold in the fed token's own row, normalise
-                own = self_ref[lane]                             # [1, W]
-                s_self = (qb_ref[...].astype(jnp.float32) * own).sum(axis=-1, keepdims=True)
-                m_all = jnp.maximum(m_new, s_self)
-                a = jnp.exp(m_new - m_all)
-                b = jnp.exp(s_self - m_all)
-                o_ref[lane] = (acc_ref[...] * a + b * own[:, :v_width]) / (l_ref[...] * a + b)
+                parts(bk // part, False)
+
+            for n in range(1, bk // part + 1):
+                @pl.when(last & (held == n))
+                def _(n=n):
+                    m_new = parts(n, True)
+                    # fold in the fed token's own row, normalise
+                    own = self_ref[lane]                         # [1, W]
+                    s_self = (qb_ref[...].astype(jnp.float32) * own).sum(axis=-1, keepdims=True)
+                    m_all = jnp.maximum(m_new, s_self)
+                    a = jnp.exp(m_new - m_all)
+                    b = jnp.exp(s_self - m_all)
+                    o_ref[lane] = (acc_ref[...] * a + b * own[:, :v_width]) / (l_ref[...] * a + b)
 
         return blk, first, fold
 
@@ -120,6 +193,7 @@ def mla_paged_decode_attention_kernel(q, row_self, pages, layer, block_tables, l
     n = _BLOCK_POSITIONS // block_size  # pages a compute block
     dt = pages.dtype
     items = B * -(-pages_per_seq // n)  # compute blocks the lanes can hold
+    buf, *rest = vmem_scratch(H, W, v_width, dt)
 
     def whole(rows, width):
         return pl.BlockSpec((B, rows, width), lambda i, *_: (0, 0, 0))
@@ -137,12 +211,9 @@ def mla_paged_decode_attention_kernel(q, row_self, pages, layer, block_tables, l
             scratch_shapes=[
                 pltpu.SMEM((items,), jnp.int32),                   # item_lane
                 pltpu.SMEM((items,), jnp.int32),                   # item_blk
-                pltpu.VMEM((2, _BLOCK_POSITIONS, W), dt),          # buf: two compute blocks of rows
+                pltpu.VMEM(*buf),
                 pltpu.SemaphoreType.DMA((2,)),                     # a buffer each
-                pltpu.VMEM((H, W), dt),                            # the lane's queries, in the pool's dtype
-                pltpu.VMEM((H, 1), jnp.float32),                   # m: running max
-                pltpu.VMEM((H, 1), jnp.float32),                   # l: running sum
-                pltpu.VMEM((H, v_width), jnp.float32),             # acc: unnormalised output
+                *(pltpu.VMEM(shape, dtype) for shape, dtype in rest),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, v_width), jnp.float32),

@@ -201,8 +201,8 @@ def test_absorbed_queries_score_what_expanded_keys_score():
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_mla_paged_kernel_reads_the_lanes_pages(dtype):
     """The kernel in interpret mode against the ``jax.numpy`` path:
-    lengths 0, 1, a page less one, several pages (two compute blocks),
-    physical pages shuffled."""
+    lengths 0, 1, a page less one, several pages (two parts of a compute
+    block), physical pages shuffled."""
     rng = np.random.default_rng(0)
     B, H, W, V, bs, per = 4, 8, 256, 128, 16, 40
     n_blocks = 1 + B * per

@@ -276,6 +276,8 @@ def _walk_edges(what, bk):
         return [0, 7, 0]
     if what == "no_item":    # nothing starts, nothing is walked
         return [0, 0]
+    if what == "long_lane":  # a lane longer than two compute blocks, before an empty one and a short one
+        return [2 * bk + 3, 0, 7]
     # a lane that ends on a compute block's edge, a lane of length 0
     # between two that run, a lane one position past the edge
     return [bk, 0, bk + 1, 5]
@@ -298,7 +300,7 @@ def _assigned(lens, width, n_pools, per_lane, seed):
     return [jnp.asarray(p, jnp.float32) for p in pools], jnp.asarray(tables)
 
 
-@pytest.mark.parametrize("what", ["every_edge", "one_item", "no_item", "other_pages"])
+@pytest.mark.parametrize("what", ["every_edge", "one_item", "no_item", "long_lane", "other_pages"])
 @pytest.mark.parametrize("name", list(WALKS))
 def test_every_kernel_walks_its_pages_as_its_gather_reads_them(name, what):
     """Each kernel on the shared walk (interpret mode, float32) at the
@@ -323,3 +325,61 @@ def test_every_kernel_walks_its_pages_as_its_gather_reads_them(name, what):
         np.testing.assert_array_equal(got, run(2, True))
     else:
         np.testing.assert_allclose(got, run(1, False), atol=2e-5, rtol=2e-5)
+
+
+# ----------------------------------------------------------------------
+# the latent kernel's block: large, folded in parts under one maximum
+# ----------------------------------------------------------------------
+def _mla_lens(what):
+    bk, part = mla_kernel._BLOCK_POSITIONS, mla_kernel._PART_POSITIONS
+    if what == "part_edges":  # a last block of one part, of two, of all but one, of every one; a second block of two
+        return [part - 1, part, part + 1, bk - part, bk - part + 1, bk + part + 1]
+    if what == "short_lanes":  # a lane shorter than one page, an empty lane between two that run
+        return [BS - 3, 0, 2 * BS + 1]
+    return _walk_edges(what, bk)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("what", ["every_edge", "part_edges", "short_lanes", "long_lane", "other_pages"])
+def test_mla_kernel_folds_a_block_in_parts_as_its_gather_reads_them(what, dtype):
+    """The latent kernel (interpret mode) at its own block's edges, in
+    float32 and in bf16, against the gather: a lane ending on a compute
+    block's edge and one position past it, on a part's edge and either
+    side of it (each count of parts a last block can hold), shorter
+    than a page, empty between two that run, longer than two blocks;
+    and the same lanes on other physical pages to the bit."""
+    lens = _mla_lens(what)
+    per_lane = -(-max(lens) // BS) + 1
+
+    def run(seed, interpret):
+        pools, tables = _assigned(lens, 64, 1, per_lane, seed)
+        rng = np.random.default_rng(7)
+        entry, gather, args, sizes = _mla_walk(
+            lambda *shape: jnp.asarray(rng.standard_normal(shape), dtype), [p.astype(dtype) for p in pools], tables,
+            jnp.asarray(lens, jnp.int32))
+        out = entry(*args, **sizes, interpret=True) if interpret else gather(*args, **sizes)
+        return np.asarray(out, np.float32)
+
+    got = run(1, True)
+    assert np.isfinite(got).all()
+    if what == "other_pages":
+        np.testing.assert_array_equal(got, run(2, True))
+    else:
+        tol = 2e-5 if dtype == jnp.float32 else 3e-2
+        np.testing.assert_allclose(got, run(1, False), atol=tol, rtol=tol)
+
+
+def test_mla_kernel_scratch_at_the_served_shape_fits_the_vmem_a_kernel_gets():
+    """Mistral-Small-4's decode shape (32 heads over rows of 384 stored
+    columns, 256 of them values, bf16): two buffers of a compute block,
+    a lane's queries and the softmax state, laid out in whole tiles,
+    leave more than half of the 16 MiB a kernel gets unasked to the
+    operands the call holds there whole; a float32 pool's buffers would
+    not, and the entry gathers instead."""
+    H, W, V = 32, 384, 256
+    shapes = mla_kernel.vmem_scratch(H, W, V, jnp.bfloat16)
+    assert shapes[0] == ((2, mla_kernel._BLOCK_POSITIONS, W), jnp.bfloat16)
+    by_hand = 2 * mla_kernel._BLOCK_POSITIONS * W * 2 + H * W * 2 + 2 * H * 128 * 4 + H * V * 4
+    assert mla_kernel.vmem_scratch_bytes(H, W, V, jnp.bfloat16) == by_hand < 8 * 2**20
+    assert mla_kernel.kernel_takes(H, W, V, 64, jnp.bfloat16)
+    assert not mla_kernel.kernel_takes(H, W, V, 64, jnp.float32)
