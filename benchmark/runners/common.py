@@ -38,6 +38,23 @@ def device_facts() -> dict:
             "memory_peak_bytes": max(peaks), "pid": os.getpid()}
 
 
+def _rep_settle(rep):
+    """After set-up, as an operator does after warm-up: one full
+    collection, then what lives (430k objects, most of them the traced
+    programs' jaxprs and executables) is moved out of the collector's
+    sight.  A full collection of them takes 0.16 s of the replica's loop
+    thread, with one decode program in flight that is ten steps without
+    a token, and whether a window met none, one or three of them decided
+    its rate by up to 3% (1,707.98-1,757.78 tokens/s in six runs; my chip
+    runs, PR 33).  The engine does not do this itself yet (PERF.md
+    section 7).  Run in the replica through ``__ray_call__``."""
+    import gc
+
+    gc.collect()
+    gc.freeze()
+    return gc.get_freeze_count()
+
+
 class Tracer:
     """``jax.profiler`` around part of a window, in the process that
     holds the chip; `facts` reduces the trace there, so only numbers

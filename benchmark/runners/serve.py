@@ -20,6 +20,7 @@ import os
 import time
 
 from benchmark import traffic as traffic_mod
+from benchmark.runners.common import _rep_settle
 
 # A stream cut off at the end of the drain counts as failed only if it had
 # stalled: no token for this long, a dozen engine steps and more.  An
@@ -320,6 +321,7 @@ def run(job) -> dict:
     installed = ray_tpu.get(call(_rep_install), timeout=600)
     stream_handle = handle.options(stream=True)
     a1, a2, b = setup_checks(job, stream_handle)
+    ray_tpu.get(call(_rep_settle), timeout=300)
 
     vocab = job["sizes"]["vocab_size"]
     if tr["mode"] == "open":
@@ -427,13 +429,15 @@ def run(job) -> dict:
         os.makedirs(job["keep"], exist_ok=True)
         with open(os.path.join(job["keep"], "bursts.json"), "w") as f:
             json.dump({"t0": t0, "t_end": t_end, "bursts": bursts(streams)}, f)
+    window_stats = {"before": stats["before"], "after": stats["after"],
+                    "window_s": stats["after"]["t"] - stats["before"]["t"]}
     print("[serve] " + ", ".join(f"{k}={v}" for k, v in values.items()), flush=True)
     print(f"[serve] checks={checks} failed_streams={[s.summary for s in bad][:3]}", flush=True)
+    # the engine's counters at the window's ends, for benchmark/spread.py
+    print("[serve] stats=" + json.dumps(window_stats), flush=True)
     return {
         "checks": checks, "attempted": len(in_window), "failed": failed,
-        "values": values, "device": device, "trace": trace,
-        "stats": {"before": stats["before"], "after": stats["after"],
-                  "window_s": stats["after"]["t"] - stats["before"]["t"]},
+        "values": values, "device": device, "trace": trace, "stats": window_stats,
     }
 
 

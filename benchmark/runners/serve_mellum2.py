@@ -67,7 +67,8 @@ from benchmark.runners.serve import (  # noqa: F401 - stop is the harness's hook
 )
 from benchmark.runners import serve_minicpm_sala as chunked
 from benchmark.runners.serve_minicpm_sala import deploy, drive_from_full
-from benchmark.runners.serve_mistral_small_4 import _rep_settle, _round_to_e4m3
+from benchmark.runners.common import _rep_settle
+from benchmark.runners.serve_mistral_small_4 import _round_to_e4m3
 from benchmark.runners.serve_nemotron_3_nano import GQA_KERNEL, kernel_roofline_pct, setup_checks
 from benchmark.runners.serve_olmoe import GMM, _rep_trace_facts, _rep_trace_start, from_the_head
 

@@ -25,7 +25,7 @@ in chunks, the engine's ``max_model_len``) with what this family needs:
   a layer (``moe_pairs_routed``), and every pair whose expert is held
   was computed (``moe_pairs == moe_pairs_held``);
 - after set-up the replica's heap is collected once and frozen
-  (``_rep_settle`` says why);
+  (``common._rep_settle`` says why);
 - ``mla_paged_decode_attention_roofline``: a call's least time
   (``flops_mla.mla_decode_work`` over the window's attended positions, by
   ``steps`` x layers calls) against a call's time in the trace; and
@@ -50,6 +50,7 @@ from benchmark.runners.serve import (  # noqa: F401 - stop is the harness's hook
     _cycle, _rep_device, _rep_install, _rep_stats, bursts, edge_rate, stop,
 )
 from benchmark.runners import serve_minicpm_sala as chunked
+from benchmark.runners.common import _rep_settle
 from benchmark.runners.serve_minicpm_sala import deploy, drive_from_full, setup_checks
 from benchmark.runners.serve_olmoe import (
     _rep_trace_facts, _rep_trace_start, from_the_head, gmm_roofline_pct,
@@ -85,23 +86,6 @@ def _rep_mla_sizes(rep):
             "held": {key: getattr(cfg, attr) for key, attr in HELD_KEYS},
             "published": {"n_routed_experts": cfg.n_routed_experts, "vocab_size": cfg.published_vocab_size},
             "max_context": eng.max_ctx, "cache": {k: list(v.shape) for k, v in eng.cache.items()}}
-
-
-def _rep_settle(rep):
-    """After set-up, as an operator does after warm-up: one full
-    collection, then what lives (430k objects, most of them the traced
-    programs' jaxprs and executables) is moved out of the collector's
-    sight.  A full collection of them takes 0.16 s of the replica's loop
-    thread, with one decode program in flight that is ten steps without
-    a token, and whether a window met none, one or three of them decided
-    its rate by up to 3% (1,707.98-1,757.78 tokens/s in six runs; my chip
-    runs, PR 33).  The engine does not do this itself yet (PERF.md
-    section 7)."""
-    import gc
-
-    gc.collect()
-    gc.freeze()
-    return gc.get_freeze_count()
 
 
 def _round_to_e4m3(params):

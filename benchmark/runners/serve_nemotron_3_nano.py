@@ -55,7 +55,8 @@ from benchmark.runners.serve import (  # noqa: F401 - stop is the harness's hook
 )
 from benchmark.runners import serve_minicpm_sala as chunked
 from benchmark.runners.serve_minicpm_sala import chunk_buckets, deploy, drive_from_full
-from benchmark.runners.serve_mistral_small_4 import _rep_settle, _round_to_e4m3
+from benchmark.runners.common import _rep_settle
+from benchmark.runners.serve_mistral_small_4 import _round_to_e4m3
 from benchmark.runners.serve_olmoe import GMM, _rep_trace_facts, _rep_trace_start, from_the_head
 
 FAMILY = "ray_tpu.models.nemotron_h"

@@ -259,6 +259,7 @@ def test_data_files_load_and_agree_with_benchmark_json():
         assert NAME.match(m["name"]) and UNIT.match(m["unit"])
         how = spec.load_layer_metric(m["name"])
         assert set(how) == {"reader", "args"} and how["reader"] in readers.READERS
+        assert m["workloads"] and all(c in cells for c in m["workloads"])  # some cell reports it
         layers.add(m["layer"])
         # the metric it moves is reported in every cell that reports it
         for cell in m.get("workloads", list(cells)):
@@ -271,6 +272,80 @@ def test_data_files_load_and_agree_with_benchmark_json():
         on_disk = {f[:-5] for f in os.listdir(os.path.join(BENCH, sub)) if f.endswith(".json")}
         assert on_disk == set(names), sub
     assert set(spec.load_peaks()) == {"TPU v5 lite"}
+
+
+def _what_a_cell_reads(bench, cell):
+    """{(reader, args, moves)} of the cell's per-layer metrics, each as JSON."""
+    rows = set()
+    for m in spec.metrics_of_cell(bench, "per_layer", cell):
+        how = spec.load_layer_metric(m["name"])
+        rows.add(json.dumps([how["reader"], how.get("args", {}), m["moves"]], sort_keys=True))
+    return rows
+
+
+def test_the_fold_dropped_nothing_a_cell_read():
+    """PR 49 folded the families' twins (128 entries to 57).  Every
+    reader, with its arguments and the end-to-end metric it moves, that a
+    cell resolved on the parent it resolves still, under whatever name.
+    ``data/per_layer_before_fold.json`` is the parent's table, made in a
+    checkout of 71c9b1a by::
+
+        table = {w["name"]: sorted(_what_a_cell_reads(bench, w["name"])) for w in bench["workloads"]}
+        json.dump({c: [json.loads(r) for r in rows] for c, rows in table.items()}, f, indent=1)
+    """
+    bench = spec.load_benchmark()
+    with open(os.path.join(HERE, "data", "per_layer_before_fold.json")) as f:
+        before = json.load(f)
+    assert set(before) <= {w["name"] for w in bench["workloads"]}
+    for cell, rows in before.items():
+        missing = {json.dumps(r, sort_keys=True) for r in rows} - _what_a_cell_reads(bench, cell)
+        assert not missing, (cell, missing)
+
+
+def test_one_entry_for_each_thing_measured_and_room_for_the_next_cells():
+    """No two entries share reader, arguments, unit, direction, source,
+    layer and `moves` but those that tier-1 holds to one cell
+    (``tests/test_serve_engine_phases.py`` asserts ``workloads == [cell]``
+    for them; PERF.md section 7), and the list is asked to be folded
+    again well before it is full."""
+    bench = spec.load_benchmark()
+    held_to_one_cell = {"prefill_share_pct.backlog", "host_ms_per_step.backlog", "kv_gather_useful_pct.backlog",
+                        "prefill_pad_ratio.backlog", "decode_overlap_pct.backlog", "decode_overlap_pct.moe"}
+    seen = {}
+    for m in bench["per_layer"]:
+        if m["name"] in held_to_one_cell:
+            assert len(m["workloads"]) == 1
+            continue
+        how = spec.load_layer_metric(m["name"])
+        key = json.dumps([how, m["unit"], m["better"], m["source"], m["layer"], m["moves"]], sort_keys=True)
+        assert key not in seen, (m["name"], seen[key])
+        seen[key] = m["name"]
+    assert len(bench["per_layer"]) <= 128 - 60
+
+
+def test_spread_reads_a_set_as_the_check_does():
+    """``spread.set_rule`` on six runs by hand: the run farthest from the
+    median is left out of the range only where that narrows it, and of
+    the quartiles' distance always."""
+    from benchmark import spread
+
+    runs = [2596.0, 2601.0, 2590.0, 2610.0, 2540.0, 2599.0]  # median 2597.5, 2540 the farthest
+    rule = spread.set_rule(runs)
+    assert rule["median"] == 2597.5 and rule["range"] == 2610.0 - 2590.0
+    # statistics.quantiles of the five kept: 2593 and 2605.5; of all six: 2577.5 and 2603.25
+    assert rule["spread"] == pytest.approx(12.5) and rule["spread_all"] == pytest.approx(25.75)
+    # the farthest run is no end of the range where two runs tie for the other end
+    tied = [10.0, 10.0, 11.0, 12.0, 13.0, 20.0]
+    assert spread.without_farthest(tied) == [10.0, 10.0, 11.0, 12.0, 13.0] and spread.set_rule(tied)["range"] == 3.0
+    flat = [5.0, 5.0, 5.0, 5.0, 5.0, 5.0]
+    assert spread.set_rule(flat)["range"] == 0.0 and spread.set_rule(flat)["spread"] == 0.0
+    # a run's window second by second, and a gap between two bursts
+    bursts = {"t0": 100.0, "t_end": 104.0,
+              "bursts": [[99.99, 16]] + [[100.0 + 0.01 * i, 16] for i in range(180)]
+              + [[102.0 + 0.01 * i, 16] for i in range(200)] + [[104.0, 16]]}
+    prof = spread.window_profile(bursts)
+    assert prof["per_s"] == [1600, 1280, 1600, 1600] and prof["half_slope_pct"] == pytest.approx(100 * (3200 / 2880 - 1))
+    assert prof["gaps_over_50ms"] == [[1.79, 210.0]]
 
 
 # ----------------------------------------------------------------------

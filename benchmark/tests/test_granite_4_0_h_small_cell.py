@@ -51,15 +51,15 @@ TINY_CELL = {
 # what a traced run prints without a chip: the counters' metrics and the
 # host clock's (those that read the device trace or the chip's peak find
 # nothing on the CPU and are left out)
-ON_THE_CPU = {"engine_step_ms.granite", "lanes_busy_pct.granite", "host_ms_per_step.granite",
-              "prefill_share_pct.granite", "prefill_pad_ratio.granite", "prefill_chunk_ms.granite",
-              "decode_overlap_pct.granite", "kv_gather_useful_pct.granite", "deploy_ready_s.granite",
-              "moe_experts_hit_pct.granite", "moe_imbalance.granite", "moe_held_share_pct.granite",
-              "ssm_state_mb_per_step.granite"}
-FROM_THE_DEVICE = {"device_idle_pct.granite", "moe_gmm_busy_pct.granite", "moe_gmm_roofline_pct.granite",
-                   "mamba2_decode_step_busy_pct.granite", "mamba2_decode_step_roofline.granite",
-                   "gqa_paged_decode_attention_busy_pct.granite", "gqa_paged_decode_attention_roofline.granite",
-                   "prefill_mfu_pct.granite"}
+ON_THE_CPU = {"engine_step_ms.backlog", "lanes_busy_pct.backlog", "host_ms_per_step",
+              "prefill_share_pct", "prefill_pad_ratio", "prefill_chunk_ms",
+              "decode_overlap_pct", "kv_gather_useful_pct", "deploy_ready_s.serve",
+              "moe_experts_hit_pct", "moe_imbalance", "moe_held_share_pct",
+              "ssm_state_mb_per_step"}
+FROM_THE_DEVICE = {"device_idle_pct.backlog", "moe_gmm_busy_pct", "moe_gmm_roofline_pct",
+                   "mamba2_decode_step_busy_pct", "mamba2_decode_step_roofline",
+                   "gqa_paged_decode_attention_busy_pct", "gqa_paged_decode_attention_roofline",
+                   "prefill_mfu_pct"}
 
 
 @pytest.mark.parametrize("trace", [0, 1])
@@ -74,12 +74,12 @@ def test_cell_end_to_end_at_tiny_size(monkeypatch, trace):
     assert out["correct"] and out["attempted"] > 0 and out["failed"] == 0
     if trace:
         assert set(out["metrics"]) == ON_THE_CPU and "breakdown" in out
-        assert out["metrics"]["moe_held_share_pct.granite"]["value"] == 100  # the tiny preset holds all 16
-        assert 0 < out["metrics"]["kv_gather_useful_pct.granite"]["value"] <= 100
+        assert out["metrics"]["moe_held_share_pct"]["value"] == 100  # the tiny preset holds all 16
+        assert 0 < out["metrics"]["kv_gather_useful_pct"]["value"] <= 100
         # 4 lanes x 2 Mamba layers x (8 x 16 x 16 float32 + 3 x 160 float32), read and written a decode
         # step, and a lane's share of it for every chunk program between two steps
         a_step = 2 * 4 * 2 * (8 * 16 * 16 + 3 * 160) * 4 / 1e6
-        assert a_step <= out["metrics"]["ssm_state_mb_per_step.granite"]["value"] < 2 * a_step
+        assert a_step <= out["metrics"]["ssm_state_mb_per_step"]["value"] < 2 * a_step
     else:
         assert set(out["metrics"]) == {"serve_out_tokens_per_s", "setup_s"}
         assert out["metrics"]["serve_out_tokens_per_s"]["value"] > 0
@@ -90,14 +90,15 @@ def test_the_cell_s_metrics_are_the_entries_of_benchmark_json():
     per_layer = {m["name"]: m for m in spec.metrics_of_cell(bench, "per_layer", CELL)}
     assert set(per_layer) == ON_THE_CPU | FROM_THE_DEVICE
     for name, m in per_layer.items():
-        assert m["workloads"] == [CELL] and spec.load_layer_metric(name)["reader"]
+        assert CELL in m["workloads"] and spec.load_layer_metric(name)["reader"]
         assert m["moves"] == ("setup_s" if name.startswith("deploy_ready") else "serve_out_tokens_per_s")
     assert {m["name"] for m in spec.metrics_of_cell(bench, "end_to_end", CELL)} == {
         "serve_out_tokens_per_s", "setup_s"}
-    # the cell and its configuration are there, on one chip; the four-chip cells are what they were
+    # the cell and its configuration are there, on one chip
     names = [w["name"] for w in bench["workloads"]]
     assert CELL in names and NAME in [c["name"] for c in bench["configs"]]
-    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1 and len(names) <= 24
+    # the driver's rule: at most a quarter of the cells, rounded down, on four chips, and one always
+    assert sum(w["chips"] == 4 for w in bench["workloads"]) <= max(1, len(names) // 4) and len(names) <= 24
     cell, wl = spec.load_cell(CELL), spec.entry(bench, "workloads", CELL)
     assert (cell["why"], cell["config"], cell["chips"]) == (wl["why"], wl["config"], 1) and len(wl["why"]) <= 200
     # the traffic and the engine the issue names

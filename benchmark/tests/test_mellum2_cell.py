@@ -53,12 +53,15 @@ TINY_CELL = {
 # what a traced run prints without a chip: the counters' metrics and the
 # host clock's (those that read the device trace or the chip's peak find
 # nothing on the CPU and are left out)
-ON_THE_CPU = {"engine_step_ms.mellum", "lanes_busy_pct.mellum", "host_ms_per_step.mellum",
-              "prefill_share_pct.mellum", "prefill_chunk_ms.mellum", "deploy_ready_s.mellum",
-              "attn_positions_kept_pct.mellum"}
-FROM_THE_DEVICE = {"device_idle_pct.mellum", "moe_gmm_busy_pct.mellum", "moe_gmm_roofline_pct.mellum",
-                   "gqa_paged_decode_attention_busy_pct.mellum", "gqa_paged_decode_attention_roofline.mellum",
-                   "prefill_mfu_pct.mellum"}
+ON_THE_CPU = {"engine_step_ms.backlog", "lanes_busy_pct.backlog", "host_ms_per_step",
+              "prefill_share_pct", "prefill_chunk_ms", "deploy_ready_s.serve",
+              "attn_positions_kept_pct.mellum",
+              # the five the full list of PR 45 had no room for (PR 49)
+              "prefill_pad_ratio", "decode_overlap_pct", "kv_gather_useful_pct",
+              "moe_experts_hit_pct", "moe_imbalance"}
+FROM_THE_DEVICE = {"device_idle_pct.backlog", "moe_gmm_busy_pct", "moe_gmm_roofline_pct",
+                   "gqa_paged_decode_attention_busy_pct", "gqa_paged_decode_attention_roofline",
+                   "prefill_mfu_pct"}
 
 
 def _run(trace, checks=None):
@@ -101,15 +104,16 @@ def test_the_cell_s_metrics_are_the_entries_of_benchmark_json():
     per_layer = {m["name"]: m for m in spec.metrics_of_cell(bench, "per_layer", CELL)}
     assert set(per_layer) == ON_THE_CPU | FROM_THE_DEVICE
     for name, m in per_layer.items():
-        assert m["workloads"] == [CELL] and spec.load_layer_metric(name)["reader"]
+        assert CELL in m["workloads"] and spec.load_layer_metric(name)["reader"]
         assert m["moves"] == ("setup_s" if name.startswith("deploy_ready") else "serve_out_tokens_per_s")
     assert {m["name"] for m in spec.metrics_of_cell(bench, "end_to_end", CELL)} >= {
         "serve_out_tokens_per_s", "setup_s"}
-    assert len(bench["per_layer"]) <= 128  # the contract's ceiling: why five of the issue's eighteen are not here
-    # the cell and its configuration are there, on one chip; the four-chip cells are what they were
+    assert len(bench["per_layer"]) <= 128  # the contract's ceiling
+    # the cell and its configuration are there, on one chip
     names = [w["name"] for w in bench["workloads"]]
     assert CELL in names and NAME in [c["name"] for c in bench["configs"]]
-    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1 and len(names) <= 24
+    # the driver's rule: at most a quarter of the cells, rounded down, on four chips, and one always
+    assert sum(w["chips"] == 4 for w in bench["workloads"]) <= max(1, len(names) // 4) and len(names) <= 24
     cell, wl = spec.load_cell(CELL), spec.entry(bench, "workloads", CELL)
     assert (cell["why"], cell["config"], cell["chips"]) == (wl["why"], wl["config"], 1) and len(wl["why"]) <= 200
     # the traffic and the engine the issue names

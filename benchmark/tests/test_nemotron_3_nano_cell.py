@@ -48,13 +48,13 @@ TINY_CELL = {
 # what a traced run prints without a chip: the counters' metrics and the
 # host clock's (the seven that read the device trace find nothing on the
 # CPU and are left out)
-ON_THE_CPU = {"engine_step_ms.ssm", "lanes_busy_pct.ssm", "host_ms_per_step.ssm", "prefill_share_pct.ssm",
-              "prefill_pad_ratio.ssm", "prefill_chunk_ms.ssm", "decode_overlap_pct.ssm",
-              "kv_gather_useful_pct.ssm", "deploy_ready_s.ssm", "moe_experts_hit_pct.ssm",
-              "moe_imbalance.ssm", "moe_held_share_pct.ssm", "ssm_state_mb_per_step.ssm"}
-FROM_THE_DEVICE = {"device_idle_pct.ssm", "moe_gmm_busy_pct.ssm", "moe_gmm_roofline_pct.ssm",
-                   "mamba2_decode_step_busy_pct.ssm", "mamba2_decode_step_roofline.ssm",
-                   "gqa_paged_decode_attention_busy_pct.ssm", "gqa_paged_decode_attention_roofline.ssm"}
+ON_THE_CPU = {"engine_step_ms.backlog", "lanes_busy_pct.backlog", "host_ms_per_step", "prefill_share_pct",
+              "prefill_pad_ratio", "prefill_chunk_ms", "decode_overlap_pct",
+              "kv_gather_useful_pct", "deploy_ready_s.serve", "moe_experts_hit_pct",
+              "moe_imbalance", "moe_held_share_pct", "ssm_state_mb_per_step"}
+FROM_THE_DEVICE = {"device_idle_pct.backlog", "moe_gmm_busy_pct", "moe_gmm_roofline_pct",
+                   "mamba2_decode_step_busy_pct", "mamba2_decode_step_roofline",
+                   "gqa_paged_decode_attention_busy_pct", "gqa_paged_decode_attention_roofline"}
 
 
 @pytest.mark.parametrize("trace", [0, 1])
@@ -69,12 +69,12 @@ def test_cell_end_to_end_at_tiny_size(monkeypatch, trace):
     assert out["correct"] and out["attempted"] > 0 and out["failed"] == 0
     if trace:
         assert set(out["metrics"]) == ON_THE_CPU and "breakdown" in out
-        assert out["metrics"]["moe_held_share_pct.ssm"]["value"] == 100  # the tiny preset holds all 32
-        assert 0 < out["metrics"]["kv_gather_useful_pct.ssm"]["value"] <= 100
+        assert out["metrics"]["moe_held_share_pct"]["value"] == 100  # the tiny preset holds all 32
+        assert 0 < out["metrics"]["kv_gather_useful_pct"]["value"] <= 100
         # 4 lanes x 3 Mamba layers x (8 x 8 x 16 float32 + 3 x 96 float32), read and written a decode
         # step, and a lane's share of it for every chunk program between two steps
         a_step = 2 * 4 * 3 * (8 * 8 * 16 + 3 * 96) * 4 / 1e6
-        assert a_step <= out["metrics"]["ssm_state_mb_per_step.ssm"]["value"] < 2 * a_step
+        assert a_step <= out["metrics"]["ssm_state_mb_per_step"]["value"] < 2 * a_step
     else:
         assert set(out["metrics"]) == {"serve_out_tokens_per_s", "setup_s"}
         assert out["metrics"]["serve_out_tokens_per_s"]["value"] > 0
@@ -85,13 +85,15 @@ def test_the_cell_s_metrics_are_the_entries_of_benchmark_json():
     per_layer = {m["name"]: m for m in spec.metrics_of_cell(bench, "per_layer", CELL)}
     assert set(per_layer) == ON_THE_CPU | FROM_THE_DEVICE
     for name, m in per_layer.items():
-        assert m["workloads"] == [CELL] and spec.load_layer_metric(name)["reader"]
+        assert CELL in m["workloads"] and spec.load_layer_metric(name)["reader"]
         assert m["moves"] == ("setup_s" if name.startswith("deploy_ready") else "serve_out_tokens_per_s")
     assert {m["name"] for m in spec.metrics_of_cell(bench, "end_to_end", CELL)} == {
         "serve_out_tokens_per_s", "setup_s"}
-    # eight cells, one of them on four chips; nothing that was there has moved
-    assert len(bench["workloads"]) == 8 and sum(w["chips"] == 4 for w in bench["workloads"]) == 1
-    assert bench["workloads"][-1]["name"] == CELL and bench["configs"][-1]["name"] == "nemotron-3-nano"
+    # the cell and its configuration are there among eight or more; of the cells at most a
+    # quarter, rounded down, are on four chips, and one always (the driver's rule)
+    names = [w["name"] for w in bench["workloads"]]
+    assert 8 <= len(names) <= 24 and sum(w["chips"] == 4 for w in bench["workloads"]) <= max(1, len(names) // 4)
+    assert CELL in names and "nemotron-3-nano" in [c["name"] for c in bench["configs"]]
     cell, wl = spec.load_cell(CELL), spec.entry(bench, "workloads", CELL)
     assert (cell["why"], cell["config"], cell["chips"]) == (wl["why"], wl["config"], 1)
     # the traffic and the engine the issue names

@@ -37,9 +37,10 @@ TINY_CELL = {
 # what a traced run prints without a chip: the counters' metrics and the
 # host clock's (the four that read the device trace find nothing on the
 # CPU and are left out)
-ON_THE_CPU = {"engine_step_ms.moe", "lanes_busy_pct.moe", "host_ms_per_step.moe",
-              "prefill_share_pct.moe", "kv_gather_useful_pct.moe", "moe_experts_hit_pct.moe",
-              "moe_imbalance.moe", "prefill_pad_ratio.moe", "deploy_ready_s.moe"}
+ON_THE_CPU = {"engine_step_ms.backlog", "lanes_busy_pct.backlog", "host_ms_per_step",
+              "prefill_share_pct", "kv_gather_useful_pct", "moe_experts_hit_pct",
+              "moe_imbalance", "prefill_pad_ratio", "deploy_ready_s.serve",
+              "decode_overlap_pct.moe"}
 
 
 @pytest.mark.parametrize("trace", [0, 1])
@@ -54,10 +55,10 @@ def test_cell_end_to_end_at_tiny_size(monkeypatch, trace):
     assert out["correct"] and out["attempted"] > 0 and out["failed"] == 0
     if trace:
         assert set(out["metrics"]) == ON_THE_CPU and "breakdown" in out
-        assert 0 < out["metrics"]["moe_experts_hit_pct.moe"]["value"] <= 100
-        assert out["metrics"]["moe_imbalance.moe"]["value"] >= 1
-        assert out["metrics"]["lanes_busy_pct.moe"]["value"] > 50
-        assert out["metrics"]["prefill_pad_ratio.moe"]["value"] >= 1
+        assert 0 < out["metrics"]["moe_experts_hit_pct"]["value"] <= 100
+        assert out["metrics"]["moe_imbalance"]["value"] >= 1
+        assert out["metrics"]["lanes_busy_pct.backlog"]["value"] > 50
+        assert out["metrics"]["prefill_pad_ratio"]["value"] >= 1
     else:
         assert set(out["metrics"]) == {"serve_out_tokens_per_s", "setup_s"}
         assert out["metrics"]["serve_out_tokens_per_s"]["value"] > 0
