@@ -189,8 +189,7 @@ def gqa_paged_decode_attention(q, k_self, v_self, k_pages, v_pages, layer, block
     (None: ``Dh ** -0.5``).  Returns [B, G, R, Dh].
 
     On a TPU, where the shapes fit its tiling (whole lane tiles a head,
-    whole sublane tiles a page; any R that is whole sublane tiles or
-    fewer than one), the Pallas kernel reads the pages where they lie, a
+    whole sublane tiles a page; any R), the Pallas kernel reads the pages where they lie, a
     page once for its group's R heads (ops.pallas_gqa_paged_attention).
     Elsewhere the lane's pages are gathered to a contiguous context
     first."""

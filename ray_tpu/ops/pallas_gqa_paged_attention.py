@@ -40,15 +40,10 @@ _BLOCK_POSITIONS = 512
 def kernel_takes(n_rep, d_head, block_size, dtype) -> bool:
     """The shapes the kernel's tiling can take: a page is whole sublane
     tiles of the pool's dtype (16 rows of bf16), a compute block whole
-    pages, a head whole lane tiles.  A group's query heads are whole
-    sublane tiles or fewer than one (padded to one)."""
+    pages, a head whole lane tiles.  A group's query heads are any
+    number (padded up to whole sublane tiles)."""
     sublanes = paged_walk.sublanes(dtype)
-    return (
-        block_size % sublanes == 0
-        and _BLOCK_POSITIONS % block_size == 0
-        and d_head % 128 == 0
-        and (n_rep % sublanes == 0 or n_rep < sublanes)
-    )
+    return n_rep > 0 and block_size % sublanes == 0 and _BLOCK_POSITIONS % block_size == 0 and d_head % 128 == 0
 
 
 def _kernel(layer_ref, len_ref, tab_ref,               # scalar prefetch (SMEM)
@@ -134,8 +129,8 @@ def gqa_paged_decode_attention_kernel(q, k_self, v_self, k_pages, v_pages, layer
     """The arguments of ``ops.attention.gqa_paged_decode_attention``.
     ``interpret=True`` runs the same kernel on the CPU for tests."""
     heads, tile = q.shape[2], paged_walk.sublanes(k_pages.dtype)
-    if heads < tile:  # a small group: heads of zeros up to a tile, dropped at the end
-        pad = tile - heads
+    if heads % tile:  # not whole tiles: heads of zeros up to the next tile, dropped at the end
+        pad = -heads % tile
         out = gqa_paged_decode_attention_kernel(
             jnp.pad(q, ((0, 0), (0, 0), (0, pad), (0, 0))), k_self, v_self, k_pages, v_pages, layer, block_tables,
             lengths, block_size=block_size, scale=scale, interpret=interpret)

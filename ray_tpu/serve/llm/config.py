@@ -24,6 +24,7 @@ MODEL_FAMILIES = (
     ("ray_tpu.models.granite_hybrid", "GraniteHybridConfig",
      ("granite_4_0_h_small_tiny", "granite_4_0_h_small", "granite_4_0_h_small_10l_ep2")),
     ("ray_tpu.models.mellum", "MellumConfig", ("mellum2_tiny", "mellum2_12b_a2_5b", "mellum2_12b_a2_5b_12l")),
+    ("ray_tpu.models.jamba", "JambaConfig", ("jamba2_tiny", "jamba2_3b")),
 )
 # every preset ``LLMConfig.model`` may name, family by family
 PRESETS = " | ".join(name for *_, presets in MODEL_FAMILIES for name in presets)
@@ -86,7 +87,10 @@ class LLMConfig:
     and 38.2 MB a lane at the depths their ``*_ep*`` presets hold), and
     only their attention layers page K and V; the ``mellum2*`` presets a
     ring of K and of V a window layer (18.9 MB a lane at 9 window layers
-    of 1,024 positions), and only their full-attention layers page.
+    of 1,024 positions), and only their full-attention layers page; the
+    ``jamba2*`` presets a convolution tail and a float32 scan state a
+    Mamba-1 layer (9.32 MB a lane at the 26 Mamba layers of
+    ``jamba2_3b``), and only their two attention layers page.
 
     ``model`` names a preset of a model family, one of (from
     ``MODEL_FAMILIES``): {presets}.

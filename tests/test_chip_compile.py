@@ -560,6 +560,111 @@ def test_granite_hybrid_programs_compile_for_v5e(one_chip, monkeypatch):
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 * 2**30
 
 
+def _kernel_calls(text):
+    """The names of a compiled program's Pallas calls."""
+    return [ln.split(" = ")[0].split("%")[-1] for ln in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in ln]
+
+
+def test_mamba1_kernels_compile_for_v5e(one_chip):
+    """The two Mamba-1 kernels alone at Jamba2-3B's shapes (a state of 16
+    x 5,120 float32 a lane a layer): the decode kernel over 256 lanes in
+    blocks of eight (2.6 MB of states in and 2.6 MB out a grid step, twice
+    for the double buffering, inside its ``vmem_limit_bytes``: the
+    compiler would refuse it here), the states going out in the buffer
+    they came in; the chunk kernel on 2,048 and on 32 positions, with
+    nothing of ``[T, 5120, 16]`` (671 MB at 2,048) among its temporaries:
+    what it holds beside its arguments are the float32 rows XLA hands it
+    (``dt``, ``dt x``, ``y``: 42 MB each)."""
+    from ray_tpu.ops import pallas_mamba1
+
+    lanes, N, D = 256, 16, 5120
+    assert pallas_mamba1.step_kernel_takes(lanes, N, D)
+
+    def arr(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    step = jax.jit(pallas_mamba1.mamba1_decode_step, donate_argnums=(6,)).lower(
+        arr((lanes, D), jnp.bfloat16), arr((lanes, D)), arr((N, D)), arr((lanes, N)), arr((lanes, N)), arr((D,)),
+        arr((lanes, N, D)), arr((lanes,), jnp.bool_)).compile()
+    calls = _kernel_calls(step.as_text())
+    assert len(calls) == 1 and calls[0].startswith("mamba1_decode_step")
+    state_bytes = lanes * N * D * 4
+    assert 4 * pallas_mamba1._LANES_A_BLOCK * N * D * 4 < pallas_mamba1._VMEM_BYTES
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes == state_bytes
+    assert mem.temp_size_in_bytes < state_bytes // 4  # the rows, never a second array of states
+    for T in (2048, 32):
+        assert pallas_mamba1.chunk_kernel_takes(T, N, D)
+        chunk = jax.jit(pallas_mamba1.mamba1_chunk_scan).lower(
+            arr((T, D), jnp.bfloat16), arr((T, D)), arr((N, D)), arr((T, N)), arr((T, N)), arr((D,)), arr((N, D)),
+            arr((), jnp.int32)).compile()
+        calls = _kernel_calls(chunk.as_text())
+        assert len(calls) == 1 and calls[0].startswith("mamba1_chunk_scan")
+        assert chunk.memory_analysis().temp_size_in_bytes < 4 * T * D * 4  # far under T x 5120 x 16 x 4
+
+
+def test_jamba_programs_compile_for_v5e(one_chip, monkeypatch):
+    """Jamba2-3B's two programs at the published widths (d 2560; Mamba-1
+    of 5,120 channels x 16 state values, ``dt`` through rank 160; 20 query
+    heads of 128 on ONE K/V head; the SwiGLU of 8,192; the tied head over
+    65,536 rows), 256 lanes over 8,192 positions in pages of 64, depth cut
+    to one layer of each kind: the decode step updates the lanes' states
+    through the Mamba-1 kernel INTO the donated array and reads the K/V
+    pages through the grouped-query kernel (a group of 20 padded to 32
+    rows: no gather of a ``[lanes, max_ctx, ...]`` context); a
+    2,048-token chunk runs its scan in the chunk kernel (no ``while`` a
+    position, nothing of ``[2048, 5120, 16]``) and fits beside the
+    cache."""
+    from ray_tpu.models import jamba
+    from ray_tpu.serve.llm.engine import decode_step, prefill_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = jamba.JambaConfig.jamba2_3b(n_layer=2, attn_layer_period=2, attn_layer_offset=1)
+    assert cfg.layer_types == ("mamba", "attention")
+    B, C, block, T, slots = 256, 8192, 64, 2048, 786432 + 64
+    spec = jamba.cache_spec(cfg, block)
+    assert spec.names == ("k_pages", "v_pages", "conv_tail_0", "ssm_state_0")
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    def shaped(tree):
+        return jax.tree_util.tree_map(lambda x: arr(x.shape, x.dtype), tree)
+
+    params = shaped(jax.eval_shape(lambda: jamba.init_params(cfg)))
+    key = shaped(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    pool = arr((spec.paged_layers, slots, spec.row_width), cfg.dtype)
+    cache = [pool, pool] + [arr((B, *shape), dtype) for _, shape, dtype in spec.lane_state]
+    held = tuple(range(1, 1 + len(cache)))
+    state_bytes = B * 16 * 5120 * 4
+
+    decode = jax.jit(lambda *a: decode_step(cfg, 0, block, spec, *a), donate_argnums=held).lower(
+        params, *cache, arr((B,), jnp.int32), arr((B,), jnp.int32), arr((B, C // block), jnp.int32),
+        arr((B,), jnp.int32), arr((B,), jnp.float32), key).compile()
+    text = decode.as_text()
+    calls = _kernel_calls(text)
+    assert sum(c.startswith("mamba1_decode_step") for c in calls) == 1
+    assert sum(c.startswith("gqa_paged_decode_attention") for c in calls) == 1 and len(calls) == 2
+    assert f"[{B},{C}," not in text  # the gather path's contexts
+    mem = decode.memory_analysis()
+    # every held array goes out in the buffer it came in: both pools, the tail, the state
+    assert mem.alias_size_in_bytes >= state_bytes + 2 * slots * 128 * 2 + B * 15360 * 2
+    assert mem.temp_size_in_bytes < state_bytes  # no second array of states among the temporaries
+    assert f"s32[{B + len(jamba.COUNTERS)}]" in text
+
+    chunk = jax.jit(lambda *a: prefill_step(cfg, 0, block, spec, *a), donate_argnums=held).lower(
+        params, *cache, arr((1, T), jnp.int32), arr((T,), jnp.int32), arr((1,), jnp.int32),
+        arr((1,), jnp.float32), key, arr((), jnp.int32), arr((C // block,), jnp.int32),
+        arr((), jnp.int32)).compile()
+    text = chunk.as_text()
+    assert [c.split(".")[0] for c in _kernel_calls(text)] == ["mamba1_chunk_scan"]
+    assert "f32[2048,5120,16]" not in text and "f32[2048,16,5120]" not in text
+    mem = chunk.memory_analysis()
+    assert mem.temp_size_in_bytes < 2**29  # 671 MB would be ONE layer's states a position
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 * 2**30
+
+
 def test_moe_combine_moves_the_pairs_rows_once(one_chip, monkeypatch):
     """`moe_experts` at Granite 4.0-H's chunk shape (2,048 tokens x 10
     experts a token = 20,480 pairs of 4,096 columns, 36 held SwiGLU

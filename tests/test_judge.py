@@ -23,16 +23,20 @@ pytest.register_assert_rewrite(
     "benchmark.tests.test_benchmark", "benchmark.tests.test_olmoe_cell",
     "benchmark.tests.test_mistral_small_4_cell", "benchmark.tests.test_nemotron_3_nano_cell",
     "benchmark.tests.test_granite_4_0_h_small_cell", "benchmark.tests.test_mellum2_cell",
+    "benchmark.tests.test_jamba2_cell",
 )
 
 from benchmark.tests.test_benchmark import (  # noqa: E402,F401
     test_closed_loop_rate_is_cut_at_bursts,
     test_data_files_load_and_agree_with_benchmark_json,
     test_flops_against_hand_worked_numbers,
+    test_one_entry_for_each_thing_measured_and_room_for_the_next_cells,
     test_operation_names_are_cut_to_instruction_and_target,
     test_recorded_chip_trace_reduces,
     test_run_refuses_without_a_chip,
+    test_spread_reads_a_set_as_the_check_does,
     test_stats_delta_arithmetic,
+    test_the_fold_dropped_nothing_a_cell_read,
     test_trace_readers_on_the_reduced_trace,
     test_trace_reduction_busy_union_time_by_name_and_gaps,
     test_traffic_from_the_seed,
@@ -56,7 +60,7 @@ from benchmark.tests.test_nemotron_3_nano_cell import (  # noqa: E402,F401
 )
 from benchmark.tests.test_nemotron_3_nano_cell import (  # noqa: E402,F401
     test_runner_fails_at_once_where_the_program_has_no_such_family as test_nemotron_runner_fails_at_once_where_the_program_has_no_such_family,
-    test_the_cell_s_metrics_are_the_entries_of_benchmark_json as _nemotron_cell_s_metrics_as_pr_38_wrote_it,
+    test_the_cell_s_metrics_are_the_entries_of_benchmark_json as test_the_nemotron_cell_s_metrics_are_the_entries_of_benchmark_json,
     test_the_configuration_is_the_catalog_s_but_for_what_it_says_is_reduced as test_the_nemotron_configuration_is_the_catalog_s_but_for_what_it_says_is_reduced,
 )
 from benchmark.tests.test_granite_4_0_h_small_cell import (  # noqa: E402,F401
@@ -79,17 +83,14 @@ from benchmark.tests.test_mellum2_cell import (  # noqa: E402,F401
     test_the_configuration_is_the_catalog_s_but_for_what_it_says_is_reduced as test_the_mellum_configuration_is_the_catalog_s_but_for_what_it_says_is_reduced,
     test_the_cut_s_arithmetic_reckoned_again as test_the_mellum_cut_s_arithmetic_reckoned_again,
 )
-
-
-def test_the_nemotron_cell_s_metrics_are_the_entries_of_benchmark_json(monkeypatch):
-    """PR 38's test on the benchmark as far as PR 38 brought it: it says
-    there are eight cells and that its own is the last, which held until
-    the next cell was appended behind it (PR 41), and only a
-    ``benchmark`` PR may edit its file.  The cells and configurations up
-    to its own are still what it says, in their places."""
-    from benchmark import spec
-
-    bench = spec.load_benchmark()
-    then = dict(bench, workloads=bench["workloads"][:8], configs=bench["configs"][:6])
-    monkeypatch.setattr(spec, "load_benchmark", lambda: then)
-    _nemotron_cell_s_metrics_as_pr_38_wrote_it()
+from benchmark.tests.test_jamba2_cell import (  # noqa: E402,F401
+    test_the_configuration_is_the_catalog_s_with_nothing_reduced,
+    test_the_stated_cache_is_two_paged_layers_of_one_head_and_two_arrays_a_mamba_layer,
+    test_the_two_scan_kernels_the_grouped_query_kernel_and_a_chunk_s_work_by_hand,
+    test_the_whole_model_s_arithmetic_reckoned_again,
+    test_wrong_reference_swaps_the_mixers_of_the_layers_it_moves,
+)
+from benchmark.tests.test_jamba2_cell import (  # noqa: E402,F401
+    test_runner_fails_at_once_where_the_program_has_no_such_family as test_jamba_runner_fails_at_once_where_the_program_has_no_such_family,
+    test_the_cell_s_metrics_are_the_entries_of_benchmark_json as test_the_jamba_cell_s_metrics_are_the_entries_of_benchmark_json,
+)
