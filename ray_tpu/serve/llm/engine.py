@@ -4,7 +4,8 @@ runtime; PAPERS.md "Fine-Tuning and Serving Gemma 4 31B on Google Cloud
 TPU" for the TPU-native decode shape).
 
 Execution model: one asyncio loop task per engine ("the step loop"),
-which keeps one decode program in flight.  Each iteration:
+which keeps one decode program in flight, and a second behind it while
+the device is the one that waits.  Each iteration:
 
 1. cancelled sequences leave the batch and free their KV blocks;
 2. waiting requests join free decode lanes (admission reserved their
@@ -19,7 +20,10 @@ which keeps one decode program in flight.  Each iteration:
    tables and temperatures, none of which leave the device (a join
    writes its lane's row there, a leave the device cannot foresee clears
    it), and only then are the programs dispatched before it fetched, in
-   order, and their tokens emitted (docs/serving.md "What a step is made of").
+   order, and their tokens emitted (docs/serving.md "What a step is made
+   of"); where the loop is seldom blocked in those fetches, it waits only
+   for what was dispatched before the decode step BEFORE the new one and
+   takes of the rest what the device has finished (``_fetch_in_flight``).
 
 Tokens stream to per-request asyncio queues; the serve replica's
 ``handle_request_stream`` path turns them into stream items.  The jit
@@ -108,6 +112,15 @@ _LOOP_WAITS = (
 # it) that takes longer than this outside ``engine.idle`` is a stall:
 # counted, and logged with its milliseconds by phase.
 STALL_S = 1.0
+# The loop leaves a second decode step queued behind the running one
+# while it is the one waited for: while, of its last AHEAD_WINDOW
+# iterations' time outside ``engine.idle``, it spent under
+# AHEAD_BLOCKED_SHARE blocked in the two fetch phases.  A loop whose
+# device sets the pace reads 0.55-0.9 there and one the device waits
+# for 0.2, 0.1 once it is ahead (PERF.md §6, PR 51): being ahead lowers
+# the share, so neither state undoes itself.
+AHEAD_BLOCKED_SHARE = 0.5
+AHEAD_WINDOW = 32
 
 
 @dataclass
@@ -341,8 +354,12 @@ class LLMEngine:
         self._wake: Optional[asyncio.Event] = None
         self._stopped = False
         # programs dispatched and not yet fetched, oldest first: between
-        # two iterations at most one decode step, the newest
+        # two iterations at most two decode steps, the newest
         self._inflight: Deque[_InFlight] = collections.deque()
+        # the loop's clock at the end of each of its last iterations:
+        # (seconds outside engine.idle, seconds blocked in the two fetch
+        # phases), both cumulative (_device_waits)
+        self._pace: Deque[tuple] = collections.deque(maxlen=AHEAD_WINDOW + 1)
         # the executor's future of the jit call under way (stop() waits for it)
         self._dispatching: Optional[asyncio.Future] = None
         self.step_count = 0
@@ -363,9 +380,11 @@ class LLMEngine:
             "kv_positions_attended": 0, "kv_positions_gathered": 0,
             "stalls": 0, "stall_s": 0.0,
             # decode steps dispatched while the one before was unfetched
-            # (the pipeline engaged), and lane-steps whose request had
-            # ended (eos_token, cancel) by the time their token came
-            "decodes_chained": 0, "lane_steps_discarded": 0,
+            # (the pipeline engaged), those dispatched while the two
+            # before were (the loop ran a step ahead), and lane-steps
+            # whose request had ended (eos_token, cancel) by the time
+            # their token came
+            "decodes_chained": 0, "decodes_ahead": 0, "lane_steps_discarded": 0,
             # bytes of host-made arguments the decode dispatches carried
             # (0: a step sends the device nothing it already has), and
             # rows of the device's lane state written or cleared (joins
@@ -740,6 +759,8 @@ class LLMEngine:
     async def _run(self):
         loop = asyncio.get_running_loop()
         self._note_stall()  # the first slice begins now
+        self._pace.clear()  # and nothing is known of the loop's pace
+        self._note_pace()
         with self._gc_watched():
             while not self._stopped:
                 try:
@@ -796,6 +817,12 @@ class LLMEngine:
             self._counts["gc_full_collections"] += int(info["generation"] == 2)
 
     async def _iterate(self, loop):
+        """One iteration: leaves, a preemption, joins (their prefills go
+        in front of this iteration's decode step), one decode step, then
+        the fetches.  The loop blocks on what was dispatched before the
+        new step, or, while the device is the one that waits
+        (``_device_waits``), before the step BEFORE it, and takes of the
+        rest what is finished: one or two decode steps stay queued."""
         with self._phase("engine.admit"):
             self._reap()
             victim, for_req = self._preempt_victim()
@@ -826,13 +853,30 @@ class LLMEngine:
                     await asyncio.sleep(0.005)
             return
         # the device now has the next step to run: wait for what was
-        # dispatched before it, and emit
+        # dispatched before it (or before the step before it), and emit
         self._fetch_in_flight(keep=newest)
+        self._note_pace()
         with self._phase("engine.metrics"):
             self._push_metrics()
         # step boundary: let pending add_request/cancel callbacks run
         with self._phase("engine.yield", span=False):
             await asyncio.sleep(0)
+
+    def _note_pace(self):
+        """The end of an iteration that ran something, on the loop's
+        own clock: its seconds so far outside ``engine.idle``, and those
+        of them blocked on the device in the two fetch phases."""
+        spent = self._phase_s
+        self._pace.append((time.perf_counter() - spent["engine.idle"],
+                           spent["engine.decode.fetch"] + spent["engine.prefill.fetch"]))
+
+    def _device_waits(self) -> bool:
+        """Whether the loop is the one waited for: over its last
+        AHEAD_WINDOW iterations it was blocked on the device for less
+        than AHEAD_BLOCKED_SHARE of its time.  False until an iteration
+        has been clocked."""
+        (t0, blocked0), (t1, blocked1) = self._pace[0], self._pace[-1]
+        return blocked1 - blocked0 < AHEAD_BLOCKED_SHARE * (t1 - t0)
 
     def _note_stall(self):
         """End a slice of the loop (called after every prefill and every
@@ -1176,7 +1220,7 @@ class LLMEngine:
 
             first_tok = await self._dispatch(loop, "engine.prefill", call)
             # a chunk before the last is fetched for its counters alone
-            self._inflight.append(_InFlight(first_tok, [(0, req)] if last else [], counts, decode=False))
+            self._enqueue(_InFlight(first_tok, [(0, req)] if last else [], counts, decode=False))
         req.dispatched += 1
 
     async def _dispatch_decode(self, loop) -> Optional[_InFlight]:
@@ -1206,7 +1250,8 @@ class LLMEngine:
             return None
         with self._phase("engine.decode.build"):
             bs = self.bm.block_size
-            counts = {"decodes_chained": int(any(p.decode for p in self._inflight)),
+            unfetched = sum(p.decode for p in self._inflight)
+            counts = {"decodes_chained": int(unfetched >= 1), "decodes_ahead": int(unfetched >= 2),
                       "state_bytes": 2 * self._state_bytes}
             if "kv_positions_gathered" not in self._counter_names:
                 # every cached position of every lane is read; a family
@@ -1232,13 +1277,34 @@ class LLMEngine:
         for _, req in lanes:
             req.dispatched += 1
             self.bm.advance(req.request_id, 1)
-        self._inflight.append(_InFlight(nxt, lanes, counts))
-        return self._inflight[-1]
+        return self._enqueue(_InFlight(nxt, lanes, counts))
+
+    def _enqueue(self, prog: _InFlight) -> _InFlight:
+        """A dispatched program joins those in flight, and its tokens
+        start for the host where it ends on the device, not where the
+        loop comes to fetch it: the fetch of a finished program then
+        finds them there."""
+        prog.out.copy_to_host_async()
+        self._inflight.append(prog)
+        return prog
 
     def _fetch_in_flight(self, keep: Optional[_InFlight] = None):
         """Fetch the programs in flight, oldest first, and emit their
-        tokens; all of them, or all dispatched before ``keep``."""
+        tokens; all of them, or all dispatched before ``keep``, the
+        newest decode step.  While the device is the one that waits
+        (``_device_waits``) the loop blocks only on those dispatched
+        before the decode step before ``keep`` and fetches of the others
+        what the device has finished (``is_ready()``): that step stays
+        queued behind what runs."""
+        wait_before = keep
+        if keep is not None and self._device_waits():
+            wait_before = next((p for p in reversed(self._inflight) if p.decode and p is not keep),
+                               self._inflight[0])
+        blocking = True
         while self._inflight and self._inflight[0] is not keep:
+            blocking = blocking and self._inflight[0] is not wait_before
+            if not (blocking or self._inflight[0].out.is_ready()):
+                break
             self._fetch(self._inflight.popleft())
 
     def _fetch(self, prog: _InFlight):

@@ -39,3 +39,52 @@ def ray_start_regular():
     ray_tpu.init(num_cpus=4, ignore_reinit_error=True)
     yield ray_tpu
     ray_tpu.shutdown()
+
+
+class _Unfinished:
+    """A program's output that never says it is finished: the step loop
+    fetches it only where it blocks."""
+
+    def __init__(self, out):
+        self.out = out
+
+    def is_ready(self):
+        return False
+
+    def __array__(self, *args, **kwargs):
+        import numpy as np
+
+        return np.asarray(self.out)
+
+
+@pytest.fixture
+def hold_depth():
+    """-> hold(eng, depth): an LLMEngine's step loop held to ``depth``
+    decode steps dispatched and unfetched between two iterations, through
+    what the loop observes and through no argument of its own.  1: every
+    fetch is clocked as a second blocked on the device, so the loop is
+    never the one waited for.  2: its clock never reads a fetch as
+    blocked, and no program in flight says it is finished, so the loop
+    fetches exactly what it blocks on."""
+
+    def hold(eng, depth):
+        fetch, fetch_in_flight = eng._fetch, eng._fetch_in_flight
+
+        def clocked(prog):
+            fetch(prog)
+            if depth == 1:
+                eng._phase_s["engine.decode.fetch"] += 1.0
+            else:
+                eng._phase_s["engine.decode.fetch"] = eng._phase_s["engine.prefill.fetch"] = 0.0
+
+        def unfinished(keep=None):
+            for prog in eng._inflight:
+                if not isinstance(prog.out, _Unfinished):
+                    prog.out = _Unfinished(prog.out)
+            fetch_in_flight(keep)
+
+        eng._fetch = clocked
+        if depth == 2:
+            eng._fetch_in_flight = unfinished
+
+    return hold

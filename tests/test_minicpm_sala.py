@@ -439,17 +439,29 @@ def test_preemption_by_recompute_gives_the_same_tokens_and_leaves_nothing():
     prompt, n = _tokens(90, seed=8).tolist(), 24
 
     async def run(preempt):
-        eng = _engine(max_batch_size=1, preempt_wait_s=0.005, tenant_weights={"a": 1.0, "b": 1.0})
+        eng = _engine(max_batch_size=1, preempt_wait_s=0.0, tenant_weights={"a": 1.0, "b": 1.0})
         hog = await eng.add_request(prompt, max_tokens=n, tenant="a", slo="batch")
-        others = []
+        sent = []
         if preempt:
-            for wave in range(2):
-                while hog.generated < 4 * (wave + 1) or hog.slot < 0:
-                    await asyncio.sleep(0.005)
-                others.append(await eng.add_request(_tokens(70, seed=wave).tolist(), max_tokens=3,
-                                                    tenant="b", slo="interactive"))
-                while not others[-1].finish_reason:
-                    await asyncio.sleep(0.005)
+            fetch = eng._fetch
+
+            def fetch_then_send(prog):
+                """Each preemptor is sent from the loop's own thread, at the
+                fetch after which the hog holds the lane with 4 (then 8)
+                tokens out and the preemptor before it has ended; it evicts
+                at the next iteration (no wait).  A poll from outside can
+                find the hog finished on a loaded host."""
+                fetch(prog)
+                wave = len(sent)
+                if (wave < 2 and hog.slot >= 0 and hog.generated >= 4 * (wave + 1)
+                        and all(t.done() and t.result().finish_reason for t in sent)):
+                    sent.append(asyncio.ensure_future(eng.add_request(
+                        _tokens(70, seed=wave).tolist(), max_tokens=3, tenant="b", slo="interactive")))
+
+            eng._fetch = fetch_then_send
+            while len(sent) < 2 and not hog.finish_reason:
+                await asyncio.sleep(0.005)
+        others = [await t for t in sent]
         await asyncio.gather(*[_drain(r) for r in [hog] + others])
         stats = eng.stats()
         await eng.stop()

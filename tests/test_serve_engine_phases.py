@@ -114,6 +114,116 @@ def test_decode_steps_are_chained_on_a_full_batch_and_never_on_single_steps(batc
     assert (st["steps"], st["decodes_chained"], st["total_tokens"]) == (3, 0, 7)
 
 
+@pytest.mark.parametrize("depth", [1, 2])
+def test_decodes_ahead_counts_the_steps_dispatched_behind_two(depth, hold_depth, batch_run):
+    """``decodes_ahead`` counts the decode steps dispatched while the two
+    before them were unfetched: none where every fetch blocks (the loop
+    is then never the one waited for), nearly all where none does; the
+    tokens are the same at either depth, and ``decodes_chained`` keeps
+    its meaning."""
+    _, outs, natural, _ = batch_run
+
+    async def main():
+        eng = LLMEngine(_tiny())
+        hold_depth(eng, depth)
+        got = await _generate(eng, BATCH)
+        st = eng.stats()
+        await eng.stop()
+        return got, st
+
+    got, st = asyncio.run(main())
+    assert got == outs
+    assert st["steps"] / 2 < st["decodes_chained"] < st["steps"]
+    if depth == 1:
+        assert st["decodes_ahead"] == 0
+    else:
+        assert st["steps"] / 2 < st["decodes_ahead"] < st["decodes_chained"]
+    # left to itself the loop lies between the two, whatever this host's pace
+    assert 0 <= natural["decodes_ahead"] < natural["decodes_chained"]
+    assert st["lane_steps_discarded"] == 0 and st["kv_blocks_in_use"] == 0
+
+
+@pytest.mark.parametrize("kind", ["prefill", "decode"])
+def test_a_dispatched_program_has_its_copy_to_the_host_asked_for_at_once(kind):
+    """Both dispatch sites end in ``_enqueue``: the program's output is
+    asked to start for the host exactly once, before the program joins
+    those in flight (so before any fetch of it), and ``_InFlight`` stays
+    a plain record: building one starts nothing."""
+
+    class Spy:
+        def __init__(self, out, log):
+            self.out, self.log = out, log
+
+        def copy_to_host_async(self):
+            self.log.append("copy")
+            self.out.copy_to_host_async()
+
+        def is_ready(self):
+            return self.out.is_ready()
+
+        def __array__(self, *args, **kwargs):
+            import numpy as np
+
+            self.log.append("fetch")
+            return np.asarray(self.out)
+
+    engine_mod._InFlight(out=object(), lanes=[], counts={})  # no transfer, no attribute of ``out`` touched
+
+    async def main():
+        eng = LLMEngine(_tiny())
+        enqueue, logs = eng._enqueue, []
+
+        def spied(prog):
+            if prog.decode == (kind == "decode"):
+                logs.append(["queued" if any(p is prog for p in eng._inflight) else "new"])
+                prog.out = Spy(prog.out, logs[-1])
+            return enqueue(prog)
+
+        eng._enqueue = spied
+        outs = await _generate(eng, BATCH)
+        await eng.stop()
+        return outs, logs
+
+    outs, logs = asyncio.run(main())
+    assert [len(o) for o in outs] == [m for _, m in BATCH]
+    assert len(logs) >= len(BATCH) - (kind == "decode") and all(log == ["new", "copy", "fetch"] for log in logs), logs
+
+
+def test_the_loop_runs_ahead_only_while_the_device_waits_for_it(monkeypatch):
+    """The observation itself, on the engine's clock: of the last
+    AHEAD_WINDOW iterations' time outside engine.idle, the share blocked
+    in the two fetch phases, against AHEAD_BLOCKED_SHARE; nothing is
+    known before an iteration has been clocked, and idle time is no
+    time."""
+    import types
+
+    eng = LLMEngine(_tiny())
+    clock = {"t": 100.0}
+    monkeypatch.setattr(engine_mod, "time", types.SimpleNamespace(perf_counter=lambda: clock["t"]))
+
+    def iteration(took_s, blocked_s, idle_s=0.0):
+        clock["t"] += took_s + idle_s
+        eng._phase_s["engine.idle"] += idle_s
+        eng._phase_s["engine.decode.fetch"] += blocked_s / 2
+        eng._phase_s["engine.prefill.fetch"] += blocked_s / 2
+        eng._note_pace()
+
+    eng._note_pace()
+    assert not eng._device_waits()  # nothing clocked yet
+    iteration(0.006, 0.0013)  # a fifth blocked: the device waits for the loop
+    assert eng._device_waits()
+    for _ in range(engine_mod.AHEAD_WINDOW):
+        iteration(0.024, 0.017, idle_s=1.0)  # 71% blocked: the device sets the pace
+    assert not eng._device_waits()
+    for _ in range(engine_mod.AHEAD_WINDOW // 2 - 4):
+        iteration(0.006, 0.0004)
+    assert not eng._device_waits()  # the window still holds more blocked time than half
+    for _ in range(engine_mod.AHEAD_WINDOW // 2 + 4):
+        iteration(0.006, 0.0004)
+    assert eng._device_waits()
+    assert len(eng._pace) == engine_mod.AHEAD_WINDOW + 1
+
+
 def test_phase_seconds_fit_the_wall_time(batch_run):
     _, _, st, wall_s = batch_run
     for name in ENGINE_SPANS:
@@ -277,7 +387,7 @@ _BEFORE = {
     "admit_s": 0.10, "prefill_build_s": 0.05, "prefill_run_s": 0.20, "prefill_await_s": 0.25,
     "prefill_fetch_s": 4.0, "decode_build_s": 2.0, "decode_run_s": 2.5, "decode_await_s": 3.0,
     "decode_fetch_s": 108.0, "emit_s": 0.30, "metrics_s": 0.20, "yield_s": 0.40, "idle_s": 50.0,
-    "decodes_chained": 700,
+    "decodes_chained": 700, "decodes_ahead": 300,
 }
 _AFTER = {
     "steps": 1250, "max_batch_size": 16, "platform": "tpu", "total_tokens": 12000,
@@ -286,14 +396,18 @@ _AFTER = {
     "admit_s": 0.15, "prefill_build_s": 0.07, "prefill_run_s": 0.28, "prefill_await_s": 0.35,
     "prefill_fetch_s": 6.08, "decode_build_s": 2.5, "decode_run_s": 3.125, "decode_await_s": 3.75,
     "decode_fetch_s": 134.0, "emit_s": 0.45, "metrics_s": 0.25, "yield_s": 0.55, "idle_s": 50.5,
-    "decodes_chained": 900,
+    "decodes_chained": 900, "decodes_ahead": 425,
 }
 # host time a step: admit 0.05 + build 0.5 + await 0.75 (dispatch and
 # hop) + emit 0.15 + metrics 0.05 + yield 0.15 = 1.65 s over 250 steps;
 # prefill: build 0.02 + await 0.10 + fetch 2.08 = 2.2 s of 30; 200 of
-# the 250 decode steps were dispatched while the one before was unfetched
-_CELL = {"steady": "gpt2-large.serve.chat-steady", "backlog": "gpt2-large.serve.batch-backlog",
-         "moe": "olmoe-1b-7b.serve.backlog-wide"}
+# the 250 decode steps were dispatched while the one before was
+# unfetched, 125 while the two before were
+_CELL = {"steady": ["gpt2-large.serve.chat-steady"], "backlog": ["gpt2-large.serve.batch-backlog"],
+         "moe": ["olmoe-1b-7b.serve.backlog-wide"],
+         # the seven other backlog cells' own tests pin their per-layer sets
+         # (benchmark/tests/test_*_cell.py; PERF.md section 7)
+         "decode_ahead_pct": ["gpt2-large.serve.batch-backlog"]}
 _BY_HAND = {
     "queue_wait_ms.steady": 1000 * 3.0 / 40,
     "prefill_share_pct.steady": 100 * 2.2 / 30,
@@ -307,6 +421,8 @@ _BY_HAND = {
     "decode_overlap_pct.steady": 80.0,
     "decode_overlap_pct.backlog": 80.0,
     "decode_overlap_pct.moe": 80.0,
+    "decode_ahead_pct": 50.0,
+    "decode_ahead_pct.steady": 50.0,
 }
 
 
@@ -321,10 +437,10 @@ def test_layer_metric_reads_the_engine_counters(metric):
     # on a program that lacks the counters the metric is left out, not raised
     old = {"before": {"steps": 1000}, "after": {"steps": 1250}, "window_s": 30.0}
     assert readers.stats_delta(how["args"], {"values": {}, "stats": old}) is None
-    # and BENCHMARK.json reports it in its one cell, under the layer's name
+    # and BENCHMARK.json reports it in its cells, under the layer's name
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         entry = next(m for m in json.load(f)["per_layer"] if m["name"] == metric)
-    assert entry["workloads"] == [_CELL[metric.rsplit(".", 1)[1]]]
+    assert entry["workloads"] == _CELL[metric.rsplit(".", 1)[-1]]
     assert (entry["layer"], entry["source"]) == ("serve plane", "program_counter")
 
 

@@ -84,7 +84,7 @@ class Recorder:
             return out
 
         def put_lane(lane_tok, lanes, first, lane, row, temp):
-            self.events.append(("put", int(lane), None))
+            self.events.append(("put", int(lane), int(row[1])))  # the decode steps the joined request has left
             return put(lane_tok, lanes, first, lane, row, temp)
 
         async def dispatch_decode(loop):
@@ -252,18 +252,35 @@ def test_the_loop_decodes_on_what_the_host_would_have_built(model, temperature):
         assert got == want, lane
 
 
+@pytest.mark.parametrize("depth", [1, 2])
 @pytest.mark.parametrize("model", FAMILIES)
-def test_a_leave_the_device_cannot_foresee_clears_the_row(model):
-    """eos_token, cancel() and a preemption: the host finds out, the
-    lane's row is cleared by one edit each, every step before and after
-    runs on what the host would have built, the resumed victim's row is
-    written again, and blocks, state slots and rows go back to zero."""
+def test_a_leave_the_device_cannot_foresee_clears_the_row(model, depth, hold_depth):
+    """eos_token, cancel() and a preemption, with one decode step in
+    flight or two: the host finds out, the lane's row is cleared by one
+    edit each, every step before and after runs on what the host would
+    have built, the resumed victim's row is written again, and blocks,
+    state slots and rows go back to zero.  A preemption fetches every
+    program in flight before it folds: what is folded was emitted."""
     config = _config(model, max_batch_size=2, preempt_wait_s=1e9, tenant_weights={"a": 1.0, "b": 1.0})
     n = 24
 
     async def main():
         eng = LLMEngine(config)
         rec = Recorder(eng)
+        hold_depth(eng, depth)
+        pick, fold, folds = eng._preempt_victim, eng._preempt, []
+
+        def preempt_victim():
+            victim, for_req = pick()
+            if victim is not None and eng._inflight:  # the first of an iteration's two decisions
+                folds.append([len(eng._inflight)])
+            return victim, for_req
+
+        def preempt(req, for_req=None):
+            folds[-1] += [len(eng._inflight), req.dispatched - req.generated]
+            return fold(req, for_req)
+
+        eng._preempt_victim, eng._preempt = preempt_victim, preempt
         free, other = await asyncio.gather(*[_drain(await eng.add_request(_prompt(*p), max_tokens=n))
                                              for p in ((5, 1), (7, 2))])
         # eos_token: a token of the free run past the prefill's own that came
@@ -295,11 +312,14 @@ def test_a_leave_the_device_cannot_foresee_clears_the_row(model):
         outs = await asyncio.gather(_drain(stays), _drain(victim), _drain(urgent))
         st = eng.stats()
         await eng.stop()
-        return eng, rec, st, edits, outs, (stays, victim, urgent)
+        return eng, rec, st, edits, outs, (stays, victim, urgent), folds
 
-    eng, rec, st, edits, outs, (stays, victim, urgent) = asyncio.run(main())
+    eng, rec, st, edits, outs, (stays, victim, urgent), folds = asyncio.run(main())
     assert not rec.errors, rec.errors[0]
     assert [len(o) for o in outs] == [30, 40, 5]
+    # decided with ``depth`` decode steps in flight, folded with none, nothing of the victim's unfetched
+    assert folds and all(f[0] >= depth and f[1:] == [0, 0] for f in folds), folds
+    assert depth - 1 <= st["lane_steps_discarded"] // 2 <= depth  # eos and cancel(): a lane-step a step in flight
     assert st["preemptions_total"] >= 1 and stays.preemptions + victim.preemptions == st["preemptions_total"]
     assert edits == {"eos": 1, "cancel": 2}
     # a row a join (a resumed victim joins again), and one cleared for each leave by eos, cancel and preemption
@@ -307,6 +327,91 @@ def test_a_leave_the_device_cannot_foresee_clears_the_row(model):
     assert st["lane_steps_discarded"] >= 1 and st["decode_host_bytes"] == 0
     assert rec.decode_steps == st["steps"]
     _balanced(eng, st)
+
+
+@pytest.mark.parametrize("model", FAMILIES)
+def test_the_same_requests_give_the_same_tokens_at_either_depth(model, hold_depth):
+    """One decode step queued behind the running one or two: the same
+    requests, all there from the start, get the same SAMPLED tokens from
+    the same programs in the same order (a step's key follows from how
+    many steps were dispatched, its lanes from who was dispatched for),
+    every step runs on what the host would have built, and a join's
+    prefill goes in front of the iteration's decode step, never behind
+    it: the step dispatched next runs the joined lane."""
+    config = _config(model, temperature=0.9, top_k=20)
+
+    async def run(depth):
+        eng = LLMEngine(config)
+        rec = Recorder(eng)
+        hold_depth(eng, depth)
+        prefill, behind = eng._prefill, []
+
+        async def prefill_behind(loop, req):
+            behind.append(sum(p.decode for p in eng._inflight))  # decode steps a join's prefill is queued behind
+            return await prefill(loop, req)
+
+        eng._prefill = prefill_behind
+        reqs = [await eng.add_request(_prompt(n, i), max_tokens=m) for i, (n, m) in enumerate(BATCH)]
+        outs = await asyncio.gather(*[_drain(r) for r in reqs])
+        st = eng.stats()
+        await eng.stop()
+        assert not rec.errors, rec.errors[0]
+        _balanced(eng, st)
+        return rec, outs, st, behind
+
+    (one, outs_one, st_one, behind_one), (two, outs_two, st_two, behind_two) = (
+        asyncio.run(run(depth)) for depth in (1, 2))
+    assert outs_one == outs_two and [len(o) for o in outs_one] == [m for _, m in BATCH]
+    assert [kind for kind, *_ in one.events] == [kind for kind, *_ in two.events]
+    assert _loop_tokens(one.events) == _loop_tokens(two.events)
+    assert st_one["steps"] == st_two["steps"] and st_one["decodes_chained"] == st_two["decodes_chained"]
+    assert st_one["decodes_ahead"] == 0 and max(behind_one) == 1
+    assert st_two["decodes_ahead"] > st_two["steps"] / 2 and max(behind_two) == 2
+    assert st_one["lane_steps_discarded"] == st_two["lane_steps_discarded"] == 0
+    for rec in (one, two):
+        for k, (kind, lane, left) in enumerate(rec.events):
+            if kind == "put" and left:  # (a request answered by its prefill alone runs no step)
+                lengths = next(args[0] for what, args, _ in rec.events[k:] if what == "decode")
+                assert lengths[lane] > 0, (k, lane)
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_a_step_that_raises_leaves_device_and_mirror_where_they_were(depth, hold_depth):
+    """A decode call that raises with one or two steps in flight behind
+    it: those are fetched and emitted, the lanes' state on the device is
+    what the mirror says after every fetch, the step is dispatched again
+    on the same arguments, and every request gets the tokens of a run
+    nothing happened to."""
+    config = _config("tiny")
+
+    async def run(plant):
+        eng = LLMEngine(config)
+        rec = Recorder(eng)
+        hold_depth(eng, depth)
+        reqs = [await eng.add_request(_prompt(n, i), max_tokens=m) for i, (n, m) in enumerate(BATCH)]
+        raised = []
+        if plant:
+            while reqs[2].generated < 4:
+                await asyncio.sleep(0.002)
+            jit = eng._decode_jit
+
+            def once(*args):
+                eng._decode_jit = jit
+                raised.append(sum(p.decode for p in eng._inflight))
+                raise RuntimeError("planted")
+
+            eng._decode_jit = once
+        outs = await asyncio.gather(*[_drain(r) for r in reqs])
+        st = eng.stats()
+        await eng.stop()
+        assert not rec.errors, rec.errors[0]
+        assert rec.decode_steps == st["steps"] == int(eng._lanes["step"])
+        _balanced(eng, st)
+        return outs, raised
+
+    (outs, raised), (want, _) = asyncio.run(run(True)), asyncio.run(run(False))
+    assert outs == want
+    assert raised == [depth]  # the decode steps in flight when it raised
 
 
 def test_stop_clears_every_row_and_a_restart_starts_clean():

@@ -291,14 +291,17 @@ def test_engine_mixed_batch_equals_one_request_at_a_time(model):
     assert 0 < st["decodes_chained"] <= st["steps"] < st_alone["steps"]
 
 
-def test_engine_successor_joins_before_the_last_token_is_fetched():
+@pytest.mark.parametrize("depth", [1, 2])
+def test_engine_successor_joins_before_the_last_token_is_fetched(depth, hold_depth):
     """A lane that ends by length is known a step ahead: the next
-    request takes it while the step with the last token is in flight,
-    so the lane stands empty for no step; FINISHED still follows the
-    last token, and a lane never decodes for two requests at once."""
+    request takes it while the step with the last token is in flight
+    (the last two, where the loop runs a step ahead), so the lane stands
+    empty for no step; FINISHED still follows the last token, and a lane
+    never decodes for two requests at once."""
 
     async def main():
         eng = LLMEngine(_tiny(max_batch_size=1, temperature=0.0))
+        hold_depth(eng, depth)
         a = await eng.add_request([1, 2, 3], max_tokens=6)
         b = await eng.add_request([4, 5], max_tokens=4)
         outs = await asyncio.gather(_drain(a), _drain(b))
@@ -310,23 +313,27 @@ def test_engine_successor_joins_before_the_last_token_is_fetched():
     alone, _ = asyncio.run(_one_at_a_time(_tiny(max_batch_size=1, temperature=0.0),
                                           [([1, 2, 3], 6), ([4, 5], 4)]))
     assert outs == alone
-    assert b.join_step == a.finish_step - 1  # joined with a's last step dispatched, not fetched
+    assert b.join_step == a.finish_step - depth  # joined with a's last steps dispatched, not fetched
     assert st["steps"] == 5 + 3 and st["lane_steps_discarded"] == 0
     assert st["decodes_chained"] == st["steps"] - 1  # b's prefill did not break the chain
+    assert st["decodes_ahead"] == (st["steps"] - 2) * (depth - 1)  # nor the second step behind it
     assert st["kv_leak_report"]["blocks_in_use"] == 0
 
 
+@pytest.mark.parametrize("depth", [1, 2])
 @pytest.mark.parametrize("at", [0, 4])
-def test_engine_eos_ends_the_request_a_lane_step_late(at):
+def test_engine_eos_ends_the_request_a_lane_step_late(at, depth, hold_depth):
     """The request ends at eos_token and nothing after it is emitted,
-    though the next step was dispatched with its lane: that token is
-    dropped and counted.  ``at`` 0: the prefill's own token ends it."""
+    though the next step (the next two, where the loop runs a step
+    ahead) was dispatched with its lane: those tokens are dropped and
+    counted.  ``at`` 0: the prefill's own token ends it."""
     prompt, n = [3, 1, 4, 1, 5], 12
 
     async def main():
         (free,), _ = await _one_at_a_time(_tiny(temperature=0.0), [(prompt, n)])
         eos = free[at]
         eng = LLMEngine(_tiny(temperature=0.0, eos_token=eos))
+        hold_depth(eng, depth)
         req = await eng.add_request(prompt, max_tokens=n)
         other = await eng.add_request([2, 7, 1], max_tokens=n)
         toks, others = await asyncio.gather(_drain(req), _drain(other))
@@ -337,26 +344,30 @@ def test_engine_eos_ends_the_request_a_lane_step_late(at):
     free, eos, req, toks, others, st = asyncio.run(main())
     assert toks == free[:free.index(eos) + 1] and len(toks) < n
     assert req.finish_reason == "eos" and req.generated == len(toks)
-    assert st["lane_steps_discarded"] >= 1
+    # a lane-step for each step in flight behind the one that brought eos
+    assert depth <= st["lane_steps_discarded"] <= depth * (1 + (eos in others))
     assert st["total_tokens"] == len(toks) + len(others)  # a dropped token is not counted
     assert st["kv_leak_report"]["blocks_in_use"] == 0
     assert st["kv_leak_report"]["live_sequences"] == 0
 
 
-def test_engine_cancel_and_stop_with_a_step_in_flight():
+@pytest.mark.parametrize("depth", [1, 2])
+def test_engine_cancel_and_stop_with_a_step_in_flight(depth, hold_depth):
     """cancel() of a running request and stop() while a jit call that
-    donates the pool is under way: blocks balance to zero, the engine
-    stays bound to live buffers, and it serves again after a restart."""
+    donates the pool is under way, with one decode step in flight or
+    two: blocks balance to zero, the engine stays bound to live buffers,
+    and it serves again after a restart."""
     prompt, n = [3, 1, 4], 6
 
     async def main():
         eng = LLMEngine(_tiny(temperature=0.0))
         want = await _drain(await eng.add_request(prompt, max_tokens=n))
+        hold_depth(eng, depth)
         a = await eng.add_request([1, 2], max_tokens=200)
         b = await eng.add_request([3], max_tokens=200)
         while a.generated < 3:
             await asyncio.sleep(0.005)
-        assert eng._inflight, "no step in flight between two iterations"
+        assert [p.decode for p in eng._inflight] == [True] * depth, "the steps in flight between two iterations"
         eng.cancel(a.request_id)
         sent = await _drain(a)
         assert a.finish_reason == "cancelled" and sent == a.tokens
@@ -385,12 +396,13 @@ def test_engine_cancel_and_stop_with_a_step_in_flight():
 
     want, again, discarded = asyncio.run(main())
     assert again == want
-    assert discarded >= 1  # a's lane-step that was in flight when it was cancelled
+    assert discarded == depth  # a's lane-steps that were in flight when it was cancelled
 
 
+@pytest.mark.parametrize("depth", [1, 2])
 @pytest.mark.parametrize("what", ["jit_call", "executor_shut_down"])
-def test_engine_failed_step_ends_no_stream_short(what):
-    """A step that raises retires the step in flight first (its tokens
+def test_engine_failed_step_ends_no_stream_short(what, depth, hold_depth):
+    """A step that raises retires the steps in flight first (their tokens
     are real and go out in order) and never becomes a clean end: after a
     jit call that raised the loop goes on and every request still gets
     its max_tokens; with the default executor shut down (a replica on
@@ -401,10 +413,11 @@ def test_engine_failed_step_ends_no_stream_short(what):
     async def main():
         (free,), _ = await _one_at_a_time(_tiny(temperature=0.0), [(prompt, n)])
         eng = LLMEngine(_tiny(temperature=0.0))
+        hold_depth(eng, depth)
         req = await eng.add_request(prompt, max_tokens=n)
         while req.generated < 3:
             await asyncio.sleep(0.005)
-        seen = []  # tokens on the host when the step raised: one more is in flight
+        seen = []  # tokens on the host when the step raised: ``depth`` more are in flight
 
         def planted(*args):
             seen.append(len(req.tokens))
@@ -433,12 +446,12 @@ def test_engine_failed_step_ends_no_stream_short(what):
         return free, seen, toks, req, eng.bm.leak_report()
 
     free, seen, toks, req, report = asyncio.run(main())
-    assert len(seen) == 1 and len(toks) > seen[0]  # the step in flight was fetched and emitted
+    assert len(seen) == 1 and len(toks) > seen[0]  # the steps in flight were fetched and emitted
     assert toks[:seen[0] + 1] == free[:seen[0] + 1]
     if what == "jit_call":
         assert len(toks) == n and req.finish_reason == "length"
     else:
-        assert len(toks) == seen[0] + 1 and req.finish_reason == "engine_stopped"
+        assert len(toks) == seen[0] + depth and req.finish_reason == "engine_stopped"
     assert report["blocks_in_use"] == 0 and report["live_sequences"] == 0
 
 

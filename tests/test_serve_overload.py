@@ -407,11 +407,12 @@ def test_engine_preempt_with_a_step_in_flight_folds_what_the_client_was_sent():
     assert report["blocks_in_use"] == 0
 
 
-def test_engine_no_preemption_when_the_step_in_flight_frees_a_lane():
-    """The victim is picked with a step in flight; if the fetch of that
-    step ends another lane (here by eos_token), the starved request joins
-    there and nobody is evicted: the decision is made again on what is
-    true after the fetch."""
+@pytest.mark.parametrize("depth", [1, 2])
+def test_engine_no_preemption_when_the_step_in_flight_frees_a_lane(depth, hold_depth):
+    """The victim is picked with a step in flight (two, where the loop
+    runs a step ahead); if the fetch of those steps ends another lane
+    (here by eos_token), the starved request joins there and nobody is
+    evicted: the decision is made again on what is true after the fetch."""
     a_prompt, b_prompt, n, k = [6, 2, 8], [3, 1, 4, 1, 5], 24, 12
 
     async def main():
@@ -424,6 +425,7 @@ def test_engine_no_preemption_when_the_step_in_flight_frees_a_lane():
         assert eos not in a_free and b_free.index(eos) == k, "pick other prompts"
         # nobody is starved until b's step k, the one that ends it, is dispatched
         eng = LLMEngine(_tiny(eos_token=eos, preempt_wait_s=1e9, **cfg))
+        hold_depth(eng, depth)
         a = await eng.add_request(a_prompt, max_tokens=n, tenant="a", slo="batch")
         b = await eng.add_request(b_prompt, max_tokens=n, tenant="a", slo="batch")
         jit, picks = eng._decode_jit, []
@@ -450,7 +452,7 @@ def test_engine_no_preemption_when_the_step_in_flight_frees_a_lane():
         return a_free, b_free, outs, picks, a, stats
 
     a_free, b_free, outs, picks, a, stats = asyncio.run(main())
-    first = picks.index((True, 1, ""))  # a victim was picked with b's last step in flight
+    first = picks.index((True, depth, ""))  # a victim was picked with b's last step in flight
     assert picks[first + 1] == (False, 0, "eos")  # and given up after the fetch
     assert a.preemptions == 0 and stats["preemptions_total"] == 0
     assert outs[0] == a_free and outs[1] == b_free[:k + 1] and len(outs[2]) == 3
