@@ -6,9 +6,11 @@ from __future__ import annotations
 
 import logging
 import sys
+import time
 
 
 def main():
+    t_entry = time.time()  # where this process's ``setup.worker`` phase begins
     logging.basicConfig(level=logging.INFO, format="[worker %(asctime)s] %(message)s")
     import os
     import sys as _sys
@@ -29,8 +31,12 @@ def main():
             _sys.modules["jax"].config.update("jax_platforms", platforms)
         except Exception:
             pass
+    from ray_tpu._private import profiling
     from ray_tpu._private.worker import get_global_worker
 
+    # ends where a replica's ``__init__`` enters (serve/_private/replica.py);
+    # a worker that hosts none leaves it open and records nothing
+    profiling.begin_setup(at=t_entry).enter("setup.worker")
     worker = get_global_worker()
     worker.connect_worker()
     try:

@@ -549,113 +549,335 @@ def top_frames(records: List[Dict[str, Any]], n: int = 10) -> List[Tuple[str, in
 # ----------------------------------------------------------------------
 # JAX/XLA introspection (CPU-safe; no-ops when jax is absent)
 # ----------------------------------------------------------------------
+# The compile ledger.  JAX reports each part of making a program ready,
+# in the thread that made the call, as a ``jax.monitoring`` event; the
+# listeners book them under the name open in that thread (the
+# innermost ``instrument_jit`` call, else the set-up phase under way,
+# else "other") and in the process's totals.  A call that finds its
+# program in memory reports nothing, so the listeners run only where
+# JAX compiles.  docs/profiling.md has the table.
+_TRACED = "/jax/core/compile/jaxpr_trace_duration"
+# every program JAX lowers (a first call, a new shape or dtype).  A call
+# that only misses the jit's fast-path cache (the same shapes from
+# another place: NumPy arrays after device arrays) finds its traced and
+# compiled program in memory and lowers nothing, though that cache grows
+_LOWERED = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+# brackets ``compiler.compile_or_get_cached``: a real backend compile OR
+# the read and load of an executable the persistent cache held.  The hit
+# event fires inside the bracket, before the duration is reported
+_BACKEND = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+# reported where a compiled program is WRITTEN to the cache: one that
+# compiled in under ``jax_persistent_cache_min_compile_time_secs`` is a
+# backend miss that counts no cache miss
+_CACHE_MISS = "/jax/compilation_cache/cache_misses"
+_CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_SAVED = "/jax/compilation_cache/compile_time_saved_sec"
+_SPANS = {_TRACED: ("traces", "trace_s"), _LOWERED: ("lowerings", "lower_s"),
+          _BACKEND: ("backend_calls", None)}  # hit or miss: by what was last seen
+LEDGER_KEYS = (
+    "traces", "trace_s", "lowerings", "lower_s",
+    "backend_calls", "backend_hit_s", "backend_miss_s",
+    "cache_hits", "cache_misses", "cache_read_s", "cache_saved_s",
+)
+
+
+def _new_ledger() -> Dict[str, Any]:
+    return {k: 0.0 if k.endswith("_s") else 0 for k in LEDGER_KEYS}
+
+
 _jit_lock = threading.Lock()
 _jit_records: Dict[str, Dict[str, Any]] = {}
+_compile_totals: Dict[str, Any] = _new_ledger()
+_listening = False
 
 
-# JAX reports every program it lowers (a first call, a new shape or
-# dtype) as this event, in the thread that made the call.  A call that
-# only misses the jit's fast-path cache (the same shapes from another
-# place: NumPy arrays after device arrays) finds its traced and compiled
-# program in memory and lowers nothing, though that cache grows by it.
-_LOWERED_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
-_lowered = threading.local()
-_lowered_listening = False
+class _Thread:
+    """What one thread has open, for the listeners."""
+
+    __slots__ = ("jit", "booked", "split", "cache_hit", "spans")
+
+    def __init__(self):
+        self.jit: Optional[str] = None  # the innermost instrument_jit call's name
+        self.booked = 0  # events booked from this thread
+        self.split: Dict[str, Any] = {}  # those of the wrapped call under way
+        self.cache_hit = False  # a hit reported since the last backend call's span
+        # (start, count key, seconds key, seconds) of the spans no later
+        # one has enclosed yet
+        self.spans: List[Tuple[float, str, str, float]] = []
 
 
-def _programs_lowered() -> int:
-    """Programs the calling thread has lowered since the first
-    ``instrument_jit``."""
-    return getattr(_lowered, "n", 0)
+_threads = threading.local()
 
 
-def _listen_for_lowerings() -> None:
-    global _lowered_listening
+def _this_thread() -> _Thread:
+    mine = getattr(_threads, "mine", None)
+    if mine is None:
+        mine = _threads.mine = _Thread()
+    return mine
+
+
+def _book(mine: _Thread, **amounts) -> None:
+    setup = _setup
+    name = mine.jit or (setup.open if setup is not None else None) or "other"
     with _jit_lock:
-        if _lowered_listening:
-            return
-        import jax.monitoring
+        rec = _jit_records.get(name)
+        if rec is None:
+            rec = _jit_records[name] = _new_record()
+        for key, n in amounts.items():
+            rec[key] += n
+            _compile_totals[key] += n
+    mine.booked += 1
+    if mine.jit is not None:
+        split = mine.split
+        for key, n in amounts.items():
+            split[key] = split.get(key, 0) + n
 
-        def on_event(event, _secs, **_kw):
-            if event == _LOWERED_EVENT:
-                _lowered.n = getattr(_lowered, "n", 0) + 1
 
-        jax.monitoring.register_event_duration_secs_listener(on_event)
-        _lowered_listening = True
+def _on_span(event, start, end, **_kw) -> None:
+    keys = _SPANS.get(event)
+    if keys is None:
+        return
+    mine = _this_thread()
+    count, seconds = keys
+    if event == _BACKEND:
+        hit, mine.cache_hit = mine.cache_hit, False
+        seconds = "backend_hit_s" if hit else "backend_miss_s"
+    # What JAX does inside a span it reports before it: a jit traced
+    # while another is traced, the jitted helpers a lowering rule calls.
+    # The outermost span keeps the time and what it enclosed is taken
+    # off again, so a program is one trace, one lowering and one backend
+    # call whose seconds do not overlap.  (JAX reports the same seconds
+    # as durations; only this form says where they lie.)
+    amounts = {count: 1, seconds: end - start}
+    spans = mine.spans
+    while spans and spans[-1][0] >= start:
+        _, in_count, in_seconds, secs = spans.pop()
+        amounts[in_count] = amounts.get(in_count, 0) - 1
+        amounts[in_seconds] = amounts.get(in_seconds, 0.0) - secs
+    spans.append((start, count, seconds, end - start))
+    if len(spans) > 4096:  # outermost spans of programs long done
+        del spans[:2048]
+    _book(mine, **amounts)
+
+
+def _on_duration(event, secs, **_kw) -> None:
+    if event == _CACHE_READ:
+        _book(_this_thread(), cache_read_s=secs)
+    elif event == _CACHE_SAVED:
+        _book(_this_thread(), cache_saved_s=secs)
+
+
+def _on_event(event, **_kw) -> None:
+    if event == _CACHE_HIT:
+        mine = _this_thread()
+        mine.cache_hit = True
+        _book(mine, cache_hits=1)
+    elif event == _CACHE_MISS:
+        _book(_this_thread(), cache_misses=1)
+
+
+def listen_for_compiles() -> bool:
+    """Install the ledger's listeners, once a process -> whether JAX
+    introspection is on (``jax_introspection``).  ``instrument_jit``
+    calls this; so does whoever wants what compiles before the first
+    instrumented jit booked (a replica's set-up)."""
+    global _listening
+    try:
+        if not CONFIG.jax_introspection:
+            return False
+    except Exception:  # noqa: BLE001 — config unavailable in exotic contexts
+        pass
+    with _jit_lock:
+        if not _listening:
+            import jax.monitoring
+
+            jax.monitoring.register_event_time_span_listener(_on_span)
+            jax.monitoring.register_event_duration_secs_listener(_on_duration)
+            jax.monitoring.register_event_listener(_on_event)
+            _listening = True
+    return True
+
+
+def _new_record() -> Dict[str, Any]:
+    return {"compiles": 0, "retraces": 0, "compile_seconds": 0.0, "first_call_s": 0.0,
+            **_new_ledger()}
 
 
 def jit_stats(name: Optional[str] = None) -> Dict[str, Any]:
-    """Per-instrumented-function compile/retrace records."""
+    """Per-name compile records: an instrumented function's
+    ``compiles``, ``retraces`` and ``compile_seconds`` (``first_call_s``
+    is the same seconds) with its share of the ledger (LEDGER_KEYS), and
+    the ledger alone under the set-up phases' names and "other"."""
     with _jit_lock:
         if name is not None:
             return dict(_jit_records.get(name, {}))
         return {k: dict(v) for k, v in _jit_records.items()}
 
 
+def compile_totals() -> Dict[str, Any]:
+    """The ledger's sums over every name: what this process has spent
+    tracing, lowering, compiling and loading from the cache."""
+    with _jit_lock:
+        return dict(_compile_totals)
+
+
+def programs_lowered() -> int:
+    """Programs this process has lowered since the listeners were
+    installed: it grows where anything compiled (one int read: the
+    engine asks after every slice of its loop)."""
+    return _compile_totals["lowerings"]
+
+
 def instrument_jit(name: str, jfn):
     """Wrap an already-jitted callable with compile-time and retrace
     counters.
 
-    Steady-state cost per call: two reads of a thread-local count and
-    two perf_counter reads (~0.5 us) — far inside the telemetry budget
-    for step-scale functions.  A call compiled where JAX reports that it
-    lowered a program (``_LOWERED_EVENT``), not where the jit's
-    fast-path cache grew: that also grows when the same shapes come from
-    another place, which costs no compile.  When a call triggers a
-    (re)trace, its wall time is recorded as ``jax_compile_seconds``
+    Steady-state cost per call: one thread-local read, one perf_counter
+    read and the name the listeners book under set and put back (well
+    under a microsecond) — far inside the telemetry budget for
+    step-scale functions.  A call compiled where JAX reports that it
+    lowered a program (``_LOWERED``), not where the jit's fast-path
+    cache grew: that also grows when the same shapes come from another
+    place, which costs no compile.  When a call triggers a (re)trace,
+    its wall time is recorded as ``jax_compile_seconds``
     (trace+compile+first run — the stall the operator actually sees) and
-    a ``jax.compile`` span lands in the timeline.  Disabled via
-    ``jax_introspection=False`` (returns ``jfn`` unwrapped).
+    a ``jax.compile`` span lands in the timeline, with the call's split
+    of the ledger as attributes.  Disabled via
+    ``jax_introspection=False`` (returns ``jfn`` unwrapped, and nothing
+    listens).
     """
-    try:
-        if not CONFIG.jax_introspection:
-            return jfn
-    except Exception:  # noqa: BLE001 — config unavailable in exotic contexts
-        pass
-    _listen_for_lowerings()
+    if not listen_for_compiles():
+        return jfn
     state = {"compiles": 0}
     with _jit_lock:
-        _jit_records.setdefault(
-            name,
-            {"compiles": 0, "retraces": 0, "compile_seconds": 0.0},
-        )
+        _jit_records.setdefault(name, _new_record())
 
     def wrapped(*args, **kwargs):
-        from ray_tpu._private import telemetry
-
-        t_wall = time.time()
+        mine = getattr(_threads, "mine", None) or _this_thread()
+        outer, booked = mine.jit, mine.booked
+        mine.jit = name
         t0 = time.perf_counter()
-        lowered = _programs_lowered()
-        out = jfn(*args, **kwargs)
-        dt = time.perf_counter() - t0
-        if _programs_lowered() > lowered:
-            state["compiles"] += 1
-            first = state["compiles"] == 1
-            with _jit_lock:
-                rec = _jit_records[name]
-                rec["compiles"] += 1
-                rec["compile_seconds"] += dt
-                if not first:
-                    rec["retraces"] += 1
-            telemetry.observe_jax_compile(name, dt)
-            if not first:
-                telemetry.count_jax_retrace(name)
-            try:
-                from ray_tpu.util import tracing
-
-                tracing.record_event_span(
-                    "jax.compile",
-                    t_wall,
-                    t_wall + dt,
-                    attributes={"function": name, "retrace": not first},
-                )
-            except Exception:  # noqa: BLE001
-                pass
+        try:
+            out = jfn(*args, **kwargs)
+        except BaseException:
+            mine.jit, mine.split = outer, {}
+            raise
+        mine.jit = outer
+        if mine.booked != booked:
+            dt = time.perf_counter() - t0
+            split, mine.split = mine.split, {}
+            if split.get("lowerings"):
+                _note_compile(name, state, split, dt)
         return out
 
     wrapped.__name__ = f"instrumented_{name}"
     wrapped.__wrapped__ = jfn
     return wrapped
+
+
+def _note_compile(name: str, state: Dict[str, int], split: Dict[str, Any], dt: float) -> None:
+    """A wrapped call that lowered a program: its counters, its metric
+    and its ``jax.compile`` span."""
+    from ray_tpu._private import telemetry
+
+    state["compiles"] += 1
+    first = state["compiles"] == 1
+    with _jit_lock:
+        rec = _jit_records[name]
+        rec["compiles"] += 1
+        rec["compile_seconds"] += dt
+        rec["first_call_s"] += dt
+        if not first:
+            rec["retraces"] += 1
+    telemetry.observe_jax_compile(name, dt)
+    if not first:
+        telemetry.count_jax_retrace(name)
+    try:
+        from ray_tpu.util import tracing
+
+        end = time.time()
+        tracing.record_event_span(
+            "jax.compile",
+            end - dt,
+            end,
+            attributes={
+                "function": name, "retrace": not first,
+                "trace_s": split.get("trace_s", 0.0), "lower_s": split.get("lower_s", 0.0),
+                "backend_s": split.get("backend_hit_s", 0.0) + split.get("backend_miss_s", 0.0),
+                "cache_hit": bool(split.get("cache_hits")),
+            },
+        )
+    except Exception:  # noqa: BLE001
+        pass
+
+
+# ----------------------------------------------------------------------
+# a replica's set-up, phase by phase
+# ----------------------------------------------------------------------
+class SetupPhases:
+    """One replica's set-up as consecutive phases on the epoch clock
+    (``time.time()``: the clock ``jax.profiler`` stamps host events on,
+    and the ``jax.compile`` spans).  A phase begins where the one before
+    it ended, so the phases tile the time from the first one's start and
+    never overlap.  ``finish`` records each as a span, all of one trace,
+    under the root ``setup.replica`` that caused them.  While a phase is
+    open the ledger books under its name what compiles outside every
+    instrumented jit.  docs/serving.md "What a start is made of"."""
+
+    ROOT = "setup.replica"
+
+    def __init__(self, at: Optional[float] = None):
+        self.spans: List[Tuple[str, float, float]] = []  # (name, start, end), in order
+        self.open: Optional[str] = None
+        self.at = time.time() if at is None else at  # where the last phase ended
+        self._recorded = False
+
+    def enter(self, name: str) -> None:
+        """End the open phase and begin ``name`` where the last one ended."""
+        self.leave()
+        self.open = name
+
+    def leave(self) -> None:
+        """End the open phase; what follows belongs to the next ``enter``."""
+        if self.open is not None:
+            now = time.time()
+            self.spans.append((self.open, self.at, now))
+            self.open, self.at = None, now
+
+    def finish(self) -> None:
+        """End the set-up and record its spans, once."""
+        global _setup
+        self.leave()
+        if _setup is self:
+            _setup = None
+        if self._recorded or not self.spans:
+            return
+        self._recorded = True
+        from ray_tpu.util import tracing
+
+        trace_id, root = tracing.new_trace_id(), tracing.new_span_id()
+        tracing.record_span(self.ROOT, self.spans[0][1], self.at, context=(trace_id, root, None))
+        for name, start, end in self.spans:
+            tracing.record_span(name, start, end, context=(trace_id, tracing.new_span_id(), root))
+
+    def seconds(self, name: str) -> float:
+        return sum((end - start for n, start, end in self.spans if n == name), 0.0)
+
+
+# the set-up under way in this process: begun at the worker's entry
+# (default_worker.main) or by the first engine built outside a worker
+_setup: Optional[SetupPhases] = None
+
+
+def begin_setup(at: Optional[float] = None) -> SetupPhases:
+    global _setup
+    _setup = SetupPhases(at)
+    return _setup
+
+
+def setup_under_way() -> Optional[SetupPhases]:
+    return _setup
 
 
 _dev_report_lock = threading.Lock()

@@ -28,6 +28,14 @@ class Replica:
         user_config: Any = None,
         max_ongoing: int = 100,
     ):
+        from ray_tpu._private import profiling
+
+        # the worker's start (its entry, connect, registration, this
+        # creation task's arrival) ends here; an LLM engine built below
+        # goes on with the phases of its own and finishes the set-up
+        setup = profiling.setup_under_way()
+        if setup is not None:
+            setup.leave()
         self.replica_id = replica_id
         self.deployment_name = deployment_name
         target, args, kwargs = serialized_init
@@ -35,6 +43,8 @@ class Replica:
             self.callable = target(*args, **kwargs)
         else:
             self.callable = target
+        if setup is not None:
+            setup.finish()
         self.max_ongoing = max_ongoing
         self._ongoing = 0
         self._total = 0

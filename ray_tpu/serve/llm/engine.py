@@ -107,6 +107,16 @@ _LOOP_WAITS = (
     "engine.yield",          # sleep(0): what the loop's other callbacks took
     "engine.idle",           # nothing to run: waiting on _wake or the 5 ms retry
 )
+# A replica's set-up, as consecutive phases on the epoch clock: each a
+# span under ``setup.replica`` and seconds in ``stats()["<phase>_s"]``
+# (dots to underscores).  docs/serving.md "What a start is made of".
+SETUP_PHASES = (
+    "setup.worker",    # default_worker.main's entry to the replica's __init__ entering
+    "setup.device",    # config, ``import jax``, the backend's client: to the first array on the device
+    "setup.params",    # family.init_params + family.serving_params (dispatched, not waited for)
+    "setup.pools",     # the cache's pools, lane state, _lane_tok, _lanes
+    "setup.programs",  # the jits, the first _clear_lane, the host's state: to __init__ returning
+)
 # A slice of the loop (from one dispatched prefill, fetched program or
 # iteration's end to the next: one device program, the host work around
 # it) that takes longer than this outside ``engine.idle`` is a stall:
@@ -328,6 +338,24 @@ class LLMEngine:
     page or a lane holds), and the continuous-batching step loop."""
 
     def __init__(self, config: Optional[Any] = None):
+        from ray_tpu._private import profiling as _profiling
+
+        # the set-up, phase by phase (docs/serving.md "What a start is
+        # made of"): the worker's, where this process is a replica's
+        setup = _profiling.setup_under_way() or _profiling.begin_setup()
+        setup.enter("setup.device")
+        try:
+            # before anything compiles: imports jax, which this phase covers
+            _profiling.listen_for_compiles()
+            self._set_up(config, setup)
+        finally:
+            setup.finish()
+        # seconds by phase, and the instant the last one ended: plain
+        # numbers in stats()
+        self._setup_s = {f"{name.replace('.', '_')}_s": setup.seconds(name) for name in SETUP_PHASES}
+        self._setup_s["engine_ready_at"] = setup.at
+
+    def _set_up(self, config, setup):
         self.config = LLMConfig.coerce(config)
         self.model_cfg = self.config.model_config()
         self.max_ctx = self.config.max_context
@@ -346,7 +374,7 @@ class LLMEngine:
                 f"{self.bm.blocks_needed(self.max_ctx)} needed for "
                 f"max_context {self.max_ctx}"
             )
-        self._build_model()
+        self._build_model(setup)
         self.slots: List[Optional[_Request]] = [None] * self.config.max_batch_size
         self.waiting: Deque[_Request] = collections.deque()
         self._by_id: Dict[str, _Request] = {}
@@ -410,7 +438,9 @@ class LLMEngine:
         # where the current slice of the loop began (_note_stall)
         self._slice_t0 = time.perf_counter()
         self._slice_before = dict(self._phase_s)
-        self._slice_compiles = 0
+        self._slice_lowered = 0
+        # the compile ledger as the last "llm engine compiled" line saw it
+        self._ledger_seen: Dict[str, Dict[str, Any]] = {}
         self._shed_total = 0
         # shed attribution: {(where, tenant_label): n}, flushed at 1 Hz
         self._shed_unreported: Dict[tuple, int] = {}
@@ -439,19 +469,22 @@ class LLMEngine:
         )
 
     # -- model / jit ----------------------------------------------------
-    def _build_model(self):
+    def _build_model(self, setup):
         import jax
 
         import jax.numpy as jnp
 
         cfg = self.model_cfg
         family = model_family(cfg)
+        # the first array on the device: the backend's client has started
+        rng = jax.random.PRNGKey(self.config.seed)
+        setup.enter("setup.params")
         # only what the two programs read is kept, each leaf in the dtype
         # they compute with: an argument in another dtype is read whole
         # and cast again by every program
-        self.params = family.serving_params(
-            family.init_params(cfg, rng=jax.random.PRNGKey(self.config.seed)), cfg)
+        self.params = family.serving_params(family.init_params(cfg, rng=rng), cfg)
         self._param_bytes = sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(self.params))
+        setup.enter("setup.pools")
         # what the family's programs count and return after their tokens
         self._counter_names = tuple(getattr(family, "COUNTERS", ()))
         # the cache, by the family's statement.  A position is one row
@@ -482,6 +515,7 @@ class LLMEngine:
         self._lanes = {"rows": jnp.zeros((lanes, _TABLE + pages), jnp.int32),
                        "temp": jnp.zeros(lanes, jnp.float32), "step": jnp.zeros((), jnp.int32)}
         assert tuple(self._lanes) == LANE_STATE
+        setup.enter("setup.programs")
         self._put_lane = jax.jit(put_lane)
         self._clear_lane = jax.jit(clear_lane)
         self._advance_lanes = jax.jit(functools.partial(advance_lanes, self.config.block_size))
@@ -693,7 +727,10 @@ class LLMEngine:
         return True
 
     def stats(self) -> Dict[str, Any]:
+        from ray_tpu._private import profiling as _profiling
+
         running = sum(1 for r in self.slots if r is not None)
+        compiled = _profiling.compile_totals()
         tenants: Dict[str, Dict[str, int]] = {}
         for r in self.slots:
             if r is None:
@@ -732,6 +769,15 @@ class LLMEngine:
             **self._counts,
             **{n[len("engine."):].replace(".", "_") + "_s": v
                for n, v in self._phase_s.items()},
+            # the start, phase by phase, and the process's compile ledger
+            # (docs/serving.md "What a start is made of")
+            **self._setup_s,
+            **{f"compile_{k}": compiled[k] for k in (
+                "trace_s", "lower_s", "backend_hit_s", "backend_miss_s",
+                "cache_read_s", "cache_hits", "cache_misses")},
+            "programs_lowered": compiled["lowerings"],
+            **{f"{f}_first_call_s": _profiling.jit_stats(f).get("first_call_s", 0.0)
+               for f in ("serve_prefill", "serve_decode")},
             "preemptions_total": self._preempt_total,
             "degradation_level": self._degrade.level,
             "tenants": tenants,
@@ -882,16 +928,17 @@ class LLMEngine:
         """End a slice of the loop (called after every prefill and every
         iteration).  One that took over STALL_S outside ``engine.idle``
         is counted and logged with the phases its time went to; one in
-        which a program compiled is logged at INFO and not counted."""
+        which a program compiled is logged at INFO, with each program's
+        split of the compile ledger, and not counted."""
         from ray_tpu._private import profiling as _profiling
 
         now = time.perf_counter()
-        compiles = sum(_profiling.jit_stats(f).get("compiles", 0)
-                       for f in ("serve_prefill", "serve_decode"))
+        lowered = _profiling.programs_lowered()
+        compiled = lowered != self._slice_lowered
+        programs = self._compiled_since_last() if compiled else ""
         spent = {n: s - self._slice_before[n] for n, s in self._phase_s.items()}
         took_s = now - self._slice_t0 - spent["engine.idle"]
         if took_s > STALL_S:
-            compiled = compiles > self._slice_compiles
             if not compiled:
                 self._counts["stalls"] += 1
                 self._counts["stall_s"] += took_s
@@ -901,14 +948,33 @@ class LLMEngine:
             )
             logger.log(
                 logging.INFO if compiled else logging.WARNING,
-                "llm engine %s: %.0f ms at step %d, waiting=%d running=%d; ms by phase: %s",
+                "llm engine %s: %.0f ms at step %d, waiting=%d running=%d; ms by phase: %s%s",
                 "compiled" if compiled else "slow iteration",
                 1000 * took_s, self.step_count, len(self.waiting),
                 sum(1 for r in self.slots if r is not None), by_phase,
+                "; programs, ms: " + programs if compiled else "",
             )
         self._slice_t0 = now
         self._slice_before = dict(self._phase_s)
-        self._slice_compiles = compiles
+        self._slice_lowered = lowered
+
+    def _compiled_since_last(self) -> str:
+        """The programs that compiled since this was last asked (in the
+        slice that ends, as every slice that compiled asks), each with
+        its milliseconds of trace, lowering and backend: a compile, or
+        the load of what the persistent cache held."""
+        from ray_tpu._private import profiling as _profiling
+
+        before, self._ledger_seen = self._ledger_seen, _profiling.jit_stats()
+        parts = []
+        for name, rec in self._ledger_seen.items():
+            was = before.get(name, {})
+            n, trace, lower, miss, hit = (rec[k] - was.get(k, 0) for k in (
+                "lowerings", "trace_s", "lower_s", "backend_miss_s", "backend_hit_s"))
+            if n:
+                parts.append(f"{name} x{n} trace={1000 * trace:.0f} lower={1000 * lower:.0f} "
+                             f"backend_miss={1000 * miss:.0f} backend_hit={1000 * hit:.0f}")
+        return ", ".join(parts)
 
     def _reap(self):
         """Step-boundary cleanup: cancelled lanes leave, blocks freed."""

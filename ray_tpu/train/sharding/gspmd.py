@@ -86,6 +86,8 @@ class GspmdPlan:
         init follows the param shardings leaf-for-leaf."""
         import jax
 
+        from ray_tpu._private import profiling
+
         rng = rng if rng is not None else jax.random.PRNGKey(0)
         abstract = jax.eval_shape(init_fn, rng)
         shardings = self.param_shardings(abstract)
@@ -96,7 +98,8 @@ class GspmdPlan:
         prev = jax.config.jax_threefry_partitionable
         jax.config.update("jax_threefry_partitionable", True)
         try:
-            params = jax.jit(init_fn, out_shardings=shardings)(rng)
+            params = profiling.instrument_jit(
+                "gspmd_shard_init", jax.jit(init_fn, out_shardings=shardings))(rng)
         finally:
             jax.config.update("jax_threefry_partitionable", prev)
         # Optimizer moments mirror the param tree (their paths carry the
@@ -114,7 +117,8 @@ class GspmdPlan:
             opt_specs,
             is_leaf=lambda x: isinstance(x, P),
         )
-        opt_state = jax.jit(optimizer.init, out_shardings=opt_shardings)(params)
+        opt_state = profiling.instrument_jit(
+            "gspmd_opt_init", jax.jit(optimizer.init, out_shardings=opt_shardings))(params)
         return params, opt_state
 
     def jit_train_step(self, step_fn: Callable, params: Any, opt_state: Any):

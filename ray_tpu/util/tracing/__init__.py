@@ -10,9 +10,6 @@ child context before running the task body, so ``get_trace_id()`` is
 stable across an entire distributed call tree and every task event
 row carries (trace_id, span_id, parent_span_id) — the timeline and any
 external collector can reassemble the tree.
-
-If an OpenTelemetry SDK IS importable, ``use_opentelemetry()`` bridges
-span starts/ends to a real tracer.
 """
 
 from __future__ import annotations
@@ -26,7 +23,6 @@ from contextlib import contextmanager
 from typing import Any, Dict, List, Optional, Tuple
 
 _ctx: contextvars.ContextVar = contextvars.ContextVar("ray_tpu_trace", default=None)
-_otel_tracer = None
 # process-local span log (drained by tests/exporters; shipped off-box by
 # the background flusher — see flush())
 _finished_spans: List[Dict[str, Any]] = []
@@ -56,6 +52,12 @@ def _new_span_id() -> str:
 def new_span_id() -> str:
     """Mint a span id (public: channel hops mint per-frame write spans)."""
     return _new_span_id()
+
+
+def new_trace_id() -> str:
+    """Mint a trace id (public: a replica's set-up records its phases
+    after the fact, as the spans of one trace)."""
+    return _new_trace_id()
 
 
 def set_frame_context(frame_ctx: Optional[Tuple[str, str]]) -> Any:
@@ -138,8 +140,7 @@ def install_context(traceparent: Optional[str]) -> None:
 @contextmanager
 def start_span(name: str, attributes: Optional[Dict[str, Any]] = None):
     """Open a span under the current context (starting a new trace if
-    none is active); spans land in the process span log and, when
-    bridged, the OpenTelemetry tracer."""
+    none is active); spans land in the process span log."""
     prev = _ctx.get()
     if prev is None:
         trace_id, parent = _new_trace_id(), None
@@ -148,15 +149,9 @@ def start_span(name: str, attributes: Optional[Dict[str, Any]] = None):
     span_id = _new_span_id()
     token = _ctx.set((trace_id, span_id, parent))
     start = time.time()
-    otel_cm = None
-    if _otel_tracer is not None:
-        otel_cm = _otel_tracer.start_as_current_span(name)
-        otel_cm.__enter__()
     try:
         yield SpanHandle(trace_id, span_id)
     finally:
-        if otel_cm is not None:
-            otel_cm.__exit__(None, None, None)
         _record_span(
             {
                 "name": name,
@@ -367,19 +362,3 @@ def _safe_flush():
                 break
     except Exception:
         pass
-
-
-def use_opentelemetry(tracer=None) -> bool:
-    """Bridge spans to an OpenTelemetry tracer if the SDK is available
-    (reference: tracing_helper's use of opentelemetry.trace)."""
-    global _otel_tracer
-    if tracer is not None:
-        _otel_tracer = tracer
-        return True
-    try:
-        from opentelemetry import trace as otel_trace
-
-        _otel_tracer = otel_trace.get_tracer("ray_tpu")
-        return True
-    except Exception:
-        return False

@@ -325,9 +325,130 @@ def test_instrument_jit_kill_switch_returns_unwrapped():
     CONFIG._overrides["jax_introspection"] = False
     try:
         jfn = jax.jit(lambda x: x + 1)
+        # nothing wraps the call and nothing is asked to listen: the
+        # ledger costs a call nothing, compiling or not
         assert profiling_mod.instrument_jit("killed", jfn) is jfn
+        assert profiling_mod.listen_for_compiles() is False
+        jfn(jax.numpy.ones((3,)))
+        assert profiling_mod.jit_stats("killed") == {}
     finally:
         CONFIG._overrides.pop("jax_introspection", None)
+
+
+def _ledger(rec):
+    return {k: rec[k] for k in profiling_mod.LEDGER_KEYS}
+
+
+def test_compile_ledger_splits_a_first_call_and_books_nothing_on_the_second():
+    """A first call is one trace, one lowering and one backend call with
+    seconds of each, under the function's name and in the process's
+    totals; a second call books nothing; a new shape is a retrace with a
+    split of its own, which its ``jax.compile`` span carries."""
+    jax = pytest.importorskip("jax")
+    jnp = jax.numpy
+    from ray_tpu.util import tracing
+
+    # jnp.sin is a jit of its own, traced inside f's trace: one trace all the same
+    f = profiling_mod.instrument_jit("ledger_probe", jax.jit(lambda x: jnp.sin(x) * 3))
+    small, large = jnp.ones((4,)), jnp.ones((16,))  # made before anything is read: they compile too
+    tracing.drain_spans()
+    totals = profiling_mod.compile_totals()
+    f(small)
+    first = profiling_mod.jit_stats("ledger_probe")
+    assert (first["traces"], first["lowerings"], first["backend_calls"]) == (1, 1, 1)
+    assert first["trace_s"] > 0 and first["lower_s"] > 0
+    assert first["backend_hit_s"] + first["backend_miss_s"] > 0
+    # the old three keys read as before, and first_call_s is compile_seconds
+    assert (first["compiles"], first["retraces"]) == (1, 0)
+    assert first["first_call_s"] == first["compile_seconds"] > first["trace_s"] + first["lower_s"]
+    after = profiling_mod.compile_totals()
+    assert {k: after[k] - totals[k] for k in after} == pytest.approx(_ledger(first))
+
+    f(small)
+    assert profiling_mod.jit_stats("ledger_probe") == first
+    assert profiling_mod.compile_totals() == after
+
+    f(large)
+    second = profiling_mod.jit_stats("ledger_probe")
+    assert (second["compiles"], second["retraces"]) == (2, 1)
+    assert (second["traces"], second["lowerings"], second["backend_calls"]) == (2, 2, 2)
+    spans = [sp["attributes"] for sp in tracing.drain_spans()
+             if sp["name"] == "jax.compile" and sp["attributes"]["function"] == "ledger_probe"]
+    assert [a["retrace"] for a in spans] == [False, True]
+    for a, before, now in zip(spans, (dict.fromkeys(first, 0), first), (first, second)):
+        assert a["trace_s"] == pytest.approx(now["trace_s"] - before["trace_s"]) and a["trace_s"] > 0
+        assert a["lower_s"] == pytest.approx(now["lower_s"] - before["lower_s"]) and a["lower_s"] > 0
+        assert a["backend_s"] > 0 and a["cache_hit"] == (now["cache_hits"] > before["cache_hits"])
+
+
+def test_compile_ledger_books_under_the_name_open_in_the_calling_thread():
+    """Two instrumented jits compiling at once, each in a thread of its
+    own: each program lands under its own name."""
+    jax = pytest.importorskip("jax")
+    jnp = jax.numpy
+    tracing_a, release = threading.Event(), threading.Event()
+
+    def held(x):  # runs while it is traced: holds thread A inside its compile
+        tracing_a.set()
+        assert release.wait(30)
+        return x - 1
+
+    fa = profiling_mod.instrument_jit("ledger_thread_a", jax.jit(held))
+    fb = profiling_mod.instrument_jit("ledger_thread_b", jax.jit(lambda x: x * 7))
+    x = jnp.ones((5,))
+    a = threading.Thread(target=fa, args=(x,), daemon=True)
+    a.start()
+    try:
+        assert tracing_a.wait(30)
+        fb(x)  # compiles whole while A is in the middle of its trace
+        mid = profiling_mod.jit_stats("ledger_thread_a")
+        assert (mid["traces"], mid["lowerings"]) == (0, 0)
+    finally:
+        release.set()
+    a.join(30)
+    assert not a.is_alive()
+    for name in ("ledger_thread_a", "ledger_thread_b"):
+        rec = profiling_mod.jit_stats(name)
+        assert (rec["compiles"], rec["traces"], rec["lowerings"], rec["backend_calls"]) == (1, 1, 1, 1)
+
+
+_CACHE_SCRIPT = """
+import json, jax, jax.numpy as jnp
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+from ray_tpu._private import profiling
+f = profiling.instrument_jit("cached_probe", jax.jit(lambda x: jnp.cos(x) * 5))
+x = jnp.ones((8,))
+f(x)
+first = profiling.jit_stats("cached_probe")
+jax.clear_caches()
+f(x)
+print("LEDGER=" + json.dumps([first, profiling.jit_stats("cached_probe")]))
+"""
+
+
+def test_compile_ledger_tells_a_cache_load_from_a_compile(tmp_path):
+    """With the persistent cache on: a process's first compile of a
+    program is a miss, booked under ``backend_miss_s``; the same program
+    after ``jax.clear_caches()`` is read back, booked under
+    ``backend_hit_s`` with the cache's own read time."""
+    pytest.importorskip("jax")
+    import subprocess
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu", JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               PYTHONPATH=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    out = subprocess.run([sys.executable, "-c", _CACHE_SCRIPT], env=env, capture_output=True,
+                         text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    first, second = json.loads(next(
+        line for line in out.stdout.splitlines() if line.startswith("LEDGER="))[len("LEDGER="):])
+    if second["cache_hits"] == 0:
+        pytest.skip("the CPU backend of this JAX does not read its persistent cache back")
+    assert (first["cache_hits"], first["cache_misses"], first["backend_calls"]) == (0, 1, 1)
+    assert first["backend_miss_s"] > 0 and first["backend_hit_s"] == 0 and first["cache_read_s"] == 0
+    assert (second["cache_hits"], second["cache_misses"], second["backend_calls"]) == (1, 1, 2)
+    assert second["backend_miss_s"] == first["backend_miss_s"]
+    assert second["backend_hit_s"] >= second["cache_read_s"] > 0
 
 
 def test_report_device_memory_cpu_safe():

@@ -383,10 +383,10 @@ def test_layer_metric_reads_the_request_path_counters(metric, handled_run):
     st = handled_run[2]
     for key in re.findall(r"\bd\.(\w+)", how["args"]["expr"]):
         assert isinstance(st[key], (int, float)), (metric, key)
-    # and BENCHMARK.json reports it in its one cell, under its layer's name
+    # and BENCHMARK.json reports it in its cell, under its layer's name
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         entry = next(m for m in json.load(f)["per_layer"] if m["name"] == metric)
-    assert entry["workloads"] == [_CELL[metric.rsplit(".", 1)[1]]]
+    assert _CELL[metric.rsplit(".", 1)[1]] in entry["workloads"]
     assert (entry["layer"], entry["moves"], entry["source"]) == (layer, moves, "program_counter")
 
 
