@@ -22,6 +22,16 @@ from typing import Any, Callable, Optional, Sequence, Tuple
 
 from ray_tpu.train.sharding.rules import ShardingConfig, match_partition_rules
 
+# What lets the TPU compiler start an all-reduce asynchronously and fuse
+# it beside independent work: the backward pass's sums of dx over `model`
+# then run under the weight-gradient matmuls of their layer.  The two
+# work only TOGETHER (either alone compiles the synchronous program), and
+# the CPU compiler rejects `xla_tpu_*` names.
+_OVERLAP_COLLECTIVES = {
+    "xla_enable_async_all_reduce": True,
+    "xla_tpu_enable_async_collective_fusion_fuse_all_reduce": True,
+}
+
 
 def build_mesh(config: ShardingConfig, devices: Optional[Sequence] = None):
     """Device mesh with the config's axes over ``devices`` (default: the
@@ -121,11 +131,21 @@ class GspmdPlan:
             "gspmd_opt_init", jax.jit(optimizer.init, out_shardings=opt_shardings))(params)
         return params, opt_state
 
+    def _step_compiler_options(self) -> Optional[dict]:
+        """The step's compile options: `_OVERLAP_COLLECTIVES` where the
+        mesh is several TPUs (there are sums to overlap and a compiler
+        that knows the names), none anywhere else, so that one device and
+        a CPU mesh compile the program they always did."""
+        several_tpus = self.mesh.size > 1 and self.mesh.devices.flat[0].platform == "tpu"
+        return _OVERLAP_COLLECTIVES if several_tpus else None
+
     def jit_train_step(self, step_fn: Callable, params: Any, opt_state: Any):
         """jit ``step_fn(params, opt_state, tokens, targets) ->
         (params, opt_state, loss)`` with explicit NamedSharding in/out
-        shardings and donated state.  The returned callable device_puts
-        host batches onto the batch-axis layout before dispatch."""
+        shardings and donated state; on a mesh of several TPUs the
+        compiler may start the step's all-reduces asynchronously.  The
+        returned callable device_puts host batches onto the batch-axis
+        layout before dispatch."""
         import jax
 
         from ray_tpu._private import profiling
@@ -138,6 +158,7 @@ class GspmdPlan:
             in_shardings=(param_sh, opt_sh, data_sh, data_sh),
             out_shardings=(param_sh, opt_sh, self.replicated()),
             donate_argnums=(0, 1),
+            compiler_options=self._step_compiler_options(),
         )
         jitted = profiling.instrument_jit("gspmd_train_step", jit_fn)
 

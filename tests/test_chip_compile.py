@@ -125,31 +125,33 @@ def test_flash_under_a_mesh_compiles_for_v5e(topo):
     assert "all-gather" not in text
 
 
-def test_mesh_train_step_moves_weights_not_activations(topo):
+def _mesh_step_cfg():
+    """GPT-2-large at its published widths, depth cut to two layers."""
+    import dataclasses
+
+    from ray_tpu.models import gpt2
+
+    return dataclasses.replace(gpt2.GPT2Config.large(remat=False), n_layer=2)
+
+
+@pytest.fixture(scope="module")
+def mesh_step_text(topo):
     """`gpt2-large.train.mesh2x2`'s step (the sharding plan's jit of
     `make_train_step` under the default rules, 8 x 1024 tokens, mesh
     {batch: 2, model: 2}) at the published widths, depth cut to two
-    layers: what crosses `model` beside Megatron's sums is the fused
-    projection's weights, once forward and once backward a layer.  q, k
-    and v leave the projection on their device's heads, so nothing of a
-    sequence's length is permuted, exchanged or gathered around the
-    flash kernel's shard_map."""
-    import dataclasses
-
+    layers, compiled for the described chips: its optimized HLO."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from ray_tpu.models import gpt2
-    from ray_tpu.parallel.collectives import collectives, format_collectives
     from ray_tpu.train.sharding import ShardingConfig
     from ray_tpu.train.sharding.gspmd import GspmdPlan
     from ray_tpu.train.sharding.rules import match_partition_rules
 
     mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("batch", "model"))
     plan = GspmdPlan(ShardingConfig(mesh_shape={"batch": 2, "model": 2}), mesh)
-    cfg = dataclasses.replace(gpt2.GPT2Config.large(remat=False), n_layer=2)
+    cfg = _mesh_step_cfg()
     opt = gpt2.make_adamw()
-    B, T, d = 8, 1024, cfg.d_model
 
     def on_mesh(tree, specs):
         return jax.tree_util.tree_map(
@@ -160,9 +162,22 @@ def test_mesh_train_step_moves_weights_not_activations(topo):
     params = on_mesh(params, plan.param_specs(params))
     opt_state = jax.eval_shape(opt.init, params)
     opt_state = on_mesh(opt_state, match_partition_rules(plan.config.rules(), opt_state, mesh, strict=False))
-    tokens = jax.ShapeDtypeStruct((B, T), jnp.int32, sharding=plan.data_sharding())
+    tokens = jax.ShapeDtypeStruct((8, 1024), jnp.int32, sharding=plan.data_sharding())
     step = plan.jit_train_step(gpt2.make_train_step(cfg, opt), params, opt_state)
-    text = step.lower(params, opt_state, tokens, tokens).compile().as_text()
+    return step.lower(params, opt_state, tokens, tokens).compile().as_text()
+
+
+def test_mesh_train_step_moves_weights_not_activations(mesh_step_text):
+    """What crosses `model` in the mesh step beside Megatron's sums is
+    the fused projection's weights, once forward and once backward a
+    layer.  q, k and v leave the projection on their device's heads, so
+    nothing of a sequence's length is permuted, exchanged or gathered
+    around the flash kernel's shard_map."""
+    from ray_tpu.parallel.collectives import collectives, format_collectives
+
+    cfg = _mesh_step_cfg()
+    B, T, d = 8, 1024, cfg.d_model
+    text = mesh_step_text
     rows = collectives(text)
     listing = format_collectives(rows)
 
@@ -192,6 +207,47 @@ def test_mesh_train_step_moves_weights_not_activations(topo):
 
     assert d * 3 * d // 2 not in f32_copied("copy"), f32_copied("copy")
     assert 4 * sum(f32_copied("copy(?:-start)?")) < 65e6 * cfg.n_layer
+
+
+def test_mesh_train_step_starts_backward_sums_async(mesh_step_text):
+    """The plan compiles a step for several TPUs with the options that
+    let an all-reduce start asynchronously: the backward pass's sums of
+    dx over `model` (`bf16[4,1024,1280]`) each start in an
+    `async-collective-start` fusion and end in its `-done`, with a
+    weight-gradient matmul of the same layer between the two (the
+    compiler carries the sum through that fusion).  Nothing is claimed
+    of the forward pass's sums: what one of them can run beside is the
+    compiler's to find."""
+    from ray_tpu.parallel.collectives import collectives, format_collectives
+
+    text = mesh_step_text
+    rows = collectives(text)
+    listing = format_collectives(rows)
+    over_model = ("[2,2]<=[4]", "{{0,1},{2,3}}")
+    sums = [r for r in rows if r.op == "all-reduce" and r.shape == "bf16[4,1024,1280]"]
+    assert sums and all(r.groups in over_model for r in sums), listing
+    assert sum(r.started_async for r in sums) >= 3, listing
+
+    computations = dict(re.findall(r"^%(\S+) \(.*?\{$(.*?)^\}$", text, flags=re.M | re.S))
+    entry = text[text.index("\nENTRY"):].splitlines()
+    at = {m[1]: i for i, ln in enumerate(entry) if (m := re.match(r"\s*%(async-collective-\S+) = ", ln))}
+    backward = 0
+    for name, first in at.items():
+        if not name.startswith("async-collective-start"):
+            continue
+        last = at[name.replace("start", "done")]
+        assert first < last
+        started = computations[re.search(r"calls=%([^\s,)]+)", entry[first])[1]]
+        summed = started.split(" all-reduce(")[0].rsplit("\n", 1)[-1]  # the sum's name and result
+        if " = bf16[4,1024,1280]" not in summed or "transpose(jvp(GPT2))" not in started:
+            continue  # another collective than a sum of dx, or one of the forward pass
+        backward += 1
+        # a matmul that yields a weight's gradient (no [.., T, ..] activation) runs under it
+        beside = [ln for ln in entry[first + 1:last]
+                  if "calls=%async_collective_fusion" in ln and "dot_general" in ln and "transpose(jvp(GPT2))" in ln]
+        results = [re.match(r"\s*%\S+ = \(?(\w+\[[\d,]+\])", ln)[1] for ln in beside]
+        assert any(",1024," not in r for r in results), (name, results, listing)
+    assert backward >= 3, listing
 
 
 def test_engine_decode_step_compiles_for_v5e(one_chip, monkeypatch):

@@ -76,6 +76,49 @@ def test_gspmd_mesh_shards_params_and_state():
     )
 
 
+@pytest.mark.parametrize("platform, n_devices, overlapped", [
+    ("tpu", 4, True), ("tpu", 1, False), ("cpu", 4, False), ("cpu", 1, False),
+])
+def test_step_compile_options_follow_the_mesh(platform, n_devices, overlapped):
+    """The overlap options go to a mesh of several TPUs and to nothing
+    else: one device has no sums, and the CPU compiler refuses the
+    `xla_tpu_*` names."""
+    from types import SimpleNamespace
+
+    from ray_tpu.train.sharding import gspmd
+
+    devices = np.array([SimpleNamespace(platform=platform)] * n_devices, dtype=object)
+    plan = gspmd.GspmdPlan(sharding.ShardingConfig(), SimpleNamespace(size=n_devices, devices=devices))
+    options = plan._step_compiler_options()
+    assert options == (gspmd._OVERLAP_COLLECTIVES if overlapped else None)
+    # the pair that works only together
+    assert set(gspmd._OVERLAP_COLLECTIVES) >= {
+        "xla_enable_async_all_reduce", "xla_tpu_enable_async_collective_fusion_fuse_all_reduce"}
+
+
+@pytest.mark.parametrize("mesh_shape", [{"batch": 1}, {"batch": 2, "model": 2}], ids=["one-device", "cpu-2x2"])
+def test_step_on_one_device_or_a_cpu_mesh_hands_jit_no_options(monkeypatch, mesh_shape):
+    """What `jit_train_step` hands `jax.jit` on one device and on a CPU
+    mesh of several is the parent's call: no `compiler_options`.  The
+    step compiles and trains here."""
+    n = int(np.prod(list(mesh_shape.values())))
+    if len(jax.devices()) < n:
+        pytest.skip(f"needs {n} devices")
+    plan = sharding.build_plan(sharding.ShardingConfig(mesh_shape=mesh_shape), devices=jax.devices()[:n])
+    handed = []
+    real_jit = jax.jit
+
+    def spy(fn, **kwargs):
+        handed.append(kwargs)
+        return real_jit(fn, **kwargs)
+
+    monkeypatch.setattr(jax, "jit", spy)
+    _, _, losses = _run(plan, _tiny_cfg(), _data())
+    steps = [kw for kw in handed if kw.get("donate_argnums") == (0, 1)]
+    assert len(steps) == 1 and steps[0].get("compiler_options") is None
+    assert all(np.isfinite(losses)) and losses[-1] < losses[0]
+
+
 @pytest.mark.parametrize("n_head", [20, 8])
 @pytest.mark.parametrize("mesh_shape", [(2, 2), (2, 4), (1, 8)])
 def test_qkv_exchange_matches_plain_projection(mesh_shape, n_head):
