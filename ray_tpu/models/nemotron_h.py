@@ -51,7 +51,9 @@ The module is also where the two hybrids' shared layers live:
 mixer and the chunked grouped-query attention here (``mamba_chunk``,
 ``mamba_decode``, ``attention_chunk``, ``attention_decode``, ``K_BLOCK``)
 and names its lanes' state and sums its counters as this family does
-(``tail_name``, ``state_name``, ``counters``).
+(``tail_name``, ``state_name``, ``counters``); ``models/zaya.py``, which
+makes q, k and v its own way, calls the half of ``attention_chunk`` that
+builds the paged context and attends (``attend_chunk``).
 
 ASSUMED, because the source's ``config.json`` does not settle it (the
 file ``benchmark/configs/nemotron-3-nano.json`` lists the same): no
@@ -408,17 +410,25 @@ def attention_chunk(y, lp, cfg, cache, i, where, room, start, n_valid, scale=Non
     sequence's cached rows (``where`` [C]: its positions' slots by page)
     and the chunk's own, with ``room`` rows of zeros behind them so that
     the chunk fits wherever it starts -> (out [T, d], k, v [T, G, hd])."""
-    G, hd = cfg.n_kv_head, cfg.head_dim
     with jax.named_scope("attn.gqa"):
         q, k, v = _qkv(y, lp, cfg)
+        return attend_chunk(q, k, v, cache, i, where, room, start, n_valid, scale) @ lp["wo"], k, v
 
-        def context(pages, rows):
-            ctx = jnp.concatenate([pool_rows(pages, i, where).reshape(-1, G, hd),
-                                   jnp.zeros((room, G, hd), pages.dtype)])
-            return jax.lax.dynamic_update_slice_in_dim(ctx, rows, start, axis=0)
 
-        att = chunk_attention(q, context(cache["k_pages"], k), context(cache["v_pages"], v), start, n_valid, scale)
-        return att @ lp["wo"], k, v
+def attend_chunk(q, k, v, cache, i, where, room, start, n_valid, scale=None):
+    """A chunk's queries q [T, G, R, hd] over paged layer i's cached
+    rows of the sequence (``where``, ``room`` as ``attention_chunk``
+    says) with the chunk's own k, v [T, G, hd] laid in at ``start`` ->
+    [T, G * R * hd].  What a family that makes q, k and v its own way
+    (``models/zaya.py``) shares with ``attention_chunk``."""
+    G, hd = k.shape[1:]
+
+    def context(pages, rows):
+        ctx = jnp.concatenate([pool_rows(pages, i, where).reshape(-1, G, hd),
+                               jnp.zeros((room, G, hd), pages.dtype)])
+        return jax.lax.dynamic_update_slice_in_dim(ctx, rows, start, axis=0)
+
+    return chunk_attention(q, context(cache["k_pages"], k), context(cache["v_pages"], v), start, n_valid, scale)
 
 
 def attention_decode(y, lp, cfg, cache, i, block_tables, lengths, block_size, scale=None):

@@ -25,6 +25,7 @@ MODEL_FAMILIES = (
      ("granite_4_0_h_small_tiny", "granite_4_0_h_small", "granite_4_0_h_small_10l_ep2")),
     ("ray_tpu.models.mellum", "MellumConfig", ("mellum2_tiny", "mellum2_12b_a2_5b", "mellum2_12b_a2_5b_12l")),
     ("ray_tpu.models.jamba", "JambaConfig", ("jamba2_tiny", "jamba2_3b")),
+    ("ray_tpu.models.zaya", "ZayaConfig", ("zaya1_tiny", "zaya1_8b", "zaya1_8b_20l")),
 )
 # every preset ``LLMConfig.model`` may name, family by family
 PRESETS = " | ".join(name for *_, presets in MODEL_FAMILIES for name in presets)
@@ -90,7 +91,11 @@ class LLMConfig:
     of 1,024 positions), and only their full-attention layers page; the
     ``jamba2*`` presets a convolution tail and a float32 scan state a
     Mamba-1 layer (9.32 MB a lane at the 26 Mamba layers of
-    ``jamba2_3b``), and only their two attention layers page.
+    ``jamba2_3b``), and only their two attention layers page; the
+    ``zaya1*`` presets page K and V in EVERY layer (256 values each a
+    position) and hold a tail a layer beside them (the convolutions' and
+    the value shift's last position: 107,520 B a lane at the 20 layers
+    of ``zaya1_8b_20l``).
 
     ``model`` names a preset of a model family, one of (from
     ``MODEL_FAMILIES``): {presets}.

@@ -1,5 +1,5 @@
 """Whether a change left the serve programs of the families that exist
-what they were (PERF.md section 6, PR 33, PR 38, PR 40, PR 41, PR 45 and PR 50).
+what they were (PERF.md section 6, PR 33, PR 38, PR 40, PR 41, PR 45, PR 50 and PR 54).
 
     JAX_PLATFORMS=cpu PYTHONPATH=<checkout> python scripts/serve_program_hashes.py out.json
 
@@ -38,6 +38,7 @@ CELLS = {  # preset: lanes, block, pool tokens, max context, prefill tokens
     "granite_4_0_h_small_10l_ep2": (32, 64, 557056, 17408, 2048),
     "mellum2_12b_a2_5b_12l": (32, 64, 393216, 34816, 2048),
     "jamba2_3b": (256, 64, 786432, 8192, 2048),
+    "zaya1_8b_20l": (48, 64, 229376, 16384, 2048),
 }
 
 def strip_payloads(text):

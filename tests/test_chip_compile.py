@@ -753,3 +753,64 @@ def test_moe_combine_moves_the_pairs_rows_once(one_chip, monkeypatch):
     assert len(wide) <= 1, wide
     assert f"[{T},{k},{d}]" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 560e6
+
+
+@pytest.mark.parametrize("program", ["serve_decode", "serve_prefill"])
+def test_zaya_programs_compile_for_v5e(one_chip, monkeypatch, program):
+    """ZAYA1-8B's two programs at the cell's OWN sizes, all 20 layers
+    (d 2048; 8 query heads on 2 K/V heads of 128 behind the two
+    convolutions; 16 experts of 2,048 and the skip output behind the
+    router's three small matmuls; the tied head over 262,272 rows), 48
+    lanes over a pool of 229,376 positions in pages of 64, sequences of
+    up to 16,384: the decode step reads every layer's pages through the
+    grouped-query kernel (four queries a group; no gather of a ``[lanes,
+    max_ctx, ...]`` context) and its experts through the grouped matmul;
+    a 2,048-token chunk runs the same grouped matmul and no decode
+    kernel.  Every held array, the two pools and the twenty tails, goes
+    out in the buffer it came in, and the program's temporaries fit
+    beside 9.38 GB of weights and 4.70 GB of pool under the engine's
+    budget of a 16 GB chip."""
+    from ray_tpu.models import zaya
+    from ray_tpu.serve.llm.engine import decode_step, prefill_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = zaya.ZayaConfig.zaya1_8b_20l()
+    B, C, block, T, slots = 48, 16384, 64, 2048, 229376 + 64
+    spec = zaya.cache_spec(cfg, block)
+    assert spec.names == ("k_pages", "v_pages", *(f"cca_tail_{i}" for i in range(20)))
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    def shaped(tree):
+        return jax.tree_util.tree_map(lambda x: arr(x.shape, x.dtype), tree)
+
+    params = shaped(jax.eval_shape(lambda: zaya.init_params(cfg)))
+    key = shaped(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    pool = arr((spec.paged_layers, slots, spec.row_width), cfg.dtype)
+    cache = [pool, pool] + [arr((B, *shape), dtype) for _, shape, dtype in spec.lane_state]
+    held = tuple(range(1, 1 + len(cache)))
+    held_bytes = 2 * 20 * slots * 256 * 2 + B * 107_520
+    if program == "serve_decode":
+        compiled = jax.jit(lambda *a: decode_step(cfg, 0, block, spec, *a), donate_argnums=held).lower(
+            params, *cache, arr((B,), jnp.int32), arr((B,), jnp.int32), arr((B, C // block), jnp.int32),
+            arr((B,), jnp.int32), arr((B,), jnp.float32), key).compile()
+    else:
+        compiled = jax.jit(lambda *a: prefill_step(cfg, 0, block, spec, *a), donate_argnums=held).lower(
+            params, *cache, arr((1, T), jnp.int32), arr((T,), jnp.int32), arr((1,), jnp.int32),
+            arr((1,), jnp.float32), key, arr((), jnp.int32), arr((C // block,), jnp.int32),
+            arr((), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = [c.split(".")[0] for c in _kernel_calls(text)]
+    assert calls.count("moe_gmm") == 2 * 20  # gate and up side by side, then down, a layer
+    assert calls.count("gqa_paged_decode_attention") == (20 if program == "serve_decode" else 0)
+    assert len(calls) == (60 if program == "serve_decode" else 40)
+    assert f"[{B},{C}," not in text  # the gather path's contexts
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= held_bytes
+    assert mem.argument_size_in_bytes >= 2 * 4_688_636_304 + held_bytes
+    # a decode step's temporaries are its logits (48 x 262,272 float32) and little more; a chunk's under 0.6 GB
+    assert mem.temp_size_in_bytes < (80e6 if program == "serve_decode" else 600e6)
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+    if program == "serve_decode":
+        assert f"s32[{B + len(zaya.COUNTERS)}]" in text

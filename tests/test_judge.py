@@ -23,7 +23,7 @@ pytest.register_assert_rewrite(
     "benchmark.tests.test_benchmark", "benchmark.tests.test_olmoe_cell",
     "benchmark.tests.test_mistral_small_4_cell", "benchmark.tests.test_nemotron_3_nano_cell",
     "benchmark.tests.test_granite_4_0_h_small_cell", "benchmark.tests.test_mellum2_cell",
-    "benchmark.tests.test_jamba2_cell",
+    "benchmark.tests.test_jamba2_cell", "benchmark.tests.test_zaya1_cell",
 )
 
 from benchmark.tests.test_benchmark import (  # noqa: E402,F401
@@ -93,4 +93,14 @@ from benchmark.tests.test_jamba2_cell import (  # noqa: E402,F401
 from benchmark.tests.test_jamba2_cell import (  # noqa: E402,F401
     test_runner_fails_at_once_where_the_program_has_no_such_family as test_jamba_runner_fails_at_once_where_the_program_has_no_such_family,
     test_the_cell_s_metrics_are_the_entries_of_benchmark_json as test_the_jamba_cell_s_metrics_are_the_entries_of_benchmark_json,
+)
+from benchmark.tests.test_zaya1_cell import (  # noqa: E402,F401
+    test_the_stated_cache_is_twenty_paged_layers_and_a_tail_a_lane_a_layer,
+)
+from benchmark.tests.test_zaya1_cell import (  # noqa: E402,F401
+    test_decode_kernel_experts_and_chunk_work_and_their_shares_by_hand as test_the_zaya_decode_kernel_experts_and_chunk_work_and_their_shares_by_hand,
+    test_runner_fails_at_once_where_the_program_has_no_such_family as test_zaya_runner_fails_at_once_where_the_program_has_no_such_family,
+    test_the_cell_s_metrics_are_the_entries_of_benchmark_json as test_the_zaya_cell_s_metrics_are_the_entries_of_benchmark_json,
+    test_the_configuration_is_the_catalog_s_but_for_what_it_says_is_reduced as test_the_zaya_configuration_is_the_catalog_s_but_for_what_it_says_is_reduced,
+    test_the_cut_s_arithmetic_reckoned_again as test_the_zaya_cut_s_arithmetic_reckoned_again,
 )
