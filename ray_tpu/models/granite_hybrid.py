@@ -78,7 +78,8 @@ PUBLISHED_LAYER_TYPES = tuple(ATTENTION if i % 10 == 5 else MAMBA for i in range
 # expert part: pairs are tokens x 10 a layer).
 COUNTERS = ("moe_pairs_routed", "moe_pairs_held", "moe_pairs", "moe_experts_hit",
             "moe_expert_slots", "moe_peak_rows", "moe_layer_programs",
-            "kv_positions_attended", "kv_positions_gathered", "ssm_lane_steps", "ssm_chunk_tokens")
+            "kv_positions_attended", "kv_positions_gathered", "ssm_lane_steps", "ssm_chunk_tokens",
+            "kv_blocks_walked", "kv_blocks_whole")
 
 
 @dataclass(frozen=True)
@@ -371,6 +372,8 @@ def decode_chosen(params, cfg: GraniteHybridConfig, cache, tok, block_tables, le
     hd], {}, {"conv_tail_<i>", "ssm_state_<i>": the whole new arrays},
     COUNTERS, and for the checks the experts each layer's router chose
     [L, B, k])."""
+    from ray_tpu.ops.attention import gqa_decode_blocks
+
     runs = lengths > 0
     res = cfg.residual_multiplier
     x = _embed(tok, params, cfg)
@@ -393,4 +396,5 @@ def decode_chosen(params, cfg: GraniteHybridConfig, cache, tok, block_tables, le
     pages = -(-lengths // block_size) * block_size
     n_a, n_m = cfg.layer_types.count(ATTENTION), cfg.layer_types.count(MAMBA)
     return (_logits(x, params, cfg), jnp.stack(ks), jnp.stack(vs), {}, state,
-            counters(cfg, counts, lengths.sum() * n_a, pages.sum() * n_a, runs.sum() * n_m), jnp.stack(chose))
+            counters(cfg, counts, lengths.sum() * n_a, pages.sum() * n_a, runs.sum() * n_m,
+                     blocks=gqa_decode_blocks(cache["k_pages"], lengths, block_size, n_a)), jnp.stack(chose))

@@ -82,7 +82,8 @@ MAMBA, ATTENTION = "mamba", "attention"
 # calls attended and the positions of the whole pages they copied; the
 # (lane, Mamba layer) states a decode step updated, idle lanes not
 # counted; and the real tokens x Mamba layers a chunk's scan took.
-COUNTERS = ("kv_positions_attended", "kv_positions_gathered", "ssm_lane_steps", "ssm_chunk_tokens")
+COUNTERS = ("kv_positions_attended", "kv_positions_gathered", "ssm_lane_steps", "ssm_chunk_tokens",
+            "kv_blocks_walked", "kv_blocks_whole")
 
 
 @dataclass(frozen=True)
@@ -308,8 +309,9 @@ def _logits(x, params, cfg):
     return jax.lax.dot_general(y, params["embed"], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
 
 
-def _counters(attended=0, gathered=0, lane_steps=0, chunk_tokens=0):
-    return jnp.stack([jnp.asarray(v, jnp.int32) for v in (attended, gathered, lane_steps, chunk_tokens)])
+def _counters(attended=0, gathered=0, lane_steps=0, chunk_tokens=0, blocks=(0, 0)):
+    return jnp.stack([*(jnp.asarray(v, jnp.int32) for v in (attended, gathered, lane_steps, chunk_tokens)),
+                      *jnp.asarray(blocks, jnp.int32)])
 
 
 # ----------------------------------------------------------------------
@@ -356,6 +358,8 @@ def decode_forward_cached(params, cfg: JambaConfig, cache, tok, block_tables, le
     pages where they lie.  -> (logits [L, V], k_new, v_new [2, L, 1,
     hd], {}, {"conv_tail_<i>", "ssm_state_<i>": the whole new arrays},
     COUNTERS)."""
+    from ray_tpu.ops.attention import gqa_decode_blocks
+
     runs = lengths > 0
     x = params["embed"][tok]
     ks, vs, state = [], [], {}
@@ -373,4 +377,5 @@ def decode_forward_cached(params, cfg: JambaConfig, cache, tok, block_tables, le
     pages = -(-lengths // block_size) * block_size
     n_a, n_m = cfg.layer_types.count(ATTENTION), cfg.layer_types.count(MAMBA)
     return (_logits(x, params, cfg), jnp.stack(ks), jnp.stack(vs), {}, state,
-            _counters(lengths.sum() * n_a, pages.sum() * n_a, runs.sum() * n_m))
+            _counters(lengths.sum() * n_a, pages.sum() * n_a, runs.sum() * n_m,
+                      blocks=gqa_decode_blocks(cache["k_pages"], lengths, block_size, n_a)))

@@ -99,7 +99,8 @@ _NEG = -1e30
 # were all of them full.
 COUNTERS = ("moe_pairs", "moe_experts_hit", "moe_peak_rows", "moe_expert_slots", "moe_layer_programs",
             "kv_positions_attended", "kv_positions_gathered",
-            "attn_positions_window", "attn_positions_full", "attn_positions_unwindowed")
+            "attn_positions_window", "attn_positions_full", "attn_positions_unwindowed",
+            "kv_blocks_walked", "kv_blocks_whole")
 
 
 @dataclass(frozen=True)
@@ -328,14 +329,16 @@ def _experts(y, lp, cfg):
     return out, c, top_e
 
 
-def _counters(cfg, per_layer, window=0, full=0, unwindowed=0, gathered=0):
+def _counters(cfg, per_layer, window=0, full=0, unwindowed=0, gathered=0, blocks=(0, 0)):
     """COUNTERS of one program from its layers' [pairs, hit, peak] and
-    what its attention read."""
+    what its attention read (``blocks``: the full layers',
+    ``ops.attention.gqa_decode_blocks``)."""
     window, full = jnp.asarray(window, jnp.int32), jnp.asarray(full, jnp.int32)
     return jnp.concatenate([
         jnp.stack(per_layer).sum(0).astype(jnp.int32),
         jnp.stack([jnp.int32(cfg.num_experts * cfg.n_layer), jnp.int32(cfg.n_layer), window + full,
-                   jnp.asarray(gathered, jnp.int32), window, full, jnp.asarray(unwindowed, jnp.int32)])])
+                   jnp.asarray(gathered, jnp.int32), window, full, jnp.asarray(unwindowed, jnp.int32),
+                   *jnp.asarray(blocks, jnp.int32)])])
 
 
 def _logits(x, params, cfg):
@@ -424,7 +427,7 @@ def decode_chosen(params, cfg: MellumConfig, cache, tok, block_tables, lengths, 
     [B, V], k_new, v_new [Lf, B, G, hd], {}, {"win_k", "win_v": the
     whole rings}, COUNTERS, and for the checks the experts each layer's
     router chose [L, B, k])."""
-    from ray_tpu.ops.attention import gqa_paged_decode_attention
+    from ray_tpu.ops.attention import gqa_decode_blocks, gqa_paged_decode_attention
 
     B = tok.shape[0]
     window, ring = cfg.sliding_window, cfg.ring_rows
@@ -470,4 +473,5 @@ def decode_chosen(params, cfg: MellumConfig, cache, tok, block_tables, lengths, 
     return (_logits(x, params, cfg), jnp.stack(ks), jnp.stack(vs), {}, state,
             _counters(cfg, counts, window=held.sum() * n_w, full=lengths.sum() * n_f,
                       unwindowed=lengths.sum() * (n_w + n_f),
-                      gathered=copied(held) * n_w + copied(lengths) * n_f), jnp.stack(chose))
+                      gathered=copied(held) * n_w + copied(lengths) * n_f,
+                      blocks=gqa_decode_blocks(cache["k_pages"], lengths, block_size, n_f)), jnp.stack(chose))

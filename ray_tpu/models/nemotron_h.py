@@ -93,7 +93,8 @@ PUBLISHED_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
 # tokens x Mamba layers a chunk's scan took.
 COUNTERS = ("moe_pairs_routed", "moe_pairs_held", "moe_pairs", "moe_experts_hit",
             "moe_expert_slots", "moe_peak_rows", "moe_layer_programs",
-            "kv_positions_attended", "kv_positions_gathered", "ssm_lane_steps", "ssm_chunk_tokens")
+            "kv_positions_attended", "kv_positions_gathered", "ssm_lane_steps", "ssm_chunk_tokens",
+            "kv_blocks_walked", "kv_blocks_whole")
 
 K_BLOCK = 512  # keys a block of the prefill's online softmax
 _Q_BLOCK = 512  # queries a block of it: scores are [32, _Q_BLOCK, K_BLOCK] float32
@@ -466,14 +467,15 @@ def _experts(y, lp, cfg):
     return shared + out, jnp.concatenate([jnp.stack([routed, here.sum(dtype=jnp.int32)]), c]), top_e
 
 
-def counters(cfg, per_layer, attended=0, gathered=0, lane_steps=0, chunk_tokens=0):
+def counters(cfg, per_layer, attended=0, gathered=0, lane_steps=0, chunk_tokens=0, blocks=(0, 0)):
     """COUNTERS of one program from its expert layers' [routed, held,
     computed, hit, peak] (one entry an expert layer) and what its other
-    layers read."""
+    layers read (``blocks``: ``ops.attention.gqa_decode_blocks``)."""
     routed, held, computed, hit, peak = jnp.stack(per_layer).sum(0).astype(jnp.int32)
     n_e = len(per_layer)
     return jnp.stack([routed, held, computed, hit, jnp.int32(cfg.experts_held * n_e), peak, jnp.int32(n_e),
-                      *(jnp.asarray(v, jnp.int32) for v in (attended, gathered, lane_steps, chunk_tokens))])
+                      *(jnp.asarray(v, jnp.int32) for v in (attended, gathered, lane_steps, chunk_tokens)),
+                      *jnp.asarray(blocks, jnp.int32)])
 
 
 def _logits(x, params, cfg):
@@ -542,6 +544,8 @@ def decode_chosen(params, cfg: NemotronHConfig, cache, tok, block_tables, length
     hd], {}, {"conv_tail_<i>", "ssm_state_<i>": the whole new arrays},
     COUNTERS, and for the checks the experts each expert layer's router
     chose [Le, B, k])."""
+    from ray_tpu.ops.attention import gqa_decode_blocks
+
     runs = lengths > 0
     x = params["embed"][tok]
     ks, vs, state, counts, chose = [], [], {}, [], []
@@ -562,4 +566,5 @@ def decode_chosen(params, cfg: NemotronHConfig, cache, tok, block_tables, length
     pages = -(-lengths // block_size) * block_size
     n_a, n_m = cfg.pattern.count(ATTENTION), cfg.pattern.count(MAMBA)
     return (_logits(x, params, cfg), jnp.stack(ks), jnp.stack(vs), {}, state,
-            counters(cfg, counts, lengths.sum() * n_a, pages.sum() * n_a, runs.sum() * n_m), jnp.stack(chose))
+            counters(cfg, counts, lengths.sum() * n_a, pages.sum() * n_a, runs.sum() * n_m,
+                     blocks=gqa_decode_blocks(cache["k_pages"], lengths, block_size, n_a)), jnp.stack(chose))

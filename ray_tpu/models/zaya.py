@@ -84,7 +84,8 @@ from ray_tpu.ops import cca
 # every expert is held, ``held + skipped == routed``.
 COUNTERS = ("moe_pairs_routed", "moe_pairs_held", "moe_pairs", "moe_experts_hit",
             "moe_expert_slots", "moe_peak_rows", "moe_layer_programs",
-            "kv_positions_attended", "kv_positions_gathered", "moe_pairs_skipped")
+            "kv_positions_attended", "kv_positions_gathered", "moe_pairs_skipped",
+            "kv_blocks_walked", "kv_blocks_whole")
 
 
 # The seeded draws no key of the source sizes (init_params): the depth
@@ -366,13 +367,15 @@ def _merge(x, out, a, b):
             ).astype(x.dtype)
 
 
-def counters(cfg, per_layer, attended=0, gathered=0):
+def counters(cfg, per_layer, attended=0, gathered=0, blocks=(0, 0)):
     """COUNTERS of one program from its layers' [routed, held, computed,
-    hit, peak, skipped] and what its attention read."""
+    hit, peak, skipped] and what its attention read (``blocks``:
+    ``ops.attention.gqa_decode_blocks``)."""
     routed, held, computed, hit, peak, skipped = jnp.stack(per_layer).sum(0).astype(jnp.int32)
     n_e = len(per_layer)
     return jnp.stack([routed, held, computed, hit, jnp.int32(cfg.experts_held * n_e), peak, jnp.int32(n_e),
-                      jnp.asarray(attended, jnp.int32), jnp.asarray(gathered, jnp.int32), skipped])
+                      jnp.asarray(attended, jnp.int32), jnp.asarray(gathered, jnp.int32), skipped,
+                      *jnp.asarray(blocks, jnp.int32)])
 
 
 def _logits(x, params, cfg):
@@ -434,6 +437,8 @@ def decode_chosen(params, cfg: ZayaConfig, cache, tok, block_tables, lengths, bl
     lanes' pages where they lie.  -> (logits [B, V], k_new, v_new [L, B,
     G, hd], {}, {"cca_tail_<i>": the whole new array}, COUNTERS, and for
     the checks the output each layer's router chose [L, B, 1])."""
+    from ray_tpu.ops.attention import gqa_decode_blocks
+
     x = params["embed"][tok]
     r = jnp.zeros((tok.shape[0], cfg.router_hidden_size), jnp.float32)
     ks, vs, state, counts, chose = [], [], {}, [], []
@@ -449,4 +454,5 @@ def decode_chosen(params, cfg: ZayaConfig, cache, tok, block_tables, lengths, bl
         x = _merge(x, out, lp["a2"], lp["b2"])
     pages = -(-lengths // block_size) * block_size
     return (_logits(x, params, cfg), jnp.stack(ks), jnp.stack(vs), {}, state,
-            counters(cfg, counts, lengths.sum() * cfg.n_layer, pages.sum() * cfg.n_layer), jnp.stack(chose))
+            counters(cfg, counts, lengths.sum() * cfg.n_layer, pages.sum() * cfg.n_layer,
+                     gqa_decode_blocks(cache["k_pages"], lengths, block_size, cfg.n_layer)), jnp.stack(chose))

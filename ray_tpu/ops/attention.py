@@ -175,6 +175,18 @@ def mla_paged_decode_attention(q, row_self, pages, layer, block_tables, lengths,
     return att + probs[..., -1:] * row_self[:, None, :v_width]
 
 
+def gqa_decode_blocks(k_pages, lengths, block_size, calls=1):
+    """What ``calls`` calls of ``gqa_paged_decode_attention`` over lanes
+    of ``lengths`` cached positions walk: int32 [2], the kernel's
+    compute blocks and those of them that hold all their pages
+    (``paged_walk.blocks`` at the kernel's block for this pool), for a
+    family's counters ``kv_blocks_walked`` and ``kv_blocks_whole``."""
+    from ray_tpu.ops import paged_walk
+    from ray_tpu.ops import pallas_gqa_paged_attention as kernel
+
+    return jnp.stack(paged_walk.blocks(lengths, block_size, kernel.block_positions(k_pages))) * calls
+
+
 def gqa_paged_decode_attention(q, k_self, v_self, k_pages, v_pages, layer, block_tables, lengths, *,
                                block_size, scale=None):
     """One fed token a lane over the pages it holds of a paged KV pool,

@@ -19,14 +19,28 @@ written here, as plain functions a kernel calls while it is traced:
   VMEM buffers, a page a copy a stream (K and V; a latent pool is one
   stream), the next item's copies running behind this item's compute,
   across owners too; the softmax state is reset at an owner's first
-  block and the kernel folds the block into it.
+  block and the kernel folds the block into it.  A block that holds
+  all the pages its buffer has room for (every block of an owner but
+  its last) is copied by ``whole_block``: the copies written out as
+  straight-line code and ONE wait a stream for the bytes of them all.
+  Starting a copy is scalar work (some 20 ns) that stands in front of
+  the block's arithmetic; in a loop of the lane's own trip count, with
+  a wait a page, it was a fifth more (PERF.md section 6, PR 55).  An
+  owner's last, partial block is copied by
+  ``page_loop``, a page at a time, started and awaited in a loop: no
+  page past an owner's count is ever copied.
+- ``blocks``: what a walk over lanes of given lengths visits, blocks
+  and whole blocks, for a family's counters.
 
 The order of summation depends on positions only, never on which
-physical pages an owner was given.  What the kernels do not share is the
-block's arithmetic; each module says its own.
+physical pages an owner was given nor on which of the two ways a block
+was copied.  What the kernels do not share is the block's arithmetic
+(and how many positions a block holds); each module says its own.
 """
 
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -40,6 +54,18 @@ from ray_tpu.ops.pallas_attention import NEG_INF
 def sublanes(dtype) -> int:
     """Rows of a sublane tile of `dtype` (16 of bf16, 8 of float32)."""
     return 8 * 4 // jnp.dtype(dtype).itemsize
+
+
+def tiled_bytes(scratch) -> int:
+    """Bytes of a kernel's VMEM scratch, ``(shape, dtype)`` each, as the
+    chip lays it out: the last two dimensions in whole (sublane,
+    128-lane) tiles of the dtype."""
+    def tiled(shape, dt):
+        *lead, rows, cols = shape
+        sub = sublanes(dt)
+        return math.prod(lead) * -(-rows // sub) * sub * -(-cols // 128) * 128 * jnp.dtype(dt).itemsize
+
+    return sum(tiled(shape, dt) for shape, dt in scratch)
 
 
 def list_work(n_owners, blocks_of, item_owner, item_blk):
@@ -81,6 +107,80 @@ def lane_blocks(len_ref, tab_ref, item_lane, item_blk, block_size, n):
     return blocks_of, pages_of
 
 
+def blocks(lengths, block_size, block_positions):
+    """Compute blocks of ``block_positions`` positions a walk over lanes
+    of ``lengths`` cached positions visits, and how many of them are
+    WHOLE (hold all their pages, and so take ``walk``'s straight-line
+    copies): ``(walked, whole)``, two int32 sums.  ``jnp`` on a
+    program's own ``lengths``, for its family's counters."""
+    n = block_positions // block_size
+    pages = (lengths.astype(jnp.int32) + (block_size - 1)) // block_size
+    return ((pages + (n - 1)) // n).sum(), (pages // n).sum()
+
+
+def _page_copy(page, p, slot, *, block_size, layer, cols, stream):
+    """The copy of pool page ``page`` to rows ``p * block_size ..`` of
+    buffer ``slot`` of one stream."""
+    hbm, buf, sem = stream
+    src = pl.ds(pl.multiple_of(page * block_size, block_size), block_size)
+    dst = pl.ds(pl.multiple_of(p * block_size, block_size), block_size)
+    return pltpu.make_async_copy(hbm.at[layer, src, cols], buf.at[slot, dst, :], sem(slot))
+
+
+def page_loop(count, page_of, cols, slot, start, *, n, block_size, layer, streams):
+    """A block's ``count`` pages a page at a time: start (or, ``start``
+    false, await) each page's copy of each stream in a loop of
+    ``count`` trips.  Any count takes it; ``walk`` gives it a lane's
+    last, partial block."""
+    del n
+
+    def one(p, _):
+        for stream in streams:
+            copy = _page_copy(page_of(p), p, slot, block_size=block_size, layer=layer, cols=cols, stream=stream)
+            copy.start() if start else copy.wait()
+        return _
+
+    jax.lax.fori_loop(0, count, one, 0)
+
+
+# pages a run of straight-line copies holds at most: the latent kernel's
+# block at the served page (4,096 positions in pages of 64), the longest
+# run measured on the chip; a block of more pages is as many runs in a loop
+_STRAIGHT_PAGES = 64
+
+
+def whole_block(count, page_of, cols, slot, start, *, n, block_size, layer, streams):
+    """A WHOLE block's ``n`` pages as straight-line code: the page
+    numbers read up front, every copy of every stream written out (no
+    trip count of the lane's, nothing of a page recomputed in a loop
+    body; in runs of ``_STRAIGHT_PAGES`` where ``n`` is more), and ONE
+    wait a stream: the ``n`` copies of a stream signal one semaphore,
+    which counts bytes, so a wait for the buffer slot's whole
+    ``[n * block_size, columns]`` is a wait for them all."""
+    del count
+    if not start:
+        for _, buf, sem in streams:
+            pltpu.make_async_copy(buf.at[slot], buf.at[slot], sem(slot)).wait()
+        return
+    run = next(r for r in range(min(n, _STRAIGHT_PAGES), 0, -1) if n % r == 0)
+
+    def straight(first):
+        pages = [page_of(first + p) for p in range(run)]
+        for p, page in enumerate(pages):
+            for stream in streams:
+                _page_copy(page, first + p, slot, block_size=block_size, layer=layer, cols=cols,
+                           stream=stream).start()
+
+    if run == n:
+        straight(0)
+    else:
+        def each_run(r, _):
+            straight(r * run)
+            return _
+
+        jax.lax.fori_loop(0, n // run, each_run, 0)
+
+
 def walk(total, item, *, block_size, layer, pages_of, streams, state):
     """Walk the ``total`` items of the work list.
 
@@ -93,34 +193,31 @@ def walk(total, item, *, block_size, layer, pages_of, streams, state):
     an owner's first block.  ``item(j)`` reads what the kernel needs of
     item ``j`` and returns its block's number within its owner, what
     else to do at a first block, and ``fold(slot)``: fold the block,
-    whose rows are in buffer ``slot`` by then, into the state."""
-    bs = block_size
-    m_ref, l_ref, acc_ref = state
+    whose rows are in buffer ``slot`` by then, into the state.
 
-    def each_page(j, slot, act):
-        """act(copy) for every page of item j and every stream: HBM
-        page -> its rows of buffer ``slot``."""
+    A block that holds all the ``n`` pages its buffer has room for
+    (every block of an owner but its last) is copied by ``whole_block``,
+    any other by ``page_loop``: what the item's count says, nothing
+    else, decides.  No page past an owner's count is ever copied."""
+    m_ref, l_ref, acc_ref = state
+    n = streams[0][1].shape[1] // block_size     # pages a compute block
+    how = dict(n=n, block_size=block_size, layer=layer, streams=streams)
+
+    def copies(j, slot, start):
+        """Start, or await, the copies of item j's pages to buffer ``slot``."""
         count, page_of, cols = pages_of(j)
 
-        def one(p, _):
-            page = page_of(p)
-            src = pl.ds(pl.multiple_of(page * bs, bs), bs)
-            dst = pl.ds(pl.multiple_of(p * bs, bs), bs)
-            for hbm, buf, sem in streams:
-                act(pltpu.make_async_copy(hbm.at[layer, src, cols], buf.at[slot, dst, :], sem(slot)))
-            return _
+        @pl.when(count == n)
+        def _():
+            whole_block(count, page_of, cols, slot, start, **how)
 
-        jax.lax.fori_loop(0, count, one, 0)
-
-    def start(j, slot):
-        each_page(j, slot, lambda copy: copy.start())
-
-    def wait(j, slot):
-        each_page(j, slot, lambda copy: copy.wait())
+        @pl.when(count != n)
+        def _():
+            page_loop(count, page_of, cols, slot, start, **how)
 
     @pl.when(total > 0)
     def _():
-        start(0, 0)
+        copies(0, 0, True)
 
     def body(j, carry):
         slot = j % 2
@@ -128,7 +225,7 @@ def walk(total, item, *, block_size, layer, pages_of, streams, state):
 
         @pl.when(j + 1 < total)
         def _():
-            start(j + 1, 1 - slot)
+            copies(j + 1, 1 - slot, True)
 
         @pl.when(blk == 0)
         def _():
@@ -137,7 +234,7 @@ def walk(total, item, *, block_size, layer, pages_of, streams, state):
             acc_ref[...] = jnp.zeros_like(acc_ref)
             first()
 
-        wait(j, slot)
+        copies(j, slot, False)
         fold(slot)
         return carry
 

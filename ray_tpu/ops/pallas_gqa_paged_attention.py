@@ -8,16 +8,25 @@ One new token a lane.  The pools are ``[n_layer, num_blocks *
 block_size, G * Dh]``, a position one row of all its K/V heads, read by
 the walk of ``ops/paged_walk.py``: the owner a lane, a page one
 contiguous ``[block_size, G * Dh]`` slab of K and one of V, each copied
-ONCE for the R query heads of every group.  Its own is the block's
-arithmetic: a group's scores are one ``[R, Dh] x [Dh, positions]``
-matmul against the group's own columns of the slab, its output one
-``[R, positions] x [positions, Dh]`` matmul (no block-diagonal query: a
-group's heads read the same columns), and no K/V head is repeated for
-its query heads.  A group of fewer query heads than a sublane tile of
-the pool's dtype (4 where bf16 packs 16 rows) is padded to one with
-heads of zeros, whose rows are dropped: a group's rows are then whole
-tiles, and the matmul unit takes a tile's rows at a time whatever they
-hold.  Operands in the pool's dtype, float32 scores and softmax state.
+ONCE for the R query heads of every group (a whole block's pages
+written out as straight-line copies with one wait a stream, a last,
+partial block's started and awaited a page at a time).  A compute block
+holds a constant number of BYTES, not of positions (``block_positions``,
+read off the pool's row): what a block costs beyond its bytes (the
+chain matmul -> max -> exp -> matmul and the state's read-modify-write,
+about half a microsecond whatever the row's width) is paid once in
+1,024 positions of two K/V heads and once in 2,048 of one, where 512
+would leave the copies waiting for it (``scripts/gqa_decode_check.py``;
+PERF.md section 6, PR 55).  Its own is the block's arithmetic: a
+group's scores are one ``[R, Dh] x [Dh, positions]`` matmul against the
+group's own columns of the slab, its output one ``[R, positions] x
+[positions, Dh]`` matmul (no block-diagonal query: a group's heads read
+the same columns), and no K/V head is repeated for its query heads.  A
+group of fewer query heads than a sublane tile of the pool's dtype (4
+where bf16 packs 16 rows) is padded to one with heads of zeros, whose
+rows are dropped: a group's rows are then whole tiles, and the matmul
+unit takes a tile's rows at a time whatever they hold.  Operands in the
+pool's dtype, float32 scores and softmax state.
 """
 
 from __future__ import annotations
@@ -33,8 +42,34 @@ from jax.experimental.pallas import tpu as pltpu
 from ray_tpu.ops import paged_walk
 from ray_tpu.ops.pallas_attention import NEG_INF
 
-# positions a compute block covers: whole pages, two buffers of K and of V in VMEM
+# positions a compute block covers from `_ROW_BYTES` a row a stream up: whole pages
 _BLOCK_POSITIONS = 512
+_ROW_BYTES = 1024
+
+
+def block_positions(k_pages) -> int:
+    """Positions a compute block covers (whole pages; two buffers of K
+    and two of V in VMEM), read off the pool's row: 512 from 1 KB a row
+    a stream up (four K/V heads of 128 in bf16: a buffer is 512 KB
+    there and 1 MB at eight), 1,024 at 512 B, 2,048 at 256 B and under:
+    a buffer of a narrower row stays 512 KB."""
+    row = k_pages.shape[-1] * jnp.dtype(k_pages.dtype).itemsize
+    return _BLOCK_POSITIONS * min(4, max(1, _ROW_BYTES // row))
+
+
+def vmem_scratch(groups, n_rep, d_head, positions, dtype) -> list:
+    """The kernel's VMEM scratch, ``(shape, dtype)`` each: two buffers of
+    a compute block's rows of K and of V, a lane's queries, the softmax
+    state."""
+    H, GD = groups * n_rep, groups * d_head
+    return [
+        ((2, positions, GD), dtype),                  # kbuf: two compute blocks of K
+        ((2, positions, GD), dtype),                  # vbuf
+        ((H, d_head), dtype),                         # the lane's queries, in the pool's dtype
+        ((H, 1), jnp.float32),                        # m: running max
+        ((H, 1), jnp.float32),                        # l: running sum
+        ((H, d_head), jnp.float32),                   # acc: unnormalised output
+    ]
 
 
 def kernel_takes(n_rep, d_head, block_size, dtype) -> bool:
@@ -138,8 +173,9 @@ def gqa_paged_decode_attention_kernel(q, k_self, v_self, k_pages, v_pages, layer
     B, G, R, Dh = q.shape
     H, GD = G * R, G * Dh
     pages_per_seq = block_tables.shape[1]
-    n = _BLOCK_POSITIONS // block_size  # pages a compute block
-    dt = k_pages.dtype
+    bk = block_positions(k_pages)
+    n = bk // block_size  # pages a compute block
+    kbuf, vbuf, *rest = vmem_scratch(G, R, Dh, bk, k_pages.dtype)
     items = B * -(-pages_per_seq // n)  # compute blocks the lanes can hold
 
     def whole(rows, width):
@@ -165,13 +201,10 @@ def gqa_paged_decode_attention_kernel(q, k_self, v_self, k_pages, v_pages, layer
             scratch_shapes=[
                 pltpu.SMEM((items,), jnp.int32),                   # item_lane
                 pltpu.SMEM((items,), jnp.int32),                   # item_blk
-                pltpu.VMEM((2, _BLOCK_POSITIONS, GD), dt),         # kbuf: two compute blocks of K
-                pltpu.VMEM((2, _BLOCK_POSITIONS, GD), dt),         # vbuf
+                pltpu.VMEM(*kbuf),
+                pltpu.VMEM(*vbuf),
                 pltpu.SemaphoreType.DMA((2, 2)),                   # [K or V, buffer]
-                pltpu.VMEM((H, Dh), dt),                           # the lane's queries, in the pool's dtype
-                pltpu.VMEM((H, 1), jnp.float32),                   # m: running max
-                pltpu.VMEM((H, 1), jnp.float32),                   # l: running sum
-                pltpu.VMEM((H, Dh), jnp.float32),                  # acc: unnormalised output
+                *(pltpu.VMEM(*one) for one in rest),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, Dh), jnp.float32),

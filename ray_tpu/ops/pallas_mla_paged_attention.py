@@ -7,7 +7,9 @@ keys are a cached row's ``W`` columns, values its first ``v_width``
 columns.  The pool is ``[n_layer, num_blocks * block_size, W]``, read
 by the walk of ``ops/paged_walk.py``: the owner a lane, a page one
 contiguous ``[block_size, W]`` slab, ONE copy a page serving keys and
-values both (one stream, one buffer).  Its own is the block's
+values both (one stream, one buffer; a whole block's pages written out
+as straight-line copies with one wait, a last, partial block's started
+and awaited a page at a time).  Its own is the block's
 arithmetic: scores are ``[H, W] x [W, positions]`` matmuls of all heads
 (no block-diagonal layout: the heads share the row), the output
 ``[H, positions] x [positions, v_width]`` matmuls over the same buffer's
@@ -32,7 +34,6 @@ PR 47).
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -65,14 +66,8 @@ def vmem_scratch(n_head, width, v_width, dtype) -> list:
 
 
 def vmem_scratch_bytes(n_head, width, v_width, dtype) -> int:
-    """Bytes of ``vmem_scratch`` as the chip lays it out: the last two
-    dimensions in whole (sublane, 128-lane) tiles of the dtype."""
-    def tiled(shape, dt):
-        *lead, rows, cols = shape
-        sub = paged_walk.sublanes(dt)
-        return math.prod(lead) * -(-rows // sub) * sub * -(-cols // 128) * 128 * jnp.dtype(dt).itemsize
-
-    return sum(tiled(shape, dt) for shape, dt in vmem_scratch(n_head, width, v_width, dtype))
+    """Bytes of ``vmem_scratch`` as the chip lays it out."""
+    return paged_walk.tiled_bytes(vmem_scratch(n_head, width, v_width, dtype))
 
 
 def kernel_takes(n_head, width, v_width, block_size, dtype) -> bool:

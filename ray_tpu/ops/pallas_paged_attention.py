@@ -10,7 +10,9 @@ from the same block tables in plain ``jax.numpy`` elsewhere.
 
 The kernel is one program a layer, and reads the pool by the walk of
 ``ops/paged_walk.py``: the owner a lane, a page one contiguous
-``[block_size, n_head * d_head]`` slab of all heads, of K and of V.
+``[block_size, n_head * d_head]`` slab of all heads, of K and of V (a
+whole block's pages written out as straight-line copies with one wait a
+stream, a last, partial block's started and awaited a page at a time).
 Operands in the pool's dtype, float32 scores and softmax state, every
 cached position attended.  Its own is the block's arithmetic:
 

@@ -10,7 +10,9 @@ num_blocks * block_size, n_kv * d_head]``, a position one row of all
 K/V heads, read by the walk of ``ops/paged_walk.py``: the owner a pair,
 a compute block a few of its chosen blocks, a page's number read from
 the chosen pages and not from a block table, and THAT K/V head's
-columns only copied (``[block_size, d_head]`` a copy).  Pages that were
+columns only copied (``[block_size, d_head]`` a copy; a compute block
+that is full of chosen blocks as straight-line copies with one wait a
+stream, any other a page at a time).  Pages that were
 not chosen are never touched; a pair that reads nothing costs nothing.
 Its own is the block's arithmetic: scores are one ``[R, d] x [d,
 positions]`` matmul of the pair's R query heads, no block-diagonal

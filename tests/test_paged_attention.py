@@ -13,6 +13,8 @@ And the page walk the four paged-decode kernels share
 edge of the walk against its own gather in ``ops/attention.py``.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -235,7 +237,7 @@ def _dense_walk(rand, pools, tables, lens):
 def _gqa_walk(rand, pools, tables, lens):
     B = len(lens)
     return (gqa_kernel.gqa_paged_decode_attention_kernel, attention.gqa_paged_decode_attention,
-            (rand(B, 2, 8, 16), rand(B, 2, 16), rand(B, 2, 16), *pools, 1, tables, lens), dict(block_size=BS))
+            (rand(B, 2, 8, 128), rand(B, 2, 128), rand(B, 2, 128), *pools, 1, tables, lens), dict(block_size=BS))
 
 
 def _mla_walk(rand, pools, tables, lens):
@@ -263,7 +265,7 @@ def _sparse_walk(rand, pools, tables, lens):
 # kernel -> (its module, the columns of a pool row, pools, its entry and gather with their arguments)
 WALKS = {
     "dense": (kernel, 128, 2, _dense_walk),
-    "gqa": (gqa_kernel, 32, 2, _gqa_walk),
+    "gqa": (gqa_kernel, 256, 2, _gqa_walk),  # 1 KB a row in float32: blocks of _BLOCK_POSITIONS
     "mla": (mla_kernel, 64, 1, _mla_walk),
     "sparse": (sparse_kernel, 64, 2, _sparse_walk),
 }
@@ -325,6 +327,176 @@ def test_every_kernel_walks_its_pages_as_its_gather_reads_them(name, what):
         np.testing.assert_array_equal(got, run(2, True))
     else:
         np.testing.assert_allclose(got, run(1, False), atol=2e-5, rtol=2e-5)
+
+
+# ----------------------------------------------------------------------
+# a whole block's pages as straight-line code, any other a page at a time
+# ----------------------------------------------------------------------
+def _block_lens(what, bk):
+    """Cached positions a lane, by the pages its blocks hold; bk the
+    positions of the kernel's compute block (n pages)."""
+    return {
+        "no_position": [0, 0],                      # no item: neither path
+        "part_of_a_page": [BS - 3, 0, 1],           # one partial block a lane: the loop alone
+        "n_pages": [bk, bk - BS + 1],               # a lane's only block is whole; so is one whose last page is part full
+        "n_pages_and_a_part": [bk + BS + 3, 5],     # a whole block, then the loop, then the next lane's
+        "whole_blocks": [2 * bk + BS, 0, bk],       # whole after whole, across an empty lane
+    }[what]
+
+
+@pytest.mark.parametrize("what", ["no_position", "part_of_a_page", "n_pages", "n_pages_and_a_part", "whole_blocks"])
+@pytest.mark.parametrize("name", list(WALKS))
+def test_a_whole_block_s_straight_line_copies_give_the_page_loop_s_bits(name, what, monkeypatch):
+    """Each kernel (interpret mode, float32) with the walk as it is, a
+    block that holds all its pages copied by ``whole_block``, against
+    the same kernel with every block copied a page at a time
+    (``page_loop``, the partial block's path, given the whole ones too):
+    the same bits, and the same again on other physical pages.  What
+    interpret mode cannot show is that a whole block's one wait a
+    stream waits for all its copies: ``scripts/gqa_decode_check.py``
+    holds that to the gather path on the chip."""
+    module, width, n_pools, flavour = WALKS[name]
+    lens = _block_lens(what, module._BLOCK_POSITIONS)
+    per_lane = (-(-max(lens) // 64) + 1) * 64 // BS
+    straight = []
+
+    def run(seed):
+        pools, tables = _assigned(lens, width, n_pools, per_lane, seed)
+        rng = np.random.default_rng(7)
+        entry, _, args, sizes = flavour(
+            lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32), pools, tables,
+            jnp.asarray(lens, jnp.int32))
+        # a jit keeps what it traced: the function under the entry's own is traced anew
+        return np.asarray(jax.jit(functools.partial(entry.__wrapped__, **sizes, interpret=True))(*args))
+
+    def noted(*a, **kw):
+        straight.append(kw["n"])
+        return whole_block(*a, **kw)
+
+    whole_block = paged_walk.whole_block
+    monkeypatch.setattr(paged_walk, "whole_block", noted)
+    got = run(1)
+    assert straight and set(straight) == {module._BLOCK_POSITIONS // BS}  # traced, n pages a block
+    if what in ("n_pages_and_a_part", "whole_blocks"):
+        np.testing.assert_array_equal(got, run(2))
+    monkeypatch.setattr(paged_walk, "whole_block", paged_walk.page_loop)
+    del straight[:]
+    looped = run(1)
+    assert not straight and np.isfinite(got).all()
+    np.testing.assert_array_equal(got, looped)
+
+
+@pytest.mark.parametrize("block_size, block_positions", [(64, 512), (16, 128), (64, 4096), (4, 512)])
+def test_blocks_counts_the_walk_s_blocks_and_the_whole_ones(block_size, block_positions):
+    """``paged_walk.blocks`` against a count in Python over drawn
+    lengths: a lane walks a block for every ``n`` pages it holds or part
+    of them, and a block is whole when the lane holds all ``n``."""
+    rng = np.random.default_rng(block_size + block_positions)
+    lens = np.concatenate([rng.integers(0, 5 * block_positions, 61),
+                           [0, 1, block_size, block_positions - block_size, block_positions - block_size + 1,
+                            block_positions, block_positions + 1, 2 * block_positions]])
+    n = block_positions // block_size
+    held = [-(-int(length) // block_size) for length in lens]
+    walked, whole = paged_walk.blocks(jnp.asarray(lens, jnp.int32), block_size, block_positions)
+    assert int(walked) == sum(-(-pages // n) for pages in held)
+    assert int(whole) == sum(pages // n for pages in held) > 0
+    assert walked.dtype == whole.dtype == jnp.int32
+
+
+# ----------------------------------------------------------------------
+# the grouped-query kernel's block: a constant number of bytes
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("kv_heads, n_rep, positions", [(1, 20, 2048), (2, 4, 1024), (2, 16, 1024), (4, 8, 512),
+                                                        (8, 4, 512)])
+def test_gqa_kernel_block_is_read_off_the_pool_s_row_and_its_scratch_fits_the_vmem_a_kernel_gets(
+        kv_heads, n_rep, positions):
+    """The served shapes (Jamba's one K/V head of 128 in bf16, ZAYA's and
+    Nemotron's two, Mellum's four, Granite's eight): a compute block is
+    512 positions from 1 KB a row up and as many as keep a buffer at
+    512 KB under it; the scratch as the chip lays it out (a group of
+    fewer heads than a tile padded to one) and three ``[heads,
+    positions]`` float32 tiles of scores beside it leave more than half
+    of the 16 MiB a kernel gets unasked to its operands."""
+    pool = jax.ShapeDtypeStruct((3, 64 * 100, kv_heads * 128), jnp.bfloat16)
+    assert gqa_kernel.block_positions(pool) == positions
+    assert max(positions * kv_heads * 128 * 2, 2**19) == (2**20 if kv_heads == 8 else 2**19)
+    padded = -(-n_rep // 16) * 16
+    scratch = gqa_kernel.vmem_scratch(kv_heads, padded, 128, positions, jnp.bfloat16)
+    assert scratch[0] == scratch[1] == ((2, positions, kv_heads * 128), jnp.bfloat16)
+    scores = 3 * kv_heads * padded * positions * 4
+    assert paged_walk.tiled_bytes(scratch) + scores < 8 * 2**20
+    # a float32 pool's row is twice the bytes: a block of half the positions, never under 512
+    wide = jax.ShapeDtypeStruct(pool.shape, jnp.float32)
+    assert gqa_kernel.block_positions(wide) == max(512, positions // 2)
+
+
+@pytest.mark.parametrize("kv_heads, positions", [(1, 2048), (2, 1024)])
+def test_gqa_kernel_at_a_narrow_row_s_block_reads_what_its_gather_reads(kv_heads, positions):
+    """The kernel (interpret mode, bf16) where a row is under 1 KB and a
+    compute block longer than 512 positions, at the block's edges (a
+    lane ending on it, an empty lane, a lane one position past it, a
+    short one, a lane of two blocks and a part) against the gather."""
+    lens = [positions, 0, positions + 1, 5, 2 * positions + BS + 3]
+    per_lane = -(-max(lens) // BS) + 1
+    pools, tables = _assigned(lens, kv_heads * 128, 2, per_lane, 1)
+    pools = [p.astype(jnp.bfloat16) for p in pools]
+    assert gqa_kernel.block_positions(pools[0]) == positions
+    rng = np.random.default_rng(7)
+
+    def rand(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+
+    B = len(lens)
+    args = (rand(B, kv_heads, 16, 128), rand(B, kv_heads, 128), rand(B, kv_heads, 128), *pools, 1, tables,
+            jnp.asarray(lens, jnp.int32))
+    got = np.asarray(gqa_kernel.gqa_paged_decode_attention_kernel(*args, block_size=BS, interpret=True), np.float32)
+    want = np.asarray(attention.gqa_paged_decode_attention(*args, block_size=BS), np.float32)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, atol=3e-2, rtol=3e-2)
+
+
+@pytest.mark.parametrize("model, module, config, block_size", [
+    ("zaya1_tiny", "ray_tpu.models.zaya", "ZayaConfig", 4),
+    ("nemotron_3_nano_tiny", "ray_tpu.models.nemotron_h", "NemotronHConfig", 8),
+])
+def test_engine_counts_the_blocks_its_decode_steps_walk(model, module, config, block_size, monkeypatch):
+    """``stats()`` of a tiny engine of two grouped-query families after
+    decode steps over a lane that grows past a compute block: the
+    blocks its kernel calls walk and those of them that hold all their
+    pages, a Python count over the steps' lengths and the paged layers.
+    The tiny presets stop at 512 positions, under one block of their
+    narrow rows: the preset is given room for a block and a part."""
+    import asyncio
+    import dataclasses
+    import importlib
+
+    from ray_tpu.serve.llm import LLMConfig, LLMEngine
+    from ray_tpu.serve.llm.engine import FINISHED
+
+    cls = getattr(importlib.import_module(module), config)
+    preset = getattr(cls, model)
+    monkeypatch.setattr(cls, model, classmethod(
+        lambda _, **kw: dataclasses.replace(preset(**kw), max_seq_len=2304, prefill_chunk=256)))
+    n_prompt, n_out = 2044, 7
+
+    async def main():
+        eng = LLMEngine(LLMConfig(model=model, max_batch_size=2, num_blocks=2304 // block_size + 8,
+                                  block_size=block_size, seed=5))
+        req = await eng.add_request(np.random.default_rng(6).integers(0, 256, n_prompt).tolist(), max_tokens=n_out)
+        while (await req.out.get()) is not FINISHED:
+            pass
+        stats, cfg, pool = eng.stats(), eng.model_cfg, eng.cache["k_pages"]
+        await eng.stop()
+        return stats, cfg, pool
+
+    stats, cfg, pool = asyncio.run(main())
+    bk = gqa_kernel.block_positions(pool)
+    assert bk == 2048 and n_prompt < bk < n_prompt + n_out - 1
+    n = bk // block_size
+    held = [-(-length // block_size) for length in range(n_prompt, n_prompt + n_out - 1)]  # the decode steps' lanes
+    assert stats["kv_blocks_walked"] == pool.shape[0] * sum(-(-pages // n) for pages in held)
+    assert stats["kv_blocks_whole"] == pool.shape[0] * sum(pages // n for pages in held)
+    assert stats["kv_blocks_walked"] > stats["kv_blocks_whole"] > 0
 
 
 # ----------------------------------------------------------------------
