@@ -20,6 +20,7 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 from benchmark import flops, flops_sala, spec  # noqa: E402
+from benchmark.tests import rehearsal  # noqa: E402
 
 CELL = "minicpm-sala.serve.longdoc-backlog"
 L, S = "lightning-attn", "minicpm4"
@@ -64,7 +65,7 @@ def test_cell_end_to_end_at_tiny_size(monkeypatch, trace):
     assert out is not None
     assert out["correct"] and out["attempted"] > 0 and out["failed"] == 0
     if trace:
-        assert set(out["metrics"]) == ON_THE_CPU and "breakdown" in out
+        assert set(out["metrics"]) >= ON_THE_CPU and "breakdown" in out
         assert 0 < out["metrics"]["sparse_kept_pct.sala"]["value"] < 100  # blocks were dropped
         assert out["metrics"]["prefill_pad_ratio"]["value"] >= 1
         assert out["metrics"]["prefill_chunk_ms"]["value"] > 0
@@ -73,14 +74,19 @@ def test_cell_end_to_end_at_tiny_size(monkeypatch, trace):
         assert out["metrics"]["serve_out_tokens_per_s"]["value"] > 0
 
 
-def test_the_cell_s_metrics_are_the_entries_of_benchmark_json():
+@pytest.mark.parametrize("tree", rehearsal.TREES)
+def test_the_cell_s_metrics_are_the_entries_of_benchmark_json(tree, tmp_path, monkeypatch):
+    """About this cell alone, so that a later PR's cells and entries
+    (``rehearsal.plant`` makes such an addition) need no edit here."""
+    rehearsal.plant(tree, tmp_path, monkeypatch)
     bench = spec.load_benchmark()
     per_layer = {m["name"]: m for m in spec.metrics_of_cell(bench, "per_layer", CELL)}
-    assert set(per_layer) == ON_THE_CPU | FROM_THE_DEVICE
-    for name, m in per_layer.items():
-        assert CELL in m["workloads"] and spec.load_layer_metric(name)["reader"]
-        assert m["moves"] == ("setup_s" if name.startswith("deploy_ready") else "serve_out_tokens_per_s")
-    assert {m["name"] for m in spec.metrics_of_cell(bench, "end_to_end", CELL)} == {
+    assert set(per_layer) >= ON_THE_CPU | FROM_THE_DEVICE
+    for name in ON_THE_CPU | FROM_THE_DEVICE:
+        assert CELL in per_layer[name]["workloads"] and spec.load_layer_metric(name)["reader"]
+        assert per_layer[name]["moves"] == (
+            "setup_s" if name.startswith("deploy_ready") else "serve_out_tokens_per_s")
+    assert {m["name"] for m in spec.metrics_of_cell(bench, "end_to_end", CELL)} >= {
         "serve_out_tokens_per_s", "setup_s"}
     cell, wl = spec.load_cell(CELL), spec.entry(bench, "workloads", CELL)
     assert (cell["why"], cell["config"], cell["chips"]) == (wl["why"], wl["config"], 1)

@@ -54,7 +54,7 @@ def test_cell_end_to_end_at_tiny_size(monkeypatch, trace):
     assert out is not None
     assert out["correct"] and out["attempted"] > 0 and out["failed"] == 0
     if trace:
-        assert set(out["metrics"]) == ON_THE_CPU and "breakdown" in out
+        assert set(out["metrics"]) >= ON_THE_CPU and "breakdown" in out
         assert 0 < out["metrics"]["moe_experts_hit_pct"]["value"] <= 100
         assert out["metrics"]["moe_imbalance"]["value"] >= 1
         assert out["metrics"]["lanes_busy_pct.backlog"]["value"] > 50

@@ -22,6 +22,7 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 from benchmark import flops, flops_zaya, spec  # noqa: E402
+from benchmark.tests import rehearsal  # noqa: E402
 
 CELL = "zaya1-8b.serve.longthink-backlog"
 NAME = "zaya1-8b"
@@ -49,7 +50,7 @@ TINY_CELL = {
 ON_THE_CPU = {"engine_step_ms.backlog", "lanes_busy_pct.backlog", "host_ms_per_step", "prefill_share_pct",
               "prefill_chunk_ms", "deploy_ready_s.serve", "prefill_pad_ratio", "decode_overlap_pct",
               "kv_gather_useful_pct", "moe_experts_hit_pct", "moe_imbalance", "moe_held_share_pct",
-              "ssm_state_mb_per_step"}
+              "ssm_state_mb_per_step", "kv_blocks_whole_pct", "moe_skip_share_pct"}
 FROM_THE_DEVICE = {"device_idle_pct.backlog", "moe_gmm_busy_pct", "moe_gmm_roofline_pct",
                    "gqa_paged_decode_attention_busy_pct", "gqa_paged_decode_attention_roofline",
                    "prefill_mfu_pct"}
@@ -72,7 +73,6 @@ def test_cell_end_to_end_at_tiny_size(monkeypatch, trace):
     assert out["correct"] and out["attempted"] > 0 and out["failed"] == 0
     if trace:
         assert set(out["metrics"]) >= ON_THE_CPU and "breakdown" in out
-        assert set(out["metrics"]) <= ON_THE_CPU | FROM_THE_DEVICE
         # every expert is held: what is not held is skipped, about one pair in five at four experts
         assert 50 < out["metrics"]["moe_held_share_pct"]["value"] < 100
         # a decode step reads and writes every lane's tails: 4 lanes x 4 layers x 336 float32 values, twice
@@ -92,21 +92,23 @@ def test_a_reference_told_another_model_is_not_correct(monkeypatch, wrong):
     assert out is not None and not out["correct"]
 
 
-def test_the_cell_s_metrics_are_the_entries_of_benchmark_json():
+@pytest.mark.parametrize("tree", rehearsal.TREES)
+def test_the_cell_s_metrics_are_the_entries_of_benchmark_json(tree, tmp_path, monkeypatch):
+    """About this cell alone, so that a later PR's cells and entries
+    (``rehearsal.plant`` makes such an addition) need no edit here."""
+    rehearsal.plant(tree, tmp_path, monkeypatch)
     bench = spec.load_benchmark()
     per_layer = {m["name"]: m for m in spec.metrics_of_cell(bench, "per_layer", CELL)}
-    assert set(per_layer) == ON_THE_CPU | FROM_THE_DEVICE
-    for name, m in per_layer.items():
-        assert CELL in m["workloads"] and spec.load_layer_metric(name)["reader"]
-        assert m["moves"] == ("setup_s" if name.startswith("deploy_ready") else "serve_out_tokens_per_s")
-    assert {m["name"] for m in spec.metrics_of_cell(bench, "end_to_end", CELL)} == {
+    assert set(per_layer) >= ON_THE_CPU | FROM_THE_DEVICE
+    for name in ON_THE_CPU | FROM_THE_DEVICE:
+        assert CELL in per_layer[name]["workloads"] and spec.load_layer_metric(name)["reader"]
+        assert per_layer[name]["moves"] == (
+            "setup_s" if name.startswith("deploy_ready") else "serve_out_tokens_per_s")
+    assert {m["name"] for m in spec.metrics_of_cell(bench, "end_to_end", CELL)} >= {
         "serve_out_tokens_per_s", "setup_s"}
-    # this PR adds NO entry: the table stands at its own test's ceiling, and the cell joins entries that are there
-    assert len(bench["per_layer"]) == 68
-    assert not os.path.exists(os.path.join(os.path.dirname(HERE), "layer_metrics", "moe_skip_share_pct.json"))
-    # the cell and its configuration are there, on one chip, last in their lists
+    # the cell and its configuration are there, on one chip
     names = [w["name"] for w in bench["workloads"]]
-    assert names[-1] == CELL and [c["name"] for c in bench["configs"]][-1] == NAME
+    assert CELL in names and NAME in [c["name"] for c in bench["configs"]]
     # the driver's rule: at most a quarter of the cells, rounded down, on four chips, and one always
     assert sum(w["chips"] == 4 for w in bench["workloads"]) <= max(1, len(names) // 4) and len(names) <= 24
     cell, wl = spec.load_cell(CELL), spec.entry(bench, "workloads", CELL)
