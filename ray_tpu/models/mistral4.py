@@ -38,8 +38,10 @@ There is no V pool (``cache_spec``).  Two attention paths read it:
   sequence's rows are gathered through the block table, the chunk's own
   put in at ``start``, and ``k_nope`` and ``v`` EXPANDED from them a
   block of keys at a time inside an online softmax; blocks wholly above
-  the diagonal are not visited.
-- *decode* (``decode_forward_cached``): ABSORBED.  ``q_lat = q_nope
+  the diagonal are not visited (``ops.mla.expanded_attention``, which
+  ``models/glm_moe_dsa.py`` shares).
+- *decode* (``decode_forward_cached``): ABSORBED
+  (``ops.mla.absorbed_queries``).  ``q_lat = q_nope
   W_uk[i]^T`` (32 x 256), scores ``q_lat . c + q_rope . k_r`` against
   the latent pages read in place by
   ``ops.attention.mla_paged_decode_attention``, ``o_lat = softmax . c``,
@@ -72,6 +74,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.common import CacheSpec, rmsnorm, yarn_inv_freq as common_yarn_inv_freq
+from ray_tpu.ops.mla import K_BLOCK as _K_BLOCK, absorbed_queries, expanded_attention, rope_interleaved
 
 # What a forward returns after what it writes, summed over its layers:
 # token-expert pairs the router made (tokens x 4); of those, the pairs
@@ -86,9 +89,6 @@ COUNTERS = ("moe_pairs_routed", "moe_pairs_held", "moe_pairs", "moe_experts_hit"
             "kv_positions_attended", "kv_positions_gathered")
 
 _LANE = 128  # columns of a lane tile: a cached row is whole tiles
-_K_BLOCK = 512  # keys a block of the prefill's online softmax
-_Q_BLOCK = 1024  # queries a block of it: scores are [32, _Q_BLOCK, _K_BLOCK] float32
-_NEG = -1e30
 
 
 @dataclass(frozen=True)
@@ -191,16 +191,11 @@ def query_scale(pos, cfg: Mistral4Config):
 
 
 def _rope(x, pos, cfg):
-    """x [..., D] rotated at positions pos (broadcast against x's
-    leading dims) over interleaved pairs (2i, 2i + 1); cos and sin
+    """x [..., D] rotated at positions pos over interleaved pairs
+    (``ops.mla.rope_interleaved``) at YaRN's frequencies; cos and sin
     scaled by ``mscale / mscale_all_dim`` as YaRN has it (1 here)."""
     attn = _yarn_mscale(cfg.rope_factor, cfg.mscale) / _yarn_mscale(cfg.rope_factor, cfg.mscale_all_dim)
-    ang = pos.astype(jnp.float32)[..., None] * jnp.asarray(yarn_inv_freq(cfg), jnp.float32)
-    cos, sin = jnp.cos(ang) * attn, jnp.sin(ang) * attn
-    pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], -1, 2)
-    even, odd = pairs[..., 0], pairs[..., 1]
-    out = jnp.stack([even * cos - odd * sin, odd * cos + even * sin], axis=-1)
-    return out.reshape(x.shape).astype(x.dtype)
+    return rope_interleaved(x, pos, yarn_inv_freq(cfg), attn)
 
 
 # ----------------------------------------------------------------------
@@ -317,53 +312,6 @@ def _logits(x, params, cfg):
     return (rmsnorm(x, params["norm"], cfg.rms_norm_eps) @ params["lm_head"]).astype(jnp.float32)
 
 
-def expanded_attention(q_nope, q_rope, ctx, wukv, start, n_valid, cfg):
-    """The prefill path: queries [T, H, .] (scaled) of the positions
-    ``start ..`` over the cached rows ``ctx [C, latent_row]`` (position
-    p in row p; whole key blocks), keys and values expanded from the
-    rows a block at a time inside an online softmax.  A block of keys
-    past a query block's last position, or past the last real position,
-    is not visited.  -> [T, H * v_head_dim]."""
-    T, H = q_nope.shape[:2]
-    nope, rope, kv, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank, cfg.v_head_dim
-    tq = min(T, _Q_BLOCK)
-    q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    outs = []
-    for first in range(0, T, tq):
-        qb = q[first:first + tq]
-        q_pos = start + first + jnp.arange(tq)
-        # keys up to this block's last query, and no further than the last real position
-        seen = jnp.minimum(start + first + tq, start + n_valid)
-        blocks = jnp.where(first < n_valid, -(-seen // _K_BLOCK), 0)
-
-        def body(j, carry, qb=qb, q_pos=q_pos):
-            m, l, acc = carry
-            rows = jax.lax.dynamic_slice_in_dim(ctx, j * _K_BLOCK, _K_BLOCK)
-            with jax.named_scope("mla.expand"):
-                knv = (rows[:, :kv] @ wukv).reshape(_K_BLOCK, H, nope + dv)
-                k_r = jnp.broadcast_to(rows[:, None, kv:kv + rope], (_K_BLOCK, H, rope))
-                k = jnp.concatenate([knv[..., :nope], k_r], axis=-1)
-            with jax.named_scope("mla.attend"):
-                s = jnp.einsum("thd,khd->htk", qb, k, preferred_element_type=jnp.float32)
-                k_pos = j * _K_BLOCK + jnp.arange(_K_BLOCK)
-                s = jnp.where(k_pos[None, None, :] <= q_pos[None, :, None], s, _NEG)
-                m_new = jnp.maximum(m, s.max(-1))
-                alpha = jnp.exp(m - m_new)
-                p = jnp.exp(s - m_new[..., None])
-                l = alpha * l + p.sum(-1)
-                acc = alpha[..., None] * acc + jnp.einsum(
-                    "htk,khd->htd", p.astype(qb.dtype), knv[..., nope:], preferred_element_type=jnp.float32)
-            return m_new, l, acc
-
-        init = (jnp.full((H, tq), _NEG, jnp.float32), jnp.zeros((H, tq), jnp.float32),
-                jnp.zeros((H, tq, dv), jnp.float32))
-        _, l, acc = jax.lax.fori_loop(0, blocks, body, init)
-        # a block of pads alone visited nothing: l is 0 there, and its rows are dropped
-        o = acc / jnp.maximum(l, 1e-30)[..., None]
-        outs.append(o.transpose(1, 0, 2).reshape(tq, H * dv).astype(qb.dtype))
-    return jnp.concatenate(outs) if len(outs) > 1 else outs[0]
-
-
 def prefill_chunk(params, cfg: Mistral4Config, cache, tokens, start, last_index, table, lane,
                   block_size: int):
     """``prefill_chosen`` less its last result: what the engine takes."""
@@ -404,18 +352,6 @@ def prefill_chosen(params, cfg: Mistral4Config, cache, tokens, start, last_index
         chose.append(top_e)
     return (_logits(x[last_index], params, cfg), jnp.stack(rows_out)[:, None], None, {}, {},
             _counters(cfg, counts), jnp.stack(chose))
-
-
-def absorbed_queries(q_nope, q_rope, wukv, cfg):
-    """``mla.absorb``: [B, H, latent_row] queries against latent rows:
-    ``q_nope W_uk[i]^T`` (the latent's 256 columns), the rotated part,
-    zeros."""
-    B, H = q_nope.shape[:2]
-    nope, kv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
-    w_uk = wukv.reshape(kv, H, nope + cfg.v_head_dim)[..., :nope]
-    q_lat = jnp.einsum("bhd,chd->bhc", q_nope, w_uk)
-    pad = jnp.zeros((B, H, cfg.latent_row - kv - cfg.qk_rope_head_dim), q_lat.dtype)
-    return jnp.concatenate([q_lat, q_rope, pad], axis=-1)
 
 
 def decode_forward_cached(params, cfg: Mistral4Config, cache, tok, block_tables, lengths,

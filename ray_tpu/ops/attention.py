@@ -19,7 +19,12 @@ queries (ops.pallas_sparse_paged_attention); a pool of latent rows that
 all heads share, keys and values both (ops.pallas_mla_paged_attention);
 the whole of a lane's pages with grouped queries
 (ops.pallas_gqa_paged_attention).  The four share the walk over the
-pages (ops.paged_walk) and differ in the block's arithmetic.
+pages (ops.paged_walk) and differ in the block's arithmetic.  Under a
+learned token-level index (ops.dsa) two more on the same walk: the index
+scores of a lane's queries against the index keys in its pages
+(ops.pallas_dsa), and the latent kernel again with the scores masked by
+a lane's CHOICE of positions (a pool's single row is not a copy Mosaic
+takes, so every page a lane holds is still read).
 
 All paths: f32 accumulation, bf16 in/out, static shapes.
 """
@@ -174,6 +179,81 @@ def mla_paged_decode_attention(q, row_self, pages, layer, block_tables, lengths,
     att = jnp.einsum("bhc,bcv->bhv", probs[..., :-1], ctx[..., :v_width])
     return att + probs[..., -1:] * row_self[:, None, :v_width]
 
+
+def dsa_index_paged_scores(q_i, w, k_self, pages, layer, block_tables, lengths, *, block_size):
+    """The index scores (``ops.dsa.index_scores``) of one fed token a
+    lane against the index keys in the pages the lane holds of a paged
+    pool, layer ``layer``, and against its own key (not in the pool yet).
+
+    q_i [B, Hi, Di] the index queries, w [B, Hi] float32 the heads'
+    weights, k_self [B, Di]; pages [L, num_blocks * block_size, Di];
+    block_tables [B, pages] int32, scratch block 0 where a lane holds
+    none; lengths [B] int32 the cached positions of a lane, under
+    ``pages * block_size``.  Returns [B, pages * block_size] float32: a
+    cached position's score in its column, the fed token's own in column
+    ``lengths``, -1e30 past it.
+
+    On a TPU, where the shapes fit its tiling, the Pallas kernel reads
+    the keys where they lie (ops.pallas_dsa).  Elsewhere the lane's keys
+    are gathered to a contiguous context."""
+    from ray_tpu.ops import dsa
+
+    B, Hi, Di = q_i.shape
+    C = block_tables.shape[1] * block_size
+    cached = None
+    if jax.default_backend() == "tpu":  # as paged_decode_attention: the CPU tests gather
+        from ray_tpu.ops import pallas_dsa as kernel
+
+        if kernel.index_kernel_takes(B, Hi, Di, block_size, block_tables.shape[1], pages.dtype):
+            cached = kernel.dsa_index_paged_scores_kernel(q_i, w, pages, layer, block_tables, lengths,
+                                                          block_size=block_size)
+    if cached is None:
+        idx = (block_tables[:, :, None] * block_size + jnp.arange(block_size)).reshape(B, C)
+        L, P, _ = pages.shape
+        ctx = pages.reshape(L * P, Di)[layer * P + idx]  # [B, C, Di]
+        cached = jax.vmap(lambda q, ws, keys: dsa.index_scores(q[None], ws[None], keys)[0])(q_i, w, ctx)
+    own = jax.vmap(lambda q, ws, key: dsa.index_scores(q[None], ws[None], key[None])[0, 0])(q_i, w, k_self)
+    pos = jnp.arange(C)[None, :]
+    return jnp.where(pos < lengths[:, None], cached, jnp.where(pos == lengths[:, None], own[:, None], -1e30))
+
+
+def mla_sparse_paged_decode_attention(q, row_self, pages, layer, block_tables, keep, lengths, *, block_size,
+                                      v_width):
+    """One fed token a lane over the CHOSEN latent rows of a paged pool,
+    layer ``layer``: as ``mla_paged_decode_attention``, but a lane
+    attends the positions ``keep [B, pages * block_size]`` bool marks
+    alone (``ops.dsa.keep_mask`` over the cached positions and, in column
+    ``lengths``, the fed token's own, whose row ``row_self`` is not in
+    the pool yet; where the choice left it out it is not attended).
+    Returns [B, H, v_width].
+
+    On a TPU, where the shapes fit its tiling, the Pallas kernel walks
+    the lane's pages where they lie and masks the scores by the choice
+    (ops.pallas_mla_paged_attention: a pool's single row is not a copy
+    Mosaic takes, so every page is read).  Elsewhere the lane's rows are
+    gathered to a contiguous context."""
+    B, H, W = q.shape
+    C = block_tables.shape[1] * block_size
+    pos = jnp.arange(C)[None, :]
+    own_kept = (keep & (pos == lengths[:, None])).any(-1)
+    cached = keep & (pos < lengths[:, None])
+    if jax.default_backend() == "tpu":  # as paged_decode_attention: the CPU tests gather
+        from ray_tpu.ops import pallas_mla_paged_attention as kernel
+
+        if kernel.sparse_kernel_takes(B, H, W, v_width, block_size, block_tables.shape[1], pages.dtype):
+            return kernel.mla_sparse_paged_decode_attention_kernel(
+                q, row_self, cached, own_kept, pages, layer, block_tables, lengths, block_size=block_size,
+                v_width=v_width)
+    idx = (block_tables[:, :, None] * block_size + jnp.arange(block_size)).reshape(B, C)
+    L, P, _ = pages.shape
+    ctx = pages.reshape(L * P, W)[layer * P + idx]  # [B, C, W]
+    s_ctx = jnp.einsum("bhw,bcw->bhc", q, ctx).astype(jnp.float32)
+    s_ctx = jnp.where(cached[:, None, :], s_ctx, jnp.float32(-1e30))
+    s_self = (q * row_self[:, None]).sum(-1).astype(jnp.float32)[..., None]
+    s_self = jnp.where(own_kept[:, None, None], s_self, jnp.float32(-1e30))
+    probs = jax.nn.softmax(jnp.concatenate([s_ctx, s_self], axis=-1), axis=-1).astype(q.dtype)
+    att = jnp.einsum("bhc,bcv->bhv", probs[..., :-1], ctx[..., :v_width])
+    return att + probs[..., -1:] * row_self[:, None, :v_width]
 
 def gqa_decode_blocks(k_pages, lengths, block_size, calls=1):
     """What ``calls`` calls of ``gqa_paged_decode_attention`` over lanes

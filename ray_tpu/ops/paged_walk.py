@@ -1,8 +1,8 @@
 """The page walk under the paged-decode kernels (Pallas TPU).
 
 ``pallas_paged_attention.py``, ``pallas_gqa_paged_attention.py``,
-``pallas_mla_paged_attention.py`` and ``pallas_sparse_paged_attention.py``
-are each one program a layer over the serving engine's pool
+``pallas_mla_paged_attention.py``, ``pallas_sparse_paged_attention.py``
+and the index scores of ``pallas_dsa.py`` are each one program a layer over the serving engine's pool
 (``serve/llm/kv_cache.py``): ``[n_layer, num_blocks * block_size,
 width]`` in HBM, whole, the layer an index into it (a slice of the pool
 as an operand would be a copy of the layer), lengths and page numbers
@@ -190,7 +190,7 @@ def walk(total, item, *, block_size, layer, pages_of, streams, state):
     buffer ``[2, positions, columns]`` in VMEM and its DMA semaphore (a
     function of the buffer's number).  ``state``: the refs of the
     running max, the running sum and the unnormalised output, reset at
-    an owner's first block.  ``item(j)`` reads what the kernel needs of
+    an owner's first block (None: the kernel keeps no softmax).  ``item(j)`` reads what the kernel needs of
     item ``j`` and returns its block's number within its owner, what
     else to do at a first block, and ``fold(slot)``: fold the block,
     whose rows are in buffer ``slot`` by then, into the state.
@@ -199,7 +199,6 @@ def walk(total, item, *, block_size, layer, pages_of, streams, state):
     (every block of an owner but its last) is copied by ``whole_block``,
     any other by ``page_loop``: what the item's count says, nothing
     else, decides.  No page past an owner's count is ever copied."""
-    m_ref, l_ref, acc_ref = state
     n = streams[0][1].shape[1] // block_size     # pages a compute block
     how = dict(n=n, block_size=block_size, layer=layer, streams=streams)
 
@@ -229,9 +228,11 @@ def walk(total, item, *, block_size, layer, pages_of, streams, state):
 
         @pl.when(blk == 0)
         def _():
-            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-            l_ref[...] = jnp.zeros_like(l_ref)
-            acc_ref[...] = jnp.zeros_like(acc_ref)
+            if state is not None:
+                m_ref, l_ref, acc_ref = state
+                m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+                l_ref[...] = jnp.zeros_like(l_ref)
+                acc_ref[...] = jnp.zeros_like(acc_ref)
             first()
 
         copies(j, slot, False)

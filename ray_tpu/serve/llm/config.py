@@ -26,6 +26,7 @@ MODEL_FAMILIES = (
     ("ray_tpu.models.mellum", "MellumConfig", ("mellum2_tiny", "mellum2_12b_a2_5b", "mellum2_12b_a2_5b_12l")),
     ("ray_tpu.models.jamba", "JambaConfig", ("jamba2_tiny", "jamba2_3b")),
     ("ray_tpu.models.zaya", "ZayaConfig", ("zaya1_tiny", "zaya1_8b", "zaya1_8b_20l")),
+    ("ray_tpu.models.glm_moe_dsa", "GlmMoeDsaConfig", ("glm5_tiny", "glm5", "glm5_6l_ep16")),
 )
 # every preset ``LLMConfig.model`` may name, family by family
 PRESETS = " | ".join(name for *_, presets in MODEL_FAMILIES for name in presets)
@@ -95,7 +96,10 @@ class LLMConfig:
     ``zaya1*`` presets page K and V in EVERY layer (256 values each a
     position) and hold a tail a layer beside them (the convolutions' and
     the value shift's last position: 107,520 B a lane at the 20 layers
-    of ``zaya1_8b_20l``).
+    of ``zaya1_8b_20l``); the ``glm5*`` presets page a latent row and,
+    in a second pool under the same block table, an index key a position
+    in every layer (9,216 B a position at the 6 layers of
+    ``glm5_6l_ep16``) and hold nothing a lane.
 
     ``model`` names a preset of a model family, one of (from
     ``MODEL_FAMILIES``): {presets}.

@@ -814,3 +814,49 @@ def test_zaya_programs_compile_for_v5e(one_chip, monkeypatch, program):
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
     if program == "serve_decode":
         assert f"s32[{B + len(zaya.COUNTERS)}]" in text
+
+
+def test_glm_moe_dsa_decode_step_compiles_for_v5e(one_chip, monkeypatch):
+    """GLM-5's decode step at the published widths (d 6144, 64 heads over
+    one latent row of 576 stored as 640, 32 index heads of 128 over an
+    index key a position, 16 held experts of width 2,048 of 256 routed),
+    20 lanes over 36,864 positions in pages of 64, depth cut to the dense
+    layer and one expert layer: a layer scores the lanes' index keys
+    through the index kernel and attends under the choice through the
+    latent kernel's walk (one call each a layer; the expert layer two
+    grouped matmuls), builds no ``[lanes, context, ...]`` gather of
+    either pool, and both pools go out in the buffers they came in."""
+    from ray_tpu.models import glm_moe_dsa
+    from ray_tpu.serve.llm.engine import decode_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = glm_moe_dsa.GlmMoeDsaConfig.glm5_6l_ep16(n_layer=2)
+    B, C, block, slots = 20, 36864, 64, 458752 + 64
+    spec = glm_moe_dsa.cache_spec(cfg, block)
+    assert spec.names == ("k_pages", "index_k") and spec.row_width == 640 and not spec.v_pool
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    def shaped(tree):
+        return jax.tree_util.tree_map(lambda x: arr(x.shape, x.dtype), tree)
+
+    params = shaped(jax.eval_shape(lambda: glm_moe_dsa.init_params(cfg)))
+    key = shaped(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    cache = [arr((2, slots, 640), cfg.dtype), arr((2, slots, 128), cfg.dtype)]
+    compiled = jax.jit(lambda *a: decode_step(cfg, 0, block, spec, *a), donate_argnums=(1, 2)).lower(
+        params, *cache, arr((B,), jnp.int32), arr((B,), jnp.int32), arr((B, C // block), jnp.int32),
+        arr((B,), jnp.int32), arr((B,), jnp.float32), key).compile()
+    text = compiled.as_text()
+    calls = [c.split(".")[0] for c in _kernel_calls(text)]
+    assert calls.count("dsa_index_paged_scores") == 2 and calls.count("mla_sparse_paged_decode_attention") == 2
+    assert calls.count("moe_gmm") == 2 and len(calls) == 6
+    # the gather path's contexts: 20 lanes x 36,864 rows of either pool
+    assert f"bf16[{B},{C},640]" not in text and f"bf16[{B},{C},128]" not in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * slots * (640 + 128) * 2
+    # the lanes' scores, their sortable keys and both layers' masks over 36,864 positions (2.9 MB a
+    # float32 [20, 36864], the choice's passes hold several), the logits, the experts' rows: 103 MB
+    # read, where one lane's latent rows gathered for 20 lanes would be 944 MB a layer
+    assert mem.temp_size_in_bytes < 128e6
+    assert f"s32[{B + len(glm_moe_dsa.COUNTERS)}]" in text

@@ -24,6 +24,7 @@ pytest.register_assert_rewrite(
     "benchmark.tests.test_mistral_small_4_cell", "benchmark.tests.test_nemotron_3_nano_cell",
     "benchmark.tests.test_granite_4_0_h_small_cell", "benchmark.tests.test_mellum2_cell",
     "benchmark.tests.test_jamba2_cell", "benchmark.tests.test_zaya1_cell",
+    "benchmark.tests.test_glm_5_cell",
 )
 
 from benchmark.tests.test_benchmark import (  # noqa: E402,F401
@@ -103,4 +104,13 @@ from benchmark.tests.test_zaya1_cell import (  # noqa: E402,F401
     test_the_cell_s_metrics_are_the_entries_of_benchmark_json as test_the_zaya_cell_s_metrics_are_the_entries_of_benchmark_json,
     test_the_configuration_is_the_catalog_s_but_for_what_it_says_is_reduced as test_the_zaya_configuration_is_the_catalog_s_but_for_what_it_says_is_reduced,
     test_the_cut_s_arithmetic_reckoned_again as test_the_zaya_cut_s_arithmetic_reckoned_again,
+)
+from benchmark.tests.test_glm_5_cell import (  # noqa: E402,F401
+    test_the_two_decode_kernels_work_and_roofline_shares_by_hand,
+)
+from benchmark.tests.test_glm_5_cell import (  # noqa: E402,F401
+    test_runner_fails_at_once_where_the_program_has_no_such_family as test_glm_runner_fails_at_once_where_the_program_has_no_such_family,
+    test_the_cell_s_metrics_are_the_entries_of_benchmark_json as test_the_glm_cell_s_metrics_are_the_entries_of_benchmark_json,
+    test_the_configuration_is_the_catalog_s_but_for_what_it_says_is_reduced as test_the_glm_configuration_is_the_catalog_s_but_for_what_it_says_is_reduced,
+    test_the_cut_s_arithmetic_reckoned_again as test_the_glm_cut_s_arithmetic_reckoned_again,
 )
