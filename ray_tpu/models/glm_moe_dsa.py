@@ -54,7 +54,9 @@ read them:
   unchosen positions masked).
 - *decode* (``decode_forward_cached``): the index scores of a lane's
   cached positions by ``ops.attention.dsa_index_paged_scores`` over the
-  pages where they lie, the choice by ``ops.dsa.keep_mask``, and the
+  pages where they lie, the choice by ``ops.dsa.keep_mask`` over the
+  table's whole width (twenty lanes of different lengths have no reach in
+  common worth a loop: a pass over them is 40 us), and the
   ABSORBED attention (``ops.mla.absorbed_queries``) over the chosen rows
   by ``ops.attention.mla_sparse_paged_decode_attention``.
 
@@ -103,12 +105,17 @@ from ray_tpu.ops.mla import absorbed_queries, rope_interleaved
 # the index's own, prefill and decode apart: a query's candidates (the
 # positions up to its own) and the positions it attended, summed over
 # real queries and layers; and the cached positions a decode step's
-# index kernel scored.
+# index kernel scored.  Last the scores the choice's passes read: of a
+# chunk a tile's queries times the columns of the key blocks it can reach
+# (0 of a tile that reaches at most ``index_topk`` positions and counts
+# nothing), of a decode step every column of the table for each lane that
+# runs; over layers.
 COUNTERS = ("moe_pairs_routed", "moe_pairs_held", "moe_pairs", "moe_experts_hit",
             "moe_expert_slots", "moe_peak_rows", "moe_layer_programs",
             "kv_positions_attended", "kv_positions_gathered",
             "dsa_positions_cached_prefill", "dsa_positions_kept_prefill",
-            "dsa_positions_cached", "dsa_positions_kept", "dsa_index_positions_scored")
+            "dsa_positions_cached", "dsa_positions_kept", "dsa_index_positions_scored",
+            "dsa_select_columns_prefill", "dsa_select_columns")
 
 _LANE = 128  # columns of a lane tile: a cached row is whole tiles
 
@@ -409,7 +416,7 @@ def prefill_chosen(params, cfg: GlmMoeDsaConfig, cache, tokens, start, last_inde
     pool, keys = cache["k_pages"], cache["index_k"]
     L, P, W = pool.shape
     Di = keys.shape[-1]
-    rows_out, keys_out, counts, chose, masks, kept = [], [], [], [], [], []
+    rows_out, keys_out, counts, chose, masks, kept, columns = [], [], [], [], [], [], []
     for i, lp in enumerate(params["layers"]):
         h = rmsnorm(x, lp["w_in"], cfg.rms_norm_eps)
         q_nope, q_rope, row, q_i, w, k_i = _project(h, lp, cfg, pos)
@@ -418,7 +425,7 @@ def prefill_chosen(params, cfg: GlmMoeDsaConfig, cache, tokens, start, last_inde
         ctx = jax.lax.dynamic_update_slice_in_dim(ctx, row, start, axis=0)
         k_ctx = jnp.concatenate([keys.reshape(L * P, Di)[i * P + where], jnp.zeros((room, Di), keys.dtype)])
         k_ctx = jax.lax.dynamic_update_slice_in_dim(k_ctx, k_i, start, axis=0)
-        att, n_kept, mask = dsa.sparse_chunk_attention(
+        att, n_kept, n_columns, mask = dsa.sparse_chunk_attention(
             q_nope, q_rope, q_i, w, ctx, k_ctx, lp["wukv"], start, n_valid, cfg, cfg.index_topk)
         x = x + att @ lp["wo"]
         y, c, top_e = _feed_forward(x, lp, cfg, _is_dense(cfg, i))
@@ -431,13 +438,15 @@ def prefill_chosen(params, cfg: GlmMoeDsaConfig, cache, tokens, start, last_inde
         chose.append(top_e)
         masks.append(mask)
         kept.append(n_kept)
+        columns.append(n_columns)
         if c is not None:
             counts.append(c)
     candidates = jnp.where(jnp.arange(T) < n_valid, pos + 1, 0).sum() * cfg.n_layer
     return (_logits(x[last_index], params, cfg), jnp.stack(rows_out)[:, None], None,
             {"index_k": (jnp.stack(keys_out), slots)}, {},
             _counters(cfg, counts, dsa_positions_cached_prefill=candidates,
-                      dsa_positions_kept_prefill=jnp.stack(kept).sum()),
+                      dsa_positions_kept_prefill=jnp.stack(kept).sum(),
+                      dsa_select_columns_prefill=jnp.stack(columns).sum()),
             jnp.stack(chose), jnp.stack(masks))
 
 
@@ -501,5 +510,6 @@ def decode_chosen(params, cfg: GlmMoeDsaConfig, cache, tok, block_tables, length
             _counters(cfg, counts, kv_positions_attended=n_cached, kv_positions_gathered=pages.sum() * cfg.n_layer,
                       dsa_positions_cached=jnp.where(running, lengths + 1, 0).sum() * cfg.n_layer,
                       dsa_positions_kept=n_kept - (~running).sum() * cfg.n_layer,
-                      dsa_index_positions_scored=lengths.sum() * cfg.n_layer),
+                      dsa_index_positions_scored=lengths.sum() * cfg.n_layer,
+                      dsa_select_columns=running.sum() * C * cfg.n_layer),
             jnp.stack(chose), masks)

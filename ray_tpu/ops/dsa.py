@@ -13,7 +13,10 @@ rows alone.
   counting, four bits of the scores' bit pattern a pass (eight passes of
   fifteen comparisons an element; a sort a query is what PR 46 found
   fragile at 2k blocks).  ``keep_mask`` gives the choice as a mask a
-  query, to a chunk and to a decode step alike.
+  query, to a chunk and to a decode step alike; told how many key
+  ``blocks`` hold candidates (a chunk's tile knows its reach, a decode
+  step's lanes differ), it counts over those alone, a block at a time,
+  and not at all where they hold at most ``k`` positions.
 - ``sparse_chunk_attention``: a prompt chunk over the sequence's latent
   rows under that choice: ``ops.mla.expanded_attention`` with the mask
   (every key block up to the diagonal is visited, an unchosen position
@@ -79,12 +82,85 @@ def kth_largest(keys, k):
     return prefix
 
 
-def keep_mask(scores, valid, k):
+def _running_count(ties):
+    """ties [N, KEY_BLOCK] bool -> [N, KEY_BLOCK] int32, the ties up to
+    and with each column: a product with a triangle of ones on the matrix
+    unit, which is idle under the passes (0 and 1 are exact in bfloat16,
+    their sums in float32).  Alone it costs a tile what ``jnp.cumsum``
+    over a block's columns costs; inside the chunk program it took 11 ms
+    out of 685 (PERF.md section 5, PR 58)."""
+    at = jnp.arange(ties.shape[-1])
+    upto = (at[:, None] <= at[None, :]).astype(jnp.bfloat16)
+    return jnp.dot(ties.astype(jnp.bfloat16), upto, preferred_element_type=jnp.float32).astype(jnp.int32)
+
+
+def _keep_within(scores, valid, k, blocks, running=_running_count):
+    """``keep_mask`` over the first ``blocks`` (traced, at least one)
+    blocks of ``KEY_BLOCK`` columns, a block at a time, in two loops a
+    tile (a program holds 48 tiles, and every loop is code to trace, to
+    compile and to load).  The first is ``kth_largest``'s eight passes
+    block by block: a block's counts added to the tile's, and at a pass's
+    last block the digit taken; it also keeps the count of the keys ABOVE
+    the prefix so far (those at or above the next candidate over the
+    digit taken, or where the digit is 15 the pass before's), which after
+    the last pass is the count above the k-th largest.  The second writes
+    the mask, the ties counted on from block to block (``running``: a
+    block's ties up to each column).  False beyond the blocks."""
+    N, C = scores.shape
+    digits = jnp.arange(1, 16, dtype=jnp.uint32)
+
+    def part(j):
+        ok = jax.lax.dynamic_slice_in_dim(valid, j * KEY_BLOCK, KEY_BLOCK, axis=1)
+        return _sortable(jax.lax.dynamic_slice_in_dim(scores, j * KEY_BLOCK, KEY_BLOCK, axis=1), ok), ok
+
+    def count(i, carry):
+        prefix, above, counts = carry
+        shift = (28 - 4 * (i // blocks)).astype(jnp.uint32)
+        cand = prefix[:, None] | (digits << shift)[None, :]
+        counts = counts + (part(i % blocks)[0][:, None, :] >= cand[:, :, None]).sum(-1, dtype=jnp.int32)
+        digit = (counts >= k).sum(-1)
+        over = jnp.take_along_axis(counts, jnp.minimum(digit, 14)[:, None], axis=1)[:, 0]
+        last = i % blocks == blocks - 1
+        return (jnp.where(last, prefix | (digit.astype(jnp.uint32) << shift), prefix),
+                jnp.where(last & (digit < 15), over, above), jnp.where(last, 0, counts))
+
+    zeros = jnp.zeros(N, jnp.int32)
+    kth, above, _ = jax.lax.fori_loop(0, 8 * blocks, count,
+                                      (jnp.zeros(N, jnp.uint32), zeros, jnp.zeros((N, 15), jnp.int32)))
+    kth, room = kth[:, None], (k - above)[:, None]
+
+    def choose(j, carry):
+        mask, before = carry
+        keys, ok = part(j)
+        ties = (keys == kth) & ok
+        upto = before[:, None] + running(ties)
+        keep = ((keys > kth) | (ties & (upto <= room))) & ok
+        return jax.lax.dynamic_update_slice_in_dim(mask, keep, j * KEY_BLOCK, axis=1), upto[:, -1]
+
+    return jax.lax.fori_loop(0, blocks, choose, (jnp.zeros((N, C), bool), zeros))[0]
+
+
+def counts_over(blocks, k):
+    """Whether the choice over ``blocks`` key blocks counts at all: where
+    they hold at most ``k`` positions every candidate is kept."""
+    return blocks * KEY_BLOCK > k
+
+
+def keep_mask(scores, valid, k, blocks=None):
     """The choice as a mask: scores [N, C] float32, valid [N, C] bool a
     query's candidates, k static -> [N, C] bool, the k candidates of
     largest score (every candidate where there are at most k), a tie at
-    the k-th going to the lower position."""
+    the k-th going to the lower position.  ``blocks`` (traced or not; C
+    whole key blocks then): the candidates lie in the first ``blocks``
+    blocks of ``KEY_BLOCK`` columns, no other column is read and the
+    mask is False beyond them: the same mask as without it, by work in
+    proportion to ``blocks``."""
     with jax.named_scope("dsa.select"):
+        if blocks is not None:
+            blocks = jnp.asarray(blocks, jnp.int32)
+            within = valid & (jnp.arange(scores.shape[1]) < blocks * KEY_BLOCK)[None, :]
+            return jax.lax.cond(counts_over(blocks, k), lambda: _keep_within(scores, valid, k, blocks),
+                                lambda: within)
         keys = _sortable(scores, valid)
         kth = kth_largest(keys, k)[:, None]
         above = keys > kth
@@ -100,12 +176,14 @@ def sparse_chunk_attention(q_nope, q_rope, q_i, w, ctx, k_ctx, wukv, start, n_va
     rows and k_ctx [C, Di] its index keys (position p in row p, the
     chunk's own put in; C whole key blocks of both kinds).  -> ([T, H *
     v_head_dim], kept: the positions the real queries attended, int32;
+    columns: the scores the choice's passes read, a tile's queries times
+    the columns of its reach, 0 of a tile that counted nothing, int32;
     the choice as a mask [T, C], for the checks)."""
     T, C = q_nope.shape[0], ctx.shape[0]
     tile = min(T, Q_TILE)
     assert T % tile == 0 and C % KEY_BLOCK == 0 and C % mla.K_BLOCK == 0
     pos = jnp.arange(C)
-    masks = {}
+    masks, columns = {}, {}
 
     def keep_of(first, n):
         # a query block of the online softmax is a tile of the index
@@ -115,9 +193,10 @@ def sparse_chunk_attention(q_nope, q_rope, q_i, w, ctx, k_ctx, wukv, start, n_va
         with jax.named_scope("dsa.index"):
             scores = chunk_index_scores(q_i[first:first + n], w[first:first + n], k_ctx, blocks)
         valid = (pos[None, :] <= q_pos[:, None]) & ((first + jnp.arange(n)) < n_valid)[:, None]
-        masks[first] = keep_mask(scores, valid, k)
+        masks[first] = keep_mask(scores, valid, k, blocks)
+        columns[first] = jnp.where(counts_over(blocks, k), n * blocks * KEY_BLOCK, 0)
         return masks[first]
 
     out = mla.expanded_attention(q_nope, q_rope, ctx, wukv, start, n_valid, cfg, keep_of=keep_of, q_block=tile)
     mask = jnp.concatenate([masks[f] for f in sorted(masks)])
-    return out, mask.sum(dtype=jnp.int32), mask
+    return out, mask.sum(dtype=jnp.int32), sum(columns.values()), mask

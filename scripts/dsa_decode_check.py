@@ -1,8 +1,9 @@
 """GLM-5's two decode kernels alone on the chip, and the choice between
 them: device ms a call, share of the roof, distance from the
-``jax.numpy`` paths.
+``jax.numpy`` paths; and (``--mode chunk``) the index and the choice of
+one tile of a prompt chunk's queries, in the forms the choice could take.
 
-    python scripts/dsa_decode_check.py [--contexts 4096,16384,36000] [--iters 24] [--seed 0]
+    python scripts/dsa_decode_check.py [--mode decode|chunk] [--contexts 4096,16384,36000] [--iters 24] [--seed 0]
 
 At the shape of ``glm-5.serve.longrepo-backlog`` (the sizes from
 ``benchmark/configs/glm-5.json``; lanes, pages and pool from
@@ -23,9 +24,26 @@ each of ``--contexts``: every lane within 64 positions under it.
   at most the share of positions chosen); ``around_ms`` is the rest of
   the dispatch's device time a call (the mask's cast and reshape).
 
+``--mode chunk``: one tile of ``ops.dsa.Q_TILE`` queries at the cell's
+widths over the ``C`` a chunk program has (``max_model_len`` and room for
+a chunk: 40,960 columns) whose last query stands at each of ``--reaches``
+(2,048 / 4,096 / 16,384 / 36,864): device ms a call of the index scores
+(``chunk_index_scores``: the ``[512, 32, 2048]`` float32 tiles), of the
+choice over all ``C`` columns (``keep_mask`` without a reach), and of
+each form that takes the reach: ``loop``, every pass a loop over the key
+blocks (``keep_mask(..., blocks)``: the ties' running count a product
+with a triangle of ones), ``loop.cumsum`` (the same with ``jnp.cumsum``
+a block), and ``switch``, a ``lax.switch`` over static widths of the
+full-width code; every form's mask held equal to the full-width one.
+(Loops over slabs of 4,096 and 8,192 columns read what 2,048 read and
+more: PERF.md section 5, PR 58.)  Then a decode step's choice
+``[lanes, max_model_len]`` at ``--contexts``, with and without the
+longest lane's reach.
+
 Prints a table, then one JSON object, and writes it to
-``chiprun_out/dsa_decode_check.json``.  Needs the TPU: in interpret mode
-a time says nothing.  No benchmark cell and no test runs this.
+``chiprun_out/dsa_decode_check.json`` (``dsa_chunk_check.json``).  Needs
+the TPU: in interpret mode a time says nothing.  No benchmark cell and no
+test runs this.
 """
 
 from __future__ import annotations
@@ -53,13 +71,13 @@ def cell_shape() -> dict:
             "pool_tokens": eng["pool_tokens"], "max_model_len": eng["max_model_len"],
             "layers": cfg["num_hidden_layers"], "heads": cfg["num_attention_heads"],
             "v_width": cfg["kv_lora_rank"], "row": cfg["kv_lora_rank"] + cfg["qk_rope_head_dim"], "width": 640,
-            "index_heads": cfg["index_n_heads"], "index_dim": cfg["index_head_dim"], "topk": cfg["index_topk"]}
+            "index_heads": cfg["index_n_heads"], "index_dim": cfg["index_head_dim"], "topk": cfg["index_topk"],
+            "prefill_chunk": eng["prefill_chunk"]}
 
 
-def traced(run, iters, pattern):
-    """(device ms a call of the operations whose name starts with
-    `pattern`, device ms a call of everything) from a trace of `iters`
-    calls of run(i)."""
+def traced_ops(run, iters):
+    """[[name, start_ns, dur_ns]] of device 0's operations in a trace of
+    `iters` calls of run(i)."""
     import jax
 
     from benchmark import trace_reduce
@@ -70,11 +88,108 @@ def traced(run, iters, pattern):
             jax.block_until_ready([run(i) for i in range(iters)])
         planes = trace_reduce.load(trace_reduce.find_xplane(logdir))
     ops = trace_reduce.device_ops(planes)
-    ops = ops[min(ops)]
+    return ops[min(ops)]
+
+
+def traced(run, iters, pattern):
+    """(device ms a call of the operations whose name starts with
+    `pattern`, device ms a call of everything) from a trace of `iters`
+    calls of run(i)."""
+    from benchmark import trace_reduce
+
+    ops = traced_ops(run, iters)
     named = [dur for name, _, dur in ops if trace_reduce.family(name).startswith(pattern)]
     if len(named) != iters:
         raise RuntimeError(f"{len(named)} {pattern} events in a trace of {iters} calls")
     return sum(named) / 1e6 / iters, sum(dur for *_, dur in ops) / 1e6 / iters
+
+
+def device_ms(run, iters):
+    """Device ms a call: the union of every device operation's interval
+    in a trace of `iters` calls of run(i), over `iters`."""
+    from benchmark import trace_reduce
+
+    ops = traced_ops(run, iters)
+    return trace_reduce.union_seconds([(start, start + dur) for _, start, dur in ops]) / 1e6 / iters
+
+
+SWITCH_WIDTHS = (4096, 8192, 16384, 24576)  # and C: the static widths of the ``switch`` form
+
+
+def switched_keep_mask(scores, valid, k, blocks):
+    """The ``switch`` form: the full-width code on the narrowest of a
+    handful of static widths that holds ``blocks`` key blocks (``valid``
+    is False beyond them); ``valid`` itself where they hold at most k."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import dsa
+
+    C = scores.shape[1]
+    widths = [w for w in SWITCH_WIDTHS if w < C] + [C]
+
+    def narrow(w):
+        return lambda: jnp.pad(dsa.keep_mask(scores[:, :w], valid[:, :w], k), ((0, 0), (0, C - w)))
+
+    reach = blocks * dsa.KEY_BLOCK
+    at = jnp.where(dsa.counts_over(blocks, k), 1 + jnp.searchsorted(jnp.asarray(widths), reach), 0)
+    return jax.lax.switch(at, [lambda: valid] + [narrow(w) for w in widths])
+
+
+def chunk_mode(shape, reaches, contexts, iters, seed, timer=device_ms) -> dict:
+    """The rows of ``--mode chunk`` (the module's docstring)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops import dsa
+
+    Hi, Di, k, lanes = shape["index_heads"], shape["index_dim"], shape["topk"], shape["lanes"]
+    n, block = dsa.Q_TILE, dsa.KEY_BLOCK
+    C = -(-(shape["max_model_len"] + shape["prefill_chunk"]) // block) * block
+    keys = jax.random.split(jax.random.PRNGKey(seed % 2**31), 4)
+    q_i = jax.random.normal(keys[0], (n, Hi, Di), jnp.bfloat16)
+    w = jax.random.normal(keys[1], (n, Hi), jnp.float32) * (Hi * Di) ** -0.5
+    k_ctx = jax.random.normal(keys[2], (C, Di), jnp.bfloat16)
+    pos = jnp.arange(C)
+    index = jax.jit(lambda b: dsa.chunk_index_scores(q_i, w, k_ctx, b))
+
+    def plain_running(ties):
+        return jnp.cumsum(ties, axis=-1, dtype=jnp.int32)
+
+    forms = {"full": lambda s, v, b: dsa.keep_mask(s, v, k),
+             "loop": lambda s, v, b: dsa.keep_mask(s, v, k, b),
+             "loop.cumsum": lambda s, v, b: jax.lax.cond(
+                 dsa.counts_over(b, k), lambda: dsa._keep_within(s, v, k, b, running=plain_running), lambda: v),
+             "switch": lambda s, v, b: switched_keep_mask(s, v, k, b)}
+    forms = {name: jax.jit(fn) for name, fn in forms.items()}
+    rows = []
+    for reach in reaches:
+        blocks = jnp.int32(-(-reach // block))
+        valid = pos[None, :] <= (reach - n + jnp.arange(n))[:, None]
+        scores = index(blocks)
+        row = {"reach": reach, "index_ms": timer(lambda i: index(blocks), iters)}
+        want = np.asarray(forms["full"](scores, valid, blocks))
+        for name, fn in forms.items():
+            row[f"{name}_ms"] = timer(lambda i, fn=fn: fn(scores, valid, blocks), iters)
+            row[f"{name}_equal"] = bool((np.asarray(fn(scores, valid, blocks)) == want).all())
+        rows.append(row)
+    # a decode step's choice: lanes within 64 positions under a context, the table's whole width
+    width = shape["max_model_len"]
+    rng = np.random.default_rng(seed)
+    steps = []
+    lane_scores = jax.random.normal(keys[3], (lanes, width), jnp.float32)
+    full, bound = forms["full"], forms["loop"]
+    for context in contexts:
+        lengths = jnp.asarray(context - rng.integers(0, 64, lanes), jnp.int32)
+        valid = jnp.arange(width)[None, :] <= lengths[:, None]
+        blocks = -(-(lengths.max() + 1) // block)
+        steps.append({"context": context, "blocks": int(blocks),
+                      "full_ms": timer(lambda i: full(lane_scores, valid, blocks), iters),
+                      "bound_ms": timer(lambda i: bound(lane_scores, valid, blocks), iters),
+                      "bound_equal": bool((np.asarray(bound(lane_scores, valid, blocks))
+                                           == np.asarray(full(lane_scores, valid, blocks))).all())})
+    return {"tile": n, "columns": C, "topk": k, "rows": rows, "decode": steps}
 
 
 def on_the_gather_path(fn, *args):
@@ -92,7 +207,9 @@ def on_the_gather_path(fn, *args):
 
 def main() -> int:
     ap = argparse.ArgumentParser()
+    ap.add_argument("--mode", choices=("decode", "chunk"), default="decode")
     ap.add_argument("--contexts", default="4096,16384,36000")
+    ap.add_argument("--reaches", default="2048,4096,16384,36864")
     ap.add_argument("--iters", type=int, default=24)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
@@ -112,6 +229,23 @@ def main() -> int:
     with open(os.path.join(ROOT, "benchmark", "peaks.json")) as fh:
         peak = json.load(fh)[dev.device_kind]
     shape = cell_shape()
+    if args.mode == "chunk":
+        result = {"device": {"platform": dev.platform, "kind": dev.device_kind}, "iters": args.iters,
+                  **chunk_mode(shape, [int(r) for r in args.reaches.split(",")],
+                               [int(c) for c in args.contexts.split(",")], args.iters, args.seed)}
+        os.makedirs("chiprun_out", exist_ok=True)
+        with open("chiprun_out/dsa_chunk_check.json", "w") as fh:
+            json.dump(result, fh, indent=1)
+        names = [key[:-3] for key in result["rows"][0] if key.endswith("_ms")]
+        print(f"{'reach':>8}" + "".join(f"{name + ' ms':>14}" for name in names) + "   equal")
+        for r in result["rows"]:
+            print(f"{r['reach']:8d}" + "".join(f"{r[name + '_ms']:14.4f}" for name in names)
+                  + f"   {all(v for key, v in r.items() if key.endswith('_equal'))}")
+        print(f"{'context':>8}{'blocks':>8}{'full ms':>12}{'bound ms':>12}   equal")
+        for r in result["decode"]:
+            print(f"{r['context']:8d}{r['blocks']:8d}{r['full_ms']:12.4f}{r['bound_ms']:12.4f}   {r['bound_equal']}")
+        print(json.dumps(result))
+        return 0
     lanes, bs, L, W = shape["lanes"], shape["block_size"], shape["layers"], shape["width"]
     Hi, Di, H, k = shape["index_heads"], shape["index_dim"], shape["heads"], shape["topk"]
     keys = jax.random.split(jax.random.PRNGKey(args.seed % 2**31), 8)

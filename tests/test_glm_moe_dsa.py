@@ -166,9 +166,9 @@ def test_chunked_prefill_is_one_program_prefill(engine):
 def test_a_broken_model_fails_the_tolerance(monkeypatch, broken):
     engine = _engine()  # its own: a program the engine traces under the patch stays in its cache
     if broken == "latest_positions":
-        def latest(scores, valid, k):
+        def latest(scores, valid, k, blocks=None):
             pos = jnp.arange(scores.shape[-1], dtype=jnp.float32)
-            return keep_mask(jnp.broadcast_to(pos, scores.shape), valid, k)
+            return keep_mask(jnp.broadcast_to(pos, scores.shape), valid, k, blocks)
 
         keep_mask = dsa.keep_mask
         monkeypatch.setattr(dsa, "keep_mask", latest)
@@ -220,6 +220,106 @@ def test_the_exact_choice_is_a_full_sort_s_ties_included(k):
     keep = np.asarray(dsa.keep_mask(jnp.asarray(scores), jnp.asarray(valid), k))
     assert (keep == want).all()
     assert (keep.sum(-1) == np.minimum(last + 1, k)).all()
+
+
+def _planted(rng, n, blocks):
+    """Scores [n, blocks * KEY_BLOCK] of a dozen values only (every k-th
+    largest is a tie), each row's ties running across every block
+    boundary, one row all zeros of both signs, and uneven candidates."""
+    C = blocks * dsa.KEY_BLOCK
+    scores = rng.integers(0, 12, size=(n, C)).astype(np.float32) - 4.0
+    scores[0, :] = 0.0
+    scores[0, ::2] = -0.0
+    for b in range(1, blocks):  # one value from 40 columns before a boundary to 40 after it
+        scores[1:, b * dsa.KEY_BLOCK - 40:b * dsa.KEY_BLOCK + 40] = rng.integers(0, 12, size=(n - 1, 1)) - 4.0
+    # a row whose values' bit patterns end in fifteens: a pass's digit is 15 and the count above it the pass before's
+    ends = np.array([0x3FFFFFFF, 0x3FFFFFEF, 0x3FFFFF0F, 0x3FFFF0FF, 0x3FF0FFFF], np.uint32).view(np.float32)
+    scores[5] = ends[rng.integers(0, len(ends), size=C)]
+    last = rng.integers(0, C, size=n)
+    last[:4] = [C - 1, dsa.KEY_BLOCK, dsa.KEY_BLOCK - 1, 0]
+    last[5] = C - 7
+    return scores, np.arange(C)[None, :] <= last[:, None]
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["blocks_static", "blocks_traced"])
+@pytest.mark.parametrize("k", [1, 64, 2048, 2049])
+@pytest.mark.parametrize("b", [0, 1, 2, 4])
+def test_the_choice_within_a_reach_is_the_whole_width_s_choice(b, k, traced):
+    """``keep_mask(..., blocks=b)`` against ``keep_mask`` of the same
+    scores with everything past ``b`` blocks invalid: the same mask, by
+    counting over ``b`` blocks or (``b * KEY_BLOCK <= k``: k 2,048 at one
+    block, and just past the edge at 2,049) by counting nothing."""
+    scores, valid = _planted(np.random.default_rng(100 * b + k), 8, 4)
+    reach = np.arange(scores.shape[1])[None, :] < b * dsa.KEY_BLOCK
+    want = np.asarray(dsa.keep_mask(jnp.asarray(scores), jnp.asarray(valid & reach), k))
+    if traced:
+        got = jax.jit(lambda s, v, n: dsa.keep_mask(s, v, k, n))(scores, valid, jnp.int32(b))
+    else:
+        got = dsa.keep_mask(jnp.asarray(scores), jnp.asarray(valid), k, b)
+    got = np.asarray(got)
+    assert (got == want).all()
+    assert (got.sum(-1) == np.minimum((valid & reach).sum(-1), k)).all()
+    # it counted wherever a row could hold more than k candidates, and there alone
+    assert bool(dsa.counts_over(b, k)) == (b * dsa.KEY_BLOCK > k)
+    # the ties' running count by jnp.cumsum (a candidate form of scripts/dsa_decode_check.py) is the product's
+    if b and dsa.counts_over(b, k):
+        plain = dsa._keep_within(jnp.asarray(scores), jnp.asarray(valid & reach), k, jnp.int32(b),
+                                 running=lambda ties: jnp.cumsum(ties, axis=-1, dtype=jnp.int32))
+        assert (np.asarray(plain) == want).all()
+
+
+def _parent_chunk_attention(q_nope, q_rope, q_i, w, ctx, k_ctx, wukv, start, n_valid, cfg, k):
+    """``dsa.sparse_chunk_attention`` as it stood before a tile's choice
+    took its reach: every tile's scores chosen from over all C columns."""
+    T, C = q_nope.shape[0], ctx.shape[0]
+    tile = min(T, dsa.Q_TILE)
+    pos = jnp.arange(C)
+    masks = {}
+
+    def keep_of(first, n):
+        q_pos = start + first + jnp.arange(n)
+        seen = jnp.minimum(start + first + n, start + n_valid)
+        blocks = jnp.where(first < n_valid, -(-seen // dsa.KEY_BLOCK), 0)
+        scores = dsa.chunk_index_scores(q_i[first:first + n], w[first:first + n], k_ctx, blocks)
+        valid = (pos[None, :] <= q_pos[:, None]) & ((first + jnp.arange(n)) < n_valid)[:, None]
+        masks[first] = dsa.keep_mask(scores, valid, k)
+        return masks[first]
+
+    out = mla.expanded_attention(q_nope, q_rope, ctx, wukv, start, n_valid, cfg, keep_of=keep_of, q_block=tile)
+    return out, jnp.concatenate([masks[f] for f in sorted(masks)])
+
+
+@pytest.mark.parametrize("start, n_valid, k", [
+    (1500, 1000, 64),    # mid-prompt, across the first key block's end, pads after 1,000 queries: tiles reach 1, 2, 0 blocks
+    (0, 1024, 2048),     # a prompt's first tiles: at most k candidates, nothing is counted
+    (2048, 700, 2048),   # the first tile past the edge: 2 blocks, counted; a whole tile of pads
+    (3000, 1536, 16),    # every tile real, reaches 2, 2 and 3 blocks
+])
+def test_a_chunk_s_choice_and_output_are_the_parent_form_s(start, n_valid, k):
+    """A chunk of three tiles over 3 key blocks and room, under jit with
+    ``start`` and ``n_valid`` traced as the engine's are."""
+    rng = np.random.default_rng(start)
+    T, C, H, Hi, Di = 3 * dsa.Q_TILE, 4 * dsa.KEY_BLOCK, CFG.n_head, CFG.index_n_heads, CFG.index_head_dim
+
+    def normal(*shape, scale=1.0):
+        return jnp.asarray(scale * rng.normal(size=shape), jnp.float32)
+
+    # index keys of a few values only: scores tie, across key blocks too
+    k_ctx = jnp.asarray(rng.integers(-1, 2, size=(C, Di)), jnp.float32)
+    q_i = jnp.asarray(rng.integers(-1, 2, size=(T, Hi, Di)), jnp.float32)
+    args = (normal(T, H, CFG.qk_nope_head_dim, scale=0.3), normal(T, H, CFG.qk_rope_head_dim, scale=0.3), q_i,
+            jnp.ones((T, Hi), jnp.float32), normal(C, CFG.latent_row),
+            k_ctx, normal(CFG.kv_lora_rank, H * (CFG.qk_nope_head_dim + CFG.v_head_dim), scale=0.2))
+    got = jax.jit(lambda a, s, n: dsa.sparse_chunk_attention(*a, s, n, CFG, k))(args, jnp.int32(start), jnp.int32(n_valid))
+    want = jax.jit(lambda a, s, n: _parent_chunk_attention(*a, s, n, CFG, k))(args, jnp.int32(start), jnp.int32(n_valid))
+    out, kept, columns, mask = (np.asarray(x) for x in got)
+    assert (mask == np.asarray(want[1])).all() and kept == mask.sum()
+    assert (mask.sum(-1) == np.where(np.arange(T) < n_valid, np.minimum(start + np.arange(T) + 1, k), 0)).all()
+    assert (out[:n_valid] == np.asarray(want[0])[:n_valid]).all()
+    # the columns its passes read, by hand: a real tile's 512 queries times its reach, where that is over k
+    reach = [-(-min(start + f + dsa.Q_TILE, start + n_valid) // dsa.KEY_BLOCK) * dsa.KEY_BLOCK if f < n_valid else 0
+             for f in range(0, T, dsa.Q_TILE)]
+    assert columns == sum(dsa.Q_TILE * r for r in reach if r > k)
 
 
 def test_absorbed_sparse_decode_is_expanded_chunk_attention_on_the_same_choice(engine):
@@ -460,6 +560,19 @@ def test_engine_serves_the_reference_s_tokens_and_counts_by_hand():
     assert stats["dsa_positions_cached"] == 2 * L * sum(t + 1 for t in range(150, 157))
     assert stats["dsa_positions_kept"] == 2 * L * 7 * K
     assert stats["dsa_index_positions_scored"] == 2 * L * sum(range(150, 157))
+    # the choice's passes: a chunk's one tile (64, 64, 32 queries at 0, 64, 128) reaches one key block of
+    # 2,048 columns, over index_topk (16), so it counts; a decode step counts over the table's 25 pages of 8
+    assert stats["dsa_select_columns_prefill"] == 2 * L * (64 + 64 + 32) * dsa.KEY_BLOCK
+    assert stats["dsa_select_columns"] == 2 * L * 7 * eng.bm.blocks_needed(eng.max_ctx) * BS
+    # the benchmark's entry over them: columns read a candidate
+    from benchmark import readers, spec
+
+    how = spec.load_layer_metric("dsa_select_columns_per_candidate.glm5")
+    zero = dict.fromkeys(stats, 0)
+    read = readers.stats_delta(how["args"], {"values": {}, "stats": {"before": zero, "after": stats, "window_s": 1.0}})
+    assert read == pytest.approx(
+        (stats["dsa_select_columns_prefill"] + stats["dsa_select_columns"])
+        / (2 * L * sum(t + 1 for t in range(157))))
     # of the 16 a query keeps, the cached ones were attended (all, or all but its own), out of
     # the whole pages of 8 the walk copies: 152 positions at lengths 150-152, 160 from 153
     assert 2 * L * 7 * (K - 1) <= stats["kv_positions_attended"] <= 2 * L * 7 * K
@@ -539,3 +652,39 @@ def test_preemption_by_recompute_and_an_early_join_give_the_same_tokens():
     (a_toks, b_toks), alone, stats = asyncio.run(join())
     assert a_toks == hog_o.tokens and b_toks == alone
     assert stats["kv_blocks_in_use"] == 0
+
+
+# ----------------------------------------------------------------------
+# (g) the benchmark's entry over the choice's two counters
+# ----------------------------------------------------------------------
+def test_the_columns_a_candidate_entry_reads_the_two_counters():
+    import json
+
+    from benchmark import readers, spec
+
+    name, cell = "dsa_select_columns_per_candidate.glm5", "glm-5.serve.longrepo-backlog"
+    how = spec.load_layer_metric(name)
+    assert how["reader"] == "stats_delta"
+    # a 16k prompt's fifth chunk (positions 16,384 .. 20,479 of 20,480: 8 tiles reaching 9, 9, 9, 9, 10, 10,
+    # 10, 10 key blocks) in six layers, then one decode step of 20 lanes at 20,480 over a table of 36,864
+    chunk = 6 * 512 * 2048 * (4 * 9 + 4 * 10)
+    cached = 6 * sum(range(16385, 20481))
+    step, candidates = 6 * 20 * 36864, 6 * 20 * 20481
+    before = {"dsa_select_columns_prefill": 5, "dsa_select_columns": 7, "dsa_positions_cached_prefill": 11,
+              "dsa_positions_cached": 13}
+    after = {"dsa_select_columns_prefill": 5 + chunk, "dsa_select_columns": 7 + step,
+             "dsa_positions_cached_prefill": 11 + cached, "dsa_positions_cached": 13 + candidates}
+    ctx = {"values": {}, "stats": {"before": before, "after": after, "window_s": 30.0}}
+    by_hand = (chunk + step) / (cached + candidates)
+    assert 1.0 < by_hand < 1.2
+    assert readers.stats_delta(how["args"], ctx) == pytest.approx(by_hand)
+    # the parent's arithmetic on the same work: 40,960 columns a query of the chunk
+    assert (6 * 4096 * 40960 + step) / (cached + candidates) > 2
+    # on a program without the two counters the entry is left out, not raised
+    old = {"before": {"dsa_positions_cached": 13}, "after": {"dsa_positions_cached": 99}, "window_s": 30.0}
+    assert readers.stats_delta(how["args"], {"values": {}, "stats": old}) is None
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        entry = next(m for m in json.load(f)["per_layer"] if m["name"] == name)
+    assert entry == {"name": name, "unit": "cols/candidate", "better": "lower", "source": "program_counter",
+                     "layer": "models", "moves": "serve_out_tokens_per_s", "workloads": [cell]}
+    assert {"dsa_select_columns_prefill", "dsa_select_columns"} <= set(glm.COUNTERS)
