@@ -1,12 +1,13 @@
 """Shared scaffolding for the model families.
 
-One copy of the loss and the pure train step that gpt2.py and llama.py
-both build on — the models differ in architecture, not in how they
-train — of the sampler the serving engine applies to whatever
-family's logits (``sample_logits``), of what a served family states of
-its cache (``CacheSpec``), and of the layer helpers more than one served
-family uses (``rmsnorm``, ``rope``, ``yarn_inv_freq``, ``pool_rows``): a family imports
-from here, never a private name of another family.  Laying a state out
+One copy of the loss and the pure train step that gpt2.py builds on, of
+the sampler the serving engine applies to whatever family's logits
+(``sample_logits``), of what a served family states of its cache
+(``CacheSpec``), and of the layer helpers more than one served family
+uses (``rmsnorm``, ``rope``, ``yarn_inv_freq``, ``pool_rows``).  The
+layers families share, which take a layer's parameters and the cache,
+are ``models/layers.py``'s: a family imports from here and from there,
+never from another family.  Laying a state out
 on a mesh and jitting the step over it is ``ray_tpu.train.sharding``'s
 (``GspmdPlan.shard_init`` / ``jit_train_step``).
 """

@@ -91,6 +91,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.common import CacheSpec, rmsnorm
+from ray_tpu.models.layers import chunk_context, chunk_slots, counters
 from ray_tpu.ops import dsa
 from ray_tpu.ops.mla import absorbed_queries, rope_interleaved
 
@@ -373,15 +374,6 @@ def _feed_forward(x, lp, cfg, dense):
     return shared + y, jnp.concatenate([jnp.stack([routed, here.sum(dtype=jnp.int32)]), c]), top_e
 
 
-def _counters(cfg, per_layer, **named):
-    """COUNTERS of one program from its expert layers' [routed, held,
-    computed, hit, peak] and what else it counted, by name."""
-    layers = cfg.n_layer - cfg.first_k_dense_replace
-    routed, held, computed, hit, peak = jnp.stack(per_layer).sum(0).astype(jnp.int32)
-    head = [routed, held, computed, hit, jnp.int32(cfg.experts_held * layers), peak, jnp.int32(layers)]
-    return jnp.stack(head + [jnp.asarray(named.get(name, 0), jnp.int32) for name in COUNTERS[len(head):]])
-
-
 def _logits(x, params, cfg):
     return (rmsnorm(x, params["norm"], cfg.rms_norm_eps) @ params["lm_head"]).astype(jnp.float32)
 
@@ -407,24 +399,16 @@ def prefill_chosen(params, cfg: GlmMoeDsaConfig, cache, tokens, start, last_inde
     n_valid = last_index[0] + 1
     x = params["embed"][tokens[0]]
     pos = start + jnp.arange(T)
-    # the sequence's positions by page, then room for this chunk wherever it starts
-    C = table.shape[0] * block_size
-    where = (table[:, None] * block_size + jnp.arange(block_size)).reshape(C)
-    room = -(-(C + T) // dsa.KEY_BLOCK) * dsa.KEY_BLOCK - C
+    where, room = chunk_slots(table, block_size, T, dsa.KEY_BLOCK)
+    C = where.shape[0]
     # where the chunk's index keys go: a real token's own slot, the scratch slot at pads
     slots = jnp.where(jnp.arange(T) < n_valid, where[jnp.minimum(pos, C - 1)], 0)
-    pool, keys = cache["k_pages"], cache["index_k"]
-    L, P, W = pool.shape
-    Di = keys.shape[-1]
     rows_out, keys_out, counts, chose, masks, kept, columns = [], [], [], [], [], [], []
     for i, lp in enumerate(params["layers"]):
         h = rmsnorm(x, lp["w_in"], cfg.rms_norm_eps)
         q_nope, q_rope, row, q_i, w, k_i = _project(h, lp, cfg, pos)
-        # as common.pool_rows: the pools addressed as [L * P, .], never a layer copied out
-        ctx = jnp.concatenate([pool.reshape(L * P, W)[i * P + where], jnp.zeros((room, W), pool.dtype)])
-        ctx = jax.lax.dynamic_update_slice_in_dim(ctx, row, start, axis=0)
-        k_ctx = jnp.concatenate([keys.reshape(L * P, Di)[i * P + where], jnp.zeros((room, Di), keys.dtype)])
-        k_ctx = jax.lax.dynamic_update_slice_in_dim(k_ctx, k_i, start, axis=0)
+        ctx = chunk_context(cache["k_pages"], i, where, room, row, start)
+        k_ctx = chunk_context(cache["index_k"], i, where, room, k_i, start)
         att, n_kept, n_columns, mask = dsa.sparse_chunk_attention(
             q_nope, q_rope, q_i, w, ctx, k_ctx, lp["wukv"], start, n_valid, cfg, cfg.index_topk)
         x = x + att @ lp["wo"]
@@ -444,9 +428,9 @@ def prefill_chosen(params, cfg: GlmMoeDsaConfig, cache, tokens, start, last_inde
     candidates = jnp.where(jnp.arange(T) < n_valid, pos + 1, 0).sum() * cfg.n_layer
     return (_logits(x[last_index], params, cfg), jnp.stack(rows_out)[:, None], None,
             {"index_k": (jnp.stack(keys_out), slots)}, {},
-            _counters(cfg, counts, dsa_positions_cached_prefill=candidates,
-                      dsa_positions_kept_prefill=jnp.stack(kept).sum(),
-                      dsa_select_columns_prefill=jnp.stack(columns).sum()),
+            counters(COUNTERS, counts, cfg.experts_held, dsa_positions_cached_prefill=candidates,
+                     dsa_positions_kept_prefill=jnp.stack(kept).sum(),
+                     dsa_select_columns_prefill=jnp.stack(columns).sum()),
             jnp.stack(chose), jnp.stack(masks))
 
 
@@ -507,9 +491,10 @@ def decode_chosen(params, cfg: GlmMoeDsaConfig, cache, tok, block_tables, length
     running = lengths > 0
     pages = -(-lengths // block_size) * block_size  # the whole pages the attention's walk copies
     return (_logits(x, params, cfg), jnp.stack(rows_out), None, {"index_k": (jnp.stack(keys_out), slots)}, {},
-            _counters(cfg, counts, kv_positions_attended=n_cached, kv_positions_gathered=pages.sum() * cfg.n_layer,
-                      dsa_positions_cached=jnp.where(running, lengths + 1, 0).sum() * cfg.n_layer,
-                      dsa_positions_kept=n_kept - (~running).sum() * cfg.n_layer,
-                      dsa_index_positions_scored=lengths.sum() * cfg.n_layer,
-                      dsa_select_columns=running.sum() * C * cfg.n_layer),
+            counters(COUNTERS, counts, cfg.experts_held, kv_positions_attended=n_cached,
+                     kv_positions_gathered=pages.sum() * cfg.n_layer,
+                     dsa_positions_cached=jnp.where(running, lengths + 1, 0).sum() * cfg.n_layer,
+                     dsa_positions_kept=n_kept - (~running).sum() * cfg.n_layer,
+                     dsa_index_positions_scored=lengths.sum() * cfg.n_layer,
+                     dsa_select_columns=running.sum() * C * cfg.n_layer),
             jnp.stack(chose), masks)

@@ -11,7 +11,7 @@ experts, each part behind its own norm and its own scaled residual:
 - ``mamba``, Mamba-2 (128 heads of 64, state 128, ONE group, convolution
   4, scan blocks of 256): ``[z | xBC | dt] = y W_in`` (8192 | 8448 |
   128); the convolution, the scan and the gated norm as
-  ``models/nemotron_h.py`` writes them (whose mixers this module calls),
+  ``models/nemotron_h.py`` writes them (``models/layers.py`` has them),
   the norm over all 8,192 columns.  It caches the last 3 rows of ``xBC``
   and the state ``[128, 64, 128]`` float32, a sequence.
 - ``attention``: 32 query heads of 128 over 8 K/V heads, FOUR queries a
@@ -65,9 +65,11 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.common import CacheSpec, rmsnorm
-from ray_tpu.models.nemotron_h import (
-    K_BLOCK, attention_chunk, attention_decode, counters, mamba_chunk, mamba_decode, state_name, tail_name,
+from ray_tpu.models.layers import (
+    attention_chunk, attention_decode, chunk_slots, counters, mamba_chunk, mamba_decode, numbered, state_name,
+    tail_name,
 )
+from ray_tpu.ops.attention import K_BLOCK
 
 MAMBA, ATTENTION = "mamba", "attention"
 # the published kind of each of the 40 layers (config.json: layer_types): attention at 5, 15, 25, 35
@@ -85,9 +87,9 @@ COUNTERS = ("moe_pairs_routed", "moe_pairs_held", "moe_pairs", "moe_experts_hit"
 @dataclass(frozen=True)
 class GraniteHybridConfig:
     """The source's ``config.json`` under the engine's names where it
-    has one and under the Nemotron-H family's for the two mixers, which
-    this family calls (the source's key in the comment);
-    then the share held here."""
+    has one and under ``models/layers.py``'s for the two mixers it
+    shares with the Nemotron-H family (the source's key in the
+    comment); then the share held here."""
 
     vocab_size: int = 100352  # rows of the vocabulary HELD (the engine's name); ids are below it
     published_vocab_size: int = 100352
@@ -163,16 +165,6 @@ class GraniteHybridConfig:
             chunk_size=8, intermediate_size=32, shared_intermediate_size=48, num_local_experts=16,
             experts_held=16, num_experts_per_tok=4, attention_multiplier=0.25, max_seq_len=512, prefill_chunk=32)
         return GraniteHybridConfig(**{**fields, **kw})
-
-
-def _kinds(cfg):
-    """(kind, index among the layers of its kind) of every layer."""
-    seen = {MAMBA: 0, ATTENTION: 0}
-    out = []
-    for kind in cfg.layer_types:
-        out.append((kind, seen[kind]))
-        seen[kind] += 1
-    return out
 
 
 # ----------------------------------------------------------------------
@@ -333,12 +325,9 @@ def prefill_chosen(params, cfg: GraniteHybridConfig, cache, tokens, start, last_
     n_valid = last_index[0] + 1
     res = cfg.residual_multiplier
     x = _embed(tokens[0], params, cfg)
-    # the sequence's positions by page, then room for this chunk wherever it starts
-    C = table.shape[0] * block_size
-    where = (table[:, None] * block_size + jnp.arange(block_size)).reshape(C)
-    room = -(-(C + T) // K_BLOCK) * K_BLOCK - C
+    where, room = chunk_slots(table, block_size, T, K_BLOCK)
     ks, vs, state, counts, chose = [], [], {}, [], []
-    for lp, (kind, i) in zip(params["layers"], _kinds(cfg)):
+    for lp, (kind, i) in zip(params["layers"], numbered(cfg.layer_types)):
         y = rmsnorm(x, lp["norm1"], cfg.layer_norm_epsilon)
         if kind == MAMBA:
             out, after = mamba_chunk(y, lp, cfg, cache, i, lane, start, n_valid)
@@ -354,7 +343,9 @@ def prefill_chosen(params, cfg: GraniteHybridConfig, cache, tokens, start, last_
         chose.append(top_e)
         x = x + res * out
     return (_logits(x[last_index], params, cfg), jnp.stack(ks)[:, None], jnp.stack(vs)[:, None], {}, state,
-            counters(cfg, counts, chunk_tokens=n_valid * cfg.layer_types.count(MAMBA)), jnp.stack(chose))
+            counters(COUNTERS, counts, cfg.experts_held, ssm_chunk_tokens=n_valid * cfg.layer_types.count(MAMBA),
+                     kv_blocks_walked=(0, 0)),  # stated: layers.counters says why
+            jnp.stack(chose))
 
 
 def decode_forward_cached(params, cfg: GraniteHybridConfig, cache, tok, block_tables, lengths,
@@ -378,7 +369,7 @@ def decode_chosen(params, cfg: GraniteHybridConfig, cache, tok, block_tables, le
     res = cfg.residual_multiplier
     x = _embed(tok, params, cfg)
     ks, vs, state, counts, chose = [], [], {}, [], []
-    for lp, (kind, i) in zip(params["layers"], _kinds(cfg)):
+    for lp, (kind, i) in zip(params["layers"], numbered(cfg.layer_types)):
         y = rmsnorm(x, lp["norm1"], cfg.layer_norm_epsilon)
         if kind == MAMBA:
             out, after = mamba_decode(y, lp, cfg, cache, i, runs)
@@ -396,5 +387,7 @@ def decode_chosen(params, cfg: GraniteHybridConfig, cache, tok, block_tables, le
     pages = -(-lengths // block_size) * block_size
     n_a, n_m = cfg.layer_types.count(ATTENTION), cfg.layer_types.count(MAMBA)
     return (_logits(x, params, cfg), jnp.stack(ks), jnp.stack(vs), {}, state,
-            counters(cfg, counts, lengths.sum() * n_a, pages.sum() * n_a, runs.sum() * n_m,
-                     blocks=gqa_decode_blocks(cache["k_pages"], lengths, block_size, n_a)), jnp.stack(chose))
+            counters(COUNTERS, counts, cfg.experts_held, kv_positions_attended=lengths.sum() * n_a,
+                     kv_positions_gathered=pages.sum() * n_a, ssm_lane_steps=runs.sum() * n_m,
+                     kv_blocks_walked=gqa_decode_blocks(cache["k_pages"], lengths, block_size, n_a)),
+            jnp.stack(chose))

@@ -70,8 +70,11 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.common import CacheSpec, rmsnorm
-from ray_tpu.models.nemotron_h import K_BLOCK, attention_chunk, attention_decode, state_name, tail_name
+from ray_tpu.models.layers import (
+    attention_chunk, attention_decode, chunk_slots, counters, numbered, state_name, tail_name,
+)
 from ray_tpu.ops import mamba1
+from ray_tpu.ops.attention import K_BLOCK
 from ray_tpu.ops.mamba2 import conv_tail
 
 MAMBA, ATTENTION = "mamba", "attention"
@@ -139,16 +142,6 @@ class JambaConfig:
         fields = dict(vocab_size=256, n_layer=8, attn_layer_period=4, attn_layer_offset=2, d_model=40, n_head=5,
                       intermediate_size=64, mamba_d_state=4, mamba_dt_rank=4, max_seq_len=256, prefill_chunk=8)
         return JambaConfig(**{**fields, **kw})
-
-
-def _kinds(cfg):
-    """(kind, index among the layers of its kind) of every layer."""
-    seen = {MAMBA: 0, ATTENTION: 0}
-    out = []
-    for kind in cfg.layer_types:
-        out.append((kind, seen[kind]))
-        seen[kind] += 1
-    return out
 
 
 # ----------------------------------------------------------------------
@@ -309,11 +302,6 @@ def _logits(x, params, cfg):
     return jax.lax.dot_general(y, params["embed"], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
 
 
-def _counters(attended=0, gathered=0, lane_steps=0, chunk_tokens=0, blocks=(0, 0)):
-    return jnp.stack([*(jnp.asarray(v, jnp.int32) for v in (attended, gathered, lane_steps, chunk_tokens)),
-                      *jnp.asarray(blocks, jnp.int32)])
-
-
 # ----------------------------------------------------------------------
 # the two forwards
 # ----------------------------------------------------------------------
@@ -330,12 +318,9 @@ def prefill_chunk(params, cfg: JambaConfig, cache, tokens, start, last_index, ta
     T = tokens.shape[1]
     n_valid = last_index[0] + 1
     x = params["embed"][tokens[0]]
-    # the sequence's positions by page, then room for this chunk wherever it starts
-    C = table.shape[0] * block_size
-    where = (table[:, None] * block_size + jnp.arange(block_size)).reshape(C)
-    room = -(-(C + T) // K_BLOCK) * K_BLOCK - C
+    where, room = chunk_slots(table, block_size, T, K_BLOCK)
     ks, vs, state = [], [], {}
-    for lp, (kind, i) in zip(params["layers"], _kinds(cfg)):
+    for lp, (kind, i) in zip(params["layers"], numbered(cfg.layer_types)):
         y = rmsnorm(x, lp["norm1"], cfg.layer_norm_epsilon)
         if kind == MAMBA:
             out, after = mamba_chunk(y, lp, cfg, cache, i, lane, start, n_valid)
@@ -347,7 +332,8 @@ def prefill_chunk(params, cfg: JambaConfig, cache, tokens, start, last_index, ta
         x = x + out
         x = x + _mlp(x, lp, cfg)
     return (_logits(x[last_index], params, cfg), jnp.stack(ks)[:, None], jnp.stack(vs)[:, None], {}, state,
-            _counters(chunk_tokens=n_valid * cfg.layer_types.count(MAMBA)))
+            counters(COUNTERS, ssm_chunk_tokens=n_valid * cfg.layer_types.count(MAMBA),
+                     kv_blocks_walked=(0, 0)))  # stated: layers.counters says why
 
 
 def decode_forward_cached(params, cfg: JambaConfig, cache, tok, block_tables, lengths, block_size: int):
@@ -363,7 +349,7 @@ def decode_forward_cached(params, cfg: JambaConfig, cache, tok, block_tables, le
     runs = lengths > 0
     x = params["embed"][tok]
     ks, vs, state = [], [], {}
-    for lp, (kind, i) in zip(params["layers"], _kinds(cfg)):
+    for lp, (kind, i) in zip(params["layers"], numbered(cfg.layer_types)):
         y = rmsnorm(x, lp["norm1"], cfg.layer_norm_epsilon)
         if kind == MAMBA:
             out, after = mamba_decode(y, lp, cfg, cache, i, runs)
@@ -377,5 +363,6 @@ def decode_forward_cached(params, cfg: JambaConfig, cache, tok, block_tables, le
     pages = -(-lengths // block_size) * block_size
     n_a, n_m = cfg.layer_types.count(ATTENTION), cfg.layer_types.count(MAMBA)
     return (_logits(x, params, cfg), jnp.stack(ks), jnp.stack(vs), {}, state,
-            _counters(lengths.sum() * n_a, pages.sum() * n_a, runs.sum() * n_m,
-                      blocks=gqa_decode_blocks(cache["k_pages"], lengths, block_size, n_a)))
+            counters(COUNTERS, kv_positions_attended=lengths.sum() * n_a, kv_positions_gathered=pages.sum() * n_a,
+                     ssm_lane_steps=runs.sum() * n_m,
+                     kv_blocks_walked=gqa_decode_blocks(cache["k_pages"], lengths, block_size, n_a)))

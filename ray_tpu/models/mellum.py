@@ -82,8 +82,9 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.common import CacheSpec, pool_rows, rmsnorm, rope, yarn_inv_freq
-from ray_tpu.models.nemotron_h import K_BLOCK, chunk_attention
+from ray_tpu.models.common import CacheSpec, rmsnorm, rope, yarn_inv_freq
+from ray_tpu.models.layers import chunk_context, chunk_slots, numbered
+from ray_tpu.ops.attention import K_BLOCK, chunk_attention
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 # the published kind of each of the 28 layers (config.json: layer_types): full at 3, 7, 11, ...
@@ -171,16 +172,6 @@ class MellumConfig:
             sliding_window=16, moe_intermediate_size=32, num_experts=8, num_experts_per_tok=2,
             original_max_position_embeddings=32, max_seq_len=512, prefill_chunk=8)
         return MellumConfig(**{**fields, **kw})
-
-
-def _kinds(cfg):
-    """(kind, index among the layers of its kind) of every layer."""
-    seen = {SLIDING: 0, FULL: 0}
-    out = []
-    for kind in cfg.layer_types:
-        out.append((kind, seen[kind]))
-        seen[kind] += 1
-    return out
 
 
 # ----------------------------------------------------------------------
@@ -369,29 +360,22 @@ def prefill_chosen(params, cfg: MellumConfig, cache, tokens, start, last_index, 
     n_valid = last_index[0] + 1
     pos = start + jnp.arange(T)
     x = params["embed"][tokens[0]]
-    # the full layers: the sequence's positions by page, then room for this chunk wherever it starts
-    C = table.shape[0] * block_size
-    where = (table[:, None] * block_size + jnp.arange(block_size)).reshape(C)
-    room = -(-(C + T) // K_BLOCK) * K_BLOCK - C
+    where, room = chunk_slots(table, block_size, T, K_BLOCK)  # the full layers' context
     # the window layers: the ring's rows in their positions' order (row c: position start - ring + c)
     ring_k, ring_v = cache[RING_K][lane], cache[RING_V][lane]  # [Lw, window, G * hd]
     order = (start + jnp.arange(ring)) % ring
     pad = -(ring + T) % cfg.chunk_block
 
-    def full_context(pages, i, rows):
-        ctx = jnp.concatenate([pool_rows(pages, i, where).reshape(-1, G, hd), jnp.zeros((room, G, hd), pages.dtype)])
-        return jax.lax.dynamic_update_slice_in_dim(ctx, rows, start, axis=0)
-
     def window_context(held, i, rows):
         return jnp.concatenate([held[i][order].reshape(-1, G, hd), rows, jnp.zeros((pad, G, hd), rows.dtype)])
 
     ks, vs, win_k, win_v, counts, chose = [], [], [], [], [], []
-    for lp, (kind, i) in zip(params["layers"], _kinds(cfg)):
+    for lp, (kind, i) in zip(params["layers"], numbered(cfg.layer_types)):
         with jax.named_scope("attn.gqa"):
             q, k, v = _qkv(rmsnorm(x, lp["norm1"], cfg.layer_norm_epsilon), lp, cfg, pos, kind)
             if kind == FULL:
-                att = chunk_attention(q, full_context(cache["k_pages"], i, k), full_context(cache["v_pages"], i, v),
-                                      start, n_valid)
+                att = chunk_attention(q, chunk_context(cache["k_pages"], i, where, room, k, start),
+                                      chunk_context(cache["v_pages"], i, where, room, v, start), start, n_valid)
                 ks.append(k)
                 vs.append(v)
             else:
@@ -440,7 +424,7 @@ def decode_chosen(params, cfg: MellumConfig, cache, tok, block_tables, lengths, 
     pool_v = cache[RING_V].reshape(1, B * n_w * window, -1)
     held = jnp.minimum(lengths, ring)  # the cached positions inside the fed token's window
     ks, vs, win_k, win_v, counts, chose = [], [], [], [], [], []
-    for lp, (kind, i) in zip(params["layers"], _kinds(cfg)):
+    for lp, (kind, i) in zip(params["layers"], numbered(cfg.layer_types)):
         with jax.named_scope("attn.gqa"):
             q, k, v = _qkv(rmsnorm(x, lp["norm1"], cfg.layer_norm_epsilon), lp, cfg, lengths, kind)
             if kind == FULL:

@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.common import CacheSpec, pool_rows, rmsnorm, rope
+from ray_tpu.models.layers import chunk_context, chunk_slots, numbered
 from ray_tpu.ops import block_sparse
 from ray_tpu.ops.lightning import lightning_chunk, lightning_slopes, lightning_step
 
@@ -131,16 +132,6 @@ class MiniCPMSalaConfig:
             init_blocks=1, window_size=32, topk=4, dense_len=64, prefill_chunk=64, **kw)
 
 
-def _kinds(cfg):
-    """(kind, index among the layers of its kind) of every layer."""
-    seen = {SPARSE: 0, LIGHTNING: 0}
-    out = []
-    for kind in cfg.mixer_types:
-        out.append((kind, seen[kind]))
-        seen[kind] += 1
-    return out
-
-
 def cache_spec(cfg: MiniCPMSalaConfig, block_size: int) -> CacheSpec:
     """The sparse layers page K and V of the K/V heads alone, and a
     compressed key a stride of positions beside them; the lightning
@@ -149,16 +140,15 @@ def cache_spec(cfg: MiniCPMSalaConfig, block_size: int) -> CacheSpec:
         raise ValueError(
             f"pages of {block_size} positions: a page must be whole strides of {cfg.kernel_stride} "
             f"and a selection block of {cfg.block_size} whole pages")
-    kinds = [k for k, _ in _kinds(cfg)]
     row = cfg.n_kv_head * cfg.head_dim
     state = (cfg.lightning_nh, cfg.lightning_head_dim, cfg.lightning_head_dim)
     # a state a lightning LAYER: a decode step then reads and writes whole
     # arrays; one array of all layers cost a strided copy a layer each way
     # (5.3 ms of a 25.4 ms step on the chip, PR 30)
     return CacheSpec(
-        paged_layers=kinds.count(SPARSE), row_width=row,
+        paged_layers=cfg.mixer_types.count(SPARSE), row_width=row,
         page_extras=(("ck_pages", block_size // cfg.kernel_stride, row, cfg.dtype),),
-        lane_state=tuple((_state_name(i), state, jnp.float32) for i in range(kinds.count(LIGHTNING))),
+        lane_state=tuple((_state_name(i), state, jnp.float32) for i in range(cfg.mixer_types.count(LIGHTNING))),
         prefill_chunk=cfg.prefill_chunk)
 
 
@@ -275,10 +265,7 @@ def prefill_chunk(params, cfg: MiniCPMSalaConfig, cache, tokens, start, last_ind
     G, hd, stride = cfg.n_kv_head, cfg.head_dim, cfg.kernel_stride
     x = (cfg.scale_emb * params["embed"][tokens[0]].astype(jnp.float32)).astype(cfg.dtype)
     pos = start + jnp.arange(T)
-    # the sequence's positions by page, then room for this chunk wherever it starts
-    C = table.shape[0] * block_size
-    where = (table[:, None] * block_size + jnp.arange(block_size)).reshape(C)
-    room = -(-(C + T) // block_sparse._K_BLOCK) * block_sparse._K_BLOCK - C
+    where, room = chunk_slots(table, block_size, T, block_sparse.K_BLOCK)
     # the windows whose last key lies in this chunk, and their rows of the pool
     per_page = block_size // stride
     n_win = -(-T // stride)
@@ -287,17 +274,12 @@ def prefill_chunk(params, cfg: MiniCPMSalaConfig, cache, tokens, start, last_ind
     win = jnp.maximum(win, 0)
     ck_where = jnp.where(whole, table[win // per_page] * per_page + win % per_page, 0)
     ks, vs, cks, states, counts = [], [], [], {}, []
-    for lp, (kind, i) in zip(params["layers"], _kinds(cfg)):
+    for lp, (kind, i) in zip(params["layers"], numbered(cfg.mixer_types)):
         y = rmsnorm(x, lp["w_in"], cfg.rms_norm_eps)
         if kind == SPARSE:
             q, k, v, z = _sparse_qkvz(y, lp, cfg)
-
-            def context(pages, rows):
-                ctx = jnp.concatenate([pool_rows(pages, i, where).reshape(C, G, hd),
-                                       jnp.zeros((room, G, hd), pages.dtype)])
-                return jax.lax.dynamic_update_slice_in_dim(ctx, rows, start, axis=0)
-
-            ctx_k, ctx_v = context(cache["k_pages"], k), context(cache["v_pages"], v)
+            ctx_k = chunk_context(cache["k_pages"], i, where, room, k, start)
+            ctx_v = chunk_context(cache["v_pages"], i, where, room, v, start)
             ck = block_sparse.compress_keys(ctx_k, cfg)
             o, counted = block_sparse.sparse_chunk_attention(q, ctx_k, ctx_v, ck, start, n_valid, cfg)
             out = _gated(o.reshape(T, -1), z) @ lp["wo"]
@@ -377,7 +359,7 @@ def decode_chosen(params, cfg: MiniCPMSalaConfig, cache, tok, block_tables, leng
     x = (cfg.scale_emb * params["embed"][tok].astype(jnp.float32)).astype(cfg.dtype)
     ks, vs, cks, states, counts, chose = [], [], [], {}, [], []
     ck_where = jnp.zeros(B, jnp.int32)
-    for lp, (kind, i) in zip(params["layers"], _kinds(cfg)):
+    for lp, (kind, i) in zip(params["layers"], numbered(cfg.mixer_types)):
         y = rmsnorm(x, lp["w_in"], cfg.rms_norm_eps)
         if kind == SPARSE:
             q, k, v, z = _sparse_qkvz(y, lp, cfg)

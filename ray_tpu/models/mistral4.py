@@ -74,7 +74,8 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.common import CacheSpec, rmsnorm, yarn_inv_freq as common_yarn_inv_freq
-from ray_tpu.ops.mla import K_BLOCK as _K_BLOCK, absorbed_queries, expanded_attention, rope_interleaved
+from ray_tpu.models.layers import chunk_context, chunk_slots, counters
+from ray_tpu.ops.mla import K_BLOCK, absorbed_queries, expanded_attention, rope_interleaved
 
 # What a forward returns after what it writes, summed over its layers:
 # token-expert pairs the router made (tokens x 4); of those, the pairs
@@ -300,14 +301,6 @@ def _experts(x, lp, cfg):
     return shared + y, jnp.concatenate([jnp.stack([routed, here.sum(dtype=jnp.int32)]), c]), top_e
 
 
-def _counters(cfg, per_layer, attended=0, gathered=0):
-    """COUNTERS of one program from its layers' [routed, held, computed,
-    hit, peak] and what its decode kernels read."""
-    routed, held, computed, hit, peak = jnp.stack(per_layer).sum(0).astype(jnp.int32)
-    return jnp.stack([routed, held, computed, hit, jnp.int32(cfg.experts_held * cfg.n_layer), peak,
-                      jnp.int32(cfg.n_layer), jnp.asarray(attended, jnp.int32), jnp.asarray(gathered, jnp.int32)])
-
-
 def _logits(x, params, cfg):
     return (rmsnorm(x, params["norm"], cfg.rms_norm_eps) @ params["lm_head"]).astype(jnp.float32)
 
@@ -330,19 +323,12 @@ def prefill_chosen(params, cfg: Mistral4Config, cache, tokens, start, last_index
     n_valid = last_index[0] + 1
     x = params["embed"][tokens[0]]
     pos = start + jnp.arange(T)
-    # the sequence's positions by page, then room for this chunk wherever it starts
-    C = table.shape[0] * block_size
-    where = (table[:, None] * block_size + jnp.arange(block_size)).reshape(C)
-    room = -(-(C + T) // _K_BLOCK) * _K_BLOCK - C
-    pool = cache["k_pages"]
-    L, P, W = pool.shape
+    where, room = chunk_slots(table, block_size, T, K_BLOCK)
     rows_out, counts, chose = [], [], []
     for i, lp in enumerate(params["layers"]):
         h = rmsnorm(x, lp["w_in"], cfg.rms_norm_eps)
         q_nope, q_rope, row = _project(h, lp, cfg, pos)
-        # as common.pool_rows: the pool addressed as [L * P, W], never a layer copied out
-        ctx = jnp.concatenate([pool.reshape(L * P, W)[i * P + where], jnp.zeros((room, W), pool.dtype)])
-        ctx = jax.lax.dynamic_update_slice_in_dim(ctx, row, start, axis=0)
+        ctx = chunk_context(cache["k_pages"], i, where, room, row, start)
         att = expanded_attention(q_nope, q_rope, ctx, lp["wukv"], start, n_valid, cfg)
         x = x + att @ lp["wo"]
         y, c, top_e = _experts(x, lp, cfg)
@@ -351,7 +337,7 @@ def prefill_chosen(params, cfg: Mistral4Config, cache, tokens, start, last_index
         counts.append(c)
         chose.append(top_e)
     return (_logits(x[last_index], params, cfg), jnp.stack(rows_out)[:, None], None, {}, {},
-            _counters(cfg, counts), jnp.stack(chose))
+            counters(COUNTERS, counts, cfg.experts_held), jnp.stack(chose))
 
 
 def decode_forward_cached(params, cfg: Mistral4Config, cache, tok, block_tables, lengths,
@@ -391,5 +377,5 @@ def decode_chosen(params, cfg: Mistral4Config, cache, tok, block_tables, lengths
         chose.append(top_e)
     pages = -(-lengths // block_size) * block_size
     return (_logits(x, params, cfg), jnp.stack(rows_out), None, {}, {},
-            _counters(cfg, counts, lengths.sum() * cfg.n_layer, pages.sum() * cfg.n_layer),
-            jnp.stack(chose))
+            counters(COUNTERS, counts, cfg.experts_held, kv_positions_attended=lengths.sum() * cfg.n_layer,
+                     kv_positions_gathered=pages.sum() * cfg.n_layer), jnp.stack(chose))

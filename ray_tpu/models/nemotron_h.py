@@ -46,14 +46,10 @@ float32 forward of the same equations and reads the same tree: ``embed
 ``lm_head [d, V]``.  Weights are seeded random, made on the device a
 layer at a time in the serving dtype.  There is no training path.
 
-The module is also where the two hybrids' shared layers live:
-``models/granite_hybrid.py`` builds its two-part layers from the Mamba-2
-mixer and the chunked grouped-query attention here (``mamba_chunk``,
-``mamba_decode``, ``attention_chunk``, ``attention_decode``, ``K_BLOCK``)
-and names its lanes' state and sums its counters as this family does
-(``tail_name``, ``state_name``, ``counters``); ``models/zaya.py``, which
-makes q, k and v its own way, calls the half of ``attention_chunk`` that
-builds the paged context and attends (``attend_chunk``).
+The Mamba-2 mixer and the grouped-query attention are not this
+module's: ``models/layers.py`` has them (``mamba_chunk``,
+``mamba_decode``, ``attention_chunk``, ``attention_decode``), for this
+family as for ``models/granite_hybrid.py``.
 
 ASSUMED, because the source's ``config.json`` does not settle it (the
 file ``benchmark/configs/nemotron-3-nano.json`` lists the same): no
@@ -75,8 +71,12 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.common import CacheSpec, pool_rows, rmsnorm
-from ray_tpu.ops import mamba2
+from ray_tpu.models.common import CacheSpec, rmsnorm
+from ray_tpu.models.layers import (
+    attention_chunk, attention_decode, chunk_slots, counters, mamba_chunk, mamba_decode, numbered, state_name,
+    tail_name,
+)
+from ray_tpu.ops.attention import K_BLOCK
 
 MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
 # the published order of the 52 layers (config.json: hybrid_override_pattern)
@@ -95,10 +95,6 @@ COUNTERS = ("moe_pairs_routed", "moe_pairs_held", "moe_pairs", "moe_experts_hit"
             "moe_expert_slots", "moe_peak_rows", "moe_layer_programs",
             "kv_positions_attended", "kv_positions_gathered", "ssm_lane_steps", "ssm_chunk_tokens",
             "kv_blocks_walked", "kv_blocks_whole")
-
-K_BLOCK = 512  # keys a block of the prefill's online softmax
-_Q_BLOCK = 512  # queries a block of it: scores are [32, _Q_BLOCK, K_BLOCK] float32
-_NEG = -1e30
 
 
 @dataclass(frozen=True)
@@ -175,24 +171,6 @@ class NemotronHConfig:
             moe_intermediate_size=32, moe_shared_expert_intermediate_size=48, n_routed_experts=32,
             experts_held=32, num_experts_per_tok=6, max_seq_len=512, prefill_chunk=32)
         return NemotronHConfig(**{**fields, **kw})
-
-
-def _kinds(cfg):
-    """(letter, index among the layers of its letter) of every layer."""
-    seen = {MAMBA: 0, EXPERTS: 0, ATTENTION: 0}
-    out = []
-    for kind in cfg.pattern:
-        out.append((kind, seen[kind]))
-        seen[kind] += 1
-    return out
-
-
-def tail_name(i: int) -> str:
-    return f"conv_tail_{i}"
-
-
-def state_name(i: int) -> str:
-    return f"ssm_state_{i}"
 
 
 # ----------------------------------------------------------------------
@@ -303,147 +281,6 @@ def serving_params(params, cfg: NemotronHConfig):
 # ----------------------------------------------------------------------
 # the layers' parts
 # ----------------------------------------------------------------------
-def _mamba_in(y, lp, cfg):
-    """y [N, d] -> the gate z [N, 4096], xBC [N, 6144] before its
-    convolution, dt [N, 64] float32 after its softplus."""
-    with jax.named_scope("mamba.in_proj"):
-        z, xbc, dt = jnp.split(y @ lp["in_proj"], [cfg.d_inner, cfg.d_inner + cfg.conv_dim], axis=-1)
-        return z, xbc, jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])
-
-
-def _mamba_split(xbc, cfg):
-    """xBC [N, 6144] after its convolution -> x [N, 64, 64], B, C [N, 8, 128]."""
-    N, G, S = xbc.shape[0], cfg.n_groups, cfg.ssm_state_size
-    x, B, C = jnp.split(xbc, [cfg.d_inner, cfg.d_inner + G * S], axis=-1)
-    return x.reshape(N, cfg.mamba_num_heads, cfg.mamba_head_dim), B.reshape(N, G, S), C.reshape(N, G, S)
-
-
-def _mamba_out(o, z, lp, cfg):
-    """The gate, the norm over each group's columns, the way out."""
-    with jax.named_scope("mamba.gate_out"):
-        N = o.shape[0]
-        u = o.reshape(N, -1).astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-        u = u.reshape(N, cfg.n_groups, -1)
-        u = u * jax.lax.rsqrt((u * u).mean(-1, keepdims=True) + cfg.layer_norm_epsilon)
-        return (u.reshape(N, -1) * lp["w_gn"].astype(jnp.float32)).astype(o.dtype) @ lp["out_proj"]
-
-
-def _qkv(y, lp, cfg):
-    """y [N, d] -> q [N, G, R, hd] and k, v [N, G, hd]."""
-    H, G, hd = cfg.n_head, cfg.n_kv_head, cfg.head_dim
-    q, k, v = jnp.split(y @ lp["wqkv"], [H * hd, (H + G) * hd], axis=-1)
-    return q.reshape(-1, G, H // G, hd), k.reshape(-1, G, hd), v.reshape(-1, G, hd)
-
-
-def chunk_attention(q, ctx_k, ctx_v, start, n_valid, scale=None):
-    """The prefill path: queries [T, G, R, hd] of the positions ``start
-    ..`` over the cached rows ``ctx_k``, ``ctx_v`` [C, G, hd] (position
-    p in row p; whole key blocks), a block of keys at a time inside an
-    online softmax.  A block of keys past a query block's last position,
-    or past the last real position, is not visited.  `scale` multiplies
-    the scores (None: ``hd ** -0.5``).  -> [T, G * R * hd]."""
-    T, G, R, hd = q.shape
-    tq = min(T, _Q_BLOCK)
-    scale = hd ** -0.5 if scale is None else scale
-    outs = []
-    for first in range(0, T, tq):
-        qb = q[first:first + tq]
-        q_pos = start + first + jnp.arange(tq)
-        seen = jnp.minimum(start + first + tq, start + n_valid)
-        blocks = jnp.where(first < n_valid, -(-seen // K_BLOCK), 0)
-
-        def body(j, carry, qb=qb, q_pos=q_pos):
-            m, l, acc = carry
-            k = jax.lax.dynamic_slice_in_dim(ctx_k, j * K_BLOCK, K_BLOCK)
-            v = jax.lax.dynamic_slice_in_dim(ctx_v, j * K_BLOCK, K_BLOCK)
-            s = jnp.einsum("tgrd,kgd->grtk", qb, k, preferred_element_type=jnp.float32) * scale
-            k_pos = j * K_BLOCK + jnp.arange(K_BLOCK)
-            s = jnp.where(k_pos[None, None, None, :] <= q_pos[None, None, :, None], s, _NEG)
-            m_new = jnp.maximum(m, s.max(-1))
-            alpha = jnp.exp(m - m_new)
-            p = jnp.exp(s - m_new[..., None])
-            l = alpha * l + p.sum(-1)
-            acc = alpha[..., None] * acc + jnp.einsum(
-                "grtk,kgd->grtd", p.astype(qb.dtype), v, preferred_element_type=jnp.float32)
-            return m_new, l, acc
-
-        init = (jnp.full((G, R, tq), _NEG, jnp.float32), jnp.zeros((G, R, tq), jnp.float32),
-                jnp.zeros((G, R, tq, hd), jnp.float32))
-        _, l, acc = jax.lax.fori_loop(0, blocks, body, init)
-        # a block of pads alone visited nothing: l is 0 there, and its rows are dropped
-        o = acc / jnp.maximum(l, 1e-30)[..., None]
-        outs.append(o.transpose(2, 0, 1, 3).reshape(tq, G * R * hd).astype(qb.dtype))
-    return jnp.concatenate(outs) if len(outs) > 1 else outs[0]
-
-
-def mamba_chunk(y, lp, cfg, cache, i, lane, start, n_valid):
-    """Mamba layer i on a chunk's normed tokens y [T, d], from lane
-    ``lane``'s tail and state (zeros where ``start`` is 0) -> (out [T,
-    d], {the tail's name, the state's name: as they stand after the last
-    real position})."""
-    z, xbc, dt = _mamba_in(y, lp, cfg)
-    with jax.named_scope("mamba.conv"):
-        tail = jnp.where(start == 0, 0, cache[tail_name(i)][lane])
-        xbc, tail = mamba2.conv_tail(xbc, tail, lp["conv_w"], lp["conv_b"], n_valid)
-    with jax.named_scope("mamba.scan"):
-        xs, B, Cm = _mamba_split(xbc, cfg)
-        held = jnp.where(start == 0, 0.0, cache[state_name(i)][lane])
-        o, held = mamba2.ssd_chunk(xs, dt, -jnp.exp(lp["A_log"]), B, Cm, lp["D"], held, n_valid, cfg.chunk_size)
-    return _mamba_out(o, z, lp, cfg), {tail_name(i): tail, state_name(i): held}
-
-
-def mamba_decode(y, lp, cfg, cache, i, runs):
-    """Mamba layer i on one normed token a lane y [B, d]: the running
-    lanes' states updated where they lie, every tail shifted -> (out [B,
-    d], {the tail's name, the state's name: the whole new arrays})."""
-    z, xbc, dt = _mamba_in(y, lp, cfg)
-    with jax.named_scope("mamba.conv"):
-        xbc, tail = mamba2.conv_tail(xbc[:, None], cache[tail_name(i)], lp["conv_w"], lp["conv_b"])
-    with jax.named_scope("mamba.step"):
-        xs, Bm, Cm = _mamba_split(xbc[:, 0], cfg)
-        o, state = mamba2.ssm_decode_step(
-            xs, dt, -jnp.exp(lp["A_log"]), Bm, Cm, lp["D"], cache[state_name(i)], runs)
-    return _mamba_out(o, z, lp, cfg), {tail_name(i): tail, state_name(i): state}
-
-
-def attention_chunk(y, lp, cfg, cache, i, where, room, start, n_valid, scale=None):
-    """Attention layer i on a chunk's normed tokens y [T, d] over the
-    sequence's cached rows (``where`` [C]: its positions' slots by page)
-    and the chunk's own, with ``room`` rows of zeros behind them so that
-    the chunk fits wherever it starts -> (out [T, d], k, v [T, G, hd])."""
-    with jax.named_scope("attn.gqa"):
-        q, k, v = _qkv(y, lp, cfg)
-        return attend_chunk(q, k, v, cache, i, where, room, start, n_valid, scale) @ lp["wo"], k, v
-
-
-def attend_chunk(q, k, v, cache, i, where, room, start, n_valid, scale=None):
-    """A chunk's queries q [T, G, R, hd] over paged layer i's cached
-    rows of the sequence (``where``, ``room`` as ``attention_chunk``
-    says) with the chunk's own k, v [T, G, hd] laid in at ``start`` ->
-    [T, G * R * hd].  What a family that makes q, k and v its own way
-    (``models/zaya.py``) shares with ``attention_chunk``."""
-    G, hd = k.shape[1:]
-
-    def context(pages, rows):
-        ctx = jnp.concatenate([pool_rows(pages, i, where).reshape(-1, G, hd),
-                               jnp.zeros((room, G, hd), pages.dtype)])
-        return jax.lax.dynamic_update_slice_in_dim(ctx, rows, start, axis=0)
-
-    return chunk_attention(q, context(cache["k_pages"], k), context(cache["v_pages"], v), start, n_valid, scale)
-
-
-def attention_decode(y, lp, cfg, cache, i, block_tables, lengths, block_size, scale=None):
-    """Attention layer i on one normed token a lane y [B, d] over the
-    lanes' pages where they lie -> (out [B, d], k, v [B, G, hd])."""
-    from ray_tpu.ops.attention import gqa_paged_decode_attention
-
-    with jax.named_scope("attn.gqa"):
-        q, k, v = _qkv(y, lp, cfg)
-        o = gqa_paged_decode_attention(q, k, v, cache["k_pages"], cache["v_pages"], i, block_tables, lengths,
-                                       block_size=block_size, scale=scale)
-        return o.reshape(y.shape[0], -1) @ lp["wo"], k, v
-
-
 def _experts(y, lp, cfg):
     """The expert part on normed tokens y [T, d]: what to add to the
     stream (the shared expert and the held routed experts' part), the
@@ -465,17 +302,6 @@ def _experts(y, lp, cfg):
     out, c = moe_experts(y, top_s, top_e, lp["w_up"], lp["w_down"], held=held, gated=False)
     routed = jnp.int32(top_e.size)
     return shared + out, jnp.concatenate([jnp.stack([routed, here.sum(dtype=jnp.int32)]), c]), top_e
-
-
-def counters(cfg, per_layer, attended=0, gathered=0, lane_steps=0, chunk_tokens=0, blocks=(0, 0)):
-    """COUNTERS of one program from its expert layers' [routed, held,
-    computed, hit, peak] (one entry an expert layer) and what its other
-    layers read (``blocks``: ``ops.attention.gqa_decode_blocks``)."""
-    routed, held, computed, hit, peak = jnp.stack(per_layer).sum(0).astype(jnp.int32)
-    n_e = len(per_layer)
-    return jnp.stack([routed, held, computed, hit, jnp.int32(cfg.experts_held * n_e), peak, jnp.int32(n_e),
-                      *(jnp.asarray(v, jnp.int32) for v in (attended, gathered, lane_steps, chunk_tokens)),
-                      *jnp.asarray(blocks, jnp.int32)])
 
 
 def _logits(x, params, cfg):
@@ -506,12 +332,9 @@ def prefill_chosen(params, cfg: NemotronHConfig, cache, tokens, start, last_inde
     T = tokens.shape[1]
     n_valid = last_index[0] + 1
     x = params["embed"][tokens[0]]
-    # the sequence's positions by page, then room for this chunk wherever it starts
-    C = table.shape[0] * block_size
-    where = (table[:, None] * block_size + jnp.arange(block_size)).reshape(C)
-    room = -(-(C + T) // K_BLOCK) * K_BLOCK - C
+    where, room = chunk_slots(table, block_size, T, K_BLOCK)
     ks, vs, state, counts, chose = [], [], {}, [], []
-    for lp, (kind, i) in zip(params["layers"], _kinds(cfg)):
+    for lp, (kind, i) in zip(params["layers"], numbered(cfg.pattern)):
         y = rmsnorm(x, lp["norm"], cfg.layer_norm_epsilon)
         if kind == MAMBA:
             out, after = mamba_chunk(y, lp, cfg, cache, i, lane, start, n_valid)
@@ -526,7 +349,9 @@ def prefill_chosen(params, cfg: NemotronHConfig, cache, tokens, start, last_inde
             chose.append(top_e)
         x = x + out
     return (_logits(x[last_index], params, cfg), jnp.stack(ks)[:, None], jnp.stack(vs)[:, None], {}, state,
-            counters(cfg, counts, chunk_tokens=n_valid * cfg.pattern.count(MAMBA)), jnp.stack(chose))
+            counters(COUNTERS, counts, cfg.experts_held, ssm_chunk_tokens=n_valid * cfg.pattern.count(MAMBA),
+                     kv_blocks_walked=(0, 0)),  # stated: layers.counters says why
+            jnp.stack(chose))
 
 
 def decode_forward_cached(params, cfg: NemotronHConfig, cache, tok, block_tables, lengths,
@@ -549,7 +374,7 @@ def decode_chosen(params, cfg: NemotronHConfig, cache, tok, block_tables, length
     runs = lengths > 0
     x = params["embed"][tok]
     ks, vs, state, counts, chose = [], [], {}, [], []
-    for lp, (kind, i) in zip(params["layers"], _kinds(cfg)):
+    for lp, (kind, i) in zip(params["layers"], numbered(cfg.pattern)):
         y = rmsnorm(x, lp["norm"], cfg.layer_norm_epsilon)
         if kind == MAMBA:
             out, after = mamba_decode(y, lp, cfg, cache, i, runs)
@@ -566,5 +391,7 @@ def decode_chosen(params, cfg: NemotronHConfig, cache, tok, block_tables, length
     pages = -(-lengths // block_size) * block_size
     n_a, n_m = cfg.pattern.count(ATTENTION), cfg.pattern.count(MAMBA)
     return (_logits(x, params, cfg), jnp.stack(ks), jnp.stack(vs), {}, state,
-            counters(cfg, counts, lengths.sum() * n_a, pages.sum() * n_a, runs.sum() * n_m,
-                     blocks=gqa_decode_blocks(cache["k_pages"], lengths, block_size, n_a)), jnp.stack(chose))
+            counters(COUNTERS, counts, cfg.experts_held, kv_positions_attended=lengths.sum() * n_a,
+                     kv_positions_gathered=pages.sum() * n_a, ssm_lane_steps=runs.sum() * n_m,
+                     kv_blocks_walked=gqa_decode_blocks(cache["k_pages"], lengths, block_size, n_a)),
+            jnp.stack(chose))

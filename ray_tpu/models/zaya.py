@@ -37,9 +37,10 @@ pages of 256 values a position, and one array a lane a layer
 (``cca_tail_<i>`` ``[2 * 1280 + 128]`` in the serving dtype).  ``r`` is
 a position's own (``r_l`` of a position needs that position's
 ``r_{l-1}`` alone) and is not cached.  Its two forwards read the cache
-and return what to write into it, as the Nemotron-H family's do, whose
-chunked attention over the paged context this module calls
-(``nemotron_h.attend_chunk``); a decode step attends through
+and return what to write into it, as the Nemotron-H family's do; a
+chunk attends over the paged context through the shared grouped-query
+layer's half that takes q, k and v (``models/layers.py:attend_chunk``);
+a decode step attends through
 ``ops.attention.gqa_paged_decode_attention`` with its scale left at
 ``hd^-0.5`` (the temperature is in ``k`` before it is cached).
 ``benchmark/reference_zaya1.py`` is the plain float32 forward of the
@@ -74,8 +75,9 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.common import CacheSpec, rmsnorm, rope
-from ray_tpu.models.nemotron_h import K_BLOCK, attend_chunk
+from ray_tpu.models.layers import attend_chunk, chunk_slots, counters
 from ray_tpu.ops import cca
+from ray_tpu.ops.attention import K_BLOCK
 
 # What a forward returns after what it writes, summed over its layers,
 # as ``models/granite_hybrid.py`` names them (every layer has an expert
@@ -367,17 +369,6 @@ def _merge(x, out, a, b):
             ).astype(x.dtype)
 
 
-def counters(cfg, per_layer, attended=0, gathered=0, blocks=(0, 0)):
-    """COUNTERS of one program from its layers' [routed, held, computed,
-    hit, peak, skipped] and what its attention read (``blocks``:
-    ``ops.attention.gqa_decode_blocks``)."""
-    routed, held, computed, hit, peak, skipped = jnp.stack(per_layer).sum(0).astype(jnp.int32)
-    n_e = len(per_layer)
-    return jnp.stack([routed, held, computed, hit, jnp.int32(cfg.experts_held * n_e), peak, jnp.int32(n_e),
-                      jnp.asarray(attended, jnp.int32), jnp.asarray(gathered, jnp.int32), skipped,
-                      *jnp.asarray(blocks, jnp.int32)])
-
-
 def _logits(x, params, cfg):
     """The tied head: the embedding's rows, transposed."""
     y = rmsnorm(x, params["norm"], cfg.layer_norm_epsilon)
@@ -406,10 +397,7 @@ def prefill_chosen(params, cfg: ZayaConfig, cache, tokens, start, last_index, ta
     n_valid = last_index[0] + 1
     x = params["embed"][tokens[0]]
     r = jnp.zeros((T, cfg.router_hidden_size), jnp.float32)
-    # the sequence's positions by page, then room for this chunk wherever it starts
-    C = table.shape[0] * block_size
-    where = (table[:, None] * block_size + jnp.arange(block_size)).reshape(C)
-    room = -(-(C + T) // K_BLOCK) * K_BLOCK - C
+    where, room = chunk_slots(table, block_size, T, K_BLOCK)
     ks, vs, state, counts, chose = [], [], {}, [], []
     for i, lp in enumerate(params["layers"]):
         out, k, v, state[tail_name(i)] = cca_chunk(
@@ -422,7 +410,8 @@ def prefill_chosen(params, cfg: ZayaConfig, cache, tokens, start, last_index, ta
         chose.append(top_e)
         x = _merge(x, out, lp["a2"], lp["b2"])
     return (_logits(x[last_index], params, cfg), jnp.stack(ks)[:, None], jnp.stack(vs)[:, None], {}, state,
-            counters(cfg, counts), jnp.stack(chose))
+            counters(COUNTERS, counts, cfg.experts_held, kv_blocks_walked=(0, 0)),  # stated: layers.counters says why
+            jnp.stack(chose))
 
 
 def decode_forward_cached(params, cfg: ZayaConfig, cache, tok, block_tables, lengths, block_size: int):
@@ -454,5 +443,7 @@ def decode_chosen(params, cfg: ZayaConfig, cache, tok, block_tables, lengths, bl
         x = _merge(x, out, lp["a2"], lp["b2"])
     pages = -(-lengths // block_size) * block_size
     return (_logits(x, params, cfg), jnp.stack(ks), jnp.stack(vs), {}, state,
-            counters(cfg, counts, lengths.sum() * cfg.n_layer, pages.sum() * cfg.n_layer,
-                     gqa_decode_blocks(cache["k_pages"], lengths, block_size, cfg.n_layer)), jnp.stack(chose))
+            counters(COUNTERS, counts, cfg.experts_held, kv_positions_attended=lengths.sum() * cfg.n_layer,
+                     kv_positions_gathered=pages.sum() * cfg.n_layer,
+                     kv_blocks_walked=gqa_decode_blocks(cache["k_pages"], lengths, block_size, cfg.n_layer)),
+            jnp.stack(chose))

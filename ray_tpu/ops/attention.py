@@ -26,6 +26,10 @@ scores of a lane's queries against the index keys in its pages
 a lane's CHOICE of positions (a pool's single row is not a copy Mosaic
 takes, so every page a lane holds is still read).
 
+A prompt chunk of the grouped-query families attends over a contiguous
+context built from the pages (``chunk_attention``: an online softmax a
+block of ``K_BLOCK`` keys at a time, plain XLA).
+
 All paths: f32 accumulation, bf16 in/out, static shapes.
 """
 
@@ -304,6 +308,53 @@ def gqa_paged_decode_attention(q, k_self, v_self, k_pages, v_pages, layer, block
     out = reference_decode_attention(q.reshape(B, G * R, Dh), jnp.repeat(k_self, R, axis=1),
                                      jnp.repeat(v_self, R, axis=1), k_ctx, v_ctx, mask)
     return out.reshape(B, G, R, Dh)
+
+
+K_BLOCK = 512  # keys a block of ``chunk_attention``'s online softmax: its context is whole blocks of it
+Q_BLOCK = 512  # queries a block of it: scores are [G, R, Q_BLOCK, K_BLOCK] float32
+_NEG = -1e30
+
+
+def chunk_attention(q, ctx_k, ctx_v, start, n_valid, scale=None):
+    """The prefill twin of ``gqa_paged_decode_attention``: a chunk's
+    queries [T, G, R, hd] of the positions ``start ..`` over the cached
+    rows ``ctx_k``, ``ctx_v`` [C, G, hd] (position p in row p; whole key
+    blocks), a block of keys at a time inside an online softmax.  A
+    block of keys past a query block's last position, or past the last
+    real position, is not visited.  `scale` multiplies the scores (None:
+    ``hd ** -0.5``).  -> [T, G * R * hd]."""
+    T, G, R, hd = q.shape
+    tq = min(T, Q_BLOCK)
+    scale = hd ** -0.5 if scale is None else scale
+    outs = []
+    for first in range(0, T, tq):
+        qb = q[first:first + tq]
+        q_pos = start + first + jnp.arange(tq)
+        seen = jnp.minimum(start + first + tq, start + n_valid)
+        blocks = jnp.where(first < n_valid, -(-seen // K_BLOCK), 0)
+
+        def body(j, carry, qb=qb, q_pos=q_pos):
+            m, l, acc = carry
+            k = jax.lax.dynamic_slice_in_dim(ctx_k, j * K_BLOCK, K_BLOCK)
+            v = jax.lax.dynamic_slice_in_dim(ctx_v, j * K_BLOCK, K_BLOCK)
+            s = jnp.einsum("tgrd,kgd->grtk", qb, k, preferred_element_type=jnp.float32) * scale
+            k_pos = j * K_BLOCK + jnp.arange(K_BLOCK)
+            s = jnp.where(k_pos[None, None, None, :] <= q_pos[None, None, :, None], s, _NEG)
+            m_new = jnp.maximum(m, s.max(-1))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new[..., None])
+            l = alpha * l + p.sum(-1)
+            acc = alpha[..., None] * acc + jnp.einsum(
+                "grtk,kgd->grtd", p.astype(qb.dtype), v, preferred_element_type=jnp.float32)
+            return m_new, l, acc
+
+        init = (jnp.full((G, R, tq), _NEG, jnp.float32), jnp.zeros((G, R, tq), jnp.float32),
+                jnp.zeros((G, R, tq, hd), jnp.float32))
+        _, l, acc = jax.lax.fori_loop(0, blocks, body, init)
+        # a block of pads alone visited nothing: l is 0 there, and its rows are dropped
+        o = acc / jnp.maximum(l, 1e-30)[..., None]
+        outs.append(o.transpose(2, 0, 1, 3).reshape(tq, G * R * hd).astype(qb.dtype))
+    return jnp.concatenate(outs) if len(outs) > 1 else outs[0]
 
 
 def causal_attention(q, k, v, *, mesh=None, sp_axis: Optional[str] = None):

@@ -26,9 +26,9 @@ import jax.numpy as jnp
 
 NEG = -1e30
 # a prompt chunk's queries are taken _Q_TILE at a time, and the keys
-# before them _K_BLOCK at a time under an online softmax: the scores of
+# before them K_BLOCK at a time under an online softmax: the scores of
 # 4,096 queries over 32k keys would be 17 GB
-_Q_TILE, _K_BLOCK = 512, 1024
+_Q_TILE, K_BLOCK = 512, 1024
 # and a tile's selection scores the compressed keys _W_BLOCK windows at a time
 _W_BLOCK = 512
 
@@ -139,7 +139,7 @@ def sparse_chunk_attention(q, ctx_k, ctx_v, ck, start, n_valid, sp):
     q [T, G, R, d] at positions ``start .. start + T - 1`` (the first
     ``n_valid`` real); ctx_k, ctx_v [C, G, d] the sequence's keys and
     values by position, this chunk's among them (C a multiple of
-    ``_K_BLOCK``, at least ``start + T``); ck [C / stride, G, d] the
+    ``K_BLOCK``, at least ``start + T``); ck [C / stride, G, d] the
     compressed keys.  -> (o [T, G, R, d] in q's dtype, int32 [4]: blocks
     kept and blocks cached, both summed over real queries and K/V heads;
     tiles that selected and tiles, of those with a real query).
@@ -154,7 +154,7 @@ def sparse_chunk_attention(q, ctx_k, ctx_v, ck, start, n_valid, sp):
     T, G, R, d = q.shape
     C = ctx_k.shape[0]
     tq = min(T, _Q_TILE)
-    kb, per = _K_BLOCK, _K_BLOCK // sp.block_size
+    kb, per = K_BLOCK, K_BLOCK // sp.block_size
     n_blocks = C // sp.block_size
     scale = 1.0 / (d ** 0.5)
     wb = min(_W_BLOCK, ck.shape[0])

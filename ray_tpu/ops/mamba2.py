@@ -1,6 +1,7 @@
 """The Mamba-2 (state-space duality) mixer's two stateful parts, for one
-layer: a causal depthwise convolution of width ``K`` that needs the last
-``K - 1`` rows it saw (the TAIL), and the selective scan of one head,
+layer (and its stateless way out, ``gated_group_norm``): a causal
+depthwise convolution of width ``K`` that needs the last ``K - 1`` rows
+it saw (the TAIL), and the selective scan of one head,
 
     S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T      ([P, N], float32)
     y_t = S_t C_t + D x_t
@@ -54,6 +55,18 @@ def conv_tail(x, tail, w, b, n_valid=None):
     end = T if n_valid is None else n_valid
     after = jax.lax.dynamic_slice_in_dim(full, end, K - 1, axis=-2)
     return jax.nn.silu(acc).astype(x.dtype), after.reshape(tail.shape)
+
+
+def gated_group_norm(o, z, w, groups, eps):
+    """The mixer's way out before its last matmul: the scan's output o
+    [N, H, P] times ``silu`` of the gate z [N, H * P] (float32), RMSNorm
+    over each group's columns, times w [H * P] -> [N, H * P] in o's
+    dtype."""
+    N = o.shape[0]
+    u = o.reshape(N, -1).astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    u = u.reshape(N, groups, -1)
+    u = u * jax.lax.rsqrt((u * u).mean(-1, keepdims=True) + eps)
+    return (u.reshape(N, -1) * w.astype(jnp.float32)).astype(o.dtype)
 
 
 def ssd_chunk(x, dt, A, B, C, D, state, n_valid, chunk: int = 128):

@@ -1,4 +1,4 @@
-"""ray_tpu.parallel — device meshes and in-program pipeline schedules.
+"""ray_tpu.parallel — device meshes.
 
 This is the TPU-native replacement for the reference's torch DDP/FSDP
 wrappers and NCCL process groups (reference:

@@ -1,13 +1,12 @@
 """MPMD pipeline parallelism: stage actor groups over compiled channels.
 
-The SPMD pipeline (``parallel/pipeline.py``) runs all stages inside one
-jitted program — right when the stages fit one mesh.  This plane is the
-MPMD formulation (PAPERS.md "Scaling Deep Learning Training with MPMD
-Pipeline Parallelism"): each stage is its OWN actor group member with
-its own program, placed via a placement group, and activations/grads
-flow stage-to-stage as wire frames over the PR 11 channel dataplane —
-shm rings same-node, persistent sockets cross-node, **no object store
-on the steady-state path**.
+The repo's one pipeline, in the MPMD formulation (PAPERS.md "Scaling
+Deep Learning Training with MPMD Pipeline Parallelism"; stages that fit
+one mesh are GSPMD's, ``train/sharding/gspmd.py``): each stage is its OWN
+actor group member with its own program, placed via a placement group,
+and activations/grads flow stage-to-stage as wire frames over the PR 11
+channel dataplane — shm rings same-node, persistent sockets cross-node,
+**no object store on the steady-state path**.
 
 Schedule: 1F1B.  Stage ``s`` of ``S`` runs ``w = min(M, S-1-s)`` warmup
 forwards, then ``M-w`` (forward, backward) pairs, then ``w`` cooldown

@@ -215,7 +215,7 @@ def _parent_chunk_attention(q, ctx_k, ctx_v, ck, start, n_valid, sp):
     went by its position: every tile scores every window the context has
     room for, in one piece.  -> (o, blocks kept, blocks cached)."""
     T, G, R, d = q.shape
-    tq, kb = min(T, block_sparse._Q_TILE), block_sparse._K_BLOCK
+    tq, kb = min(T, block_sparse._Q_TILE), block_sparse.K_BLOCK
     per, n_blocks, scale = kb // sp.block_size, ctx_k.shape[0] // sp.block_size, 1.0 / (d ** 0.5)
     ck = ck.astype(q.dtype)
 

@@ -1,5 +1,6 @@
-"""GPT-2, Llama and ViT models; sharded train steps through the sharding
-plan on the virtual 8-device CPU mesh."""
+"""GPT-2 through the sharding plan on the virtual 8-device CPU mesh, a
+second parameter tree (OLMoE's) through the same plan, and the seam
+between the served families."""
 
 import numpy as np
 import pytest
@@ -62,39 +63,16 @@ def test_gpt2_sharded_train_step_dp_tp_sp():
 
 
 # ---------------------------------------------------------------------------
-# Llama family
+# a second parameter tree through the plan; what the families share
 
 
-def test_llama_forward_and_loss():
-    from ray_tpu.models import llama
-
-    cfg = llama.LlamaConfig.tiny()
-    params = llama.init_params(cfg)
-    toks = jnp.asarray(np.random.default_rng(0).integers(0, cfg.vocab_size, (2, 33), dtype=np.int32))
-    logits = llama.Llama(cfg).apply({"params": params}, toks[:, :-1])
-    assert logits.shape == (2, 32, cfg.vocab_size)
-    loss = float(llama.loss_fn(params, toks[:, :-1], toks[:, 1:], cfg))
-    assert np.isfinite(loss)
-    # Untrained loss should be near ln(vocab) for a random model.
-    assert abs(loss - np.log(cfg.vocab_size)) < 1.5
-
-
-def test_llama_gqa_kv_heads_smaller():
-    from ray_tpu.models import llama
-
-    cfg = llama.LlamaConfig.tiny()
-    params = llama.init_params(cfg)
-    blk = params["h_0"]["attn"]
-    d_head = cfg.d_model // cfg.n_head
-    assert blk["q_proj"]["kernel"].shape[1] == cfg.n_head * d_head
-    assert blk["k_proj"]["kernel"].shape[1] == cfg.n_kv_head * d_head
-    assert cfg.n_kv_head < cfg.n_head
-
-
-def test_llama_sharded_train_step():
-    """The one rule table serves a second parameter tree: partition
-    rules given with the ShardingConfig, the same plan recipe."""
-    from ray_tpu.models import llama
+def test_a_second_tree_trains_sharded_under_rules_given_with_the_config():
+    """The one rule table serves a second parameter tree: OLMoE's, whose
+    leaves the default (GPT-2) rules do not name, under partition rules
+    given with the ShardingConfig and the same plan recipe.  The loss is
+    the next token's from ``olmoe.prefill_forward``'s logits at the last
+    position."""
+    from ray_tpu.models import common, olmoe
     from ray_tpu.train import sharding
 
     devs = jax.devices()
@@ -104,40 +82,47 @@ def test_llama_sharded_train_step():
         sharding.ShardingConfig(
             mesh_shape={"batch": 2, "model": 2},
             partition_rules=[
-                (r"token_embed/embedding", ("model", None)),
-                (r"(q_proj|k_proj|v_proj|gate_proj|up_proj)/kernel", (None, "model")),
-                (r"(o_proj|down_proj)/kernel", ("model", None)),
-                (r"lm_head/kernel", (None, "model")),
-                (r"(ln_attn|ln_mlp|ln_f)/scale", ()),
+                (r"embed", ("model", None)),
+                (r"(wqkv|router)", (None, "model")),
+                (r"layers/\d+/wo", ("model", None)),
+                (r"(wgu|wd)", ("model", None, None)),  # an expert's matrices whole, the experts split
+                (r"lm_head", (None, "model")),
+                (r"(w_in|w_qn|w_kn|w_post)", ()),
+                (r"^norm", ()),
             ],
         ),
         devs[:4],
     )
-    cfg = llama.LlamaConfig.tiny()
-    opt = __import__("optax").sgd(1e-2)
-    params, opt_state = plan.shard_init(lambda rng: llama.init_params(cfg, rng), opt)
-    step = plan.jit_train_step(llama.make_train_step(cfg, opt), params, opt_state)
-    toks = np.random.default_rng(1).integers(0, cfg.vocab_size, (4, 65), dtype=np.int32)
-    t, y = jnp.asarray(toks[:, :-1]), jnp.asarray(toks[:, 1:])
+    cfg = olmoe.OlmoeConfig.olmoe_tiny(dtype=jnp.float32)
+
+    def loss_fn(params, tokens, targets, cfg):
+        return common.next_token_loss(olmoe.prefill_forward(params, cfg, tokens)[0], targets)
+
+    opt = __import__("optax").sgd(1e-1)
+    params, opt_state = plan.shard_init(lambda rng: olmoe.init_params(cfg, rng), opt)
+    step = plan.jit_train_step(common.make_train_step(loss_fn, cfg, opt), params, opt_state)
+    toks = np.random.default_rng(1).integers(0, cfg.vocab_size, (4, 33), dtype=np.int32)
+    t, y = jnp.asarray(toks[:, :-1]), jnp.asarray(toks[:, -1])
     losses = []
     for _ in range(3):
         params, opt_state, loss = step(params, opt_state, t, y)
         losses.append(float(loss))
     assert losses[-1] < losses[0]  # learns on the repeated batch
-    # the model axis hit the projections, each leaf half a device
-    attn = params["h_0"]["attn"]
-    assert attn["q_proj"]["kernel"].sharding.spec == (None, "model")
-    assert attn["o_proj"]["kernel"].sharding.spec == ("model", None)
+    # the model axis hit the projections and the experts, each leaf half a device
+    layer = params["layers"][0]
+    assert layer["wqkv"].sharding.spec == (None, "model")
+    assert layer["wo"].sharding.spec == ("model", None)
+    assert layer["wgu"].sharding.spec == ("model", None, None)
     # a tree the rules do not cover is refused, not replicated
-    with pytest.raises(sharding.UnmatchedParamError, match="ln_f/scale"):
+    with pytest.raises(sharding.UnmatchedParamError, match="norm"):
         sharding.match_partition_rules(plan.config.rules()[:-1], params)
 
 
-def test_llama_rope_rotation_properties():
-    from ray_tpu.models.llama import rope
+def test_rope_rotation_properties():
+    from ray_tpu.models.common import rope
 
     x = jnp.asarray(np.random.default_rng(2).standard_normal((1, 8, 2, 16)), dtype=jnp.float32)
-    r = rope(x, 10000.0)
+    r = rope(x, jnp.arange(8)[None], 10000.0)
     # Norm-preserving per position...
     assert np.allclose(np.linalg.norm(np.asarray(r), axis=-1),
                        np.linalg.norm(np.asarray(x), axis=-1), atol=1e-4)
@@ -145,52 +130,43 @@ def test_llama_rope_rotation_properties():
     assert np.allclose(np.asarray(r[:, 0]), np.asarray(x[:, 0]), atol=1e-6)
 
 
-def test_vit_overfits_synthetic_batch():
-    """ViT (models/vit.py): forward shapes + a few steps overfit a tiny
-    labeled batch (the standard can-it-learn smoke for a new model
-    family; reference trains ViTs through the Train library)."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    import optax
-
-    from ray_tpu.models import vit
-
-    cfg = vit.ViTConfig.tiny(image_size=16, patch_size=4, num_classes=4,
-                             dtype=jnp.float32)
-    params = vit.init_params(cfg)
-    rng = np.random.default_rng(0)
-    images = jnp.asarray(rng.normal(size=(16, 16, 16, 3)), jnp.float32)
-    labels = jnp.asarray(rng.integers(0, 4, 16))
-
-    logits = vit.ViT(cfg).apply({"params": params}, images)
-    assert logits.shape == (16, 4)
-
-    opt = optax.adam(1e-3)
-    opt_state = opt.init(params)
-    step = jax.jit(vit.make_train_step(cfg, opt))
-    first = None
-    for _ in range(40):
-        params, opt_state, loss = step(params, opt_state, images, labels)
-        first = first if first is not None else float(loss)
-    last = float(loss)
-    assert last < first * 0.5, (first, last)
-
-
-def test_no_family_imports_a_private_name_of_another():
-    """What two families share has a public name and one home
-    (``models/common.py``; the two hybrids' layers ``models/nemotron_h.py``):
-    no module under ``ray_tpu/models`` imports a name that starts with an
-    underscore from another one there, so an edit to a private helper is
-    an edit to its own family alone."""
-    import ast
+def _model_files():
     import pathlib
 
     import ray_tpu.models
 
-    borrowed = []
-    for path in sorted(pathlib.Path(ray_tpu.models.__file__).parent.glob("*.py")):
-        for node in ast.walk(ast.parse(path.read_text())):
-            if isinstance(node, ast.ImportFrom) and (node.module or "").startswith("ray_tpu.models"):
-                borrowed += [f"{path.name}: {node.module}.{a.name}" for a in node.names if a.name.startswith("_")]
+    return sorted(pathlib.Path(ray_tpu.models.__file__).parent.glob("*.py"))
+
+
+@pytest.mark.parametrize("path", _model_files(), ids=lambda p: p.name)
+def test_a_models_module_imports_from_no_family_and_no_private_name_of_ops(path):
+    """What families share is no family's (``models/common.py``,
+    ``models/layers.py``, ``ops/``): no module under ``ray_tpu/models``
+    imports ANY name from a module named in MODEL_FAMILIES (but itself),
+    so an edit for one family's cell is an edit to no other cell's
+    program; and none imports an underscore name from ``ray_tpu.ops``,
+    so a constant a family sizes its context by has a public name in the
+    module that owns it."""
+    import ast
+
+    from ray_tpu.serve.llm.config import MODEL_FAMILIES
+
+    families = {module for module, *_ in MODEL_FAMILIES} - {"ray_tpu.models." + path.stem}
+    tree = ast.parse(path.read_text())
+    borrowed, ops_modules = [], set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            borrowed += [a.name for a in node.names if a.name in families]
+        elif isinstance(node, ast.ImportFrom):
+            module = node.module or ""
+            for a in node.names:
+                private = module.startswith("ray_tpu.ops") and a.name.startswith("_")
+                if module in families or f"{module}.{a.name}" in families or private:
+                    borrowed.append(f"{module}.{a.name}")
+                if module == "ray_tpu.ops":
+                    ops_modules.add(a.asname or a.name)
+    # and reaches for none through the module's name (``block_sparse._K_BLOCK``)
+    borrowed += [f"{node.value.id}.{node.attr}" for node in ast.walk(tree)
+                 if isinstance(node, ast.Attribute) and isinstance(node.value, ast.Name)
+                 and node.value.id in ops_modules and node.attr.startswith("_")]
     assert not borrowed, borrowed

@@ -30,7 +30,7 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 from benchmark import reference_nemotron_3_nano as reference  # noqa: E402
-from ray_tpu.models import nemotron_h as nh  # noqa: E402
+from ray_tpu.models import layers, nemotron_h as nh  # noqa: E402
 from ray_tpu.ops import mamba2, moe  # noqa: E402
 from ray_tpu.serve.llm import LLMConfig, LLMEngine  # noqa: E402
 from ray_tpu.serve.llm.engine import FINISHED  # noqa: E402
@@ -181,7 +181,7 @@ def test_a_broken_model_fails_the_tolerance(monkeypatch, broken):
             u = o.reshape(N, cfg.n_groups, -1)
             u = (u * jax.lax.rsqrt((u * u).mean(-1, keepdims=True) + cfg.layer_norm_epsilon)).reshape(N, -1)
             return (u * lp["w_gn"] * jax.nn.silu(z)) @ lp["out_proj"]
-        monkeypatch.setattr(nh, "_mamba_out", out)
+        monkeypatch.setattr(layers, "_mamba_out", out)
     elif broken == "weights_by_biased_score":
         real = nh._experts
         # b_sel enters the weights: as if the scores themselves were biased
