@@ -39,7 +39,8 @@ There is no V pool (``cache_spec``).  Two attention paths read it:
   put in at ``start``, and ``k_nope`` and ``v`` EXPANDED from them a
   block of keys at a time inside an online softmax; blocks wholly above
   the diagonal are not visited (``ops.mla.expanded_attention``, which
-  ``models/glm_moe_dsa.py`` shares).
+  ``models/glm_moe_dsa.py`` shares; with no mask, on a TPU, one kernel:
+  ``ops/pallas_mla_chunk_attention.py``).
 - *decode* (``decode_forward_cached``): ABSORBED
   (``ops.mla.absorbed_queries``).  ``q_lat = q_nope
   W_uk[i]^T`` (32 x 256), scores ``q_lat . c + q_rope . k_r`` against

@@ -9,6 +9,9 @@ position for all its heads, and reads it two ways.
   time inside an online softmax; blocks wholly above the diagonal are
   not visited.  A family that attends a CHOICE of the positions hands it
   ``keep_of`` (``ops/dsa.py``): unchosen positions score ``-1e30``.
+  Without a choice, on a TPU, the loop is one kernel
+  (``ops/pallas_mla_chunk_attention.py``): a block is expanded and
+  attended in VMEM.
 - ``absorbed_queries``: a decode step's queries against the rows as they
   lie, ``q_nope W_uk[i]^T`` beside the rotated part, for the paged
   kernels of ``ops/attention.py``.
@@ -49,11 +52,20 @@ def expanded_attention(q_nope, q_rope, ctx, wukv, start, n_valid, cfg, keep_of=N
     past a query block's last position, or past the last real position,
     is not visited.  ``keep_of(first, n)``: [n, C] bool, the positions
     the chunk's queries ``first .. first + n - 1`` attend (None: every
-    earlier one).  -> [T, H * v_head_dim]."""
+    earlier one).  -> [T, H * v_head_dim].
+
+    On a TPU, with no ``keep_of`` and where the widths fit its tiling,
+    the Pallas kernel expands and attends a block in VMEM, in tiles of
+    its own (ops.pallas_mla_chunk_attention).  Elsewhere the loop below."""
     T, H = q_nope.shape[:2]
     nope, rope, kv, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank, cfg.v_head_dim
     tq = min(T, q_block)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    if keep_of is None and jax.default_backend() == "tpu":  # as the paged kernels of ops/attention.py
+        from ray_tpu.ops import pallas_mla_chunk_attention as kernel
+
+        if kernel.kernel_takes(nope, rope, kv, dv, ctx.shape[1], q.dtype):
+            return kernel.mla_chunk_attention_kernel(q, ctx, wukv, start, n_valid, nope=nope, rope=rope, kv=kv, dv=dv)
     outs = []
     for first in range(0, T, tq):
         qb = q[first:first + tq]

@@ -456,6 +456,43 @@ def test_mistral_small_4_programs_compile_for_v5e(one_chip, monkeypatch):
     # the scores of 4,096 queries x 32 heads over 36,864 keys would be 19 GB in float32
     assert mem.temp_size_in_bytes < 1 * 2**30
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 * 2**30
+    # the chunk attends in the kernel, one call a layer: no loop's scores of a tile over a key block,
+    # and no more room than the loop took (the cell stands at 15.69 of 16 GB)
+    text = chunk.as_text()
+    assert [c.split(".")[0] for c in _kernel_calls(text)].count("mla_chunk_attention") == cfg.n_layer
+    assert "f32[32,1024,512]" not in text
+    assert mem.temp_size_in_bytes <= 410_944_000, "the parent's chunk program (3f1f045, the XLA loop): 410,944,000"
+
+
+def test_glm_moe_dsa_chunk_keeps_its_loop_for_v5e(one_chip, monkeypatch):
+    """GLM-5's chunk program (1,024 tokens, the dense layer and one
+    expert layer) attends under the index's choice: a mask a tile, so
+    ``expanded_attention``'s loop over key blocks and no chunk kernel."""
+    from ray_tpu.models import glm_moe_dsa
+    from ray_tpu.serve.llm.engine import prefill_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = glm_moe_dsa.GlmMoeDsaConfig.glm5_6l_ep16(n_layer=2)
+    C, block, slots, T = 36864, 64, 458752 + 64, 1024
+    spec = glm_moe_dsa.cache_spec(cfg, block)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    def shaped(tree):
+        return jax.tree_util.tree_map(lambda x: arr(x.shape, x.dtype), tree)
+
+    params = shaped(jax.eval_shape(lambda: glm_moe_dsa.init_params(cfg)))
+    key = shaped(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    cache = [arr((2, slots, 640), cfg.dtype), arr((2, slots, 128), cfg.dtype)]
+    text = jax.jit(lambda *a: prefill_step(cfg, 0, block, spec, *a), donate_argnums=(1, 2)).lower(
+        params, *cache, arr((1, T), jnp.int32), arr((T,), jnp.int32), arr((1,), jnp.int32),
+        arr((1,), jnp.float32), key, arr((), jnp.int32), arr((C // block,), jnp.int32),
+        arr((), jnp.int32)).compile().as_text()
+    assert not any(c.startswith("mla_chunk_attention") for c in _kernel_calls(text))
+    # a tile of 512 queries a layer runs the attention's loop (the choice's passes run more)
+    assert len(re.findall(r" while\(", text)) >= 2 * (T // 512)
+    assert "while/body/mla.attend" in text
 
 
 @pytest.mark.parametrize("lanes, H, G", [(128, 64, 8), (32, 128, 1)], ids=["nemotron-h", "granite-4.0-h"])

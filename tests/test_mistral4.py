@@ -19,6 +19,7 @@ import dataclasses
 import math
 import os
 import sys
+import types
 
 import jax
 import jax.numpy as jnp
@@ -31,7 +32,8 @@ if REPO not in sys.path:
 
 from benchmark import reference_mistral_small_4 as reference  # noqa: E402
 from ray_tpu.models import mistral4 as m4  # noqa: E402
-from ray_tpu.ops import moe  # noqa: E402
+from ray_tpu.ops import mla, moe  # noqa: E402
+from ray_tpu.ops import pallas_mla_chunk_attention as chunk_kernel  # noqa: E402
 from ray_tpu.ops.attention import mla_paged_decode_attention  # noqa: E402
 from ray_tpu.ops.pallas_mla_paged_attention import mla_paged_decode_attention_kernel  # noqa: E402
 from ray_tpu.serve.llm import LLMConfig, LLMEngine  # noqa: E402
@@ -227,6 +229,84 @@ def test_mla_paged_kernel_reads_the_lanes_pages(dtype):
         s = np.asarray(q)[3] @ K.T
         p = np.exp(s - s.max(-1, keepdims=True))
         assert _distance((p / p.sum(-1, keepdims=True)) @ K[:, :V], want[3]) < 1e-5
+
+
+# the latent widths of a tiny chunk: two heads, a row of 128 columns of which 48 are live
+_CHUNK_CFG = types.SimpleNamespace(qk_nope_head_dim=16, qk_rope_head_dim=16, kv_lora_rank=32, v_head_dim=32,
+                                   latent_row=128)
+
+
+def _chunk_inputs(T, start, dtype, cfg=_CHUNK_CFG, H=2, seed=0):
+    """(q_nope, q_rope, ctx, wukv): a chunk of T queries at ``start``
+    over whole key blocks of rows that hold it."""
+    rng = np.random.default_rng(seed + T + start)
+    nope, rope, kv, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank, cfg.v_head_dim
+    C = -(-(start + T) // mla.K_BLOCK) * mla.K_BLOCK
+    q_nope, q_rope = (jnp.asarray(0.5 * rng.normal(size=(T, H, n)), dtype) for n in (nope, rope))
+    ctx = jnp.asarray(rng.normal(size=(C, cfg.latent_row)), dtype)
+    wukv = jnp.asarray(0.2 * rng.normal(size=(kv, H * (nope + dv))), dtype)
+    return q_nope, q_rope, ctx, wukv
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("T,start,n_valid", [
+    (64, 0, 64), (64, 512, 40), (64, 700, 64),
+    (1024, 0, 1024), (1024, 512, 1000), (1024, 700, 300),
+    (1536, 0, 1536), (1536, 512, 1500), (1536, 700, 100),
+    # past the kernel's largest tile: a second, ragged one, whole or all pads
+    (4608, 700, 4608), (4608, 512, 4000),
+])
+def test_mla_chunk_kernel_is_the_loop(T, start, n_valid, dtype):
+    """The kernel in interpret mode against the XLA loop of
+    ``expanded_attention``: a chunk at the context's start, behind whole
+    key blocks and behind a part of one; every query real, the last ones
+    pads, and all but the first few (chunks of pads, a tile of pads:
+    zeros)."""
+    cfg = _CHUNK_CFG
+    q_nope, q_rope, ctx, wukv = _chunk_inputs(T, start, dtype)
+    # the loop takes whole blocks of queries: 1,536 and 4,608 are three and nine of 512
+    want = mla.expanded_attention(q_nope, q_rope, ctx, wukv, start, n_valid, cfg, q_block=min(T, 512))
+    got = chunk_kernel.mla_chunk_attention_kernel(
+        jnp.concatenate([q_nope, q_rope], axis=-1), ctx, wukv, start, n_valid, nope=cfg.qk_nope_head_dim,
+        rope=cfg.qk_rope_head_dim, kv=cfg.kv_lora_rank, dv=cfg.v_head_dim, interpret=True)
+    assert got.shape == want.shape == (T, 2 * cfg.v_head_dim) and got.dtype == dtype
+    assert _distance(got[:n_valid], want[:n_valid]) < (2e-5 if dtype == jnp.float32 else 3e-2)
+    # queries past the last real one's 1,024 (the loop's tile) visit nothing
+    assert not np.asarray(got[-(-n_valid // 1024) * 1024:], np.float32).any()
+
+
+def _primitives(jaxpr, into=("jit", "pjit", "closed_call")):
+    """The primitives a jaxpr runs, those of the jits it calls among
+    them; what a kernel or a loop holds inside is its own."""
+    names = []
+    for eqn in jaxpr.eqns:
+        names.append(eqn.primitive.name)
+        if eqn.primitive.name in into:
+            names.extend(_primitives(next(v for v in eqn.params.values() if hasattr(v, "jaxpr")).jaxpr))
+    return names
+
+
+def test_expanded_attention_takes_the_kernel_without_a_choice_on_a_tpu(monkeypatch):
+    """At Mistral's widths: with a ``keep_of`` the loop and no kernel,
+    whatever the backend; without one, on a TPU, the kernel and no
+    loop; off the TPU the loop."""
+    cfg = types.SimpleNamespace(qk_nope_head_dim=64, qk_rope_head_dim=64, kv_lora_rank=256, v_head_dim=128,
+                                latent_row=384)
+    q_nope, q_rope, ctx, wukv = _chunk_inputs(128, 512, jnp.bfloat16, cfg=cfg)
+
+    def traced(keep_of):
+        return _primitives(jax.make_jaxpr(lambda *a: mla.expanded_attention(*a, 512, 128, cfg, keep_of=keep_of))(
+            q_nope, q_rope, ctx, wukv).jaxpr)
+
+    def every(first, n):
+        return jnp.ones((n, ctx.shape[0]), bool)
+
+    off_tpu = traced(None)
+    assert "while" in off_tpu and "pallas_call" not in off_tpu
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    free, chosen = traced(None), traced(every)
+    assert "pallas_call" in free and "while" not in free
+    assert "while" in chosen and "pallas_call" not in chosen
 
 
 # ----------------------------------------------------------------------
