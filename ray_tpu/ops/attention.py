@@ -171,8 +171,15 @@ def mla_paged_decode_attention(q, row_self, pages, layer, block_tables, lengths,
         from ray_tpu.ops import pallas_mla_paged_attention as kernel
 
         if kernel.kernel_takes(H, W, v_width, block_size, pages.dtype):
-            return kernel.mla_paged_decode_attention_kernel(
-                q, row_self, pages, layer, block_tables, lengths, block_size=block_size, v_width=v_width)
+            n = kernel.lanes_a_call(B, H, W, v_width, pages.dtype)  # B where the operands fit one call's VMEM
+
+            def lanes(x, at):
+                return x if n == B else x[at:at + n]
+
+            out = [kernel.mla_paged_decode_attention_kernel(
+                lanes(q, at), lanes(row_self, at), pages, layer, lanes(block_tables, at), lanes(lengths, at),
+                block_size=block_size, v_width=v_width) for at in range(0, B, n)]
+            return out[0] if n == B else jnp.concatenate(out)
     C = block_tables.shape[1] * block_size
     idx = (block_tables[:, :, None] * block_size + jnp.arange(block_size)).reshape(B, C)
     ctx = pages[layer][idx]  # [B, C, W]

@@ -29,13 +29,14 @@ import jax.numpy as jnp
 _HI = jax.lax.Precision.HIGHEST  # the state's matmuls stay float32 on the TPU
 
 
-def conv_tail(x, tail, w, b, n_valid=None):
+def conv_tail(x, tail, w, b=None, n_valid=None):
     """The convolution and the tail after it.  x [..., T, C] at
     consecutive positions, of which the first ``n_valid`` (a traced
     scalar; None: all) are real; tail [..., (K - 1) * C] the K - 1 rows
     before the first, side by side (zeros before position 0: kept flat,
     because an array whose last two dims are ``[3, C]`` is padded to 16
-    rows on the chip); w [C, K], b [C].  -> (``silu(b + sum_j w[:, j]
+    rows on the chip); w [C, K], b [C] (None: a convolution without a
+    bias).  -> (``silu(b + sum_j w[:, j]
     x_{t-K+1+j})`` [..., T, C] in x's dtype, the tail after position
     ``n_valid - 1``)."""
     T, C = x.shape[-2:]
@@ -44,12 +45,15 @@ def conv_tail(x, tail, w, b, n_valid=None):
     if T == 1 and n_valid is None:
         # one token a lane: the tail's rows are slices of whole lane tiles, and no row is moved
         new = x[..., 0, :]
-        acc = b.astype(jnp.float32) + wf[:, K - 1] * new.astype(jnp.float32)
+        if b is None:
+            acc = wf[:, K - 1] * new.astype(jnp.float32)
+        else:
+            acc = b.astype(jnp.float32) + wf[:, K - 1] * new.astype(jnp.float32)
         for j in range(K - 1):
             acc = acc + wf[:, j] * tail[..., j * C:(j + 1) * C].astype(jnp.float32)
         return jax.nn.silu(acc).astype(x.dtype)[..., None, :], jnp.concatenate([tail[..., C:], new], axis=-1)
     full = jnp.concatenate([tail.reshape(*tail.shape[:-1], K - 1, C), x], axis=-2)  # row i is position i - (K - 1)
-    acc = b.astype(jnp.float32)
+    acc = 0.0 if b is None else b.astype(jnp.float32)
     for j in range(K):
         acc = acc + wf[:, j] * jax.lax.slice_in_dim(full, j, j + T, axis=-2).astype(jnp.float32)
     end = T if n_valid is None else n_valid

@@ -98,14 +98,35 @@ def _tiles(n_head, width, v_width, block_size, dtype) -> bool:
 
 def kernel_takes(n_head, width, v_width, block_size, dtype) -> bool:
     """The shapes the kernel's tiling can take (``_tiles``) at its WHOLE
-    compute block (the block it was measured at, PR 47: a pool whose rows
-    would halve it is gathered instead); and the two buffers of a block
+    compute block (the block it was measured at, PR 47), or, for a pool
+    of 16-bit rows, at the block its width leaves (half of it at 640
+    columns, where the walk under a choice has run since PR 57 and the
+    dense one since PR 61; a float32 pool whose rows would halve it is
+    gathered instead: none is served); and the two buffers of a block
     leave half the VMEM a kernel gets to its operands."""
     return (
         _tiles(n_head, width, v_width, block_size, dtype)
-        and block_positions(width, dtype) == _BLOCK_POSITIONS
+        and (block_positions(width, dtype) == _BLOCK_POSITIONS or jnp.dtype(dtype).itemsize == 2)
         and 2 * vmem_scratch_bytes(n_head, width, v_width, dtype) <= _VMEM_BYTES
     )
+
+
+def lanes_a_call(n_lanes, n_head, width, v_width, dtype) -> int:
+    """The lanes one call of the kernel takes: its operands (every
+    lane's queries, own row and output, float32, whole in VMEM and twice
+    over, as the grid's pipeline holds them) beside the scratch within
+    the VMEM a kernel gets unasked.  All of them where they fit (48
+    lanes of 32 heads over 384 columns do); else the largest power of two
+    that does and divides them (256 lanes of 32 heads over 640 columns:
+    32 a call)."""
+    def fits(n):
+        operands = n * (n_head * (width + v_width) + 8 * width) * 4  # the own row: a tile of 8 sublanes
+        return 2 * operands + vmem_scratch_bytes(n_head, width, v_width, dtype) <= _VMEM_BYTES
+
+    n = n_lanes
+    while not fits(n) and n % 2 == 0 and n > 1:
+        n //= 2
+    return n
 
 
 def _kernel(layer_ref, len_ref, tab_ref,               # scalar prefetch (SMEM)

@@ -27,6 +27,8 @@ MODEL_FAMILIES = (
     ("ray_tpu.models.jamba", "JambaConfig", ("jamba2_tiny", "jamba2_3b")),
     ("ray_tpu.models.zaya", "ZayaConfig", ("zaya1_tiny", "zaya1_8b", "zaya1_8b_20l")),
     ("ray_tpu.models.glm_moe_dsa", "GlmMoeDsaConfig", ("glm5_tiny", "glm5", "glm5_6l_ep16")),
+    ("ray_tpu.models.kimi_linear", "KimiLinearConfig",
+     ("kimi_linear_tiny", "kimi_linear_48b_a3b", "kimi_linear_48b_a3b_8l_ep8")),
 )
 # every preset ``LLMConfig.model`` may name, family by family
 PRESETS = " | ".join(name for *_, presets in MODEL_FAMILIES for name in presets)
@@ -99,7 +101,12 @@ class LLMConfig:
     of ``zaya1_8b_20l``); the ``glm5*`` presets page a latent row and,
     in a second pool under the same block table, an index key a position
     in every layer (9,216 B a position at the 6 layers of
-    ``glm5_6l_ep16``) and hold nothing a lane.
+    ``glm5_6l_ep16``) and hold nothing a lane; the ``kimi_linear*``
+    presets hold three convolution tails and a float32 delta-rule state a
+    KDA layer (13.0 MB a lane at the 6 KDA layers of
+    ``kimi_linear_48b_a3b_8l_ep8``) and page ONE latent row a position,
+    with no V pool, in their MLA layers alone (2,560 B a position at its
+    2 MLA layers).
 
     ``model`` names a preset of a model family, one of (from
     ``MODEL_FAMILIES``): {presets}.

@@ -1,5 +1,5 @@
 """Whether a change left the serve programs of the families that exist
-what they were (PERF.md section 6, PR 33, PR 38, PR 40, PR 41, PR 45, PR 50, PR 54 and PR 57).
+what they were (PERF.md section 6, PR 33, PR 38, PR 40, PR 41, PR 45, PR 50, PR 54, PR 57 and PR 61).
 
     JAX_PLATFORMS=cpu PYTHONPATH=<checkout> python scripts/serve_program_hashes.py out.json
 
@@ -40,6 +40,7 @@ CELLS = {  # preset: lanes, block, pool tokens, max context, prefill tokens
     "jamba2_3b": (256, 64, 786432, 8192, 2048),
     "zaya1_8b_20l": (48, 64, 229376, 16384, 2048),
     "glm5_6l_ep16": (20, 64, 458752, 36864, 4096),
+    "kimi_linear_48b_a3b_8l_ep8": (256, 64, 1835008, 40960, 2048),
 }
 
 def strip_payloads(text):

@@ -897,3 +897,89 @@ def test_glm_moe_dsa_decode_step_compiles_for_v5e(one_chip, monkeypatch):
     # read, where one lane's latent rows gathered for 20 lanes would be 944 MB a layer
     assert mem.temp_size_in_bytes < 128e6
     assert f"s32[{B + len(glm_moe_dsa.COUNTERS)}]" in text
+
+
+def test_kda_decode_step_compiles_for_v5e(one_chip):
+    """The gated-delta-rule decode kernel alone at the served shape (256
+    lanes of 32 heads of 128 x 128 float32: 2 MB a lane, two in and two
+    out of them 8 MB of the kernel's VMEM): one custom call under its own
+    name, the states going out in the buffer they came in, nothing of a
+    state's size among the temporaries."""
+    from ray_tpu.ops import pallas_kda
+
+    lanes, H, dk, dv = 256, 32, 128, 128
+    assert pallas_kda.kernel_takes(H, dk, dv)
+
+    def arr(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(pallas_kda.kda_decode_step, donate_argnums=(5,)).lower(
+        arr((lanes, H, dk), jnp.bfloat16), arr((lanes, H, dk), jnp.bfloat16), arr((lanes, H, dv), jnp.bfloat16),
+        arr((lanes, H, dk)), arr((lanes, H)), arr((lanes, H, dk, dv)), arr((lanes,), jnp.bool_)).compile()
+    calls = _kernel_calls(compiled.as_text())
+    assert len(calls) == 1 and calls[0].startswith("kda_decode_step")
+    lane_bytes = H * dk * dv * 4
+    assert 4 * lane_bytes < pallas_kda._VMEM_BYTES
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == lanes * lane_bytes
+    assert mem.temp_size_in_bytes < 16 * lane_bytes
+
+
+def test_kimi_linear_programs_compile_for_v5e(one_chip, monkeypatch):
+    """Kimi-Linear's two programs at the published widths (d 2304; KDA 32
+    heads of 128 x 128, convolutions of 4; 32 latent heads of 128 + 64
+    over one row of 576 stored as 640; 32 held experts of width 1,024 of
+    256 routed), 256 lanes over 40,960 positions in pages of 64, depth
+    cut to one layer of each mixer (K dense, A experts): the decode step
+    updates the lanes' states through the KDA kernel INTO the donated
+    array, walks the latent pages 32 lanes a call (the operands of 256
+    lanes do not fit one call's VMEM) at half the compute block, and
+    builds no ``[lanes, context, 640]`` gather; a 2,048-token chunk
+    compiles with the chunked delta rule and the attention's XLA loop (a
+    head's 192 query columns are not whole lane tiles: no chunk kernel)
+    and fits beside the cache."""
+    from ray_tpu.models import kimi_linear as kimi
+    from ray_tpu.serve.llm.engine import decode_step, prefill_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = kimi.KimiLinearConfig.kimi_linear_48b_a3b_8l_ep8(mixer_types=("K", "A"))
+    B, C, block, T, slots = 256, 40960, 64, 2048, 1441792 + 64
+    spec = kimi.cache_spec(cfg, block)
+    assert spec.names == ("k_pages", "kda_tail_q_0", "kda_tail_k_0", "kda_tail_v_0", "kda_state_0")
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    def shaped(tree):
+        return jax.tree_util.tree_map(lambda x: arr(x.shape, x.dtype), tree)
+
+    params = shaped(jax.eval_shape(lambda: kimi.init_params(cfg)))
+    key = shaped(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    cache = [arr((1, slots, 640), cfg.dtype)] + [arr((B, *shape), dtype) for _, shape, dtype in spec.lane_state]
+    held = tuple(range(1, 1 + len(cache)))
+    state_bytes = B * 32 * 128 * 128 * 4
+
+    decode = jax.jit(lambda *a: decode_step(cfg, 0, block, spec, *a), donate_argnums=held).lower(
+        params, *cache, arr((B,), jnp.int32), arr((B,), jnp.int32), arr((B, C // block), jnp.int32),
+        arr((B,), jnp.int32), arr((B,), jnp.float32), key).compile()
+    text = decode.as_text()
+    calls = [c.split(".")[0] for c in _kernel_calls(text)]
+    assert calls.count("kda_decode_step") == 1 and calls.count("mla_paged_decode_attention") == B // 32
+    assert calls.count("moe_gmm") == 2 and len(calls) == 3 + B // 32
+    assert f"bf16[{B},{C},640]" not in text
+    mem = decode.memory_analysis()
+    assert mem.alias_size_in_bytes >= state_bytes + slots * 640 * 2 + 3 * B * 12288 * 2
+    assert mem.temp_size_in_bytes < state_bytes  # no second array of states among the temporaries
+    assert f"s32[{B + len(kimi.COUNTERS)}]" in text
+
+    chunk = jax.jit(lambda *a: prefill_step(cfg, 0, block, spec, *a), donate_argnums=held).lower(
+        params, *cache, arr((1, T), jnp.int32), arr((T,), jnp.int32), arr((1,), jnp.int32),
+        arr((1,), jnp.float32), key, arr((), jnp.int32), arr((C // block,), jnp.int32),
+        arr((), jnp.int32)).compile()
+    text = chunk.as_text()
+    assert not any(c.startswith("mla_chunk_attention") for c in _kernel_calls(text))
+    assert "while/body/mla.attend" in text and "kda.chunk" in text
+    mem = chunk.memory_analysis()
+    print("kimi chunk temp bytes", mem.temp_size_in_bytes)
+    assert mem.temp_size_in_bytes < 2 * 2**30
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 * 2**30

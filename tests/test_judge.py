@@ -114,3 +114,12 @@ from benchmark.tests.test_glm_5_cell import (  # noqa: E402,F401
     test_the_configuration_is_the_catalog_s_but_for_what_it_says_is_reduced as test_the_glm_configuration_is_the_catalog_s_but_for_what_it_says_is_reduced,
     test_the_cut_s_arithmetic_reckoned_again as test_the_glm_cut_s_arithmetic_reckoned_again,
 )
+from benchmark.tests.test_kimi_linear_cell import (  # noqa: E402,F401
+    test_the_two_decode_kernels_and_the_chunk_form_s_work_by_hand,
+)
+from benchmark.tests.test_kimi_linear_cell import (  # noqa: E402,F401
+    test_runner_fails_at_once_where_the_program_has_no_such_family as test_kimi_runner_fails_at_once_where_the_program_has_no_such_family,
+    test_the_cell_s_metrics_are_the_entries_of_benchmark_json as test_the_kimi_cell_s_metrics_are_the_entries_of_benchmark_json,
+    test_the_configuration_is_the_catalog_s_but_for_what_it_says_is_reduced as test_the_kimi_configuration_is_the_catalog_s_but_for_what_it_says_is_reduced,
+    test_the_cut_s_arithmetic_reckoned_again as test_the_kimi_cut_s_arithmetic_reckoned_again,
+)
