@@ -48,12 +48,14 @@ lane tiles as ``mistral4.py`` says, keys all 576 and values the first
 (``kda_tail_q_<i>``, ``kda_tail_k_<i>``, ``kda_tail_v_<i>`` ``[3 *
 4096]`` in the serving dtype, ``kda_state_<i>`` ``[32, 128, 128]``
 float32).  Its two forwards read that cache and return what to write
-into it: ``prefill_chunk`` (``ops.kda.kda_chunk`` from the lane's state
-and tails; ``ops.mla.expanded_attention`` over the paged context, its
-XLA loop: a head's 192 query columns are not whole lane tiles, which the
-chunk kernel's tiling asks for) and ``decode_forward_cached``
-(``ops.pallas_kda.kda_decode_step`` updating the running lanes' states in
-place; the ABSORBED attention of ``ops.mla.absorbed_queries`` over the
+into it: ``prefill_chunk`` (``ops.kda.kda_chunk_scan`` from the lane's
+state and tails: on a TPU the kernel of ``ops/pallas_kda_chunk.py`` for a
+bucket of whole blocks of 64, ``ops.kda.kda_chunk`` elsewhere and for a
+prompt's tail under 64; ``ops.mla.expanded_attention`` over the paged
+context, its XLA loop: a head's 192 query columns are not whole lane
+tiles, which the latent chunk kernel's tiling asks for) and
+``decode_forward_cached`` (``ops.pallas_kda.kda_decode_step`` updating
+the running lanes' states in place; the ABSORBED attention of ``ops.mla.absorbed_queries`` over the
 pages where they lie by ``ops.attention.mla_paged_decode_attention``).
 
 The tree, which ``benchmark/reference_kimi_linear.py`` reads too: ``embed
@@ -108,12 +110,13 @@ PUBLISHED_MIXERS = tuple(MLA if n in (4, 8, 12, 16, 20, 24, 27) else KDA for n i
 # decode step the cached positions its latent kernel calls attended, the
 # positions of the whole pages they copied, and the calls there were
 # (``ops.pallas_mla_paged_attention.lanes_a_call`` lanes each); the
-# (lane, KDA layer) states it updated, idle lanes not counted; and the
-# real tokens x KDA layers a chunk's delta rule took.
+# (lane, KDA layer) states it updated, idle lanes not counted; the real
+# tokens x KDA layers a chunk's delta rule took, and those of them that
+# went through the chunk kernel (``ops.kda.chunk_kernel_takes``).
 COUNTERS = ("moe_pairs_routed", "moe_pairs_held", "moe_pairs", "moe_experts_hit",
             "moe_expert_slots", "moe_peak_rows", "moe_layer_programs",
             "kv_positions_attended", "kv_positions_gathered", "mla_decode_calls",
-            "kda_lane_steps", "kda_chunk_tokens")
+            "kda_lane_steps", "kda_chunk_tokens", "kda_chunk_kernel_tokens")
 
 _LANE = 128  # columns of a lane tile: a cached row is whole tiles
 
@@ -367,7 +370,7 @@ def kda_chunk(h, lp, cfg, cache, i, lane, start, n_valid):
             conved.append(x)
     with jax.named_scope("kda.chunk"):
         held = jnp.where(start == 0, 0.0, cache[state_name(i)][lane])
-        o, after[state_name(i)] = kda.kda_chunk(*_kda_heads(*conved, cfg), a, beta, held, n_valid)
+        o, after[state_name(i)] = kda.kda_chunk_scan(*_kda_heads(*conved, cfg), a, beta, held, n_valid)
     return _kda_out(o, gate, lp, cfg), after
 
 
@@ -471,6 +474,8 @@ def prefill_chosen(params, cfg: KimiLinearConfig, cache, tokens, start, last_ind
     layer))."""
     T = tokens.shape[1]
     n_valid = last_index[0] + 1
+    kda_tokens = n_valid * cfg.mixer_types.count(KDA)
+    in_kernel = kda.chunk_kernel_takes(T, cfg.kda_num_heads, cfg.kda_head_dim, cfg.kda_head_dim)
     x = params["embed"][tokens[0]]
     where, room = chunk_slots(table, block_size, T, K_BLOCK)
     rows_out, state, counts, chose = [], {}, [], []
@@ -491,7 +496,8 @@ def prefill_chosen(params, cfg: KimiLinearConfig, cache, tokens, start, last_ind
         if c is not None:
             counts.append(c)
     return (_logits(x[last_index], params, cfg), jnp.stack(rows_out)[:, None], None, {}, state,
-            counters(COUNTERS, counts, cfg.experts_held, kda_chunk_tokens=n_valid * cfg.mixer_types.count(KDA)),
+            counters(COUNTERS, counts, cfg.experts_held, kda_chunk_tokens=kda_tokens,
+                     kda_chunk_kernel_tokens=kda_tokens * in_kernel),
             jnp.stack(chose))
 
 

@@ -12,10 +12,11 @@ positions is no sum of decayed outer products (``ops/lightning.py``,
 ``ops/mamba2.py``).  In the two forms a server needs: ``kda_step`` takes
 one position a lane (decode; the definition of
 ``ops.pallas_kda.kda_decode_step``, which does the same in place), and
-``kda_chunk`` a run of positions and the state before it (prefill), in
-blocks of ``BLOCK`` positions.  Inside a block, with ``g_i`` the sum of
-``a`` from the block's first position through i and ``S_0`` the state
-before it, the corrections ``u_j = beta_j (v_j - S'_j^T k_j)`` solve
+``kda_chunk`` a run of positions and the state before it (prefill; the
+definition of ``ops.pallas_kda_chunk.kda_chunk_scan``, which keeps a
+block's working set in VMEM), in blocks of ``BLOCK`` positions.  Inside a
+block, with ``g_i`` the sum of ``a`` from the block's first position
+through i and ``S_0`` the state before it, the corrections ``u_j = beta_j (v_j - S'_j^T k_j)`` solve
 
     (I + Diag(beta) strict_lower(A)) U = Diag(beta) (V - (K exp(G)) S_0)
     A[j, i] = sum_c k_j[c] k_i[c] exp(g_j[c] - g_i[c])
@@ -97,6 +98,22 @@ def _decayed_products(rows, keys, g, diagonal: bool):
     return far.reshape(*lead, C, C) + near
 
 
+def _carried(state, tv, tk, p, q_in, k_out, g_last):
+    """The part of ``kda_chunk`` that goes from block to block: the state
+    [H, dk, dv] before the first block and, a block a row, ``T V``, ``T K
+    exp(G)``, ``P``, ``Q exp(G)``, ``K exp(g_last - G)`` and ``g_last`` ->
+    (the state after the last block, o [nb, H, cb, dv])."""
+
+    def block(S, xs):
+        tv, tk, p, q_in, k_out, g_last = xs
+        u = tv - jnp.einsum("hjc,hcd->hjd", tk, S, precision=_HI)
+        o = jnp.einsum("hic,hcd->hid", q_in, S, precision=_HI) + jnp.einsum("hij,hjd->hid", p, u, precision=_HI)
+        S = jnp.exp(g_last)[..., None] * S + jnp.einsum("hjc,hjd->hcd", k_out, u, precision=_HI)
+        return S, o
+
+    return jax.lax.scan(block, state, (tv, tk, p, q_in, k_out, g_last))
+
+
 def kda_chunk(q, k, v, a, beta, state, n_valid):
     """q, k, a [T, H, dk] at consecutive positions, of which the first
     ``n_valid`` (a traced scalar) are real; v [T, H, dv]; beta [T, H];
@@ -131,17 +148,32 @@ def kda_chunk(q, k, v, a, beta, state, n_valid):
     p = _decayed_products(qb, kb, g, diagonal=True)  # [nb, H, cb, cb]
     q_in = qb * eg  # what the carried state gives position i
     k_out = kb * jnp.exp(g[..., -1:, :] - g)  # what is left at the block's end of what position j added
-
-    def block(S, xs):
-        tv, tk, p, q_in, k_out, g_last = xs
-        u = tv - jnp.einsum("hjc,hcd->hjd", tk, S, precision=_HI)
-        o = jnp.einsum("hic,hcd->hid", q_in, S, precision=_HI) + jnp.einsum("hij,hjd->hid", p, u, precision=_HI)
-        S = jnp.exp(g_last)[..., None] * S + jnp.einsum("hjc,hjd->hcd", k_out, u, precision=_HI)
-        return S, o
-
-    state, o = jax.lax.scan(block, state, (tv, tk, p, q_in, k_out, g[..., -1, :]))
+    state, o = _carried(state, tv, tk, p, q_in, k_out, g[..., -1, :])
     o = jnp.moveaxis(o, 1, 2).reshape(T, H, dv) * (dk ** -0.5)
     return o.astype(v.dtype), state
+
+
+def chunk_kernel_takes(T, H, dk, dv) -> bool:
+    """Whether ``kda_chunk_scan`` gives a chunk of these shapes to the
+    kernel: on a TPU, whole blocks of positions of heads whose columns are
+    whole lane tiles (a bucket under ``BLOCK`` tokens keeps the plain
+    form)."""
+    if jax.default_backend() != "tpu":  # as the paged attentions: the CPU tests take the plain path
+        return False
+    from ray_tpu.ops import pallas_kda_chunk as kernel
+
+    return kernel.kernel_takes(T, H, dk, dv)
+
+
+def kda_chunk_scan(q, k, v, a, beta, state, n_valid):
+    """``kda_chunk`` by the backend and the shapes: the Pallas kernel
+    (ops.pallas_kda_chunk) where ``chunk_kernel_takes``, elsewhere the
+    plain form."""
+    if chunk_kernel_takes(*q.shape, v.shape[-1]):
+        from ray_tpu.ops import pallas_kda_chunk as kernel
+
+        return kernel.kda_chunk_scan(q, k, v, a, beta, state, n_valid)
+    return kda_chunk(q, k, v, a, beta, state, n_valid)
 
 
 def kda_decode_step(q, k, v, a, beta, state, active):

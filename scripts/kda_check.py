@@ -1,6 +1,7 @@
 """Kimi-Linear's delta rule alone on the chip: the decode kernel, the
-chunked form and the latent walk at the cell's shape; device ms a call,
-share of the roof, distance from the ``jax.numpy`` paths.
+chunked form, the chunk kernel and the latent walk at the cell's shape;
+device ms a call, share of the roof, distance from the ``jax.numpy``
+paths.
 
     python scripts/kda_check.py [--lanes 256] [--context 4096] [--chunk 2048] [--iters 12] [--seed 0]
 
@@ -14,7 +15,15 @@ At the shape of ``kimi-linear-48b-a3b.serve.rollout-backlog`` (sizes from
   and the largest distance of state and output from ``ops.kda.kda_step``.
 - ``kda_chunk`` (plain XLA) over ``--chunk`` positions: wall ms a call
   and its distance from the recurrence a position at a time
-  (``kda_step`` under ``lax.scan``).
+  (``kda_step`` under ``lax.scan``); its three parts alone (the two
+  decayed products, the triangular solve, the carried scan) and the
+  float32 temporaries its compiled text keeps outside VMEM.
+- ``kda_chunk_scan`` (``ops/pallas_kda_chunk.py``) beside ``kda_chunk`` at
+  every bucket of 64 to ``--chunk`` tokens: ms a layer (sixteen calls in
+  one program, the state carried from call to call), both distances
+  from the recurrence for both, and the kernel's share of the least time
+  by ``benchmark.flops_kda.kda_chunk_work`` (the bf16 peak's: six-pass
+  float32 products cannot reach it).
 - ``mla_paged_decode_attention`` over ``--lanes`` lanes of ``--context``
   cached rows of 640 columns, 32 heads, in calls of
   ``pallas_mla_paged_attention.lanes_a_call`` lanes: wall ms a layer and
@@ -38,6 +47,61 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
+def chunk_parts(kda, timed, q, k, v, a, beta, state):
+    """``ops.kda.kda_chunk``'s three parts, each a program of its own over
+    operands made before it: the two decayed products (the system's ``A``
+    and the outputs' ``P``), the triangular solve, the carried scan.  ms a
+    call; their sum is more than the whole's where XLA fuses across them."""
+    import jax
+    import jax.numpy as jnp
+
+    T, H, dk = q.shape
+    nb, cb = T // kda.BLOCK, kda.BLOCK
+
+    def blocks(x):
+        return jnp.moveaxis(x.astype(jnp.float32).reshape(nb, cb, *x.shape[1:]), 2, 1)
+
+    qb, kb, vb, ab = blocks(q), blocks(k), blocks(v), blocks(a)
+    bb = blocks(beta)[..., None]
+    g = jnp.cumsum(ab, axis=-2)
+    eg = jnp.exp(g)
+
+    @jax.jit
+    def products(qb, kb, g):
+        return kda._decayed_products(kb, kb, g, diagonal=False), kda._decayed_products(qb, kb, g, diagonal=True)
+
+    @jax.jit
+    def solve(system, rhs):
+        one = lambda m, r: jax.scipy.linalg.solve_triangular(m, r, lower=True, unit_diagonal=True)
+        return jax.vmap(jax.vmap(one))(system, rhs)
+
+    ms_products, (a_kk, p) = timed(products, qb, kb, g, n=4)
+    system = jnp.eye(cb, dtype=jnp.float32) + bb * a_kk
+    rhs = bb * jnp.concatenate([vb, kb * eg], axis=-1)
+    ms_solve, tv_tk = timed(solve, system, rhs, n=4)
+    ms_scan, _ = timed(jax.jit(kda._carried), state, tv_tk[..., :dk], tv_tk[..., dk:], p, qb * eg,
+                       kb * jnp.exp(g[..., -1:, :] - g), g[..., -1, :], n=4)
+    return {"products": ms_products, "solve": ms_solve, "scan": ms_scan}
+
+
+def hbm_temporaries(text, least_mb=32):
+    """The float32 arrays of at least `least_mb` MB that a compiled
+    program's text names outside VMEM (``S(1)`` marks VMEM): shape -> MB,
+    each shape once."""
+    import re
+
+    found = {}
+    for m in re.finditer(r"f32\[([0-9,]+)\]\{[^}]*\}", text):
+        if "S(1)" in m.group(0):
+            continue
+        size = 4
+        for dim in m.group(1).split(","):
+            size *= int(dim)
+        if size >= least_mb * 2**20:
+            found[m.group(1)] = size / 2**20
+    return found
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--lanes", type=int, default=256)
@@ -52,7 +116,7 @@ def main() -> int:
     import numpy as np
 
     from benchmark import flops, flops_kda, flops_mla, spec
-    from ray_tpu.ops import kda, pallas_kda
+    from ray_tpu.ops import kda, pallas_kda, pallas_kda_chunk
     from ray_tpu.ops import pallas_mla_paged_attention as mla_kernel
     from ray_tpu.ops.attention import mla_paged_decode_attention
 
@@ -98,15 +162,14 @@ def main() -> int:
         out["kda_decode_step." + name] = {"ms": ms, "least_ms": least, "roofline_pct": 100 * least / ms,
                                           "distance_o": dist_o, "distance_state": dist_s}
 
-    # the chunked form
+    # the chunked form: whole, and its three parts alone
     T = args.chunk
     qc, kc = unit(jax.random.normal(ks[0], (T, H, d))), unit(jax.random.normal(ks[1], (T, H, d)))
     vc = jax.random.normal(ks[2], (T, H, d)).astype(jnp.bfloat16)
     ac = jax.random.uniform(ks[3], (T, H, d), minval=-2.0, maxval=-1e-3)
     bc = jax.nn.sigmoid(jax.random.normal(ks[4], (T, H)))
     s0 = jax.random.normal(ks[6], (H, d, d))
-    chunk = jax.jit(kda.kda_chunk)
-    ms, (o, s_end) = timed(chunk, qc, kc, vc, ac, bc, s0, jnp.int32(T - 37), n=4)
+    n = T - 37
 
     @jax.jit
     def recurrence(q, k, v, a, beta, s):
@@ -116,12 +179,39 @@ def main() -> int:
 
         return jax.lax.scan(one, s, (q, k, v, a, beta))
 
-    n = T - 37
-    s_ref, o_ref = recurrence(qc[:n], kc[:n], vc[:n], ac[:n], bc[:n], s0)
-    out["kda_chunk"] = {"tokens": T, "ms": ms,
-                        "distance_o": float(jnp.abs(o[:n].astype(jnp.float32) - o_ref.astype(jnp.float32)).max()),
-                        "distance_state": float(jnp.abs(s_end - s_ref).max()),
-                        "flops": flops_kda.kda_chunk_work(config, T)["flops"]}
+    LAYERS = 16  # calls in one program, each from the state the last left: a small bucket's call is shorter than its dispatch
+
+    def against_recurrence(fn, T):
+        """ms a call over the first T positions (of LAYERS calls in one
+        program), and the distance of one call's real rows and its state
+        from the recurrence a position at a time."""
+        n = T - 37
+        xs = (qc[:T], kc[:T], vc[:T], ac[:T], bc[:T])
+
+        @jax.jit
+        def layers(q, k, v, a, beta, s, n):
+            return jax.lax.fori_loop(0, LAYERS, lambda _, o_s: fn(q, k, v, a, beta, o_s[1], n), (v, s))
+
+        ms, _ = timed(layers, *xs, s0, jnp.int32(n), n=4)
+        o, s_end = fn(*xs, s0, jnp.int32(n))
+        s_ref, o_ref = recurrence(*(x[:n] for x in xs), s0)
+        return {"ms": ms / LAYERS,
+                "distance_o": float(jnp.abs(o[:n].astype(jnp.float32) - o_ref.astype(jnp.float32)).max()),
+                "distance_state": float(jnp.abs(s_end - s_ref).max())}
+
+    chunk = jax.jit(kda.kda_chunk)
+    out["kda_chunk"] = {"tokens": T, **against_recurrence(chunk, T), "flops": flops_kda.kda_chunk_work(config, T)["flops"]}
+    # the kernel beside the form, a bucket at a time
+    out["kda_chunk_scan"] = {}
+    for bucket in (b for b in (64, 128, 256, 512, 1024, 2048) if b <= T):
+        assert pallas_kda_chunk.kernel_takes(bucket, H, d, d)
+        least = flops.least_seconds(flops_kda.kda_chunk_work(config, bucket), peak)["seconds"] * 1e3
+        kernel = against_recurrence(pallas_kda_chunk.kda_chunk_scan, bucket)
+        out["kda_chunk_scan"][str(bucket)] = {"kernel": kernel, "xla": against_recurrence(chunk, bucket),
+                                              "least_ms": least, "kernel_pct_of_least": 100 * least / kernel["ms"]}
+    out["kda_chunk"]["parts_ms"] = chunk_parts(kda, timed, qc, kc, vc, ac, bc, s0)
+    text = chunk.lower(qc, kc, vc, ac, bc, s0, jnp.int32(n)).compile().as_text()
+    out["kda_chunk"]["hbm_temporaries_mb"] = hbm_temporaries(text)
 
     # the latent walk
     W, kv, bs = 640, config["kv_lora_rank"], 64

@@ -977,9 +977,12 @@ def test_kimi_linear_programs_compile_for_v5e(one_chip, monkeypatch):
         arr((1,), jnp.float32), key, arr((), jnp.int32), arr((C // block,), jnp.int32),
         arr((), jnp.int32)).compile()
     text = chunk.as_text()
-    assert not any(c.startswith("mla_chunk_attention") for c in _kernel_calls(text))
+    calls = [c.split(".")[0] for c in _kernel_calls(text)]
+    assert "mla_chunk_attention" not in calls and calls.count("kda_chunk_scan") == 1
     assert "while/body/mla.attend" in text and "kda.chunk" in text
+    # the delta rule's solve and its float32 powers a sub-block are the kernel's, in VMEM
+    assert "InvertDiagBlocksLowerTriangular" not in text and not re.search(r"f32\[[0-9,]*16,16,128\]", text)
     mem = chunk.memory_analysis()
     print("kimi chunk temp bytes", mem.temp_size_in_bytes)
-    assert mem.temp_size_in_bytes < 2 * 2**30
+    assert mem.temp_size_in_bytes < 160 * 2**20  # 113 MiB read; 577 MiB with the delta rule in XLA
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 * 2**30

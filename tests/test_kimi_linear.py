@@ -23,6 +23,7 @@ from ``exp(-60)`` a position (a float32 zero after two) to ``exp(-1e-6)``.
 
 import asyncio
 import dataclasses
+import functools
 import os
 import sys
 
@@ -37,7 +38,7 @@ if REPO not in sys.path:
 
 from benchmark import reference_kimi_linear as reference  # noqa: E402
 from ray_tpu.models import kimi_linear as kimi  # noqa: E402
-from ray_tpu.ops import kda, pallas_kda  # noqa: E402
+from ray_tpu.ops import kda, pallas_kda, pallas_kda_chunk  # noqa: E402
 from ray_tpu.ops import pallas_mla_paged_attention as mla_kernel  # noqa: E402
 from ray_tpu.serve.llm import LLMConfig, LLMEngine  # noqa: E402
 from ray_tpu.serve.llm.engine import FINISHED  # noqa: E402
@@ -233,6 +234,94 @@ def test_decode_kernel_updates_the_running_lanes_states_in_place(active):
 
 
 # ----------------------------------------------------------------------
+# (a') the chunk kernel (interpret mode) against the chunk form and the step form
+# ----------------------------------------------------------------------
+_KERNEL = functools.partial(pallas_kda_chunk.kda_chunk_scan, interpret=True)
+
+
+@pytest.mark.parametrize("decays", list(DECAYS))
+@pytest.mark.parametrize("T, n_valid", [
+    (192, 192),  # three whole blocks
+    (192, 150),  # pads: the last block's last 42 rows, across sub-block boundaries
+    (192, 64),   # two whole blocks of pads, which the kernel does not compute
+])
+def test_chunk_kernel_is_the_chunk_form_is_the_step_form(decays, T, n_valid):
+    """``kda_chunk_scan`` at a head of 128 x 128: the same powers (every
+    one ``exp`` of a difference that is never positive), the system's
+    inverse by substitution and block merges where the form calls XLA's
+    solve, the state carried transposed: the form's tolerance holds
+    against the recurrence, pads leave the state alone."""
+    assert pallas_kda_chunk.kernel_takes(T, 2, 128, 128) and pallas_kda_chunk.kernel_takes(2048, 32, 128, 128)
+    q, k, v, a, beta, state = _delta_inputs(T, 2, 128, 128, *DECAYS[decays], seed=T + n_valid)
+    want, want_state = _by_steps(q, k, v, a, beta, state, n_valid)
+    form, form_state = jax.jit(kda.kda_chunk)(q, k, v, a, beta, state, n_valid)
+    got, got_state = _KERNEL(q, k, v, a, beta, state, n_valid)
+    scale = float(jnp.abs(want).max())
+    state_scale = float(jnp.maximum(jnp.abs(state).max(), jnp.abs(want_state).max()))
+    assert np.isfinite(np.asarray(got)).all()
+    assert _distance(got[:n_valid], want) < KDA_TOL * scale and _distance(got[:n_valid], form[:n_valid]) < KDA_TOL * scale
+    assert _distance(got_state, want_state) < KDA_TOL * state_scale
+    assert _distance(got_state, form_state) < KDA_TOL * state_scale
+
+
+def test_repeated_keys_cost_the_chunk_kernel_no_digits():
+    """``test_repeated_keys_cost_the_chunk_form_no_digits``' inputs at a
+    head of 128: the kernel's inverse is substitution inside a sub-block
+    and block merges between them, no series of powers of a system whose
+    powers pass 1e18."""
+    T, H, d = 128, 2, 128
+    k = jnp.full((T, H, d), d ** -0.5)  # of length 1
+    v = jax.random.normal(jax.random.PRNGKey(0), (T, H, d))
+    a, beta, zero = jnp.full((T, H, d), -1e-6), jnp.ones((T, H)), jnp.zeros((H, d, d))
+    want, want_state = _by_steps(k, k, v, a, beta, zero, T)
+    got, got_state = _KERNEL(k, k, v, a, beta, zero, T)
+    assert _distance(got, want) < KDA_TOL and _distance(got_state, want_state) < KDA_TOL
+
+
+def test_the_kernel_s_run_in_two_chunks_is_its_run_in_one():
+    """The first chunk's state, written after its last real position, is
+    what the second starts from."""
+    q, k, v, a, beta, state = _delta_inputs(256, 2, 128, 128, -3.0, -1e-3, seed=3)
+    whole, whole_state = _KERNEL(q, k, v, a, beta, state, 256)
+    first, mid = _KERNEL(*(x[:128] for x in (q, k, v, a, beta)), state, 100)  # 28 pads behind the first chunk
+    assert _distance(mid, _by_steps(q, k, v, a, beta, state, 100)[1]) < KDA_TOL * 4
+    first, mid = _KERNEL(*(x[:128] for x in (q, k, v, a, beta)), state, 128)
+    second, end = _KERNEL(*(x[128:] for x in (q, k, v, a, beta)), mid, 128)
+    assert _distance(jnp.concatenate([first, second]), whole) < KDA_TOL
+    assert _distance(end, whole_state) < KDA_TOL
+
+
+def test_the_state_before_the_run_counts_in_the_kernel():
+    q, k, v, a, beta, state = _delta_inputs(64, 2, 128, 128, -0.5, -1e-3, seed=11)
+    want, want_state = _by_steps(q, k, v, a, beta, state, 64)
+    got, got_state = _KERNEL(q, k, v, a, beta, state, 64)
+    from_nothing, _ = _KERNEL(q, k, v, a, beta, jnp.zeros_like(state), 64)
+    scale = float(jnp.abs(want).max())
+    assert _distance(got, want) < KDA_TOL * scale < 1e3 * KDA_TOL * scale < _distance(from_nothing, want)
+    assert _distance(got_state, want_state) < KDA_TOL * float(jnp.abs(state).max())
+
+
+@pytest.mark.parametrize("T, d", [(16, 128), (64, 16)], ids=["under_a_block", "a_head_of_16"])
+def test_the_dispatcher_keeps_the_plain_form_where_the_kernel_s_tiling_does_not_fit(monkeypatch, T, d):
+    """On a TPU too a bucket under a block of 64 tokens and a head whose
+    columns are not whole lane tiles (the tiny preset's) take
+    ``kda_chunk``: the kernel is not traced."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def never(*a, **kw):
+        raise AssertionError("the kernel was traced")
+
+    monkeypatch.setattr(pallas_kda_chunk, "kda_chunk_scan", never)
+    assert not kda.chunk_kernel_takes(T, 2, d, d) and kda.chunk_kernel_takes(64, 2, 128, 128)
+    x = _delta_inputs(T, 2, d, d, -1.0, -1e-3)
+    got, got_state = kda.kda_chunk_scan(*x, T - 3)
+    want, want_state = kda.kda_chunk(*x, T - 3)
+    assert _distance(got, want) == 0.0 and _distance(got_state, want_state) == 0.0
+    with pytest.raises(AssertionError, match="was traced"):
+        kda.kda_chunk_scan(*_delta_inputs(64, 2, 128, 128, -1.0, -1e-3), 64)
+
+
+# ----------------------------------------------------------------------
 # (b) chunks, then decode, through the engine's cache against the reference
 # ----------------------------------------------------------------------
 @pytest.mark.parametrize("n_prompt, n_new", [
@@ -302,6 +391,7 @@ def test_engine_serves_the_reference_s_tokens_and_counts_by_hand():
     assert toks == [int(t) for t in np.asarray(want).argmax(-1)]
     n_k, n_a = CFG.mixer_types.count(kimi.KDA), CFG.mixer_types.count(kimi.MLA)
     assert stats["kda_chunk_tokens"] == 70 * n_k and stats["prefill_chunks"] == 2
+    assert stats["kda_chunk_kernel_tokens"] == 0  # the CPU's path is the plain form
     assert stats["kda_lane_steps"] == 5 * n_k  # five decode steps of one running lane
     assert stats["kv_positions_attended"] == sum(range(70, 75)) * n_a
     assert stats["mla_decode_calls"] == 5 * n_a
@@ -413,3 +503,28 @@ def test_a_share_serves_through_the_engine_and_counts_what_it_held(monkeypatch):
 def KimiTinyShare(**kw):
     base = dataclasses.asdict(CFG)
     return kimi.KimiLinearConfig(**{**base, "experts_first": 4, "experts_held": 4, "vocab_size": 128, **kw})
+
+
+def test_the_engine_counts_the_tokens_that_went_through_the_chunk_kernel(monkeypatch):
+    """Heads of 128 x 128 and the dispatcher told it is on a TPU (the
+    kernel in interpret mode): every chunk of a 128-token prompt is whole
+    blocks, so ``kda_chunk_kernel_tokens`` is ``kda_chunk_tokens``, and
+    the tokens are the plain form's."""
+    wide = functools.partial(kimi.KimiLinearConfig.kimi_linear_tiny, kda_num_heads=2, kda_head_dim=128)
+    monkeypatch.setattr(kimi.KimiLinearConfig, "kimi_linear_tiny", staticmethod(wide))
+    prompt = [int(t) for t in _tokens(128, seed=6)]
+
+    async def go():
+        eng = _engine()
+        toks = await _drain(await eng.add_request(prompt, max_tokens=4))
+        stats = eng.stats()
+        await eng.stop()
+        return toks, stats
+
+    plain, stats = asyncio.run(go())
+    assert stats["kda_chunk_tokens"] > 0 == stats["kda_chunk_kernel_tokens"]
+    monkeypatch.setattr(kda, "chunk_kernel_takes", pallas_kda_chunk.kernel_takes)
+    monkeypatch.setattr(pallas_kda_chunk, "kda_chunk_scan", _KERNEL)
+    toks, stats = asyncio.run(go())
+    assert toks == plain
+    assert stats["kda_chunk_kernel_tokens"] == stats["kda_chunk_tokens"] == 128 * CFG.mixer_types.count(kimi.KDA)
